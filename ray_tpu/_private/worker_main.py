@@ -2,9 +2,10 @@
 
 Equivalent of the reference's default_worker.py (python/ray/_private/
 workers/default_worker.py): spawned by the raylet, connects back, serves
-push_task RPCs until told to exit. TPU visibility env vars
-(TPU_VISIBLE_CHIPS etc.) are set by the raylet before spawn when the lease
-carries TPU resources.
+push_task RPCs until told to exit. The raylet holds every worker to the
+CPU backend (JAX_PLATFORMS=cpu) except the one leased to TPU work, which
+sees every chip of the host: per-lease chip visibility
+(accelerators.set_visible_chips_env) is not wired in.
 """
 
 from __future__ import annotations

@@ -75,7 +75,8 @@ class Config:
     # Zygote worker factory (reference: worker_pool.h PrestartWorkers /
     # StartWorkerProcess): fork CPU workers from a warm pre-imported
     # template (~10ms) instead of a fresh interpreter (~0.25s, >1s under
-    # spawn storms). TPU-flavored workers always use fresh interpreters.
+    # spawn storms). The template never imports JAX, so TPU workers fork
+    # from it too.
     forkserver_enabled: bool = True
     # --- task retries / lineage ---
     task_max_retries: int = 3

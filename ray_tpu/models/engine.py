@@ -94,7 +94,7 @@ from ray_tpu.models.generate import (_check_sampling_knobs,
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
 from ray_tpu.models.prefix_cache import PrefixCacheIndex, block_bytes
-from ray_tpu.ops.attention import paged_attention
+from ray_tpu.ops.attention import paged_attention, spmd_mesh_scope
 from ray_tpu.ops.kv_quant import (KVQuantSpec, block_scale as
                                   _kv_block_scale, dequantize as
                                   _kv_dequantize, paged_quant_write,
@@ -2541,19 +2541,21 @@ class DecodeEngine:
                                         self._shardings.replicated)
                 btd_dev = jax.device_put(btd_dev,
                                          self._shardings.replicated)
-            (toks, self._pool_k, self._pool_v, self._pool_dk,
-             self._pool_dv, self._scale_k, self._scale_v,
-             self._scale_dk, self._scale_dv, self._last_logits, rl,
-             ac, bu, ti, dl, dt) = _spec_round_paged(
-                self.params, self.draft_params, self._pool_k,
-                self._pool_v, self._pool_dk, self._pool_dv, bt_dev,
-                btd_dev, self._last_logits, *args,
-                jnp.asarray(self._row_keys), rg, wr, self.temperature,
-                self.cfg, self.draft_cfg, W, all_greedy, self.top_k,
-                self.top_p, self.eos_id, shardings=self._shardings,
-                scale_k=self._scale_k, scale_v=self._scale_v,
-                scale_dk=self._scale_dk, scale_dv=self._scale_dv,
-                qspec=self.kv_quant_spec)
+            with spmd_mesh_scope(self.mesh):
+                (toks, self._pool_k, self._pool_v, self._pool_dk,
+                 self._pool_dv, self._scale_k, self._scale_v,
+                 self._scale_dk, self._scale_dv, self._last_logits, rl,
+                 ac, bu, ti, dl, dt) = _spec_round_paged(
+                    self.params, self.draft_params, self._pool_k,
+                    self._pool_v, self._pool_dk, self._pool_dv, bt_dev,
+                    btd_dev, self._last_logits, *args,
+                    jnp.asarray(self._row_keys), rg, wr,
+                    self.temperature, self.cfg, self.draft_cfg, W,
+                    all_greedy, self.top_k, self.top_p, self.eos_id,
+                    shardings=self._shardings,
+                    scale_k=self._scale_k, scale_v=self._scale_v,
+                    scale_dk=self._scale_dk, scale_dv=self._scale_dv,
+                    qspec=self.kv_quant_spec)
         else:
             (toks, self.cache, self._d_cache, self._last_logits, rl,
              ac, bu, ti, dl, dt) = _spec_round(
@@ -2624,16 +2626,20 @@ class DecodeEngine:
             if self._shardings is not None:
                 bt_dev = jax.device_put(bt_dev,
                                         self._shardings.replicated)
-            (toks, self._pool_k, self._pool_v, self._scale_k,
-             self._scale_v, self._last_logits,
-             rl, ac, bu, ti) = _decode_multi_paged(
-                self.params, self._pool_k, self._pool_v, bt_dev,
-                self._last_logits, *args, jnp.asarray(self._row_keys),
-                rg, self.temperature, self.cfg, H, all_greedy,
-                self.top_k, self.top_p, self.eos_id,
-                shardings=self._shardings, adapters=adapters,
-                row_slot=row_slot, scale_k=self._scale_k,
-                scale_v=self._scale_v, qspec=self.kv_quant_spec)
+            # the scope only matters while the program traces: under
+            # a tp mesh paged_attention must not pick a Mosaic kernel
+            with spmd_mesh_scope(self.mesh):
+                (toks, self._pool_k, self._pool_v, self._scale_k,
+                 self._scale_v, self._last_logits,
+                 rl, ac, bu, ti) = _decode_multi_paged(
+                    self.params, self._pool_k, self._pool_v, bt_dev,
+                    self._last_logits, *args,
+                    jnp.asarray(self._row_keys), rg, self.temperature,
+                    self.cfg, H, all_greedy, self.top_k, self.top_p,
+                    self.eos_id, shardings=self._shardings,
+                    adapters=adapters, row_slot=row_slot,
+                    scale_k=self._scale_k, scale_v=self._scale_v,
+                    qspec=self.kv_quant_spec)
         else:
             toks, self.cache, self._last_logits, rl, ac, bu, ti = \
                 _decode_multi(

@@ -41,13 +41,7 @@ def spmd_mesh_scope(mesh):
 
 def _in_manual_region() -> bool:
     """True inside a shard_map body (axes already manual there)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return False
-    if am is None or not getattr(am, "shape", None):
-        return False
-    return any("Manual" in str(t) for t in getattr(am, "axis_types", ()))
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def _flash_spmd_spec(q_shape, kv_shape, mesh):
@@ -174,7 +168,11 @@ def paged_attention(q: jax.Array,
     + attend fused, no materialized [B, MB*T, KV, D] view (off-TPU the
     kernel runs in interpret mode, which is how it is unit-tested
     against this reference). "auto" resolves to "flash" on TPU and
-    "reference" elsewhere, same policy as `attention`."""
+    "reference" elsewhere, same policy as `attention` — except while a
+    program is traced for a multi-device mesh (`spmd_mesh_scope`, which
+    the tp engine announces): GSPMD cannot partition a Mosaic kernel and
+    the paged kernel has no shard_map form, so "auto" stays on the
+    pure-lax path there."""
     if impl not in ("auto", "flash", "reference"):
         raise ValueError(f"impl must be auto|flash|reference, got {impl!r}")
     B, S, H, D = q.shape
@@ -184,7 +182,9 @@ def paged_attention(q: jax.Array,
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "reference"
+        mesh = _SPMD_MESH.get()
+        impl = "flash" if jax.default_backend() == "tpu" and (
+            mesh is None or mesh.size == 1) else "reference"
     if impl == "flash":
         from ray_tpu.ops.paged_attention_kernel import paged_attention_kernel
 

@@ -114,7 +114,11 @@ def create_mesh(axis_sizes: Dict[str, int],
         dev_array = mesh_utils.create_device_mesh(
             shape, devices=devices,
             allow_split_physical_axes=allow_split_physical_axes)
-    except Exception:
+    except AssertionError:
+        # create_device_mesh asserts that TPU devices fill a whole
+        # physical (sub-)torus. A subset that does not (three of a 2x2)
+        # has no ICI layout to follow and is laid out in the order
+        # given; a mesh shape the full torus cannot carry still raises.
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, names)
 

@@ -25,8 +25,11 @@ import sys
 N_DEVICES = 64
 HBM_PER_CHIP = 16 * 1024 ** 3        # v5e: 16 GiB
 PEAK_BF16_FLOPS = 197e12             # v5e: 197 TFLOP/s bf16
-# bench.py single-chip result (551M flagship, BENCH_r05: 54.54% with
-# the named remat policy save:ffn_* + 1024x1024 flash tiles)
+# bench.py single-chip result for the 551M flagship (54.54% with the
+# named remat policy save:ffn_* + 1024x1024 flash tiles). The record it
+# came from, BENCH_r05.json, was removed: the number is an old claim
+# from an installation that is gone, and the projection below inherits
+# that until S1's benchmark re-takes it.
 MEASURED_MFU = 0.5454
 
 # Mesh: pure fsdp over the slice — params + optimizer state shard 64
@@ -148,9 +151,10 @@ def aot_body(mesh_sizes: dict = None, cfg=None,
         "hbm_per_chip_gib": HBM_PER_CHIP / 1024 ** 3,
         "fits_16gib": per_chip <= HBM_PER_CHIP,
         "measured_single_chip_mfu": MEASURED_MFU,
-        "mfu_source": ("BENCH_r05 551M flagship (named remat policy "
+        "mfu_source": ("551M flagship (named remat policy "
                        "save:ffn_gate+ffn_up+ffn_down, 1024x1024 flash "
-                       "tiles)"),
+                       "tiles): an old claim, its record BENCH_r05.json "
+                       "removed with the installation it was taken on"),
         "peak_bf16_flops": PEAK_BF16_FLOPS,
         "flops_per_token": int(flops_per_token),
         "projected_tokens_per_sec_per_chip": round(projected, 1),
