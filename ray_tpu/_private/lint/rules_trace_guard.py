@@ -17,6 +17,14 @@ tracers) list appends on every decode step even when tracing is off — the
 exact overhead the ``test_gate_null_tracer_zero_allocations_on_decode_path``
 perf gate exists to prevent.
 
+The one sanctioned unguarded call is ``lane`` (``with self.trace.lane(
+"dispatch", "dispatch", horizon=H):``): the engine-lane helper of
+``engine_trace.py`` is a `jax.profiler.TraceAnnotation` on every tracer, the
+null one included, so that a profiler session sees the engine's seams with no
+knob turned. With no session the null tracer answers it with one shared no-op
+object (the perf gate runs over it). Guarding it would take the spans out of
+the profiler's trace whenever the ring is off, which is always in production.
+
 The rule matches calls of span methods (``add``/``instant``/``open``/
 ``close``/``mark``/``span_since_mark``/``now``/``finish``) on receivers that
 look like tracers (``tr``, ``tracer``, ``*.trace``, ``*_tracer`` ...) and
@@ -41,6 +49,10 @@ _SPAN_METHODS = {
     "now",
     "finish",
 }
+
+# Tracer methods that are meant to be called without a guard (see above).
+_UNGUARDED_BY_DESIGN = {"lane"}
+assert not _UNGUARDED_BY_DESIGN & _SPAN_METHODS
 
 _TRACER_NAMES = {"tr", "tracer", "etr", "trace"}
 

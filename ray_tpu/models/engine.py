@@ -94,6 +94,7 @@ from ray_tpu.models.generate import (_check_sampling_knobs,
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
 from ray_tpu.models.prefix_cache import PrefixCacheIndex, block_bytes
+from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention, spmd_mesh_scope
 from ray_tpu.ops.kv_quant import (KVQuantSpec, block_scale as
                                   _kv_block_scale, dequantize as
@@ -241,18 +242,21 @@ def _prefill_rows(params: Params, prompts: jax.Array, cache,
     lane at the dispatch site) thread to `_layer_body`'s per-row
     deltas; None (the default) adds no pytree leaves, so adapter-less
     engines trace the exact pre-LoRA program."""
-    row_cache = {"k": cache["k"][:, rows], "v": cache["v"][:, rows]}
+    with jax.named_scope(sn.KV_GATHER):
+        row_cache = {"k": cache["k"][:, rows], "v": cache["v"][:, rows]}
     logits, row_cache = forward_cached_rows(params, prompts, row_cache,
                                             starts, cfg,
                                             adapters=adapters,
                                             row_slot=row_slot)
-    cache = {
-        "k": cache["k"].at[:, rows].set(row_cache["k"]),
-        "v": cache["v"].at[:, rows].set(row_cache["v"]),
-    }
+    with jax.named_scope(sn.KV_WRITE):
+        cache = {
+            "k": cache["k"].at[:, rows].set(row_cache["k"]),
+            "v": cache["v"].at[:, rows].set(row_cache["v"]),
+        }
     n = prompts.shape[0]
-    last = logits[jnp.arange(n), last_idx]              # [N, vocab]
-    out_logits = last_logits.at[rows].set(last)
+    with jax.named_scope(sn.LM_HEAD):
+        last = logits[jnp.arange(n), last_idx]          # [N, vocab]
+        out_logits = last_logits.at[rows].set(last)
     if shardings is not None:
         # Donated buffers must leave with the sharding they arrived in.
         cache = jax.lax.with_sharding_constraint(cache, shardings.cache)
@@ -282,8 +286,9 @@ def _prefix_copy_in(cache, pool_k, pool_v, block_ids: jax.Array,
     slots the suffix prefill and decode overwrite before any mask ever
     admits them."""
     span = n_blocks * block_tokens
-    blk_k = pool_k[:, block_ids]          # [L, N, nb, T, KV, D]
-    blk_v = pool_v[:, block_ids]
+    with jax.named_scope(sn.KV_GATHER):
+        blk_k = pool_k[:, block_ids]      # [L, N, nb, T, KV, D]
+        blk_v = pool_v[:, block_ids]
     if shardings is not None:
         # Sharded gather: pool and cache carry the same KV-head
         # sharding, so pin the gathered blocks to it too — each chip
@@ -298,10 +303,13 @@ def _prefix_copy_in(cache, pool_k, pool_v, block_ids: jax.Array,
     L, N = blk_k.shape[:2]
     k = blk_k.reshape(L, N, span, *blk_k.shape[4:])
     v = blk_v.reshape(L, N, span, *blk_v.shape[4:])
-    out = {
-        "k": cache["k"].at[:, rows, :span].set(k.astype(cache["k"].dtype)),
-        "v": cache["v"].at[:, rows, :span].set(v.astype(cache["v"].dtype)),
-    }
+    with jax.named_scope(sn.KV_WRITE):
+        out = {
+            "k": cache["k"].at[:, rows, :span].set(
+                k.astype(cache["k"].dtype)),
+            "v": cache["v"].at[:, rows, :span].set(
+                v.astype(cache["v"].dtype)),
+        }
     if shardings is not None:
         out = jax.lax.with_sharding_constraint(out, shardings.cache)
     return out
@@ -326,16 +334,20 @@ def _prefix_copy_out(cache_k, cache_v, pool_k, pool_v, row,
     which the index never hands out."""
     span = n_blocks * block_tokens
     max_len = cache_k.shape[2]
-    slots = jnp.minimum(start_slot + jnp.arange(span), max_len - 1)
-    row_k = jnp.take(cache_k, row, axis=1)      # [L, max_len, KV, D]
-    row_v = jnp.take(cache_v, row, axis=1)
-    seg_k = jnp.take(row_k, slots, axis=1)      # [L, span, KV, D]
-    seg_v = jnp.take(row_v, slots, axis=1)
-    L = seg_k.shape[0]
-    seg_k = seg_k.reshape(L, n_blocks, block_tokens, *seg_k.shape[2:])
-    seg_v = seg_v.reshape(L, n_blocks, block_tokens, *seg_v.shape[2:])
-    pool_k = pool_k.at[:, block_ids].set(seg_k.astype(pool_k.dtype))
-    pool_v = pool_v.at[:, block_ids].set(seg_v.astype(pool_v.dtype))
+    with jax.named_scope(sn.KV_GATHER):
+        slots = jnp.minimum(start_slot + jnp.arange(span), max_len - 1)
+        row_k = jnp.take(cache_k, row, axis=1)      # [L, max_len, KV, D]
+        row_v = jnp.take(cache_v, row, axis=1)
+        seg_k = jnp.take(row_k, slots, axis=1)      # [L, span, KV, D]
+        seg_v = jnp.take(row_v, slots, axis=1)
+        L = seg_k.shape[0]
+        seg_k = seg_k.reshape(L, n_blocks, block_tokens,
+                              *seg_k.shape[2:])
+        seg_v = seg_v.reshape(L, n_blocks, block_tokens,
+                              *seg_v.shape[2:])
+    with jax.named_scope(sn.KV_WRITE):
+        pool_k = pool_k.at[:, block_ids].set(seg_k.astype(pool_k.dtype))
+        pool_v = pool_v.at[:, block_ids].set(seg_v.astype(pool_v.dtype))
     if shardings is not None:
         # Sharded scatter, the mirror of copy-in's gather: cache row
         # and pool share the KV-head sharding, so each chip writes its
@@ -385,7 +397,8 @@ def _decode_core(params: Params, toks: jax.Array, cache, row_len,
     excluding it meanwhile. Returns (next-token logits [B, vocab] f32,
     cache). Plain function so `_decode_multi`'s scan can inline it."""
     write_slots = row_len                                   # [B]
-    h = params["tok_embed"].astype(cfg.dtype)[toks[:, None]]
+    with jax.named_scope(sn.EMBED):
+        h = params["tok_embed"].astype(cfg.dtype)[toks[:, None]]
 
     def body(carry, xs):
         h = carry
@@ -404,9 +417,10 @@ def _decode_core(params: Params, toks: jax.Array, cache, row_len,
         xs = xs + (adapters,)
     h, (k_new, v_new) = jax.lax.scan(body, h, xs)
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", h,
-                        params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(sn.LM_HEAD):
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            params["lm_head"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
     return logits[:, 0], {"k": k_new, "v": v_new}
 
 
@@ -463,27 +477,29 @@ def _decode_multi(params: Params, cache, last_logits, row_len, active,
 
     def body(carry, _):
         cache, last_logits, row_len, active, budget, tok_idx = carry
-        tok = sample_rows(last_logits, row_keys, tok_idx,
-                          greedy=greedy, temperature=temperature,
-                          top_k=top_k, top_p=top_p)
-        if not greedy:
-            tok = jnp.where(
-                row_greedy,
-                jnp.argmax(last_logits, axis=-1).astype(tok.dtype),
-                tok)
-        emit = jnp.where(active, tok, -1)
-        live = active.astype(jnp.int32)
-        budget = budget - live
-        tok_idx = tok_idx + live
-        done_now = (budget <= 0) | (row_len + 1 >= max_len)
-        if eos_id is not None:
-            done_now = done_now | (tok == eos_id)
-        cont = active & ~done_now
+        with jax.named_scope(sn.SAMPLE):
+            tok = sample_rows(last_logits, row_keys, tok_idx,
+                              greedy=greedy, temperature=temperature,
+                              top_k=top_k, top_p=top_p)
+            if not greedy:
+                tok = jnp.where(
+                    row_greedy,
+                    jnp.argmax(last_logits, axis=-1).astype(tok.dtype),
+                    tok)
+            emit = jnp.where(active, tok, -1)
+            live = active.astype(jnp.int32)
+            budget = budget - live
+            tok_idx = tok_idx + live
+            done_now = (budget <= 0) | (row_len + 1 >= max_len)
+            if eos_id is not None:
+                done_now = done_now | (tok == eos_id)
+            cont = active & ~done_now
         logits, cache = _decode_core(params, tok, cache, row_len, cfg,
                                      adapters=adapters,
                                      row_slot=row_slot)
-        row_len = row_len + cont.astype(jnp.int32)
-        last_logits = jnp.where(cont[:, None], logits, last_logits)
+        with jax.named_scope(sn.SAMPLE):
+            row_len = row_len + cont.astype(jnp.int32)
+            last_logits = jnp.where(cont[:, None], logits, last_logits)
         if shardings is not None:
             # Pin the scan carry to the engine's layout every
             # iteration: the KV write stays a chip-local scatter (each
@@ -609,14 +625,15 @@ def _spec_round(params: Params, d_params: Params, cache, d_cache,
     W = window
     max_len = cache["k"].shape[2]
 
-    t_greedy = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-    if greedy:
-        t0 = t_greedy
-    else:
-        t_samp = sample_rows(last_logits, row_keys, tok_idx,
-                             greedy=False, temperature=temperature,
-                             top_k=top_k, top_p=top_p)
-        t0 = jnp.where(row_greedy, t_greedy, t_samp)
+    with jax.named_scope(sn.SAMPLE):
+        t_greedy = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        if greedy:
+            t0 = t_greedy
+        else:
+            t_samp = sample_rows(last_logits, row_keys, tok_idx,
+                                 greedy=False, temperature=temperature,
+                                 top_k=top_k, top_p=top_p)
+            t0 = jnp.where(row_greedy, t_greedy, t_samp)
 
     # Draft: catch-up consume, then propose W greedy tokens.
     pend = jnp.where(d_lag == 1, d_tok, t0)
@@ -644,11 +661,12 @@ def _spec_round(params: Params, d_params: Params, cache, d_cache,
                                           row_len, cfg)
     ver = jnp.argmax(v_logits, axis=-1).astype(jnp.int32)
 
-    (emits, last_logits, row_len, active, budget, tok_idx, d_lag,
-     d_tok) = _spec_accept(chunk, proposals, ver, v_logits,
-                           last_logits, row_len, active, budget,
-                           tok_idx, d_tok, row_greedy, w_row, W,
-                           eos_id, max_len)
+    with jax.named_scope(sn.SAMPLE):
+        (emits, last_logits, row_len, active, budget, tok_idx, d_lag,
+         d_tok) = _spec_accept(chunk, proposals, ver, v_logits,
+                               last_logits, row_len, active, budget,
+                               tok_idx, d_tok, row_greedy, w_row, W,
+                               eos_id, max_len)
     if shardings is not None:
         cache = jax.lax.with_sharding_constraint(cache,
                                                  shardings.cache)
@@ -716,13 +734,14 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
     requantization of an unmodified dequantized block is byte-stable
     (see ops/kv_quant.py), which is what keeps zero-copy shares safe
     under the whole-view write-back."""
-    blk_k = pool_k[:, bt]                  # [L, N, MB, T, KV, D]
-    blk_v = pool_v[:, bt]
-    if qspec is not None:
-        blk_k = _kv_dequantize(
-            blk_k, scale_k[:, bt][:, :, :, None, :, None])
-        blk_v = _kv_dequantize(
-            blk_v, scale_v[:, bt][:, :, :, None, :, None])
+    with jax.named_scope(sn.KV_GATHER):
+        blk_k = pool_k[:, bt]              # [L, N, MB, T, KV, D]
+        blk_v = pool_v[:, bt]
+        if qspec is not None:
+            blk_k = _kv_dequantize(
+                blk_k, scale_k[:, bt][:, :, :, None, :, None])
+            blk_v = _kv_dequantize(
+                blk_v, scale_v[:, bt][:, :, :, None, :, None])
     if shardings is not None:
         # Same chip-local discipline as _prefix_copy_in: the gathered
         # view carries the pool's KV-head sharding.
@@ -733,37 +752,40 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
         blk_k = jax.lax.with_sharding_constraint(blk_k, blk_spec)
         blk_v = jax.lax.with_sharding_constraint(blk_v, blk_spec)
     L, N, MB, T = blk_k.shape[:4]
-    row_cache = {
-        "k": blk_k.reshape(L, N, MB * T, *blk_k.shape[4:]),
-        "v": blk_v.reshape(L, N, MB * T, *blk_v.shape[4:]),
-    }
+    with jax.named_scope(sn.KV_GATHER):
+        row_cache = {
+            "k": blk_k.reshape(L, N, MB * T, *blk_k.shape[4:]),
+            "v": blk_v.reshape(L, N, MB * T, *blk_v.shape[4:]),
+        }
     logits, row_cache = forward_cached_rows(params, prompts, row_cache,
                                             starts, cfg,
                                             adapters=adapters,
                                             row_slot=row_slot)
-    k = row_cache["k"].reshape(L, N, MB, T, *blk_k.shape[4:])
-    v = row_cache["v"].reshape(L, N, MB, T, *blk_v.shape[4:])
-    if qspec is None:
-        pool_k = pool_k.at[:, bt].set(k.astype(pool_k.dtype))
-        pool_v = pool_v.at[:, bt].set(v.astype(pool_v.dtype))
-    else:
-        valid = starts + last_idx + 1                       # [N]
-        live = (jnp.arange(MB * T)[None, :] < valid[:, None]) \
-            .reshape(1, N, MB, T, 1, 1)
+    with jax.named_scope(sn.KV_WRITE):
+        k = row_cache["k"].reshape(L, N, MB, T, *blk_k.shape[4:])
+        v = row_cache["v"].reshape(L, N, MB, T, *blk_v.shape[4:])
+        if qspec is None:
+            pool_k = pool_k.at[:, bt].set(k.astype(pool_k.dtype))
+            pool_v = pool_v.at[:, bt].set(v.astype(pool_v.dtype))
+        else:
+            valid = starts + last_idx + 1                       # [N]
+            live = (jnp.arange(MB * T)[None, :] < valid[:, None]) \
+                .reshape(1, N, MB, T, 1, 1)
 
-        def _writeback(pool, scales, x):
-            x = jnp.where(live, x.astype(jnp.float32), 0.0)
-            amax = jnp.max(jnp.abs(x), axis=(3, 5))         # [L,N,MB,KV]
-            s = _kv_block_scale(amax, qspec)
-            pool = pool.at[:, bt].set(
-                _kv_quantize(x, s[:, :, :, None, :, None], qspec))
-            return pool, scales.at[:, bt].set(s)
+            def _writeback(pool, scales, x):
+                x = jnp.where(live, x.astype(jnp.float32), 0.0)
+                amax = jnp.max(jnp.abs(x), axis=(3, 5))     # [L,N,MB,KV]
+                s = _kv_block_scale(amax, qspec)
+                pool = pool.at[:, bt].set(
+                    _kv_quantize(x, s[:, :, :, None, :, None], qspec))
+                return pool, scales.at[:, bt].set(s)
 
-        pool_k, scale_k = _writeback(pool_k, scale_k, k)
-        pool_v, scale_v = _writeback(pool_v, scale_v, v)
+            pool_k, scale_k = _writeback(pool_k, scale_k, k)
+            pool_v, scale_v = _writeback(pool_v, scale_v, v)
     n = prompts.shape[0]
-    last = logits[jnp.arange(n), last_idx]              # [N, vocab]
-    out_logits = last_logits.at[rows].set(last)
+    with jax.named_scope(sn.LM_HEAD):
+        last = logits[jnp.arange(n), last_idx]          # [N, vocab]
+        out_logits = last_logits.at[rows].set(last)
     if shardings is not None:
         pool_k = jax.lax.with_sharding_constraint(pool_k, shardings.pool)
         pool_v = jax.lax.with_sharding_constraint(pool_v, shardings.pool)
@@ -801,8 +823,9 @@ def _decode_layer_rows_paged(h, layer, k_pages, v_pages, bt,
     bidx = jnp.arange(B)
     T = (k_pages[0] if qspec is not None else k_pages).shape[1]
     span = bt.shape[1] * T                 # == engine max_len
-    blk = bt[bidx, write_slots // T]       # [B] physical frontier block
-    off = write_slots % T
+    with jax.named_scope(sn.KV_WRITE):
+        blk = bt[bidx, write_slots // T]   # [B] physical frontier block
+        off = write_slots % T
 
     if qspec is None:
         def write_kv(k_pages, v_pages, k, v):
@@ -844,7 +867,8 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     quantized scale slabs ride the same scan as two extra xs entries).
     Plain function so `_decode_multi_paged`'s scan can inline it."""
     write_slots = row_len                                   # [B]
-    h = params["tok_embed"].astype(cfg.dtype)[toks[:, None]]
+    with jax.named_scope(sn.EMBED):
+        h = params["tok_embed"].astype(cfg.dtype)[toks[:, None]]
 
     def body(carry, xs):
         h = carry
@@ -882,9 +906,10 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     else:
         k_new, v_new, s_k, s_v = ys
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", h,
-                        params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(sn.LM_HEAD):
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            params["lm_head"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
     return logits[:, 0], k_new, v_new, s_k, s_v
 
 
@@ -920,28 +945,30 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
     def body(carry, _):
         pool_k, pool_v, scale_k, scale_v, last_logits, row_len, \
             active, budget, tok_idx = carry
-        tok = sample_rows(last_logits, row_keys, tok_idx,
-                          greedy=greedy, temperature=temperature,
-                          top_k=top_k, top_p=top_p)
-        if not greedy:
-            tok = jnp.where(
-                row_greedy,
-                jnp.argmax(last_logits, axis=-1).astype(tok.dtype),
-                tok)
-        emit = jnp.where(active, tok, -1)
-        live = active.astype(jnp.int32)
-        budget = budget - live
-        tok_idx = tok_idx + live
-        done_now = (budget <= 0) | (row_len + 1 >= max_len)
-        if eos_id is not None:
-            done_now = done_now | (tok == eos_id)
-        cont = active & ~done_now
+        with jax.named_scope(sn.SAMPLE):
+            tok = sample_rows(last_logits, row_keys, tok_idx,
+                              greedy=greedy, temperature=temperature,
+                              top_k=top_k, top_p=top_p)
+            if not greedy:
+                tok = jnp.where(
+                    row_greedy,
+                    jnp.argmax(last_logits, axis=-1).astype(tok.dtype),
+                    tok)
+            emit = jnp.where(active, tok, -1)
+            live = active.astype(jnp.int32)
+            budget = budget - live
+            tok_idx = tok_idx + live
+            done_now = (budget <= 0) | (row_len + 1 >= max_len)
+            if eos_id is not None:
+                done_now = done_now | (tok == eos_id)
+            cont = active & ~done_now
         logits, pool_k, pool_v, scale_k, scale_v = _decode_core_paged(
             params, tok, pool_k, pool_v, bt, row_len, cfg,
             adapters=adapters, row_slot=row_slot, scale_k=scale_k,
             scale_v=scale_v, qspec=qspec)
-        row_len = row_len + cont.astype(jnp.int32)
-        last_logits = jnp.where(cont[:, None], logits, last_logits)
+        with jax.named_scope(sn.SAMPLE):
+            row_len = row_len + cont.astype(jnp.int32)
+            last_logits = jnp.where(cont[:, None], logits, last_logits)
         if shardings is not None:
             pool_k = jax.lax.with_sharding_constraint(
                 pool_k, shardings.pool)
@@ -988,8 +1015,9 @@ def _spec_layer_rows_paged(h, layer, k_pages, v_pages, bt, slots,
         T = k_pages[0].shape[1]
     span = bt.shape[1] * T
     bidx = jnp.arange(slots.shape[0])[:, None]
-    blk = bt[bidx, slots // T]             # [B, S]
-    off = slots % T
+    with jax.named_scope(sn.KV_WRITE):
+        blk = bt[bidx, slots // T]         # [B, S]
+        off = slots % T
 
     if qspec is None:
         def write_kv(k_pages, v_pages, k, v):
@@ -1027,7 +1055,8 @@ def _spec_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     this one shape family."""
     S = toks.shape[1]
     slots = starts[:, None] + jnp.arange(S)[None, :]
-    h = params["tok_embed"].astype(cfg.dtype)[toks]
+    with jax.named_scope(sn.EMBED):
+        h = params["tok_embed"].astype(cfg.dtype)[toks]
 
     def body(carry, xs):
         h = carry
@@ -1052,9 +1081,10 @@ def _spec_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     else:
         k_new, v_new, s_k, s_v = ys
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", h,
-                        params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(sn.LM_HEAD):
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            params["lm_head"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
     return logits, k_new, v_new, s_k, s_v
 
 
@@ -1090,14 +1120,15 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
     W = window
     max_len = bt.shape[1] * pool_k.shape[2]
 
-    t_greedy = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-    if greedy:
-        t0 = t_greedy
-    else:
-        t_samp = sample_rows(last_logits, row_keys, tok_idx,
-                             greedy=False, temperature=temperature,
-                             top_k=top_k, top_p=top_p)
-        t0 = jnp.where(row_greedy, t_greedy, t_samp)
+    with jax.named_scope(sn.SAMPLE):
+        t_greedy = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        if greedy:
+            t0 = t_greedy
+        else:
+            t_samp = sample_rows(last_logits, row_keys, tok_idx,
+                                 greedy=False, temperature=temperature,
+                                 top_k=top_k, top_p=top_p)
+            t0 = jnp.where(row_greedy, t_greedy, t_samp)
 
     pend = jnp.where(d_lag == 1, d_tok, t0)
     chunk2 = jnp.stack([pend, t0], axis=1)
@@ -1128,11 +1159,12 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
         scale_k=scale_k, scale_v=scale_v, qspec=qspec)
     ver = jnp.argmax(v_logits, axis=-1).astype(jnp.int32)
 
-    (emits, last_logits, row_len, active, budget, tok_idx, d_lag,
-     d_tok) = _spec_accept(chunk, proposals, ver, v_logits,
-                           last_logits, row_len, active, budget,
-                           tok_idx, d_tok, row_greedy, w_row, W,
-                           eos_id, max_len)
+    with jax.named_scope(sn.SAMPLE):
+        (emits, last_logits, row_len, active, budget, tok_idx, d_lag,
+         d_tok) = _spec_accept(chunk, proposals, ver, v_logits,
+                               last_logits, row_len, active, budget,
+                               tok_idx, d_tok, row_greedy, w_row, W,
+                               eos_id, max_len)
     if shardings is not None:
         pool_k = jax.lax.with_sharding_constraint(pool_k,
                                                   shardings.pool)
@@ -1175,11 +1207,12 @@ def _cow_blocks(pool_k, pool_v, src: jax.Array, dst: jax.Array,
     are power-of-two padded with (0, 0): null -> null, harmless. A
     quantized pool copies its per-block scales alongside — the copy is
     byte-exact, never a requantization."""
-    pool_k = pool_k.at[:, dst].set(pool_k[:, src])
-    pool_v = pool_v.at[:, dst].set(pool_v[:, src])
-    if scale_k is not None:
-        scale_k = scale_k.at[:, dst].set(scale_k[:, src])
-        scale_v = scale_v.at[:, dst].set(scale_v[:, src])
+    with jax.named_scope(sn.KV_WRITE):
+        pool_k = pool_k.at[:, dst].set(pool_k[:, src])
+        pool_v = pool_v.at[:, dst].set(pool_v[:, src])
+        if scale_k is not None:
+            scale_k = scale_k.at[:, dst].set(scale_k[:, src])
+            scale_v = scale_v.at[:, dst].set(scale_v[:, src])
     if shardings is not None:
         pool_k = jax.lax.with_sharding_constraint(pool_k, shardings.pool)
         pool_v = jax.lax.with_sharding_constraint(pool_v, shardings.pool)
@@ -1204,10 +1237,12 @@ def _swap_out_gather(pool_k, pool_v, block_ids: jax.Array,
     the QUANTIZED bytes plus the [L, n, KV] scales — roughly half the
     bf16 swap traffic — and the round trip is byte-exact by
     construction (no dequantization happens on either leg)."""
-    if scale_k is None:
-        return (pool_k[:, block_ids], pool_v[:, block_ids], None, None)
-    return (pool_k[:, block_ids], pool_v[:, block_ids],
-            scale_k[:, block_ids], scale_v[:, block_ids])
+    with jax.named_scope(sn.KV_GATHER):
+        if scale_k is None:
+            return (pool_k[:, block_ids], pool_v[:, block_ids], None,
+                    None)
+        return (pool_k[:, block_ids], pool_v[:, block_ids],
+                scale_k[:, block_ids], scale_v[:, block_ids])
 
 
 @functools.partial(jax.jit, static_argnames=("shardings",),
@@ -1223,13 +1258,14 @@ def _swap_in_scatter(pool_k, pool_v, host_k, host_v,
     new physical block ids need not match the old ones: the block
     table indirection is what makes the bytes land logically where
     they were. Quantized bytes + scales scatter back verbatim."""
-    pool_k = pool_k.at[:, block_ids].set(host_k.astype(pool_k.dtype))
-    pool_v = pool_v.at[:, block_ids].set(host_v.astype(pool_v.dtype))
-    if scale_k is not None:
-        scale_k = scale_k.at[:, block_ids].set(
-            host_sk.astype(scale_k.dtype))
-        scale_v = scale_v.at[:, block_ids].set(
-            host_sv.astype(scale_v.dtype))
+    with jax.named_scope(sn.KV_WRITE):
+        pool_k = pool_k.at[:, block_ids].set(host_k.astype(pool_k.dtype))
+        pool_v = pool_v.at[:, block_ids].set(host_v.astype(pool_v.dtype))
+        if scale_k is not None:
+            scale_k = scale_k.at[:, block_ids].set(
+                host_sk.astype(scale_k.dtype))
+            scale_v = scale_v.at[:, block_ids].set(
+                host_sv.astype(scale_v.dtype))
     if shardings is not None:
         pool_k = jax.lax.with_sharding_constraint(pool_k, shardings.pool)
         pool_v = jax.lax.with_sharding_constraint(pool_v, shardings.pool)
@@ -1721,6 +1757,8 @@ class DecodeEngine:
         self.decode_dispatches = 0     # fused decode program launches
         self.prefill_dispatches = 0    # batched prefill launches
         self.host_syncs = 0            # device->host transfers
+        self.device_waits = 0          # blocking pulls (`_device_wait`)
+        self.device_wait_s = 0.0       # engine-clock seconds inside them
         self.host_transfer_bytes = 0   # bytes those transfers moved
         self.tokens_out = 0            # tokens emitted, all requests
         # Prefill/prefix-reuse accounting (same plain-int discipline):
@@ -2298,75 +2336,77 @@ class DecodeEngine:
         if self._ring and (self.scheduler.admissions_pending()
                            or self._row_prefill):
             self._flush_pipeline(emitted)
-        budget = self.max_prefills_per_step or self.B
-        admissions: List[Tuple[int, _Request]] = []
-        begin = getattr(self.scheduler, "begin_admission_round", None)
-        if begin is not None:
-            begin()
-        # Commit any landed adapter prefetches before gating: the
-        # commit donates the stacks, so it must never race an
-        # in-flight dispatch — with the ring empty (flushed above
-        # whenever admissions were pending) nothing on device still
-        # reads the old stack buffers.
-        if self.adapter_pool is not None and not self._ring:
-            self.adapter_pool.drain_prefetches()
-        deferred = False
-        for row in range(self.B):
-            if budget <= 0 or deferred:
-                break
-            if self.row_req[row] is not None:
-                continue
-            req = None
-            while len(self.scheduler):
-                cand = self.scheduler.pop()
-                if cand is None:
-                    deferred = True  # prefix policy deferred the queue
+        with self.trace.lane("admit", "admit") as admit:
+            budget = self.max_prefills_per_step or self.B
+            admissions: List[Tuple[int, _Request]] = []
+            begin = getattr(self.scheduler, "begin_admission_round", None)
+            if begin is not None:
+                begin()
+            # Commit any landed adapter prefetches before gating: the
+            # commit donates the stacks, so it must never race an
+            # in-flight dispatch — with the ring empty (flushed above
+            # whenever admissions were pending) nothing on device still
+            # reads the old stack buffers.
+            if self.adapter_pool is not None and not self._ring:
+                self.adapter_pool.drain_prefetches()
+            deferred = False
+            for row in range(self.B):
+                if budget <= 0 or deferred:
                     break
-                if cand.deadline is not None and \
-                        self._clock() >= cand.deadline and \
-                        not cand.resume:
-                    # Expired mid-queue: shed at the admission gate —
-                    # the last moment before prefill compute would be
-                    # committed to a request nobody is waiting for.
-                    # A PREEMPTED request is exempt: it was already
-                    # admitted once, and admitted requests run to
-                    # completion.
-                    self._shed(cand)
+                if self.row_req[row] is not None:
                     continue
-                if self.paged and not self._fits_now(cand):
-                    # No room even counting evictable cold prefix
-                    # blocks: capacity, not order, is the constraint —
-                    # stop admitting this step and retry when decode
-                    # retirements free blocks.
-                    self._requeue_front(cand)
-                    deferred = True
-                    break
-                if cand.adapter_id is not None:
-                    # Adapter residency gate: acquire the slot HERE
-                    # (refcount taken) so nothing admitted later this
-                    # round can evict it; a cold adapter starts its
-                    # async prefetch and the request waits at the
-                    # queue front instead of stalling the step.
-                    slot = self.adapter_pool.alloc(cand.adapter_id)
-                    if slot is None:
-                        self.adapter_pool.prefetch(cand.adapter_id)
+                req = None
+                while len(self.scheduler):
+                    cand = self.scheduler.pop()
+                    if cand is None:
+                        deferred = True  # prefix policy deferred the queue
+                        break
+                    if cand.deadline is not None and \
+                            self._clock() >= cand.deadline and \
+                            not cand.resume:
+                        # Expired mid-queue: shed at the admission gate —
+                        # the last moment before prefill compute would be
+                        # committed to a request nobody is waiting for.
+                        # A PREEMPTED request is exempt: it was already
+                        # admitted once, and admitted requests run to
+                        # completion.
+                        self._shed(cand)
+                        continue
+                    if self.paged and not self._fits_now(cand):
+                        # No room even counting evictable cold prefix
+                        # blocks: capacity, not order, is the constraint —
+                        # stop admitting this step and retry when decode
+                        # retirements free blocks.
                         self._requeue_front(cand)
-                        self.adapter_deferrals += 1
-                        self.metrics.on_adapter_defer()
                         deferred = True
                         break
-                    self._pending_slots[cand.req_id] = slot
-                req = cand
-                break
-            if req is None:
-                continue       # queue drained to empty (or deferred)
-            admissions.append((row, req))
-            budget -= 1
-        if deferred and self.trace.enabled:
-            self.trace.instant("admission_defer", lane="events",
-                               args={"queued": len(self.scheduler)})
-        if admissions:
-            self._admit_rows(admissions)
+                    if cand.adapter_id is not None:
+                        # Adapter residency gate: acquire the slot HERE
+                        # (refcount taken) so nothing admitted later this
+                        # round can evict it; a cold adapter starts its
+                        # async prefetch and the request waits at the
+                        # queue front instead of stalling the step.
+                        slot = self.adapter_pool.alloc(cand.adapter_id)
+                        if slot is None:
+                            self.adapter_pool.prefetch(cand.adapter_id)
+                            self._requeue_front(cand)
+                            self.adapter_deferrals += 1
+                            self.metrics.on_adapter_defer()
+                            deferred = True
+                            break
+                        self._pending_slots[cand.req_id] = slot
+                    req = cand
+                    break
+                if req is None:
+                    continue       # queue drained to empty (or deferred)
+                admissions.append((row, req))
+                budget -= 1
+            if deferred and self.trace.enabled:
+                self.trace.instant("admission_defer", lane="events",
+                                   args={"queued": len(self.scheduler)})
+            admit.note(admitted=len(admissions))
+            if admissions:
+                self._admit_rows(admissions)
         self._advance_prefills()
 
         live = [b for b in range(self.B) if self.row_req[b] is not None]
@@ -2517,71 +2557,67 @@ class DecodeEngine:
         (including the draft-lag lane) stored for run-ahead chaining,
         ONE host pull later at drain. The ring entry's H is W+1 (the
         emit block height and the pessimistic in-flight token count)."""
-        tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
-        if chain is None:
-            active = np.array([self.row_req[b] is not None
-                               and b not in self._row_prefill
-                               for b in range(self.B)])
-            args = (jnp.asarray(self.row_len), jnp.asarray(active),
-                    jnp.asarray(self.row_budget),
-                    jnp.asarray(self._tok_idx),
-                    jnp.asarray(self._d_lag),
-                    jnp.asarray(self._d_tok))
-        else:
-            args = chain
-        rg = jnp.asarray(self._row_greedy)
-        all_greedy = bool(self._row_greedy.all())
-        wr = jnp.asarray(w_row)
-        if self.paged:
-            bt_dev = jnp.asarray(self._bt)
-            btd_dev = jnp.asarray(self._bt_d)
-            if self._shardings is not None:
-                bt_dev = jax.device_put(bt_dev,
-                                        self._shardings.replicated)
-                btd_dev = jax.device_put(btd_dev,
-                                         self._shardings.replicated)
-            with spmd_mesh_scope(self.mesh):
-                (toks, self._pool_k, self._pool_v, self._pool_dk,
-                 self._pool_dv, self._scale_k, self._scale_v,
-                 self._scale_dk, self._scale_dv, self._last_logits, rl,
-                 ac, bu, ti, dl, dt) = _spec_round_paged(
-                    self.params, self.draft_params, self._pool_k,
-                    self._pool_v, self._pool_dk, self._pool_dv, bt_dev,
-                    btd_dev, self._last_logits, *args,
-                    jnp.asarray(self._row_keys), rg, wr,
-                    self.temperature, self.cfg, self.draft_cfg, W,
-                    all_greedy, self.top_k, self.top_p, self.eos_id,
-                    shardings=self._shardings,
-                    scale_k=self._scale_k, scale_v=self._scale_v,
-                    scale_dk=self._scale_dk, scale_dv=self._scale_dv,
-                    qspec=self.kv_quant_spec)
-        else:
-            (toks, self.cache, self._d_cache, self._last_logits, rl,
-             ac, bu, ti, dl, dt) = _spec_round(
-                self.params, self.draft_params, self.cache,
-                self._d_cache, self._last_logits, *args,
-                jnp.asarray(self._row_keys), rg, wr, self.temperature,
-                self.cfg, self.draft_cfg, W, all_greedy, self.top_k,
-                self.top_p, self.eos_id, shardings=self._shardings)
-        _host_async(toks)
-        self._ring.append(_InflightStep(
-            toks, W + 1, list(rows), run_ahead=chain is not None,
-            chain=(rl, ac, bu, ti, dl, dt), spec=True, w_max=W,
-            w_row=np.array(w_row, np.int32)))
-        self.decode_dispatches += 1
-        self.spec_dispatches += 1
-        self.metrics.on_dispatch(W + 1, host_syncs=0)
-        if tr.enabled:
-            # The draft scan and verify pass live inside ONE fused
-            # program, so the dispatch seam carries the spec_draft
-            # span (proposal width known here) and the drain seam
-            # carries spec_verify (acceptance known there).
-            tr.add("spec_draft", t0, tr.now() - t0, lane="dispatch",
-                   args={"window": W,
-                         "proposed": int(w_row[rows].sum()),
-                         "rows": len(rows),
-                         "run_ahead": chain is not None})
+        # The draft scan and verify pass live inside ONE fused program,
+        # so the dispatch seam carries the spec_draft span (proposal
+        # width known here) and the drain seam carries spec_verify
+        # (acceptance known there).
+        with self.trace.lane("spec_draft", "dispatch", window=W,
+                             proposed=int(w_row[rows].sum()),
+                             rows=len(rows),
+                             run_ahead=chain is not None):
+            if chain is None:
+                active = np.array([self.row_req[b] is not None
+                                   and b not in self._row_prefill
+                                   for b in range(self.B)])
+                args = (jnp.asarray(self.row_len), jnp.asarray(active),
+                        jnp.asarray(self.row_budget),
+                        jnp.asarray(self._tok_idx),
+                        jnp.asarray(self._d_lag),
+                        jnp.asarray(self._d_tok))
+            else:
+                args = chain
+            rg = jnp.asarray(self._row_greedy)
+            all_greedy = bool(self._row_greedy.all())
+            wr = jnp.asarray(w_row)
+            if self.paged:
+                bt_dev = jnp.asarray(self._bt)
+                btd_dev = jnp.asarray(self._bt_d)
+                if self._shardings is not None:
+                    bt_dev = jax.device_put(bt_dev,
+                                            self._shardings.replicated)
+                    btd_dev = jax.device_put(btd_dev,
+                                             self._shardings.replicated)
+                with spmd_mesh_scope(self.mesh):
+                    (toks, self._pool_k, self._pool_v, self._pool_dk,
+                     self._pool_dv, self._scale_k, self._scale_v,
+                     self._scale_dk, self._scale_dv, self._last_logits, rl,
+                     ac, bu, ti, dl, dt) = _spec_round_paged(
+                        self.params, self.draft_params, self._pool_k,
+                        self._pool_v, self._pool_dk, self._pool_dv, bt_dev,
+                        btd_dev, self._last_logits, *args,
+                        jnp.asarray(self._row_keys), rg, wr,
+                        self.temperature, self.cfg, self.draft_cfg, W,
+                        all_greedy, self.top_k, self.top_p, self.eos_id,
+                        shardings=self._shardings,
+                        scale_k=self._scale_k, scale_v=self._scale_v,
+                        scale_dk=self._scale_dk, scale_dv=self._scale_dv,
+                        qspec=self.kv_quant_spec)
+            else:
+                (toks, self.cache, self._d_cache, self._last_logits, rl,
+                 ac, bu, ti, dl, dt) = _spec_round(
+                    self.params, self.draft_params, self.cache,
+                    self._d_cache, self._last_logits, *args,
+                    jnp.asarray(self._row_keys), rg, wr, self.temperature,
+                    self.cfg, self.draft_cfg, W, all_greedy, self.top_k,
+                    self.top_p, self.eos_id, shardings=self._shardings)
+            _host_async(toks)
+            self._ring.append(_InflightStep(
+                toks, W + 1, list(rows), run_ahead=chain is not None,
+                chain=(rl, ac, bu, ti, dl, dt), spec=True, w_max=W,
+                w_row=np.array(w_row, np.int32)))
+            self.decode_dispatches += 1
+            self.spec_dispatches += 1
+            self.metrics.on_dispatch(W + 1, host_syncs=0)
 
     def _dispatch_decode(self, H: int, rows: List[int],
                          chain: Optional[tuple]) -> None:
@@ -2591,73 +2627,70 @@ class DecodeEngine:
         row state (run-ahead). The token block's `copy_to_host_async`
         is issued immediately, so the transfer overlaps the device
         computing the block — and any queued successors."""
-        tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
-        if chain is None:
-            active = np.array([self.row_req[b] is not None
-                               and b not in self._row_prefill
-                               for b in range(self.B)])
-            args = (jnp.asarray(self.row_len), jnp.asarray(active),
-                    jnp.asarray(self.row_budget),
-                    jnp.asarray(self._tok_idx))
-        else:
-            args = chain
-        # The static greedy flag is the all-greedy fast path: without
-        # per-request overrides it equals the engine-wide mode exactly
-        # (the lane resets to the default at retirement), so existing
-        # engines compile the same two programs they always did.
-        rg = jnp.asarray(self._row_greedy)
-        all_greedy = bool(self._row_greedy.all())
-        # Multi-LoRA lane: the pool stacks + the [B] slot lane ride
-        # every dispatch (slot 0 = zero null adapter, so base-only
-        # rows are untouched); adapter_pool=None passes None/None —
-        # no extra pytree leaves, the exact pre-LoRA programs.
-        if self.adapter_pool is not None:
-            adapters = self.adapter_pool.stacks
-            row_slot = jnp.asarray(self._row_slot)
-        else:
-            adapters = row_slot = None
-        if self.paged:
-            # Snapshot the block table at dispatch: jnp.asarray copies
-            # it to device, so host-side growth between chained
-            # dispatches only reaches FUTURE dispatches (in-flight
-            # steps never read past the coverage they were reserved).
-            bt_dev = jnp.asarray(self._bt)
-            if self._shardings is not None:
-                bt_dev = jax.device_put(bt_dev,
-                                        self._shardings.replicated)
-            # the scope only matters while the program traces: under
-            # a tp mesh paged_attention must not pick a Mosaic kernel
-            with spmd_mesh_scope(self.mesh):
-                (toks, self._pool_k, self._pool_v, self._scale_k,
-                 self._scale_v, self._last_logits,
-                 rl, ac, bu, ti) = _decode_multi_paged(
-                    self.params, self._pool_k, self._pool_v, bt_dev,
-                    self._last_logits, *args,
-                    jnp.asarray(self._row_keys), rg, self.temperature,
-                    self.cfg, H, all_greedy, self.top_k, self.top_p,
-                    self.eos_id, shardings=self._shardings,
-                    adapters=adapters, row_slot=row_slot,
-                    scale_k=self._scale_k, scale_v=self._scale_v,
-                    qspec=self.kv_quant_spec)
-        else:
-            toks, self.cache, self._last_logits, rl, ac, bu, ti = \
-                _decode_multi(
-                    self.params, self.cache, self._last_logits, *args,
-                    jnp.asarray(self._row_keys), rg, self.temperature,
-                    self.cfg, H, all_greedy, self.top_k, self.top_p,
-                    self.eos_id, shardings=self._shardings,
-                    adapters=adapters, row_slot=row_slot)
-        _host_async(toks)
-        self._ring.append(_InflightStep(toks, H, list(rows),
-                                        run_ahead=chain is not None,
-                                        chain=(rl, ac, bu, ti)))
-        self.decode_dispatches += 1
-        self.metrics.on_dispatch(H, host_syncs=0)
-        if tr.enabled:
-            tr.add("dispatch", t0, tr.now() - t0, lane="dispatch",
-                   args={"horizon": H, "rows": len(rows),
-                         "run_ahead": chain is not None})
+        with self.trace.lane("dispatch", "dispatch", horizon=H,
+                             rows=len(rows),
+                             run_ahead=chain is not None):
+            if chain is None:
+                active = np.array([self.row_req[b] is not None
+                                   and b not in self._row_prefill
+                                   for b in range(self.B)])
+                args = (jnp.asarray(self.row_len), jnp.asarray(active),
+                        jnp.asarray(self.row_budget),
+                        jnp.asarray(self._tok_idx))
+            else:
+                args = chain
+            # The static greedy flag is the all-greedy fast path: without
+            # per-request overrides it equals the engine-wide mode exactly
+            # (the lane resets to the default at retirement), so existing
+            # engines compile the same two programs they always did.
+            rg = jnp.asarray(self._row_greedy)
+            all_greedy = bool(self._row_greedy.all())
+            # Multi-LoRA lane: the pool stacks + the [B] slot lane ride
+            # every dispatch (slot 0 = zero null adapter, so base-only
+            # rows are untouched); adapter_pool=None passes None/None —
+            # no extra pytree leaves, the exact pre-LoRA programs.
+            if self.adapter_pool is not None:
+                adapters = self.adapter_pool.stacks
+                row_slot = jnp.asarray(self._row_slot)
+            else:
+                adapters = row_slot = None
+            if self.paged:
+                # Snapshot the block table at dispatch: jnp.asarray copies
+                # it to device, so host-side growth between chained
+                # dispatches only reaches FUTURE dispatches (in-flight
+                # steps never read past the coverage they were reserved).
+                bt_dev = jnp.asarray(self._bt)
+                if self._shardings is not None:
+                    bt_dev = jax.device_put(bt_dev,
+                                            self._shardings.replicated)
+                # the scope only matters while the program traces: under
+                # a tp mesh paged_attention must not pick a Mosaic kernel
+                with spmd_mesh_scope(self.mesh):
+                    (toks, self._pool_k, self._pool_v, self._scale_k,
+                     self._scale_v, self._last_logits,
+                     rl, ac, bu, ti) = _decode_multi_paged(
+                        self.params, self._pool_k, self._pool_v, bt_dev,
+                        self._last_logits, *args,
+                        jnp.asarray(self._row_keys), rg, self.temperature,
+                        self.cfg, H, all_greedy, self.top_k, self.top_p,
+                        self.eos_id, shardings=self._shardings,
+                        adapters=adapters, row_slot=row_slot,
+                        scale_k=self._scale_k, scale_v=self._scale_v,
+                        qspec=self.kv_quant_spec)
+            else:
+                toks, self.cache, self._last_logits, rl, ac, bu, ti = \
+                    _decode_multi(
+                        self.params, self.cache, self._last_logits, *args,
+                        jnp.asarray(self._row_keys), rg, self.temperature,
+                        self.cfg, H, all_greedy, self.top_k, self.top_p,
+                        self.eos_id, shardings=self._shardings,
+                        adapters=adapters, row_slot=row_slot)
+            _host_async(toks)
+            self._ring.append(_InflightStep(toks, H, list(rows),
+                                            run_ahead=chain is not None,
+                                            chain=(rl, ac, bu, ti)))
+            self.decode_dispatches += 1
+            self.metrics.on_dispatch(H, host_syncs=0)
 
     def _top_up_pipeline(self, rows: List[int],
                          horizon: Optional[int]) -> None:
@@ -2718,38 +2751,51 @@ class DecodeEngine:
         next step(s) while this replay runs — the overlap that hides
         the host bookkeeping."""
         tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
         entry = self._ring.popleft()
         depth = len(self._ring) + 1    # steps in flight at this drain
         self._pl_depth_sum += depth
         self._pl_depth_n += 1
-        block = _device_get(entry.toks)
-        self.host_syncs += 1
-        nbytes = int(getattr(block, "nbytes", block.size * 4))
-        self.host_transfer_bytes += nbytes
-        self.metrics.on_host_sync(nbytes=nbytes)
-        sp_rounds, sp_prop, sp_acc = self._emit_block(
-            block, entry, emitted)
-        self.metrics.on_pipeline_drain(depth, len(self._ring))
-        if entry.spec and sp_rounds:
-            self.metrics.on_spec_round(sp_rounds, sp_prop, sp_acc)
-            if self.spec_metrics is not None:
-                from ray_tpu.models.speculative import SpecStats
-                self.spec_metrics.observe(SpecStats(
-                    rounds=sp_rounds, proposed=sp_prop,
-                    accepted=sp_acc))
-        if entry.spec and tr.enabled:
-            # The draft scan and verify pass live inside ONE fused
-            # program, so acceptance is only knowable here at drain:
-            # spec_draft marks the dispatch seam, spec_verify the
-            # drain seam where the accept counts land.
-            tr.add("spec_verify", t0, tr.now() - t0, lane="drain",
-                   args={"window": entry.w_max, "rounds": sp_rounds,
-                         "proposed": sp_prop, "accepted": sp_acc})
-        if tr.enabled:
-            tr.add("host_drain", t0, tr.now() - t0, lane="drain",
-                   args={"horizon": entry.H, "depth": depth,
-                         "bytes": nbytes})
+        with tr.lane("host_drain", "drain", horizon=entry.H,
+                     depth=depth) as drain:
+            t0 = tr.now() if tr.enabled else 0.0
+            block = self._device_wait(entry.toks)
+            self.host_syncs += 1
+            nbytes = int(getattr(block, "nbytes", block.size * 4))
+            drain.note(bytes=nbytes)
+            self.host_transfer_bytes += nbytes
+            self.metrics.on_host_sync(nbytes=nbytes)
+            with tr.lane("emit", "drain"):
+                sp_rounds, sp_prop, sp_acc = self._emit_block(
+                    block, entry, emitted)
+            self.metrics.on_pipeline_drain(depth, len(self._ring))
+            if entry.spec and sp_rounds:
+                self.metrics.on_spec_round(sp_rounds, sp_prop, sp_acc)
+                if self.spec_metrics is not None:
+                    from ray_tpu.models.speculative import SpecStats
+                    self.spec_metrics.observe(SpecStats(
+                        rounds=sp_rounds, proposed=sp_prop,
+                        accepted=sp_acc))
+            if entry.spec and tr.enabled:
+                # The draft scan and verify pass live inside ONE fused
+                # program, so acceptance is only knowable here at
+                # drain: spec_draft marks the dispatch seam,
+                # spec_verify the drain seam where the accept counts
+                # land.
+                tr.add("spec_verify", t0, tr.now() - t0, lane="drain",
+                       args={"window": entry.w_max, "rounds": sp_rounds,
+                             "proposed": sp_prop, "accepted": sp_acc})
+
+    def _device_wait(self, x) -> np.ndarray:
+        """`_device_get` from inside the serving loop, with the time the
+        host stood blocked in it kept (`device_wait_s`, `device_waits`)
+        and shown as its own span: what is left of a step's wall time
+        is the host's own work."""
+        with self.trace.lane("device_wait", "drain"):
+            t = self._clock()
+            out = _device_get(x)
+            self.device_wait_s += self._clock() - t
+        self.device_waits += 1
+        return out
 
     def _flush_pipeline(self, emitted: Dict[int, List[int]]) -> None:
         """Drain EVERY in-flight step. Called before any admission /
@@ -2759,14 +2805,10 @@ class DecodeEngine:
             return
         self.pipeline_flushes += 1
         self.metrics.on_pipeline_flush()
-        tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
-        steps = len(self._ring)
-        while self._ring:
-            self._drain_one(emitted)
-        if tr.enabled:
-            tr.add("pipeline_flush", t0, tr.now() - t0, lane="drain",
-                   args={"steps": steps})
+        with self.trace.lane("pipeline_flush", "drain",
+                             steps=len(self._ring)):
+            while self._ring:
+                self._drain_one(emitted)
 
     def stats(self) -> Dict[str, float]:
         """Flat numeric telemetry snapshot (EngineMetrics.stats) plus
@@ -2800,6 +2842,10 @@ class DecodeEngine:
         out["host_syncs"] = float(self.host_syncs)
         out["host_syncs_per_token"] = _ratio(self.host_syncs,
                                              self.tokens_out)
+        # Seconds the host stood blocked on the device: a step's wall
+        # time less this is the host's own work.
+        out["device_waits"] = float(self.device_waits)
+        out["device_wait_s"] = float(self.device_wait_s)
         # Tensor-parallel plane: tp_degree is 1 for an unsharded
         # engine; transfer bytes count the [H, B] token blocks pulled
         # at drain — the replicated choke point, so bytes/token must
@@ -3392,43 +3438,40 @@ class DecodeEngine:
         for Cb in sorted(groups):
             grp = groups[Cb]
             n = len(grp)
-            t0 = self.trace.now() if self.trace.enabled else 0.0
-            n_pad = _pow2(n)
-            prompts = np.zeros((n_pad, Cb), np.int32)
-            rows = np.zeros((n_pad,), np.int32)
-            starts = np.zeros((n_pad,), np.int32)
-            last_idx = np.zeros((n_pad,), np.int32)
-            for i, (row, toks) in enumerate(grp):
-                prompts[i, :len(toks)] = toks
-                rows[i] = row
-                last_idx[i] = len(toks) - 1
-            prompts[n:] = prompts[n - 1]    # filler: repeat last row —
-            rows[n:] = rows[n - 1]          # duplicate scatters write
-            last_idx[n:] = last_idx[n - 1]  # identical values
-            if self.paged:
-                bt_grp = self._bt_d[rows]
-                (self._pool_dk, self._pool_dv, self._scale_dk,
-                 self._scale_dv,
-                 self._d_last_logits) = _prefill_rows_paged(
-                    self.draft_params, jnp.asarray(prompts),
-                    self._pool_dk, self._pool_dv, self._d_last_logits,
-                    jnp.asarray(bt_grp), jnp.asarray(rows),
-                    jnp.asarray(starts), jnp.asarray(last_idx),
-                    self.draft_cfg, shardings=self._d_shardings,
-                    scale_k=self._scale_dk, scale_v=self._scale_dv,
-                    qspec=self.kv_quant_spec)
-            else:
-                self._d_cache, self._d_last_logits = _prefill_rows(
-                    self.draft_params, jnp.asarray(prompts),
-                    self._d_cache, self._d_last_logits,
-                    jnp.asarray(rows), jnp.asarray(starts),
-                    jnp.asarray(last_idx), self.draft_cfg,
-                    shardings=self._d_shardings)
-            self.spec_prefill_dispatches += 1
-            if self.trace.enabled:
-                self.trace.add(
-                    "spec_draft_prefill", t0, self.trace.now() - t0,
-                    lane="dispatch", args={"bucket": Cb, "rows": n})
+            with self.trace.lane("spec_draft_prefill", "dispatch",
+                                 bucket=Cb, rows=n):
+                n_pad = _pow2(n)
+                prompts = np.zeros((n_pad, Cb), np.int32)
+                rows = np.zeros((n_pad,), np.int32)
+                starts = np.zeros((n_pad,), np.int32)
+                last_idx = np.zeros((n_pad,), np.int32)
+                for i, (row, toks) in enumerate(grp):
+                    prompts[i, :len(toks)] = toks
+                    rows[i] = row
+                    last_idx[i] = len(toks) - 1
+                prompts[n:] = prompts[n - 1]    # filler: repeat last row —
+                rows[n:] = rows[n - 1]          # duplicate scatters write
+                last_idx[n:] = last_idx[n - 1]  # identical values
+                if self.paged:
+                    bt_grp = self._bt_d[rows]
+                    (self._pool_dk, self._pool_dv, self._scale_dk,
+                     self._scale_dv,
+                     self._d_last_logits) = _prefill_rows_paged(
+                        self.draft_params, jnp.asarray(prompts),
+                        self._pool_dk, self._pool_dv, self._d_last_logits,
+                        jnp.asarray(bt_grp), jnp.asarray(rows),
+                        jnp.asarray(starts), jnp.asarray(last_idx),
+                        self.draft_cfg, shardings=self._d_shardings,
+                        scale_k=self._scale_dk, scale_v=self._scale_dv,
+                        qspec=self.kv_quant_spec)
+                else:
+                    self._d_cache, self._d_last_logits = _prefill_rows(
+                        self.draft_params, jnp.asarray(prompts),
+                        self._d_cache, self._d_last_logits,
+                        jnp.asarray(rows), jnp.asarray(starts),
+                        jnp.asarray(last_idx), self.draft_cfg,
+                        shardings=self._d_shardings)
+                self.spec_prefill_dispatches += 1
 
     def _bind_row(self, row: int, req: _Request, chain: List[int],
                   start: int) -> None:
@@ -3591,12 +3634,12 @@ class DecodeEngine:
             for x in (k, v, lg, sk, sv):
                 if x is not None:
                     _host_async(x)
-            k = _device_get(k)
-            v = _device_get(v)
-            lg = _device_get(lg)
+            k = self._device_wait(k)
+            v = self._device_wait(v)
+            lg = self._device_wait(lg)
             if sk is not None:
-                sk = _device_get(sk)
-                sv = _device_get(sv)
+                sk = self._device_wait(sk)
+                sv = self._device_wait(sv)
             self._swapped[req.req_id] = _SwapState(
                 k, v, n, int(self.row_len[row]),
                 int(self._tok_idx[row]), int(self.row_budget[row]), lg,
@@ -3692,6 +3735,7 @@ class DecodeEngine:
         self.swap_ins += 1
         self.swap_in_bytes += nbytes
         self.metrics.on_swap_in(nbytes)
+        self.metrics.on_decodable(req.req_id)
         if self.trace.enabled:
             self.trace.span_since_mark(
                 "swap_in", req.req_id,
@@ -3771,12 +3815,12 @@ class DecodeEngine:
             for x in (k, v, lg, sk, sv):
                 if x is not None:
                     _host_async(x)
-            k = _device_get(k)
-            v = _device_get(v)
-            lg = _device_get(lg)
+            k = self._device_wait(k)
+            v = self._device_wait(v)
+            lg = self._device_wait(lg)
             if sk is not None:
-                sk = _device_get(sk)
-                sv = _device_get(sv)
+                sk = self._device_wait(sk)
+                sv = self._device_wait(sv)
             nbytes = k.nbytes + v.nbytes + lg.nbytes
             if sk is not None:
                 nbytes += sk.nbytes + sv.nbytes
@@ -3946,92 +3990,92 @@ class DecodeEngine:
         pool and committed as the frontier passes them."""
         if not self._row_prefill:
             return
-        groups: Dict[int, List[Tuple[int, _PrefillState, int]]] = {}
-        for row, st in self._row_prefill.items():
-            C = len(st.prompt) - st.pos
-            if self.prefill_chunk is not None:
-                C = min(C, self.prefill_chunk)
-            # Bucket the chunk, capped so the scatter never runs past
-            # max_len (starts differ per row; the cap is per-row).
-            Cb = min(self._bucket(C), self.max_len - st.pos)
-            groups.setdefault(Cb, []).append((row, st, C))
-        for Cb in sorted(groups):
-            grp = groups[Cb]
-            n = len(grp)
-            t0 = self.trace.now() if self.trace.enabled else 0.0
-            n_pad = _pow2(n)
-            prompts = np.zeros((n_pad, Cb), np.int32)
-            rows = np.zeros((n_pad,), np.int32)
-            starts = np.zeros((n_pad,), np.int32)
-            last_idx = np.zeros((n_pad,), np.int32)
-            real = 0
-            for i, (row, st, C) in enumerate(grp):
-                prompts[i, :C] = st.prompt[st.pos:st.pos + C]
-                rows[i] = row
-                starts[i] = st.pos
-                last_idx[i] = C - 1
-                real += C
-            prompts[n:] = prompts[n - 1]    # filler: repeat last row —
-            rows[n:] = rows[n - 1]          # duplicate scatters write
-            starts[n:] = starts[n - 1]      # identical values
-            last_idx[n:] = last_idx[n - 1]
-            # Per-chunk adapter-slot lane gathered from the engine's
-            # [B] lane (filler rows repeat the last real row, so the
-            # gather stays well-defined).
-            if self.adapter_pool is not None:
-                adapters = self.adapter_pool.stacks
-                row_slot = jnp.asarray(self._row_slot[rows])
-            else:
-                adapters = row_slot = None
-            if self.paged:
-                bt_grp = self._bt[rows]            # [n_pad, MB]
-                (self._pool_k, self._pool_v, self._scale_k,
-                 self._scale_v,
-                 self._last_logits) = _prefill_rows_paged(
-                    self.params, jnp.asarray(prompts), self._pool_k,
-                    self._pool_v, self._last_logits,
-                    jnp.asarray(bt_grp), jnp.asarray(rows),
-                    jnp.asarray(starts), jnp.asarray(last_idx),
-                    self.cfg, shardings=self._shardings,
-                    adapters=adapters, row_slot=row_slot,
-                    scale_k=self._scale_k, scale_v=self._scale_v,
-                    qspec=self.kv_quant_spec)
-            else:
-                self.cache, self._last_logits = _prefill_rows(
-                    self.params, jnp.asarray(prompts), self.cache,
-                    self._last_logits, jnp.asarray(rows),
-                    jnp.asarray(starts), jnp.asarray(last_idx),
-                    self.cfg, shardings=self._shardings,
-                    adapters=adapters, row_slot=row_slot)
-            self.prefill_dispatches += 1
-            padded = n_pad * Cb - real
-            self.prefill_real_tokens += real
-            self.prefill_padded_tokens += padded
-            self.metrics.on_prefill_batch(real, padded)
-            if self.trace.enabled:
-                self.trace.add("prefill_dispatch", t0,
-                               self.trace.now() - t0, lane="dispatch",
-                               args={"bucket": Cb, "rows": n,
-                                     "real": real, "padded": padded})
-        done_rows = []
-        for grp in groups.values():
-            for row, st, C in grp:
-                st.pos += C
-                self.row_len[row] = st.pos
-                if self.trace.enabled:
-                    self.trace.span_since_mark(
-                        "prefill_chunk", st.req.req_id,
-                        {"pos": st.pos, "tokens": C,
-                         "prompt_tokens": len(st.prompt)})
-                if self._prefix is not None:
-                    if self.paged:
-                        self._commit_covered(row, st)
+        with self.trace.lane("advance_prefills", "dispatch",
+                             rows=len(self._row_prefill)):
+            groups: Dict[int, List[Tuple[int, _PrefillState, int]]] = {}
+            for row, st in self._row_prefill.items():
+                C = len(st.prompt) - st.pos
+                if self.prefill_chunk is not None:
+                    C = min(C, self.prefill_chunk)
+                # Bucket the chunk, capped so the scatter never runs past
+                # max_len (starts differ per row; the cap is per-row).
+                Cb = min(self._bucket(C), self.max_len - st.pos)
+                groups.setdefault(Cb, []).append((row, st, C))
+            for Cb in sorted(groups):
+                grp = groups[Cb]
+                n = len(grp)
+                with self.trace.lane("prefill_dispatch", "dispatch",
+                                     bucket=Cb, rows=n) as span:
+                    n_pad = _pow2(n)
+                    prompts = np.zeros((n_pad, Cb), np.int32)
+                    rows = np.zeros((n_pad,), np.int32)
+                    starts = np.zeros((n_pad,), np.int32)
+                    last_idx = np.zeros((n_pad,), np.int32)
+                    real = 0
+                    for i, (row, st, C) in enumerate(grp):
+                        prompts[i, :C] = st.prompt[st.pos:st.pos + C]
+                        rows[i] = row
+                        starts[i] = st.pos
+                        last_idx[i] = C - 1
+                        real += C
+                    prompts[n:] = prompts[n - 1]    # filler: repeat last row —
+                    rows[n:] = rows[n - 1]          # duplicate scatters write
+                    starts[n:] = starts[n - 1]      # identical values
+                    last_idx[n:] = last_idx[n - 1]
+                    # Per-chunk adapter-slot lane gathered from the engine's
+                    # [B] lane (filler rows repeat the last real row, so the
+                    # gather stays well-defined).
+                    if self.adapter_pool is not None:
+                        adapters = self.adapter_pool.stacks
+                        row_slot = jnp.asarray(self._row_slot[rows])
                     else:
-                        self._flush_copy_out(row, st)
-                if st.pos >= len(st.prompt):
-                    done_rows.append(row)
-        for row in done_rows:
-            del self._row_prefill[row]
+                        adapters = row_slot = None
+                    if self.paged:
+                        bt_grp = self._bt[rows]            # [n_pad, MB]
+                        (self._pool_k, self._pool_v, self._scale_k,
+                         self._scale_v,
+                         self._last_logits) = _prefill_rows_paged(
+                            self.params, jnp.asarray(prompts), self._pool_k,
+                            self._pool_v, self._last_logits,
+                            jnp.asarray(bt_grp), jnp.asarray(rows),
+                            jnp.asarray(starts), jnp.asarray(last_idx),
+                            self.cfg, shardings=self._shardings,
+                            adapters=adapters, row_slot=row_slot,
+                            scale_k=self._scale_k, scale_v=self._scale_v,
+                            qspec=self.kv_quant_spec)
+                    else:
+                        self.cache, self._last_logits = _prefill_rows(
+                            self.params, jnp.asarray(prompts), self.cache,
+                            self._last_logits, jnp.asarray(rows),
+                            jnp.asarray(starts), jnp.asarray(last_idx),
+                            self.cfg, shardings=self._shardings,
+                            adapters=adapters, row_slot=row_slot)
+                    self.prefill_dispatches += 1
+                    padded = n_pad * Cb - real
+                    self.prefill_real_tokens += real
+                    self.prefill_padded_tokens += padded
+                    self.metrics.on_prefill_batch(real, padded)
+                    span.note(real=real, padded=padded)
+            done_rows = []
+            for grp in groups.values():
+                for row, st, C in grp:
+                    st.pos += C
+                    self.row_len[row] = st.pos
+                    if self.trace.enabled:
+                        self.trace.span_since_mark(
+                            "prefill_chunk", st.req.req_id,
+                            {"pos": st.pos, "tokens": C,
+                             "prompt_tokens": len(st.prompt)})
+                    if self._prefix is not None:
+                        if self.paged:
+                            self._commit_covered(row, st)
+                        else:
+                            self._flush_copy_out(row, st)
+                    if st.pos >= len(st.prompt):
+                        done_rows.append(row)
+            for row in done_rows:
+                st = self._row_prefill.pop(row)
+                self.metrics.on_decodable(st.req.req_id)
 
     def _flush_copy_out(self, row: int, st: _PrefillState) -> None:
         """Copy every pending prefix block the row's frontier now

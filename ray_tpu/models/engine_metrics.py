@@ -5,7 +5,13 @@ this module timestamps each transition and exports the serving numbers
 a vLLM-class engine is judged by:
 
 - queue wait      (submit → prefill admission)
-- TTFT            (submit → first emitted token)
+- prefill         (admission → the row becomes decodable: its last
+                   prompt chunk is dispatched)
+- first block     (decodable → first token on the host; the first token
+                   rides a fused decode block that the host drains up
+                   to a pipeline depth later)
+- TTFT            (submit → first emitted token; exactly the sum of the
+                   three above, per request, on the engine's clock)
 - TPOT            (gap between consecutive tokens of one request)
 - tokens/steps    (throughput counters)
 - slot occupancy / batch efficiency per step (how full the shared
@@ -98,12 +104,13 @@ class _Agg:
 
 
 class _ReqTimes:
-    __slots__ = ("submit_t", "admit_t", "first_token_t", "last_token_t",
-                 "n_tokens")
+    __slots__ = ("submit_t", "admit_t", "decodable_t", "first_token_t",
+                 "last_token_t", "n_tokens")
 
     def __init__(self, submit_t: float):
         self.submit_t = submit_t
         self.admit_t: Optional[float] = None
+        self.decodable_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
         self.last_token_t: Optional[float] = None
         self.n_tokens = 0
@@ -135,6 +142,8 @@ class EngineMetrics:
         self.live_slots = 0
         self.batch_efficiency = 0.0
         self.queue_wait_s = _Agg()
+        self.prefill_s = _Agg()
+        self.first_block_s = _Agg()
         self.ttft_s = _Agg()
         self.tpot_s = _Agg()
         self.decode_dispatches = 0
@@ -422,6 +431,27 @@ class EngineMetrics:
         self.queue_wait_s.add(wait)
         self._m_queue_wait.observe(wait)
 
+    def on_decodable(self, req_id: int) -> None:
+        """The request's row can decode: its last prompt chunk is
+        dispatched (or its K/V was swapped back in). Before the first
+        token this may fire again (a preempted row prefills twice); the
+        last one before the first token splits the wait."""
+        rt = self._req.get(req_id)
+        if rt is not None and rt.first_token_t is None:
+            rt.decodable_t = self._clock()
+
+    def _on_first_token(self, rt: _ReqTimes, now: float) -> None:
+        """TTFT and its two inner parts close together, so that
+        queue_wait + prefill + first_block == ttft for every request."""
+        rt.first_token_t = now
+        admit_t = rt.submit_t if rt.admit_t is None else rt.admit_t
+        dec_t = admit_t if rt.decodable_t is None else rt.decodable_t
+        self.prefill_s.add(dec_t - admit_t)
+        self.first_block_s.add(now - dec_t)
+        ttft = now - rt.submit_t
+        self.ttft_s.add(ttft)
+        self._m_ttft.observe(ttft)
+
     def on_token(self, req_id: int, n: int = 1) -> None:
         rt = self._req.get(req_id)
         now = self._clock()
@@ -430,10 +460,7 @@ class EngineMetrics:
         if rt is None:
             return
         if rt.first_token_t is None:
-            rt.first_token_t = now
-            ttft = now - rt.submit_t
-            self.ttft_s.add(ttft)
-            self._m_ttft.observe(ttft)
+            self._on_first_token(rt, now)
         else:
             tpot = now - rt.last_token_t
             self.tpot_s.add(tpot)
@@ -446,10 +473,11 @@ class EngineMetrics:
         [H, B] block) — the vectorized twin of per-token `on_token`
         calls, preserving its observation arithmetic: TTFT once at the
         request's first token, then one TPOT observation per further
-        token (total = tokens - 1 per request). The first gap of a
-        block is the real inter-block wall gap; the rest are 0.0 —
-        honest for a fused block, whose tokens genuinely arrive at the
-        same instant."""
+        token (total = tokens - 1 per request). A later block's `n`
+        tokens each take an `n`-th of the wall gap since the block
+        before (same count, same sum as one real gap and `n - 1` zeros,
+        but a median that is the device's cadence and not 0); the
+        tokens that land WITH the first one wait 0.0 behind it."""
         if n <= 0:
             return
         rt = self._req.get(req_id)
@@ -459,17 +487,13 @@ class EngineMetrics:
         if rt is None:
             return
         if rt.first_token_t is None:
-            rt.first_token_t = now
-            ttft = now - rt.submit_t
-            self.ttft_s.add(ttft)
-            self._m_ttft.observe(ttft)
+            self._on_first_token(rt, now)
+            k, tpot = n - 1, 0.0
         else:
-            tpot = now - rt.last_token_t
+            k, tpot = n, (now - rt.last_token_t) / n
+        for _ in range(k):
             self.tpot_s.add(tpot)
             self._m_tpot.observe(tpot)
-        for _ in range(n - 1):
-            self.tpot_s.add(0.0)
-            self._m_tpot.observe(0.0)
         rt.last_token_t = now
         rt.n_tokens += n
 
@@ -779,6 +803,8 @@ class EngineMetrics:
         out["adapter_slots_resident"] = self.adapter_slots_resident
         out["adapter_slots_pinned"] = self.adapter_slots_pinned
         self.queue_wait_s.fields("queue_wait_s", out)
+        self.prefill_s.fields("prefill_s", out)
+        self.first_block_s.fields("first_block_s", out)
         self.ttft_s.fields("ttft_s", out)
         self.tpot_s.fields("tpot_s", out)
         self.decode_horizon.fields("decode_horizon", out)
@@ -798,6 +824,8 @@ class NullEngineMetrics:
     def on_shed(self, req_id): pass
 
     def on_admit(self, req_id): pass
+
+    def on_decodable(self, req_id): pass
 
     def on_token(self, req_id, n=1): pass
 

@@ -16,7 +16,18 @@ Design rules (mirroring engine_metrics):
   ``enabled = False``; every engine hot-path call site guards with
   ``if tr.enabled:`` so the off path never builds an args dict, never
   reads a clock, never allocates. `tests/test_perf_gates.py` pins
-  this with a tracemalloc gate.
+  this with a tracemalloc gate. The one sanctioned unguarded call is
+  `lane()` (below).
+- Engine lanes are ALSO on the profiler's clock. Every engine-lane
+  span is opened through ``tracer.lane(name, lane, **args)``, on the
+  null tracer too: it enters a `jax.profiler.TraceAnnotation` named
+  ``eng.<name>`` with those arguments, so whenever a `jax.profiler`
+  session is running the engine's seams appear on the ``/host:CPU``
+  plane beside the device's ops, with no knob to turn. With no session
+  an annotation would be inert, so the null tracer hands out one
+  shared no-op object instead (a 20 ns check, no allocation). The ring additionally gets the span under its
+  bare name when the ring is on. Per-request spans are not lexically
+  scoped and stay in the ring only, guarded as ever.
 - Bounded-memory-when-on. The ring overwrites its OLDEST record when
   full and counts the overwrite in ``events_dropped`` — a long churn
   run keeps the most recent window, never grows without bound.
@@ -47,7 +58,16 @@ Span catalogue (name / tid lane / meaning):
   trip.
 - ``finish`` / ``shed`` (req): instant markers closing the lifecycle.
 - ``dispatch`` / ``host_drain`` (engine lane): one batched program
-  launch / one blocking device->host token pull.
+  launch / one drained token block. ``host_drain`` is the parent of
+  ``device_wait`` (the blocking device->host pull alone) and ``emit``
+  (the host replay of the block: bookkeeping, retirements, metrics).
+- ``prefill_dispatch`` (engine ``dispatch`` lane): one batched prefill
+  program launch, inside ``advance_prefills`` (one chunk for every row
+  mid-prompt).
+- ``admit`` (engine ``admit`` lane): the admission loop of one step
+  and the row binding / prefix work of what it admitted.
+- ``pipeline_flush`` (engine ``drain`` lane): a forced drain of the
+  whole in-flight ring.
 - ``spec_draft`` (engine ``dispatch`` lane): one speculative dispatch
   — draft proposals + target verify fused in one program (args:
   window, proposed, rows, run_ahead).
@@ -71,13 +91,77 @@ import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
+from jax.profiler import TraceAnnotation
+
 from ray_tpu.util.timeline import chrome_complete_event
 
 ENV_TRACE = "RAY_TPU_TRACE"
 
+# Engine-lane spans carry this prefix in a `jax.profiler` trace, which
+# keeps them apart from spans other code puts on the same host plane.
+PROFILER_PREFIX = "eng."
+
 # Default ring capacity: ~16k spans covers thousands of requests of
 # recent history at a few spans per request, at < 2 MiB of host RAM.
 DEFAULT_CAPACITY = 16384
+
+
+class _InertSpan:
+    """What the null tracer's `lane()` hands out while no profiler
+    session runs: one shared object, so the off path allocates
+    nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **args) -> None:
+        """Arguments known only at the span's end (ring only)."""
+
+
+_INERT = _InertSpan()
+
+
+class _ProfilerSpan(TraceAnnotation):
+    """An engine-lane span of the null tracer while a `jax.profiler`
+    session runs: in the profiler's trace only."""
+
+    __slots__ = ()
+
+    def note(self, **args) -> None:
+        pass
+
+
+class _RingSpan(TraceAnnotation):
+    """An engine-lane span in the profiler's trace AND the ring."""
+
+    __slots__ = ("_tr", "_name", "_lane", "_args", "_t0")
+
+    def __init__(self, tracer: "EngineTracer", name: str, lane: str,
+                 args: dict):
+        super().__init__(PROFILER_PREFIX + name, **args)
+        self._tr, self._name, self._lane, self._args = \
+            tracer, name, lane, args
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._t0 = self._tr.clock()
+        super().__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        ring = self._tr         # only an EngineTracer builds a _RingSpan
+        ring.add(self._name, self._t0, ring.clock() - self._t0,
+                 lane=self._lane, args=self._args or None)
+        return False
+
+    def note(self, **args) -> None:
+        self._args.update(args)
 
 
 class EngineTracer:
@@ -131,6 +215,13 @@ class EngineTracer:
                 args: Optional[dict] = None,
                 lane: Optional[str] = None) -> None:
         self.add(name, self.clock(), 0.0, req_id, lane, args)
+
+    def lane(self, name: str, lane: str, **args) -> _RingSpan:
+        """``with tracer.lane("dispatch", "dispatch", horizon=8):`` —
+        one engine-lane span: a `TraceAnnotation` ``eng.<name>`` on the
+        profiler's clock and a ring record ``name`` on this tracer's.
+        Call sites do NOT guard it (see the module docstring)."""
+        return _RingSpan(self, name, lane, args)
 
     def open(self, name: str, req_id: Any) -> None:
         """Mark the start of a span closed later by `close` (or
@@ -236,6 +327,11 @@ class NullEngineTracer:
 
     def instant(self, *a, **k) -> None:
         pass
+
+    def lane(self, name: str, lane: str, **args):
+        if not TraceAnnotation.is_enabled():    # no session: ~20 ns
+            return _INERT
+        return _ProfilerSpan(PROFILER_PREFIX + name, **args)
 
     def open(self, *a, **k) -> None:
         pass

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import LlamaConfig, _rmsnorm, _rope
+from ray_tpu.ops import scope_names as sn
 
 Params = Dict[str, Any]
 Cache = Dict[str, jax.Array]  # {"k","v": [L, B, max_len, kv_heads, hd]}
@@ -52,8 +53,9 @@ def _cached_attention(q, k_cache, v_cache, q_slots, kv_valid_len,
     B, S, H, D = q.shape
     max_len = k_cache.shape[1]
     rep = H // k_cache.shape[2]
-    k = jnp.repeat(k_cache, rep, axis=2)  # [B, max_len, H, D]
-    v = jnp.repeat(v_cache, rep, axis=2)
+    with jax.named_scope(sn.KV_GATHER):
+        k = jnp.repeat(k_cache, rep, axis=2)  # [B, max_len, H, D]
+        v = jnp.repeat(v_cache, rep, axis=2)
     logits = jnp.einsum("bshd,bthd->bhst", q, k,
                         preferred_element_type=jnp.float32)
     logits = logits * (D ** -0.5)
@@ -111,47 +113,55 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
     byte-identical to before this feature existed."""
     dt = cfg.dtype
     x = _rmsnorm(h, layer["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", x, layer["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", x, layer["wv"].astype(dt))
-    if lora is not None:
-        if "wq" in lora:
-            q = q + _lora_delta(x, lora["wq"], lora_slots,
-                                dt).reshape(q.shape)
-        if "wk" in lora:
-            k = k + _lora_delta(x, lora["wk"], lora_slots,
-                                dt).reshape(k.shape)
-        if "wv" in lora:
-            v = v + _lora_delta(x, lora["wv"], lora_slots,
-                                dt).reshape(v.shape)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    k_cache, v_cache = write_kv(k_cache, v_cache, k, v)
+    with jax.named_scope(sn.ATTN_QKV):
+        q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", x, layer["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", x, layer["wv"].astype(dt))
+        if lora is not None:
+            if "wq" in lora:
+                q = q + _lora_delta(x, lora["wq"], lora_slots,
+                                    dt).reshape(q.shape)
+            if "wk" in lora:
+                k = k + _lora_delta(x, lora["wk"], lora_slots,
+                                    dt).reshape(k.shape)
+            if "wv" in lora:
+                v = v + _lora_delta(x, lora["wv"], lora_slots,
+                                    dt).reshape(v.shape)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope(sn.KV_WRITE):
+        k_cache, v_cache = write_kv(k_cache, v_cache, k, v)
     if attend is not None:
-        o = attend(q, k_cache, v_cache)
+        with jax.named_scope(sn.PAGED_ATTENTION):
+            o = attend(q, k_cache, v_cache)
     else:
-        o = _cached_attention(q, k_cache, v_cache, q_slots,
-                              kv_valid_len, cfg, slot_live=slot_live)
-    attn_out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-    if lora is not None and "wo" in lora:
-        o_flat = o.reshape(o.shape[0], o.shape[1], -1)
-        attn_out = attn_out + _lora_delta(o_flat, lora["wo"],
-                                          lora_slots, dt)
-    h = h + attn_out
+        with jax.named_scope(sn.CACHED_ATTENTION):
+            o = _cached_attention(q, k_cache, v_cache, q_slots,
+                                  kv_valid_len, cfg, slot_live=slot_live)
+    with jax.named_scope(sn.ATTN_OUT):
+        attn_out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+        if lora is not None and "wo" in lora:
+            o_flat = o.reshape(o.shape[0], o.shape[1], -1)
+            attn_out = attn_out + _lora_delta(o_flat, lora["wo"],
+                                              lora_slots, dt)
+        h = h + attn_out
     x = _rmsnorm(h, layer["mlp_norm"], cfg.norm_eps)
-    gate = jnp.einsum("bsd,df->bsf", x, layer["w_gate"].astype(dt))
-    up = jnp.einsum("bsd,df->bsf", x, layer["w_up"].astype(dt))
-    if lora is not None:
-        if "w_gate" in lora:
-            gate = gate + _lora_delta(x, lora["w_gate"], lora_slots, dt)
-        if "w_up" in lora:
-            up = up + _lora_delta(x, lora["w_up"], lora_slots, dt)
-    act = jax.nn.silu(gate) * up
-    mlp_out = jnp.einsum("bsf,fd->bsd", act, layer["w_down"].astype(dt))
-    if lora is not None and "w_down" in lora:
-        mlp_out = mlp_out + _lora_delta(act, lora["w_down"],
-                                        lora_slots, dt)
-    h = h + mlp_out
+    with jax.named_scope(sn.MLP):
+        gate = jnp.einsum("bsd,df->bsf", x, layer["w_gate"].astype(dt))
+        up = jnp.einsum("bsd,df->bsf", x, layer["w_up"].astype(dt))
+        if lora is not None:
+            if "w_gate" in lora:
+                gate = gate + _lora_delta(x, lora["w_gate"], lora_slots,
+                                          dt)
+            if "w_up" in lora:
+                up = up + _lora_delta(x, lora["w_up"], lora_slots, dt)
+        act = jax.nn.silu(gate) * up
+        mlp_out = jnp.einsum("bsf,fd->bsd", act,
+                             layer["w_down"].astype(dt))
+        if lora is not None and "w_down" in lora:
+            mlp_out = mlp_out + _lora_delta(act, lora["w_down"],
+                                            lora_slots, dt)
+        h = h + mlp_out
     return h, k_cache, v_cache
 
 
@@ -188,7 +198,8 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
     tokens at position 0); ``slot_live`` [B, max_len] masks dead (pad)
     cache slots out of every attention."""
     B, S = tokens.shape
-    h = params["tok_embed"].astype(cfg.dtype)[tokens]
+    with jax.named_scope(sn.EMBED):
+        h = params["tok_embed"].astype(cfg.dtype)[tokens]
     slot_ids = start + jnp.broadcast_to(jnp.arange(S), (B, S))
     if positions is None:
         positions = slot_ids
@@ -205,9 +216,10 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
     h, (k_new, v_new) = jax.lax.scan(
         body, h, (params["layers"], cache["k"], cache["v"]))
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", h,
-                        params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(sn.LM_HEAD):
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            params["lm_head"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
     return logits, {"k": k_new, "v": v_new}
 
 
@@ -247,7 +259,8 @@ def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
     scan carries its original 3-tuple and the traced program is
     byte-identical to the pre-LoRA path."""
     B, S = tokens.shape
-    h = params["tok_embed"].astype(cfg.dtype)[tokens]
+    with jax.named_scope(sn.EMBED):
+        h = params["tok_embed"].astype(cfg.dtype)[tokens]
     slot_ids = starts[:, None] + jnp.arange(S)[None, :]      # [B, S]
     bidx = jnp.arange(B)
 
@@ -276,9 +289,10 @@ def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
         xs = xs + (adapters,)
     h, (k_new, v_new) = jax.lax.scan(body, h, xs)
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", h,
-                        params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(sn.LM_HEAD):
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            params["lm_head"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
     return logits, {"k": k_new, "v": v_new}
 
 
@@ -434,10 +448,11 @@ def generate(params: Params, prompt: jax.Array, cfg: LlamaConfig, *,
 
     def step(carry, i):
         cache, last_logits, slot, pos_ids, done = carry
-        tok = sample(last_logits, i)
-        if eos_id is not None:
-            tok = jnp.where(done, eos_id, tok)
-            done = done | (tok == eos_id)
+        with jax.named_scope(sn.SAMPLE):
+            tok = sample(last_logits, i)
+            if eos_id is not None:
+                tok = jnp.where(done, eos_id, tok)
+                done = done | (tok == eos_id)
         logits, cache = forward_cached(
             params, tok[:, None], cache, slot, cfg,
             positions=pos_ids[:, None], slot_live=slot_live)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import attention
+from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import logical_to_mesh, LogicalAxisRules
 
@@ -215,9 +216,11 @@ def llama_param_specs(cfg: LlamaConfig,
 # ---------------------------------------------------------------------------
 
 def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * rms).astype(x.dtype) * scale.astype(x.dtype)
+    with jax.named_scope(sn.NORM):
+        x32 = x.astype(jnp.float32)
+        rms = jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+        return (x32 * rms).astype(x.dtype) * scale.astype(x.dtype)
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -261,22 +264,29 @@ def _decoder_layer(h: jax.Array, layer: Params, positions: jax.Array,
     dt = cfg.dtype
     name = jax.ad_checkpoint.checkpoint_name
     x = _rmsnorm(h, layer["attn_norm"], cfg.norm_eps)
-    q = name(jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt)), "qkv")
-    k = name(jnp.einsum("bsd,dhk->bshk", x, layer["wk"].astype(dt)), "qkv")
-    v = name(jnp.einsum("bsd,dhk->bshk", x, layer["wv"].astype(dt)), "qkv")
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    o = name(_attention_call(q, k, v, cfg), "attn_out")
-    h = h + name(jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt)),
-                 "wo_out")
+    with jax.named_scope(sn.ATTN_QKV):
+        q = name(jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt)),
+                 "qkv")
+        k = name(jnp.einsum("bsd,dhk->bshk", x, layer["wk"].astype(dt)),
+                 "qkv")
+        v = name(jnp.einsum("bsd,dhk->bshk", x, layer["wv"].astype(dt)),
+                 "qkv")
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope(sn.ATTENTION):
+        o = name(_attention_call(q, k, v, cfg), "attn_out")
+    with jax.named_scope(sn.ATTN_OUT):
+        h = h + name(jnp.einsum("bshk,hkd->bsd", o,
+                                layer["wo"].astype(dt)), "wo_out")
 
     x = _rmsnorm(h, layer["mlp_norm"], cfg.norm_eps)
-    gate = name(jnp.einsum("bsd,df->bsf", x, layer["w_gate"].astype(dt)),
-                "ffn_gate")
-    up = name(jnp.einsum("bsd,df->bsf", x, layer["w_up"].astype(dt)),
-              "ffn_up")
-    h = h + name(jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                            layer["w_down"].astype(dt)), "ffn_down")
+    with jax.named_scope(sn.MLP):
+        gate = name(jnp.einsum("bsd,df->bsf", x,
+                               layer["w_gate"].astype(dt)), "ffn_gate")
+        up = name(jnp.einsum("bsd,df->bsf", x, layer["w_up"].astype(dt)),
+                  "ffn_up")
+        h = h + name(jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                                layer["w_down"].astype(dt)), "ffn_down")
     return h
 
 
@@ -287,7 +297,8 @@ def llama_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1]), tokens.shape)
-    h = params["tok_embed"].astype(cfg.dtype)[tokens]
+    with jax.named_scope(sn.EMBED):
+        h = params["tok_embed"].astype(cfg.dtype)[tokens]
 
     layer_fn = functools.partial(_decoder_layer, positions=positions, cfg=cfg)
     if cfg.remat:
@@ -315,18 +326,24 @@ def llama_forward(params: Params, tokens: jax.Array, cfg: LlamaConfig,
                   positions: Optional[jax.Array] = None) -> jax.Array:
     """tokens [B, S] int32 -> logits [B, S, vocab] (float32)."""
     h = llama_hidden(params, tokens, cfg, positions)
-    logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(sn.LM_HEAD):
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            params["lm_head"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
     return logits
 
 
 def _nll(h: jax.Array, targets: jax.Array, lm_head: jax.Array,
          cfg: LlamaConfig) -> jax.Array:
     """[.., S, d] hidden + [.., S] targets -> [.., S] token nll (f32)."""
-    logits = jnp.einsum("...sd,dv->...sv", h, lm_head.astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with jax.named_scope(sn.LM_HEAD):
+        logits = jnp.einsum("...sd,dv->...sv", h,
+                            lm_head.astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+    with jax.named_scope(sn.LOSS):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None],
+                                    axis=-1)[..., 0]
 
 
 def llama_loss(params: Params, batch: Dict[str, jax.Array],

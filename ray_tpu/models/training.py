@@ -16,6 +16,7 @@ import jax
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops import scope_names as sn
 from ray_tpu.parallel.sharding import logical_to_mesh, LogicalAxisRules
 
 Pytree = Any
@@ -78,9 +79,12 @@ def make_sharded_train_step(
                 lambda x: jax.lax.with_sharding_constraint(
                     x, _batch_sharding_for(x)), batch)
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
+            with jax.named_scope(sn.OPTIMIZER):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
+                metrics = {"loss": loss,
+                           "grad_norm": optax.global_norm(grads)}
             return params, opt_state, metrics
 
     return init_fn, step_fn

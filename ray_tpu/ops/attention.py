@@ -23,6 +23,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import scope_names as sn
+
 _SPMD_MESH: contextvars.ContextVar = contextvars.ContextVar(
     "ray_tpu_spmd_mesh", default=None)
 
@@ -195,22 +197,23 @@ def paged_attention(q: jax.Array,
     # Gather the per-row dense view: [B, MB, T, KV, D] -> [B, MB*T, ..]
     # (logical slot p*T + t of row b is block_tables[b, p] slot t, so
     # the reshape restores contiguous slot order per row).
-    k = k_pages[block_tables]
-    v = v_pages[block_tables]
-    if k_scale is not None:
-        # dequant-in-gather; the view must stay f32 (requantization
-        # byte-stability — see ops/kv_quant.py)
-        k = k.astype(jnp.float32) * k_scale[block_tables][:, :, None, :,
-                                                          None]
-        v = v.astype(jnp.float32) * v_scale[block_tables][:, :, None, :,
-                                                          None]
-    span = k.shape[1] * T
-    k = k.reshape(B, span, KV, D)
-    v = v.reshape(B, span, KV, D)
-    # -- lockstep with generate._cached_attention from here on --
-    rep = H // KV
-    k = jnp.repeat(k, rep, axis=2)                 # [B, span, H, D]
-    v = jnp.repeat(v, rep, axis=2)
+    with jax.named_scope(sn.KV_GATHER):
+        k = k_pages[block_tables]
+        v = v_pages[block_tables]
+        if k_scale is not None:
+            # dequant-in-gather; the view must stay f32 (requantization
+            # byte-stability — see ops/kv_quant.py)
+            k = k.astype(jnp.float32) \
+                * k_scale[block_tables][:, :, None, :, None]
+            v = v.astype(jnp.float32) \
+                * v_scale[block_tables][:, :, None, :, None]
+        span = k.shape[1] * T
+        k = k.reshape(B, span, KV, D)
+        v = v.reshape(B, span, KV, D)
+        # -- lockstep with generate._cached_attention from here on --
+        rep = H // KV
+        k = jnp.repeat(k, rep, axis=2)                 # [B, span, H, D]
+        v = jnp.repeat(v, rep, axis=2)
     logits = jnp.einsum("bshd,bthd->bhst", q, k,
                         preferred_element_type=jnp.float32)
     logits = logits * (sm_scale if sm_scale is not None else D ** -0.5)

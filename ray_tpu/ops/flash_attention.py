@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import scope_names as sn
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
@@ -179,6 +181,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=sn.FLASH_FWD,
     )(qp, kp, vp)
     if with_lse:
         out, lse = result
@@ -350,6 +353,7 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=sn.FLASH_BWD_DQ,
     )(qp, kp, vp, gp, lse, delta)
 
     # --- dk/dv: grid (b, h, nk, nq), q innermost (axis2=kv, axis3=q);
@@ -389,6 +393,7 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=sn.FLASH_BWD_DKV,
     )(qp, kp, vp, gp, lse, delta)
 
     dq = dq[:, :, :sq] if sq_p != sq else dq

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import scope_names as sn
+
 _NEG_INF = -1e30
 
 __all__ = ["paged_attention_kernel"]
@@ -167,13 +169,14 @@ def paged_attention_kernel(q: jax.Array,
         pl.BlockSpec((1, T, KV * D), page_map),        # k page
         pl.BlockSpec((1, T, KV * D), page_map),        # v page
     ]
-    args = [qs, qg, k_pages.reshape(NB, T, KV * D),
-            v_pages.reshape(NB, T, KV * D)]
-    if has_scale:
-        in_specs += [pl.BlockSpec((1, 1, MB * KV), row_map,
-                                  memory_space=pltpu.SMEM)] * 2
-        args += [s.astype(jnp.float32)[block_tables].reshape(B, 1, MB * KV)
-                 for s in (k_scale, v_scale)]
+    with jax.named_scope(sn.KV_GATHER):
+        args = [qs, qg, k_pages.reshape(NB, T, KV * D),
+                v_pages.reshape(NB, T, KV * D)]
+        if has_scale:
+            in_specs += [pl.BlockSpec((1, 1, MB * KV), row_map,
+                                      memory_space=pltpu.SMEM)] * 2
+            args += [s.astype(jnp.float32)[block_tables]
+                     .reshape(B, 1, MB * KV) for s in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -196,6 +199,7 @@ def paged_attention_kernel(q: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=sn.PAGED_KERNEL,
     )(bt, lim, *args)
     return out.reshape(B, KV, S, g, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, S, H, D)

@@ -1,0 +1,45 @@
+"""The names the device programs give their parts.
+
+Every jitted program of `ray_tpu.models` wraps its parts in
+`jax.named_scope(<one of SCOPES>)` and every Pallas kernel of
+`ray_tpu.ops` passes `name=<one of KERNELS>` to `pallas_call`. A scope
+is metadata of the compiled program (the op's `op_name`, which a
+profiler trace shows as the device event's `tf_op`): it changes no
+instruction, no buffer and no result. The names live here, once, so that
+whatever reads a trace (`benchmark/harness/scopes.py`) matches on the
+list the program was written against and not on instruction numbers,
+which change with every compile.
+"""
+
+EMBED = "embed"                      # token ids -> hidden states
+NORM = "norm"                        # every rmsnorm
+ATTN_QKV = "attn_qkv"                # q/k/v projections and rope
+KV_WRITE = "kv_write"                # this chunk's K/V into cache or pool
+KV_GATHER = "kv_gather"              # slices/reshapes/gathers of cached
+#                                      K/V ahead of attention
+PAGED_ATTENTION = "paged_attention"  # attention through a block table
+CACHED_ATTENTION = "cached_attention"    # attention over a dense cache row
+ATTENTION = "attention"              # uncached causal attention (training)
+ATTN_OUT = "attn_out"                # output projection and residual
+MLP = "mlp"                          # gated feed-forward and residual
+LM_HEAD = "lm_head"                  # final vocab projection
+SAMPLE = "sample"                    # on-device sampling and row freezing
+LOSS = "loss"                        # log-softmax and token nll
+OPTIMIZER = "optimizer"              # update rule and parameter apply
+
+SCOPES = (EMBED, NORM, ATTN_QKV, KV_WRITE, KV_GATHER, PAGED_ATTENTION,
+          CACHED_ATTENTION, ATTENTION, ATTN_OUT, MLP, LM_HEAD, SAMPLE,
+          LOSS, OPTIMIZER)
+
+# Scopes whose ops move cached K/V without computing on it.
+KV_MOVE = (KV_WRITE, KV_GATHER)
+
+# `pallas_call(name=...)`: the name is in the kernel's custom call, so a
+# trace tells the kernels apart.
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+PAGED_KERNEL = "paged_attention"
+
+KERNELS = (PAGED_KERNEL, FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+

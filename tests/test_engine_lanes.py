@@ -1,0 +1,312 @@
+"""The engine's own names in a profiler trace and its per-phase counters
+(PR 24): engine-lane spans on the profiler's clock with no knob, inert and
+allocation-free with no session; scopes and kernel names in the compiled
+programs; `queue_wait + prefill + first_block == ttft` per request;
+`device_wait_s`; the repaired `tpot_s`.
+"""
+
+import functools
+import glob
+import os
+import re
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from ray_tpu.models import LlamaConfig, llama_init  # noqa: E402
+from ray_tpu.models import engine as engine_mod  # noqa: E402
+from ray_tpu.models import engine_trace  # noqa: E402
+from ray_tpu.models.engine import DecodeEngine  # noqa: E402
+from ray_tpu.ops import scope_names as sn  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def nano_model():
+    cfg = LlamaConfig.nano()
+    return cfg, llama_init(jax.random.PRNGKey(0), cfg)
+
+
+def _pool_bytes(cfg, blocks, T=4):
+    from ray_tpu.models.prefix_cache import block_bytes
+    return blocks * block_bytes(cfg.n_layers, T, cfg.n_kv_heads,
+                                cfg.head_dim,
+                                jnp.dtype(cfg.dtype).itemsize)
+
+
+# -- engine lanes in a jax.profiler trace ------------------------------------
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                lines.append([(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                              for e in line.events
+                              if e.name.startswith("eng.")])
+    return [ln for ln in lines if ln]
+
+
+@pytest.mark.parametrize("trace", [None, True], ids=["ring_off", "ring_on"])
+def test_engine_lanes_appear_in_a_profiler_session(nano_model, tmp_path,
+                                                   trace):
+    """No knob: whatever `trace=` says, a running `jax.profiler` session
+    gets `eng.device_wait` and `eng.emit` inside `eng.host_drain`, and the
+    other seams, on the host plane."""
+    cfg, params = nano_model
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32, trace=trace)
+    eng.submit([5, 6, 7], 6)
+    eng.run()                                   # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for i in range(3):
+            eng.submit([5, 6, 7 + i], 6)
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    (events,) = _host_events(str(tmp_path))     # one thread drove it
+    names = {n for n, _, _ in events}
+    assert {"eng.admit", "eng.advance_prefills", "eng.prefill_dispatch",
+            "eng.dispatch", "eng.host_drain", "eng.device_wait",
+            "eng.emit"} <= names
+    drains = [(s, e) for n, s, e in events if n == "eng.host_drain"]
+    for child in ("eng.device_wait", "eng.emit"):
+        kids = [(s, e) for n, s, e in events if n == child]
+        assert len(kids) == len(drains) > 0
+        for s, e in kids:
+            assert any(ds <= s and e <= de for ds, de in drains), child
+    assert (len(eng.trace) > 0) == bool(trace)
+
+
+def test_no_session_the_null_tracer_hands_out_one_inert_span(nano_model):
+    """With no session the helper allocates nothing: the null tracer
+    returns the same shared no-op object every time (the tracemalloc
+    gate in test_perf_gates.py runs the engine over it)."""
+    span = engine_trace.NULL_TRACER.lane("dispatch", "dispatch", horizon=8)
+    assert span is engine_trace.NULL_TRACER.lane("emit", "drain")
+    with span as s:
+        s.note(bytes=1)
+    assert len(engine_trace.NULL_TRACER) == 0
+
+
+def test_ring_keeps_host_drain_as_parent_of_wait_and_emit(nano_model):
+    cfg, params = nano_model
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32, trace=True)
+    eng.submit([5, 6, 7], 6)
+    eng.run()
+    lanes = {}
+    for name, rid, lane, t0, dur, args in eng.trace.events():
+        if rid is None:
+            lanes.setdefault(name, []).append((lane, t0, t0 + dur, args))
+    assert {"admit", "advance_prefills", "prefill_dispatch", "dispatch",
+            "host_drain", "device_wait", "emit"} <= set(lanes)
+    assert {ln for ln, *_ in lanes["device_wait"] + lanes["emit"]
+            + lanes["host_drain"]} == {"drain"}
+    for (_, s, e, args), (_, ws, we, _), (_, es, ee, _) in zip(
+            lanes["host_drain"], lanes["device_wait"], lanes["emit"]):
+        assert s <= ws <= we <= es <= ee <= e
+        assert set(args) == {"horizon", "depth", "bytes"}   # as before
+
+
+# -- counters ----------------------------------------------------------------
+
+def test_device_wait_counts_the_seconds_inside_device_get(
+        nano_model, fake_clock, monkeypatch):
+    cfg, params = nano_model
+    real = engine_mod._device_get
+
+    def slow_get(x):
+        fake_clock.advance(0.25)
+        return real(x)
+
+    monkeypatch.setattr(engine_mod, "_device_get", slow_get)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
+                       clock=fake_clock)
+    eng.submit([5, 6, 7], 9)
+    eng.run()
+    s = eng.stats()
+    assert s["device_waits"] == s["host_syncs"] >= 2
+    assert s["device_wait_s"] == pytest.approx(0.25 * s["device_waits"])
+
+
+@pytest.mark.parametrize("chunk", [None, 4], ids=["unchunked", "chunked"])
+@pytest.mark.parametrize("preempt", ["swap", "recompute"])
+def test_ttft_is_the_sum_of_its_three_parts(nano_model, fake_clock, chunk,
+                                            preempt, monkeypatch):
+    """Per request, on the engine's clock: queue wait + prefill + first
+    block == TTFT exactly, with a pool so small that rows are preempted."""
+    cfg, params = nano_model
+    real = engine_mod._device_get
+
+    def slow_get(x):                    # the device takes its time
+        fake_clock.advance(0.02)
+        return real(x)
+
+    monkeypatch.setattr(engine_mod, "_device_get", slow_get)
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=32, paged=True,
+                       kv_block_tokens=4, prefill_chunk=chunk,
+                       preempt=preempt,
+                       kv_pool_bytes=_pool_bytes(cfg, 10),
+                       prefix_cache=False, clock=fake_clock)
+    m = eng.metrics
+    per_request = []
+    closing = m._on_first_token
+
+    def spy(rt, now):
+        closing(rt, now)
+        per_request.append((rt.admit_t - rt.submit_t, m.prefill_s._ring[-1],
+                            m.first_block_s._ring[-1], m.ttft_s._ring[-1]))
+
+    m._on_first_token = spy
+    prompts = [[7, 8, 9, 10, 11, 12, 13], [3, 1, 4, 1, 5], [2, 7, 1, 8, 2],
+               [9, 9, 8, 8, 7, 7], [1, 2, 3], [4, 5, 6, 7, 8, 9]]
+    for p in prompts:
+        eng.submit(p, 12)
+        fake_clock.advance(0.013)
+    while eng.pending():
+        fake_clock.advance(0.1)
+        eng.step()
+    s = eng.stats()
+    assert s["preemptions"] >= 1
+    assert len(per_request) == len(prompts) == s["ttft_s_count"] \
+        == s["prefill_s_count"] == s["first_block_s_count"] \
+        == s["queue_wait_s_count"]
+    for q, p, f, t in per_request:
+        assert q + p + f == pytest.approx(t, abs=1e-12)
+        assert q >= 0 and p >= 0 and f >= 0
+    assert sum(q for q, *_ in per_request) == pytest.approx(
+        s["queue_wait_s_mean"] * s["queue_wait_s_count"])
+    if chunk:                       # a prompt of two chunks spans steps
+        assert max(p for _, p, _, _ in per_request) > 0
+    assert min(f for _, _, f, _ in per_request) > 0
+
+
+def test_tpot_median_is_the_cadence_not_zero_at_horizon_8(nano_model,
+                                                          fake_clock):
+    """A fused block of n tokens records gap/n for each: same count
+    (tokens - 1) and sum as before, a p50 above 0."""
+    cfg, params = nano_model
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                       decode_horizon=8, clock=fake_clock)
+    eng.submit([5, 6, 7], 33)
+    t_first = t_last = None
+    while eng.pending():
+        fake_clock.advance(0.08)
+        if eng.step():
+            t_first = fake_clock() if t_first is None else t_first
+            t_last = fake_clock()
+    s = eng.stats()
+    assert s["tpot_s_count"] == 32
+    assert s["tpot_s_mean"] * 32 == pytest.approx(t_last - t_first)
+    assert s["tpot_s_p50"] > 0
+    assert s["tpot_s_p50"] == pytest.approx(0.08 / 8)
+
+
+# -- scopes and kernel names in the compiled programs ------------------------
+
+def _op_names(lowered):
+    return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+
+
+def _scopes_in(names):
+    """Scopes among the paths' components; the backward pass wraps a
+    component in what made it: "transpose(jvp(mlp))" is `mlp`."""
+    parts = {part for n in names for part in n.split("/")}
+    parts |= {re.findall(r"\w+", p)[-1] for p in parts if p.endswith("))")
+              or p.startswith("jvp(")}
+    return parts & set(sn.SCOPES)
+
+
+def test_decode_and_prefill_programs_carry_the_scopes(nano_model):
+    cfg, params = nano_model
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32, paged=True,
+                       kv_block_tokens=4)
+    seen = {}
+
+    def spy(name):
+        fn = getattr(engine_mod, name)
+
+        def wrapped(*a, **k):
+            if name not in seen:
+                seen[name] = _op_names(fn.lower(*a, **k))
+            return fn(*a, **k)
+        return wrapped
+
+    mp = pytest.MonkeyPatch()
+    try:
+        for name in ("_decode_multi_paged", "_prefill_rows_paged"):
+            mp.setattr(engine_mod, name, spy(name))
+        eng.submit([5, 6, 7, 8, 9], 4)
+        eng.run()
+    finally:
+        mp.undo()
+    layer = {sn.NORM, sn.ATTN_QKV, sn.KV_WRITE, sn.ATTN_OUT, sn.MLP,
+             sn.EMBED, sn.LM_HEAD}
+    assert layer | {sn.SAMPLE, sn.PAGED_ATTENTION, sn.KV_GATHER} \
+        <= _scopes_in(seen["_decode_multi_paged"])
+    assert layer | {sn.CACHED_ATTENTION, sn.KV_GATHER} \
+        <= _scopes_in(seen["_prefill_rows_paged"])
+    # the names of the jitted programs are what the readers match
+    assert all(n.startswith("jit(_decode_multi_paged)/")
+               for n in seen["_decode_multi_paged"] if "/" in n
+               and n.startswith("jit("))
+
+
+def test_train_step_carries_scopes_and_jax_marks_the_recompute():
+    import optax
+
+    from ray_tpu.models.llama import llama_loss, llama_param_specs
+    from ray_tpu.models.training import make_sharded_train_step
+    from ray_tpu.parallel.mesh import create_mesh
+
+    cfg = LlamaConfig.nano(remat=True, remat_policy="full", loss_chunk=16,
+                           max_seq_len=64)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    init_fn, step_fn = make_sharded_train_step(
+        functools.partial(llama_loss, cfg=cfg), optax.adamw(1e-3),
+        create_mesh({"fsdp": 1}, devices=jax.devices()[:1]),
+        llama_param_specs(cfg))
+    p, o = init_fn(params)
+    names = _op_names(step_fn.lower(
+        p, o, {"tokens": jnp.zeros((2, 65), jnp.int32)}))
+    assert all(n.startswith("jit(step_fn)/") for n in names
+               if n.startswith("jit("))
+    assert {sn.EMBED, sn.NORM, sn.ATTN_QKV, sn.ATTENTION, sn.ATTN_OUT,
+            sn.MLP, sn.LM_HEAD, sn.LOSS, sn.OPTIMIZER} <= _scopes_in(names)
+    again = [n for n in names if "/rematted_computation/" in n]
+    assert again and _scopes_in(again) >= {sn.MLP, sn.ATTN_QKV}
+    # the backward pass wraps a scope in what made it
+    assert any("transpose(jvp(norm))" in n for n in names)
+
+
+@pytest.mark.parametrize("kernel", [sn.FLASH_FWD, sn.FLASH_BWD_DQ,
+                                    sn.FLASH_BWD_DKV, sn.PAGED_KERNEL])
+def test_every_pallas_kernel_has_its_name(kernel):
+    """`pallas_call(name=)` puts the kernel's name into the lowered
+    program (the scope of its call and the custom call's kernel name)."""
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.paged_attention_kernel import paged_attention_kernel
+
+    if kernel == sn.PAGED_KERNEL:
+        q = jnp.zeros((2, 1, 4, 16), jnp.float32)
+        pages = jnp.zeros((6, 4, 2, 16), jnp.float32)
+        text = jax.jit(functools.partial(
+            paged_attention_kernel, kv_valid_len=8, interpret=True)).lower(
+                q, pages, pages, jnp.zeros((2, 2), jnp.int32),
+                jnp.zeros((2, 1), jnp.int32)).as_text(debug_info=True)
+    else:
+        x = jnp.zeros((1, 2, 128, 16), jnp.float32)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, block_q=64,
+                                   block_k=64, interpret=True).sum()
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).as_text(debug_info=True)
+    assert re.search(r'"[^"]*\b%s\b[^"]*"' % kernel, text), kernel

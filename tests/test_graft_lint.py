@@ -177,6 +177,27 @@ class TestTraceGuard:
         """)
         assert _open(findings, "trace-guard") == []
 
+    def test_negative_lane_helper_is_unguarded_by_design(self):
+        findings = _lint("""
+            class E:
+                def drain(self, entry):
+                    with self.trace.lane("host_drain", "drain",
+                                         horizon=entry.H) as span:
+                        with self.trace.lane("device_wait", "drain"):
+                            block = entry.toks
+                        span.note(bytes=block.nbytes)
+        """)
+        assert _open(findings, "trace-guard") == []
+
+    def test_positive_ring_call_inside_a_lane_still_needs_its_guard(self):
+        findings = _lint("""
+            class E:
+                def drain(self, t0):
+                    with self.trace.lane("host_drain", "drain"):
+                        self.trace.add("spec_verify", t0, 1.0, lane="drain")
+        """)
+        assert len(_open(findings, "trace-guard")) == 1
+
     def test_suppressed(self):
         findings = _lint("""
             class E:

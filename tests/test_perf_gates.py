@@ -245,6 +245,10 @@ def test_gate_null_tracer_zero_allocations_on_decode_path():
     params = llama_init(jax.random.PRNGKey(0), cfg)
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32)
     assert eng.trace.enabled is False
+    # The engine-lane helper is the one call site with no guard: with no
+    # profiler session it must hand out a shared object, not build one.
+    assert eng.trace.lane("dispatch", "dispatch", horizon=8, rows=2) \
+        is eng.trace.lane("emit", "drain")
     eng.submit([5, 6, 7], 4)
     eng.run()                        # compile outside the window
 
