@@ -1779,6 +1779,11 @@ class DecodeEngine:
         self.swap_outs = 0             # swap-mode spills to host
         self.swap_in_bytes = 0         # host->device swap traffic
         self.swap_out_bytes = 0        # device->host swap traffic
+        # What the paged kernel has to walk against what its table
+        # holds, per fused decode dispatch (host estimate, see
+        # `_count_paged_walk`).
+        self.paged_walk_pages_total = 0    # live pages, active rows
+        self.paged_walk_entries_total = 0  # B * MB per decode token
         # Disaggregated prefill/decode plane (plain ints; identically
         # zero on a colocated engine so fleet rollups sum blindly).
         # `prefill_only` is set by the fleet on prefill-class replicas:
@@ -2655,6 +2660,7 @@ class DecodeEngine:
             else:
                 adapters = row_slot = None
             if self.paged:
+                self._count_paged_walk(H, rows)
                 # Snapshot the block table at dispatch: jnp.asarray copies
                 # it to device, so host-side growth between chained
                 # dispatches only reaches FUTURE dispatches (in-flight
@@ -2691,6 +2697,21 @@ class DecodeEngine:
                                             chain=(rl, ac, bu, ti)))
             self.decode_dispatches += 1
             self.metrics.on_dispatch(H, host_syncs=0)
+
+    def _count_paged_walk(self, H: int, rows: List[int]) -> None:
+        """Account one fused decode dispatch of `H` tokens over the
+        active `rows`: token h of row b queries slot ``row_len[b] +
+        inflight + h``, so the kernel walks that slot's page and every
+        page before it, of the ``B * MB`` entries the table holds.
+        From the host's replayed `row_len` plus what the ring already
+        carries — pessimistic like the block reservation: a row that
+        finishes mid-flight freezes on device and walks less."""
+        T = self.kv_block_tokens
+        inflight = sum(e.H for e in self._ring)
+        slots = (self.row_len[rows] + inflight)[:, None] + np.arange(H)
+        self.paged_walk_pages_total += int(
+            np.minimum(slots // T + 1, self._mb).sum())
+        self.paged_walk_entries_total += H * self.B * self._mb
 
     def _top_up_pipeline(self, rows: List[int],
                          horizon: Optional[int]) -> None:
@@ -2905,6 +2926,9 @@ class DecodeEngine:
         out["swap_in_bytes"] = float(self.swap_in_bytes)
         out["swap_out_bytes"] = float(self.swap_out_bytes)
         out["kv_used_fraction"] = self.kv_used_fraction()
+        out["paged_walk_pages_total"] = float(self.paged_walk_pages_total)
+        out["paged_walk_entries_total"] = float(
+            self.paged_walk_entries_total)
         # Disaggregated-handoff plane: identically 0.0 on a colocated
         # engine (prefill_only never set, import never called) so
         # fleet rollups sum blindly.

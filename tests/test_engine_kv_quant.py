@@ -465,3 +465,133 @@ def test_paged_attention_impl_dispatch_seam():
     with pytest.raises(ValueError, match="heads"):
         paged_attention(jnp.zeros((2, 1, 3, D)), kf, vf, bt, q_slots,
                         **kw)
+
+
+# ---------------------------------------------------------------------------
+# The walk follows each row's length (live_pages, ragged rows, poison)
+# ---------------------------------------------------------------------------
+
+from ray_tpu.ops import paged_attention_kernel as pak  # noqa: E402
+
+_RT, _RMB = 4, 6            # ragged sweep: pages of 4, tables of 6
+# first query slot of each row: length 1; one slot short of a page
+# boundary; the page's last slot; the next page's first; the table's
+# last S slots (set per S below); a retired row (table all block 0)
+_RAGGED_STARTS = [0, 2 * _RT - 2, 2 * _RT - 1, 2 * _RT, None, 0]
+
+
+def _ragged_case(S, quant, rng, poison=False):
+    """q, pools, table, slots, scales for the ragged rows above, every
+    table entry of a live row a distinct real block, so that an entry
+    past the live prefix is readable garbage (or NaN with `poison`)."""
+    B, KV, D, gm = len(_RAGGED_STARTS), 2, 16, 2
+    span = _RT * _RMB
+    starts = [span - S if s is None else s for s in _RAGGED_STARTS]
+    q_slots = np.asarray(starts)[:, None] + np.arange(S)[None]
+    NB = 1 + B * _RMB
+    kf = rng.randn(NB, _RT, KV, D).astype(np.float32)
+    vf = rng.randn(NB, _RT, KV, D).astype(np.float32)
+    bt = 1 + np.arange(B * _RMB).reshape(B, _RMB)
+    bt[-1] = 0                                       # the retired row
+    q = jnp.asarray(rng.randn(B, S, KV * gm, D), jnp.float32)
+    sk = sv = None
+    kf, vf = jnp.asarray(kf), jnp.asarray(vf)
+    if quant is not None:
+        qspec = resolve_kv_quant(quant)
+        sk = block_scale(jnp.max(jnp.abs(kf), axis=(1, 3)), qspec)
+        sv = block_scale(jnp.max(jnp.abs(vf), axis=(1, 3)), qspec)
+        kf = quantize(kf, sk[:, None, :, None], qspec)
+        vf = quantize(vf, sv[:, None, :, None], qspec)
+    if poison:
+        live = q_slots.max(axis=1) // _RT + 1
+        dead = np.concatenate([bt[b, live[b]:] for b in range(B - 1)])
+        kf = kf.at[dead].set(jnp.nan)
+        vf = vf.at[dead].set(jnp.nan)
+    return (q, kf, vf, jnp.asarray(bt, jnp.int32),
+            jnp.asarray(q_slots, jnp.int32), sk, sv)
+
+
+def test_live_pages_is_the_prefix_a_query_may_see():
+    q_slots = jnp.asarray([[0, 0], [5, 6], [7, 8], [23, 40], [2, 1]])
+    # by slot: 1, 2, 3, 11 -> capped at the table's 6, 1; by length 24: 6
+    assert pak.live_pages(q_slots, 24, 4, 6).tolist() == [1, 2, 3, 6, 1]
+    # kv_valid_len 10 = 2.5 pages: no row walks more than 3
+    assert pak.live_pages(q_slots, 10, 4, 6).tolist() == [1, 2, 3, 3, 1]
+    assert pak.live_pages(q_slots, 0, 4, 6).tolist() == [0] * 5
+
+
+@pytest.mark.parametrize("keys_per_step", [8, 512],
+                         ids=["steps_of_2_pages", "one_step"])
+@pytest.mark.parametrize("slots", [1, 4], ids=["s1", "s4_straddling"])
+@pytest.mark.parametrize("quant", [None, "int8", "fp8_e4m3"],
+                         ids=["dense", "int8", "fp8"])
+def test_kernel_ragged_rows_match_reference_and_full_walk(
+        monkeypatch, quant, slots, keys_per_step):
+    """Rows of every kind in ONE call, against the reference; and the
+    walk that stops at each row's live prefix gives the bits of a walk
+    over all MB entries (a fully masked step changes nothing)."""
+    monkeypatch.setattr(pak, "_KEYS_PER_STEP", keys_per_step)
+    rng = np.random.RandomState(17 + slots)
+    q, kf, vf, bt, q_slots, sk, sv = _ragged_case(slots, quant, rng)
+    for valid in (_RT * _RMB, 10):        # 10: below some rows' slots
+        kw = dict(kv_valid_len=valid, k_scale=sk, v_scale=sv)
+        ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference",
+                              **kw)
+        got = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        full = pak._walk(q, kf, vf, bt, q_slots,
+                         jnp.full((q.shape[0],), _RMB, jnp.int32),
+                         sm_scale=None, interpret=True, **kw)
+        assert jnp.array_equal(got, full)
+
+
+@pytest.mark.parametrize("keys_per_step", [8, 512],
+                         ids=["steps_of_2_pages", "one_step"])
+@pytest.mark.parametrize("slots", [1, 4], ids=["s1", "s4_straddling"])
+@pytest.mark.parametrize("quant", [None, "fp8_e4m3"],
+                         ids=["dense", "fp8"])
+def test_kernel_never_reads_past_the_live_prefix(monkeypatch, quant, slots,
+                                                 keys_per_step):
+    """NaN in every page past each row's live prefix: the output is the
+    clean pool's, bit for bit — such a page is not fetched, and what the
+    buffer holds in its place is zeroed before the matmul sees it."""
+    monkeypatch.setattr(pak, "_KEYS_PER_STEP", keys_per_step)
+    outs = []
+    for poison in (False, True):
+        rng = np.random.RandomState(23 + slots)
+        q, kf, vf, bt, q_slots, sk, sv = _ragged_case(slots, quant, rng,
+                                                      poison=poison)
+        outs.append(paged_attention(
+            q, kf, vf, bt, q_slots, impl="flash",
+            kv_valid_len=_RT * _RMB, k_scale=sk, v_scale=sv))
+    assert bool(jnp.isfinite(outs[1]).all())
+    assert jnp.array_equal(outs[0], outs[1])
+
+
+@pytest.mark.parametrize("lengths", [
+    [(3, 5), (6, 2)],            # inside a page, and across a boundary
+    [(9, 7), (4, 7), (1, 3)],    # three requests on two slots
+], ids=["two", "three_on_two_slots"])
+def test_paged_walk_counters_match_the_hand_count(nano_model, lengths):
+    """`paged_walk_pages_total / paged_walk_entries_total` is the share
+    of table entries the kernel has to walk: per decode token of a
+    request at prompt length L, token i queries slot L + i and walks
+    (L + i) // T + 1 pages, of B * MB entries per dispatched token."""
+    cfg, params = nano_model
+    B = 2
+    eng = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN,
+                       paged=True, kv_block_tokens=T, pipeline_depth=1)
+    rng = np.random.RandomState(5)
+    for L, n in lengths:
+        eng.submit(rng.randint(1, cfg.vocab_size, size=L).tolist(), n)
+    while eng.pending():
+        eng.step(horizon=1)
+    s = eng.stats()
+    pages = sum((L + i) // T + 1 for L, n in lengths for i in range(n))
+    assert s["paged_walk_pages_total"] == pages
+    assert s["paged_walk_entries_total"] == \
+        s["decode_dispatches"] * B * (MAX_LEN // T)
+    assert 0 < pages / s["paged_walk_entries_total"] < 1
+    dense = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN)
+    assert dense.stats()["paged_walk_entries_total"] == 0.0

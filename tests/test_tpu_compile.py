@@ -53,13 +53,8 @@ def _compiles_with_kernel(fn, *args) -> bool:
     return "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("quant", [None, jnp.int8], ids=["bf16", "int8"])
-@pytest.mark.parametrize("block_tokens", [16, 128])
-def test_paged_kernel_compiles_at_llama3_8b_widths(v5e, block_tokens,
-                                                   quant):
-    B, S, H, KV, D = 8, 1, 32, 8, 128
-    T, MB = block_tokens, 2048 // block_tokens
-    NB = B * MB + 1
+def _paged_kernel_compiles(v5e, B, S, T, MB, NB, quant) -> bool:
+    H, KV, D = 32, 8, 128
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -76,7 +71,26 @@ def test_paged_kernel_compiles_at_llama3_8b_widths(v5e, block_tokens,
             q, k, v, bt, slots, kv_valid_len=MB * T, k_scale=k_scale,
             v_scale=v_scale, interpret=False)
 
-    assert _compiles_with_kernel(fn, *args)
+    return _compiles_with_kernel(fn, *args)
+
+
+@pytest.mark.parametrize("quant", [None, jnp.int8], ids=["bf16", "int8"])
+@pytest.mark.parametrize("block_tokens", [16, 128])
+def test_paged_kernel_compiles_at_llama3_8b_widths(v5e, block_tokens,
+                                                   quant):
+    MB = 2048 // block_tokens
+    assert _paged_kernel_compiles(v5e, 8, 1, block_tokens, MB, 8 * MB + 1,
+                                  quant)
+
+
+@pytest.mark.parametrize("quant", [None, jnp.int8, jnp.float8_e4m3fn],
+                         ids=["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("slots", [1, 4], ids=["decode", "spec4"])
+def test_paged_kernel_compiles_at_benchmark_shape(v5e, slots, quant):
+    """`benchmark/configs/mistral-7b-v0.3-serve.json` as the engine runs
+    it: 32 rows, 128 table entries of 32 tokens, a pool of 1,878 blocks,
+    8 KV heads of 128; and the speculative verify's 4 query slots."""
+    assert _paged_kernel_compiles(v5e, 32, slots, 32, 128, 1878, quant)
 
 
 def _flash_args(v5e, b, h, hkv, s, d):
