@@ -25,6 +25,7 @@ from ray_tpu.models.vit import (
 from ray_tpu.models.moe import (
     MoeConfig,
     moe_init,
+    moe_ffn_dropless,
     moe_forward,
     moe_loss,
     moe_param_specs,
@@ -93,6 +94,7 @@ __all__ = [
     "mlp_forward",
     "MoeConfig",
     "moe_init",
+    "moe_ffn_dropless",
     "moe_forward",
     "moe_loss",
     "moe_param_specs",

@@ -93,6 +93,7 @@ from ray_tpu.models.generate import (_check_sampling_knobs,
                                      init_cache, sample_rows)
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
+from ray_tpu.models.moe import MoeConfig
 from ray_tpu.models.prefix_cache import PrefixCacheIndex, block_bytes
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention, spmd_mesh_scope
@@ -208,6 +209,56 @@ class _EngineShardings:
 # Compiled programs
 # ---------------------------------------------------------------------------
 
+# An `MoeConfig` engine keeps its expert-layer counters on the device,
+# int32 [4] that wrap: (live assignments, token-expert rows computed,
+# experts hit in decode, expert-layer runs in decode). Prefill and
+# decode programs add to them over LIVE rows only; a decode program
+# also appends them to its token block as `_MOE_CTR_ROWS` more rows, so
+# they reach the host in the pull that fetches the tokens anyway. A
+# dense model passes None everywhere: no leaves, the same programs.
+_MOE_CTR_ROWS = 4
+
+
+def _moe_count(moe_ctr, layer_stats):
+    """One decode iteration's per-layer counts [L, 3] into the [4]
+    counters; the fourth goes up by L, the expert layers that ran."""
+    return moe_ctr + jnp.concatenate(
+        [layer_stats.sum(axis=0),
+         jnp.full((1,), layer_stats.shape[0], jnp.int32)])
+
+
+def _append_moe_ctr(toks, moe_ctr):
+    """[H, B] token block -> [H + _MOE_CTR_ROWS, B]: counter i fills
+    row H + i, so the drain's one transfer carries both."""
+    return jnp.concatenate(
+        [toks, jnp.broadcast_to(moe_ctr[:, None],
+                                (_MOE_CTR_ROWS, toks.shape[1]))])
+
+
+def _forward_rows_counted(params, prompts, row_cache, starts, cfg,
+                          adapters, row_slot, moe_ctr, rows, last_idx):
+    """`forward_cached_rows` for the prefill programs, adding the chunk's
+    expert-layer counts to ``moe_ctr`` when the engine carries one. Live
+    are positions up to each row's last real token; a group's padding
+    rows repeat the last admission verbatim and count once."""
+    if moe_ctr is None:
+        logits, row_cache = forward_cached_rows(
+            params, prompts, row_cache, starts, cfg, adapters=adapters,
+            row_slot=row_slot)
+        return logits, row_cache, None
+    n, s = prompts.shape
+    with jax.named_scope(sn.MOE_ROUTER):
+        earlier = jnp.arange(n)[None, :] < jnp.arange(n)[:, None]
+        repeat = jnp.any((rows[:, None] == rows[None, :]) & earlier,
+                         axis=1)
+        live = (jnp.arange(s)[None, :] <= last_idx[:, None]) \
+            & ~repeat[:, None]
+    logits, row_cache, st = forward_cached_rows(
+        params, prompts, row_cache, starts, cfg, adapters=adapters,
+        row_slot=row_slot, moe_live=live)
+    return logits, row_cache, moe_ctr.at[:2].add(st[:2])
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "shardings"),
                    donate_argnames=("cache", "last_logits"))
 def _prefill_rows(params: Params, prompts: jax.Array, cache,
@@ -215,7 +266,8 @@ def _prefill_rows(params: Params, prompts: jax.Array, cache,
                   last_idx: jax.Array, cfg: LlamaConfig,
                   shardings: Optional[_EngineShardings] = None,
                   adapters: Optional[Params] = None,
-                  row_slot: Optional[jax.Array] = None):
+                  row_slot: Optional[jax.Array] = None,
+                  moe_ctr: Optional[jax.Array] = None):
     """Batched admission/continuation prefill: write N same-bucket
     chunks' [N, Cb] K/V into N slots in ONE program — each row at its
     OWN cache offset ``starts[n]`` (0 for a cold admission; the cached
@@ -241,13 +293,14 @@ def _prefill_rows(params: Params, prompts: jax.Array, cache,
     chunk's PER-CHUNK slot lane [N], gathered from the engine's [B]
     lane at the dispatch site) thread to `_layer_body`'s per-row
     deltas; None (the default) adds no pytree leaves, so adapter-less
-    engines trace the exact pre-LoRA program."""
+    engines trace the exact pre-LoRA program. ``moe_ctr`` (an
+    `MoeConfig` engine's device-resident expert-layer counters, see
+    `_moe_count`) works the same way: None for a dense model."""
     with jax.named_scope(sn.KV_GATHER):
         row_cache = {"k": cache["k"][:, rows], "v": cache["v"][:, rows]}
-    logits, row_cache = forward_cached_rows(params, prompts, row_cache,
-                                            starts, cfg,
-                                            adapters=adapters,
-                                            row_slot=row_slot)
+    logits, row_cache, moe_ctr = _forward_rows_counted(
+        params, prompts, row_cache, starts, cfg, adapters, row_slot,
+        moe_ctr, rows, last_idx)
     with jax.named_scope(sn.KV_WRITE):
         cache = {
             "k": cache["k"].at[:, rows].set(row_cache["k"]),
@@ -262,7 +315,7 @@ def _prefill_rows(params: Params, prompts: jax.Array, cache,
         cache = jax.lax.with_sharding_constraint(cache, shardings.cache)
         out_logits = jax.lax.with_sharding_constraint(
             out_logits, shardings.logits)
-    return cache, out_logits
+    return cache, out_logits, moe_ctr
 
 
 @functools.partial(jax.jit,
@@ -358,7 +411,8 @@ def _prefix_copy_out(cache_k, cache_v, pool_k, pool_v, row,
 
 
 def _decode_layer_rows(h, layer, k_cache, v_cache, write_slots,
-                       cfg: LlamaConfig, lora=None, lora_slots=None):
+                       cfg: LlamaConfig, lora=None, lora_slots=None,
+                       moe_live=None):
     """One decoder layer, one new token per row, each row writing its
     K/V at its own slot (scatter) and attending its own prefix.
 
@@ -384,18 +438,22 @@ def _decode_layer_rows(h, layer, k_cache, v_cache, write_slots,
     return _layer_body(h, layer, k_cache, v_cache,
                        write_slots[:, None], write_kv,
                        write_slots[:, None], k_cache.shape[1], cfg,
-                       lora=lora, lora_slots=lora_slots)
+                       lora=lora, lora_slots=lora_slots,
+                       moe_live=moe_live)
 
 
 def _decode_core(params: Params, toks: jax.Array, cache, row_len,
-                 cfg: LlamaConfig, adapters=None, row_slot=None):
+                 cfg: LlamaConfig, adapters=None, row_slot=None,
+                 moe_live=None):
     """One decode step for ALL slots: row b's token `toks[b]` is
     written at slot `row_len[b]` and attends slots [0, row_len[b]].
     Dead/frozen rows compute discarded garbage at their frontier slot —
     it lands one past their real tokens (or at slot 0 for empty rows)
     and is overwritten by the next occupant's prefill, with every mask
     excluding it meanwhile. Returns (next-token logits [B, vocab] f32,
-    cache). Plain function so `_decode_multi`'s scan can inline it."""
+    cache, the expert layers' per-layer counts [L, 3] or None: see
+    `_moe_count`). Plain function so `_decode_multi`'s scan can inline
+    it."""
     write_slots = row_len                                   # [B]
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(cfg.dtype)[toks[:, None]]
@@ -407,21 +465,21 @@ def _decode_core(params: Params, toks: jax.Array, cache, row_len,
             lora = None
         else:
             layer, k_c, v_c, lora = xs
-        h, k_c, v_c = _decode_layer_rows(h, layer, k_c, v_c,
-                                         write_slots, cfg, lora=lora,
-                                         lora_slots=row_slot)
-        return h, (k_c, v_c)
+        h, k_c, v_c, st = _decode_layer_rows(
+            h, layer, k_c, v_c, write_slots, cfg, lora=lora,
+            lora_slots=row_slot, moe_live=moe_live)
+        return h, (k_c, v_c, st)
 
     xs = (params["layers"], cache["k"], cache["v"])
     if adapters is not None:
         xs = xs + (adapters,)
-    h, (k_new, v_new) = jax.lax.scan(body, h, xs)
+    h, (k_new, v_new, moe_stats) = jax.lax.scan(body, h, xs)
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         logits = jnp.einsum("bsd,dv->bsv", h,
                             params["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-    return logits[:, 0], {"k": k_new, "v": v_new}
+    return logits[:, 0], {"k": k_new, "v": v_new}, moe_stats
 
 
 @functools.partial(jax.jit,
@@ -436,7 +494,8 @@ def _decode_multi(params: Params, cache, last_logits, row_len, active,
                   eos_id: Optional[int],
                   shardings: Optional[_EngineShardings] = None,
                   adapters: Optional[Params] = None,
-                  row_slot: Optional[jax.Array] = None):
+                  row_slot: Optional[jax.Array] = None,
+                  moe_ctr: Optional[jax.Array] = None):
     """Fuse `horizon` decode iterations into ONE program: a `lax.scan`
     whose body samples every row's next token ON DEVICE from the
     carried `last_logits` (greedy argmax, or per-row rng streams — see
@@ -476,7 +535,8 @@ def _decode_multi(params: Params, cache, last_logits, row_len, active,
     max_len = cache["k"].shape[2]
 
     def body(carry, _):
-        cache, last_logits, row_len, active, budget, tok_idx = carry
+        cache, last_logits, row_len, active, budget, tok_idx, \
+            moe_ctr = carry
         with jax.named_scope(sn.SAMPLE):
             tok = sample_rows(last_logits, row_keys, tok_idx,
                               greedy=greedy, temperature=temperature,
@@ -494,9 +554,12 @@ def _decode_multi(params: Params, cache, last_logits, row_len, active,
             if eos_id is not None:
                 done_now = done_now | (tok == eos_id)
             cont = active & ~done_now
-        logits, cache = _decode_core(params, tok, cache, row_len, cfg,
-                                     adapters=adapters,
-                                     row_slot=row_slot)
+        logits, cache, moe_stats = _decode_core(
+            params, tok, cache, row_len, cfg, adapters=adapters,
+            row_slot=row_slot,
+            moe_live=None if moe_ctr is None else cont[:, None])
+        if moe_ctr is not None:
+            moe_ctr = _moe_count(moe_ctr, moe_stats)
         with jax.named_scope(sn.SAMPLE):
             row_len = row_len + cont.astype(jnp.int32)
             last_logits = jnp.where(cont[:, None], logits, last_logits)
@@ -511,20 +574,23 @@ def _decode_multi(params: Params, cache, last_logits, row_len, active,
             last_logits = jax.lax.with_sharding_constraint(
                 last_logits, shardings.logits)
         return (cache, last_logits, row_len, cont, budget,
-                tok_idx), emit
+                tok_idx, moe_ctr), emit
 
-    (cache, last_logits, row_len, active, budget, tok_idx), toks = \
-        jax.lax.scan(
-            body, (cache, last_logits, row_len, active, budget,
-                   tok_idx),
-            None, length=horizon)
+    (cache, last_logits, row_len, active, budget, tok_idx,
+     moe_ctr), toks = jax.lax.scan(
+        body, (cache, last_logits, row_len, active, budget, tok_idx,
+               moe_ctr),
+        None, length=horizon)
+    if moe_ctr is not None:
+        toks = _append_moe_ctr(toks, moe_ctr)
     if shardings is not None:
         # The [H, B] block is the ONE device->host transfer: keep it
         # fully replicated so the drain reads whole from any chip —
         # host-sync bytes stay 4*H*B regardless of tp degree.
         toks = jax.lax.with_sharding_constraint(
             toks, shardings.replicated)
-    return toks, cache, last_logits, row_len, active, budget, tok_idx
+    return toks, cache, last_logits, row_len, active, budget, tok_idx, \
+        moe_ctr
 
 
 def _spec_accept(chunk, proposals, ver, v_logits, last_logits, row_len,
@@ -708,7 +774,8 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
                         adapters: Optional[Params] = None,
                         row_slot: Optional[jax.Array] = None,
                         scale_k=None, scale_v=None,
-                        qspec: Optional[KVQuantSpec] = None):
+                        qspec: Optional[KVQuantSpec] = None,
+                        moe_ctr: Optional[jax.Array] = None):
     """`_prefill_rows` for the block pool: gather each admission row's
     full [max_len] view through its block table, run the SAME
     `forward_cached_rows` math, scatter the view back block-by-block.
@@ -757,10 +824,9 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
             "k": blk_k.reshape(L, N, MB * T, *blk_k.shape[4:]),
             "v": blk_v.reshape(L, N, MB * T, *blk_v.shape[4:]),
         }
-    logits, row_cache = forward_cached_rows(params, prompts, row_cache,
-                                            starts, cfg,
-                                            adapters=adapters,
-                                            row_slot=row_slot)
+    logits, row_cache, moe_ctr = _forward_rows_counted(
+        params, prompts, row_cache, starts, cfg, adapters, row_slot,
+        moe_ctr, rows, last_idx)
     with jax.named_scope(sn.KV_WRITE):
         k = row_cache["k"].reshape(L, N, MB, T, *blk_k.shape[4:])
         v = row_cache["v"].reshape(L, N, MB, T, *blk_v.shape[4:])
@@ -796,13 +862,14 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
                 scale_v, shardings.scale)
         out_logits = jax.lax.with_sharding_constraint(
             out_logits, shardings.logits)
-    return pool_k, pool_v, scale_k, scale_v, out_logits
+    return pool_k, pool_v, scale_k, scale_v, out_logits, moe_ctr
 
 
 def _decode_layer_rows_paged(h, layer, k_pages, v_pages, bt,
                              write_slots, cfg: LlamaConfig,
                              lora=None, lora_slots=None,
-                             qspec: Optional[KVQuantSpec] = None):
+                             qspec: Optional[KVQuantSpec] = None,
+                             moe_live=None):
     """`_decode_layer_rows` against the pool: row b's new K/V scatter
     into physical block ``bt[b, slot//T]`` at offset ``slot%T`` and
     attention reads back through `ops.attention.paged_attention` (the
@@ -855,13 +922,15 @@ def _decode_layer_rows_paged(h, layer, k_pages, v_pages, bt,
 
     return _layer_body(h, layer, k_pages, v_pages, write_slots[:, None],
                        write_kv, write_slots[:, None], span, cfg,
-                       attend=attend, lora=lora, lora_slots=lora_slots)
+                       attend=attend, lora=lora, lora_slots=lora_slots,
+                       moe_live=moe_live)
 
 
 def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
                        bt, row_len, cfg: LlamaConfig, adapters=None,
                        row_slot=None, scale_k=None, scale_v=None,
-                       qspec: Optional[KVQuantSpec] = None):
+                       qspec: Optional[KVQuantSpec] = None,
+                       moe_live=None):
     """`_decode_core` over the pool: the layer scan unstacks the pool's
     layer axis exactly as the dense scan unstacks the cache's (the
     quantized scale slabs ride the same scan as two extra xs entries).
@@ -886,14 +955,12 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
             else:
                 layer, k_p, v_p, ks, vs, lora = xs
             kc, vc = (k_p, ks), (v_p, vs)
-        h, kc, vc = _decode_layer_rows_paged(h, layer, kc, vc, bt,
-                                             write_slots, cfg,
-                                             lora=lora,
-                                             lora_slots=row_slot,
-                                             qspec=qspec)
+        h, kc, vc, st = _decode_layer_rows_paged(
+            h, layer, kc, vc, bt, write_slots, cfg, lora=lora,
+            lora_slots=row_slot, qspec=qspec, moe_live=moe_live)
         if qspec is None:
-            return h, (kc, vc)
-        return h, (kc[0], vc[0], kc[1], vc[1])
+            return h, (kc, vc, st)
+        return h, (kc[0], vc[0], kc[1], vc[1], st)
 
     xs = (params["layers"], pool_k, pool_v)
     if qspec is not None:
@@ -902,15 +969,15 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
         xs = xs + (adapters,)
     h, ys = jax.lax.scan(body, h, xs)
     if qspec is None:
-        (k_new, v_new), s_k, s_v = ys, None, None
+        (k_new, v_new, moe_stats), s_k, s_v = ys, None, None
     else:
-        k_new, v_new, s_k, s_v = ys
+        k_new, v_new, s_k, s_v, moe_stats = ys
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         logits = jnp.einsum("bsd,dv->bsv", h,
                             params["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-    return logits[:, 0], k_new, v_new, s_k, s_v
+    return logits[:, 0], k_new, v_new, s_k, s_v, moe_stats
 
 
 @functools.partial(jax.jit,
@@ -930,7 +997,8 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
                         adapters: Optional[Params] = None,
                         row_slot: Optional[jax.Array] = None,
                         scale_k=None, scale_v=None,
-                        qspec: Optional[KVQuantSpec] = None):
+                        qspec: Optional[KVQuantSpec] = None,
+                        moe_ctr: Optional[jax.Array] = None):
     """`_decode_multi` with the pool + block tables standing in for
     the dense cache: identical scan body, identical per-iteration
     transition, identical [H, B] single-transfer contract — only the
@@ -944,7 +1012,7 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
 
     def body(carry, _):
         pool_k, pool_v, scale_k, scale_v, last_logits, row_len, \
-            active, budget, tok_idx = carry
+            active, budget, tok_idx, moe_ctr = carry
         with jax.named_scope(sn.SAMPLE):
             tok = sample_rows(last_logits, row_keys, tok_idx,
                               greedy=greedy, temperature=temperature,
@@ -962,10 +1030,14 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
             if eos_id is not None:
                 done_now = done_now | (tok == eos_id)
             cont = active & ~done_now
-        logits, pool_k, pool_v, scale_k, scale_v = _decode_core_paged(
-            params, tok, pool_k, pool_v, bt, row_len, cfg,
-            adapters=adapters, row_slot=row_slot, scale_k=scale_k,
-            scale_v=scale_v, qspec=qspec)
+        logits, pool_k, pool_v, scale_k, scale_v, moe_stats = \
+            _decode_core_paged(
+                params, tok, pool_k, pool_v, bt, row_len, cfg,
+                adapters=adapters, row_slot=row_slot, scale_k=scale_k,
+                scale_v=scale_v, qspec=qspec,
+                moe_live=None if moe_ctr is None else cont[:, None])
+        if moe_ctr is not None:
+            moe_ctr = _moe_count(moe_ctr, moe_stats)
         with jax.named_scope(sn.SAMPLE):
             row_len = row_len + cont.astype(jnp.int32)
             last_logits = jnp.where(cont[:, None], logits, last_logits)
@@ -982,18 +1054,20 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
             last_logits = jax.lax.with_sharding_constraint(
                 last_logits, shardings.logits)
         return (pool_k, pool_v, scale_k, scale_v, last_logits, row_len,
-                cont, budget, tok_idx), emit
+                cont, budget, tok_idx, moe_ctr), emit
 
     (pool_k, pool_v, scale_k, scale_v, last_logits, row_len, active,
-     budget, tok_idx), toks = jax.lax.scan(
+     budget, tok_idx, moe_ctr), toks = jax.lax.scan(
             body, (pool_k, pool_v, scale_k, scale_v, last_logits,
-                   row_len, active, budget, tok_idx),
+                   row_len, active, budget, tok_idx, moe_ctr),
             None, length=horizon)
+    if moe_ctr is not None:
+        toks = _append_moe_ctr(toks, moe_ctr)
     if shardings is not None:
         toks = jax.lax.with_sharding_constraint(
             toks, shardings.replicated)
     return (toks, pool_k, pool_v, scale_k, scale_v, last_logits,
-            row_len, active, budget, tok_idx)
+            row_len, active, budget, tok_idx, moe_ctr)
 
 
 def _spec_layer_rows_paged(h, layer, k_pages, v_pages, bt, slots,
@@ -1042,7 +1116,7 @@ def _spec_layer_rows_paged(h, layer, k_pages, v_pages, bt, slots,
                                    v_scale=vc[1])
 
     return _layer_body(h, layer, k_pages, v_pages, slots, write_kv,
-                       slots, span, cfg, attend=attend)
+                       slots, span, cfg, attend=attend)[:3]
 
 
 def _spec_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
@@ -1401,6 +1475,19 @@ class DecodeEngine:
     `decode_horizon` once slots are saturated or the queue is empty
     (amortize dispatch overhead) — pass `step(horizon=...)` to pin it.
 
+    Model families: ``cfg`` is a `LlamaConfig` (dense decoder) or an
+    `MoeConfig` (sparse: `moe.moe_ffn_dropless` experts and, with
+    `qk_norm`, OLMoE's q/k norm). The family is read in ONE place,
+    `generate._layer_body`, so both are served by the same programs,
+    scheduler, block pool, KV quantization, prefix cache, preemption and
+    fleet; the KV side does not differ. An `MoeConfig` refuses what
+    reads the dense feed-forward's names (`tp=`/`mesh=`, `lora=` targets
+    on w_gate/w_up/w_down, a `draft_cfg=` of the other family) and adds
+    four counters to `stats()` (`moe_assignments_total`,
+    `moe_rows_computed_total`, `moe_decode_experts_hit_total`,
+    `moe_decode_layer_steps_total`), counted on the device over live
+    rows and carried to the host in the token block's own transfer.
+
     `pipeline_depth` (default 2) bounds the async ring of fused steps
     kept in flight during pure-decode stretches: step N+1 is dispatched
     BEFORE step N's token block is pulled to the host (the block's
@@ -1539,6 +1626,27 @@ class DecodeEngine:
                     "speculative decoding is follow-up work)")
             if max_live_adapters < 1:
                 raise ValueError("max_live_adapters must be >= 1")
+        # A sparse model (`MoeConfig`) is served by the same programs;
+        # what reads the DENSE feed-forward's names is refused here.
+        sparse = isinstance(cfg, MoeConfig)
+        if sparse and (tp is not None or mesh is not None):
+            raise ValueError(
+                "tp=/mesh= cannot serve an MoeConfig: the serving "
+                "sharding rules split the dense 'mlp' width, and the "
+                "expert stacks have no rule yet")
+        if sparse and lora is not None:
+            ffn = sorted(set(lora.targets) & {"w_gate", "w_up", "w_down"})
+            if ffn:
+                raise ValueError(
+                    f"lora= targets {ffn} name the dense feed-forward, "
+                    "which an MoeConfig does not have (attention "
+                    "targets wq/wk/wv/wo are served)")
+        if draft_cfg is not None and \
+                isinstance(draft_cfg, MoeConfig) != sparse:
+            raise ValueError(
+                "draft_cfg= is of another family than the target (one "
+                "is an MoeConfig, one dense): speculative decoding "
+                "serves a draft of the target's own family")
         self.params = params
         self.cfg = cfg
         self.B = batch_slots
@@ -1738,6 +1846,13 @@ class DecodeEngine:
         if self._shardings is not None:
             self._last_logits = jax.device_put(self._last_logits,
                                                self._shardings.logits)
+        # Expert-layer counters (see `_moe_count`): the device's wrapping
+        # int32 [4], what the host last saw of them, and the unwrapped
+        # totals `stats()` reports. None/zeros for a dense model.
+        self._moe_ctr = jnp.zeros((_MOE_CTR_ROWS,), jnp.int32) \
+            if sparse else None
+        self._moe_seen = np.zeros((_MOE_CTR_ROWS,), np.uint32)
+        self._moe_totals = np.zeros((_MOE_CTR_ROWS,), np.int64)
         self.row_len = np.zeros((self.B,), np.int32)   # written slots
         self.row_req: List[Optional[_Request]] = [None] * self.B
         self.row_budget = np.zeros((self.B,), np.int32)
@@ -2674,7 +2789,8 @@ class DecodeEngine:
                 with spmd_mesh_scope(self.mesh):
                     (toks, self._pool_k, self._pool_v, self._scale_k,
                      self._scale_v, self._last_logits,
-                     rl, ac, bu, ti) = _decode_multi_paged(
+                     rl, ac, bu, ti,
+                     self._moe_ctr) = _decode_multi_paged(
                         self.params, self._pool_k, self._pool_v, bt_dev,
                         self._last_logits, *args,
                         jnp.asarray(self._row_keys), rg, self.temperature,
@@ -2682,15 +2798,16 @@ class DecodeEngine:
                         self.eos_id, shardings=self._shardings,
                         adapters=adapters, row_slot=row_slot,
                         scale_k=self._scale_k, scale_v=self._scale_v,
-                        qspec=self.kv_quant_spec)
+                        qspec=self.kv_quant_spec, moe_ctr=self._moe_ctr)
             else:
-                toks, self.cache, self._last_logits, rl, ac, bu, ti = \
-                    _decode_multi(
+                (toks, self.cache, self._last_logits, rl, ac, bu, ti,
+                 self._moe_ctr) = _decode_multi(
                         self.params, self.cache, self._last_logits, *args,
                         jnp.asarray(self._row_keys), rg, self.temperature,
                         self.cfg, H, all_greedy, self.top_k, self.top_p,
                         self.eos_id, shardings=self._shardings,
-                        adapters=adapters, row_slot=row_slot)
+                        adapters=adapters, row_slot=row_slot,
+                        moe_ctr=self._moe_ctr)
             _host_async(toks)
             self._ring.append(_InflightStep(toks, H, list(rows),
                                             run_ahead=chain is not None,
@@ -2785,6 +2902,14 @@ class DecodeEngine:
             drain.note(bytes=nbytes)
             self.host_transfer_bytes += nbytes
             self.metrics.on_host_sync(nbytes=nbytes)
+            if self._moe_ctr is not None and not entry.spec:
+                # the device's wrapping counters ride the block's last
+                # rows: unwrap them into the host's totals
+                seen = block[entry.H:, 0].astype(np.uint32)
+                self._moe_totals += (seen - self._moe_seen).astype(
+                    np.int64)
+                self._moe_seen = seen
+                block = block[:entry.H]
             with tr.lane("emit", "drain"):
                 sp_rounds, sp_prop, sp_acc = self._emit_block(
                     block, entry, emitted)
@@ -2929,6 +3054,15 @@ class DecodeEngine:
         out["paged_walk_pages_total"] = float(self.paged_walk_pages_total)
         out["paged_walk_entries_total"] = float(
             self.paged_walk_entries_total)
+        # Expert-layer plane (an `MoeConfig`; identically 0.0 for a dense
+        # model): counted on the device over live rows, as of the last
+        # token block drained. Speculative rounds are not counted.
+        for name, n in zip(("moe_assignments_total",
+                            "moe_rows_computed_total",
+                            "moe_decode_experts_hit_total",
+                            "moe_decode_layer_steps_total"),
+                           self._moe_totals):
+            out[name] = float(n)
         # Disaggregated-handoff plane: identically 0.0 on a colocated
         # engine (prefill_only never set, import never called) so
         # fleet rollups sum blindly.
@@ -3480,7 +3614,7 @@ class DecodeEngine:
                     bt_grp = self._bt_d[rows]
                     (self._pool_dk, self._pool_dv, self._scale_dk,
                      self._scale_dv,
-                     self._d_last_logits) = _prefill_rows_paged(
+                     self._d_last_logits, _) = _prefill_rows_paged(
                         self.draft_params, jnp.asarray(prompts),
                         self._pool_dk, self._pool_dv, self._d_last_logits,
                         jnp.asarray(bt_grp), jnp.asarray(rows),
@@ -3489,7 +3623,7 @@ class DecodeEngine:
                         scale_k=self._scale_dk, scale_v=self._scale_dv,
                         qspec=self.kv_quant_spec)
                 else:
-                    self._d_cache, self._d_last_logits = _prefill_rows(
+                    self._d_cache, self._d_last_logits, _ = _prefill_rows(
                         self.draft_params, jnp.asarray(prompts),
                         self._d_cache, self._d_last_logits,
                         jnp.asarray(rows), jnp.asarray(starts),
@@ -4057,8 +4191,8 @@ class DecodeEngine:
                     if self.paged:
                         bt_grp = self._bt[rows]            # [n_pad, MB]
                         (self._pool_k, self._pool_v, self._scale_k,
-                         self._scale_v,
-                         self._last_logits) = _prefill_rows_paged(
+                         self._scale_v, self._last_logits,
+                         self._moe_ctr) = _prefill_rows_paged(
                             self.params, jnp.asarray(prompts), self._pool_k,
                             self._pool_v, self._last_logits,
                             jnp.asarray(bt_grp), jnp.asarray(rows),
@@ -4066,14 +4200,17 @@ class DecodeEngine:
                             self.cfg, shardings=self._shardings,
                             adapters=adapters, row_slot=row_slot,
                             scale_k=self._scale_k, scale_v=self._scale_v,
-                            qspec=self.kv_quant_spec)
+                            qspec=self.kv_quant_spec,
+                            moe_ctr=self._moe_ctr)
                     else:
-                        self.cache, self._last_logits = _prefill_rows(
+                        (self.cache, self._last_logits,
+                         self._moe_ctr) = _prefill_rows(
                             self.params, jnp.asarray(prompts), self.cache,
                             self._last_logits, jnp.asarray(rows),
                             jnp.asarray(starts), jnp.asarray(last_idx),
                             self.cfg, shardings=self._shardings,
-                            adapters=adapters, row_slot=row_slot)
+                            adapters=adapters, row_slot=row_slot,
+                            moe_ctr=self._moe_ctr)
                     self.prefill_dispatches += 1
                     padded = n_pad * Cb - real
                     self.prefill_real_tokens += real

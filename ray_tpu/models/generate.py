@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import LlamaConfig, _rmsnorm, _rope
+from ray_tpu.models.moe import MoeConfig, moe_ffn_dropless, qk_norm
 from ray_tpu.ops import scope_names as sn
 
 Params = Dict[str, Any]
@@ -86,7 +87,7 @@ def _lora_delta(x, ab, slots, dt):
 def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
                 q_slots, kv_valid_len, cfg: LlamaConfig,
                 slot_live=None, attend=None, lora=None,
-                lora_slots=None):
+                lora_slots=None, moe_live=None):
     """The decoder-layer math shared by ALL cached decode paths —
     generate.py's contiguous-chunk writes, engine.py's per-row
     scatter writes, and the paged engine's block-pool writes: rmsnorm
@@ -110,8 +111,18 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
     slot; every projection named in the stacks gains a per-row
     `_lora_delta` on top of the shared base matmul. Both are pytree
     leaves of the enclosing jit — lora=None paths trace a program
-    byte-identical to before this feature existed."""
+    byte-identical to before this feature existed.
+
+    The model seam: the two places where a family differs are read from
+    ``cfg`` HERE and nowhere else. An `MoeConfig` with `qk_norm` norms
+    the whole q and k projections ahead of RoPE, and any `MoeConfig`
+    replaces the gated MLP by `moe.moe_ffn_dropless`; a `LlamaConfig`
+    traces exactly what it always did. The KV side is the same for
+    both. ``moe_live`` [B, S] bool (engine programs) marks the rows
+    that are real tokens and asks the expert layer for its counters,
+    the fourth result (None for a dense model or without the mask)."""
     dt = cfg.dtype
+    sparse = isinstance(cfg, MoeConfig)
     x = _rmsnorm(h, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope(sn.ATTN_QKV):
         q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
@@ -127,6 +138,8 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
             if "wv" in lora:
                 v = v + _lora_delta(x, lora["wv"], lora_slots,
                                     dt).reshape(v.shape)
+        if sparse and cfg.qk_norm:
+            q, k = qk_norm(q, k, layer, cfg)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
     with jax.named_scope(sn.KV_WRITE):
@@ -146,6 +159,9 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
                                               lora_slots, dt)
         h = h + attn_out
     x = _rmsnorm(h, layer["mlp_norm"], cfg.norm_eps)
+    if sparse:
+        moe_out, moe_stats = moe_ffn_dropless(x, layer, cfg, moe_live)
+        return h + moe_out, k_cache, v_cache, moe_stats
     with jax.named_scope(sn.MLP):
         gate = jnp.einsum("bsd,df->bsf", x, layer["w_gate"].astype(dt))
         up = jnp.einsum("bsd,df->bsf", x, layer["w_up"].astype(dt))
@@ -162,7 +178,7 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
             mlp_out = mlp_out + _lora_delta(act, lora["w_down"],
                                             lora_slots, dt)
         h = h + mlp_out
-    return h, k_cache, v_cache
+    return h, k_cache, v_cache, None
 
 
 def _cached_layer(h, layer, k_cache, v_cache, positions, slot_ids,
@@ -182,7 +198,8 @@ def _cached_layer(h, layer, k_cache, v_cache, positions, slot_ids,
         return k_cache, v_cache
 
     return _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
-                       slot_ids, kv_valid_len, cfg, slot_live=slot_live)
+                       slot_ids, kv_valid_len, cfg,
+                       slot_live=slot_live)[:3]
 
 
 def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
@@ -226,8 +243,8 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
 def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
                         starts: jax.Array, cfg: LlamaConfig, *,
                         adapters: Optional[Params] = None,
-                        row_slot: Optional[jax.Array] = None
-                        ) -> Tuple[jax.Array, Cache]:
+                        row_slot: Optional[jax.Array] = None,
+                        moe_live: Optional[jax.Array] = None):
     """Run a token chunk [B, S] with a PER-ROW cache offset: row b's
     tokens land at cache slots ``starts[b] + i`` (scatter writes) and
     attend that row's whole prefix ``[0, starts[b] + i]``. Returns
@@ -257,7 +274,12 @@ def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
     layer axis unstacked by the scan) and ``row_slot`` [B] int32 maps
     each row to its adapter slot (0 = base-only). Both absent -> the
     scan carries its original 3-tuple and the traced program is
-    byte-identical to the pre-LoRA path."""
+    byte-identical to the pre-LoRA path.
+
+    ``moe_live`` [B, S] bool (the engine's prefill programs, for an
+    `MoeConfig`): the rows that are real tokens. Given, a third result
+    is returned: the expert layers' counters summed over the layers,
+    int32 [3] (see `moe.moe_ffn_dropless`)."""
     B, S = tokens.shape
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(cfg.dtype)[tokens]
@@ -279,21 +301,25 @@ def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
                 v.astype(v_cache.dtype))
             return k_cache, v_cache
 
-        h, k_c, v_c = _layer_body(h, layer, k_c, v_c, slot_ids,
-                                  write_kv, slot_ids, k_c.shape[1], cfg,
-                                  lora=lora, lora_slots=row_slot)
-        return h, (k_c, v_c)
+        h, k_c, v_c, st = _layer_body(h, layer, k_c, v_c, slot_ids,
+                                      write_kv, slot_ids, k_c.shape[1],
+                                      cfg, lora=lora,
+                                      lora_slots=row_slot,
+                                      moe_live=moe_live)
+        return h, (k_c, v_c, st)
 
     xs = (params["layers"], cache["k"], cache["v"])
     if adapters is not None:
         xs = xs + (adapters,)
-    h, (k_new, v_new) = jax.lax.scan(body, h, xs)
+    h, (k_new, v_new, stats) = jax.lax.scan(body, h, xs)
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         logits = jnp.einsum("bsd,dv->bsv", h,
                             params["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
+    if stats is None:
+        return logits, {"k": k_new, "v": v_new}
+    return logits, {"k": k_new, "v": v_new}, stats.sum(axis=0)
 
 
 def filter_logits(logits: jax.Array, top_k: Optional[int] = None,

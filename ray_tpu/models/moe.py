@@ -1,19 +1,33 @@
-"""Mixture-of-Experts decoder LM (Mixtral-style), TPU-first.
+"""Mixture-of-Experts decoder LM (Mixtral / OLMoE style), TPU-first.
 
 Expert parallelism is a capability the reference lacks entirely
 (SURVEY.md §2.4: "Expert parallel (EP/MoE) — absent"); this module is the
-new-framework original. Design:
+new-framework original. Two expert layers live here, one per job:
 
-- Top-k (default 2) token-choice routing with GShard/Switch-style static
-  capacity: dispatch/combine are one-hot einsums so every shape is static
-  and XLA tiles the expert matmuls onto the MXU — no ragged gather in the
-  hot path. Overflow tokens are dropped (standard capacity semantics);
-  the aux load-balancing loss keeps drop rates low.
-- The expert dimension is a logical axis ("expert") mapped to the `ep`
-  mesh axis: dispatch einsums become XLA all-to-alls over ICI, expert
-  FFN weights shard E-way with zero code changes.
+- SERVING, `moe_ffn_dropless`: what `generate._layer_body` calls for an
+  `MoeConfig`, so every cached path (solo `generate`, the dense and the
+  paged `DecodeEngine`, fleet replicas) serves a sparse model through the
+  programs the dense family uses. Token-choice top-k with NO capacity: no
+  assignment is ever dropped, whatever the routing (all tokens on one
+  expert included), because a served token that loses an expert is a
+  wrong answer. Shapes are static in (rows, chunk), nothing syncs with
+  the host, and it is the body of the layer `lax.scan` and of the fused
+  decode horizon. Two regimes, chosen from the shapes it is traced with:
+  few tokens (decode) multiply every row by every expert, since every
+  expert's weights are read whatever the routing and the extra FLOPs
+  hide under that read; many tokens (prefill) sort the assignments by
+  expert and multiply them as ragged groups (`jax.lax.ragged_dot`), so
+  the work follows the `tokens x top_k` assignments.
+- TRAINING, `_moe_ffn` under `moe_forward`: GShard/Switch-style static
+  capacity, dispatch/combine as one-hot einsums; assignments over an
+  expert's capacity ARE dropped there (the aux load-balancing loss keeps
+  the rate low). The expert dimension is a logical axis ("expert") mapped
+  to the `ep` mesh axis: dispatch einsums become XLA all-to-alls over
+  ICI, expert FFN weights shard E-way with zero code changes.
 - Everything else (attention, RoPE, rmsnorm, scanned layers, remat)
-  reuses the Llama building blocks.
+  reuses the Llama building blocks. `qk_norm` (OLMoE) adds an RMSNorm
+  over the whole q and k projections, before the split into heads and
+  before RoPE; the cached paths apply it in `generate._layer_body`.
 """
 
 from __future__ import annotations
@@ -27,6 +41,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import (LlamaConfig, _attention_call,
                                   _layer_shapes, _rmsnorm, _rope)
+from ray_tpu.ops import scope_names as sn
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_to_mesh
 
 Params = Dict[str, Any]
@@ -36,8 +51,15 @@ Params = Dict[str, Any]
 class MoeConfig(LlamaConfig):
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25       # training dispatch only
     router_aux_coef: float = 0.01
+    # `ffn_dim` is the width of ONE expert. Combine weights are the
+    # router's softmax probabilities of the chosen experts, renormalised
+    # over the chosen k when `norm_topk_prob` (Mixtral) and left as they
+    # are when not (OLMoE).
+    norm_topk_prob: bool = True
+    # RMSNorm over the whole q / k projection ahead of RoPE (OLMoE).
+    qk_norm: bool = False
 
     def __post_init__(self):
         super().__post_init__()
@@ -55,6 +77,18 @@ class MoeConfig(LlamaConfig):
                          n_experts=8, top_k=2, **kw)
 
     @staticmethod
+    def olmoe_1b_7b(**kw) -> "MoeConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct: 64 experts of width 1024,
+        8 a token, un-renormalised weights, q/k norm, MHA."""
+        defaults = dict(vocab_size=50304, dim=2048, n_layers=16,
+                        n_heads=16, n_kv_heads=16, ffn_dim=1024,
+                        n_experts=64, top_k=8, norm_topk_prob=False,
+                        qk_norm=True, rope_theta=10000.0,
+                        max_seq_len=4096)
+        defaults.update(kw)
+        return MoeConfig(**defaults)
+
+    @staticmethod
     def nano_moe(**kw) -> "MoeConfig":
         defaults = dict(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                         n_kv_heads=2, ffn_dim=128, n_experts=4, top_k=2,
@@ -62,22 +96,23 @@ class MoeConfig(LlamaConfig):
         defaults.update(kw)
         return MoeConfig(**defaults)
 
-    def num_params(self) -> int:
-        d, f, e = self.dim, self.ffn_dim, self.n_experts
-        per_layer_attn = d * self.n_heads * self.head_dim * 2 + \
+    def _params_with(self, experts: int) -> int:
+        d, f = self.dim, self.ffn_dim
+        attn = d * self.n_heads * self.head_dim * 2 + \
             d * self.n_kv_heads * self.head_dim * 2
-        per_layer_moe = e * 3 * d * f + d * e  # experts + router
-        return (self.vocab_size * d * 2 +
-                self.n_layers * (per_layer_attn + per_layer_moe))
+        norms = 2 * d
+        if self.qk_norm:
+            norms += (self.n_heads + self.n_kv_heads) * self.head_dim
+        moe = experts * 3 * d * f + d * self.n_experts  # experts + router
+        return (self.vocab_size * d * 2 + d +
+                self.n_layers * (attn + moe + norms))
+
+    def num_params(self) -> int:
+        return self._params_with(self.n_experts)
 
     def active_params(self) -> int:
         """Params touched per token (top-k experts only) — the MFU basis."""
-        d, f = self.dim, self.ffn_dim
-        per_layer_attn = d * self.n_heads * self.head_dim * 2 + \
-            d * self.n_kv_heads * self.head_dim * 2
-        per_layer_moe = self.top_k * 3 * d * f + d * self.n_experts
-        return (self.vocab_size * d * 2 +
-                self.n_layers * (per_layer_attn + per_layer_moe))
+        return self._params_with(self.top_k)
 
 
 def _moe_layer_shapes(cfg: MoeConfig) -> Dict[str, Any]:
@@ -85,6 +120,11 @@ def _moe_layer_shapes(cfg: MoeConfig) -> Dict[str, Any]:
     d, f, e = cfg.dim, cfg.ffn_dim, cfg.n_experts
     shapes = {k: v for k, v in _layer_shapes(cfg).items()
               if not k.startswith("w_")}  # drop dense FFN
+    if cfg.qk_norm:
+        shapes.update({
+            "q_norm": ((cfg.n_heads * cfg.head_dim,), (None,), None),
+            "k_norm": ((cfg.n_kv_heads * cfg.head_dim,), (None,), None),
+        })
     shapes.update({
         "w_router": ((d, e), ("embed", None), d),
         "we_gate": ((e, d, f), ("expert", "embed", "mlp"), d),
@@ -95,6 +135,9 @@ def _moe_layer_shapes(cfg: MoeConfig) -> Dict[str, Any]:
 
 
 def moe_init(rng: jax.Array, cfg: MoeConfig) -> Params:
+    """Stacked-layer param tree (`[L, E, d, f]` expert stacks; the router
+    initialised like every other matrix). Jit it with `cfg` static to
+    build a real-size model on the device in one program."""
     shapes = _moe_layer_shapes(cfg)
     keys = jax.random.split(rng, len(shapes) + 3)
     layers = {}
@@ -139,13 +182,110 @@ def moe_param_specs(cfg: MoeConfig,
             isinstance(e, (str, type(None))) for e in x))
 
 
-def _route_topk(gates: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+def _route_topk(gates: jax.Array, k: int, renorm: bool = True
+                ) -> Tuple[jax.Array, jax.Array]:
     """gates [G,E] -> (weights [G,k], expert_idx [G,k]); weights
-    renormalized over the chosen k."""
+    renormalized over the chosen k when `renorm`."""
     weights, idx = jax.lax.top_k(gates, k)
-    weights = weights / jnp.maximum(
-        weights.sum(-1, keepdims=True), 1e-9)
+    if renorm:
+        weights = weights / jnp.maximum(
+            weights.sum(-1, keepdims=True), 1e-9)
     return weights, idx
+
+
+def qk_norm(q: jax.Array, k: jax.Array, layer: Params, cfg: MoeConfig
+            ) -> Tuple[jax.Array, jax.Array]:
+    """OLMoE's q/k norm: RMSNorm over the WHOLE projection (all heads of
+    a token together), so it comes before the split into heads means
+    anything and before RoPE. q [B,S,H,D], k [B,S,KV,D] -> same."""
+    def norm(x, scale):
+        flat = x.reshape(*x.shape[:2], -1)
+        return _rmsnorm(flat, scale, cfg.norm_eps).reshape(x.shape)
+
+    return norm(q, layer["q_norm"]), norm(k, layer["k_norm"])
+
+
+# Up to this many tokens, every row is multiplied by every expert. The
+# weights of all experts are read from HBM either way once a handful of
+# tokens spread over them, and computing E rows a token instead of top_k
+# hides under that read while tokens < peak FLOP/s over peak bytes/s
+# (about 240 on a v5e, whatever d and f are: bytes and FLOPs of an expert
+# both scale with d * f). Past that it costs compute, but still less than
+# the sort, the gathers and `ragged_dot`'s small groups up to about 700
+# tokens. Measured on a v5e at OLMoE's widths (PR 26, PERF.md section 6),
+# ms a layer, all-experts / sorted: 64 tokens 1.22 / 2.60, 256 1.25 /
+# 2.75, 512 2.30 / 3.03; sorted alone: 2048 4.57, 4096 7.83.
+DENSE_EXPERTS_MAX_TOKENS = 512
+
+
+def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
+                     live: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """The serving expert layer: x [B,S,d] (already normed) -> (sum over
+    each token's top-k experts of p_e * down_e(silu(gate_e x) * up_e x)
+    as [B,S,d], counters). No capacity: every assignment is computed
+    for ANY routing. Rows are independent of one another, so a dead
+    slot's or a padded position's row changes no live row's output.
+
+    ``live`` [B,S] bool marks the rows that are real tokens; given, the
+    second result is int32 [3]: (live assignments = live tokens * top_k,
+    token-expert rows the expert matmuls computed, experts with at least
+    one live assignment). None -> no counters are traced at all."""
+    dt = cfg.dtype
+    b, s, d = x.shape
+    g, e, k = b * s, cfg.n_experts, cfg.top_k
+    xf = x.reshape(g, d)
+    with jax.named_scope(sn.MOE_ROUTER):
+        logits = jnp.einsum("gd,de->ge", xf, layer["w_router"].astype(dt),
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = _route_topk(probs, k, cfg.norm_topk_prob)  # [G,k]
+    w1, w3, w2 = (layer[n].astype(dt)
+                  for n in ("we_gate", "we_up", "we_down"))
+    dense = g <= DENSE_EXPERTS_MAX_TOKENS
+    if dense:
+        with jax.named_scope(sn.MOE_DISPATCH):
+            # [G,E] combine matrix: p_e where token g chose e, else 0
+            combine = jnp.sum(
+                jax.nn.one_hot(idx, e, dtype=jnp.float32)
+                * weights[..., None], axis=1)
+        with jax.named_scope(sn.MOE_EXPERTS):
+            gate = jnp.einsum("gd,edf->gef", xf, w1)
+            up = jnp.einsum("gd,edf->gef", xf, w3)
+            act = jax.nn.silu(gate) * up
+            # the combine folds into the down projection: one
+            # contraction over (expert, f), accumulated in float32
+            out = jnp.einsum(
+                "gef,efd->gd", act * combine[..., None].astype(dt), w2,
+                preferred_element_type=jnp.float32).astype(dt)
+    else:
+        with jax.named_scope(sn.MOE_DISPATCH):
+            flat = idx.reshape(g * k)
+            order = jnp.argsort(flat, stable=True)   # by expert
+            inverse = jnp.argsort(order)
+            group_sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+            xs = xf[order // k]                      # [G*k, d]
+        with jax.named_scope(sn.MOE_EXPERTS):
+            gate = jax.lax.ragged_dot(xs, w1, group_sizes)
+            up = jax.lax.ragged_dot(xs, w3, group_sizes)
+            ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2,
+                                    group_sizes)
+        with jax.named_scope(sn.MOE_DISPATCH):
+            out = jnp.einsum(
+                "gkd,gk->gd", ys[inverse].reshape(g, k, d),
+                weights.astype(dt),
+                preferred_element_type=jnp.float32).astype(dt)
+    stats = None
+    if live is not None:
+        with jax.named_scope(sn.MOE_ROUTER):
+            lv = live.reshape(g)
+            hit = jnp.zeros((e,), bool).at[idx.reshape(-1)].max(
+                jnp.repeat(lv, k))
+            stats = jnp.stack([
+                lv.sum(dtype=jnp.int32) * k,
+                jnp.int32(g * (e if dense else k)),
+                hit.sum(dtype=jnp.int32)])
+    return out.reshape(b, s, d), stats
 
 
 def _moe_ffn(x: jax.Array, layer: Params,
@@ -163,7 +303,7 @@ def _moe_ffn(x: jax.Array, layer: Params,
         "gd,de->ge", xf.astype(jnp.float32),
         layer["w_router"].astype(jnp.float32))
     gates = jax.nn.softmax(router_logits, axis=-1)          # [G,E]
-    weights, expert_idx = _route_topk(gates, k)             # [G,k]
+    weights, expert_idx = _route_topk(gates, k, cfg.norm_topk_prob)
 
     # Position of each (token, choice) within its expert's capacity.
     onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)  # [G,k,E]
@@ -208,6 +348,8 @@ def _moe_decoder_layer(carry, layer: Params, positions: jax.Array,
     q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
     kk = jnp.einsum("bsd,dhk->bshk", x, layer["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", x, layer["wv"].astype(dt))
+    if cfg.qk_norm:
+        q, kk = qk_norm(q, kk, layer, cfg)
     q = _rope(q, positions, cfg.rope_theta)
     kk = _rope(kk, positions, cfg.rope_theta)
     o = _attention_call(q, kk, v, cfg)

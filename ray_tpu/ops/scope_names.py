@@ -22,6 +22,10 @@ CACHED_ATTENTION = "cached_attention"    # attention over a dense cache row
 ATTENTION = "attention"              # uncached causal attention (training)
 ATTN_OUT = "attn_out"                # output projection and residual
 MLP = "mlp"                          # gated feed-forward and residual
+MOE_ROUTER = "moe_router"            # router matmul, softmax, top-k
+MOE_DISPATCH = "moe_dispatch"        # sort, gather, scatter, combine: moves
+#                                      tokens to experts, computes nothing
+MOE_EXPERTS = "moe_experts"          # the three expert matmuls and silu
 LM_HEAD = "lm_head"                  # final vocab projection
 SAMPLE = "sample"                    # on-device sampling and row freezing
 LOSS = "loss"                        # log-softmax and token nll
@@ -29,7 +33,7 @@ OPTIMIZER = "optimizer"              # update rule and parameter apply
 
 SCOPES = (EMBED, NORM, ATTN_QKV, KV_WRITE, KV_GATHER, PAGED_ATTENTION,
           CACHED_ATTENTION, ATTENTION, ATTN_OUT, MLP, LM_HEAD, SAMPLE,
-          LOSS, OPTIMIZER)
+          LOSS, OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS)
 
 # Scopes whose ops move cached K/V without computing on it.
 KV_MOVE = (KV_WRITE, KV_GATHER)

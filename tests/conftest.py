@@ -54,6 +54,21 @@ def fake_clock():
     return FakeClock()
 
 
+@pytest.fixture(scope="session")
+def nano_olmoe():
+    """(cfg, params) of a nano sparse model with OLMoE's layer: q/k norm,
+    8 experts, 2 a token, weights not renormalised, float32. The engine
+    identity tests take it as their second model family."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import MoeConfig, moe_init
+
+    cfg = MoeConfig.nano_moe(n_experts=8, top_k=2, qk_norm=True,
+                             norm_topk_prob=False, dtype=jnp.float32,
+                             remat=False)
+    return cfg, moe_init(jax.random.PRNGKey(0), cfg)
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _fresh_metric_registry():
     """Each test module starts from an empty process-local metric

@@ -37,6 +37,19 @@ def _solo(params, cfg, prompt, n):
 ], ids=["default", "fifo", "priority", "priority+prefill_budget",
         "fifo+bounded_block"])
 def test_engine_matches_solo_generate(nano_model, knobs):
+    _engine_matches_solo_generate(nano_model, knobs)
+
+
+@pytest.mark.parametrize("knobs", [
+    {"scheduler": "priority", "max_prefills_per_step": 1},
+], ids=["priority+prefill_budget"])
+def test_engine_matches_solo_generate_olmoe(nano_olmoe, knobs):
+    """The same contract for the sparse family (experts + q/k norm): the
+    engine serves it through the programs the dense family uses."""
+    _engine_matches_solo_generate(nano_olmoe, knobs)
+
+
+def _engine_matches_solo_generate(nano_model, knobs):
     """More requests than slots, ragged lengths, ragged budgets: every
     request's tokens equal its solo run (slots are reused as earlier
     requests finish) — under EVERY scheduler policy and admission

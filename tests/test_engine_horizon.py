@@ -52,6 +52,16 @@ SAMPLING_MODES = {
 @pytest.mark.parametrize("horizon", [1, 2, 8])
 def test_identity_across_horizons_and_sampling(nano_model, horizon,
                                                mode):
+    _identity_across_horizons(nano_model, horizon, mode)
+
+
+@pytest.mark.parametrize("horizon", [8])
+def test_identity_across_horizons_olmoe(nano_olmoe, horizon):
+    """The sparse family inside the fused horizon's scan, greedy."""
+    _identity_across_horizons(nano_olmoe, horizon, "greedy")
+
+
+def _identity_across_horizons(nano_model, horizon, mode):
     """More requests than slots, ragged budgets: every request matches
     its solo run at EVERY pinned horizon, greedy and sampled alike.
     Sampled requests pin their own rng stream; solo uses the same key —

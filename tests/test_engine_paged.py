@@ -101,6 +101,21 @@ def _pool_bytes(cfg, n_blocks):
     {"tp": 2, "prefix_cache": True},
 ], ids=["plain", "prefix", "prefix_pipeline", "chunked", "tp2"])
 def test_paged_token_identity_matrix(nano_model, mode, features):
+    _paged_token_identity(nano_model, mode, features)
+
+
+@pytest.mark.parametrize("features", [
+    {},
+    {"prefix_cache": True, "pipeline_depth": 2},
+    {"prefill_chunk": 3, "prefix_cache": True},
+], ids=["plain", "prefix_pipeline", "chunked"])
+def test_paged_token_identity_matrix_olmoe(nano_olmoe, features):
+    """The sparse family (experts, q/k norm), greedy: the seam touches
+    nothing on the KV side, so the same identities hold."""
+    _paged_token_identity(nano_olmoe, {"greedy": True}, features)
+
+
+def _paged_token_identity(nano_model, mode, features):
     """Paged == dense == solo generate across the feature matrix.
     Shared-prefix prompts drive refcounted block sharing under the
     prefix variants; 5 requests through 2 slots churn admissions so
@@ -206,6 +221,14 @@ def test_full_prompt_hit_pays_one_cow_block(nano_model):
     {"greedy": False, "temperature": 0.9, "top_k": 5},
 ], ids=["greedy", "top_k"])
 def test_preempt_and_swap_round_trip_identity(nano_model, mode):
+    _preempt_and_swap_round_trip(nano_model, mode)
+
+
+def test_preempt_and_swap_round_trip_identity_olmoe(nano_olmoe):
+    _preempt_and_swap_round_trip(nano_olmoe, {"greedy": True})
+
+
+def _preempt_and_swap_round_trip(nano_model, mode):
     """Pool sized for 2 of 4 in-flight requests: decode growth must
     preempt rows (swap out to host), requeue them, swap back in, and
     finish with tokens identical to solo generate. The per-token rng
@@ -238,12 +261,13 @@ def test_preempt_and_swap_round_trip_identity(nano_model, mode):
     assert eng.kv_pool.blocks_in_use == 0        # all returned
 
 
-def test_preempt_recompute_identity(nano_model):
+@pytest.mark.parametrize("family", ["llama", "olmoe"])
+def test_preempt_recompute_identity(nano_model, nano_olmoe, family):
     """preempt="recompute" drops the victim's blocks and replays
     prompt+emitted through prefill on re-admission — same tokens,
     zero swap traffic (greedy: prefill recomputes the same K/V the
     decode originally wrote)."""
-    cfg, params = nano_model
+    cfg, params = nano_olmoe if family == "olmoe" else nano_model
     prompts = [[7, 8, 9, 10, 11], [3, 1, 4, 1, 5],
                [2, 7, 1, 8, 2], [9, 9, 8, 8, 7]]
     M = 12

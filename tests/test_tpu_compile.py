@@ -53,8 +53,9 @@ def _compiles_with_kernel(fn, *args) -> bool:
     return "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _paged_kernel_compiles(v5e, B, S, T, MB, NB, quant) -> bool:
-    H, KV, D = 32, 8, 128
+def _paged_kernel_compiles(v5e, B, S, T, MB, NB, quant, H=32,
+                           KV=8) -> bool:
+    D = 128
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -91,6 +92,38 @@ def test_paged_kernel_compiles_at_benchmark_shape(v5e, slots, quant):
     it: 32 rows, 128 table entries of 32 tokens, a pool of 1,878 blocks,
     8 KV heads of 128; and the speculative verify's 4 query slots."""
     assert _paged_kernel_compiles(v5e, 32, slots, 32, 128, 1878, quant)
+
+
+def test_paged_kernel_compiles_at_olmoe_shape(v5e):
+    """`benchmark/configs/olmoe-1b-7b-serve.json` as the engine runs it:
+    MHA, 16 query and 16 KV heads of 128 (one query head a group, where
+    Mistral has four), 32 rows, 64 table entries of 32 tokens."""
+    assert _paged_kernel_compiles(v5e, 32, 1, 32, 64, 615, None, H=16,
+                                  KV=16)
+
+
+def test_expert_layer_compiles_at_olmoe_widths(v5e):
+    """Both regimes of `moe.moe_ffn_dropless` at the published widths: 32
+    decode rows (every expert for every row) and a prefill chunk of
+    4 x 512 tokens (sorted, `ragged_dot`)."""
+    from ray_tpu.models import MoeConfig, moe
+
+    cfg = MoeConfig.olmoe_1b_7b(n_layers=1, dtype=jnp.bfloat16,
+                                param_dtype=jnp.bfloat16)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    d, f, e = cfg.dim, cfg.ffn_dim, cfg.n_experts
+    layer = {"w_router": arg((d, e)), "we_gate": arg((e, d, f)),
+             "we_up": arg((e, d, f)), "we_down": arg((e, f, d))}
+    for rows, chunk in ((32, 1), (4, 512)):
+        text = jax.jit(lambda x, layer, live: moe.moe_ffn_dropless(
+            x, layer, cfg, live)).lower(
+            arg((rows, chunk, d)), layer,
+            arg((rows, chunk), jnp.bool_)).compile().as_text()
+        assert ("ragged" in text) == (rows * chunk
+                                      > moe.DENSE_EXPERTS_MAX_TOKENS)
 
 
 def _flash_args(v5e, b, h, hkv, s, d):
