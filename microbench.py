@@ -481,7 +481,8 @@ def _paged_gather_section(quick: bool) -> list:
         q = jax.random.normal(key, (B, 1, H, D), jnp.float32)
         dense_k = jax.random.normal(key, (B, span, KV, D), jnp.float32)
         dense_v = dense_k + 1.0
-        pool_k = jax.random.normal(key, (NB, T, KV, D), jnp.float32)
+        # the engine's layout: [L, NB, T, KV*D], one layer here
+        pool_k = jax.random.normal(key, (1, NB, T, KV * D), jnp.float32)
         pool_v = pool_k + 1.0
         # scattered tables: stride the pool so the gather is non-unit
         bt = (1 + (jnp.arange(B * MB) * 7) % (NB - 1)).reshape(B, MB)
@@ -491,7 +492,7 @@ def _paged_gather_section(quick: bool) -> list:
         dense_fn = jax.jit(lambda q, k, v: _cached_attention(
             q, k, v, slots, span, None))
         paged_fn = jax.jit(lambda q, k, v: paged_attention(
-            q, k, v, bt, slots, kv_valid_len=span))
+            q, k, v, bt, slots, layer=0, kv_valid_len=span))
         dense_fn(q, dense_k, dense_v).block_until_ready()
         paged_fn(q, pool_k, pool_v).block_until_ready()
 
@@ -544,22 +545,24 @@ def _kv_quant_gather_section(quick: bool) -> list:
         NB = 4 * MB + 1
         key = jax.random.PRNGKey(span)
         q = jax.random.normal(key, (B, 1, H, D), jnp.float32)
-        pool_k = jax.random.normal(key, (NB, T, KV, D), jnp.float32)
-        pool_v = pool_k + 1.0
-        amax_k = jnp.max(jnp.abs(pool_k), axis=(1, 3))
-        amax_v = jnp.max(jnp.abs(pool_v), axis=(1, 3))
-        sk = block_scale(amax_k, qspec)
-        sv = block_scale(amax_v, qspec)
-        qk = quantize(pool_k, sk[:, None, :, None], qspec)
-        qv = quantize(pool_v, sv[:, None, :, None], qspec)
+        pages_k = jax.random.normal(key, (1, NB, T, KV, D), jnp.float32)
+        pages_v = pages_k + 1.0
+        sk = block_scale(jnp.max(jnp.abs(pages_k), axis=(2, 4)), qspec)
+        sv = block_scale(jnp.max(jnp.abs(pages_v), axis=(2, 4)), qspec)
+        # the engine's layout: [L, NB, T, KV*D], scales [L, NB, KV]
+        pool_k, pool_v, qk, qv = (
+            x.reshape(1, NB, T, KV * D) for x in (
+                pages_k, pages_v,
+                quantize(pages_k, sk[:, :, None, :, None], qspec),
+                quantize(pages_v, sv[:, :, None, :, None], qspec)))
         bt = (1 + (jnp.arange(B * MB) * 7) % (NB - 1)).reshape(B, MB)
         bt = bt.astype(jnp.int32)
         slots = jnp.full((B, 1), span - 1, jnp.int32)
 
         dense_fn = jax.jit(lambda q, k, v: paged_attention(
-            q, k, v, bt, slots, kv_valid_len=span))
+            q, k, v, bt, slots, layer=0, kv_valid_len=span))
         quant_fn = jax.jit(lambda q, k, v, sk, sv: paged_attention(
-            q, k, v, bt, slots, kv_valid_len=span, k_scale=sk,
+            q, k, v, bt, slots, layer=0, kv_valid_len=span, k_scale=sk,
             v_scale=sv))
         dense_fn(q, pool_k, pool_v).block_until_ready()
         quant_fn(q, qk, qv, sk, sv).block_until_ready()

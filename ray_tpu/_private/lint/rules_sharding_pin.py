@@ -1,7 +1,9 @@
 """sharding-pin: host-updated donated carries must be re-pinned.
 
-The fused dispatch donates its carries (``cache``, ``pool_k/v``,
-``last_logits``, draft-plane twins); inside jit every carry is re-pinned
+The fused dispatch donates its carries (``cache``, ``pool_k/v``, a
+quantized pool's ``scale_k/v`` slabs — which ride the layer scan's carry
+beside the pool they dequantize — ``last_logits``, draft-plane twins);
+inside jit every carry is re-pinned
 with ``with_sharding_constraint`` so tensor-parallel layouts survive the
 donation.  The hazard is the HOST side: when the engine rebuilds a carry
 between dispatches (``jnp.zeros`` at init, ``.at[row].set(...)`` on swap-in,
@@ -61,6 +63,10 @@ CARRY_ATTRS = frozenset({
     "_pool_v",
     "_pool_dk",
     "_pool_dv",
+    "_scale_k",
+    "_scale_v",
+    "_scale_dk",
+    "_scale_dv",
 })
 
 _PIN_TAILS = ("device_put", "with_sharding_constraint")

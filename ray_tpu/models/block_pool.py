@@ -1,7 +1,7 @@
 """Refcounted allocator over the engine's paged KV block pool.
 
 The paged DecodeEngine keeps EVERY request's K/V in fixed-size token
-blocks of one device pool ``[L, NB, T, KV, D]`` and addresses them
+blocks of one device pool ``[L, NB, T, KV*D]`` and addresses them
 through per-request block tables — the vLLM/PagedAttention memory
 plane. This module is the pure-host ledger for that pool: which block
 ids are free, and how many holders reference each allocated block.

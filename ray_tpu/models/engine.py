@@ -176,8 +176,12 @@ class _EngineShardings:
     ``cache``  [L, B, max_len, KV, D] — KV-head axis over "tp" (when
                the model's n_kv_heads divides tp; replicated otherwise)
     ``logits`` [B, vocab]             — vocab over "tp"
-    ``pool``   [L, NB, T, KV, D]      — prefix pool, KV axis like the
-               cache so copy-in/out gathers stay chip-local
+    ``pool``   the block pool, KV heads sharded like the cache's so
+               gathers and scatters stay chip-local. A paged engine's
+               pool is [L, NB, T, KV*D] (what the decode kernel reads):
+               the merged lane axis is head-major, so splitting it over
+               "tp" splits whole KV heads. The dense engine's prefix
+               pool is another object and keeps [L, NB, T, KV, D]
     ``d_cache``/``d_pool`` — the DRAFT model's KV plane, pruned against
                the draft config's own dims (a nano draft often can't
                split its kv heads over the same mesh the target can).
@@ -751,8 +755,11 @@ def _spec_round(params: Params, d_params: Params, cache, d_cache,
 # ---------------------------------------------------------------------------
 # The paged engine has NO dense per-slot cache: every request's K/V
 # lives in fixed-size token blocks of ONE device pool
-# [L, NB, T, KV, D] (the same pool the prefix cache commits into) and
-# each program reaches it through the per-row block table bt [B, MB].
+# [L, NB, T, KV*D] (the same pool the prefix cache commits into; a
+# token's KV heads merged head-major into one lane axis, the layout the
+# decode kernel reads pages in) and each program reaches it through the
+# per-row block table bt [B, MB]. Every program takes the pool donated
+# and updates it in place; none holds a second copy of it.
 # MB * T == max_len is enforced at construction, so the gathered
 # per-row view has EXACTLY the dense cache row's shape and every
 # program below is the dense program evaluated on that view — which is
@@ -760,6 +767,38 @@ def _spec_round(params: Params, d_params: Params, cache, d_cache,
 # solo `generate` (tests/test_engine_paged.py). Block id 0 is the
 # reserved null block: unallocated table entries point at it, padded
 # gathers/scatters dump garbage into it, and no mask ever admits it.
+
+
+def _gather_pages(pools, ids):
+    """``pool[:, ids]`` for each of ``pools`` (K and V), one page at a
+    time: ``[L, NB, T, KV*D]`` and block ids of any shape give
+    ``[L, *ids.shape, T, KV*D]``, each page a `dynamic_slice` of the pool
+    copied into place. Written as the gather it is, the slice would be a
+    whole page, 1024 or 2048 lanes wide, and the chip's compiler splits a
+    gather of slices wider than 512 lanes by first slicing its OPERAND,
+    the whole pool, into 512-lane pieces: a copy of the pool per call
+    (4.5 ms an array in every prefill call at the benchmark's 2.75 GiB,
+    PERF.md PR 27). The loop moves the pages and nothing else, and leaves
+    the lane axis whole, so under a mesh each chip moves its own heads'
+    lanes. One `while_loop` for both pools: a loop is traced and lowered
+    in every prefill program, and set-up pays for it."""
+    L, _, T, W = pools[0].shape
+    flat = ids.reshape(-1)
+    n = flat.shape[0]
+
+    def body(carry):
+        i, outs = carry
+        return i + 1, tuple(
+            jax.lax.dynamic_update_slice(
+                out, jax.lax.dynamic_slice(pool, (0, flat[i], 0, 0),
+                                           (L, 1, T, W)), (0, i, 0, 0))
+            for pool, out in zip(pools, outs))
+
+    _, outs = jax.lax.while_loop(
+        lambda carry: carry[0] < n, body,
+        (jnp.int32(0), tuple(jnp.zeros((L, n, T, W), pool.dtype)
+                             for pool in pools)))
+    return tuple(out.reshape(L, *ids.shape, T, W) for out in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "shardings",
@@ -802,38 +841,42 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
     (see ops/kv_quant.py), which is what keeps zero-copy shares safe
     under the whole-view write-back."""
     with jax.named_scope(sn.KV_GATHER):
-        blk_k = pool_k[:, bt]              # [L, N, MB, T, KV, D]
-        blk_v = pool_v[:, bt]
+        # [L, N, MB, T, KV*D]
+        blk_k, blk_v = _gather_pages((pool_k, pool_v), bt)
+    if shardings is not None:
+        # Same chip-local discipline as _prefix_copy_in: the gathered
+        # view carries the pool's KV-head sharding.
+        sp = shardings.pool.spec           # (l, nb, t, kv*d)
+        blk_spec = NamedSharding(
+            shardings.pool.mesh, P(sp[0], None, sp[1], sp[2], sp[3]))
+        blk_k = jax.lax.with_sharding_constraint(blk_k, blk_spec)
+        blk_v = jax.lax.with_sharding_constraint(blk_v, blk_spec)
+    L, N, MB, T = blk_k.shape[:4]
+    KV, D = cfg.n_kv_heads, cfg.head_dim
+    with jax.named_scope(sn.KV_GATHER):
+        # The pool keeps a token's KV heads merged into one lane axis;
+        # the gathered VIEW, never the pool, is split for the forward.
+        blk_k = blk_k.reshape(L, N, MB, T, KV, D)
+        blk_v = blk_v.reshape(L, N, MB, T, KV, D)
         if qspec is not None:
             blk_k = _kv_dequantize(
                 blk_k, scale_k[:, bt][:, :, :, None, :, None])
             blk_v = _kv_dequantize(
                 blk_v, scale_v[:, bt][:, :, :, None, :, None])
-    if shardings is not None:
-        # Same chip-local discipline as _prefix_copy_in: the gathered
-        # view carries the pool's KV-head sharding.
-        sp = shardings.pool.spec           # (l, nb, t, kv, d)
-        blk_spec = NamedSharding(
-            shardings.pool.mesh, P(sp[0], None, sp[1], sp[2], sp[3],
-                                   sp[4]))
-        blk_k = jax.lax.with_sharding_constraint(blk_k, blk_spec)
-        blk_v = jax.lax.with_sharding_constraint(blk_v, blk_spec)
-    L, N, MB, T = blk_k.shape[:4]
-    with jax.named_scope(sn.KV_GATHER):
-        row_cache = {
-            "k": blk_k.reshape(L, N, MB * T, *blk_k.shape[4:]),
-            "v": blk_v.reshape(L, N, MB * T, *blk_v.shape[4:]),
-        }
+        row_cache = {"k": blk_k.reshape(L, N, MB * T, KV, D),
+                     "v": blk_v.reshape(L, N, MB * T, KV, D)}
     logits, row_cache, moe_ctr = _forward_rows_counted(
         params, prompts, row_cache, starts, cfg, adapters, row_slot,
         moe_ctr, rows, last_idx)
     with jax.named_scope(sn.KV_WRITE):
-        k = row_cache["k"].reshape(L, N, MB, T, *blk_k.shape[4:])
-        v = row_cache["v"].reshape(L, N, MB, T, *blk_v.shape[4:])
         if qspec is None:
-            pool_k = pool_k.at[:, bt].set(k.astype(pool_k.dtype))
-            pool_v = pool_v.at[:, bt].set(v.astype(pool_v.dtype))
+            pool_k = pool_k.at[:, bt].set(row_cache["k"].reshape(
+                L, N, MB, T, KV * D).astype(pool_k.dtype))
+            pool_v = pool_v.at[:, bt].set(row_cache["v"].reshape(
+                L, N, MB, T, KV * D).astype(pool_v.dtype))
         else:
+            k = row_cache["k"].reshape(L, N, MB, T, KV, D)
+            v = row_cache["v"].reshape(L, N, MB, T, KV, D)
             valid = starts + last_idx + 1                       # [N]
             live = (jnp.arange(MB * T)[None, :] < valid[:, None]) \
                 .reshape(1, N, MB, T, 1, 1)
@@ -843,7 +886,8 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
                 amax = jnp.max(jnp.abs(x), axis=(3, 5))     # [L,N,MB,KV]
                 s = _kv_block_scale(amax, qspec)
                 pool = pool.at[:, bt].set(
-                    _kv_quantize(x, s[:, :, :, None, :, None], qspec))
+                    _kv_quantize(x, s[:, :, :, None, :, None], qspec)
+                    .reshape(L, N, MB, T, KV * D))
                 return pool, scales.at[:, bt].set(s)
 
             pool_k, scale_k = _writeback(pool_k, scale_k, k)
@@ -865,119 +909,108 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
     return pool_k, pool_v, scale_k, scale_v, out_logits, moe_ctr
 
 
-def _decode_layer_rows_paged(h, layer, k_pages, v_pages, bt,
-                             write_slots, cfg: LlamaConfig,
-                             lora=None, lora_slots=None,
+def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
+                             cfg: LlamaConfig, lora=None,
+                             lora_slots=None,
                              qspec: Optional[KVQuantSpec] = None,
                              moe_live=None):
-    """`_decode_layer_rows` against the pool: row b's new K/V scatter
-    into physical block ``bt[b, slot//T]`` at offset ``slot%T`` and
-    attention reads back through `ops.attention.paged_attention` (the
-    block-table gather + `_cached_attention`'s exact op sequence).
+    """`_decode_layer_rows` against the pool, S tokens a row (1 in the
+    fused decode, the window in a speculative round): row b's new K/V
+    scatter into layer ``li`` of the WHOLE pool, at physical block
+    ``bt[b, slot//T]`` and offset ``slot%T``, and attention reads back
+    through `ops.attention.paged_attention` (the block-table gather +
+    `_cached_attention`'s exact op sequence, or the kernel), which is
+    handed the whole pool and ``li`` as well. The pool is the layer
+    scan's carry: the scatter updates it in place and nothing of a
+    layer's or the pool's size is sliced, relaid or restacked.
+
     Frontier blocks are always private to their row — a shared block
     is never a write target (full-prompt prefix hits copy-on-write
-    their tail block at admission) — so the scatter pairs are unique
-    across live rows; retired/empty rows scatter garbage into the
-    null block.
+    their tail block at admission) — so the scatter triples are unique
+    across live rows; retired/empty rows, and slots past a row's
+    allocated chain (speculative overshoot, whose results the accept
+    mask discards), scatter garbage into the null block.
 
-    Quantized pools thread ``k_pages``/``v_pages`` as (pages, scales)
-    tuples — `_layer_body` only ever touches them through the closures
-    below, which unpack/repack them around `paged_quant_write`'s
-    frontier-block read-modify-write (gather + dequant + token write +
-    stale-slot zero + requant) and hand `paged_attention` the scales so
-    dequant happens inside its gather."""
-    B = h.shape[0]
-    bidx = jnp.arange(B)
-    T = (k_pages[0] if qspec is not None else k_pages).shape[1]
+    ``kc``/``vc`` are (pool ``[L, NB, T, KV*D]``, scales ``[L, NB, KV]``
+    or None) pairs — `_layer_body` only ever touches them through the
+    closures below. A quantized pool's write is `paged_quant_write`'s
+    read-modify-write of the ONE frontier block per row (gather +
+    dequant + token write + stale-slot zero + requant; its static
+    window-block loop handles windows straddling block boundaries),
+    and `paged_attention` gets the scales so dequant happens inside its
+    gather."""
+    B, S = slots.shape
+    T = kc[0].shape[2]
     span = bt.shape[1] * T                 # == engine max_len
-    with jax.named_scope(sn.KV_WRITE):
-        blk = bt[bidx, write_slots // T]   # [B] physical frontier block
-        off = write_slots % T
 
     if qspec is None:
-        def write_kv(k_pages, v_pages, k, v):
-            k_pages = k_pages.at[blk, off].set(
-                k[:, 0].astype(k_pages.dtype))
-            v_pages = v_pages.at[blk, off].set(
-                v[:, 0].astype(v_pages.dtype))
-            return k_pages, v_pages
+        with jax.named_scope(sn.KV_WRITE):
+            blk = bt[jnp.arange(B)[:, None], slots // T]    # [B, S]
+            off = slots % T
 
-        def attend(q, k_pages, v_pages):
-            return paged_attention(q, k_pages, v_pages, bt,
-                                   write_slots[:, None],
-                                   kv_valid_len=span)
+        def write_kv(kc, vc, k, v):
+            return tuple(
+                (pool.at[li, blk, off].set(
+                    x.reshape(B, S, -1).astype(pool.dtype)), None)
+                for (pool, _), x in ((kc, k), (vc, v)))
     else:
         def write_kv(kc, vc, k, v):
-            kp, ks = paged_quant_write(kc[0], kc[1], bt, write_slots,
-                                       k[:, :1], qspec)
-            vp, vs = paged_quant_write(vc[0], vc[1], bt, write_slots,
-                                       v[:, :1], qspec)
-            return (kp, ks), (vp, vs)
+            return tuple(
+                paged_quant_write(pool, scales, li, bt, slots[:, 0], x,
+                                  qspec)
+                for (pool, scales), x in ((kc, k), (vc, v)))
 
-        def attend(q, kc, vc):
-            return paged_attention(q, kc[0], vc[0], bt,
-                                   write_slots[:, None],
-                                   kv_valid_len=span, k_scale=kc[1],
-                                   v_scale=vc[1])
+    def attend(q, kc, vc):
+        return paged_attention(q, kc[0], vc[0], bt, slots, layer=li,
+                               kv_valid_len=span, k_scale=kc[1],
+                               v_scale=vc[1])
 
-    return _layer_body(h, layer, k_pages, v_pages, write_slots[:, None],
-                       write_kv, write_slots[:, None], span, cfg,
-                       attend=attend, lora=lora, lora_slots=lora_slots,
-                       moe_live=moe_live)
+    return _layer_body(h, layer, kc, vc, slots, write_kv, slots, span,
+                       cfg, attend=attend, lora=lora,
+                       lora_slots=lora_slots, moe_live=moe_live)
 
 
 def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
-                       bt, row_len, cfg: LlamaConfig, adapters=None,
+                       bt, starts, cfg: LlamaConfig, adapters=None,
                        row_slot=None, scale_k=None, scale_v=None,
                        qspec: Optional[KVQuantSpec] = None,
                        moe_live=None):
-    """`_decode_core` over the pool: the layer scan unstacks the pool's
-    layer axis exactly as the dense scan unstacks the cache's (the
-    quantized scale slabs ride the same scan as two extra xs entries).
-    Plain function so `_decode_multi_paged`'s scan can inline it."""
-    write_slots = row_len                                   # [B]
+    """`_decode_core` over the pool: feed each row's [S] chunk at slots
+    ``starts + arange(S)`` and return the [B, S, vocab] logits. The
+    fused decode (S = 1), the draft consume/scan steps and the target
+    verify pass are all this one shape family.
+
+    The layer scan takes ``(layer weights, layer index)`` as ``xs`` and
+    CARRIES the pool (and a quantized pool's scale slabs) beside the
+    activations: each layer writes its tokens into ``pool[li]`` in
+    place and attends ``pool[li]`` where it lies. Plain function so
+    `_decode_multi_paged`'s scan can inline it and keep the pool in its
+    own carry."""
+    S = toks.shape[1]
+    slots = starts[:, None] + jnp.arange(S)[None, :]
     with jax.named_scope(sn.EMBED):
-        h = params["tok_embed"].astype(cfg.dtype)[toks[:, None]]
+        h = params["tok_embed"].astype(cfg.dtype)[toks]
 
     def body(carry, xs):
-        h = carry
-        lora = None
-        if qspec is None:
-            ks = vs = None
-            if adapters is None:
-                layer, k_p, v_p = xs
-            else:
-                layer, k_p, v_p, lora = xs
-            kc, vc = k_p, v_p
-        else:
-            if adapters is None:
-                layer, k_p, v_p, ks, vs = xs
-            else:
-                layer, k_p, v_p, ks, vs, lora = xs
-            kc, vc = (k_p, ks), (v_p, vs)
+        h, kc, vc = carry
+        layer, li = xs[:2]
         h, kc, vc, st = _decode_layer_rows_paged(
-            h, layer, kc, vc, bt, write_slots, cfg, lora=lora,
+            h, layer, li, kc, vc, bt, slots, cfg,
+            lora=xs[2] if adapters is not None else None,
             lora_slots=row_slot, qspec=qspec, moe_live=moe_live)
-        if qspec is None:
-            return h, (kc, vc, st)
-        return h, (kc[0], vc[0], kc[1], vc[1], st)
+        return (h, kc, vc), st
 
-    xs = (params["layers"], pool_k, pool_v)
-    if qspec is not None:
-        xs = xs + (scale_k, scale_v)
+    xs = (params["layers"], jnp.arange(pool_k.shape[0]))
     if adapters is not None:
         xs = xs + (adapters,)
-    h, ys = jax.lax.scan(body, h, xs)
-    if qspec is None:
-        (k_new, v_new, moe_stats), s_k, s_v = ys, None, None
-    else:
-        k_new, v_new, s_k, s_v, moe_stats = ys
+    (h, (pool_k, scale_k), (pool_v, scale_v)), moe_stats = jax.lax.scan(
+        body, (h, (pool_k, scale_k), (pool_v, scale_v)), xs)
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         logits = jnp.einsum("bsd,dv->bsv", h,
                             params["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-    return logits[:, 0], k_new, v_new, s_k, s_v, moe_stats
+    return logits, pool_k, pool_v, scale_k, scale_v, moe_stats
 
 
 @functools.partial(jax.jit,
@@ -1005,7 +1038,13 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
     KV write (block scatter) and the attention read (block-table
     gather) differ, both inside `_decode_core_paged`. The block table
     is a step invariant: the host grows/rebuilds it between
-    dispatches, never inside one. A quantized pool adds the scale
+    dispatches, never inside one. The pool is ONE donated buffer: this
+    scan over the horizon carries it, the layer scan inside
+    `_decode_core_paged` carries it again, each token's K/V is
+    scattered into it in place and the kernel reads pages out of it
+    where they lie, so the program holds no second pool and moves
+    nothing of a layer's size (tests/test_tpu_compile.py holds the
+    compiled program to that). A quantized pool adds the scale
     slabs to the fused carry; qspec=None leaves every pytree and the
     traced program exactly as before."""
     max_len = bt.shape[1] * pool_k.shape[2]
@@ -1032,10 +1071,11 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
             cont = active & ~done_now
         logits, pool_k, pool_v, scale_k, scale_v, moe_stats = \
             _decode_core_paged(
-                params, tok, pool_k, pool_v, bt, row_len, cfg,
+                params, tok[:, None], pool_k, pool_v, bt, row_len, cfg,
                 adapters=adapters, row_slot=row_slot, scale_k=scale_k,
                 scale_v=scale_v, qspec=qspec,
                 moe_live=None if moe_ctr is None else cont[:, None])
+        logits = logits[:, 0]
         if moe_ctr is not None:
             moe_ctr = _moe_count(moe_ctr, moe_stats)
         with jax.named_scope(sn.SAMPLE):
@@ -1068,98 +1108,6 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
             toks, shardings.replicated)
     return (toks, pool_k, pool_v, scale_k, scale_v, last_logits,
             row_len, active, budget, tok_idx, moe_ctr)
-
-
-def _spec_layer_rows_paged(h, layer, k_pages, v_pages, bt, slots,
-                           cfg: LlamaConfig,
-                           qspec: Optional[KVQuantSpec] = None):
-    """S-wide `_decode_layer_rows_paged`: each row's S new K/V entries
-    scatter through its block table and the S queries attend through
-    it, with per-query causal masking inside `paged_attention`. Slots
-    past a row's allocated chain map to the null block (write garbage
-    nobody reads; only overshoot queries — whose results the accept
-    mask discards — ever look that far). The quantized path hands
-    `paged_quant_write` the whole S-wide window — its static
-    window-block loop handles windows straddling block boundaries —
-    with (pages, scales) tuples threaded through `_layer_body` exactly
-    as in the decode layer."""
-    if qspec is None:
-        T = k_pages.shape[1]
-    else:
-        T = k_pages[0].shape[1]
-    span = bt.shape[1] * T
-    bidx = jnp.arange(slots.shape[0])[:, None]
-    with jax.named_scope(sn.KV_WRITE):
-        blk = bt[bidx, slots // T]         # [B, S]
-        off = slots % T
-
-    if qspec is None:
-        def write_kv(k_pages, v_pages, k, v):
-            k_pages = k_pages.at[blk, off].set(k.astype(k_pages.dtype))
-            v_pages = v_pages.at[blk, off].set(v.astype(v_pages.dtype))
-            return k_pages, v_pages
-
-        def attend(q, k_pages, v_pages):
-            return paged_attention(q, k_pages, v_pages, bt, slots,
-                                   kv_valid_len=span)
-    else:
-        def write_kv(kc, vc, k, v):
-            kp, ks = paged_quant_write(kc[0], kc[1], bt, slots[:, 0],
-                                       k, qspec)
-            vp, vs = paged_quant_write(vc[0], vc[1], bt, slots[:, 0],
-                                       v, qspec)
-            return (kp, ks), (vp, vs)
-
-        def attend(q, kc, vc):
-            return paged_attention(q, kc[0], vc[0], bt, slots,
-                                   kv_valid_len=span, k_scale=kc[1],
-                                   v_scale=vc[1])
-
-    return _layer_body(h, layer, k_pages, v_pages, slots, write_kv,
-                       slots, span, cfg, attend=attend)[:3]
-
-
-def _spec_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
-                     bt, starts, cfg: LlamaConfig, scale_k=None,
-                     scale_v=None,
-                     qspec: Optional[KVQuantSpec] = None):
-    """S-wide `_decode_core_paged`: feed each row's [S] chunk at slots
-    ``starts + arange(S)`` and return the full [B, S, vocab] logits —
-    the draft consume/scan steps and the target verify pass are all
-    this one shape family."""
-    S = toks.shape[1]
-    slots = starts[:, None] + jnp.arange(S)[None, :]
-    with jax.named_scope(sn.EMBED):
-        h = params["tok_embed"].astype(cfg.dtype)[toks]
-
-    def body(carry, xs):
-        h = carry
-        if qspec is None:
-            layer, k_p, v_p = xs
-            kc, vc = k_p, v_p
-        else:
-            layer, k_p, v_p, ks, vs = xs
-            kc, vc = (k_p, ks), (v_p, vs)
-        h, kc, vc = _spec_layer_rows_paged(h, layer, kc, vc, bt,
-                                           slots, cfg, qspec=qspec)
-        if qspec is None:
-            return h, (kc, vc)
-        return h, (kc[0], vc[0], kc[1], vc[1])
-
-    xs = (params["layers"], pool_k, pool_v)
-    if qspec is not None:
-        xs = xs + (scale_k, scale_v)
-    h, ys = jax.lax.scan(body, h, xs)
-    if qspec is None:
-        (k_new, v_new), s_k, s_v = ys, None, None
-    else:
-        k_new, v_new, s_k, s_v = ys
-    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope(sn.LM_HEAD):
-        logits = jnp.einsum("bsd,dv->bsv", h,
-                            params["lm_head"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    return logits, k_new, v_new, s_k, s_v
 
 
 @functools.partial(jax.jit,
@@ -1206,15 +1154,16 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
 
     pend = jnp.where(d_lag == 1, d_tok, t0)
     chunk2 = jnp.stack([pend, t0], axis=1)
-    d_logits, pool_dk, pool_dv, scale_dk, scale_dv = _spec_core_paged(
-        d_params, chunk2, pool_dk, pool_dv, bt_d, row_len - d_lag,
-        d_cfg, scale_k=scale_dk, scale_v=scale_dv, qspec=qspec)
+    d_logits, pool_dk, pool_dv, scale_dk, scale_dv, _ = \
+        _decode_core_paged(
+            d_params, chunk2, pool_dk, pool_dv, bt_d, row_len - d_lag,
+            d_cfg, scale_k=scale_dk, scale_v=scale_dv, qspec=qspec)
     first = jnp.argmax(d_logits[bidx, d_lag],
                        axis=-1).astype(jnp.int32)
 
     def dstep(carry, j):
         tok, pool_dk, pool_dv, scale_dk, scale_dv = carry
-        lg, pool_dk, pool_dv, scale_dk, scale_dv = _spec_core_paged(
+        lg, pool_dk, pool_dv, scale_dk, scale_dv, _ = _decode_core_paged(
             d_params, tok[:, None], pool_dk, pool_dv, bt_d,
             row_len + 1 + j, d_cfg, scale_k=scale_dk, scale_v=scale_dv,
             qspec=qspec)
@@ -1228,7 +1177,7 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
         if W > 1 else lastp[:, None]
 
     chunk = jnp.concatenate([t0[:, None], proposals], axis=1)
-    v_logits, pool_k, pool_v, scale_k, scale_v = _spec_core_paged(
+    v_logits, pool_k, pool_v, scale_k, scale_v, _ = _decode_core_paged(
         params, chunk, pool_k, pool_v, bt, row_len, cfg,
         scale_k=scale_k, scale_v=scale_v, qspec=qspec)
     ver = jnp.argmax(v_logits, axis=-1).astype(jnp.int32)
@@ -1282,8 +1231,9 @@ def _cow_blocks(pool_k, pool_v, src: jax.Array, dst: jax.Array,
     quantized pool copies its per-block scales alongside — the copy is
     byte-exact, never a requantization."""
     with jax.named_scope(sn.KV_WRITE):
-        pool_k = pool_k.at[:, dst].set(pool_k[:, src])
-        pool_v = pool_v.at[:, dst].set(pool_v[:, src])
+        k, v = _gather_pages((pool_k, pool_v), src)
+        pool_k = pool_k.at[:, dst].set(k)
+        pool_v = pool_v.at[:, dst].set(v)
         if scale_k is not None:
             scale_k = scale_k.at[:, dst].set(scale_k[:, src])
             scale_v = scale_v.at[:, dst].set(scale_v[:, src])
@@ -1302,7 +1252,7 @@ def _cow_blocks(pool_k, pool_v, src: jax.Array, dst: jax.Array,
 def _swap_out_gather(pool_k, pool_v, block_ids: jax.Array,
                      shardings: Optional[_EngineShardings] = None,
                      scale_k=None, scale_v=None):
-    """Gather a preemption victim's blocks [L, n, T, KV, D] out of the
+    """Gather a preemption victim's blocks [L, n, T, KV*D] out of the
     pool into fresh buffers. The caller issues `copy_to_host_async` on
     the result and drops the device reference once the host copy
     lands, so the victim's HBM is actually reclaimed. block_ids is
@@ -1312,11 +1262,10 @@ def _swap_out_gather(pool_k, pool_v, block_ids: jax.Array,
     bf16 swap traffic — and the round trip is byte-exact by
     construction (no dequantization happens on either leg)."""
     with jax.named_scope(sn.KV_GATHER):
+        k, v = _gather_pages((pool_k, pool_v), block_ids)
         if scale_k is None:
-            return (pool_k[:, block_ids], pool_v[:, block_ids], None,
-                    None)
-        return (pool_k[:, block_ids], pool_v[:, block_ids],
-                scale_k[:, block_ids], scale_v[:, block_ids])
+            return k, v, None, None
+        return k, v, scale_k[:, block_ids], scale_v[:, block_ids]
 
 
 @functools.partial(jax.jit, static_argnames=("shardings",),
@@ -1406,7 +1355,7 @@ class _SwapState:
     """A preempted request's spilled decode state (paged engine).
 
     ``k``/``v`` are HOST copies of the victim's gathered blocks
-    [L, nbp, T, KV, D] — `copy_to_host_async` overlaps the pull, and
+    [L, nbp, T, KV*D] — `copy_to_host_async` overlaps the pull, and
     dropping the device reference is what actually returns the HBM.
     They are None under preempt="recompute", where re-admission
     re-prefills prompt + emitted tokens instead of scattering bytes
@@ -1736,6 +1685,10 @@ class DecodeEngine:
                 params, llama_param_specs(cfg, rules), mesh)
             d_cache_sh = d_pool_sh = d_scale_sh = None
             self._d_shardings = None
+            # a paged pool merges (kv, head_dim) into one head-major
+            # lane axis; the dense engine's prefix pool keeps both
+            pool_axes = ("layers", None, None, "kv") \
+                + (() if paged else ("head_dim",))
             if draft_params is not None:
                 # The draft shards over the SAME mesh, but its rules
                 # prune against its OWN dims — a nano draft whose kv
@@ -1754,9 +1707,8 @@ class DecodeEngine:
                 d_cache_sh = named_sharding(
                     mesh, "layers", "batch", "length", "kv", "head_dim",
                     rules=d_rules)
-                d_pool_sh = named_sharding(
-                    mesh, "layers", None, None, "kv", "head_dim",
-                    rules=d_rules)
+                d_pool_sh = named_sharding(mesh, *pool_axes,
+                                           rules=d_rules)
                 if self.kv_quant_spec is not None:
                     d_scale_sh = named_sharding(
                         mesh, "layers", None, "kv", rules=d_rules)
@@ -1780,8 +1732,7 @@ class DecodeEngine:
                                      "kv", "head_dim", rules=rules),
                 logits=named_sharding(mesh, "batch", "vocab",
                                       rules=rules),
-                pool=named_sharding(mesh, "layers", None, None, "kv",
-                                    "head_dim", rules=rules),
+                pool=named_sharding(mesh, *pool_axes, rules=rules),
                 d_cache=d_cache_sh, d_pool=d_pool_sh,
                 scale=scale_sh, d_scale=d_scale_sh)
         else:
@@ -1975,9 +1926,11 @@ class DecodeEngine:
             self._swapped: Dict[int, _SwapState] = {}
             self._admit_seq = 0            # preemption recency order
             self._row_admit_seq = np.zeros((self.B,), np.int64)
-            self._pool_k = jnp.zeros((L, n_blocks, T, KV, D),
+            # the layout the decode kernel reads: one page is one
+            # contiguous [T, KV*D] slab, heads merged head-major
+            self._pool_k = jnp.zeros((L, n_blocks, T, KV * D),
                                      pool_dtype)
-            self._pool_v = jnp.zeros((L, n_blocks, T, KV, D),
+            self._pool_v = jnp.zeros((L, n_blocks, T, KV * D),
                                      pool_dtype)
             self._scale_k = self._scale_v = None
             if self.kv_quant_spec is not None:
@@ -2088,9 +2041,9 @@ class DecodeEngine:
                                 if self.kv_quant_spec is not None
                                 else d_dtype)
                 self._pool_dk = jnp.zeros(
-                    (L_d, n_blocks_d, T, KV_d, D_d), d_pool_dtype)
+                    (L_d, n_blocks_d, T, KV_d * D_d), d_pool_dtype)
                 self._pool_dv = jnp.zeros(
-                    (L_d, n_blocks_d, T, KV_d, D_d), d_pool_dtype)
+                    (L_d, n_blocks_d, T, KV_d * D_d), d_pool_dtype)
                 self._scale_dk = self._scale_dv = None
                 if self.kv_quant_spec is not None:
                     self._scale_dk = jnp.zeros(
@@ -3990,8 +3943,7 @@ class DecodeEngine:
                   "logits": lg,
                   "block_tokens": self.prefix_block,
                   "quant": self.kv_quant,
-                  "pool_shape": tuple(self._pool_k.shape[i]
-                                      for i in (0, 3, 4))}
+                  "pool_shape": self._kv_geometry}
             self._release_row_blocks(row)
         if self._row_slot[row]:
             # The exporting row's adapter pin dies here; the importing
@@ -4055,8 +4007,7 @@ class DecodeEngine:
             kv is not None and self.paged
             and kv["block_tokens"] == self.prefix_block
             and kv["quant"] == self.kv_quant
-            and kv["pool_shape"] == tuple(self._pool_k.shape[i]
-                                          for i in (0, 3, 4)))
+            and kv["pool_shape"] == self._kv_geometry)
         if compatible:
             # Pre-seed the swap ledger with the exported bytes: the
             # recompute entry submit() may have planted (resume path)
@@ -4083,6 +4034,12 @@ class DecodeEngine:
                 {"bytes": nbytes, "mode":
                  "swap" if compatible else "recompute"})
         return rid
+
+    @property
+    def _kv_geometry(self) -> Tuple[int, int, int]:
+        """(layers, KV heads, head dim): what a handoff's K/V payload
+        ``[L, n, T, KV*D]`` and scales ``[L, n, KV]`` must agree on."""
+        return (self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim)
 
     def _release_row_blocks(self, row: int) -> None:
         """Drop the row's reference on its chain (trie-shared blocks

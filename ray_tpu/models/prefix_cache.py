@@ -47,7 +47,8 @@ def block_bytes(n_layers: int, block_tokens: int, kv_heads: int,
     the PR-7 docs), so both are now explicit:
 
     - LAYERS: a block id indexes the pool's ``n_blocks`` axis of BOTH
-      pool arrays ``[L, NB, T, KV, D]``, so one block holds T tokens'
+      pool arrays (``[L, NB, T, KV*D]`` paged, ``[L, NB, T, KV, D]`` the
+      dense engine's prefix pool), so one block holds T tokens'
       K/V for ALL ``n_layers`` decoder layers. The default (and the
       number every byte budget must divide by) is therefore the
       layer-SUMMED figure ``2 * L * T * KV * D * dtype``;

@@ -118,12 +118,25 @@ def mha_reference(q: jax.Array,
     return out.astype(q.dtype)
 
 
+def pool_kv_heads(pool, q) -> int:
+    """KV heads of a ``[L, NB, T, KV*D]`` pool, read against the
+    ``[B, S, H, D]`` queries that attend it."""
+    H, D = q.shape[2:]
+    if pool.ndim != 4 or pool.shape[3] % D:
+        raise ValueError(f"pool {pool.shape} is not [L, NB, T, KV*{D}]")
+    KV = pool.shape[3] // D
+    if H % KV:
+        raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
+    return KV
+
+
 def paged_attention(q: jax.Array,
-                    k_pages: jax.Array,
-                    v_pages: jax.Array,
+                    k_pool: jax.Array,
+                    v_pool: jax.Array,
                     block_tables: jax.Array,
                     q_slots: jax.Array,
                     *,
+                    layer,
                     kv_valid_len,
                     sm_scale: Optional[float] = None,
                     k_scale: Optional[jax.Array] = None,
@@ -136,17 +149,24 @@ def paged_attention(q: jax.Array,
 
       q            [B, S, H, D]   queries (S=1 fused decode; S>1 would
                                   be a paged prefill chunk)
-      k/v_pages    [NB, T, KV, D] the shared block pool, ONE layer's
-                                  slice (the engine scans layers; NB
-                                  blocks of T tokens each; block 0 is
-                                  the reserved null block)
+      k/v_pool     [L, NB, T, KV*D] the shared block pool, WHOLE, as
+                                  the engine stores and carries it: L
+                                  layers of NB blocks of T tokens, a
+                                  token's KV heads merged head-major
+                                  into one lane axis; block 0 is the
+                                  reserved null block
       block_tables [B, MB]        row b's logical block p covers cache
                                   slots [p*T, (p+1)*T); unallocated
                                   entries point at block 0
       q_slots      [B, S]         the cache slot each query occupies
+      layer        scalar         which layer of the pool to attend
+                                  (traced: the engine's layer scan
+                                  passes its index). Pages are read
+                                  ``pool[layer, block]`` where they lie;
+                                  no layer is ever sliced out
       kv_valid_len scalar         slots >= this are masked (the
                                   engine's max_len)
-      k/v_scale    [NB, KV]       per-block per-kv-head f32 dequant
+      k/v_scale    [L, NB, KV]    per-block per-kv-head f32 dequant
                                   scales when the pool is quantized
                                   (int8/fp8 — see ops/kv_quant.py);
                                   None for a dense-precision pool
@@ -178,9 +198,8 @@ def paged_attention(q: jax.Array,
     if impl not in ("auto", "flash", "reference"):
         raise ValueError(f"impl must be auto|flash|reference, got {impl!r}")
     B, S, H, D = q.shape
-    NB, T, KV, _ = k_pages.shape
-    if H % KV:
-        raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
+    T = k_pool.shape[2]
+    KV = pool_kv_heads(k_pool, q)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     if impl == "auto":
@@ -191,23 +210,25 @@ def paged_attention(q: jax.Array,
         from ray_tpu.ops.paged_attention_kernel import paged_attention_kernel
 
         return paged_attention_kernel(
-            q, k_pages, v_pages, block_tables, q_slots,
+            q, k_pool, v_pool, block_tables, q_slots, layer=layer,
             kv_valid_len=kv_valid_len, sm_scale=sm_scale,
             k_scale=k_scale, v_scale=v_scale)
-    # Gather the per-row dense view: [B, MB, T, KV, D] -> [B, MB*T, ..]
-    # (logical slot p*T + t of row b is block_tables[b, p] slot t, so
-    # the reshape restores contiguous slot order per row).
+    # Gather the per-row dense view straight out of the whole pool:
+    # [B, MB, T, KV*D] -> [B, MB*T, KV, D] (logical slot p*T + t of row
+    # b is block_tables[b, p] slot t, so the reshape restores contiguous
+    # slot order per row; the lane axis splits head-major).
     with jax.named_scope(sn.KV_GATHER):
-        k = k_pages[block_tables]
-        v = v_pages[block_tables]
+        MB = block_tables.shape[1]
+        k = k_pool[layer, block_tables].reshape(B, MB, T, KV, D)
+        v = v_pool[layer, block_tables].reshape(B, MB, T, KV, D)
         if k_scale is not None:
             # dequant-in-gather; the view must stay f32 (requantization
             # byte-stability — see ops/kv_quant.py)
             k = k.astype(jnp.float32) \
-                * k_scale[block_tables][:, :, None, :, None]
+                * k_scale[layer, block_tables][:, :, None, :, None]
             v = v.astype(jnp.float32) \
-                * v_scale[block_tables][:, :, None, :, None]
-        span = k.shape[1] * T
+                * v_scale[layer, block_tables][:, :, None, :, None]
+        span = MB * T
         k = k.reshape(B, span, KV, D)
         v = v.reshape(B, span, KV, D)
         # -- lockstep with generate._cached_attention from here on --

@@ -1,6 +1,7 @@
 """Low-bit paged-KV quantization: per-block, per-kv-head scales.
 
-The paged engine stores its KV pool as ``[L, NB, T, KV, D]`` blocks; to
+The paged engine stores its KV pool as ``[L, NB, T, KV*D]`` blocks (a
+token's KV heads merged head-major into one lane axis); to
 double the concurrent requests per HBM byte the pool can instead hold
 int8 (qmax 127) or fp8-e4m3 (qmax 448) values plus a parallel f32 scale
 slab shaped ``[L, NB, KV]`` — one scale per block per kv head, indexed
@@ -106,14 +107,16 @@ def dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale
 
 
-def paged_quant_write(pages: jax.Array, scales: jax.Array, bt: jax.Array,
-                      start: jax.Array, vals: jax.Array,
+def paged_quant_write(pool: jax.Array, scales: jax.Array, layer,
+                      bt: jax.Array, start: jax.Array, vals: jax.Array,
                       qspec: KVQuantSpec
                       ) -> Tuple[jax.Array, jax.Array]:
-    """Read-modify-write ``vals`` [B, S, KV, D] into quantized ``pages``
-    [NB, T, KV, D] at contiguous cache slots ``start[b] + s`` routed
-    through block table ``bt`` [B, MB], recomputing the per-block
-    per-kv-head ``scales`` [NB, KV] of every touched block.
+    """Read-modify-write ``vals`` [B, S, KV, D] into layer ``layer`` of
+    the quantized ``pool`` [L, NB, T, KV*D] at contiguous cache slots
+    ``start[b] + s`` routed through block table ``bt`` [B, MB],
+    recomputing the per-block per-kv-head ``scales`` [L, NB, KV] of
+    every touched block. Only the touched blocks are gathered and
+    scattered back, ``pool[layer, block]``: no layer is sliced out.
 
     This is the decode/spec write site: S == 1 for plain decode, S ==
     the draft/verify window for speculation.  The window can straddle
@@ -131,7 +134,7 @@ def paged_quant_write(pages: jax.Array, scales: jax.Array, bt: jax.Array,
     path's masked scatter.
     """
     B, S, KV, D = vals.shape
-    T = pages.shape[1]
+    T = pool.shape[2]
     MB = bt.shape[1]
     vals = vals.astype(jnp.float32)
     bidx = jnp.arange(B)
@@ -140,7 +143,8 @@ def paged_quant_write(pages: jax.Array, scales: jax.Array, bt: jax.Array,
     for w in range(nbw):
         lb = start // T + w               # [B] logical block index
         blk = jnp.where(lb < MB, bt[bidx, jnp.minimum(lb, MB - 1)], 0)
-        cur = dequantize(pages[blk], scales[blk][:, None, :, None])
+        cur = dequantize(pool[layer, blk].reshape(B, T, KV, D),
+                         scales[layer, blk][:, None, :, None])
         # token s sits at window position off0 + s; it lands in this
         # iteration's block iff (off0 + s) // T == w.  Offset T is OOB
         # and dropped.
@@ -153,7 +157,7 @@ def paged_quant_write(pages: jax.Array, scales: jax.Array, bt: jax.Array,
         cur = jnp.where(live[:, :, None, None], cur, 0.0)
         amax = jnp.max(jnp.abs(cur), axis=(1, 3))             # [B, KV]
         s_new = block_scale(amax, qspec)
-        pages = pages.at[blk].set(quantize(
-            cur, s_new[:, None, :, None], qspec))
-        scales = scales.at[blk].set(s_new)
-    return pages, scales
+        pool = pool.at[layer, blk].set(quantize(
+            cur, s_new[:, None, :, None], qspec).reshape(B, T, KV * D))
+        scales = scales.at[layer, blk].set(s_new)
+    return pool, scales

@@ -16,10 +16,15 @@ read, fetched or folded (the engine's decode step has a quarter of its
 token, PERF.md PR 25).
 
 Grid is ``(B,)``: one grid step per row. The pool stays in HBM
-(``pl.ANY``), viewed as ``[NB, T, KV*D]`` (a reshape), so one page is
-one contiguous ``[T, KV*D]`` slab holding every KV head as a
-lane-aligned ``[T, D]`` slice; a page is read once per row whatever the
-GQA group size. Inside the grid step a ``fori_loop`` of
+(``pl.ANY``) WHOLE, every layer of it, in the layout the engine stores:
+``[L, NB, T, KV*D]``, so one page is one contiguous ``[T, KV*D]`` slab
+holding every KV head as a lane-aligned ``[T, D]`` slice, and a page is
+read once per row whatever the GQA group size. The layer to read is one
+more scalar-prefetched operand and a page is addressed
+``pool.at[layer, block]``: nothing pool-sized or layer-sized is sliced,
+reshaped or copied on the way in (the layer scan used to slice a layer
+out and relay it for every layer and token: four fifths of a decode
+token, PERF.md PR 27). Inside the grid step a ``fori_loop`` of
 ``cdiv(n_live[b], P)`` compute steps runs, each over ``P`` pages
 (``P*T`` = 512 keys, or what fits 1 MiB a buffer slot), which the kernel
 fetches itself: one ``make_async_copy`` per live page through the
@@ -54,16 +59,19 @@ has no gather and no broadcast of a ``(1, 1)`` vector over both
 sublanes and lanes.
 
 The layout compiles for a described ``v5e:2x2`` device at Llama-3-8B
-widths and at the benchmark's shape, block tokens 16-128, bf16 / int8 /
-fp8, one and four query slots (tests/test_tpu_compile.py), and runs on
-the chip in ``chip_smoke.py``'s paged variants; the value sweeps against
-the pure-lax reference run in interpret mode
+widths and at the benchmark's shapes, block tokens 16-128, bf16 / int8 /
+fp8, one and four query slots, and so does the whole fused decode
+program around it, held there to moving nothing of the pool's size
+(tests/test_tpu_compile.py); it runs on the chip in ``chip_smoke.py``'s
+paged variants; the value sweeps against the pure-lax reference, on a
+pool of three layers that hold different data, run in interpret mode
 (tests/test_engine_kv_quant.py). On a TPU `impl="auto"` routes here;
 elsewhere it stays on the reference path and this kernel runs only when
 asked for explicitly (then in interpret mode). Timed on a v5e (PERF.md
-PR 25): 0.28 ms a call at the benchmark's shape where the full walk took
-2.92; a row costs about 3 us before its first page (the first copy is
-not overlapped with the row before), a 512-key step about 3.4 us
+PR 25, PR 27): 0.28 ms a call at the benchmark's shape where the full
+walk took 2.92, and the same through the whole pool's ref as through a
+layer's view; a row costs about 3 us before its first page (the first
+copy is not overlapped with the row before), a 512-key step about 3.4 us
 against 2.6 of HBM time (ROADMAP S2 keeps what is left).
 """
 from __future__ import annotations
@@ -77,6 +85,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import scope_names as sn
+from ray_tpu.ops.attention import pool_kv_heads
 
 _NEG_INF = -1e30
 # A compute step folds as many pages as make 512 keys, or as fit 1 MiB
@@ -100,13 +109,13 @@ def live_pages(q_slots, kv_valid_len, block_tokens: int,
     return jnp.clip(jnp.minimum(by_slot, by_len), 0, max_blocks)
 
 
-def _kernel(bt_ref, lim_ref, nl_ref, *refs, sm_scale, n_kv, head_dim,
-            block_tokens, pages_per_step, max_blocks, has_scale):
+def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
+            head_dim, block_tokens, pages_per_step, max_blocks, has_scale):
     """Grid step ``b``: walk row b's live pages, ``pages_per_step`` at a
     time, folding each step into every KV head's online softmax.
     Scalar-prefetch refs: ``bt_ref`` [B*MB] flat block table,
     ``lim_ref`` [1] the valid-length cap, ``nl_ref`` [B] live pages per
-    row (the walk's trip count)."""
+    row (the walk's trip count), ``lay_ref`` [1] the pool's layer."""
     if has_scale:
         (qs_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, k_buf, v_buf,
          sem, acc_ref, m_ref, l_ref) = refs
@@ -117,6 +126,7 @@ def _kernel(bt_ref, lim_ref, nl_ref, *refs, sm_scale, n_kv, head_dim,
     t, pps = block_tokens, pages_per_step
     span = pps * t                                      # keys per step
     n_live = nl_ref[b]
+    layer = lay_ref[0]
     n_steps = (n_live + pps - 1) // pps
     rows = q_ref.shape[2]                               # S * G
 
@@ -129,10 +139,10 @@ def _kernel(bt_ref, lim_ref, nl_ref, *refs, sm_scale, n_kv, head_dim,
         (a start and its wait build the same descriptor)."""
         blk = bt_ref[b * max_blocks + step * pps + p]
         dst = pl.ds(pl.multiple_of(p * t, t), t)
-        return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf, dst],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf, dst],
-                                      sem.at[1, buf]))
+        return (pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                      k_buf.at[buf, dst], sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                      v_buf.at[buf, dst], sem.at[1, buf]))
 
     def each_live_page(step, buf, act):
         def one(p, carry):
@@ -230,11 +240,12 @@ def _page_scales(scale_ref, first_page, kv, page_of_col, n_kv,
 
 
 def paged_attention_kernel(q: jax.Array,
-                           k_pages: jax.Array,
-                           v_pages: jax.Array,
+                           k_pool: jax.Array,
+                           v_pool: jax.Array,
                            block_tables: jax.Array,
                            q_slots: jax.Array,
                            *,
+                           layer,
                            kv_valid_len,
                            sm_scale: Optional[float] = None,
                            k_scale: Optional[jax.Array] = None,
@@ -242,34 +253,33 @@ def paged_attention_kernel(q: jax.Array,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Same contract as `ops.attention.paged_attention` (reference
     impl), fused. ``interpret=None`` resolves to True off-TPU."""
-    B, S, H, D = q.shape
-    NB, T, KV, _ = k_pages.shape
-    MB = block_tables.shape[1]
-    if H % KV:
-        raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
+    T, MB = k_pool.shape[2], block_tables.shape[1]
+    pool_kv_heads(k_pool, q)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n_live = live_pages(q_slots, kv_valid_len, T, MB)
-    return _walk(q, k_pages, v_pages, block_tables, q_slots, n_live,
-                 kv_valid_len=kv_valid_len, sm_scale=sm_scale,
-                 k_scale=k_scale, v_scale=v_scale, interpret=interpret)
+    return _walk(q, k_pool, v_pool, block_tables, q_slots, n_live,
+                 layer=layer, kv_valid_len=kv_valid_len,
+                 sm_scale=sm_scale, k_scale=k_scale, v_scale=v_scale,
+                 interpret=interpret)
 
 
-def _walk(q, k_pages, v_pages, block_tables, q_slots, n_live, *,
+def _walk(q, k_pool, v_pool, block_tables, q_slots, n_live, *, layer,
           kv_valid_len, sm_scale, k_scale, v_scale, interpret):
     """The kernel call, with each row's trip count ``n_live`` [B] given:
     `paged_attention_kernel` passes `live_pages`; a walk of all ``MB``
     entries gives the same bits (a fully masked step leaves the softmax
     state untouched), which is what the tests hold it to."""
     B, S, H, D = q.shape
-    NB, T, KV, _ = k_pages.shape
+    T = k_pool.shape[2]
+    KV = pool_kv_heads(k_pool, q)
     MB = block_tables.shape[1]
     g = H // KV
     rows = S * g
     has_scale = k_scale is not None
-    page_bytes = T * KV * D * k_pages.dtype.itemsize
+    page_bytes = T * KV * D * k_pool.dtype.itemsize
     pps = max(1, min(_KEYS_PER_STEP // T, _STEP_BYTES // page_bytes, MB))
 
     # query row r = s*g + i of KV head kv is query s, head kv*g + i
@@ -291,23 +301,22 @@ def _walk(q, k_pages, v_pages, block_tables, q_slots, n_live, *,
         pl.BlockSpec(memory_space=pl.ANY),             # k pool, in HBM
         pl.BlockSpec(memory_space=pl.ANY),             # v pool
     ]
-    with jax.named_scope(sn.KV_GATHER):
-        args = [qs, qg, k_pages.reshape(NB, T, KV * D),
-                v_pages.reshape(NB, T, KV * D)]
-        if has_scale:
-            in_specs += [pl.BlockSpec((1, 1, MB * KV), row_map,
-                                      memory_space=pltpu.SMEM)] * 2
-            args += [s.astype(jnp.float32)[block_tables]
+    args = [qs, qg, k_pool, v_pool]
+    if has_scale:
+        in_specs += [pl.BlockSpec((1, 1, MB * KV), row_map,
+                                  memory_space=pltpu.SMEM)] * 2
+        with jax.named_scope(sn.KV_GATHER):
+            args += [s[layer, block_tables].astype(jnp.float32)
                      .reshape(B, 1, MB * KV) for s in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, rows, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((2, pps * T, KV * D), k_pages.dtype),
-            pltpu.VMEM((2, pps * T, KV * D), v_pages.dtype),
+            pltpu.VMEM((2, pps * T, KV * D), k_pool.dtype),
+            pltpu.VMEM((2, pps * T, KV * D), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((KV, rows, D), jnp.float32),
             pltpu.VMEM((KV, rows, 1), jnp.float32),
@@ -326,6 +335,7 @@ def _walk(q, k_pages, v_pages, block_tables, q_slots, n_live, *,
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name=sn.PAGED_KERNEL,
-    )(bt, lim, n_live.astype(jnp.int32), *args)
+    )(bt, lim, n_live.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *args)
     return out.reshape(B, KV, S, g, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, S, H, D)

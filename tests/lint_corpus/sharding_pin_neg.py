@@ -29,6 +29,14 @@ class Engine:
             self._pool_k, self._pool_v, src, dst,
             shardings=self._shardings)
 
+    def init_scales(self, layers, blocks, kv_heads):
+        # NEGATIVE: the scale slab [L, NB, KV] built on the host and
+        # pinned before the first dispatch, like the pool beside it.
+        self._scale_k = jnp.zeros((layers, blocks, kv_heads), jnp.float32)
+        if self._shardings is not None:
+            self._scale_k = jax.device_put(self._scale_k,
+                                           self._shardings.scale)
+
     def init_cache(self, cfg):
         # NEGATIVE: explicit sharding kwarg at the build site.
         self.cache = build_cache(cfg, sharding=self._shardings.cache)

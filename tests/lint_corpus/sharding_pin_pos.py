@@ -14,6 +14,11 @@ class Engine:
             jnp.asarray(logits))
 
     def rebuild_pool(self, shape):
-        # POSITIVE: fresh host-built pool, never pinned.
+        # POSITIVE: fresh host-built pool [L, NB, T, KV*D], never pinned.
         self._pool_k = jnp.zeros(shape, jnp.bfloat16)
         self._pool_v = jnp.zeros(shape, jnp.bfloat16)
+
+    def rebuild_scales(self, layers, blocks, kv_heads):
+        # POSITIVE: a quantized pool's scale slab rides the same donated
+        # carry as the pool; rebuilt on the host, it decays the same way.
+        self._scale_k = jnp.zeros((layers, blocks, kv_heads), jnp.float32)

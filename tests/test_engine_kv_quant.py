@@ -22,7 +22,10 @@ strengths:
   identity against an unpreempted run of the SAME quant mode.
 - The Pallas kernel (impl="flash") is validated off-TPU in interpret
   mode against the pure-lax reference over a shape sweep including
-  GQA, ragged valid lengths, and quantized pools.
+  GQA, ragged valid lengths, and quantized pools. Both read the pool
+  as the engine stores it, ``[L, NB, T, KV*D]`` with the layer as an
+  argument: every case here attends the middle layer of three that
+  hold different data (`_pool`), and the ragged sweep attends each.
 """
 
 import numpy as np
@@ -42,6 +45,20 @@ from ray_tpu.ops.kv_quant import (  # noqa: E402
 
 T = 4
 MAX_LEN = 32
+LAYER = 1           # the layer of `_pool`'s three that holds the data
+
+
+def _pool(x, layer=LAYER, n_layers=3):
+    """One layer's pages ``[NB, T, KV, D]`` (or scales ``[NB, KV]``) ->
+    the engine's ``[L, NB, T, KV*D]`` pool (``[L, NB, KV]`` slab) with
+    ``x`` as layer ``layer`` and the other layers holding other data
+    (the blocks rolled), so that reading the wrong layer shows. None
+    stays None."""
+    if x is None:
+        return None
+    flat = x.reshape(*x.shape[:2], -1) if x.ndim == 4 else x
+    return jnp.stack([jnp.roll(flat, i - layer, axis=0)
+                      for i in range(n_layers)])
 
 
 @pytest.fixture(scope="module")
@@ -199,11 +216,13 @@ def test_quant_logit_error_bound(quant):
     q = jnp.asarray(rng.randn(B, 1, H, D), jnp.float32)
     bt = jnp.asarray(rng.randint(1, NB, size=(B, MB)), jnp.int32)
     q_slots = jnp.asarray([[MB * TT - 1]] * B, jnp.int32)
-    exact = paged_attention(q, kf, vf, bt, q_slots,
-                            kv_valid_len=MB * TT, impl="reference")
-    approx = paged_attention(q, kq, vq, bt, q_slots,
-                             kv_valid_len=MB * TT, k_scale=sk,
-                             v_scale=sv, impl="reference")
+    exact = paged_attention(q, _pool(kf), _pool(vf), bt, q_slots,
+                            layer=LAYER, kv_valid_len=MB * TT,
+                            impl="reference")
+    approx = paged_attention(q, _pool(kq), _pool(vq), bt, q_slots,
+                             layer=LAYER, kv_valid_len=MB * TT,
+                             k_scale=_pool(sk), v_scale=_pool(sv),
+                             impl="reference")
     err = float(jnp.max(jnp.abs(exact - approx)))
     assert err < 0.05, f"{quant} attention max-abs-err {err}"
 
@@ -324,13 +343,18 @@ def test_paged_quant_write_matches_dense_write():
     qspec = resolve_kv_quant("int8")
     rng = np.random.RandomState(2)
     NB, TT, KV, D, B, S = 7, 4, 2, 8, 2, 6
-    pages = jnp.zeros((NB, TT, KV, D), qspec.dtype)
-    scales = jnp.zeros((NB, KV), jnp.float32)
+    pool = jnp.zeros((3, NB, TT, KV * D), qspec.dtype)
+    slab = jnp.zeros((3, NB, KV), jnp.float32)
     bt = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 0]], jnp.int32)
     vals = jnp.asarray(rng.randn(B, S, KV, D), jnp.float32)
     start = jnp.asarray([1, 3], jnp.int32)
-    pages, scales = paged_quant_write(pages, scales, bt, start, vals,
-                                      qspec)
+    pool, slab = paged_quant_write(pool, slab, LAYER, bt, start, vals,
+                                   qspec)
+    # the write touched its layer alone
+    for other in (0, 2):
+        assert not jnp.any(pool[other].view(jnp.uint8))
+        assert not jnp.any(slab[other])
+    pages, scales = pool[LAYER].reshape(NB, TT, KV, D), slab[LAYER]
     for b in range(B):
         for s_i in range(S):
             pos = int(start[b]) + s_i
@@ -403,7 +427,9 @@ def test_kernel_matches_reference(shape, quant):
         sv = block_scale(jnp.max(jnp.abs(vf), axis=(1, 3)), qspec)
         kf = quantize(kf, sk[:, None, :, None], qspec)
         vf = quantize(vf, sv[:, None, :, None], qspec)
-    kw = dict(kv_valid_len=MB * TT, k_scale=sk, v_scale=sv)
+    kw = dict(layer=LAYER, kv_valid_len=MB * TT, k_scale=_pool(sk),
+              v_scale=_pool(sv))
+    kf, vf = _pool(kf), _pool(vf)
     ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference",
                           **kw)
     got = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
@@ -423,8 +449,8 @@ def test_kernel_masks_garbage_blocks():
     short = jnp.asarray([[1, 0, 0, 0]], jnp.int32)   # 1 live block
     long = jnp.asarray([[1, 5, 4, 3]], jnp.int32)    # garbage tail
     q_slots = jnp.asarray([[2]], jnp.int32)          # frontier slot 2
-    outs = [paged_attention(q, kf, vf, bt, q_slots, kv_valid_len=16,
-                            impl=impl)
+    outs = [paged_attention(q, _pool(kf), _pool(vf), bt, q_slots,
+                            layer=LAYER, kv_valid_len=16, impl=impl)
             for bt in (short, long) for impl in ("reference", "flash")]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(o), np.asarray(outs[0]),
@@ -441,12 +467,12 @@ def test_paged_attention_impl_dispatch_seam():
     reference off-TPU, and bad arguments fail loudly."""
     rng = np.random.RandomState(4)
     NB, TT, KV, D = 5, 4, 2, 16
-    kf = jnp.asarray(rng.randn(NB, TT, KV, D), jnp.float32)
-    vf = jnp.asarray(rng.randn(NB, TT, KV, D), jnp.float32)
+    kf = _pool(jnp.asarray(rng.randn(NB, TT, KV, D), jnp.float32))
+    vf = _pool(jnp.asarray(rng.randn(NB, TT, KV, D), jnp.float32))
     q = jnp.asarray(rng.randn(2, 1, 4, D), jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     q_slots = jnp.asarray([[5], [7]], jnp.int32)
-    kw = dict(kv_valid_len=8)
+    kw = dict(layer=LAYER, kv_valid_len=8)
     ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference",
                           **kw)
     fla = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
@@ -461,10 +487,14 @@ def test_paged_attention_impl_dispatch_seam():
         paged_attention(q, kf, vf, bt, q_slots, impl="fused", **kw)
     with pytest.raises(ValueError, match="together"):
         paged_attention(q, kf, vf, bt, q_slots,
-                        k_scale=jnp.ones((NB, KV)), **kw)
+                        k_scale=jnp.ones((3, NB, KV)), **kw)
     with pytest.raises(ValueError, match="heads"):
         paged_attention(jnp.zeros((2, 1, 3, D)), kf, vf, bt, q_slots,
                         **kw)
+    # the pool keeps a token's heads merged: [L, NB, T, KV, D] is refused
+    with pytest.raises(ValueError, match="pool"):
+        paged_attention(q, kf.reshape(3, NB, TT, KV, D),
+                        vf.reshape(3, NB, TT, KV, D), bt, q_slots, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -480,34 +510,40 @@ _RT, _RMB = 4, 6            # ragged sweep: pages of 4, tables of 6
 _RAGGED_STARTS = [0, 2 * _RT - 2, 2 * _RT - 1, 2 * _RT, None, 0]
 
 
+_RL = 3                     # layers of the ragged sweep's pool
+
+
 def _ragged_case(S, quant, rng, poison=False):
     """q, pools, table, slots, scales for the ragged rows above, every
     table entry of a live row a distinct real block, so that an entry
-    past the live prefix is readable garbage (or NaN with `poison`)."""
+    past the live prefix is readable garbage (or NaN with `poison`).
+    The pools are the engine's: ``[_RL, NB, T, KV*D]``, each layer its
+    own draw; ``quant`` is None (f32), "bf16", "int8" or "fp8_e4m3"."""
     B, KV, D, gm = len(_RAGGED_STARTS), 2, 16, 2
     span = _RT * _RMB
     starts = [span - S if s is None else s for s in _RAGGED_STARTS]
     q_slots = np.asarray(starts)[:, None] + np.arange(S)[None]
     NB = 1 + B * _RMB
-    kf = rng.randn(NB, _RT, KV, D).astype(np.float32)
-    vf = rng.randn(NB, _RT, KV, D).astype(np.float32)
+    dt = jnp.bfloat16 if quant == "bf16" else jnp.float32
+    kf = jnp.asarray(rng.randn(_RL, NB, _RT, KV, D), dt)
+    vf = jnp.asarray(rng.randn(_RL, NB, _RT, KV, D), dt)
     bt = 1 + np.arange(B * _RMB).reshape(B, _RMB)
     bt[-1] = 0                                       # the retired row
-    q = jnp.asarray(rng.randn(B, S, KV * gm, D), jnp.float32)
+    q = jnp.asarray(rng.randn(B, S, KV * gm, D), dt)
     sk = sv = None
-    kf, vf = jnp.asarray(kf), jnp.asarray(vf)
-    if quant is not None:
+    if quant not in (None, "bf16"):
         qspec = resolve_kv_quant(quant)
-        sk = block_scale(jnp.max(jnp.abs(kf), axis=(1, 3)), qspec)
-        sv = block_scale(jnp.max(jnp.abs(vf), axis=(1, 3)), qspec)
-        kf = quantize(kf, sk[:, None, :, None], qspec)
-        vf = quantize(vf, sv[:, None, :, None], qspec)
+        sk = block_scale(jnp.max(jnp.abs(kf), axis=(2, 4)), qspec)
+        sv = block_scale(jnp.max(jnp.abs(vf), axis=(2, 4)), qspec)
+        kf = quantize(kf, sk[:, :, None, :, None], qspec)
+        vf = quantize(vf, sv[:, :, None, :, None], qspec)
     if poison:
         live = q_slots.max(axis=1) // _RT + 1
         dead = np.concatenate([bt[b, live[b]:] for b in range(B - 1)])
-        kf = kf.at[dead].set(jnp.nan)
-        vf = vf.at[dead].set(jnp.nan)
-    return (q, kf, vf, jnp.asarray(bt, jnp.int32),
+        kf = kf.at[:, dead].set(jnp.nan)
+        vf = vf.at[:, dead].set(jnp.nan)
+    return (q, kf.reshape(_RL, NB, _RT, KV * D),
+            vf.reshape(_RL, NB, _RT, KV * D), jnp.asarray(bt, jnp.int32),
             jnp.asarray(q_slots, jnp.int32), sk, sv)
 
 
@@ -523,27 +559,43 @@ def test_live_pages_is_the_prefix_a_query_may_see():
 @pytest.mark.parametrize("keys_per_step", [8, 512],
                          ids=["steps_of_2_pages", "one_step"])
 @pytest.mark.parametrize("slots", [1, 4], ids=["s1", "s4_straddling"])
-@pytest.mark.parametrize("quant", [None, "int8", "fp8_e4m3"],
-                         ids=["dense", "int8", "fp8"])
+@pytest.mark.parametrize("quant", [None, "bf16", "int8", "fp8_e4m3"],
+                         ids=["dense", "bf16", "int8", "fp8"])
 def test_kernel_ragged_rows_match_reference_and_full_walk(
         monkeypatch, quant, slots, keys_per_step):
     """Rows of every kind in ONE call, against the reference; and the
     walk that stops at each row's live prefix gives the bits of a walk
-    over all MB entries (a fully masked step changes nothing)."""
+    over all MB entries (a fully masked step changes nothing). Each of
+    the pool's three layers holds its own data and is attended by its
+    index: the kernel's answer for layer ``li`` is the reference's, and
+    the reference's is what it gives on that layer alone."""
     monkeypatch.setattr(pak, "_KEYS_PER_STEP", keys_per_step)
     rng = np.random.RandomState(17 + slots)
     q, kf, vf, bt, q_slots, sk, sv = _ragged_case(slots, quant, rng)
-    for valid in (_RT * _RMB, 10):        # 10: below some rows' slots
+    tol = 2e-2 if quant == "bf16" else 2e-5
+    seen = []
+    for li, valid in ((0, _RT * _RMB), (1, 10), (2, _RT * _RMB)):
+        # valid 10: below some rows' slots
         kw = dict(kv_valid_len=valid, k_scale=sk, v_scale=sv)
         ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference",
-                              **kw)
-        got = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+                              layer=li, **kw)
+        alone = paged_attention(
+            q, kf[li:li + 1], vf[li:li + 1], bt, q_slots, layer=0,
+            impl="reference", kv_valid_len=valid,
+            k_scale=None if sk is None else sk[li:li + 1],
+            v_scale=None if sv is None else sv[li:li + 1])
+        assert jnp.array_equal(ref, alone)
+        got = paged_attention(q, kf, vf, bt, q_slots, impl="flash",
+                              layer=jnp.int32(li), **kw)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(ref, np.float32),
+            atol=tol, rtol=tol)
         full = pak._walk(q, kf, vf, bt, q_slots,
                          jnp.full((q.shape[0],), _RMB, jnp.int32),
-                         sm_scale=None, interpret=True, **kw)
+                         layer=li, sm_scale=None, interpret=True, **kw)
         assert jnp.array_equal(got, full)
+        seen.append(np.asarray(got, np.float32))
+    assert not np.allclose(seen[0], seen[2], atol=1e-3)   # other data
 
 
 @pytest.mark.parametrize("keys_per_step", [8, 512],
@@ -563,7 +615,7 @@ def test_kernel_never_reads_past_the_live_prefix(monkeypatch, quant, slots,
         q, kf, vf, bt, q_slots, sk, sv = _ragged_case(slots, quant, rng,
                                                       poison=poison)
         outs.append(paged_attention(
-            q, kf, vf, bt, q_slots, impl="flash",
+            q, kf, vf, bt, q_slots, impl="flash", layer=LAYER,
             kv_valid_len=_RT * _RMB, k_scale=sk, v_scale=sv))
     assert bool(jnp.isfinite(outs[1]).all())
     assert jnp.array_equal(outs[0], outs[1])

@@ -296,10 +296,11 @@ def test_every_pallas_kernel_has_its_name(kernel):
 
     if kernel == sn.PAGED_KERNEL:
         q = jnp.zeros((2, 1, 4, 16), jnp.float32)
-        pages = jnp.zeros((6, 4, 2, 16), jnp.float32)
+        pool = jnp.zeros((2, 6, 4, 2 * 16), jnp.float32)
         text = jax.jit(functools.partial(
-            paged_attention_kernel, kv_valid_len=8, interpret=True)).lower(
-                q, pages, pages, jnp.zeros((2, 2), jnp.int32),
+            paged_attention_kernel, layer=1, kv_valid_len=8,
+            interpret=True)).lower(
+                q, pool, pool, jnp.zeros((2, 2), jnp.int32),
                 jnp.zeros((2, 1), jnp.int32)).as_text(debug_info=True)
     else:
         x = jnp.zeros((1, 2, 128, 16), jnp.float32)

@@ -213,6 +213,82 @@ def test_full_prompt_hit_pays_one_cow_block(nano_model):
 
 
 # ---------------------------------------------------------------------------
+# The fused decode writes in place: its triples and nothing else
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["dense", "int8"])
+def test_fused_decode_touches_only_what_it_writes(nano_model, monkeypatch,
+                                                  quant):
+    """The pool is ONE buffer the decode program updates in place, so
+    what a dispatch does NOT write must come out bit for bit: every
+    `_decode_multi_paged` dispatch of a run with shared prefix blocks,
+    rows of different lengths and rows that retire mid-horizon is
+    compared, K and V (and a quantized pool's scales), against the pool
+    that went in. Allowed to differ: ``[layer, bt[b, slot // T],
+    slot % T]`` for the horizon's slots of each row (a quantized pool
+    requantizes that row's whole frontier block) and the null block,
+    which retired rows write. The slots the active rows advanced over
+    did change, in every layer."""
+    import ray_tpu.models.engine as engine_mod
+
+    cfg, params = nano_model
+    real = engine_mod._decode_multi_paged
+    seen = []
+
+    def spy(params, pool_k, pool_v, bt, last_logits, row_len, *a, **kw):
+        before = [np.array(x) for x in (pool_k, pool_v)]
+        s_before = [None if kw.get(n) is None else np.array(kw[n])
+                    for n in ("scale_k", "scale_v")]
+        rl, bt_np = np.array(row_len), np.array(bt)
+        out = real(params, pool_k, pool_v, bt, last_logits, row_len, *a,
+                   **kw)
+        seen.append((before, [np.asarray(out[1]), np.asarray(out[2])],
+                     s_before, [None if x is None else np.array(x)
+                                for x in (out[3], out[4])], bt_np, rl,
+                     np.asarray(out[6]), out[0].shape[0]))   # toks [H, B]
+        return out
+
+    monkeypatch.setattr(engine_mod, "_decode_multi_paged", spy)
+    sys_p = list(range(1, 13))                   # 3 full shared blocks
+    eng = DecodeEngine(params, cfg, batch_slots=3, max_len=MAX_LEN,
+                       paged=True, kv_block_tokens=T, prefix_cache=True,
+                       decode_horizon=4, kv_quant=quant)
+    eng.submit(sys_p + [50, 51], 2)
+    eng.run()                                    # commits the prefix
+    for prompt, n in ((sys_p + [60, 61, 62], 9), (sys_p + [70], 3),
+                      ([9, 8, 7, 6, 5], 6)):
+        eng.submit(prompt, n)
+    eng.run()
+    assert eng.stats()["kv_blocks_shared"] >= 6
+    assert len(seen) >= 3
+    L, NB = seen[0][0][0].shape[:2]
+    advanced = 0
+    for before, after, s_before, s_after, bt, rl, rl_out, H in seen:
+        allowed = np.zeros((NB, T), bool)
+        allowed[0] = True                        # the null block
+        for b in range(bt.shape[0]):
+            for slot in range(rl[b], min(rl[b] + H, MAX_LEN)):
+                blk = bt[b, slot // T]
+                if quant is None:
+                    allowed[blk, slot % T] = True
+                else:
+                    allowed[blk] = True
+        for old, new in zip(before, after):
+            same = (old.view(np.uint8) == new.view(np.uint8)) \
+                .reshape(L, NB, T, -1).all(axis=-1)
+            assert (same | allowed[None]).all()
+            for b in range(bt.shape[0]):
+                for slot in range(rl[b], rl_out[b]):
+                    assert not same[:, bt[b, slot // T], slot % T].any()
+                    advanced += 1
+        for old, new in zip(s_before, s_after):
+            if old is not None:
+                same = (old == new).all(axis=-1)                # [L, NB]
+                assert (same | allowed.any(axis=1)[None]).all()
+    assert advanced >= 2 * (9 + 3 + 6 - 3)       # K and V, both checked
+
+
+# ---------------------------------------------------------------------------
 # Preempt-and-swap
 # ---------------------------------------------------------------------------
 

@@ -26,7 +26,7 @@ EXPECTATIONS = {
     "flush_order_pos.py": {"flush-order": (3, 0)},
     "flush_order_neg.py": {"flush-order": (0, 0)},
     "flush_order_sup.py": {"flush-order": (0, 1)},
-    "sharding_pin_pos.py": {"sharding-pin": (3, 0)},
+    "sharding_pin_pos.py": {"sharding-pin": (4, 0)},
     "sharding_pin_neg.py": {"sharding-pin": (0, 0)},
     "sharding_pin_sup.py": {"sharding-pin": (0, 1)},
     "host_sync_interproc_pos.py": {"host-sync": (2, 0)},

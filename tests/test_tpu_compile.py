@@ -7,11 +7,18 @@ much VMEM — so every kernel `impl="auto"` can select on a TPU is compiled
 here, `interpret=False`, at the shapes `chip_smoke.py` runs: Llama-3-8B
 attention widths for the paged decode kernel, the 551M flagship's
 `[8, 12, 2048, 128]` at 1024x1024 tiles for flash forward and backward.
+The fused decode PROGRAM is compiled whole as well, at the benchmark's
+shapes, and held to what PR 27 bought: the KV pool is one buffer updated
+in place, so the program holds nothing else of the pool's or a layer's
+size and its workspace is a fraction of the pool.
 Nothing runs, so these say nothing about values (the interpret-mode
 sweeps do) or times (only a chip run does).
 """
 
+import math
 import os
+import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -60,17 +67,19 @@ def _paged_kernel_compiles(v5e, B, S, T, MB, NB, quant, H=32,
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
+    L = 3   # the pool as the engine stores it; the layer is an operand
     args = [arg((B, S, H, D), jnp.bfloat16),
-            arg((NB, T, KV, D), quant or jnp.bfloat16),
-            arg((NB, T, KV, D), quant or jnp.bfloat16),
-            arg((B, MB), jnp.int32), arg((B, S), jnp.int32)]
+            arg((L, NB, T, KV * D), quant or jnp.bfloat16),
+            arg((L, NB, T, KV * D), quant or jnp.bfloat16),
+            arg((B, MB), jnp.int32), arg((B, S), jnp.int32),
+            arg((), jnp.int32)]
     if quant is not None:
-        args += [arg((NB, KV), jnp.float32)] * 2
+        args += [arg((L, NB, KV), jnp.float32)] * 2
 
-    def fn(q, k, v, bt, slots, k_scale=None, v_scale=None):
+    def fn(q, k, v, bt, slots, layer, k_scale=None, v_scale=None):
         return paged_attention_kernel(
-            q, k, v, bt, slots, kv_valid_len=MB * T, k_scale=k_scale,
-            v_scale=v_scale, interpret=False)
+            q, k, v, bt, slots, layer=layer, kv_valid_len=MB * T,
+            k_scale=k_scale, v_scale=v_scale, interpret=False)
 
     return _compiles_with_kernel(fn, *args)
 
@@ -100,6 +109,164 @@ def test_paged_kernel_compiles_at_olmoe_shape(v5e):
     Mistral has four), 32 rows, 64 table entries of 32 tokens."""
     assert _paged_kernel_compiles(v5e, 32, 1, 32, 64, 615, None, H=16,
                                   KV=16)
+
+
+# -- the fused decode program, whole ------------------------------------------
+
+def _param_shapes(v5e, cfg):
+    """The model's parameters as shapes on the described chip."""
+    from ray_tpu.models import MoeConfig, llama_init, moe_init
+
+    init = moe_init if isinstance(cfg, MoeConfig) else llama_init
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg)))
+
+
+def _decode_program(v5e, cfg, n_blocks, max_blocks, quant=None, rows=32,
+                    block_tokens=32, horizon=8):
+    """`_decode_multi_paged` compiled for the described chip at an
+    engine's shapes, with the kernel selected: `impl="auto"` asks
+    `jax.default_backend()`, which answers "cpu" here, so the TEST makes
+    it answer "tpu" while the program is traced (the program has no
+    switch for it). Returns the compiled program and the pool's shape."""
+    from ray_tpu.models import MoeConfig, engine
+    from ray_tpu.ops.kv_quant import resolve_kv_quant
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = _param_shapes(v5e, cfg)
+    qspec = resolve_kv_quant(quant)
+    shape = (cfg.n_layers, n_blocks, block_tokens,
+             cfg.n_kv_heads * cfg.head_dim)
+    pool = arg(shape, jnp.bfloat16 if qspec is None else qspec.dtype)
+    scale = None if qspec is None else arg(
+        (cfg.n_layers, n_blocks, cfg.n_kv_heads), jnp.float32)
+    lane = arg((rows,), jnp.int32)
+    flag = arg((rows,), jnp.bool_)
+    moe_ctr = arg((4,), jnp.int32) if isinstance(cfg, MoeConfig) else None
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = engine._decode_multi_paged.lower(
+            params, pool, pool, arg((rows, max_blocks), jnp.int32),
+            arg((rows, cfg.vocab_size), jnp.float32), lane, flag, lane,
+            lane, arg((rows, 2), jnp.uint32), flag, 1.0, cfg, horizon,
+            True, None, None, None, scale_k=scale, scale_v=scale,
+            qspec=qspec, moe_ctr=moe_ctr)
+    return lowered.compile(), shape
+
+
+_HLO_LINE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", re.M)
+_PLUMBING = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
+
+
+def _pool_sized_instructions(text, pool_shape):
+    """(opcode, dims) of every instruction of the optimized HLO that
+    yields, alone or in a tuple, an array with the pool's block axis
+    among its dimensions and at least a tenth of one layer-of-pool's
+    elements: the pool, a layer of it, or a lane slice of either
+    (`pool_shape[1]` is chosen so that nothing else has it). Parameters
+    and the tuples and loops that merely pass the pool on are left out."""
+    least = math.prod(pool_shape[1:]) // 10
+    found = []
+    for types, op in _HLO_LINE.findall(text):
+        if op in _PLUMBING:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]", types):
+            sizes = [int(d) for d in dims.split(",")]
+            if pool_shape[1] in sizes and math.prod(sizes) >= least:
+                found.append((op, dims))
+    return found
+
+
+def _mistral(n_layers):
+    from ray_tpu.models import LlamaConfig
+
+    return LlamaConfig(vocab_size=32768, dim=4096, n_layers=n_layers,
+                       n_heads=32, n_kv_heads=8, ffn_dim=14336,
+                       rope_theta=1e6, max_seq_len=4096,
+                       dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+
+
+def _olmoe(n_layers):
+    from ray_tpu.models import MoeConfig
+
+    return MoeConfig.olmoe_1b_7b(n_layers=n_layers, dtype=jnp.bfloat16,
+                                 param_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("case", ["mistral", "olmoe", "mistral_int8"])
+def test_decode_program_moves_nothing_of_the_pools_size(v5e, case):
+    """The benchmark's two engines (`mistral-7b-v0.3-serve`: 12 layers,
+    1,878 blocks, 128 table entries; `olmoe-1b-7b-serve`: 12 layers, 615
+    blocks, 64 entries) and an int8 pool: in the optimized HLO the only
+    instructions of the pool's or a layer-of-pool's size are the two
+    in-place `kv_write` scatter fusions (K and V) — no copy, slice,
+    reshape or restack (before PR 27: two copies of the whole pool a
+    token and six layer-sized ops a layer, 4.23 GiB of workspace) — and
+    the program keeps ONE Pallas kernel."""
+    cfg, nb, mb, quant = {
+        "mistral": (_mistral(12), 1878, 128, None),
+        "olmoe": (_olmoe(12), 615, 64, None),
+        "mistral_int8": (_mistral(12), 1878, 128, "int8"),
+    }[case]
+    compiled, shape = _decode_program(v5e, cfg, nb, mb, quant)
+    text = compiled.as_text()
+    moved = _pool_sized_instructions(text, shape)
+    dims = ",".join(map(str, shape))
+    assert sorted(moved) == [("fusion", dims)] * 2 + [("scatter", dims)] * 2
+    fusions = [ln for ln in text.splitlines()
+               if re.search(r"= \w+\[%s\]\S* fusion\(" % dims, ln)]
+    assert len(fusions) == 2 and all("kv_write" in ln for ln in fusions)
+    assert text.count("tpu_custom_call") == 1
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (1 << 30), f"{temp / 2**30:.2f} GiB of workspace"
+
+
+def test_decode_program_fits_16_layers_beside_a_5_gib_pool(v5e):
+    """16 Mistral layers (7.0 GiB) with a 5 GiB pool (2,561 blocks): the
+    chip's compiler refused it at 17.6 of 15.75 GiB while the program
+    held a second pool (PR 23). It compiles, and arguments + workspace
+    stay under the chip's memory."""
+    compiled, _ = _decode_program(v5e, _mistral(16), 2561, 128)
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < (1 << 30)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.5 * 2**30
+
+
+@pytest.mark.parametrize("program", ["prefill", "cow", "swap_out"])
+def test_other_holders_of_the_pool_copy_none_of_it(v5e, program):
+    """The programs beside the decode that read pages out of the pool,
+    at the Mistral cell's shape: the prefill of one row x 512 tokens, the
+    copy-on-write of four blocks, the swap-out of sixteen. Each gets its
+    pages through `engine._gather_pages`; as a gather of whole pages
+    (1,024 lanes) the chip's compiler sliced the POOL into 512-lane
+    halves first (`mini-gather-slice`), a copy of the pool per call. What
+    is left of the pool's size are the in-place scatters back into it."""
+    from ray_tpu.models import engine
+
+    cfg = _mistral(12)
+    shape = (12, 1878, 32, 1024)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    pool = arg(shape, jnp.bfloat16)
+    if program == "prefill":
+        lowered = engine._prefill_rows_paged.lower(
+            _param_shapes(v5e, cfg), arg((1, 512)), pool, pool,
+            arg((32, cfg.vocab_size), jnp.float32), arg((1, 128)),
+            arg((1,)), arg((1,)), arg((1,)), cfg)
+    elif program == "cow":
+        lowered = engine._cow_blocks.lower(pool, pool, arg((4,)), arg((4,)))
+    else:
+        lowered = engine._swap_out_gather.lower(pool, pool, arg((16,)))
+    text = lowered.compile().as_text()
+    assert "mini-gather" not in text
+    dims = ",".join(map(str, shape))
+    writes = 0 if program == "swap_out" else 2
+    assert sorted(_pool_sized_instructions(text, shape)) \
+        == [("fusion", dims)] * writes + [("scatter", dims)] * writes
 
 
 def test_expert_layer_compiles_at_olmoe_widths(v5e):
