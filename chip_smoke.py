@@ -24,8 +24,8 @@ One chip (the default):
              heads of 128, FFN 14336, vocab 128,256; bf16 weights), depth
              cut from 32 layers to SERVE_LAYERS so the weights sit beside
              their KV on one 16 GB chip. A handful of requests of mixed
-             prompt lengths go through submit/step/drain on the dense
-             engine, on paged=True and on paged=True+kv_quant="int8", then
+             prompt lengths go through submit/step/drain on the engine
+             with a bf16 pool and with kv_quant="int8", then
              through a two-replica LLMFleet. Each is held to solo
              `generate` by the bounds written below.
   2. train   three steps of make_sharded_train_step on the 551M
@@ -100,7 +100,7 @@ SERVE_KV_POOL_BYTES = 1 << 30
 # evaluations that merely associate differently (the engine prefills in
 # chunks into a 2,048-slot cache, solo `generate` in one piece into a
 # cache of prompt + 40; tp=4 sums partial products four ways). Measured
-# on the chip: 0.048 dense against solo, 0.071 tp=4 against one device.
+# on the chip: 0.048 against solo, 0.071 tp=4 against one device.
 # The bound is three times the estimate; a wrong mask, a wrong page or a
 # lost scale moves logits by O(1).
 LOGIT_ATOL = 0.15
@@ -308,31 +308,27 @@ def _say(**fields) -> None:
 
 @contextlib.contextmanager
 def _decode_impl_probe(impls: dict):
-    """While active, the first dispatch of each fused decode program is
+    """While active, the first dispatch of the fused decode program is
     lowered as the engine calls it — same arguments, same mesh scope —
     and ``impls[program]`` records whether that program carries a Mosaic
     kernel. Read from the lowered program, not from an argument."""
     import ray_tpu.models.engine as engine_mod
 
-    real = {n: getattr(engine_mod, n)
-            for n in ("_decode_multi", "_decode_multi_paged")}
+    name = "_decode_multi_paged"
+    real = getattr(engine_mod, name)
 
-    def spy(name):
-        def call(*a, **k):
-            if name not in impls:
-                text = real[name].lower(*a, **k).as_text()
-                impls[name] = ("pallas" if "tpu_custom_call" in text
-                               else "reference")
-            return real[name](*a, **k)
-        return call
+    def call(*a, **k):
+        if name not in impls:
+            text = real.lower(*a, **k).as_text()
+            impls[name] = ("pallas" if "tpu_custom_call" in text
+                           else "reference")
+        return real(*a, **k)
 
-    for n in real:
-        setattr(engine_mod, n, spy(n))
+    setattr(engine_mod, name, call)
     try:
         yield
     finally:
-        for n, fn in real.items():
-            setattr(engine_mod, n, fn)
+        setattr(engine_mod, name, real)
 
 
 def _serve_config():
@@ -491,9 +487,8 @@ def _run_engine(record, params, cfg, prompts, new_tokens, meter, **kw):
         logits = _first_logits(engine, prompts)
         tokens = _serve_through(engine, prompts, new_tokens)
     (record["decode_program"], record["impl"]), = impls.items()
-    if engine.paged:
-        record["kv_pool_blocks"] = engine.kv_pool.blocks_total
-        record["kv_block_tokens"] = engine.kv_block_tokens
+    record["kv_pool_blocks"] = engine.kv_pool.blocks_total
+    record["kv_block_tokens"] = engine.kv_block_tokens
     record["seconds"] = round(time.monotonic() - t0, 1)
     return engine, logits, tokens
 
@@ -529,12 +524,11 @@ def phase_serve(seed: int, cfg=None, *, prompt_lens=SERVE_PROMPT_LENS,
             logits=logits, ref_logits=solo_logits)
 
     engine_kw = dict(batch_slots=slots, max_len=cfg.max_seq_len,
-                     prefill_chunk=chunk)
-    paged = dict(engine_kw, paged=True, kv_block_tokens=kv_block_tokens,
-                 kv_pool_bytes=kv_pool_bytes)
+                     prefill_chunk=chunk, kv_block_tokens=kv_block_tokens,
+                     kv_pool_bytes=kv_pool_bytes)
     records = []
-    for name, kw in (("dense", engine_kw), ("paged", paged),
-                     ("paged_int8", dict(paged, kv_quant="int8"))):
+    for name, kw in (("paged", engine_kw),
+                     ("paged_int8", dict(engine_kw, kv_quant="int8"))):
         record = {"variant": name, "kv_quant": kw.get("kv_quant")}
         engine, logits, tokens = _run_engine(
             record, params, cfg, prompts, new_tokens, meter, **kw)
@@ -550,7 +544,8 @@ def phase_serve(seed: int, cfg=None, *, prompt_lens=SERVE_PROMPT_LENS,
     t0 = time.monotonic()
     with meter.window(record), _decode_impl_probe(impls):
         fleet = LLMFleet(
-            lambda name: DecodeEngine(params, cfg, engine_id=name, **paged),
+            lambda name: DecodeEngine(params, cfg, engine_id=name,
+                                      **engine_kw),
             initial_replicas=2)
         fids = [fleet.submit(p, max_new_tokens=new_tokens)
                 for p in prompts]
@@ -573,8 +568,8 @@ def phase_serve(seed: int, cfg=None, *, prompt_lens=SERVE_PROMPT_LENS,
 
     return {"phase": "serve", "pass": all(r["pass"] for r in records),
             "device": device,
-            "identity_dense_vs_solo": records[0]["identical_to_reference"],
-            "paged_impl": records[1]["impl"],
+            "identity_vs_solo": records[0]["identical_to_reference"],
+            "paged_impl": records[0]["impl"],
             "compile_s": round(meter.seconds, 1),
             "cache_hits": meter.hits}
 
@@ -742,8 +737,10 @@ def _bytes_per_device(devices) -> list:
 def phase_serve_tp4(seed: int, cfg=None, *, tp=4,
                     prompt_lens=SERVE_PROMPT_LENS,
                     new_tokens=SERVE_NEW_TOKENS, slots=SERVE_SLOTS,
-                    chunk=SERVE_PREFILL_CHUNK) -> dict:
-    """The phase-1 model under DecodeEngine(tp=4) against the same dense
+                    chunk=SERVE_PREFILL_CHUNK,
+                    kv_block_tokens=SERVE_KV_BLOCK_TOKENS,
+                    kv_pool_bytes=SERVE_KV_POOL_BYTES) -> dict:
+    """The phase-1 model under DecodeEngine(tp=4) against the same
     engine on one device of the same host: the same bounds as phase 1,
     with the one-device engine's logits and tokens as the reference
     (four-way partial sums reorder bf16 additions, nothing more)."""
@@ -757,19 +754,20 @@ def phase_serve_tp4(seed: int, cfg=None, *, tp=4,
     cfg = cfg or _serve_config()
     params, prompts = _seeded_model(seed, cfg, prompt_lens)
     engine_kw = dict(batch_slots=slots, max_len=cfg.max_seq_len,
-                     prefill_chunk=chunk)
+                     prefill_chunk=chunk, kv_block_tokens=kv_block_tokens,
+                     kv_pool_bytes=kv_pool_bytes)
     _say(phase="serve_tp4", model="llama3_8b widths",
          depth_cut=f"{cfg.n_layers} of 32 layers", tp=tp, seed=seed)
     _, margins = _reference_programs(cfg, new_tokens)
 
-    one: dict = {"variant": "dense_1_device", "kv_quant": None}
+    one: dict = {"variant": "paged_1_device", "kv_quant": None}
     engine, ref_logits, ref_tokens = _run_engine(
         one, params, cfg, prompts, new_tokens, meter, **engine_kw)
     del engine
     gc.collect()
     _say(**one)
 
-    record: dict = {"variant": f"dense_tp{tp}", "kv_quant": None}
+    record: dict = {"variant": f"paged_tp{tp}", "kv_quant": None}
     engine, logits, tokens = _run_engine(
         record, params, cfg, prompts, new_tokens, meter, tp=tp, **engine_kw)
     _judge(record, tokens, new_tokens, ref_tokens=ref_tokens,
@@ -778,7 +776,7 @@ def phase_serve_tp4(seed: int, cfg=None, *, tp=4,
     # Only the sharded copy stays, so what each device holds is its own.
     del params
     gc.collect()
-    state = (engine.params, engine.cache)
+    state = (engine.params, engine._pool_k, engine._pool_v)
     sharded = sum(x.nbytes for x in jax.tree.leaves(state))
     per_device = _bytes_per_device(jax.devices()[:tp])
     record["spans_all_devices"] = all(

@@ -3,7 +3,7 @@
 The r09 analyzers were strictly intraprocedural: taint, ownership and
 dominance facts died at every call boundary, so `host-sync` could not see
 that a helper forces a pull on its argument and no rule could see that
-`_prefix_copy_in` leaks a block acquired two frames up.  This module is the
+a helper leaks a block acquired two frames up.  This module is the
 shared v2 substrate:
 
 * a **function table** over one module's AST — every ``def`` (functions,

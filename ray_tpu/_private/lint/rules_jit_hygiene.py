@@ -8,7 +8,8 @@ Three mechanical hazards around ``jax.jit`` that have bitten serving PRs:
    parameter (``donate_argnames``/``donate_argnums``) is undefined once XLA
    aliases the storage; the engine convention is to rebind the result over
    the donated expression on the same statement
-   (``self.cache, ... = _decode_multi(self.params, self.cache, ...)``).
+   (``self._pool_k, ... = _decode_multi_paged(self.params, self._pool_k,
+   ...)``).
 3. **static-varying scalar** — passing an obviously per-call-varying Python
    scalar (a ``len(...)``, ``.shape[...]`` access, or an enclosing loop
    variable) as a *static* jit arg keys a new compile per distinct value.

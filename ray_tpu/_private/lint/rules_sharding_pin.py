@@ -1,6 +1,6 @@
 """sharding-pin: host-updated donated carries must be re-pinned.
 
-The fused dispatch donates its carries (``cache``, ``pool_k/v``, a
+The fused dispatch donates its carries (``pool_k/v``, a
 quantized pool's ``scale_k/v`` slabs — which ride the layer scan's carry
 beside the pool they dequantize — ``last_logits``, draft-plane twins);
 inside jit every carry is re-pinned
@@ -18,14 +18,14 @@ The repo convention is an immediate explicit pin::
                                            self._shardings.logits)
 
 This rule checks every assignment to a donated-carry attribute
-(``self.cache``, ``self._pool_k`` ...).  The value is considered pinned
+(``self._pool_k``, ``self._last_logits`` ...).  The value is considered pinned
 when it is:
 
 * a call to a module-level **jitted** function (pins internally via
   ``with_sharding_constraint`` — that side is the jit's contract), also
   through tuple-unpack targets;
 * a call carrying an explicit ``sharding=``/``shardings=`` kwarg
-  (``init_cache(..., sharding=self._shardings.cache)``);
+  (``init_pool(..., sharding=self._shardings.pool)``);
 * ``jax.device_put(...)`` / ``with_sharding_constraint(...)`` — the pin
   itself;
 * a plain name/attribute copy, ``None``/constant, or a conditional whose
@@ -55,8 +55,6 @@ from ray_tpu._private.lint.core import (
 )
 
 CARRY_ATTRS = frozenset({
-    "cache",
-    "_d_cache",
     "_last_logits",
     "_d_last_logits",
     "_pool_k",

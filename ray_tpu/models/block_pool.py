@@ -8,15 +8,13 @@ ids are free, and how many holders reference each allocated block.
 
 Reference counting is what turns prefix-cache hits into zero-copy
 SHARES: a warm admission increfs the matched blocks instead of copying
-them (the PR-4 ``_prefix_copy_in`` device-to-device gather disappears),
-the trie holds one reference of its own for every cached block, and a
+them (no device-to-device copy), the trie holds one reference of its own for every cached block, and a
 block returns to the free list only when its LAST holder drops it —
 so a shared block can never be recycled under a live reader (the
 refcount-never-evicted property, tested). Everything here is host-side
 integers: alloc/incref/decref cost zero device dispatches.
 
-Block id 0 is RESERVED as the null/scratch block, same convention as
-the prefix pool: unoccupied block-table entries point at it, padded
+Block id 0 is RESERVED as the null/scratch block: unoccupied block-table entries point at it, padded
 gather/scatter programs write garbage into it, and it is never handed
 out by ``alloc``.
 """

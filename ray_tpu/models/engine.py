@@ -9,43 +9,47 @@ slot-based continuous batching is first-class here, built the XLA way:
   advance together, every row at its OWN cache offset (per-row scatter
   writes + per-row masks — no recompilation as requests come and go,
   no left-padding). H decode iterations run inside a single program
-  (`_decode_multi`: lax.scan + on-device sampling + per-row eos/budget
-  freezing), so the host pays ONE dispatch and ONE device->host
-  transfer per H tokens instead of a blocking sample per token — the
-  vLLM/Orca lesson that the decode inner loop must be free of host
-  synchronization, applied the XLA way.
+  (`_decode_multi_paged`: lax.scan + on-device sampling + per-row
+  eos/budget freezing), so the host pays ONE dispatch and ONE
+  device->host transfer per H tokens instead of a blocking sample per
+  token — the vLLM/Orca lesson that the decode inner loop must be free
+  of host synchronization, applied the XLA way.
 - Admission is a per-length-bucket BATCHED prefill program
-  (`_prefill_rows`): all same-bucket admissions of a step write their
-  prompts' K/V into freed slots' cache rows in one dispatch while the
-  other rows' state rides along untouched (donated buffers, in-place
+  (`_prefill_rows_paged`): all same-bucket admissions of a step write
+  their prompts' K/V into their rows' pool blocks in one dispatch while
+  the other rows' state rides along untouched (donated buffers, in-place
   in HBM). First tokens are sampled on device by the fused decode from
   the device-resident `last_logits` — admission costs zero host
   round-trips.
-- A finished row's slot is reused immediately: its stale K/V need no
-  clearing because every mask is `slot < row_len`, and the next
-  occupant's prefill overwrites from slot 0. Rows finishing
-  mid-horizon freeze on device (row_len stops, emits masked to -1)
-  and are retired by the host replay of the token block.
+- K/V lives in ONE refcounted pool of fixed-size token blocks behind
+  per-row block tables (models/block_pool.py): a finished row's blocks
+  return to the pool at once, a warm prompt SHARES its cached prefix
+  blocks (models/prefix_cache.py), and a row the pool cannot cover is
+  preempted and swapped or recomputed. A finished row's slot is reused
+  immediately: stale K/V need no clearing because every mask is
+  `slot < row_len`. Rows finishing mid-horizon freeze on device
+  (row_len stops, emits masked to -1) and are retired by the host
+  replay of the token block.
 - The decode loop is ASYNC double-buffered (`pipeline_depth`, default
   2): during pure-decode stretches (queue empty, nothing mid-prefill)
   the engine keeps a bounded ring of fused steps in flight, chaining
   each run-ahead dispatch off the previous one's device-carried row
   state and issuing `copy_to_host_async` on every token block, so the
   host replays step N's tokens while the device computes step N+1.
-  The ring is flushed before any admission/prefill/prefix copy (those
-  mutate the donated cache from the host side), and run-ahead
-  iterations on rows that finished mid-flight are masked on device and
-  accounted as `pipeline_overrun_tokens`.
+  The ring is flushed before any admission/prefill (those mutate the
+  donated pool from the host side), and run-ahead iterations on rows
+  that finished mid-flight are masked on device and accounted as
+  `pipeline_overrun_tokens`.
 - SPECULATIVE decoding composes with all of the above
   (`draft_params=`/`draft_cfg=`/`spec_window=`): the engine keeps a
-  second (draft) KV plane per slot — dense rings, or a second block
-  pool in paged mode — and each decode dispatch becomes ONE batched
-  draft-propose / target-verify round (`_spec_round`): the draft scans
-  up to `spec_window` greedy proposals for every live row, one batched
-  target pass verifies the [B, window+1] chunk, and per-row
-  acceptance / correction / eos / budget freezing happens on device,
-  so the host still sees a single [window+1, B] token block per
-  dispatch (the -1-trailing-column emit contract is unchanged). Greedy
+  second (draft) KV plane per slot — a second block pool — and each
+  decode dispatch becomes ONE batched draft-propose / target-verify
+  round (`_spec_round_paged`): the draft scans up to `spec_window`
+  greedy proposals for every live row, one batched target pass
+  verifies the [B, window+1] chunk, and per-row acceptance /
+  correction / eos / budget freezing happens on device, so the host
+  still sees a single [window+1, B] token block per dispatch (the
+  -1-trailing-column emit contract is unchanged). Greedy
   rows stay token-identical to solo `generate(greedy=True)`; sampled
   rows fall back to the plain fused decode per-row via the decode-mode
   lane (`submit(..., greedy=...)`) — rejection sampling is follow-up
@@ -90,7 +94,7 @@ from ray_tpu.models.engine_metrics import EngineMetrics, NullEngineMetrics
 from ray_tpu.models.engine_trace import resolve_tracer
 from ray_tpu.models.generate import (_check_sampling_knobs,
                                      _layer_body, forward_cached_rows,
-                                     init_cache, sample_rows)
+                                     sample_rows)
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
 from ray_tpu.models.moe import MoeConfig
@@ -173,30 +177,26 @@ class _EngineShardings:
     unsharded engine — shardings=None — compiles exactly what it did
     before).
 
-    ``cache``  [L, B, max_len, KV, D] — KV-head axis over "tp" (when
-               the model's n_kv_heads divides tp; replicated otherwise)
     ``logits`` [B, vocab]             — vocab over "tp"
-    ``pool``   the block pool, KV heads sharded like the cache's so
-               gathers and scatters stay chip-local. A paged engine's
-               pool is [L, NB, T, KV*D] (what the decode kernel reads):
-               the merged lane axis is head-major, so splitting it over
-               "tp" splits whole KV heads. The dense engine's prefix
-               pool is another object and keeps [L, NB, T, KV, D]
-    ``d_cache``/``d_pool`` — the DRAFT model's KV plane, pruned against
-               the draft config's own dims (a nano draft often can't
-               split its kv heads over the same mesh the target can).
-               None on non-speculative engines, so every existing
-               program signature hashes exactly as before.
+    ``pool``   the block pool [L, NB, T, KV*D] (what the decode kernel
+               reads) — KV heads over "tp" when the model's n_kv_heads
+               divides tp, replicated otherwise, so gathers and
+               scatters stay chip-local: the merged lane axis is
+               head-major, so splitting it over "tp" splits whole KV
+               heads
+    ``d_pool`` — the DRAFT model's pool, pruned against the draft
+               config's own dims (a nano draft often can't split its kv
+               heads over the same mesh the target can). None on
+               non-speculative engines, so every existing program
+               signature hashes exactly as before.
     ``scale``/``d_scale`` [L, NB, KV] — the quantized pool's per-block
                per-kv-head scale slabs, sharded by the SAME pruned KV
                rules as the pool they dequantize. None when kv_quant
                is off (again: identical hashes for existing engines).
     """
 
-    cache: NamedSharding
     logits: NamedSharding
     pool: NamedSharding
-    d_cache: Optional[NamedSharding] = None
     d_pool: Optional[NamedSharding] = None
     scale: Optional[NamedSharding] = None
     d_scale: Optional[NamedSharding] = None
@@ -206,7 +206,7 @@ class _EngineShardings:
         """Fully-replicated sharding on the same mesh — the [H, B]
         token block is pinned to it so the single device->host transfer
         stays whole on every chip (no cross-chip fetch at drain)."""
-        return NamedSharding(self.cache.mesh, P())
+        return NamedSharding(self.logits.mesh, P())
 
 
 # ---------------------------------------------------------------------------
@@ -263,352 +263,18 @@ def _forward_rows_counted(params, prompts, row_cache, starts, cfg,
     return logits, row_cache, moe_ctr.at[:2].add(st[:2])
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "shardings"),
-                   donate_argnames=("cache", "last_logits"))
-def _prefill_rows(params: Params, prompts: jax.Array, cache,
-                  last_logits, rows: jax.Array, starts: jax.Array,
-                  last_idx: jax.Array, cfg: LlamaConfig,
-                  shardings: Optional[_EngineShardings] = None,
-                  adapters: Optional[Params] = None,
-                  row_slot: Optional[jax.Array] = None,
-                  moe_ctr: Optional[jax.Array] = None):
-    """Batched admission/continuation prefill: write N same-bucket
-    chunks' [N, Cb] K/V into N slots in ONE program — each row at its
-    OWN cache offset ``starts[n]`` (0 for a cold admission; the cached
-    prefix length for a warm one; the chunk frontier for a chunked
-    continuation) — and scatter each row's last-real-token logits into
-    the engine's device-resident `last_logits` [B, vocab]. Returns
-    (cache, last_logits) — no logits ever cross to the host; the fused
-    decode program samples the first token on device, so an admission
-    costs zero host round-trips.
-
-    Cb may exceed a chunk's true length (length-bucketed serving):
-    trailing filler tokens' K/V land at slots >= the true frontier,
-    which every later mask excludes (`slot <= q_slot` caps decode
-    attention at the written frontier and the next chunk/decode write
-    overwrites them) — only the logits at `last_idx` (true chunk length
-    - 1) are read out, and only the FINAL chunk's scatter survives in
-    `last_logits` (earlier chunks' scatters are overwritten before the
-    row ever decodes). `rows` may contain duplicates (power-of-two
-    group padding repeats the last admission verbatim): duplicate
-    scatters write identical values, so the result is deterministic.
-
-    Multi-LoRA: ``adapters``/``row_slot`` (the pool stacks + this
-    chunk's PER-CHUNK slot lane [N], gathered from the engine's [B]
-    lane at the dispatch site) thread to `_layer_body`'s per-row
-    deltas; None (the default) adds no pytree leaves, so adapter-less
-    engines trace the exact pre-LoRA program. ``moe_ctr`` (an
-    `MoeConfig` engine's device-resident expert-layer counters, see
-    `_moe_count`) works the same way: None for a dense model."""
-    with jax.named_scope(sn.KV_GATHER):
-        row_cache = {"k": cache["k"][:, rows], "v": cache["v"][:, rows]}
-    logits, row_cache, moe_ctr = _forward_rows_counted(
-        params, prompts, row_cache, starts, cfg, adapters, row_slot,
-        moe_ctr, rows, last_idx)
-    with jax.named_scope(sn.KV_WRITE):
-        cache = {
-            "k": cache["k"].at[:, rows].set(row_cache["k"]),
-            "v": cache["v"].at[:, rows].set(row_cache["v"]),
-        }
-    n = prompts.shape[0]
-    with jax.named_scope(sn.LM_HEAD):
-        last = logits[jnp.arange(n), last_idx]          # [N, vocab]
-        out_logits = last_logits.at[rows].set(last)
-    if shardings is not None:
-        # Donated buffers must leave with the sharding they arrived in.
-        cache = jax.lax.with_sharding_constraint(cache, shardings.cache)
-        out_logits = jax.lax.with_sharding_constraint(
-            out_logits, shardings.logits)
-    return cache, out_logits, moe_ctr
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_blocks", "block_tokens",
-                                    "shardings"),
-                   donate_argnames=("cache",))
-def _prefix_copy_in(cache, pool_k, pool_v, block_ids: jax.Array,
-                    rows: jax.Array, n_blocks: int, block_tokens: int,
-                    shardings: Optional[_EngineShardings] = None):
-    """Copy cached prefix blocks into engine slot rows: ONE gather
-    program per step moves every warm admission's shared K/V from the
-    device-resident pool into its slot — zero host round-trips, the
-    same choke-point discipline as `_prefill_rows`.
-
-    pool_k/v: [L, NB, T, KV, D]; block_ids [N, n_blocks]; rows [N].
-    Row n's blocks land contiguously at slots [0, n_blocks*T). Both N
-    and n_blocks are power-of-two padded by the caller (repeat the last
-    row / the last block id), so a handful of compiles cover all chain
-    lengths: duplicate row scatters write identical values, and padded
-    trailing blocks write garbage BEYOND the row's matched prefix —
-    slots the suffix prefill and decode overwrite before any mask ever
-    admits them."""
-    span = n_blocks * block_tokens
-    with jax.named_scope(sn.KV_GATHER):
-        blk_k = pool_k[:, block_ids]      # [L, N, nb, T, KV, D]
-        blk_v = pool_v[:, block_ids]
-    if shardings is not None:
-        # Sharded gather: pool and cache carry the same KV-head
-        # sharding, so pin the gathered blocks to it too — each chip
-        # gathers ONLY its heads' slice of the pool and scatters it
-        # into its own cache shard; no cross-chip block traffic.
-        sp = shardings.pool.spec          # (l, nb, t, kv, d)
-        blk_spec = NamedSharding(
-            shardings.pool.mesh, P(sp[0], None, sp[1], sp[2], sp[3],
-                                   sp[4]))
-        blk_k = jax.lax.with_sharding_constraint(blk_k, blk_spec)
-        blk_v = jax.lax.with_sharding_constraint(blk_v, blk_spec)
-    L, N = blk_k.shape[:2]
-    k = blk_k.reshape(L, N, span, *blk_k.shape[4:])
-    v = blk_v.reshape(L, N, span, *blk_v.shape[4:])
-    with jax.named_scope(sn.KV_WRITE):
-        out = {
-            "k": cache["k"].at[:, rows, :span].set(
-                k.astype(cache["k"].dtype)),
-            "v": cache["v"].at[:, rows, :span].set(
-                v.astype(cache["v"].dtype)),
-        }
-    if shardings is not None:
-        out = jax.lax.with_sharding_constraint(out, shardings.cache)
-    return out
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_blocks", "block_tokens",
-                                    "shardings"),
-                   donate_argnames=("pool_k", "pool_v"))
-def _prefix_copy_out(cache_k, cache_v, pool_k, pool_v, row,
-                     start_slot, block_ids: jax.Array, n_blocks: int,
-                     block_tokens: int,
-                     shardings: Optional[_EngineShardings] = None):
-    """Insert a freshly prefilled prefix into the pool: slice
-    [start_slot, start_slot + n_blocks*T) out of one slot row and
-    scatter it into the pool at ``block_ids`` — one program per novel
-    prefix segment, dispatched right after the chunk that produced it
-    (dispatch order guarantees any copy-in already in flight still
-    reads the blocks' OLD content). n_blocks is power-of-two padded
-    with the reserved scratch block id 0: padding writes (clamped
-    slices of whatever follows the segment) land in the scratch block,
-    which the index never hands out."""
-    span = n_blocks * block_tokens
-    max_len = cache_k.shape[2]
-    with jax.named_scope(sn.KV_GATHER):
-        slots = jnp.minimum(start_slot + jnp.arange(span), max_len - 1)
-        row_k = jnp.take(cache_k, row, axis=1)      # [L, max_len, KV, D]
-        row_v = jnp.take(cache_v, row, axis=1)
-        seg_k = jnp.take(row_k, slots, axis=1)      # [L, span, KV, D]
-        seg_v = jnp.take(row_v, slots, axis=1)
-        L = seg_k.shape[0]
-        seg_k = seg_k.reshape(L, n_blocks, block_tokens,
-                              *seg_k.shape[2:])
-        seg_v = seg_v.reshape(L, n_blocks, block_tokens,
-                              *seg_v.shape[2:])
-    with jax.named_scope(sn.KV_WRITE):
-        pool_k = pool_k.at[:, block_ids].set(seg_k.astype(pool_k.dtype))
-        pool_v = pool_v.at[:, block_ids].set(seg_v.astype(pool_v.dtype))
-    if shardings is not None:
-        # Sharded scatter, the mirror of copy-in's gather: cache row
-        # and pool share the KV-head sharding, so each chip writes its
-        # own heads' slice of the block. Donated pools keep layout.
-        pool_k = jax.lax.with_sharding_constraint(pool_k, shardings.pool)
-        pool_v = jax.lax.with_sharding_constraint(pool_v, shardings.pool)
-    return pool_k, pool_v
-
-
-def _decode_layer_rows(h, layer, k_cache, v_cache, write_slots,
-                       cfg: LlamaConfig, lora=None, lora_slots=None,
-                       moe_live=None):
-    """One decoder layer, one new token per row, each row writing its
-    K/V at its own slot (scatter) and attending its own prefix.
-
-    h: [B, 1, d]; caches [B, max_len, KV, D]; write_slots: [B].
-
-    All the per-layer math lives in generate.py's `_layer_body` (one
-    source of truth for both decode paths); only the cache-write
-    strategy differs — per-row scatter here vs the contiguous chunk
-    slice in `_cached_layer`. The per-prefix causal mask falls out of
-    `_cached_attention` with q_slots = each row's own write slot and
-    kv_valid_len = max_len (dead slots beyond a row's frontier are
-    already excluded by `slot <= write_slot`)."""
-    B = h.shape[0]
-    bidx = jnp.arange(B)
-
-    def write_kv(k_cache, v_cache, k, v):
-        k_cache = k_cache.at[bidx, write_slots].set(
-            k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[bidx, write_slots].set(
-            v[:, 0].astype(v_cache.dtype))
-        return k_cache, v_cache
-
-    return _layer_body(h, layer, k_cache, v_cache,
-                       write_slots[:, None], write_kv,
-                       write_slots[:, None], k_cache.shape[1], cfg,
-                       lora=lora, lora_slots=lora_slots,
-                       moe_live=moe_live)
-
-
-def _decode_core(params: Params, toks: jax.Array, cache, row_len,
-                 cfg: LlamaConfig, adapters=None, row_slot=None,
-                 moe_live=None):
-    """One decode step for ALL slots: row b's token `toks[b]` is
-    written at slot `row_len[b]` and attends slots [0, row_len[b]].
-    Dead/frozen rows compute discarded garbage at their frontier slot —
-    it lands one past their real tokens (or at slot 0 for empty rows)
-    and is overwritten by the next occupant's prefill, with every mask
-    excluding it meanwhile. Returns (next-token logits [B, vocab] f32,
-    cache, the expert layers' per-layer counts [L, 3] or None: see
-    `_moe_count`). Plain function so `_decode_multi`'s scan can inline
-    it."""
-    write_slots = row_len                                   # [B]
-    with jax.named_scope(sn.EMBED):
-        h = params["tok_embed"].astype(cfg.dtype)[toks[:, None]]
-
-    def body(carry, xs):
-        h = carry
-        if adapters is None:
-            layer, k_c, v_c = xs
-            lora = None
-        else:
-            layer, k_c, v_c, lora = xs
-        h, k_c, v_c, st = _decode_layer_rows(
-            h, layer, k_c, v_c, write_slots, cfg, lora=lora,
-            lora_slots=row_slot, moe_live=moe_live)
-        return h, (k_c, v_c, st)
-
-    xs = (params["layers"], cache["k"], cache["v"])
-    if adapters is not None:
-        xs = xs + (adapters,)
-    h, (k_new, v_new, moe_stats) = jax.lax.scan(body, h, xs)
-    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope(sn.LM_HEAD):
-        logits = jnp.einsum("bsd,dv->bsv", h,
-                            params["lm_head"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    return logits[:, 0], {"k": k_new, "v": v_new}, moe_stats
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "horizon", "greedy",
-                                    "top_k", "top_p", "eos_id",
-                                    "shardings"),
-                   donate_argnames=("cache", "last_logits"))
-def _decode_multi(params: Params, cache, last_logits, row_len, active,
-                  budget, tok_idx, row_keys, row_greedy, temperature,
-                  cfg: LlamaConfig, horizon: int, greedy: bool,
-                  top_k: Optional[int], top_p: Optional[float],
-                  eos_id: Optional[int],
-                  shardings: Optional[_EngineShardings] = None,
-                  adapters: Optional[Params] = None,
-                  row_slot: Optional[jax.Array] = None,
-                  moe_ctr: Optional[jax.Array] = None):
-    """Fuse `horizon` decode iterations into ONE program: a `lax.scan`
-    whose body samples every row's next token ON DEVICE from the
-    carried `last_logits` (greedy argmax, or per-row rng streams — see
-    generate.sample_rows), feeds it through `_decode_core`, and applies
-    per-row eos/budget/room masking so rows that finish mid-horizon
-    FREEZE: their row_len stops advancing, their `last_logits` stops
-    updating, and their remaining emits are masked to -1. The host gets
-    the whole [horizon, B] token block in a single transfer instead of
-    one blocking sample per token.
-
-    Per-iteration transition (bit-identical to the host replay in
-    `DecodeEngine._emit_block`, which mirrors it without touching the
-    device):
-        tok      = sample(last_logits)          # emit if active
-        budget  -= active;  tok_idx += active
-        done     = budget <= 0 | row_len+1 >= max_len | tok == eos
-        feed tok at slot row_len (all rows; frozen rows write garbage
-        one slot past their content — masked everywhere, overwritten by
-        the slot's next prefill)
-        row_len += active & ~done;  last_logits updates where continuing
-
-    Returns (toks [horizon, B] int32, cache, last_logits, row_len,
-    active, budget, tok_idx) — the FULL scan carry, not just the token
-    block. `last_logits` carries across calls, so the final iteration's
-    decode is never wasted — the next horizon samples straight from it
-    — and the carried row state lets the async pipeline chain a
-    run-ahead dispatch directly off the previous one's device arrays,
-    with zero host synchronization between dispatches (the host's own
-    row_len/budget copies catch up when it drains the token block).
-
-    `row_greedy` is the per-row DECODE-MODE lane (bool [B]): when the
-    static `greedy` flag is False (some live row samples), rows whose
-    lane is True still take the argmax so a mixed batch serves both
-    modes in one program. When `greedy` is True the lane is dead code
-    and XLA drops it — the all-greedy fast path compiles exactly what
-    it always did."""
-    max_len = cache["k"].shape[2]
-
-    def body(carry, _):
-        cache, last_logits, row_len, active, budget, tok_idx, \
-            moe_ctr = carry
-        with jax.named_scope(sn.SAMPLE):
-            tok = sample_rows(last_logits, row_keys, tok_idx,
-                              greedy=greedy, temperature=temperature,
-                              top_k=top_k, top_p=top_p)
-            if not greedy:
-                tok = jnp.where(
-                    row_greedy,
-                    jnp.argmax(last_logits, axis=-1).astype(tok.dtype),
-                    tok)
-            emit = jnp.where(active, tok, -1)
-            live = active.astype(jnp.int32)
-            budget = budget - live
-            tok_idx = tok_idx + live
-            done_now = (budget <= 0) | (row_len + 1 >= max_len)
-            if eos_id is not None:
-                done_now = done_now | (tok == eos_id)
-            cont = active & ~done_now
-        logits, cache, moe_stats = _decode_core(
-            params, tok, cache, row_len, cfg, adapters=adapters,
-            row_slot=row_slot,
-            moe_live=None if moe_ctr is None else cont[:, None])
-        if moe_ctr is not None:
-            moe_ctr = _moe_count(moe_ctr, moe_stats)
-        with jax.named_scope(sn.SAMPLE):
-            row_len = row_len + cont.astype(jnp.int32)
-            last_logits = jnp.where(cont[:, None], logits, last_logits)
-        if shardings is not None:
-            # Pin the scan carry to the engine's layout every
-            # iteration: the KV write stays a chip-local scatter (each
-            # chip owns its heads' cache shard) and the carried logits
-            # stay vocab-sharded — XLA partitions attention heads and
-            # MLP width instead of replicating the whole model.
-            cache = jax.lax.with_sharding_constraint(
-                cache, shardings.cache)
-            last_logits = jax.lax.with_sharding_constraint(
-                last_logits, shardings.logits)
-        return (cache, last_logits, row_len, cont, budget,
-                tok_idx, moe_ctr), emit
-
-    (cache, last_logits, row_len, active, budget, tok_idx,
-     moe_ctr), toks = jax.lax.scan(
-        body, (cache, last_logits, row_len, active, budget, tok_idx,
-               moe_ctr),
-        None, length=horizon)
-    if moe_ctr is not None:
-        toks = _append_moe_ctr(toks, moe_ctr)
-    if shardings is not None:
-        # The [H, B] block is the ONE device->host transfer: keep it
-        # fully replicated so the drain reads whole from any chip —
-        # host-sync bytes stay 4*H*B regardless of tp degree.
-        toks = jax.lax.with_sharding_constraint(
-            toks, shardings.replicated)
-    return toks, cache, last_logits, row_len, active, budget, tok_idx, \
-        moe_ctr
-
-
 def _spec_accept(chunk, proposals, ver, v_logits, last_logits, row_len,
                  active, budget, tok_idx, d_tok, row_greedy, w_row,
                  window: int, eos_id: Optional[int], max_len: int):
-    """On-device acceptance/correction/freeze shared by the dense and
-    paged speculative rounds — the batched analog of the solo accept
-    loop in models/speculative.py, fused so the host never sees logits.
+    """On-device acceptance/correction/freeze of a speculative round —
+    the batched analog of the solo accept loop in models/speculative.py,
+    fused so the host never sees logits.
 
     Per row: count the longest prefix of `proposals` matching the
     target's argmax continuation `ver` (capped at the row's adaptive
     width `w_row`; forced 0 on sampled rows — their lane emits just the
     t0 they sampled), emit `[t0, d_1..d_a, correction]` truncated by
-    eos / budget / room exactly like `_decode_multi`'s per-iteration
+    eos / budget / room exactly like `_decode_multi_paged`'s per-iteration
     masking, and carry the corrected `last_logits` so the next round's
     t0 is this round's on-device correction. Returns the -1-trailing
     [window+1, B] emit block plus the advanced carry, including the
@@ -649,124 +315,25 @@ def _spec_accept(chunk, proposals, ver, v_logits, last_logits, row_len,
         d_lag, d_tok
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "d_cfg", "window", "greedy",
-                                    "top_k", "top_p", "eos_id",
-                                    "shardings"),
-                   donate_argnames=("cache", "d_cache", "last_logits"))
-def _spec_round(params: Params, d_params: Params, cache, d_cache,
-                last_logits, row_len, active, budget, tok_idx, d_lag,
-                d_tok, row_keys, row_greedy, w_row, temperature,
-                cfg: LlamaConfig, d_cfg: LlamaConfig, window: int,
-                greedy: bool, top_k: Optional[int],
-                top_p: Optional[float], eos_id: Optional[int],
-                shardings: Optional[_EngineShardings] = None):
-    """ONE batched draft-propose / target-verify round for every live
-    row — the speculative replacement for a `_decode_multi` dispatch.
-
-    Round structure (greedy rows; sampled rows ride the same program
-    with acceptance forced to 0, so they advance exactly one sampled
-    token per round — their solo stream):
-
-      t0        = argmax(last_logits)         # last round's correction
-      draft     consumes its 2-wide catch-up chunk at `row_len - d_lag`
-                (the fixed-width lag trick: after a fully-accepted
-                round the draft still owes its final proposal — carried
-                in `d_tok` with `d_lag`=1 — so the consume chunk is
-                always exactly [pend, t0] and the program never
-                recompiles on acceptance length), then scans
-                `window - 1` more greedy proposals at slots
-                row_len+1+j.
-      verify    ONE target pass over [t0, d_1..d_W] at `row_len` — the
-                chunk-verify program that feeds the MXU.
-      accept    `_spec_accept` on device; stale K/V from rejected
-                candidates sits exactly where next round's writes land
-                (write-before-attend, same argument as solo spec).
-
-    Emitted tokens are ALWAYS the target's own argmax chain — a stale
-    or cold draft plane can only shrink acceptance, never change
-    output — which is what makes swap-in re-seeding and cold draft
-    admissions safe. Returns the [window+1, B] -1-trailing emit block
-    plus the full carry (incl. the draft plane and lag lane), so the
-    async pipeline chains speculative run-ahead dispatches exactly like
-    plain ones."""
-    B = row_len.shape[0]
-    bidx = jnp.arange(B)
-    W = window
-    max_len = cache["k"].shape[2]
-
-    with jax.named_scope(sn.SAMPLE):
-        t_greedy = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-        if greedy:
-            t0 = t_greedy
-        else:
-            t_samp = sample_rows(last_logits, row_keys, tok_idx,
-                                 greedy=False, temperature=temperature,
-                                 top_k=top_k, top_p=top_p)
-            t0 = jnp.where(row_greedy, t_greedy, t_samp)
-
-    # Draft: catch-up consume, then propose W greedy tokens.
-    pend = jnp.where(d_lag == 1, d_tok, t0)
-    chunk2 = jnp.stack([pend, t0], axis=1)           # [B, 2]
-    d_logits, d_cache = forward_cached_rows(
-        d_params, chunk2, d_cache, row_len - d_lag, d_cfg)
-    first = jnp.argmax(d_logits[bidx, d_lag],
-                       axis=-1).astype(jnp.int32)
-
-    def dstep(carry, j):
-        tok, d_cache = carry
-        lg, d_cache = forward_cached_rows(
-            d_params, tok[:, None], d_cache, row_len + 1 + j, d_cfg)
-        nxt = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)
-        return (nxt, d_cache), tok
-
-    (lastp, d_cache), dtoks = jax.lax.scan(
-        dstep, (first, d_cache), jnp.arange(W - 1))
-    proposals = jnp.concatenate([dtoks.T, lastp[:, None]], axis=1) \
-        if W > 1 else lastp[:, None]                 # [B, W]
-
-    # Target: one batched verify over [t0, d_1..d_W].
-    chunk = jnp.concatenate([t0[:, None], proposals], axis=1)
-    v_logits, cache = forward_cached_rows(params, chunk, cache,
-                                          row_len, cfg)
-    ver = jnp.argmax(v_logits, axis=-1).astype(jnp.int32)
-
-    with jax.named_scope(sn.SAMPLE):
-        (emits, last_logits, row_len, active, budget, tok_idx, d_lag,
-         d_tok) = _spec_accept(chunk, proposals, ver, v_logits,
-                               last_logits, row_len, active, budget,
-                               tok_idx, d_tok, row_greedy, w_row, W,
-                               eos_id, max_len)
-    if shardings is not None:
-        cache = jax.lax.with_sharding_constraint(cache,
-                                                 shardings.cache)
-        d_cache = jax.lax.with_sharding_constraint(d_cache,
-                                                   shardings.d_cache)
-        last_logits = jax.lax.with_sharding_constraint(
-            last_logits, shardings.logits)
-        emits = jax.lax.with_sharding_constraint(emits,
-                                                 shardings.replicated)
-    return (emits, cache, d_cache, last_logits, row_len, active,
-            budget, tok_idx, d_lag, d_tok)
-
-
 # ---------------------------------------------------------------------------
-# Compiled programs — paged KV mode
+# Compiled programs over the block pool
 # ---------------------------------------------------------------------------
-# The paged engine has NO dense per-slot cache: every request's K/V
-# lives in fixed-size token blocks of ONE device pool
-# [L, NB, T, KV*D] (the same pool the prefix cache commits into; a
-# token's KV heads merged head-major into one lane axis, the layout the
-# decode kernel reads pages in) and each program reaches it through the
-# per-row block table bt [B, MB]. Every program takes the pool donated
-# and updates it in place; none holds a second copy of it.
-# MB * T == max_len is enforced at construction, so the gathered
-# per-row view has EXACTLY the dense cache row's shape and every
-# program below is the dense program evaluated on that view — which is
-# what makes paged output bit-identical to the dense engine and to
-# solo `generate` (tests/test_engine_paged.py). Block id 0 is the
-# reserved null block: unallocated table entries point at it, padded
-# gathers/scatters dump garbage into it, and no mask ever admits it.
+# The engine has NO per-slot cache: every request's K/V lives in
+# fixed-size token blocks of ONE device pool [L, NB, T, KV*D] (the same
+# pool the prefix cache commits into; a token's KV heads merged
+# head-major into one lane axis, the layout the decode kernel reads
+# pages in) and each program reaches it through the per-row block table
+# bt [B, MB]. Every program takes the pool donated and updates it in
+# place; none holds a second copy of it. MB * T == max_len is enforced
+# at construction, so a row's gathered view has EXACTLY the shape of the
+# cache solo `generate` keeps for it, and prefill is `generate`'s own
+# `forward_cached_rows` evaluated on that view — which is what makes
+# engine output bit-identical to solo `generate`
+# (tests/test_engine_paged.py). Block id 0 is the reserved null block:
+# unallocated table entries point at it, padded gathers/scatters dump
+# garbage into it, and no mask ever admits it. (The `_paged` suffix of
+# the jitted names dates from when a dense twin existed; the
+# benchmark's trace readers match it.)
 
 
 def _gather_pages(pools, ids):
@@ -801,6 +368,47 @@ def _gather_pages(pools, ids):
     return tuple(out.reshape(L, *ids.shape, T, W) for out in outs)
 
 
+def _zero_pools(n_layers: int, n_blocks: int, block_tokens: int,
+                kv_heads: int, head_dim: int, dtype, quantized: bool,
+                shardings: Optional[_EngineShardings]):
+    """A plane's zeroed (pool_k, pool_v, scale_k, scale_v): pools
+    [L, NB, T, KV*D] — the layout the decode kernel reads, one page one
+    contiguous [T, KV*D] slab, heads merged head-major — and, quantized,
+    their f32 scale slabs [L, NB, KV] (else None). Zero scales: dequant
+    of the zero-initialised pool (incl. the null block) is exactly 0.0
+    everywhere. Under a mesh both are placed by ``shardings`` (the
+    plane's own pool/scale in the primary slots)."""
+    shape = (n_layers, n_blocks, block_tokens, kv_heads * head_dim)
+    out = [jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)]
+    if quantized:
+        out += [jnp.zeros((n_layers, n_blocks, kv_heads), jnp.float32)
+                for _ in range(2)]
+    else:
+        out += [None, None]
+    if shardings is not None:
+        out = [x if x is None else jax.device_put(
+            x, shardings.pool if i < 2 else shardings.scale)
+            for i, x in enumerate(out)]
+    return tuple(out)
+
+
+def _pin_pools(shardings, pool_k, pool_v, scale_k, scale_v,
+               draft: bool = False):
+    """Donated pools (and a quantized pool's scale slabs) leave a
+    program with the sharding they arrived in: under a mesh the KV
+    write stays a chip-local scatter, each chip owning its heads' lanes.
+    No-op without a mesh."""
+    if shardings is None:
+        return pool_k, pool_v, scale_k, scale_v
+    pool_sh, scale_sh = (shardings.d_pool, shardings.d_scale) if draft \
+        else (shardings.pool, shardings.scale)
+    pin = jax.lax.with_sharding_constraint
+    pool_k, pool_v = pin(pool_k, pool_sh), pin(pool_v, pool_sh)
+    if scale_k is not None and scale_sh is not None:
+        scale_k, scale_v = pin(scale_k, scale_sh), pin(scale_v, scale_sh)
+    return pool_k, pool_v, scale_k, scale_v
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "shardings",
                                              "qspec"),
                    donate_argnames=("pool_k", "pool_v", "scale_k",
@@ -815,12 +423,30 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
                         scale_k=None, scale_v=None,
                         qspec: Optional[KVQuantSpec] = None,
                         moe_ctr: Optional[jax.Array] = None):
-    """`_prefill_rows` for the block pool: gather each admission row's
-    full [max_len] view through its block table, run the SAME
-    `forward_cached_rows` math, scatter the view back block-by-block.
-    One program per length bucket, zero host round-trips, and —
-    because MB*T == max_len — the exact op sequence of the dense
-    prefill on identical shapes.
+    """Batched admission/continuation prefill: N same-bucket chunks
+    [N, Cb] in ONE program, each row at its OWN offset ``starts[n]`` (0
+    for a cold admission; the shared prefix length for a warm one; the
+    chunk frontier for a chunked continuation). Gathers each row's full
+    [max_len] view through its block table, runs solo `generate`'s
+    `forward_cached_rows` on it, scatters the view back block-by-block,
+    and scatters each row's last-real-token logits into the engine's
+    device-resident `last_logits` [B, vocab]. No logits ever cross to
+    the host: the fused decode samples the first token on device, so an
+    admission costs zero host round-trips.
+
+    Cb may exceed a chunk's true length (length-bucketed serving):
+    trailing filler tokens' K/V land at slots >= the true frontier,
+    which every later mask excludes and the next write overwrites —
+    only the logits at `last_idx` (true chunk length - 1) are read out,
+    and only the FINAL chunk's scatter survives in `last_logits`.
+    `rows` may contain duplicates (power-of-two group padding repeats
+    the last admission verbatim): duplicate scatters write identical
+    values.
+
+    ``adapters``/``row_slot`` (the LoRA pool stacks + this chunk's slot
+    lane [N]) thread to `_layer_body`'s per-row deltas; ``moe_ctr`` is
+    an `MoeConfig` engine's expert-layer counters (`_moe_count`). Both
+    default to None, which adds no pytree leaves: the same program.
 
     The whole-view write-back is safe by construction: each row only
     MODIFIES view slots [start, start+S) (its own private suffix
@@ -844,8 +470,8 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
         # [L, N, MB, T, KV*D]
         blk_k, blk_v = _gather_pages((pool_k, pool_v), bt)
     if shardings is not None:
-        # Same chip-local discipline as _prefix_copy_in: the gathered
-        # view carries the pool's KV-head sharding.
+        # The gathered view carries the pool's KV-head sharding: each
+        # chip gathers ONLY its heads' lanes, no cross-chip block traffic.
         sp = shardings.pool.spec           # (l, nb, t, kv*d)
         blk_spec = NamedSharding(
             shardings.pool.mesh, P(sp[0], None, sp[1], sp[2], sp[3]))
@@ -896,14 +522,9 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
     with jax.named_scope(sn.LM_HEAD):
         last = logits[jnp.arange(n), last_idx]          # [N, vocab]
         out_logits = last_logits.at[rows].set(last)
+    pool_k, pool_v, scale_k, scale_v = _pin_pools(
+        shardings, pool_k, pool_v, scale_k, scale_v)
     if shardings is not None:
-        pool_k = jax.lax.with_sharding_constraint(pool_k, shardings.pool)
-        pool_v = jax.lax.with_sharding_constraint(pool_v, shardings.pool)
-        if qspec is not None and shardings.scale is not None:
-            scale_k = jax.lax.with_sharding_constraint(
-                scale_k, shardings.scale)
-            scale_v = jax.lax.with_sharding_constraint(
-                scale_v, shardings.scale)
         out_logits = jax.lax.with_sharding_constraint(
             out_logits, shardings.logits)
     return pool_k, pool_v, scale_k, scale_v, out_logits, moe_ctr
@@ -914,15 +535,19 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
                              lora_slots=None,
                              qspec: Optional[KVQuantSpec] = None,
                              moe_live=None):
-    """`_decode_layer_rows` against the pool, S tokens a row (1 in the
-    fused decode, the window in a speculative round): row b's new K/V
-    scatter into layer ``li`` of the WHOLE pool, at physical block
-    ``bt[b, slot//T]`` and offset ``slot%T``, and attention reads back
-    through `ops.attention.paged_attention` (the block-table gather +
-    `_cached_attention`'s exact op sequence, or the kernel), which is
-    handed the whole pool and ``li`` as well. The pool is the layer
-    scan's carry: the scatter updates it in place and nothing of a
-    layer's or the pool's size is sliced, relaid or restacked.
+    """One decoder layer against the pool, S tokens a row (1 in the
+    fused decode, the window in a speculative round), each row writing
+    at its own slots and attending its own prefix. All the per-layer
+    math lives in generate.py's `_layer_body` (one source of truth with
+    solo `generate`); only the cache write and the attention read
+    differ. Row b's new K/V scatter into layer ``li`` of the WHOLE
+    pool, at physical block ``bt[b, slot//T]`` and offset ``slot%T``,
+    and attention reads back through `ops.attention.paged_attention`
+    (the block-table gather + `_cached_attention`'s exact op sequence,
+    or the kernel), which is handed the whole pool and ``li`` as well.
+    The pool is the layer scan's carry: the scatter updates it in place
+    and nothing of a layer's or the pool's size is sliced, relaid or
+    restacked.
 
     Frontier blocks are always private to their row — a shared block
     is never a write target (full-prompt prefix hits copy-on-write
@@ -975,10 +600,15 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
                        row_slot=None, scale_k=None, scale_v=None,
                        qspec: Optional[KVQuantSpec] = None,
                        moe_live=None):
-    """`_decode_core` over the pool: feed each row's [S] chunk at slots
-    ``starts + arange(S)`` and return the [B, S, vocab] logits. The
-    fused decode (S = 1), the draft consume/scan steps and the target
-    verify pass are all this one shape family.
+    """One step for ALL slots: feed each row's [S] chunk at slots
+    ``starts + arange(S)``, attending slots up to its own, and return
+    the [B, S, vocab] f32 logits (plus the pool and the expert layers'
+    per-layer counts [L, 3] or None: see `_moe_count`). The fused decode
+    (S = 1), the draft consume/scan steps and the target verify pass
+    are all this one shape family. Dead/frozen rows compute discarded
+    garbage at their frontier slot — one past their real tokens, or the
+    null block for empty rows — which every mask excludes and the next
+    occupant's prefill overwrites.
 
     The layer scan takes ``(layer weights, layer index)`` as ``xs`` and
     CARRIES the pool (and a quantized pool's scale slabs) beside the
@@ -1032,21 +662,43 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
                         scale_k=None, scale_v=None,
                         qspec: Optional[KVQuantSpec] = None,
                         moe_ctr: Optional[jax.Array] = None):
-    """`_decode_multi` with the pool + block tables standing in for
-    the dense cache: identical scan body, identical per-iteration
-    transition, identical [H, B] single-transfer contract — only the
-    KV write (block scatter) and the attention read (block-table
-    gather) differ, both inside `_decode_core_paged`. The block table
-    is a step invariant: the host grows/rebuilds it between
+    """Fuse `horizon` decode iterations into ONE program: a `lax.scan`
+    whose body samples every row's next token ON DEVICE from the
+    carried `last_logits` (greedy argmax, or per-row rng streams — see
+    generate.sample_rows), feeds it through `_decode_core_paged`, and
+    applies per-row eos/budget/room masking so rows that finish
+    mid-horizon FREEZE: their row_len stops advancing, their
+    `last_logits` stops updating, and their remaining emits are masked
+    to -1. The host gets the whole [horizon, B] token block in a single
+    transfer instead of one blocking sample per token.
+
+    Per-iteration transition (bit-identical to the host replay in
+    `DecodeEngine._emit_block`, which mirrors it without touching the
+    device):
+        tok      = sample(last_logits)          # emit if active
+        budget  -= active;  tok_idx += active
+        done     = budget <= 0 | row_len+1 >= max_len | tok == eos
+        feed tok at slot row_len (all rows; frozen rows write garbage
+        one slot past their content — masked everywhere, overwritten by
+        the slot's next prefill)
+        row_len += active & ~done;  last_logits updates where continuing
+
+    Returns the token block and the FULL scan carry: the next horizon
+    samples straight from the carried `last_logits`, and the async
+    pipeline chains a run-ahead dispatch off the carried row state with
+    no host synchronization between dispatches. `row_greedy` (bool [B])
+    makes rows take the argmax in a program whose static `greedy` is
+    False, so a mixed batch serves both modes in one program.
+
+    The block table is a step invariant: the host grows it between
     dispatches, never inside one. The pool is ONE donated buffer: this
-    scan over the horizon carries it, the layer scan inside
-    `_decode_core_paged` carries it again, each token's K/V is
-    scattered into it in place and the kernel reads pages out of it
-    where they lie, so the program holds no second pool and moves
-    nothing of a layer's size (tests/test_tpu_compile.py holds the
-    compiled program to that). A quantized pool adds the scale
-    slabs to the fused carry; qspec=None leaves every pytree and the
-    traced program exactly as before."""
+    scan carries it, the layer scan inside `_decode_core_paged` carries
+    it again, each token's K/V is scattered into it in place and the
+    kernel reads pages where they lie, so the program holds no second
+    pool and moves nothing of a layer's size (tests/test_tpu_compile.py
+    holds the compiled program to that). A quantized pool adds its
+    scale slabs to the carry; qspec=None leaves every pytree and the
+    traced program as they were."""
     max_len = bt.shape[1] * pool_k.shape[2]
 
     def body(carry, _):
@@ -1081,16 +733,13 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
         with jax.named_scope(sn.SAMPLE):
             row_len = row_len + cont.astype(jnp.int32)
             last_logits = jnp.where(cont[:, None], logits, last_logits)
+        # Pin the scan carry to the engine's layout every iteration:
+        # the carried logits stay vocab-sharded beside the pool, so XLA
+        # partitions attention heads and MLP width instead of
+        # replicating the whole model.
+        pool_k, pool_v, scale_k, scale_v = _pin_pools(
+            shardings, pool_k, pool_v, scale_k, scale_v)
         if shardings is not None:
-            pool_k = jax.lax.with_sharding_constraint(
-                pool_k, shardings.pool)
-            pool_v = jax.lax.with_sharding_constraint(
-                pool_v, shardings.pool)
-            if qspec is not None and shardings.scale is not None:
-                scale_k = jax.lax.with_sharding_constraint(
-                    scale_k, shardings.scale)
-                scale_v = jax.lax.with_sharding_constraint(
-                    scale_v, shardings.scale)
             last_logits = jax.lax.with_sharding_constraint(
                 last_logits, shardings.logits)
         return (pool_k, pool_v, scale_k, scale_v, last_logits, row_len,
@@ -1104,6 +753,9 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
     if moe_ctr is not None:
         toks = _append_moe_ctr(toks, moe_ctr)
     if shardings is not None:
+        # The [H, B] block is the ONE device->host transfer: keep it
+        # fully replicated so the drain reads whole from any chip —
+        # host-sync bytes stay 4*H*B regardless of tp degree.
         toks = jax.lax.with_sharding_constraint(
             toks, shardings.replicated)
     return (toks, pool_k, pool_v, scale_k, scale_v, last_logits,
@@ -1129,11 +781,35 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
                       scale_k=None, scale_v=None, scale_dk=None,
                       scale_dv=None,
                       qspec: Optional[KVQuantSpec] = None):
-    """`_spec_round` over the block pools: the target plane reaches its
-    K/V through `bt`, the draft plane through its own private table
-    `bt_d` (draft blocks are never shared — the trie only indexes the
-    target pool). Same round structure, same `_spec_accept`, same emit
-    contract. With kv_quant BOTH planes are quantized — each pool
+    """ONE batched draft-propose / target-verify round for every live
+    row — the speculative replacement for a `_decode_multi_paged`
+    dispatch. The target plane reaches its K/V through `bt`, the draft
+    plane through its own private table `bt_d` (draft blocks are never
+    shared — the trie only indexes the target pool).
+
+    Round structure (greedy rows; sampled rows ride the same program
+    with acceptance forced to 0, so they advance exactly one sampled
+    token per round — their solo stream):
+
+      t0        = argmax(last_logits)         # last round's correction
+      draft     consumes its 2-wide catch-up chunk at `row_len - d_lag`
+                (after a fully-accepted round the draft still owes its
+                final proposal — carried in `d_tok` with `d_lag`=1 — so
+                the chunk is always [pend, t0] and the program never
+                recompiles on acceptance length), then scans
+                `window - 1` more greedy proposals at row_len+1+j.
+      verify    ONE target pass over [t0, d_1..d_W] at `row_len`.
+      accept    `_spec_accept` on device; stale K/V from rejected
+                candidates sits exactly where next round's writes land
+                (write-before-attend, same argument as solo spec).
+
+    Emitted tokens are ALWAYS the target's own argmax chain — a stale
+    or cold draft plane can only shrink acceptance, never change
+    output — which is what makes swap-in re-seeding and cold draft
+    admissions safe. Returns the [window+1, B] -1-trailing emit block
+    plus the full carry, so the async pipeline chains speculative
+    run-ahead dispatches like plain ones. With kv_quant BOTH planes are
+    quantized — each pool
     carries its own scale slab; a rejected window's stale K/V is
     zeroed out of the next overlapping write's absmax by
     `paged_quant_write`, so no-rollback cache discipline still holds."""
@@ -1188,25 +864,11 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
                                last_logits, row_len, active, budget,
                                tok_idx, d_tok, row_greedy, w_row, W,
                                eos_id, max_len)
+    pool_k, pool_v, scale_k, scale_v = _pin_pools(
+        shardings, pool_k, pool_v, scale_k, scale_v)
+    pool_dk, pool_dv, scale_dk, scale_dv = _pin_pools(
+        shardings, pool_dk, pool_dv, scale_dk, scale_dv, draft=True)
     if shardings is not None:
-        pool_k = jax.lax.with_sharding_constraint(pool_k,
-                                                  shardings.pool)
-        pool_v = jax.lax.with_sharding_constraint(pool_v,
-                                                  shardings.pool)
-        pool_dk = jax.lax.with_sharding_constraint(pool_dk,
-                                                   shardings.d_pool)
-        pool_dv = jax.lax.with_sharding_constraint(pool_dv,
-                                                   shardings.d_pool)
-        if qspec is not None and shardings.scale is not None:
-            scale_k = jax.lax.with_sharding_constraint(
-                scale_k, shardings.scale)
-            scale_v = jax.lax.with_sharding_constraint(
-                scale_v, shardings.scale)
-        if qspec is not None and shardings.d_scale is not None:
-            scale_dk = jax.lax.with_sharding_constraint(
-                scale_dk, shardings.d_scale)
-            scale_dv = jax.lax.with_sharding_constraint(
-                scale_dv, shardings.d_scale)
         last_logits = jax.lax.with_sharding_constraint(
             last_logits, shardings.logits)
         emits = jax.lax.with_sharding_constraint(emits,
@@ -1237,15 +899,7 @@ def _cow_blocks(pool_k, pool_v, src: jax.Array, dst: jax.Array,
         if scale_k is not None:
             scale_k = scale_k.at[:, dst].set(scale_k[:, src])
             scale_v = scale_v.at[:, dst].set(scale_v[:, src])
-    if shardings is not None:
-        pool_k = jax.lax.with_sharding_constraint(pool_k, shardings.pool)
-        pool_v = jax.lax.with_sharding_constraint(pool_v, shardings.pool)
-        if scale_k is not None and shardings.scale is not None:
-            scale_k = jax.lax.with_sharding_constraint(
-                scale_k, shardings.scale)
-            scale_v = jax.lax.with_sharding_constraint(
-                scale_v, shardings.scale)
-    return pool_k, pool_v, scale_k, scale_v
+    return _pin_pools(shardings, pool_k, pool_v, scale_k, scale_v)
 
 
 @functools.partial(jax.jit, static_argnames=("shardings",))
@@ -1289,15 +943,7 @@ def _swap_in_scatter(pool_k, pool_v, host_k, host_v,
                 host_sk.astype(scale_k.dtype))
             scale_v = scale_v.at[:, block_ids].set(
                 host_sv.astype(scale_v.dtype))
-    if shardings is not None:
-        pool_k = jax.lax.with_sharding_constraint(pool_k, shardings.pool)
-        pool_v = jax.lax.with_sharding_constraint(pool_v, shardings.pool)
-        if scale_k is not None and shardings.scale is not None:
-            scale_k = jax.lax.with_sharding_constraint(
-                scale_k, shardings.scale)
-            scale_v = jax.lax.with_sharding_constraint(
-                scale_v, shardings.scale)
-    return pool_k, pool_v, scale_k, scale_v
+    return _pin_pools(shardings, pool_k, pool_v, scale_k, scale_v)
 
 
 # ---------------------------------------------------------------------------
@@ -1334,9 +980,9 @@ class _PrefillState:
     """A slot row whose prompt suffix is still being written.
 
     ``pos`` is the row's prefill frontier: slots [0, pos) hold valid
-    K/V (copied prefix + completed chunks). ``nodes`` are the PENDING
-    trie nodes this row's prefill will fill — each is copied out to the
-    pool and committed as soon as the frontier covers its block.
+    K/V (shared prefix + completed chunks). ``nodes`` are the PENDING
+    trie nodes this row's prefill fills in place — each is committed as
+    soon as the frontier covers its block.
     ``prompt`` is the token sequence being prefilled — the request's
     prompt, except for a preempt="recompute" re-admission, which
     replays prompt + already-emitted tokens (same K/V, recomputed)."""
@@ -1352,7 +998,7 @@ class _PrefillState:
 
 
 class _SwapState:
-    """A preempted request's spilled decode state (paged engine).
+    """A preempted (or handed-off) request's spilled decode state.
 
     ``k``/``v`` are HOST copies of the victim's gathered blocks
     [L, nbp, T, KV*D] — `copy_to_host_async` overlaps the pull, and
@@ -1377,9 +1023,15 @@ class _SwapState:
         self.budget = budget
         self.logits = logits
         # quantized pools spill their per-block scales alongside the
-        # (quantized) bytes; None for a dense-precision pool
+        # (quantized) bytes; None for an unquantized pool
         self.sk = sk
         self.sv = sv
+
+    @property
+    def nbytes(self) -> int:
+        """Host bytes this state carries (0 under recompute)."""
+        return sum(x.nbytes for x in (self.k, self.v, self.logits,
+                                      self.sk, self.sv) if x is not None)
 
 
 class _InflightStep:
@@ -1412,7 +1064,7 @@ class _InflightStep:
 
 
 class DecodeEngine:
-    """Slot-based continuous batching over a shared KV cache.
+    """Slot-based continuous batching over one paged KV pool.
 
     `submit()` enqueues a request; `step()` admits queued requests into
     free slots (batched, same-bucket prefills share ONE program), then
@@ -1472,8 +1124,8 @@ class DecodeEngine:
         burst of long prompts cannot starve in-flight decode rows.
 
     Tensor parallelism: ``tp=n`` (or a prebuilt ``mesh=`` with a "tp"
-    axis) shards the model weights, the KV cache, the prefix block
-    pool and the fused programs' carried state across n chips via the
+    axis) shards the model weights, the KV block pool and the fused
+    programs' carried state across n chips via the
     model's logical axis rules — attention heads, MLP width and the
     vocab dimension split over ICI; KV heads split when ``n_kv_heads``
     divides tp and replicate otherwise (prune_rules_for_mesh). The
@@ -1505,11 +1157,9 @@ class DecodeEngine:
                  decode_horizon: int = 8,
                  pipeline_depth: int = 2,
                  prefix_cache: bool = False,
-                 prefix_block: int = 32,
-                 prefix_cache_bytes: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
-                 paged: bool = False,
-                 kv_block_tokens: Optional[int] = None,
+                 paged: bool = True,
+                 kv_block_tokens: int = 32,
                  kv_pool_bytes: Optional[int] = None,
                  kv_quant: Optional[str] = None,
                  preempt: str = "swap",
@@ -1540,22 +1190,24 @@ class DecodeEngine:
             raise ValueError("decode_horizon must be >= 1")
         if pipeline_depth < 1:
             raise ValueError("pipeline_depth must be >= 1")
-        if prefix_block < 1:
-            raise ValueError("prefix_block must be >= 1")
+        if paged is not True:
+            # The benchmark's configuration files still pass
+            # `"paged": true`; the keyword goes when they drop it
+            # (ROADMAP D1).
+            raise ValueError(
+                "paged=False: the dense KV path was removed in PR 29 — "
+                "the block pool is the engine's one KV path; drop the "
+                "keyword")
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         if preempt not in ("swap", "recompute"):
             raise ValueError(f"preempt must be 'swap' or 'recompute', "
                              f"got {preempt!r}")
-        if kv_block_tokens is not None and kv_block_tokens < 1:
+        if kv_block_tokens < 1:
             raise ValueError("kv_block_tokens must be >= 1")
         self.kv_quant_spec = resolve_kv_quant(kv_quant)
         self.kv_quant = kv_quant if self.kv_quant_spec is not None \
             else None
-        if self.kv_quant_spec is not None and not paged:
-            raise ValueError(
-                "kv_quant requires paged=True: quantization scales are "
-                "per-block slabs of the paged KV pool")
         if draft_params is not None:
             if draft_cfg is None:
                 raise ValueError("draft_params needs draft_cfg")
@@ -1643,15 +1295,9 @@ class DecodeEngine:
         self._san_steps = 0
         self._san_warmup = _sanitize.warmup_steps()
 
-        # Tensor parallelism over an ICI mesh: `tp=n` builds a
+        # Tensor parallelism (class docstring): `tp=n` builds a
         # {"tp": n} mesh over the first n visible devices; `mesh=`
-        # hands over a prebuilt mesh carrying a "tp" axis. Weights, the
-        # KV cache, the prefix block pool and the fused programs' scan
-        # state are sharded over it via the model's logical axis rules
-        # (heads/mlp/vocab split across chips; KV heads split when
-        # n_kv_heads divides tp, replicated otherwise — see
-        # prune_rules_for_mesh). Host-side scheduling, the async
-        # pipeline and the single [H, B] transfer are tp-blind.
+        # hands over a prebuilt mesh carrying a "tp" axis.
         if tp is not None:
             if mesh is not None:
                 raise ValueError("pass mesh= or tp=, not both")
@@ -1683,12 +1329,11 @@ class DecodeEngine:
             self._rules = rules
             self.params = shard_pytree(
                 params, llama_param_specs(cfg, rules), mesh)
-            d_cache_sh = d_pool_sh = d_scale_sh = None
+            d_pool_sh = d_scale_sh = None
             self._d_shardings = None
-            # a paged pool merges (kv, head_dim) into one head-major
-            # lane axis; the dense engine's prefix pool keeps both
-            pool_axes = ("layers", None, None, "kv") \
-                + (() if paged else ("head_dim",))
+            # the pool merges (kv, head_dim) into one head-major lane
+            # axis
+            pool_axes = ("layers", None, None, "kv")
             if draft_params is not None:
                 # The draft shards over the SAME mesh, but its rules
                 # prune against its OWN dims — a nano draft whose kv
@@ -1704,19 +1349,15 @@ class DecodeEngine:
                 draft_params = shard_pytree(
                     draft_params, llama_param_specs(draft_cfg, d_rules),
                     mesh)
-                d_cache_sh = named_sharding(
-                    mesh, "layers", "batch", "length", "kv", "head_dim",
-                    rules=d_rules)
                 d_pool_sh = named_sharding(mesh, *pool_axes,
                                            rules=d_rules)
                 if self.kv_quant_spec is not None:
                     d_scale_sh = named_sharding(
                         mesh, "layers", None, "kv", rules=d_rules)
                 # A second shardings view with the DRAFT plane in the
-                # primary slots, so `_prefill_rows(_paged)` runs
-                # unchanged when seeding the draft cache.
+                # primary slots, so `_prefill_rows_paged` runs
+                # unchanged when seeding the draft pool.
                 self._d_shardings = _EngineShardings(
-                    cache=d_cache_sh,
                     logits=named_sharding(mesh, "batch", "vocab",
                                           rules=d_rules),
                     pool=d_pool_sh,
@@ -1728,13 +1369,10 @@ class DecodeEngine:
                 scale_sh = named_sharding(mesh, "layers", None, "kv",
                                           rules=rules)
             self._shardings = _EngineShardings(
-                cache=named_sharding(mesh, "layers", "batch", "length",
-                                     "kv", "head_dim", rules=rules),
                 logits=named_sharding(mesh, "batch", "vocab",
                                       rules=rules),
                 pool=named_sharding(mesh, *pool_axes, rules=rules),
-                d_cache=d_cache_sh, d_pool=d_pool_sh,
-                scale=scale_sh, d_scale=d_scale_sh)
+                d_pool=d_pool_sh, scale=scale_sh, d_scale=d_scale_sh)
         else:
             self.tp_degree = 1
             self._rules = None
@@ -1769,26 +1407,16 @@ class DecodeEngine:
             if attach is not None:
                 attach(self._adapter_probe)
 
-        # Paged KV mode: no dense per-slot cache at all — every row's
-        # K/V lives in pool blocks behind its block table (state built
-        # below, after this shared row bookkeeping). The dense engine
-        # keeps its [L, B, max_len, KV, D] cache unchanged.
-        self.paged = paged
+        # Every row's K/V lives in pool blocks behind its block table
+        # (state built below, after the row bookkeeping).
         self.preempt_mode = preempt
-        self.kv_block_tokens = (kv_block_tokens
-                                if kv_block_tokens is not None
-                                else prefix_block)
-        if paged and self.max_len % self.kv_block_tokens:
+        self.kv_block_tokens = kv_block_tokens
+        if self.max_len % kv_block_tokens:
             raise ValueError(
-                f"paged engine needs max_len ({self.max_len}) "
-                f"divisible by kv_block_tokens "
-                f"({self.kv_block_tokens}): the block view must span "
-                "exactly the dense cache row so paged attention is "
-                "bit-identical to the dense path")
-        self.cache = None if paged else init_cache(
-            cfg, self.B, self.max_len,
-            sharding=None if self._shardings is None
-            else self._shardings.cache)
+                f"max_len ({self.max_len}) must be divisible by "
+                f"kv_block_tokens ({kv_block_tokens}): a row's block "
+                "view must span exactly the cache solo `generate` "
+                "keeps, so prefill on it is bit-identical")
         # Next-token logits per slot, DEVICE-resident: prefill scatters
         # into it, the fused decode samples from and re-carries it —
         # logits never cross the jit boundary to the host.
@@ -1834,10 +1462,8 @@ class DecodeEngine:
         self.prefix_hits = 0           # ... that matched >= 1 block
         self.prefix_reused_tokens = 0  # prompt tokens copied, not run
         self.prefix_evictions = 0      # LRU blocks recycled
-        self.prefix_copy_dispatches = 0  # pool copy-in/out launches
         self.chunked_prefill_stalls = 0  # steps with a row mid-prefill
-        # Paged-KV plane (plain ints; identically zero on the dense
-        # engine so fleet rollups can sum them blindly):
+        # Block-pool plane (plain ints):
         self.kv_blocks_shared = 0      # warm-admission zero-copy shares
         self.kv_block_cows = 0         # tail blocks duplicated on write
         self.preemptions = 0           # rows evicted mid-decode
@@ -1880,123 +1506,53 @@ class DecodeEngine:
         self.prefill_chunk = prefill_chunk
         self._row_prefill: Dict[int, _PrefillState] = {}
 
-        # Shared-prefix KV cache: host-side radix index over committed
-        # prompt blocks + a device-resident pool. Dense mode keeps the
-        # PR-4 copy-in/copy-out pool, sized by prefix_cache_bytes
-        # (default: room for 2 full batches of max_len tokens) plus
-        # the reserved scratch block 0. Paged mode has ONE pool for
-        # everything — live rows' K/V and the prefix cache are the
-        # same refcounted blocks, so the trie indexes the pool
-        # directly and a warm admission SHARES blocks instead of
-        # copying them.
-        self.prefix_block = (self.kv_block_tokens if paged
-                             else prefix_block)
-        self._prefix: Optional[PrefixCacheIndex] = None
-        self.kv_pool: Optional[BlockPool] = None
+        # ONE refcounted block pool holds everything: live rows' K/V
+        # behind their block tables, and — with `prefix_cache` — the
+        # shared-prefix cache, a host-side radix index over committed
+        # prompt blocks of the same pool, so a warm admission SHARES
+        # blocks instead of copying them. `kv_pool_bytes` sizes it
+        # (default: room for two full batches of max_len tokens) plus
+        # the reserved null block 0.
         L, KV, D = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        kv_dtype = jnp.dtype(cfg.dtype)
-        if paged:
-            T = self.prefix_block
-            if self.kv_quant_spec is not None:
-                # Quantized pool: 1-byte values + the per-block scale
-                # slab's footprint (2 slabs x L x KV f32 scales per
-                # block) — the ~2x concurrency-per-HBM-byte lever.
-                pool_dtype = self.kv_quant_spec.dtype
-                bb = block_bytes(L, T, KV, D,
-                                 self.kv_quant_spec.itemsize) \
-                    + 2 * L * KV * 4
-            else:
-                pool_dtype = kv_dtype
-                bb = block_bytes(L, T, KV, D, kv_dtype.itemsize)
-            self.kv_bytes_per_block = float(bb)
-            self.kv_bytes_per_token = bb / T
-            budget_bytes = (kv_pool_bytes if kv_pool_bytes is not None
-                            else prefix_cache_bytes)
-            if budget_bytes is None:
-                # Default: the dense engine's footprint — room for two
-                # full batches of max_len tokens.
-                n_blocks = 1 + (2 * self.B * self.max_len) // T
-            else:
-                n_blocks = 1 + budget_bytes // bb
-            self._mb = self.max_len // T   # block-table width
-            self.kv_pool = BlockPool(n_blocks)
-            self._bt = np.zeros((self.B, self._mb), np.int32)
-            self._row_blocks: List[List[int]] = [
-                [] for _ in range(self.B)]
-            self._swapped: Dict[int, _SwapState] = {}
-            self._admit_seq = 0            # preemption recency order
-            self._row_admit_seq = np.zeros((self.B,), np.int64)
-            # the layout the decode kernel reads: one page is one
-            # contiguous [T, KV*D] slab, heads merged head-major
-            self._pool_k = jnp.zeros((L, n_blocks, T, KV * D),
-                                     pool_dtype)
-            self._pool_v = jnp.zeros((L, n_blocks, T, KV * D),
-                                     pool_dtype)
-            self._scale_k = self._scale_v = None
-            if self.kv_quant_spec is not None:
-                # zero scales: dequant of the zero-initialised pool
-                # (incl. the null block) is exactly 0.0 everywhere
-                self._scale_k = jnp.zeros((L, n_blocks, KV),
-                                          jnp.float32)
-                self._scale_v = jnp.zeros((L, n_blocks, KV),
-                                          jnp.float32)
-            if self._shardings is not None:
-                self._pool_k = jax.device_put(self._pool_k,
-                                              self._shardings.pool)
-                self._pool_v = jax.device_put(self._pool_v,
-                                              self._shardings.pool)
-                if self._scale_k is not None:
-                    self._scale_k = jax.device_put(
-                        self._scale_k, self._shardings.scale)
-                    self._scale_v = jax.device_put(
-                        self._scale_v, self._shardings.scale)
-            if prefix_cache:
-                self._prefix = PrefixCacheIndex(
-                    block_tokens=T, n_blocks=n_blocks,
-                    on_evict=self._on_prefix_evict, pool=self.kv_pool)
-        elif prefix_cache:
-            self._scale_k = self._scale_v = None
-            self.kv_bytes_per_block = float(block_bytes(
-                L, prefix_block, KV, D, kv_dtype.itemsize))
-            self.kv_bytes_per_token = self.kv_bytes_per_block \
-                / prefix_block
-            bb = block_bytes(L, prefix_block, KV, D, kv_dtype.itemsize)
-            if prefix_cache_bytes is None:
-                n_blocks = 1 + (2 * self.B * self.max_len) // prefix_block
-            else:
-                n_blocks = 1 + prefix_cache_bytes // bb
-            self._prefix = PrefixCacheIndex(
-                block_tokens=prefix_block, n_blocks=n_blocks,
-                on_evict=self._on_prefix_evict)
-            self._pool_k = jnp.zeros(
-                (L, n_blocks, prefix_block, KV, D), kv_dtype)
-            self._pool_v = jnp.zeros(
-                (L, n_blocks, prefix_block, KV, D), kv_dtype)
-            if self._shardings is not None:
-                # Pool lives on the mesh with the cache's KV sharding:
-                # each chip holds only its heads' slice of every block
-                # (prefix_cache_bytes stays the GLOBAL pool footprint;
-                # per-chip resident bytes are that / tp when KV
-                # shards).
-                self._pool_k = jax.device_put(self._pool_k,
-                                              self._shardings.pool)
-                self._pool_v = jax.device_put(self._pool_v,
-                                              self._shardings.pool)
+        T = kv_block_tokens
+        if self.kv_quant_spec is not None:
+            # Quantized pool: 1-byte values + the per-block scale
+            # slab's footprint (2 slabs x L x KV f32 scales per
+            # block) — the ~2x concurrency-per-HBM-byte lever.
+            pool_dtype = self.kv_quant_spec.dtype
+            bb = block_bytes(L, T, KV, D, self.kv_quant_spec.itemsize) \
+                + 2 * L * KV * 4
         else:
-            self._pool_k = self._pool_v = None
-            self._scale_k = self._scale_v = None
-            # dense per-slot cache: 2 (K+V) x L x KV x D per token
-            self.kv_bytes_per_token = float(
-                2 * L * KV * D * kv_dtype.itemsize)
-            self.kv_bytes_per_block = 0.0
-        if self._prefix is not None:
+            pool_dtype = jnp.dtype(cfg.dtype)
+            bb = block_bytes(L, T, KV, D, pool_dtype.itemsize)
+        self.kv_bytes_per_block = float(bb)
+        self.kv_bytes_per_token = bb / T
+        if kv_pool_bytes is None:
+            n_blocks = 1 + (2 * self.B * self.max_len) // T
+        else:
+            n_blocks = 1 + kv_pool_bytes // bb
+        self._mb = self.max_len // T   # block-table width
+        self.kv_pool = BlockPool(n_blocks)
+        self._bt = np.zeros((self.B, self._mb), np.int32)
+        self._row_blocks: List[List[int]] = [[] for _ in range(self.B)]
+        self._swapped: Dict[int, _SwapState] = {}
+        self._admit_seq = 0            # preemption recency order
+        self._row_admit_seq = np.zeros((self.B,), np.int64)
+        (self._pool_k, self._pool_v, self._scale_k,
+         self._scale_v) = _zero_pools(
+            L, n_blocks, T, KV, D, pool_dtype,
+            self.kv_quant_spec is not None, shardings=self._shardings)
+        self._prefix: Optional[PrefixCacheIndex] = None
+        if prefix_cache:
+            self._prefix = PrefixCacheIndex(
+                block_tokens=T, on_evict=self._on_prefix_evict,
+                pool=self.kv_pool)
             attach = getattr(self.scheduler, "attach_prefix_probe", None)
             if attach is not None:
                 attach(self._prefix_probe)
 
         # Speculative plane: the DRAFT model's KV lives in a second
-        # per-slot plane — a dense [L_d, B, max_len, KV_d, D_d] ring,
-        # or its own private block pool + table in paged mode (draft
+        # per-slot plane — its own private block pool + table (draft
         # blocks are never shared or tried; sized so every slot can
         # hold a full row, the draft allocator can never run dry).
         # Host lanes mirror the device's draft-lag trick and feed the
@@ -2026,49 +1582,19 @@ class DecodeEngine:
             if self._d_shardings is not None:
                 self._d_last_logits = jax.device_put(
                     self._d_last_logits, self._d_shardings.logits)
-            L_d, KV_d, D_d = (draft_cfg.n_layers, draft_cfg.n_kv_heads,
-                              draft_cfg.head_dim)
-            d_dtype = jnp.dtype(draft_cfg.dtype)
-            if paged:
-                T = self.prefix_block
-                n_blocks_d = 1 + self.B * self._mb
-                self.kv_pool_d = BlockPool(n_blocks_d,
-                                           label="draft_kv")
-                self._bt_d = np.zeros((self.B, self._mb), np.int32)
-                self._row_blocks_d: List[List[int]] = [
-                    [] for _ in range(self.B)]
-                d_pool_dtype = (self.kv_quant_spec.dtype
-                                if self.kv_quant_spec is not None
-                                else d_dtype)
-                self._pool_dk = jnp.zeros(
-                    (L_d, n_blocks_d, T, KV_d * D_d), d_pool_dtype)
-                self._pool_dv = jnp.zeros(
-                    (L_d, n_blocks_d, T, KV_d * D_d), d_pool_dtype)
-                self._scale_dk = self._scale_dv = None
-                if self.kv_quant_spec is not None:
-                    self._scale_dk = jnp.zeros(
-                        (L_d, n_blocks_d, KV_d), jnp.float32)
-                    self._scale_dv = jnp.zeros(
-                        (L_d, n_blocks_d, KV_d), jnp.float32)
-                if self._d_shardings is not None:
-                    self._pool_dk = jax.device_put(
-                        self._pool_dk, self._d_shardings.pool)
-                    self._pool_dv = jax.device_put(
-                        self._pool_dv, self._d_shardings.pool)
-                    if self._scale_dk is not None:
-                        self._scale_dk = jax.device_put(
-                            self._scale_dk, self._d_shardings.scale)
-                        self._scale_dv = jax.device_put(
-                            self._scale_dv, self._d_shardings.scale)
-                self._d_cache = None
-            else:
-                self.kv_pool_d = None
-                self._d_cache = init_cache(
-                    draft_cfg, self.B, self.max_len,
-                    sharding=None if self._d_shardings is None
-                    else self._d_shardings.cache)
-                self._pool_dk = self._pool_dv = None
-                self._scale_dk = self._scale_dv = None
+            n_blocks_d = 1 + self.B * self._mb
+            self.kv_pool_d = BlockPool(n_blocks_d, label="draft_kv")
+            self._bt_d = np.zeros((self.B, self._mb), np.int32)
+            self._row_blocks_d: List[List[int]] = [
+                [] for _ in range(self.B)]
+            (self._pool_dk, self._pool_dv, self._scale_dk,
+             self._scale_dv) = _zero_pools(
+                draft_cfg.n_layers, n_blocks_d, T, draft_cfg.n_kv_heads,
+                draft_cfg.head_dim,
+                self.kv_quant_spec.dtype if self.kv_quant_spec is not None
+                else jnp.dtype(draft_cfg.dtype),
+                self.kv_quant_spec is not None,
+                shardings=self._d_shardings)
             if enable_metrics:
                 # llm_spec_* Prometheus counters share the engine's
                 # tag, so fleet dashboards can join the spec plane onto
@@ -2137,7 +1663,7 @@ class DecodeEngine:
         ``resume_tokens`` is the fleet-failover resume path: tokens
         this request ALREADY emitted on a replica that died. Admission
         replays prompt + resume_tokens as the prefill (recompute — the
-        same discipline as paged preempt="recompute"), starts the
+        same discipline as preempt="recompute"), starts the
         budget and sampling-stream index at len(resume_tokens), and
         the request's final ``tokens`` list is resume_tokens plus
         everything decoded here — bit-identical to a run that never
@@ -2204,19 +1730,18 @@ class DecodeEngine:
                     "resume_tokens and deadline_s are mutually "
                     "exclusive: a resumed request was admitted once "
                     "and is exempt from deadline shedding")
-        if self.paged:
-            # A request must fit the pool ALONE in the worst case
-            # (every other row preempted, every cold prefix block
-            # evicted) or it could never complete.
-            T = self.prefix_block
-            need = -(-(len(prompt) + max_new_tokens) // T)
-            if need > self.kv_pool.blocks_total:
-                raise ValueError(
-                    f"request needs {need} KV blocks ({len(prompt)} "
-                    f"prompt + {max_new_tokens} new tokens at "
-                    f"{T} tokens/block) but the pool holds only "
-                    f"{self.kv_pool.blocks_total}; raise "
-                    "kv_pool_bytes or shrink the request")
+        # A request must fit the pool ALONE in the worst case (every
+        # other row preempted, every cold prefix block evicted) or it
+        # could never complete.
+        T = self.kv_block_tokens
+        need = -(-(len(prompt) + max_new_tokens) // T)
+        if need > self.kv_pool.blocks_total:
+            raise ValueError(
+                f"request needs {need} KV blocks ({len(prompt)} "
+                f"prompt + {max_new_tokens} new tokens at "
+                f"{T} tokens/block) but the pool holds only "
+                f"{self.kv_pool.blocks_total}; raise "
+                "kv_pool_bytes or shrink the request")
         deadline = (None if deadline_s is None
                     else self._clock() + deadline_s)
         if deadline is not None and self._clock() >= deadline:
@@ -2270,13 +1795,12 @@ class DecodeEngine:
             # the sampling stream picks up at token len(resume).
             req.tokens = resume
             req.resume = True
-            if self.paged:
-                # Ride the existing recompute swap-in path: a k=None
-                # ledger entry makes `_admit_rows_paged` replay
-                # prompt + tokens exactly like a preempted row.
-                self._swapped[req.req_id] = _SwapState(
-                    None, None, 0, 0, len(resume),
-                    max_new_tokens - len(resume), None)
+            # Ride the recompute swap-in path: a k=None ledger entry
+            # makes `_admit_rows_paged` replay prompt + tokens exactly
+            # like a preempted row.
+            self._swapped[req.req_id] = _SwapState(
+                None, None, 0, 0, len(resume),
+                max_new_tokens - len(resume), None)
         self._next_id += 1
         self.scheduler.push(req)
         self.results[req.req_id] = req
@@ -2333,10 +1857,8 @@ class DecodeEngine:
     # The fused entry points whose compile caches the sanitizer audits:
     # any growth after arm() is a steady-state retrace regression.
     _SANITIZER_JIT_ENTRY_POINTS = (
-        "_prefill_rows", "_prefill_rows_paged", "_prefix_copy_in",
-        "_prefix_copy_out", "_decode_multi", "_decode_multi_paged",
-        "_spec_round", "_spec_round_paged", "_cow_blocks",
-        "_swap_out_gather", "_swap_in_scatter")
+        "_prefill_rows_paged", "_decode_multi_paged", "_spec_round_paged",
+        "_cow_blocks", "_swap_out_gather", "_swap_in_scatter")
 
     def arm_sanitizer(self):
         """Snapshot the jit caches and arm the runtime sanitizer: from
@@ -2401,8 +1923,8 @@ class DecodeEngine:
             if self._san_steps > self._san_warmup:
                 self.arm_sanitizer()
         emitted: Dict[int, List[int]] = {}
-        # Flush the pipeline before any admission / prefill / prefix
-        # copy: those paths mutate the cache from the host side and
+        # Flush the pipeline before any admission / prefill: those
+        # paths mutate the pool from the host side and
         # read row/slot state, so every in-flight run-ahead block must
         # be replayed first (freed slots, retired requests) for the
         # admission decision to see true state.
@@ -2445,7 +1967,7 @@ class DecodeEngine:
                         # completion.
                         self._shed(cand)
                         continue
-                    if self.paged and not self._fits_now(cand):
+                    if not self._fits_now(cand):
                         # No room even counting evictable cold prefix
                         # blocks: capacity, not order, is the constraint —
                         # stop admitting this step and retry when decode
@@ -2479,7 +2001,7 @@ class DecodeEngine:
                                    args={"queued": len(self.scheduler)})
             admit.note(admitted=len(admissions))
             if admissions:
-                self._admit_rows(admissions)
+                self._admit_rows_paged(admissions)
         self._advance_prefills()
 
         live = [b for b in range(self.B) if self.row_req[b] is not None]
@@ -2532,11 +2054,10 @@ class DecodeEngine:
         self.metrics.on_step(
             sum(r is not None for r in self.row_req),
             len(self.scheduler), n_tokens)
-        if self.paged:
-            self.metrics.on_kv_pool(self.kv_pool.blocks_total,
-                                    self.kv_pool.blocks_in_use,
-                                    self.kv_pool.free_blocks,
-                                    bytes_per_token=self.kv_bytes_per_token)
+        self.metrics.on_kv_pool(self.kv_pool.blocks_total,
+                                self.kv_pool.blocks_in_use,
+                                self.kv_pool.free_blocks,
+                                bytes_per_token=self.kv_bytes_per_token)
         return emitted
 
     # -- async pipeline ----------------------------------------------------
@@ -2549,19 +2070,18 @@ class DecodeEngine:
         with budget to speculate into, else the plain fused horizon.
         Mid-chunked-prefill steps always take the plain H=1 path — the
         chunk cadence outranks speculation depth. Returns the possibly
-        narrowed decodable set (paged reservation may preempt)."""
+        narrowed decodable set (block reservation may preempt)."""
         if self.spec_enabled and len(decodable) == len(live):
             W, w_row = self._spec_plan(decodable)
             if W:
-                if self.paged:
-                    decodable, Hr = self._reserve_decode_blocks(
-                        decodable, W + 1)
-                    if Hr < W + 1:
-                        # Pool too tight to cover the verify chunk even
-                        # after preemption: decode plainly at whatever
-                        # horizon the reservation could hold.
-                        self._dispatch_decode(Hr, decodable, chain=None)
-                        return decodable
+                decodable, Hr = self._reserve_decode_blocks(
+                    decodable, W + 1)
+                if Hr < W + 1:
+                    # Pool too tight to cover the verify chunk even
+                    # after preemption: decode plainly at whatever
+                    # horizon the reservation could hold.
+                    self._dispatch_decode(Hr, decodable, chain=None)
+                    return decodable
                 self._dispatch_spec(W, w_row, decodable, chain=None)
                 return decodable
         H = horizon
@@ -2581,12 +2101,11 @@ class DecodeEngine:
             # remainder.
             H = min(H, int(self.row_budget[decodable].max()))
             H = 1 << max(0, H.bit_length() - 1)
-        if self.paged:
-            # Grow every decodable row's chain to cover the
-            # horizon, preempting victims if the pool runs dry —
-            # admission capacity is pool bytes, not slots, so
-            # over-admission is resolved here, not refused there.
-            decodable, H = self._reserve_decode_blocks(decodable, H)
+        # Grow every decodable row's chain to cover the horizon,
+        # preempting victims if the pool runs dry — admission capacity
+        # is pool bytes, not slots, so over-admission is resolved here,
+        # not refused there.
+        decodable, H = self._reserve_decode_blocks(decodable, H)
         self._dispatch_decode(H, decodable, chain=None)
         return decodable
 
@@ -2638,51 +2157,29 @@ class DecodeEngine:
                              proposed=int(w_row[rows].sum()),
                              rows=len(rows),
                              run_ahead=chain is not None):
-            if chain is None:
-                active = np.array([self.row_req[b] is not None
-                                   and b not in self._row_prefill
-                                   for b in range(self.B)])
-                args = (jnp.asarray(self.row_len), jnp.asarray(active),
-                        jnp.asarray(self.row_budget),
-                        jnp.asarray(self._tok_idx),
-                        jnp.asarray(self._d_lag),
-                        jnp.asarray(self._d_tok))
-            else:
-                args = chain
+            args = chain if chain is not None else (
+                *self._row_state(), jnp.asarray(self._d_lag),
+                jnp.asarray(self._d_tok))
             rg = jnp.asarray(self._row_greedy)
             all_greedy = bool(self._row_greedy.all())
             wr = jnp.asarray(w_row)
-            if self.paged:
-                bt_dev = jnp.asarray(self._bt)
-                btd_dev = jnp.asarray(self._bt_d)
-                if self._shardings is not None:
-                    bt_dev = jax.device_put(bt_dev,
-                                            self._shardings.replicated)
-                    btd_dev = jax.device_put(btd_dev,
-                                             self._shardings.replicated)
-                with spmd_mesh_scope(self.mesh):
-                    (toks, self._pool_k, self._pool_v, self._pool_dk,
-                     self._pool_dv, self._scale_k, self._scale_v,
-                     self._scale_dk, self._scale_dv, self._last_logits, rl,
-                     ac, bu, ti, dl, dt) = _spec_round_paged(
-                        self.params, self.draft_params, self._pool_k,
-                        self._pool_v, self._pool_dk, self._pool_dv, bt_dev,
-                        btd_dev, self._last_logits, *args,
-                        jnp.asarray(self._row_keys), rg, wr,
-                        self.temperature, self.cfg, self.draft_cfg, W,
-                        all_greedy, self.top_k, self.top_p, self.eos_id,
-                        shardings=self._shardings,
-                        scale_k=self._scale_k, scale_v=self._scale_v,
-                        scale_dk=self._scale_dk, scale_dv=self._scale_dv,
-                        qspec=self.kv_quant_spec)
-            else:
-                (toks, self.cache, self._d_cache, self._last_logits, rl,
-                 ac, bu, ti, dl, dt) = _spec_round(
-                    self.params, self.draft_params, self.cache,
-                    self._d_cache, self._last_logits, *args,
-                    jnp.asarray(self._row_keys), rg, wr, self.temperature,
-                    self.cfg, self.draft_cfg, W, all_greedy, self.top_k,
-                    self.top_p, self.eos_id, shardings=self._shardings)
+            bt_dev = self._table_snapshot(self._bt)
+            btd_dev = self._table_snapshot(self._bt_d)
+            with spmd_mesh_scope(self.mesh):
+                (toks, self._pool_k, self._pool_v, self._pool_dk,
+                 self._pool_dv, self._scale_k, self._scale_v,
+                 self._scale_dk, self._scale_dv, self._last_logits, rl,
+                 ac, bu, ti, dl, dt) = _spec_round_paged(
+                    self.params, self.draft_params, self._pool_k,
+                    self._pool_v, self._pool_dk, self._pool_dv, bt_dev,
+                    btd_dev, self._last_logits, *args,
+                    jnp.asarray(self._row_keys), rg, wr,
+                    self.temperature, self.cfg, self.draft_cfg, W,
+                    all_greedy, self.top_k, self.top_p, self.eos_id,
+                    shardings=self._shardings,
+                    scale_k=self._scale_k, scale_v=self._scale_v,
+                    scale_dk=self._scale_dk, scale_dv=self._scale_dv,
+                    qspec=self.kv_quant_spec)
             _host_async(toks)
             self._ring.append(_InflightStep(
                 toks, W + 1, list(rows), run_ahead=chain is not None,
@@ -2703,15 +2200,7 @@ class DecodeEngine:
         with self.trace.lane("dispatch", "dispatch", horizon=H,
                              rows=len(rows),
                              run_ahead=chain is not None):
-            if chain is None:
-                active = np.array([self.row_req[b] is not None
-                                   and b not in self._row_prefill
-                                   for b in range(self.B)])
-                args = (jnp.asarray(self.row_len), jnp.asarray(active),
-                        jnp.asarray(self.row_budget),
-                        jnp.asarray(self._tok_idx))
-            else:
-                args = chain
+            args = chain if chain is not None else self._row_state()
             # The static greedy flag is the all-greedy fast path: without
             # per-request overrides it equals the engine-wide mode exactly
             # (the lane resets to the default at retirement), so existing
@@ -2727,46 +2216,49 @@ class DecodeEngine:
                 row_slot = jnp.asarray(self._row_slot)
             else:
                 adapters = row_slot = None
-            if self.paged:
-                self._count_paged_walk(H, rows)
-                # Snapshot the block table at dispatch: jnp.asarray copies
-                # it to device, so host-side growth between chained
-                # dispatches only reaches FUTURE dispatches (in-flight
-                # steps never read past the coverage they were reserved).
-                bt_dev = jnp.asarray(self._bt)
-                if self._shardings is not None:
-                    bt_dev = jax.device_put(bt_dev,
-                                            self._shardings.replicated)
-                # the scope only matters while the program traces: under
-                # a tp mesh paged_attention must not pick a Mosaic kernel
-                with spmd_mesh_scope(self.mesh):
-                    (toks, self._pool_k, self._pool_v, self._scale_k,
-                     self._scale_v, self._last_logits,
-                     rl, ac, bu, ti,
-                     self._moe_ctr) = _decode_multi_paged(
-                        self.params, self._pool_k, self._pool_v, bt_dev,
-                        self._last_logits, *args,
-                        jnp.asarray(self._row_keys), rg, self.temperature,
-                        self.cfg, H, all_greedy, self.top_k, self.top_p,
-                        self.eos_id, shardings=self._shardings,
-                        adapters=adapters, row_slot=row_slot,
-                        scale_k=self._scale_k, scale_v=self._scale_v,
-                        qspec=self.kv_quant_spec, moe_ctr=self._moe_ctr)
-            else:
-                (toks, self.cache, self._last_logits, rl, ac, bu, ti,
-                 self._moe_ctr) = _decode_multi(
-                        self.params, self.cache, self._last_logits, *args,
-                        jnp.asarray(self._row_keys), rg, self.temperature,
-                        self.cfg, H, all_greedy, self.top_k, self.top_p,
-                        self.eos_id, shardings=self._shardings,
-                        adapters=adapters, row_slot=row_slot,
-                        moe_ctr=self._moe_ctr)
+            self._count_paged_walk(H, rows)
+            bt_dev = self._table_snapshot(self._bt)
+            # the scope only matters while the program traces: under
+            # a tp mesh paged_attention must not pick a Mosaic kernel
+            with spmd_mesh_scope(self.mesh):
+                (toks, self._pool_k, self._pool_v, self._scale_k,
+                 self._scale_v, self._last_logits,
+                 rl, ac, bu, ti,
+                 self._moe_ctr) = _decode_multi_paged(
+                    self.params, self._pool_k, self._pool_v, bt_dev,
+                    self._last_logits, *args,
+                    jnp.asarray(self._row_keys), rg, self.temperature,
+                    self.cfg, H, all_greedy, self.top_k, self.top_p,
+                    self.eos_id, shardings=self._shardings,
+                    adapters=adapters, row_slot=row_slot,
+                    scale_k=self._scale_k, scale_v=self._scale_v,
+                    qspec=self.kv_quant_spec, moe_ctr=self._moe_ctr)
             _host_async(toks)
             self._ring.append(_InflightStep(toks, H, list(rows),
                                             run_ahead=chain is not None,
                                             chain=(rl, ac, bu, ti)))
             self.decode_dispatches += 1
             self.metrics.on_dispatch(H, host_syncs=0)
+
+    def _row_state(self) -> tuple:
+        """(row_len, active, budget, tok_idx) from replayed host state:
+        what a dispatch after a flush starts from. Rows mid-prefill
+        ride along frozen."""
+        active = np.array([self.row_req[b] is not None
+                           and b not in self._row_prefill
+                           for b in range(self.B)])
+        return (jnp.asarray(self.row_len), jnp.asarray(active),
+                jnp.asarray(self.row_budget), jnp.asarray(self._tok_idx))
+
+    def _table_snapshot(self, bt: np.ndarray) -> jax.Array:
+        """A block table as of this dispatch: jnp.asarray copies it to
+        the device, so host-side growth between chained dispatches only
+        reaches FUTURE dispatches (in-flight steps never read past the
+        coverage they were reserved)."""
+        bt_dev = jnp.asarray(bt)
+        if self._shardings is not None:
+            bt_dev = jax.device_put(bt_dev, self._shardings.replicated)
+        return bt_dev
 
     def _count_paged_walk(self, H: int, rows: List[int]) -> None:
         """Account one fused decode dispatch of `H` tokens over the
@@ -2811,7 +2303,7 @@ class DecodeEngine:
                 # keeps the chained dispatch on the compiled program.
                 # H accounting is pessimistic (every round could emit
                 # w_max+1), same discipline as plain run-ahead.
-                if self.paged and not self._ensure_decode_blocks(
+                if not self._ensure_decode_blocks(
                         rows, last.w_max + 1, inflight):
                     break
                 self._dispatch_spec(last.w_max, last.w_row, rows,
@@ -2826,8 +2318,7 @@ class DecodeEngine:
                     max_horizon=self.decode_horizon)
                 Hn = min(Hn, rem)
                 Hn = 1 << max(0, Hn.bit_length() - 1)
-            if self.paged and not self._ensure_decode_blocks(
-                    rows, Hn, inflight):
+            if not self._ensure_decode_blocks(rows, Hn, inflight):
                 # Pool dry: no run-ahead. Preemption needs replayed
                 # host state, so it only runs on the primary dispatch
                 # path once the ring empties.
@@ -2898,7 +2389,7 @@ class DecodeEngine:
 
     def _flush_pipeline(self, emitted: Dict[int, List[int]]) -> None:
         """Drain EVERY in-flight step. Called before any admission /
-        prefill / prefix copy, and at end of stream — the points where
+        prefill, and at end of stream — the points where
         host state must be fully caught up with the device."""
         if not self._ring:
             return
@@ -2948,7 +2439,8 @@ class DecodeEngine:
         # Tensor-parallel plane: tp_degree is 1 for an unsharded
         # engine; transfer bytes count the [H, B] token blocks pulled
         # at drain — the replicated choke point, so bytes/token must
-        # NOT grow with tp degree (microbench gates this).
+        # NOT grow with tp degree (tests/test_engine_sharded.py gates
+        # this).
         out["tp_degree"] = float(self.tp_degree)
         out["host_transfer_bytes"] = float(self.host_transfer_bytes)
         out["host_transfer_bytes_per_token"] = _ratio(
@@ -2961,9 +2453,9 @@ class DecodeEngine:
         out["prefill_padding_waste_frac"] = _ratio(
             self.prefill_padded_tokens,
             self.prefill_real_tokens + self.prefill_padded_tokens)
-        # Prefix-reuse plane: reused = prompt tokens COPIED from the
-        # pool; recomputed (= prefill_real_tokens) = prompt tokens the
-        # prefill actually ran.
+        # Prefix-reuse plane: reused = prompt tokens whose blocks were
+        # SHARED from the pool; recomputed (= prefill_real_tokens) =
+        # prompt tokens the prefill actually ran.
         out["prefix_lookups"] = float(self.prefix_lookups)
         out["prefix_hits"] = float(self.prefix_hits)
         out["prefix_hit_rate"] = _ratio(self.prefix_hits,
@@ -2973,7 +2465,6 @@ class DecodeEngine:
             self.prefix_reused_tokens,
             self.prefix_reused_tokens + self.prefill_real_tokens)
         out["prefix_evictions"] = float(self.prefix_evictions)
-        out["prefix_copy_dispatches"] = float(self.prefix_copy_dispatches)
         out["chunked_prefill_stalls"] = float(self.chunked_prefill_stalls)
         # Async-pipeline plane. depth_effective is the mean number of
         # fused steps in flight at each drain (1.0 = synchronous; ->
@@ -2992,10 +2483,7 @@ class DecodeEngine:
         if self._prefix is not None:
             out["prefix_blocks_in_use"] = float(self._prefix.blocks_in_use)
             out["prefix_blocks_total"] = float(self._prefix.blocks_total)
-        # Paged-KV plane: zero-copy sharing, CoW, preempt-and-swap.
-        # Counters are identically 0.0 on the dense engine so fleet
-        # rollups sum them without mode checks.
-        out["paged"] = 1.0 if self.paged else 0.0
+        # Block-pool plane: zero-copy sharing, CoW, preempt-and-swap.
         out["kv_blocks_shared"] = float(self.kv_blocks_shared)
         out["kv_block_cows"] = float(self.kv_block_cows)
         out["preemptions"] = float(self.preemptions)
@@ -3026,20 +2514,19 @@ class DecodeEngine:
         out["handoff_in_bytes"] = float(self.handoff_in_bytes)
         out["requests_handoff_ready"] = float(len(self._handoff_ready))
         # Quantized-KV plane: bytes/token is the concurrency lever the
-        # fleet watches (see docs/serving.md); identically dense-sized
-        # (and quant_enabled 0.0) on an unquantized engine.
+        # fleet watches (see docs/serving.md); quant_enabled is 0.0 on
+        # an unquantized engine.
         out["kv_quant_enabled"] = 1.0 if self.kv_quant else 0.0
         out["kv_bytes_per_token"] = float(self.kv_bytes_per_token)
         out["kv_bytes_per_block"] = float(self.kv_bytes_per_block)
-        if self.paged:
-            pool = self.kv_pool
-            out["kv_pool_blocks_total"] = float(pool.blocks_total)
-            out["kv_pool_blocks_in_use"] = float(pool.blocks_in_use)
-            out["kv_pool_blocks_free"] = float(pool.free_blocks)
-            out["kv_pool_occupancy"] = _ratio(pool.blocks_in_use,
-                                              pool.blocks_total)
-            out["kv_free_blocks"] = float(self.kv_free_blocks())
-            out["requests_swapped"] = float(len(self._swapped))
+        pool = self.kv_pool
+        out["kv_pool_blocks_total"] = float(pool.blocks_total)
+        out["kv_pool_blocks_in_use"] = float(pool.blocks_in_use)
+        out["kv_pool_blocks_free"] = float(pool.free_blocks)
+        out["kv_pool_occupancy"] = _ratio(pool.blocks_in_use,
+                                          pool.blocks_total)
+        out["kv_free_blocks"] = float(self.kv_free_blocks())
+        out["requests_swapped"] = float(len(self._swapped))
         # Speculative plane: identically 0.0 with spec off, so fleet
         # rollups sum/weight them without mode checks. acceptance_rate
         # is accepted/proposed over the engine's lifetime;
@@ -3059,7 +2546,7 @@ class DecodeEngine:
         out["spec_draft_tokens_wasted"] = float(self.spec_wasted)
         out["spec_prefill_dispatches"] = float(
             self.spec_prefill_dispatches)
-        if self.spec_enabled and self.paged:
+        if self.spec_enabled:
             out["spec_kv_pool_blocks_in_use"] = float(
                 self.kv_pool_d.blocks_in_use)
         # Multi-LoRA plane: identically 0.0 with no adapter pool, so
@@ -3133,7 +2620,7 @@ class DecodeEngine:
         """Abandon this engine's work WITHOUT completing it — the
         fleet's failure path (the opposite of drain's flush-before-
         removal). Discards the async pipeline ring (in-flight device
-        steps are never replayed), releases every live row's paged KV
+        steps are never replayed), releases every live row's KV
         blocks (refcount hygiene: trie-shared blocks survive through
         the trie's own references, private blocks free), drops the
         swap ledger and the queue, and refuses new submits. Host-side
@@ -3155,11 +2642,10 @@ class DecodeEngine:
         self._ring.clear()
         self._row_prefill.clear()
         for row in range(self.B):
-            if self.paged:
-                try:
-                    self._release_row_blocks(row)
-                except Exception:
-                    pass
+            try:
+                self._release_row_blocks(row)
+            except Exception:
+                pass
             if self._row_slot[row] and self.adapter_pool is not None:
                 try:
                     self.adapter_pool.decref(int(self._row_slot[row]))
@@ -3179,8 +2665,7 @@ class DecodeEngine:
         self._pending_slots.clear()
         self._handoff_ready.clear()
         self._handoff_ready_set.clear()
-        if self.paged:
-            self._swapped.clear()
+        self._swapped.clear()
         # Drop the queue wholesale (a fresh empty policy, not N pops:
         # a deferring policy could legally return None forever once
         # its probe's world is gone). The queued _Request objects stay
@@ -3200,8 +2685,7 @@ class DecodeEngine:
         if queued is not None:
             try:
                 for r in queued():
-                    swap = (self._swapped.get(r.req_id)
-                            if self.paged else None)
+                    swap = self._swapped.get(r.req_id)
                     if swap is not None and swap.k is not None:
                         continue   # swap-in is a scatter, no prefill owed
                     if swap is not None:
@@ -3214,11 +2698,8 @@ class DecodeEngine:
 
     def kv_free_blocks(self) -> int:
         """KV blocks an admission could claim right now: free +
-        evictable cold prefix blocks. 0 for the dense engine (no
-        pool) — the router falls back to `kv_used_fraction`. Pure
-        host arithmetic, zero device syncs."""
-        if not self.paged:
-            return 0
+        evictable cold prefix blocks. Pure host arithmetic, zero
+        device syncs."""
         n = self.kv_pool.free_blocks
         if self._prefix is not None:
             n += self._prefix.evictable_blocks()
@@ -3226,19 +2707,13 @@ class DecodeEngine:
 
     def kv_used_fraction(self) -> float:
         """Unreclaimable KV pressure in [0, 1] — the fleet router's
-        occupancy signal. Paged: fraction of pool blocks neither free
-        nor evictable-cold. Dense: live slots / batch slots (each
-        live slot pins a full max_len cache row, so slot occupancy IS
-        KV occupancy there)."""
-        if self.paged:
-            total = self.kv_pool.blocks_total
-            if not total:
-                return 1.0
-            return max(0.0, 1.0 - self.kv_free_blocks() / total)
-        return sum(r is not None for r in self.row_req) / self.B
+        occupancy signal: the fraction of pool blocks neither free nor
+        evictable-cold."""
+        return max(0.0, 1.0 - self.kv_free_blocks()
+                   / self.kv_pool.blocks_total)
 
     def prefix_match_tokens(self, prompt: List[int]) -> int:
-        """Prompt tokens this engine could COPY from its prefix pool
+        """Prompt tokens this engine could SHARE from its prefix cache
         instead of prefilling, right now (0 without a prefix cache).
         A pure host trie walk with peek=True: probing every replica
         per routing decision must not perturb any replica's LRU
@@ -3247,7 +2722,7 @@ class DecodeEngine:
         if self._prefix is None:
             return 0
         ids, _ = self._prefix.match(prompt, peek=True)
-        return len(ids) * self.prefix_block
+        return len(ids) * self.kv_block_tokens
 
     # -- internals ---------------------------------------------------------
 
@@ -3293,135 +2768,30 @@ class DecodeEngine:
         device dispatches. The group key (the prompt's first block) is
         None for prompts too short to ever share a block."""
         ids, pending = self._prefix.match(prompt)
-        T = self.prefix_block
+        T = self.kv_block_tokens
         key = tuple(prompt[:T]) if len(prompt) > T else None
         return len(ids) * T, key, pending
 
-    def _admit_rows(self, admissions: List[Tuple[int, _Request]]) -> None:
-        """Bind this step's admissions to their rows and start their
-        prefills. With the prefix cache on, each admission first probes
-        the trie: a warm prompt's matched blocks are COPIED from the
-        device pool into the row (grouped so same-chain-length copies
-        share ONE `_prefix_copy_in` program) and only the suffix is
-        prefilled; novel full blocks are registered PENDING and copied
-        out to the pool as the row's prefill covers them. The actual
-        prefill work — whole suffix, or `prefill_chunk`-sized pieces
-        across steps — runs in `_advance_prefills`. First tokens are
-        NOT sampled here: each row's last-prompt logits stay on device
-        in `_last_logits` and the fused decode samples them — admission
-        costs zero host round-trips. The paged engine admits through
-        `_admit_rows_paged` instead: matched blocks are SHARED (incref,
-        zero copies), not copied."""
-        if self.paged:
-            self._admit_rows_paged(admissions)
-            return
-        copy_groups: Dict[int, List[Tuple[int, List[int]]]] = {}
-        draft_seeds: List[Tuple[int, List[int]]] = []
-        for row, req in admissions:
-            self.metrics.on_admit(req.req_id)   # queue wait ends here
-            if self.trace.enabled:
-                self.trace.close("queue_wait", req.req_id)
-                self.trace.instant("admit", req.req_id, {"row": row})
-            if req.resume and req.tokens:
-                # Fleet-failover resume (dense engine): replay
-                # prompt + already-emitted tokens as the prefill —
-                # mathematically the K/V the dead replica held — and
-                # continue the stream at the saved token index. No
-                # trie traffic: emitted tokens are not a shareable
-                # prompt, and this replica may never have seen the
-                # prompt's blocks.
-                replay = list(req.prompt) + list(req.tokens)
-                self.row_req[row] = req
-                self.row_len[row] = 0
-                self.row_budget[row] = (req.max_new_tokens
-                                        - len(req.tokens))
-                self._tok_idx[row] = len(req.tokens)
-                self._row_keys[row] = self._req_key(req)
-                self._row_greedy[row] = (self.greedy
-                                         if req.greedy is None
-                                         else bool(req.greedy))
-                self._row_slot[row] = self._pending_slots.pop(
-                    req.req_id, 0)
-                self._row_prefill[row] = _PrefillState(req, 0, [],
-                                                       prompt=replay)
-                if self.spec_enabled:
-                    draft_seeds.append((row, replay))
-                continue
-            start = 0
-            nodes: list = []
-            # Adapter rows BYPASS the prefix trie entirely: their K/V
-            # depends on the adapter's deltas, so a block produced
-            # under adapter X must never be matched by (or registered
-            # for) a request under adapter Y or the base model.
-            if self._prefix is not None and req.adapter_id is None:
-                ids, _ = self._prefix.match(req.prompt)
-                self.prefix_lookups += 1
-                T = self.prefix_block
-                if ids:
-                    self.prefix_hits += 1
-                    start = len(ids) * T
-                    self.prefix_reused_tokens += start
-                    # Pad the chain to a power of two (repeat the last
-                    # block: its rewrite is overwritten by the suffix
-                    # prefill / never attended) so a handful of copy-in
-                    # compiles cover every chain length.
-                    nbp = _pow2(len(ids))
-                    if nbp * T > self.max_len:
-                        nbp = len(ids)
-                    ids_p = list(ids) + [ids[-1]] * (nbp - len(ids))
-                    copy_groups.setdefault(nbp, []).append((row, ids_p))
-                nodes = self._prefix.extend(req.prompt)
-                self.metrics.on_prefix(hit=bool(ids), reused_tokens=start)
-                if self.trace.enabled:
-                    self.trace.instant(
-                        "prefix_match", req.req_id,
-                        {"hit": bool(ids), "matched_tokens": start})
-            self.row_req[row] = req
-            self.row_len[row] = start          # frontier: copied prefix
-            self.row_budget[row] = req.max_new_tokens
-            self._tok_idx[row] = 0
-            self._row_keys[row] = self._req_key(req)
-            self._row_greedy[row] = (self.greedy if req.greedy is None
-                                     else bool(req.greedy))
-            self._row_slot[row] = self._pending_slots.pop(req.req_id, 0)
-            self._row_prefill[row] = _PrefillState(req, start, nodes)
-            if self.spec_enabled:
-                # The draft plane has no prefix cache: even a warm
-                # target admission seeds the draft with the FULL
-                # prompt, piggybacked on this admission step.
-                draft_seeds.append((row, list(req.prompt)))
-        for nbp in sorted(copy_groups):
-            grp = copy_groups[nbp]
-            n = len(grp)
-            n_pad = _pow2(n)
-            rows = np.zeros((n_pad,), np.int32)
-            bids = np.zeros((n_pad, nbp), np.int32)
-            for i, (row, ids_p) in enumerate(grp):
-                rows[i] = row
-                bids[i] = ids_p
-            rows[n:] = rows[n - 1]     # duplicate scatters: identical
-            bids[n:] = bids[n - 1]     # values, deterministic result
-            self.cache = _prefix_copy_in(
-                self.cache, self._pool_k, self._pool_v,
-                jnp.asarray(bids), jnp.asarray(rows), nbp,  # graftlint: disable=jit-hygiene -- one compile per chain-length bucket is deliberate; nbp is bounded by max_len/prefix_block
-                self.prefix_block, shardings=self._shardings)
-            self.prefix_copy_dispatches += 1
-        self._seed_draft_rows(draft_seeds)
-
-    # -- paged KV: admission, block accounting, preempt-and-swap -----------
+    # -- admission, block accounting, preempt-and-swap ---------------------
 
     def _admit_rows_paged(
             self, admissions: List[Tuple[int, _Request]]) -> None:
-        """Paged admission: bind each request to a BLOCK CHAIN instead
-        of a cache row. A warm prompt's matched blocks are shared by
-        incref — zero bytes move, the PR-4 `_prefix_copy_in` gather
-        does not exist on this path. A FULL-prompt match keeps all but
+        """Bind this step's admissions to their rows and start their
+        prefills: each request gets a BLOCK CHAIN. With the prefix
+        cache on, each admission first probes the trie: a warm prompt's
+        matched blocks are shared by incref — zero bytes move — and
+        only the suffix is prefilled. A FULL-prompt match keeps all but
         the tail block shared and copies the tail once (copy-on-write:
         the row's first generated token must extend it). Novel prompt
-        blocks are freshly allocated, registered PENDING in the trie
-        (the row's prefill writes them in place — commit needs no copy
-        either), and the suffix prefills exactly as in dense mode."""
-        T = self.prefix_block
+        blocks are freshly allocated and registered PENDING in the trie
+        (the row's prefill writes them in place — commit needs no
+        copy). The actual prefill work — whole suffix, or
+        `prefill_chunk`-sized pieces across steps — runs in
+        `_advance_prefills`. First tokens are NOT sampled here: each
+        row's last-prompt logits stay on device in `_last_logits` and
+        the fused decode samples them — admission costs zero host
+        round-trips."""
+        T = self.kv_block_tokens
         cow_pairs: List[Tuple[int, int]] = []
         draft_seeds: List[Tuple[int, List[int]]] = []
         for row, req in admissions:
@@ -3450,8 +2820,10 @@ class DecodeEngine:
             shared: List[int] = []
             cow_src: Optional[int] = None
             nodes: list = []
-            # Adapter rows bypass the trie (see _admit_rows): shared
-            # K/V must not cross adapter boundaries.
+            # Adapter rows BYPASS the prefix trie entirely: their K/V
+            # depends on the adapter's deltas, so a block produced
+            # under adapter X must never be matched by (or registered
+            # for) a request under adapter Y or the base model.
             if self._prefix is not None and req.adapter_id is None:
                 ids, _ = self._prefix.match(req.prompt, allow_full=True)
                 self.prefix_lookups += 1
@@ -3533,7 +2905,7 @@ class DecodeEngine:
         draft only lowers acceptance, never changes emitted tokens."""
         if not self.spec_enabled or not seeds:
             return
-        T = self.prefix_block
+        T = self.kv_block_tokens
         groups: Dict[int, List[Tuple[int, List[int]]]] = {}
         for row, toks in seeds:
             self._d_lag[row] = 0
@@ -3541,8 +2913,7 @@ class DecodeEngine:
             self._spec_hist[row].clear()
             if not toks:
                 continue
-            if self.paged and not self._ensure_draft_blocks(
-                    row, -(-len(toks) // T)):
+            if not self._ensure_draft_blocks(row, -(-len(toks) // T)):
                 continue
             Cb = min(self._bucket(len(toks)), self.max_len)
             groups.setdefault(Cb, []).append((row, toks))
@@ -3563,25 +2934,17 @@ class DecodeEngine:
                 prompts[n:] = prompts[n - 1]    # filler: repeat last row —
                 rows[n:] = rows[n - 1]          # duplicate scatters write
                 last_idx[n:] = last_idx[n - 1]  # identical values
-                if self.paged:
-                    bt_grp = self._bt_d[rows]
-                    (self._pool_dk, self._pool_dv, self._scale_dk,
-                     self._scale_dv,
-                     self._d_last_logits, _) = _prefill_rows_paged(
-                        self.draft_params, jnp.asarray(prompts),
-                        self._pool_dk, self._pool_dv, self._d_last_logits,
-                        jnp.asarray(bt_grp), jnp.asarray(rows),
-                        jnp.asarray(starts), jnp.asarray(last_idx),
-                        self.draft_cfg, shardings=self._d_shardings,
-                        scale_k=self._scale_dk, scale_v=self._scale_dv,
-                        qspec=self.kv_quant_spec)
-                else:
-                    self._d_cache, self._d_last_logits, _ = _prefill_rows(
-                        self.draft_params, jnp.asarray(prompts),
-                        self._d_cache, self._d_last_logits,
-                        jnp.asarray(rows), jnp.asarray(starts),
-                        jnp.asarray(last_idx), self.draft_cfg,
-                        shardings=self._d_shardings)
+                bt_grp = self._bt_d[rows]
+                (self._pool_dk, self._pool_dv, self._scale_dk,
+                 self._scale_dv,
+                 self._d_last_logits, _) = _prefill_rows_paged(
+                    self.draft_params, jnp.asarray(prompts),
+                    self._pool_dk, self._pool_dv, self._d_last_logits,
+                    jnp.asarray(bt_grp), jnp.asarray(rows),
+                    jnp.asarray(starts), jnp.asarray(last_idx),
+                    self.draft_cfg, shardings=self._d_shardings,
+                    scale_k=self._scale_dk, scale_v=self._scale_dv,
+                    qspec=self.kv_quant_spec)
                 self.spec_prefill_dispatches += 1
 
     def _bind_row(self, row: int, req: _Request, chain: List[int],
@@ -3643,7 +3006,7 @@ class DecodeEngine:
         snapshot. False when the pool (plus evictable prefix blocks)
         cannot cover it; rows already grown keep their blocks — no
         leak, the retry after preemption re-walks them as no-ops."""
-        T = self.prefix_block
+        T = self.kv_block_tokens
         for b in rows:
             req = self.row_req[b]
             lim = min(len(req.prompt) + req.max_new_tokens,
@@ -3697,7 +3060,7 @@ class DecodeEngine:
                     H = 1      # shrink the horizon before giving up
                     continue
                 raise RuntimeError(
-                    "paged KV pool exhausted with a single decodable "
+                    "KV pool exhausted with a single decodable "
                     "row at horizon 1 — kv_pool_bytes is too small "
                     "for this request shape (mid-prefill rows may be "
                     "holding the remainder)")
@@ -3718,6 +3081,29 @@ class DecodeEngine:
             return hook(ordered, self.row_req)
         return ordered[-1]
 
+    def _spill_row(self, row: int) -> _SwapState:
+        """Gather a row's blocks (quantized bytes and their scale rows
+        verbatim) and its last logits to the host: the state a swap-in
+        or a handoff import scatters back. `copy_to_host_async` on all
+        of them first, so the pulls overlap."""
+        ids = self._row_blocks[row]
+        n = len(ids)
+        bids = np.zeros((_pow2(max(1, n)),), np.int32)
+        bids[:n] = ids                 # pad = null block
+        k, v, sk, sv = _swap_out_gather(
+            self._pool_k, self._pool_v, jnp.asarray(bids),
+            shardings=self._shardings, scale_k=self._scale_k,
+            scale_v=self._scale_v)
+        parts = [k, v, self._last_logits[row], sk, sv]
+        for x in parts:
+            if x is not None:
+                _host_async(x)
+        k, v, lg, sk, sv = (None if x is None else self._device_wait(x)
+                            for x in parts)
+        return _SwapState(k, v, n, int(self.row_len[row]),
+                          int(self._tok_idx[row]),
+                          int(self.row_budget[row]), lg, sk=sk, sv=sv)
+
     def _preempt_row(self, row: int) -> None:
         """Evict a live decodable row mid-decode. swap mode gathers
         its blocks into fresh buffers, starts `copy_to_host_async`,
@@ -3733,41 +3119,16 @@ class DecodeEngine:
         req = self.row_req[row]
         ids = self._row_blocks[row]
         if self.preempt_mode == "swap":
-            n = len(ids)
-            nbp = _pow2(max(1, n))
-            bids = np.zeros((nbp,), np.int32)
-            bids[:n] = ids
-            k, v, sk, sv = _swap_out_gather(
-                self._pool_k, self._pool_v, jnp.asarray(bids),
-                shardings=self._shardings, scale_k=self._scale_k,
-                scale_v=self._scale_v)
-            lg = self._last_logits[row]
-            for x in (k, v, lg, sk, sv):
-                if x is not None:
-                    _host_async(x)
-            k = self._device_wait(k)
-            v = self._device_wait(v)
-            lg = self._device_wait(lg)
-            if sk is not None:
-                sk = self._device_wait(sk)
-                sv = self._device_wait(sv)
-            self._swapped[req.req_id] = _SwapState(
-                k, v, n, int(self.row_len[row]),
-                int(self._tok_idx[row]), int(self.row_budget[row]), lg,
-                sk=sk, sv=sv)
-            nbytes = k.nbytes + v.nbytes + lg.nbytes
-            if sk is not None:
-                nbytes += sk.nbytes + sv.nbytes
+            swap = self._spill_row(row)
             self.swap_outs += 1
-            self.swap_out_bytes += nbytes
-            self.metrics.on_swap_out(nbytes)
-            swap_bytes = nbytes
+            self.swap_out_bytes += swap.nbytes
+            self.metrics.on_swap_out(swap.nbytes)
         else:
-            swap_bytes = 0
-            self._swapped[req.req_id] = _SwapState(
+            swap = _SwapState(
                 None, None, len(ids), int(self.row_len[row]),
                 int(self._tok_idx[row]), int(self.row_budget[row]),
                 None)
+        self._swapped[req.req_id] = swap
         self._release_row_blocks(row)
         if self._row_slot[row]:
             # The row's adapter reference dies with the row; the gate
@@ -3784,7 +3145,7 @@ class DecodeEngine:
             self.trace.span_since_mark(
                 "preempt_swap_out", req.req_id,
                 {"mode": self.preempt_mode, "blocks": len(ids),
-                 "bytes": swap_bytes})
+                 "bytes": swap.nbytes})
         req.resume = True
         self._requeue_front(req)
 
@@ -3796,7 +3157,7 @@ class DecodeEngine:
         re-prefills prompt + emitted tokens (mathematically the same
         K/V) and continues the token stream at the saved tok_idx.
         False if the pool cannot cover it right now (caller requeues)."""
-        T = self.prefix_block
+        T = self.kv_block_tokens
         if swap.k is None:
             replay = list(req.prompt) + list(req.tokens)
             ids = self._pool_alloc(-(-len(replay) // T))
@@ -3840,9 +3201,7 @@ class DecodeEngine:
         self._bind_row(row, req, ids, swap.row_len)
         self.row_budget[row] = swap.budget
         self._tok_idx[row] = swap.tok_idx
-        nbytes = swap.k.nbytes + swap.v.nbytes + swap.logits.nbytes
-        if swap.sk is not None:
-            nbytes += swap.sk.nbytes + swap.sv.nbytes
+        nbytes = swap.nbytes
         self.swap_ins += 1
         self.swap_in_bytes += nbytes
         self.metrics.on_swap_in(nbytes)
@@ -3870,13 +3229,12 @@ class DecodeEngine:
         The request must be bound to a live row that is NOT
         mid-chunked-prefill, with the async pipeline empty (on a
         prefill-only engine the ring is always empty: it never
-        dispatches a decode program). A paged engine gathers the row's
-        KV blocks to host via the preempt-and-swap `_swap_out_gather`
-        path — quantized bytes plus their scale rows move verbatim —
-        together with the row's last-prompt-token logits; a dense
-        engine exports no bytes and the importer re-prefills
-        (recompute handoff). Either way the row's blocks are decref'd,
-        its adapter pin released, and the request leaves this engine
+        dispatches a decode program). The row's KV blocks are gathered
+        to host via the preempt-and-swap `_swap_out_gather` path —
+        quantized bytes plus their scale rows move verbatim — together
+        with the row's last-prompt-token logits. The row's blocks are
+        decref'd, its adapter pin released, and the request leaves this
+        engine
         entirely (`results` included): it now lives wherever
         `import_request` lands it.
 
@@ -3910,41 +3268,16 @@ class DecodeEngine:
         # wants the guard in assert form).
         assert not self._ring
         req = self.row_req[row]
-        kv = None
-        nbytes = 0
-        if self.paged:
-            ids = self._row_blocks[row]
-            n = len(ids)
-            nbp = _pow2(max(1, n))
-            bids = np.zeros((nbp,), np.int32)
-            bids[:n] = ids
-            k, v, sk, sv = _swap_out_gather(
-                self._pool_k, self._pool_v, jnp.asarray(bids),
-                shardings=self._shardings, scale_k=self._scale_k,
-                scale_v=self._scale_v)
-            lg = self._last_logits[row]
-            for x in (k, v, lg, sk, sv):
-                if x is not None:
-                    _host_async(x)
-            k = self._device_wait(k)
-            v = self._device_wait(v)
-            lg = self._device_wait(lg)
-            if sk is not None:
-                sk = self._device_wait(sk)
-                sv = self._device_wait(sv)
-            nbytes = k.nbytes + v.nbytes + lg.nbytes
-            if sk is not None:
-                nbytes += sk.nbytes + sv.nbytes
-            kv = {"k": k, "v": v, "sk": sk, "sv": sv,
-                  "n_blocks": n,
-                  "row_len": int(self.row_len[row]),
-                  "tok_idx": int(self._tok_idx[row]),
-                  "budget": int(self.row_budget[row]),
-                  "logits": lg,
-                  "block_tokens": self.prefix_block,
-                  "quant": self.kv_quant,
-                  "pool_shape": self._kv_geometry}
-            self._release_row_blocks(row)
+        st = self._spill_row(row)
+        nbytes = st.nbytes
+        kv = {"k": st.k, "v": st.v, "sk": st.sk, "sv": st.sv,
+              "n_blocks": st.n_blocks, "row_len": st.row_len,
+              "tok_idx": st.tok_idx, "budget": st.budget,
+              "logits": st.logits,
+              "block_tokens": self.kv_block_tokens,
+              "quant": self.kv_quant,
+              "pool_shape": self._kv_geometry}
+        self._release_row_blocks(row)
         if self._row_slot[row]:
             # The exporting row's adapter pin dies here; the importing
             # engine's admission gate re-pins (and prefetches a cold
@@ -3975,7 +3308,7 @@ class DecodeEngine:
             self.trace.span_since_mark(
                 "handoff_export", req.req_id,
                 {"bytes": nbytes,
-                 "blocks": 0 if kv is None else kv["n_blocks"],
+                 "blocks": kv["n_blocks"],
                  "tokens": len(req.tokens)})
         return handoff
 
@@ -3984,11 +3317,11 @@ class DecodeEngine:
         half of the handoff. Re-submits it under THIS engine's queue
         discipline (same rng key, greedy mode, priority, adapter), and
         when the exported KV payload is compatible with this engine's
-        pool (paged, same block size, same quantization, same KV
-        geometry) pre-seeds the paged swap ledger with it: admission
-        then scatters the bytes back via `_swap_in_scatter` and the
-        row is decodable immediately — no re-prefill. Incompatible or
-        dense payloads fall back to recompute (prompt + any emitted
+        pool (same block size, same quantization, same KV geometry)
+        pre-seeds the swap ledger with it: admission then scatters the
+        bytes back via `_swap_in_scatter` and the row is decodable
+        immediately — no re-prefill. Incompatible or missing payloads
+        fall back to recompute (prompt + any emitted
         tokens replay), which is slower but bit-identical. Returns the
         request id on this engine."""
         kv = handoff.get("kv")
@@ -4004,8 +3337,8 @@ class DecodeEngine:
         req = self.results[rid]
         req.handoff = True
         compatible = (
-            kv is not None and self.paged
-            and kv["block_tokens"] == self.prefix_block
+            kv is not None
+            and kv["block_tokens"] == self.kv_block_tokens
             and kv["quant"] == self.kv_quant
             and kv["pool_shape"] == self._kv_geometry)
         if compatible:
@@ -4019,10 +3352,7 @@ class DecodeEngine:
                 kv["tok_idx"], kv["budget"], kv["logits"],
                 sk=kv["sk"], sv=kv["sv"])
             req.resume = True
-            nbytes = kv["k"].nbytes + kv["v"].nbytes \
-                + kv["logits"].nbytes
-            if kv["sk"] is not None:
-                nbytes += kv["sk"].nbytes + kv["sv"].nbytes
+            nbytes = self._swapped[rid].nbytes
         else:
             nbytes = 0
         self.handoffs_in += 1
@@ -4066,7 +3396,7 @@ class DecodeEngine:
         reclaimable? Pure host probe (peek=True) — deferring an
         admission must not perturb LRU recency. An optimistic stale
         answer is safe: `_admit_rows_paged` re-checks and requeues."""
-        T = self.prefix_block
+        T = self.kv_block_tokens
         swap = self._swapped.get(req.req_id)
         if swap is not None:
             if swap.k is not None:
@@ -4086,11 +3416,11 @@ class DecodeEngine:
         return need <= self.kv_free_blocks()
 
     def _commit_covered(self, row: int, st: _PrefillState) -> None:
-        """Paged twin of `_flush_copy_out`: the row's prefill writes
-        the trie's blocks DIRECTLY (they ARE the row's chain), so a
-        pending block the frontier has covered just commits — zero
-        copy dispatches, which is the whole point."""
-        T = self.prefix_block
+        """The row's prefill writes the trie's blocks DIRECTLY (they
+        ARE the row's chain), so a pending block the frontier has
+        covered just commits — zero copy dispatches — and from the next
+        admission round on `match` hands it to warm requests."""
+        T = self.kv_block_tokens
         while st.nodes and (st.nodes[0][0] + 1) * T <= st.pos:
             _, node = st.nodes.pop(0)
             self._prefix.commit(node)
@@ -4098,11 +3428,11 @@ class DecodeEngine:
     def _advance_prefills(self) -> None:
         """Advance every mid-prefill row by one chunk (the whole
         remaining suffix when `prefill_chunk` is None), same-bucket
-        chunks batched into ONE `_prefill_rows` program. A row whose
-        frontier reaches its prompt length leaves `_row_prefill` and is
-        decodable THIS step (its last chunk scattered the true
-        last-prompt logits). Completed prefix blocks are flushed to the
-        pool and committed as the frontier passes them."""
+        chunks batched into ONE `_prefill_rows_paged` program. A row
+        whose frontier reaches its prompt length leaves `_row_prefill`
+        and is decodable THIS step (its last chunk scattered the true
+        last-prompt logits). Pending prefix blocks are committed as the
+        frontier passes them."""
         if not self._row_prefill:
             return
         with self.trace.lane("advance_prefills", "dispatch",
@@ -4145,29 +3475,19 @@ class DecodeEngine:
                         row_slot = jnp.asarray(self._row_slot[rows])
                     else:
                         adapters = row_slot = None
-                    if self.paged:
-                        bt_grp = self._bt[rows]            # [n_pad, MB]
-                        (self._pool_k, self._pool_v, self._scale_k,
-                         self._scale_v, self._last_logits,
-                         self._moe_ctr) = _prefill_rows_paged(
-                            self.params, jnp.asarray(prompts), self._pool_k,
-                            self._pool_v, self._last_logits,
-                            jnp.asarray(bt_grp), jnp.asarray(rows),
-                            jnp.asarray(starts), jnp.asarray(last_idx),
-                            self.cfg, shardings=self._shardings,
-                            adapters=adapters, row_slot=row_slot,
-                            scale_k=self._scale_k, scale_v=self._scale_v,
-                            qspec=self.kv_quant_spec,
-                            moe_ctr=self._moe_ctr)
-                    else:
-                        (self.cache, self._last_logits,
-                         self._moe_ctr) = _prefill_rows(
-                            self.params, jnp.asarray(prompts), self.cache,
-                            self._last_logits, jnp.asarray(rows),
-                            jnp.asarray(starts), jnp.asarray(last_idx),
-                            self.cfg, shardings=self._shardings,
-                            adapters=adapters, row_slot=row_slot,
-                            moe_ctr=self._moe_ctr)
+                    bt_grp = self._bt[rows]            # [n_pad, MB]
+                    (self._pool_k, self._pool_v, self._scale_k,
+                     self._scale_v, self._last_logits,
+                     self._moe_ctr) = _prefill_rows_paged(
+                        self.params, jnp.asarray(prompts), self._pool_k,
+                        self._pool_v, self._last_logits,
+                        jnp.asarray(bt_grp), jnp.asarray(rows),
+                        jnp.asarray(starts), jnp.asarray(last_idx),
+                        self.cfg, shardings=self._shardings,
+                        adapters=adapters, row_slot=row_slot,
+                        scale_k=self._scale_k, scale_v=self._scale_v,
+                        qspec=self.kv_quant_spec,
+                        moe_ctr=self._moe_ctr)
                     self.prefill_dispatches += 1
                     padded = n_pad * Cb - real
                     self.prefill_real_tokens += real
@@ -4185,46 +3505,18 @@ class DecodeEngine:
                             {"pos": st.pos, "tokens": C,
                              "prompt_tokens": len(st.prompt)})
                     if self._prefix is not None:
-                        if self.paged:
-                            self._commit_covered(row, st)
-                        else:
-                            self._flush_copy_out(row, st)
+                        self._commit_covered(row, st)
                     if st.pos >= len(st.prompt):
                         done_rows.append(row)
             for row in done_rows:
                 st = self._row_prefill.pop(row)
                 self.metrics.on_decodable(st.req.req_id)
 
-    def _flush_copy_out(self, row: int, st: _PrefillState) -> None:
-        """Copy every pending prefix block the row's frontier now
-        covers out to the pool (one program per consecutive run,
-        chain length padded to a power of two with the scratch block)
-        and COMMIT it — from the next admission round on, `match` will
-        hand the block to warm requests."""
-        T = self.prefix_block
-        while st.nodes and (st.nodes[0][0] + 1) * T <= st.pos:
-            run = [st.nodes.pop(0)]
-            while st.nodes and st.nodes[0][0] == run[-1][0] + 1 and \
-                    (st.nodes[0][0] + 1) * T <= st.pos:
-                run.append(st.nodes.pop(0))
-            nbp = _pow2(len(run))
-            bids = np.zeros((nbp,), np.int32)   # pad = scratch block 0
-            for i, (_, node) in enumerate(run):
-                bids[i] = node.block_id
-            self._pool_k, self._pool_v = _prefix_copy_out(
-                self.cache["k"], self.cache["v"], self._pool_k,
-                self._pool_v, row,
-                run[0][0] * T, jnp.asarray(bids), nbp, T,  # graftlint: disable=jit-hygiene -- nbp is power-of-two bucketed (_pow2), distinct static values are log-bounded
-                shardings=self._shardings)
-            self.prefix_copy_dispatches += 1
-            for _, node in run:
-                self._prefix.commit(node)
-
     def _emit_block(self, block: np.ndarray, entry: _InflightStep,
                     emitted: Dict[int, List[int]]
                     ) -> Tuple[int, int, int]:
         """VECTORIZED host replay of one [H, B] token block: mirrors
-        `_decode_multi`'s per-iteration transition without touching the
+        `_decode_multi_paged`'s per-iteration transition without touching the
         device, but in one numpy slice + one arithmetic pass per ROW
         instead of a Python iteration per token.
 
@@ -4317,12 +3609,11 @@ class DecodeEngine:
                 if self.spec_enabled:
                     self._d_lag[b] = 0
                     self._d_tok[b] = 0
-                if self.paged:
-                    # Blocks the trie shares stay resident (its ref);
-                    # everything else returns to the pool NOW — this
-                    # is what lets admission capacity track finished
-                    # tokens instead of max-live slots.
-                    self._release_row_blocks(b)
+                # Blocks the trie shares stay resident (its ref);
+                # everything else returns to the pool NOW — this is
+                # what lets admission capacity track finished tokens
+                # instead of max-live slots.
+                self._release_row_blocks(b)
                 if self._row_slot[b]:
                     # Retirement drops the row's adapter pin; a
                     # refcount-0 slot stays RESIDENT (LRU) so the next

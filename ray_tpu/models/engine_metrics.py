@@ -286,7 +286,7 @@ class EngineMetrics:
         self._m_kv_shared = counter(
             "llm_engine_kv_blocks_shared_total",
             "Prefix-cache blocks SHARED into warm admissions by "
-            "refcount (zero bytes copied — the paged twin of "
+            "refcount (zero bytes copied — the block count beside "
             "prefix_reused_tokens)")
         self._m_kv_cow = counter(
             "llm_engine_kv_block_cow_total",

@@ -1815,8 +1815,8 @@ class LLMFleet:
                 (s.get("tp_degree", 1.0) for s in per), default=1.0),
             "host_transfer_bytes": sum(
                 s.get("host_transfer_bytes", 0.0) for s in per),
-            # Paged-KV plane: zero-copy sharing / preempt-and-swap
-            # rollup (all-zero when replicas run the dense cache).
+            # Block-pool plane: zero-copy sharing / preempt-and-swap
+            # rollup.
             "kv_blocks_shared": sum(
                 s.get("kv_blocks_shared", 0.0) for s in per),
             "kv_block_cows": sum(
@@ -1835,7 +1835,7 @@ class LLMFleet:
             # Quantized-KV plane: replicas are homogeneous in
             # practice, so the mean bytes/token IS the fleet's KV cost
             # per cached token; quant_replicas counts how many run a
-            # low-bit pool (0 = dense fleet).
+            # low-bit pool (0 = every pool in the model's own dtype).
             "kv_quant_replicas": sum(
                 s.get("kv_quant_enabled", 0.0) for s in per),
             "kv_bytes_per_token_mean": (

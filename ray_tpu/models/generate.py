@@ -377,7 +377,7 @@ def step_rng_key(rng: jax.Array, step) -> jax.Array:
     of how many steps are fused into one program — the key for a row's
     i-th sampled token depends only on (rng, i). That invariance is
     what lets the continuous-batching engine fuse H decode iterations
-    into one program (engine.py `_decode_multi`) and still reproduce a
+    into one program (engine.py `_decode_multi_paged`) and still reproduce a
     request's solo `generate` samples token-for-token: each request
     carries its own rng stream, folded with its own token index, no
     matter which batch companions or horizon boundaries it crosses."""

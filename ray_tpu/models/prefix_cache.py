@@ -8,27 +8,27 @@ single largest remaining throughput lever once decode itself is fused.
 
 This module is the pure-host half of that design: a radix/trie index at
 BLOCK granularity (``block_tokens`` tokens per node — only full blocks
-are shareable, the vLLM rule) mapping token-sequence prefixes to slots
-in a device-resident pool of cached K/V blocks. The device half — the
-pool arrays themselves and the one-program gather/scatter copies in and
-out of engine slot rows — lives in ``models/engine.py``
-(``_prefix_copy_in`` / ``_prefix_copy_out``); this index never touches
-a device buffer, so matching and eviction cost zero dispatches.
+are shareable, the vLLM rule) mapping token-sequence prefixes to blocks
+of the engine's refcounted ``BlockPool`` — the same pool that backs
+every live request's block table, so sharing a cached prefix is an
+incref and publishing one copies nothing. The device half — the pool
+arrays — lives in ``models/engine.py``; this index never touches a
+device buffer, so matching and eviction cost zero dispatches.
 
 Concurrency/ordering contract with the engine (single-threaded, but
-dispatch-ordered): a node is created PENDING when the engine plans to
-fill its block (the owning row's prefill must first produce the K/V)
-and COMMITTED once the copy-out program has been dispatched. `match`
-only walks committed nodes; eviction only takes committed leaves.
-Because XLA executes same-device programs in dispatch order, a block
-evicted and reassigned on the host is still read with its OLD content
-by any copy-in dispatched before the new owner's copy-out.
+dispatch-ordered): a node is created PENDING when a row that is about
+to prefill its block registers it, and COMMITTED once that row's
+prefill frontier has covered the block (the program that writes it has
+been dispatched). `match` only walks committed nodes; eviction only
+takes committed leaves. Because XLA executes same-device programs in
+dispatch order, a block evicted and reassigned on the host is still
+read with its OLD content by any program dispatched before the new
+owner's write.
 
-Eviction is LRU over committed leaf nodes under a byte budget (the pool
-is preallocated at ``n_blocks`` = budget // block_bytes): evicting a
-leaf frees exactly one block; interior nodes become leaves as their
-children go, so cold chains drain tail-first while hot shared prefixes
-(recent ``last_use``) survive.
+Eviction is LRU over committed leaf nodes that nobody but the trie
+holds: evicting a leaf releases exactly one block; interior nodes
+become leaves as their children go, so cold chains drain tail-first
+while hot shared prefixes (recent ``last_use``) survive.
 """
 
 from __future__ import annotations
@@ -47,24 +47,23 @@ def block_bytes(n_layers: int, block_tokens: int, kv_heads: int,
     the PR-7 docs), so both are now explicit:
 
     - LAYERS: a block id indexes the pool's ``n_blocks`` axis of BOTH
-      pool arrays (``[L, NB, T, KV*D]`` paged, ``[L, NB, T, KV, D]`` the
-      dense engine's prefix pool), so one block holds T tokens'
+      pool arrays (``[L, NB, T, KV*D]``), so one block holds T tokens'
       K/V for ALL ``n_layers`` decoder layers. The default (and the
       number every byte budget must divide by) is therefore the
       layer-SUMMED figure ``2 * L * T * KV * D * dtype``;
       ``per_layer=True`` returns the single-layer slice (what one
-      layer's gather touches — the microbench unit).
+      layer's gather touches).
     - MESH: the returned figure is GLOBAL across the serving mesh. On
       a tensor-parallel engine whose KV-head axis shards over tp, each
-      chip holds block_bytes/tp of it; ``prefix_cache_bytes`` /
-      ``kv_pool_bytes`` therefore size the pool in global bytes at
+      chip holds block_bytes/tp of it; ``kv_pool_bytes`` therefore
+      sizes the pool in global bytes at
       every tp degree (same block count, smaller per-chip slice), so
       eviction/preemption behavior — and the emitted token stream — is
       identical sharded or not.
 
     Pool sizing from a byte budget is exact: a budget of
     ``k * block_bytes(...)`` buys exactly k shareable blocks (the
-    reserved scratch block 0 rides on top — it is part of the pool
+    reserved null block 0 rides on top — it is part of the pool
     allocation but never holds cached data)."""
     layers = 1 if per_layer else n_layers
     return 2 * layers * block_tokens * kv_heads * head_dim * dtype_bytes
@@ -85,27 +84,17 @@ class _Node:
 
 
 class PrefixCacheIndex:
-    """Radix index over cached prompt prefixes at block granularity.
+    """Radix index over cached prompt prefixes at block granularity,
+    over the engine's shared refcounted ``pool``.
 
     ``match(prompt)`` returns the pool block ids of the longest
-    COMMITTED chain of full blocks prefixing ``prompt`` — capped so the
-    matched length never covers the whole prompt (the engine must
-    always prefill at least the final token to have last-token logits
-    to sample from, the same rule vLLM applies).
+    COMMITTED chain of full blocks prefixing ``prompt``.
 
-    ``extend(prompt)`` walks the chain for every full block of
-    ``prompt`` and creates missing nodes as PENDING, allocating pool
-    blocks from the free list (evicting LRU committed leaves when it
-    runs dry). The caller fills each pending node's block from the
-    owning row's prefilled K/V and then calls ``commit(node)``.
+    ``register(prompt, block_ids)`` binds the chain for every full
+    block of ``prompt`` to the caller's own blocks, creating missing
+    nodes as PENDING; the caller calls ``commit(node)`` once its
+    prefill has covered a node's block.
 
-    Block id 0 is RESERVED as scratch: copy programs pad their block-id
-    vectors to a power of two with it so a handful of XLA compiles
-    cover every chain length; garbage scattered there is never indexed.
-
-    PAGED MODE (``pool=`` a shared BlockPool): the index no longer
-    owns a private free list — blocks belong to the engine-wide
-    refcounted pool that also backs every live request's block table.
     The trie holds ONE pool reference per cached block (`register`
     increfs a row's freshly filled blocks instead of copying them out;
     warm admissions incref matched blocks instead of copying them in),
@@ -117,20 +106,12 @@ class PrefixCacheIndex:
     tests/test_engine_paged.py.
     """
 
-    def __init__(self, *, block_tokens: int, n_blocks: int,
-                 on_evict: Optional[Callable[[int], None]] = None,
-                 pool: Optional[BlockPool] = None):
+    def __init__(self, *, block_tokens: int, pool: BlockPool,
+                 on_evict: Optional[Callable[[int], None]] = None):
         if block_tokens < 1:
             raise ValueError("block_tokens must be >= 1")
-        if n_blocks < 2:
-            raise ValueError(
-                "n_blocks must be >= 2 (block 0 is the scratch block); "
-                "raise prefix_cache_bytes or shrink prefix_block")
         self.block_tokens = block_tokens
-        self.n_blocks = n_blocks
         self.pool = pool
-        self._free: List[int] = ([] if pool is not None
-                                 else list(range(n_blocks - 1, 0, -1)))
         self._root = _Node(None, -1, None)
         self._nodes: List[_Node] = []
         self._clock = 0
@@ -148,7 +129,7 @@ class PrefixCacheIndex:
 
     @property
     def blocks_total(self) -> int:
-        return self.n_blocks - 1          # scratch block excluded
+        return self.pool.blocks_total     # null block excluded
 
     # -- core ops ----------------------------------------------------------
 
@@ -172,13 +153,12 @@ class PrefixCacheIndex:
         defers such requests one step so they admit warm.
 
         ``allow_full=True`` lifts the one-suffix-token cap to
-        ``len(prompt) // block_tokens`` — the PAGED engine's entry: a
+        ``len(prompt) // block_tokens`` — the admission path's entry: a
         block-aligned prompt matching its whole chain shares every
         block and COPY-ON-WRITES the last one (recomputing only the
         final token inside the private copy for its logits), instead
-        of recomputing a full block of suffix. The copy-in engine must
-        NOT use this: it has no CoW, so writing the recomputed final
-        token would land in the shared pool block.
+        of recomputing a full block of suffix. Probes leave it off:
+        they report what can be shared without a copy.
 
         ``peek=True`` leaves LRU recency untouched: a pure read for
         load probes (the fleet router scores EVERY replica's trie per
@@ -200,48 +180,18 @@ class PrefixCacheIndex:
             node = child
         return ids, False
 
-    def extend(self, prompt) -> List[Tuple[int, "_Node"]]:
-        """Ensure a (possibly pending) node chain exists for every full
-        block of ``prompt``; returns ``[(block_index, node), ...]`` for
-        the nodes CREATED by this call — always a consecutive tail of
-        the chain — which the caller must fill and ``commit``. Stops
-        early (shorter list) if the pool runs dry even after LRU
-        eviction; the uncached tail simply isn't shared."""
-        node = self._root
-        created: List[Tuple[int, _Node]] = []
-        protect = {id(self._root)}
-        for j in range(len(prompt) // self.block_tokens):
-            key = self._chunk(prompt, j)
-            child = node.children.get(key)
-            if child is None:
-                bid = self._alloc(protect)
-                if bid is None:
-                    break
-                child = _Node(key, bid, node)
-                node.children[key] = child
-                self._nodes.append(child)
-                created.append((j, child))
-            child.last_use = self._tick()
-            protect.add(id(child))
-            node = child
-        return created
-
     def register(self, prompt, block_ids: List[int]
                  ) -> List[Tuple[int, "_Node"]]:
-        """Paged-mode twin of `extend`: bind the chain for every full
-        block of ``prompt`` to the caller's OWN pool blocks
-        (``block_ids[j]`` backs chain position j) instead of
-        allocating fresh ones — the row that is about to prefill those
-        blocks donates a share, so publication is zero-copy: the trie
+        """Bind the chain for every full block of ``prompt`` to the
+        caller's OWN pool blocks (``block_ids[j]`` backs chain position
+        j) — the row that is about to prefill those blocks donates a
+        share, so publication is zero-copy: the trie
         increfs each newly registered block and there is nothing to
         copy out when the prefill lands. Positions already in the trie
         are left untouched (their existing block holds identical
         content; the caller keeps its own reference to its own block).
         Returns the nodes CREATED — pending until the caller's prefill
         frontier covers them and it calls ``commit``."""
-        if self.pool is None:
-            raise ValueError("register() requires a pool-backed index "
-                             "(pass pool= at construction)")
         node = self._root
         created: List[Tuple[int, _Node]] = []
         for j in range(len(prompt) // self.block_tokens):
@@ -260,54 +210,44 @@ class PrefixCacheIndex:
         return created
 
     def commit(self, node: "_Node") -> None:
-        """Mark a pending node's block as filled (copy-out dispatched)."""
+        """Mark a pending node's block as filled (the prefill that
+        writes it has been dispatched)."""
         node.committed = True
         node.last_use = self._tick()
 
-    # -- allocation / eviction ---------------------------------------------
+    # -- eviction ----------------------------------------------------------
 
-    def _evictable(self, n: "_Node", protect) -> bool:
+    def _evictable(self, n: "_Node") -> bool:
         """Eviction candidacy, HARDENED for the refcounted pool: a
-        victim must be a committed childless leaf outside the caller's
-        protected chain AND — when pool-backed — a block whose only
-        remaining holder is the trie itself. A refcount above 1 means
-        a live row's block table (or a swapped-out request) still
-        reads the block; recycling it would corrupt that reader, so
-        such blocks are simply not candidates until their last sharer
-        releases them."""
-        if n.children or not n.committed or id(n) in protect:
-            return False
-        if self.pool is not None and self.pool.ref(n.block_id) != 1:
-            return False
-        return True
+        victim must be a committed childless leaf whose only remaining
+        holder is the trie itself. A refcount above 1 means a live
+        row's block table (or a swapped-out request) still reads the
+        block; recycling it would corrupt that reader, so such blocks
+        are simply not candidates until their last sharer releases
+        them."""
+        return (not n.children and n.committed
+                and self.pool.ref(n.block_id) == 1)
 
-    def _evict_victim(self, protect) -> Optional[int]:
-        """Evict the LRU evictable leaf; returns its block id (with
-        the trie's reference DROPPED in pool mode — the block is free
-        unless someone else still holds it) or None."""
+    def evict_one(self) -> bool:
+        """Release the LRU evictable leaf's block back to the shared
+        pool (the engine calls this when `BlockPool.alloc` runs dry —
+        cold cache always gives way before any live request is
+        preempted). Returns False when nothing is evictable."""
         victim = None
         for n in self._nodes:
-            if not self._evictable(n, protect):
+            if not self._evictable(n):
                 continue
             if victim is None or n.last_use < victim.last_use:
                 victim = n
         if victim is None:
-            return None
+            return False
         victim.parent.children.pop(victim.key, None)
         self._nodes.remove(victim)
         self.evictions += 1
         if self._on_evict is not None:
             self._on_evict(1)
-        if self.pool is not None:
-            self.pool.decref([victim.block_id])
-        return victim.block_id
-
-    def evict_one(self) -> bool:
-        """Release one cold cached block back to the shared pool
-        (paged engines call this when `BlockPool.alloc` runs dry —
-        cold cache always gives way before any live request is
-        preempted). Returns False when nothing is evictable."""
-        return self._evict_victim({id(self._root)}) is not None
+        self.pool.decref([victim.block_id])
+        return True
 
     def evictable_blocks(self) -> int:
         """How many cached blocks COULD be released by repeated
@@ -319,18 +259,17 @@ class PrefixCacheIndex:
         evicting a childless leaf makes its parent childless, so a
         whole cold chain is reclaimable even though only its tail is
         evictable right now. Counting only the instantaneous leaves
-        under-reports capacity and livelocks the paged engine's
-        admission gate — `_fits_now` says a swapped-out request can
-        never fit while `_pool_alloc`'s evict loop would in fact free
-        the chain (regression-tested by the tight-pool churn in
-        `_bench_paged`). A node is reclaimable iff it is committed,
+        under-reports capacity and livelocks the engine's admission
+        gate — `_fits_now` says a swapped-out request can never fit
+        while `_pool_alloc`'s evict loop would in fact free the chain.
+        A node is reclaimable iff it is committed,
         the trie holds its only reference, and EVERY descendant is
         reclaimable too (a shared or pending descendant pins the whole
         path to the root above it)."""
         def reclaimable(n) -> bool:
             if not n.committed:
                 return False
-            if self.pool is not None and self.pool.ref(n.block_id) != 1:
+            if self.pool.ref(n.block_id) != 1:
                 return False
             return all(reclaimable(c) for c in n.children.values())
 
@@ -353,22 +292,3 @@ class PrefixCacheIndex:
             n = stack.pop()
             yield n
             stack.extend(n.children.values())
-
-    def _alloc(self, protect) -> Optional[int]:
-        if self.pool is not None:
-            ids = self.pool.alloc(1)
-            if ids is not None:
-                return ids[0]
-            return self._evict_victim_realloc(protect)
-        if self._free:
-            return self._free.pop()
-        return self._evict_victim(protect)
-
-    def _evict_victim_realloc(self, protect) -> Optional[int]:
-        """Pool-mode retry: evict one cold block, then re-alloc from
-        the pool (the evicted block is only actually free if the trie
-        was its last holder — `_evictable` guarantees it was)."""
-        if self._evict_victim(protect) is None:
-            return None
-        ids = self.pool.alloc(1)
-        return None if ids is None else ids[0]

@@ -2,7 +2,7 @@
 
 A cold run of the serving engine or the train step at real widths spends
 minutes compiling, so every entry point that runs on the chip
-(`chip_smoke.py`'s children, `bench.py`, `microbench.py`) calls
+(`chip_smoke.py`'s children, `bench.py`, the benchmark's drivers) calls
 `enable_compile_cache()` first thing in its `main`, and the raylet gives
 a worker leased to TPU work the same directory. Not at import: tests
 import those modules and run on the CPU without a cache.

@@ -144,7 +144,6 @@ def engine_state(engine) -> Dict[str, Any]:
         "batch_slots": engine.B,
         "max_len": engine.max_len,
         "tp_degree": engine.tp_degree,
-        "paged": bool(engine.paged),
         "draining": bool(engine.draining),
         "scheduler": type(engine.scheduler).__name__,
         "queue_depth": len(engine.scheduler),
@@ -154,7 +153,7 @@ def engine_state(engine) -> Dict[str, Any]:
         "kv_used_fraction": engine.kv_used_fraction(),
         "kv_free_blocks": engine.kv_free_blocks(),
         "pending_prefill_tokens": engine.pending_prefill_tokens(),
-        "requests_swapped": len(engine._swapped) if engine.paged else 0,
+        "requests_swapped": len(engine._swapped),
         "pipeline_inflight": len(engine._ring),
         "tokens_out": engine.tokens_out,
         "uptime_s": max(0.0, engine._clock() - engine._start_t),
@@ -208,7 +207,7 @@ def engine_requests(engine) -> List[Dict[str, Any]]:
     for those."""
     now = engine._clock()
     rows: List[Dict[str, Any]] = []
-    swapped_ids = set(engine._swapped) if engine.paged else set()
+    swapped_ids = set(engine._swapped)
     prefill_only = bool(getattr(engine, "prefill_only", False))
     for b, st in engine._row_prefill.items():
         rows.append(_req_row(engine, st.req, "prefilling", row=b,
@@ -307,43 +306,29 @@ def list_requests(status: Optional[str] = None,
 
 
 def list_kv_pools(limit: int = 1000) -> List[Dict[str, Any]]:
-    """One row per engine that owns KV block storage: the paged
-    engine's unified pool (refcount ledger included) or the dense
-    engine's prefix-cache pool. Engines with neither are omitted."""
+    """One row per engine: its KV block pool (refcount ledger included)
+    and, with a prefix cache, what the trie holds of it."""
     rows: List[Dict[str, Any]] = []
     for eng in engines():
         pool = eng.kv_pool
         prefix = eng._prefix
-        if pool is None and prefix is None:
-            continue
         row: Dict[str, Any] = {
             "engine_id": eng.engine_id,
-            "kind": "paged" if pool is not None else "prefix",
-            "block_tokens": eng.prefix_block,
-            # Quantized-KV plane: storage dtype (None = dense kv_dtype)
+            "block_tokens": eng.kv_block_tokens,
+            # Quantized-KV plane: storage dtype (None = the model's own)
             # and the byte cost one block/token actually pays, scale
-            # slab included. getattr defaults keep pre-quant engine
-            # objects (or test doubles) listable.
-            "quant": getattr(eng, "kv_quant", None),
-            "bytes_per_block": float(
-                getattr(eng, "kv_bytes_per_block", 0.0)),
-            "bytes_per_token": float(
-                getattr(eng, "kv_bytes_per_token", 0.0)),
+            # slab included.
+            "quant": eng.kv_quant,
+            "bytes_per_block": float(eng.kv_bytes_per_block),
+            "bytes_per_token": float(eng.kv_bytes_per_token),
         }
-        if pool is not None:
-            row.update(pool.snapshot())
-            row["occupancy"] = (pool.blocks_in_use / pool.blocks_total
-                                if pool.blocks_total else 0.0)
+        row.update(pool.snapshot())
+        row["occupancy"] = (pool.blocks_in_use / pool.blocks_total
+                            if pool.blocks_total else 0.0)
         if prefix is not None:
             row["prefix_blocks_in_use"] = prefix.blocks_in_use
             row["prefix_blocks_total"] = prefix.blocks_total
             row["evictable_blocks"] = prefix.evictable_blocks()
-            if pool is None:
-                row["blocks_total"] = prefix.blocks_total
-                row["blocks_in_use"] = prefix.blocks_in_use
-                row["occupancy"] = (
-                    prefix.blocks_in_use / prefix.blocks_total
-                    if prefix.blocks_total else 0.0)
         rows.append(row)
     return rows[:limit]
 

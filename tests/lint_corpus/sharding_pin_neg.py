@@ -37,11 +37,11 @@ class Engine:
             self._scale_k = jax.device_put(self._scale_k,
                                            self._shardings.scale)
 
-    def init_cache(self, cfg):
+    def init_draft_pool(self, cfg):
         # NEGATIVE: explicit sharding kwarg at the build site.
-        self.cache = build_cache(cfg, sharding=self._shardings.cache)
+        self._pool_dk = build_pool(cfg, sharding=self._shardings.d_pool)
 
     def teardown(self):
         # NEGATIVE: None sentinel and plain moves never decay a layout.
         self._pool_k = self._pool_v = None
-        self.cache = self._checkpoint_cache
+        self._last_logits = self._checkpoint_logits

@@ -139,12 +139,12 @@ def test_phase_serve_nano(fake_tpu, capsys):
         kv_pool_bytes=1 << 20, **SERVE_NANO)
     lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
     variants = {ln["variant"]: ln for ln in lines if "variant" in ln}
-    assert list(variants) == ["solo_generate", "dense", "paged",
-                              "paged_int8", "fleet_2x_paged"]
+    assert list(variants) == ["solo_generate", "paged", "paged_int8",
+                              "fleet_2x_paged"]
     # f32 on the CPU: the repo's identity holds exactly, off the kernel
-    assert report["pass"] and report["identity_dense_vs_solo"]
+    assert report["pass"] and report["identity_vs_solo"]
     assert report["paged_impl"] == "reference"
-    for name in ("dense", "paged", "fleet_2x_paged"):
+    for name in ("paged", "fleet_2x_paged"):
         assert variants[name]["identical_to_reference"]
         assert variants[name]["greedy_margin_max"] == 0.0
     int8 = variants["paged_int8"]
@@ -174,9 +174,10 @@ def test_phase_train_nano(fake_tpu):
 
 def test_phase_serve_tp4_nano(fake_tpu, capsys):
     report = chip_smoke.phase_serve_tp4(
-        3, LlamaConfig.nano(max_seq_len=256, n_kv_heads=4), **SERVE_NANO)
+        3, LlamaConfig.nano(max_seq_len=256, n_kv_heads=4),
+        kv_block_tokens=8, kv_pool_bytes=1 << 20, **SERVE_NANO)
     lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
-    tp = next(ln for ln in lines if ln.get("variant") == "dense_tp4")
+    tp = next(ln for ln in lines if ln.get("variant") == "paged_tp4")
     assert tp["spans_all_devices"] and tp["identical_to_reference"]
     assert tp["impl"] == "reference" and tp["greedy_margin_max"] == 0.0
     assert len(tp["device_bytes_share"]) == 4

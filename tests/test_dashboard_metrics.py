@@ -262,7 +262,7 @@ def test_state_endpoints_live_engine(dash_base):
     cfg = LlamaConfig.nano()
     params = llama_init(jax.random.PRNGKey(0), cfg)
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       prefix_cache=True, prefix_block=4,
+                       prefix_cache=True, kv_block_tokens=4,
                        engine_id="dash-eng")
     for p, n in [([5, 6, 7], 8), ([9, 8, 7, 6], 8), ([1, 2], 8),
                  ([3, 1, 4], 8)]:
@@ -297,8 +297,9 @@ def test_state_endpoints_live_engine(dash_base):
 
     pools = _get_json(dash_base, "/api/v0/state/kv_pools")
     pool, = [p for p in pools if p["engine_id"] == "dash-eng"]
-    assert pool["kind"] == "prefix"
-    assert pool["blocks_total"] == eng._prefix.blocks_total
+    assert pool["block_tokens"] == 4
+    assert pool["blocks_total"] == eng.kv_pool.blocks_total
+    assert pool["prefix_blocks_in_use"] == eng._prefix.blocks_in_use
 
     summary = _get_json(dash_base, "/api/v0/state/summary")
     assert summary["engines_total"] == len(serving.engines())

@@ -124,7 +124,7 @@ def test_quant_off_bit_identity_matrix(nano_model, features):
     ref = [_solo(params, cfg, p, n)
            for p, n in zip(prompts, budgets)]
     got, eng = _run(params, cfg, prompts, budgets,
-                    eng_kw={**kw, "paged": True, "kv_block_tokens": T,
+                    eng_kw={**kw, "kv_block_tokens": T,
                             "kv_quant": None})
     assert got == ref, "quant-off paged engine diverged from solo"
     s = eng.stats()
@@ -144,7 +144,7 @@ def test_quant_off_preemption_identity(nano_model):
     dense_bb = block_bytes(cfg.n_layers, T, cfg.n_kv_heads,
                            cfg.head_dim, jnp.dtype(cfg.dtype).itemsize)
     eng = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T, kv_quant=None,
+                       kv_block_tokens=T, kv_quant=None,
                        kv_pool_bytes=10 * dense_bb, prefix_cache=False)
     ids = [eng.submit(p, M) for p in prompts]
     out = eng.run()
@@ -176,7 +176,7 @@ def test_quant_on_token_tolerance_gate(nano_model, quant, mode):
             else [jax.random.PRNGKey(3000 + i)
                   for i in range(len(prompts))])
     rng_kw = {} if mode["greedy"] else {"rng": jax.random.PRNGKey(7)}
-    base_kw = {**mode, **rng_kw, "paged": True, "kv_block_tokens": T}
+    base_kw = {**mode, **rng_kw, "kv_block_tokens": T}
     dense, _ = _run(params, cfg, prompts, budgets,
                     eng_kw=base_kw, keys=keys)
     qtoks, eng = _run(params, cfg, prompts, budgets,
@@ -240,14 +240,14 @@ def test_quant_swap_round_trip_exact(nano_model):
                [2, 7, 1, 8, 2], [9, 9, 8, 8, 7]]
     M = 12
     ample = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                         paged=True, kv_block_tokens=T,
+                         kv_block_tokens=T,
                          kv_quant="int8", prefix_cache=False)
     ids = [ample.submit(p, M) for p in prompts]
     want = ample.run()
     want = [want[r] for r in ids]
 
     tight = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                         paged=True, kv_block_tokens=T,
+                         kv_block_tokens=T,
                          kv_quant="int8", prefix_cache=False,
                          kv_pool_bytes=_quant_pool_bytes(cfg, 10))
     assert tight.kv_pool.blocks_total == 10
@@ -273,14 +273,14 @@ def test_quant_recompute_preemption_exact(nano_model):
                [2, 7, 1, 8, 2], [9, 9, 8, 8, 7]]
     M = 12
     ample = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                         paged=True, kv_block_tokens=T,
+                         kv_block_tokens=T,
                          kv_quant="int8", prefix_cache=False)
     ids = [ample.submit(p, M) for p in prompts]
     want = ample.run()
     want = [want[r] for r in ids]
 
     rec = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T, kv_quant="int8",
+                       kv_block_tokens=T, kv_quant="int8",
                        preempt="recompute", prefix_cache=False,
                        kv_pool_bytes=_quant_pool_bytes(cfg, 10))
     ids = [rec.submit(p, M) for p in prompts]
@@ -299,7 +299,7 @@ def test_quant_cow_on_shared_tail_exact(nano_model):
     cfg, params = nano_model
     sys_p = list(range(1, 13))       # exactly 3 blocks at T=4
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T, kv_quant="int8",
+                       kv_block_tokens=T, kv_quant="int8",
                        prefix_cache=True)
     a = eng.submit(sys_p, 4)
     out = eng.run()
@@ -311,7 +311,6 @@ def test_quant_cow_on_shared_tail_exact(nano_model):
     s1 = eng.stats()
     assert s1["kv_block_cows"] - s0["kv_block_cows"] == 1
     assert s1["kv_blocks_shared"] - s0["kv_blocks_shared"] == 2
-    assert s1["prefix_copy_dispatches"] == s0["prefix_copy_dispatches"]
 
 
 # ---------------------------------------------------------------------------
@@ -377,11 +376,36 @@ def test_resolve_kv_quant_names():
         resolve_kv_quant("int4")
 
 
-def test_engine_rejects_quant_without_paged(nano_model):
+def test_engine_rejects_paged_false(nano_model):
+    """The block pool is the one KV path: the keyword the benchmark's
+    files still pass accepts True and nothing else."""
     cfg, params = nano_model
-    with pytest.raises(ValueError, match="paged"):
+    DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN, paged=True)
+    with pytest.raises(ValueError, match="PR 29"):
         DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
-                     kv_quant="int8")
+                     paged=False)
+
+
+@pytest.mark.parametrize("quant,itemsize", [(None, 4), ("int8", 1),
+                                            ("fp8_e4m3", 1)])
+def test_pool_bytes_per_token_are_exact(nano_model, quant, itemsize):
+    """What a cached token costs, from shapes (the count the old CPU
+    bench printed as 132 against 512 bytes): K and V for every layer
+    and KV head at the pool's storage width, plus — quantized — a
+    block's two f32 scale rows spread over its T tokens. A byte budget
+    buys blocks in that ratio."""
+    cfg, params = nano_model
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
+                       kv_block_tokens=8, kv_quant=quant,
+                       kv_pool_bytes=1 << 16)
+    L, KV, D = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    per_token = 2 * L * KV * D * itemsize \
+        + (2 * L * KV * 4 / 8 if quant else 0)
+    assert eng.kv_bytes_per_token == per_token
+    assert eng.kv_bytes_per_block == 8 * per_token
+    assert eng.kv_pool.blocks_total == (1 << 16) // (8 * per_token)
+    if (L, KV, D) == (2, 2, 16):
+        assert per_token == (132.0 if quant else 512.0)
 
 
 # ---------------------------------------------------------------------------
@@ -633,7 +657,7 @@ def test_paged_walk_counters_match_the_hand_count(nano_model, lengths):
     cfg, params = nano_model
     B = 2
     eng = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T, pipeline_depth=1)
+                       kv_block_tokens=T, pipeline_depth=1)
     rng = np.random.RandomState(5)
     for L, n in lengths:
         eng.submit(rng.randint(1, cfg.vocab_size, size=L).tolist(), n)

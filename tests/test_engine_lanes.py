@@ -150,8 +150,7 @@ def test_ttft_is_the_sum_of_its_three_parts(nano_model, fake_clock, chunk,
         return real(x)
 
     monkeypatch.setattr(engine_mod, "_device_get", slow_get)
-    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=32, paged=True,
-                       kv_block_tokens=4, prefill_chunk=chunk,
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=32, kv_block_tokens=4, prefill_chunk=chunk,
                        preempt=preempt,
                        kv_pool_bytes=_pool_bytes(cfg, 10),
                        prefix_cache=False, clock=fake_clock)
@@ -226,8 +225,7 @@ def _scopes_in(names):
 
 def test_decode_and_prefill_programs_carry_the_scopes(nano_model):
     cfg, params = nano_model
-    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32, paged=True,
-                       kv_block_tokens=4)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32, kv_block_tokens=4)
     seen = {}
 
     def spy(name):

@@ -93,11 +93,11 @@ def _pool_bytes(cfg, n_blocks):
 ], ids=["greedy", "top_k"])
 @pytest.mark.parametrize("features", [
     {},
-    {"paged": True, "kv_block_tokens": T, "prefix_cache": True},
-    {"paged": True, "kv_block_tokens": T, "prefix_cache": True,
+    {"kv_block_tokens": T, "prefix_cache": True},
+    {"kv_block_tokens": T, "prefix_cache": True,
      "pipeline_depth": 2},
     {"tp": 2},
-], ids=["dense", "paged_prefix", "paged_prefix_pipeline", "tp2"])
+], ids=["blocks32", "prefix", "prefix_pipeline", "tp2"])
 def test_mixed_adapter_identity_matrix(nano_model, adapters, mode,
                                        features):
     """Three distinct adapters + base-only rows through ONE engine with
@@ -117,7 +117,7 @@ def test_mixed_adapter_identity_matrix(nano_model, adapters, mode,
     keys = None if mode["greedy"] else _req_keys(len(prompts))
     rng_kw = {} if mode["greedy"] else {"rng": jax.random.PRNGKey(7)}
 
-    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=40,
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
                        lora=LCFG, max_live_adapters=2,
                        **mode, **rng_kw, **features)
     for a, lp in loras.items():
@@ -152,8 +152,8 @@ def test_preempt_swap_identity_with_adapters(nano_model, adapters):
     prompts = [[7, 8, 9, 10, 11], [3, 1, 4, 1, 5],
                [2, 7, 1, 8, 2], [9, 9, 8, 8, 7]]
     aids = ["ad0", "ad1", None, "ad2"]
-    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=40,
-                       paged=True, kv_block_tokens=T,
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=64,
+                       kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 10),
                        prefix_cache=False, greedy=True,
                        lora=LCFG, max_live_adapters=2)

@@ -1,19 +1,20 @@
-"""Paged KV memory (ray_tpu/models/engine.py paged=True).
+"""Paged KV memory (ray_tpu/models/engine.py).
 
-The paged engine stores every request's K/V in blocks of one shared
+The engine stores every request's K/V in blocks of one shared
 refcounted pool (`models/block_pool.py`) behind a per-request block
-table, instead of a private [max_len] cache row per slot. The gold
-contract is unchanged and is THE thing this file pins:
+table, not in a private [max_len] cache row per slot. The gold
+contract is THE thing this file pins:
 
-- TOKEN IDENTITY. Paged output == dense-engine output == solo
-  `generate`, greedy and sampled, under the prefix cache, chunked
-  prefill, the async pipeline, tensor parallelism, and preemption.
-  `paged_attention` is the dense `_cached_attention` evaluated on the
-  block-table gather (the engine enforces max_len % block_tokens == 0
-  so the gathered view has exactly the dense cache-row shape), so the
-  identity holds bit-for-bit, not just approximately.
+- TOKEN IDENTITY. Engine output == solo `generate`, greedy and
+  sampled, at blocks of 4 and of 32 tokens, under the prefix cache,
+  chunked prefill, the async pipeline, tensor parallelism, and
+  preemption. `paged_attention` is solo generate's `_cached_attention`
+  evaluated on the block-table gather (the engine enforces
+  max_len % block_tokens == 0 so the gathered view has exactly the
+  shape of the cache `generate` keeps), so the identity holds
+  bit-for-bit, not just approximately.
 - ZERO-COPY warm admission. A prefix-cache hit increfs the matched
-  blocks into the new request's table — no `_prefix_copy_in` gather,
+  blocks into the new request's table — no gather,
   no device bytes moved. Only a FULL-prompt hit pays one
   copy-on-write block (the new row must extend the shared tail).
 - PREEMPT-AND-SWAP. When the pool runs dry mid-decode the engine
@@ -22,8 +23,8 @@ contract is unchanged and is THE thing this file pins:
   continues — with identical tokens, because the per-token rng key
   depends only on (request key, token index).
 - CAPACITY. Admission is bounded by pool blocks, not row slots: a
-  pool sized for B dense rows runs 2B+ concurrent requests when their
-  actual lengths need less than max_len each.
+  pool sized for B rows of max_len runs 2B+ concurrent requests when
+  their actual lengths need less than max_len each.
 """
 
 import numpy as np
@@ -116,10 +117,11 @@ def test_paged_token_identity_matrix_olmoe(nano_olmoe, features):
 
 
 def _paged_token_identity(nano_model, mode, features):
-    """Paged == dense == solo generate across the feature matrix.
-    Shared-prefix prompts drive refcounted block sharing under the
-    prefix variants; 5 requests through 2 slots churn admissions so
-    block alloc/free crosses slot reuse."""
+    """Engine == solo generate across the feature matrix, at one
+    32-token block a row (the default) and at blocks of 4. Shared-prefix
+    prompts drive refcounted block sharing under the prefix variants; 5
+    requests through 2 slots churn admissions so block alloc/free
+    crosses slot reuse."""
     cfg, params = nano_model
     base = _prompts(5, cfg)
     shared = list(range(3, 11))      # 2 full blocks at T=4
@@ -131,32 +133,26 @@ def _paged_token_identity(nano_model, mode, features):
                  rng=None if keys is None else keys[i])
            for i, (p, n) in enumerate(zip(prompts, budgets))]
 
-    dense, _ = _run(params, cfg, prompts, budgets,
-                    eng_kw={**mode, **rng_kw, **features}, keys=keys)
-    assert dense == ref, "dense engine diverged from solo generate"
-
-    paged, eng = _run(params, cfg, prompts, budgets,
-                      eng_kw={**mode, **rng_kw, **features,
-                              "paged": True, "kv_block_tokens": T},
-                      keys=keys)
-    assert paged == ref, "paged engine diverged from solo generate"
-    assert paged == dense
-    s = eng.stats()
-    assert s["paged"] == 1.0
-    assert s["kv_pool_blocks_in_use"] >= 0.0
-    # every retired row returned its blocks: only trie-held blocks stay
-    assert eng.kv_pool.blocks_in_use == \
-        (eng._prefix.blocks_in_use if eng._prefix else 0)
+    for block_tokens in (MAX_LEN, T):
+        got, eng = _run(params, cfg, prompts, budgets,
+                        eng_kw={**mode, **rng_kw, **features,
+                                "kv_block_tokens": block_tokens},
+                        keys=keys)
+        assert got == ref, \
+            f"blocks of {block_tokens}: diverged from solo generate"
+        # every retired row returned its blocks: only trie-held stay
+        assert eng.kv_pool.blocks_in_use == \
+            (eng._prefix.blocks_in_use if eng._prefix else 0)
 
 
 def test_paged_rejects_misaligned_block_size(nano_model):
     cfg, params = nano_model
     with pytest.raises(ValueError, match="divisible"):
         DecodeEngine(params, cfg, batch_slots=2, max_len=30,
-                     paged=True, kv_block_tokens=T)
+                     kv_block_tokens=T)
     with pytest.raises(ValueError, match="preempt"):
         DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
-                     paged=True, kv_block_tokens=T, preempt="drop")
+                     kv_block_tokens=T, preempt="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -164,13 +160,12 @@ def test_paged_rejects_misaligned_block_size(nano_model):
 # ---------------------------------------------------------------------------
 
 def test_warm_admission_is_zero_copy(nano_model):
-    """The PR's acceptance gate: a warm admission SHARES committed
-    blocks by incref — zero `_prefix_copy_in` dispatches, zero bytes
-    gathered — where the dense engine pays a d2d copy per hit."""
+    """A warm admission SHARES committed blocks by incref: no program
+    runs for it but the suffix's prefill."""
     cfg, params = nano_model
     sys_p = list(range(1, 13))       # 3 full blocks at T=4
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        prefix_cache=True)
     a = eng.submit(sys_p + [50, 51], 4)
     out = eng.run()
@@ -183,8 +178,9 @@ def test_warm_admission_is_zero_copy(nano_model):
     s1 = eng.stats()
     assert s1["prefix_hits"] - s0["prefix_hits"] == 1
     assert s1["kv_blocks_shared"] - s0["kv_blocks_shared"] == 3
-    # THE gate: no copy-in program ran for the warm admission.
-    assert s1["prefix_copy_dispatches"] == s0["prefix_copy_dispatches"]
+    # THE gate: the one program of the warm admission is its suffix's
+    assert s1["prefill_dispatches"] - s0["prefill_dispatches"] == 1
+    assert s1["prefill_real_tokens"] - s0["prefill_real_tokens"] == 3
     # non-aligned suffix -> frontier block is fresh, no CoW either
     assert s1["kv_block_cows"] == s0["kv_block_cows"]
     # reused tokens flow into the shared prefix accounting
@@ -198,7 +194,7 @@ def test_full_prompt_hit_pays_one_cow_block(nano_model):
     cfg, params = nano_model
     sys_p = list(range(1, 13))       # exactly 3 blocks
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        prefix_cache=True)
     a = eng.submit(sys_p, 4)
     eng.run()
@@ -209,7 +205,6 @@ def test_full_prompt_hit_pays_one_cow_block(nano_model):
     s1 = eng.stats()
     assert s1["kv_block_cows"] - s0["kv_block_cows"] == 1
     assert s1["kv_blocks_shared"] - s0["kv_blocks_shared"] == 2
-    assert s1["prefix_copy_dispatches"] == s0["prefix_copy_dispatches"]
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +246,7 @@ def test_fused_decode_touches_only_what_it_writes(nano_model, monkeypatch,
     monkeypatch.setattr(engine_mod, "_decode_multi_paged", spy)
     sys_p = list(range(1, 13))                   # 3 full shared blocks
     eng = DecodeEngine(params, cfg, batch_slots=3, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T, prefix_cache=True,
+                       kv_block_tokens=T, prefix_cache=True,
                        decode_horizon=4, kv_quant=quant)
     eng.submit(sys_p + [50, 51], 2)
     eng.run()                                    # commits the prefix
@@ -317,7 +312,7 @@ def _preempt_and_swap_round_trip(nano_model, mode):
     keys = None if mode["greedy"] else _req_keys(len(prompts), seed=3)
     rng_kw = {} if mode["greedy"] else {"rng": jax.random.PRNGKey(7)}
     eng = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 10),
                        prefix_cache=False, **mode, **rng_kw)
     assert eng.kv_pool.blocks_total == 10
@@ -348,7 +343,7 @@ def test_preempt_recompute_identity(nano_model, nano_olmoe, family):
                [2, 7, 1, 8, 2], [9, 9, 8, 8, 7]]
     M = 12
     eng = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        preempt="recompute",
                        kv_pool_bytes=_pool_bytes(cfg, 10),
                        prefix_cache=False)
@@ -378,7 +373,7 @@ def test_tight_pool_with_shared_prefix_trie_terminates(nano_model):
                for _ in range(6)]
     M = 6                            # each row: ceil(21/4) = 6 blocks
     eng = DecodeEngine(params, cfg, batch_slots=3, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 7),
                        prefix_cache=True)
     ids = [eng.submit(p, M) for p in prompts]
@@ -400,7 +395,7 @@ def test_preempt_and_swap_under_tp(nano_model):
                [2, 7, 1, 8, 2], [9, 9, 8, 8, 7]]
     M = 12
     eng = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                       tp=2, paged=True, kv_block_tokens=T,
+                       tp=2, kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 10),
                        prefix_cache=False)
     ids = [eng.submit(p, M) for p in prompts]
@@ -414,28 +409,27 @@ def test_preempt_and_swap_under_tp(nano_model):
 # Capacity: pool-bounded admission beats slot-bounded admission
 # ---------------------------------------------------------------------------
 
-def test_paged_runs_2x_dense_concurrency_on_same_budget(nano_model):
-    """The PR's capacity acceptance: on a pool holding what a dense
-    engine spends on 2 rows (2 * max_len tokens of K/V), the paged
-    engine runs 4+ CONCURRENT requests — their actual footprints are
+def test_paged_runs_2x_row_concurrency_on_same_budget(nano_model):
+    """The capacity acceptance: on a pool holding 2 rows of max_len
+    (2 * max_len tokens of K/V), the engine runs 4+ CONCURRENT requests — their actual footprints are
     small, and admission charges blocks, not a max_len-sized slot —
     with every token still identical to solo generate."""
     cfg, params = nano_model
-    n_dense_rows = 2
-    pool_blocks = n_dense_rows * (MAX_LEN // T)       # 16 blocks
+    n_full_rows = 2
+    pool_blocks = n_full_rows * (MAX_LEN // T)       # 16 blocks
     prompts = _prompts(6, cfg, seed=11, lo=3, hi=7)
     budgets = [5] * len(prompts)     # ceil((~6+5)/4) <= 3 blocks/row
 
-    eng = DecodeEngine(params, cfg, batch_slots=2 * n_dense_rows,
-                       max_len=MAX_LEN, paged=True, kv_block_tokens=T,
+    eng = DecodeEngine(params, cfg, batch_slots=2 * n_full_rows,
+                       max_len=MAX_LEN, kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, pool_blocks),
                        prefix_cache=False)
     assert eng.kv_pool.blocks_total == pool_blocks
     ids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
     eng.step()
     live = sum(r is not None for r in eng.row_req)
-    assert live >= 2 * n_dense_rows, \
-        f"only {live} live rows on a {n_dense_rows}-dense-row budget"
+    assert live >= 2 * n_full_rows, \
+        f"only {live} live rows on a {n_full_rows}-full-row budget"
     out = eng.run()
     for rid, p, n in zip(ids, prompts, budgets):
         assert out[rid] == _solo(params, cfg, p, n)
@@ -444,7 +438,7 @@ def test_paged_runs_2x_dense_concurrency_on_same_budget(nano_model):
 def test_submit_rejects_request_larger_than_pool(nano_model):
     cfg, params = nano_model
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 3),
                        prefix_cache=False)
     with pytest.raises(ValueError, match="pool"):
@@ -462,7 +456,7 @@ def test_referenced_blocks_never_evicted_property():
     refcounts never go negative or leak."""
     rng = np.random.RandomState(0)
     pool = BlockPool(24)
-    idx = PrefixCacheIndex(block_tokens=4, n_blocks=24, pool=pool)
+    idx = PrefixCacheIndex(block_tokens=4, pool=pool)
     live = []                        # simulated rows: lists of bids
 
     def rand_prompt():

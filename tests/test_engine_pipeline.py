@@ -68,9 +68,9 @@ def _run(params, cfg, prompts, budgets, depth, *, eng_kw=None,
 ], ids=["greedy", "top_k", "top_p"])
 @pytest.mark.parametrize("features", [
     {},
-    {"prefix_cache": True, "prefix_block": 4},
+    {"prefix_cache": True, "kv_block_tokens": 4},
     {"prefill_chunk": 3},
-    {"prefix_cache": True, "prefix_block": 4, "prefill_chunk": 3},
+    {"prefix_cache": True, "kv_block_tokens": 4, "prefill_chunk": 3},
 ], ids=["plain", "prefix", "chunked", "prefix+chunked"])
 def test_pipeline_token_identity_matrix(nano_model, mode, features):
     """Every (depth, sampling, prefix/chunk) combination produces the
@@ -112,8 +112,8 @@ def test_pipeline_identity_under_eviction_pressure(nano_model):
         pref = rng.randint(1, cfg.vocab_size, size=8).tolist()
         prompts += [pref + [30 + i], pref + [40 + i]]
     budgets = [5] * 6
-    kw = {"prefix_cache": True, "prefix_block": 4,
-          "prefix_cache_bytes": 4 * bb}
+    kw = {"prefix_cache": True, "kv_block_tokens": 4,
+          "kv_pool_bytes": 4 * bb}
     ref, eng = _run(params, cfg, prompts, budgets, 1, eng_kw=kw)
     assert eng.stats()["prefix_evictions"] > 0   # pressure was real
     for depth in (2, 4):
@@ -237,7 +237,7 @@ def test_nonblocking_dispatch_gate(nano_model, monkeypatch):
     def drive(depth):
         events = []
         real_get = engine_mod._device_get
-        real_multi = engine_mod._decode_multi
+        real_multi = engine_mod._decode_multi_paged
 
         def logged_get(x):
             events.append("get")
@@ -248,7 +248,7 @@ def test_nonblocking_dispatch_gate(nano_model, monkeypatch):
             return real_multi(*a, **k)
 
         monkeypatch.setattr(engine_mod, "_device_get", logged_get)
-        monkeypatch.setattr(engine_mod, "_decode_multi", logged_multi)
+        monkeypatch.setattr(engine_mod, "_decode_multi_paged", logged_multi)
         try:
             eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
                                pipeline_depth=depth, decode_horizon=4)
@@ -257,7 +257,7 @@ def test_nonblocking_dispatch_gate(nano_model, monkeypatch):
             eng.run()
         finally:
             monkeypatch.setattr(engine_mod, "_device_get", real_get)
-            monkeypatch.setattr(engine_mod, "_decode_multi",
+            monkeypatch.setattr(engine_mod, "_decode_multi_paged",
                                 real_multi)
         return events
 
@@ -306,20 +306,54 @@ def test_admissions_pending_hint():
         assert pol.admissions_pending() is False
 
 
-def test_microbench_dispatch_gap_section_cpu_quick():
-    """The microbench dispatch-gap section runs on CPU and shows the
-    structural win: the synchronous loop starves the device once per
-    block (gap > 0), the pipelined loop pre-dispatches so its mean
-    starvation gap is smaller — on any backend, because the gap is
-    host-side wall time."""
-    import microbench
+def test_run_ahead_leaves_fewer_dispatch_gaps(nano_model, monkeypatch):
+    """A dispatch GAP is a blocking pull issued while nothing else is
+    in flight: the device has nothing queued behind the block the host
+    is waiting for, so it idles through the host's replay. The
+    synchronous engine pays one per decode block; at depth 2 only the
+    flushes and the end of the stream do, and the mean ring depth at a
+    drain says the same (exactly 1 against more than 1)."""
+    cfg, params = nano_model
 
-    rows = {name: value for name, value, _unit
-            in microbench._dispatch_gap_section(quick=True)}
-    d1 = rows["engine_dispatch_gap_ms_d1"]
-    d2 = rows["engine_dispatch_gap_ms_d2"]
-    assert d1 > 0.0          # sync pays the replay between dispatches
-    assert d2 < d1           # run-ahead keeps the device fed
+    def drive(depth):
+        inflight = gaps = 0
+        real_get = engine_mod._device_get
+        real_multi = engine_mod._decode_multi_paged
+
+        def counted_get(x):
+            nonlocal inflight, gaps
+            gaps += inflight == 1
+            inflight -= 1
+            return real_get(x)
+
+        def counted_multi(*a, **k):
+            nonlocal inflight
+            inflight += 1
+            return real_multi(*a, **k)
+
+        monkeypatch.setattr(engine_mod, "_device_get", counted_get)
+        monkeypatch.setattr(engine_mod, "_decode_multi_paged",
+                            counted_multi)
+        try:
+            eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                               pipeline_depth=depth, decode_horizon=4)
+            for p in _prompts(2, cfg, seed=23):
+                eng.submit(p, 24)
+            eng.run()
+        finally:
+            monkeypatch.setattr(engine_mod, "_device_get", real_get)
+            monkeypatch.setattr(engine_mod, "_decode_multi_paged",
+                                real_multi)
+        assert inflight == 0
+        return gaps, eng.stats()
+
+    gaps1, s1 = drive(1)
+    gaps2, s2 = drive(2)
+    assert s1["decode_dispatches"] == s2["decode_dispatches"]
+    assert gaps1 == s1["decode_dispatches"]       # one per block
+    assert gaps2 < gaps1 / 2
+    assert s1["pipeline_depth_effective"] == 1.0
+    assert 1.5 < s2["pipeline_depth_effective"] <= 2.0
 
 
 # ---------------------------------------------------------------------------

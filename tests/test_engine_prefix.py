@@ -2,7 +2,7 @@
 models/prefix_cache.py, scheduler.PrefixAffinityPolicy).
 
 Contract under test, extending the engine gold contract: with the
-shared-prefix cache ON — warm admissions copying cached K/V blocks and
+shared-prefix cache ON — warm admissions sharing cached K/V blocks and
 prefilling only their suffix, chunked prefill interleaving with decode,
 LRU eviction under pool pressure, prefix-affinity admission deferral —
 every request's output stays token-identical to its solo `generate`
@@ -11,8 +11,7 @@ admission runs ZERO full-prompt prefill tokens (suffix only), and the
 padding-waste / prefix-reuse / stall telemetry lands in both stats()
 and the Prometheus registry. Satellites: derived stats ratios are
 0.0 (never NaN) on a fresh engine; speculative SpecStats publish
-through the same util.metrics plane; the microbench prefix section
-runs on CPU.
+through the same util.metrics plane.
 """
 
 import jax
@@ -23,6 +22,7 @@ import pytest
 from ray_tpu.models import LlamaConfig, llama_init
 from ray_tpu.models.engine import DecodeEngine
 from ray_tpu.models.engine_metrics import EngineMetrics
+from ray_tpu.models.block_pool import BlockPool
 from ray_tpu.models.generate import generate
 from ray_tpu.models.prefix_cache import PrefixCacheIndex, block_bytes
 from ray_tpu.models.scheduler import PrefixAffinityPolicy, make_policy
@@ -62,7 +62,7 @@ def test_prefix_identity_matrix(nano_model, mode, chunked):
     """Five requests sharing a system-prompt prefix, more requests than
     slots, prefix-affinity scheduling, cache ON (+ chunked prefill):
     every request matches its solo run exactly — warm admissions'
-    copied K/V and suffix-only prefill change no token."""
+    shared K/V and suffix-only prefill change no token."""
     cfg, params = nano_model
     kw = SAMPLING_MODES[mode]
     prompts = [PREFIX + s for s in SUFFIXES]
@@ -70,7 +70,7 @@ def test_prefix_identity_matrix(nano_model, mode, chunked):
     keys = [jax.random.PRNGKey(300 + i) for i in range(len(prompts))]
 
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       prefix_cache=True, prefix_block=4,
+                       prefix_cache=True, kv_block_tokens=4,
                        scheduler="prefix",
                        prefill_chunk=4 if chunked else None, **kw)
     ids = [eng.submit(p, n, rng=k)
@@ -103,7 +103,8 @@ def test_prefix_identity_under_eviction_pressure(nano_model):
     blocks while requests stream through — still token-identical, and
     evictions actually happened (the pressure was real)."""
     cfg, params = nano_model
-    # 6 usable blocks; 4 distinct prefixes x 2 blocks = 8 -> eviction.
+    # 6 usable blocks, 3 of them under the live row; 4 distinct
+    # prefixes x 2 blocks = 8 -> eviction.
     L, _, _, KV, D = (2, 0, 0, cfg.n_kv_heads, cfg.head_dim)
     bb = block_bytes(cfg.n_layers, 4, KV, D, 4)
     prompts = []
@@ -112,8 +113,8 @@ def test_prefix_identity_under_eviction_pressure(nano_model):
         pref = rng.randint(1, cfg.vocab_size, size=8).tolist()
         prompts += [pref + [30 + i], pref + [40 + i]]
     eng = DecodeEngine(params, cfg, batch_slots=1, max_len=32,
-                       prefix_cache=True, prefix_block=4,
-                       prefix_cache_bytes=6 * bb)
+                       prefix_cache=True, kv_block_tokens=4,
+                       kv_pool_bytes=6 * bb)
     ids = [eng.submit(p, 3) for p in prompts]
     out = eng.run()
     for rid, p in zip(ids, prompts):
@@ -136,7 +137,7 @@ def test_warm_admission_runs_zero_full_prompt_prefill(nano_model):
     cfg, params = nano_model
     prefix = list(range(1, 17))                       # 4 blocks of 4
     eng = DecodeEngine(params, cfg, batch_slots=1, max_len=32,
-                       prefix_cache=True, prefix_block=4)
+                       prefix_cache=True, kv_block_tokens=4)
     r0 = eng.submit(prefix + [21], 3)
     out0 = eng.run()
     assert out0[r0] == _solo(params, cfg, prefix + [21], 3)
@@ -149,7 +150,7 @@ def test_warm_admission_runs_zero_full_prompt_prefill(nano_model):
     assert eng.prefix_reused_tokens - reused0 == 16   # whole prefix
     s = eng.stats()
     assert s["prefix_hit_rate"] == 0.5                # 1 of 2 lookups
-    assert s["prefix_copy_dispatches"] >= 2           # out (cold) + in
+    assert s["kv_blocks_shared"] == 4.0               # shared, not copied
 
 
 def test_chunked_prefill_interleaves_with_decode(nano_model):
@@ -204,7 +205,7 @@ def test_prefix_policy_defers_followers_then_admits_warm(nano_model):
     cfg, params = nano_model
     prompts = [PREFIX[:8] + [s] for s in (31, 32, 33)]
     eng = DecodeEngine(params, cfg, batch_slots=3, max_len=32,
-                       prefix_cache=True, prefix_block=4,
+                       prefix_cache=True, kv_block_tokens=4,
                        scheduler="prefix")
     ids = [eng.submit(p, 4) for p in prompts]
     eng.step()
@@ -251,39 +252,49 @@ def test_prefix_policy_pop_returns_none_when_all_deferred():
 # PrefixCacheIndex unit behavior
 # ---------------------------------------------------------------------------
 
-def test_prefix_index_match_extend_commit_evict():
-    idx = PrefixCacheIndex(block_tokens=4, n_blocks=4)   # 3 usable
+def test_prefix_index_match_register_commit_evict():
+    pool = BlockPool(4)                    # 3 usable
+    idx = PrefixCacheIndex(block_tokens=4, pool=pool)
     p = [1, 2, 3, 4, 5, 6, 7, 8, 9]
     assert idx.match(p) == ([], False)
-    created = idx.extend(p)                # 2 full blocks
+    row = pool.alloc(2)                    # the row's own chain
+    created = idx.register(p, row)         # 2 full blocks
     assert [j for j, _ in created] == [0, 1]
     assert all(not n.committed for _, n in created)
+    assert [pool.ref(b) for b in row] == [2, 2]   # row + trie
     assert idx.match(p) == ([], True)      # pending, not matched
     for _, n in created:
         idx.commit(n)
     ids, pending = idx.match(p)
-    assert len(ids) == 2 and not pending
-    assert 0 not in ids                    # scratch block reserved
+    assert ids == row and not pending
+    assert 0 not in ids                    # null block reserved
     # Matched length never covers the whole prompt: a block-aligned
-    # prompt leaves its final block unusable (the vLLM rule).
+    # prompt leaves its final block unusable (the vLLM rule) unless the
+    # caller can copy-on-write it.
     ids8, _ = idx.match([1, 2, 3, 4, 5, 6, 7, 8])
     assert len(ids8) == 1
-    # Fill the pool, then evict: the LRU committed LEAF goes first.
-    c2 = idx.extend([1, 2, 3, 4, 9, 9, 9, 9])   # 1 new block (pool full)
+    assert len(idx.match([1, 2, 3, 4, 5, 6, 7, 8], allow_full=True)[0]) == 2
+    # A position already in the trie keeps its block: only the new
+    # tail is registered.
+    row2 = pool.alloc(1)
+    c2 = idx.register([1, 2, 3, 4, 9, 9, 9, 9], [row[0]] + row2)
+    assert [j for j, _ in c2] == [1]
     for _, n in c2:
         idx.commit(n)
-    assert idx.blocks_in_use == 3
-    c3 = idx.extend([9, 8, 7, 6, 5])       # needs 1 block -> evicts
-    assert len(c3) == 1
-    assert idx.evictions == 1
-    assert idx.blocks_in_use == 3          # still at capacity
+    assert idx.blocks_in_use == 3 and idx.blocks_total == 3
+    # Shared with a live row: nothing is evictable. Once the rows let
+    # go, the LRU committed LEAF goes first and its block is free.
+    assert not idx.evict_one() and idx.evictable_blocks() == 0
+    pool.decref(row + row2)
+    assert idx.evictable_blocks() == 3     # the cascade, not the leaves
+    assert idx.evict_one() and idx.evictions == 1
+    assert idx.blocks_in_use == 2 and pool.free_blocks == 1
+    assert idx.match(p)[0] == [row[0]]     # p's tail was the LRU leaf
 
 
 def test_prefix_index_validation():
-    with pytest.raises(ValueError, match="n_blocks"):
-        PrefixCacheIndex(block_tokens=4, n_blocks=1)
     with pytest.raises(ValueError, match="block_tokens"):
-        PrefixCacheIndex(block_tokens=0, n_blocks=4)
+        PrefixCacheIndex(block_tokens=0, pool=BlockPool(4))
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +308,7 @@ def test_stats_ratios_are_zero_before_any_token(nano_model):
     cfg, params = nano_model
     for enable in (True, False):
         eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                           prefix_cache=True, prefix_block=4,
+                           prefix_cache=True, kv_block_tokens=4,
                            enable_metrics=enable)
         s = eng.stats()
         for key in ("host_syncs_per_token", "dispatches_per_token",
@@ -319,7 +330,7 @@ def test_stats_ratios_are_zero_before_any_token(nano_model):
 def test_prefix_metrics_reach_prometheus_registry(nano_model):
     cfg, params = nano_model
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       prefix_cache=True, prefix_block=4,
+                       prefix_cache=True, kv_block_tokens=4,
                        engine_id="prefix-metrics-engine")
     prefix = list(range(1, 13))
     for s in (21, 22, 23):
@@ -376,22 +387,3 @@ def test_spec_stats_reach_prometheus_registry():
     assert rows["llm_spec_proposed_total"]["value"] == stats.proposed
     assert rows["llm_spec_acceptance_rate"]["value"] == \
         pytest.approx(stats.acceptance_rate)
-
-
-# ---------------------------------------------------------------------------
-# CI tooling: the microbench prefix section runs on CPU
-# ---------------------------------------------------------------------------
-
-def test_microbench_prefix_section_cpu_quick():
-    import microbench
-
-    rows = microbench._prefix_admission_section(quick=True)
-    names = [n for n, _, _ in rows]
-    assert "engine_prefix_admission_cold_ms_p128" in names
-    assert "engine_prefix_admission_warm_ms_p128" in names
-    vals = dict((n, v) for n, v, _ in rows)
-    assert vals["engine_prefix_admission_cold_ms_p128"] > 0
-    assert vals["engine_prefix_admission_warm_ms_p128"] > 0
-    # Admission pays at most the engine's usual one sync per step.
-    assert vals["engine_prefix_admission_cold_syncs_p128"] <= 1
-    assert vals["engine_prefix_admission_warm_syncs_p128"] <= 1

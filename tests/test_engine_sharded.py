@@ -1,7 +1,7 @@
 """Tensor-parallel DecodeEngine over an ICI mesh (ray_tpu/models/engine.py).
 
-`DecodeEngine(tp=n)` shards the model weights, the KV cache, the
-prefix block pool and the fused decode scan state across n devices via
+`DecodeEngine(tp=n)` shards the model weights, the KV block pool
+and the fused decode scan state across n devices via
 the model's logical axis rules (heads/mlp/vocab over "tp"; KV heads
 when divisible). These tests run on the conftest-forced 8-device
 virtual CPU mesh (see the note next to FakeClock in conftest.py) and
@@ -77,8 +77,8 @@ def _run(params, cfg, prompts, budgets, tp, *, eng_kw=None, keys=None):
 @pytest.mark.parametrize("features", [
     {"pipeline_depth": 1},
     {"pipeline_depth": 2},
-    {"prefix_cache": True, "prefix_block": 4, "pipeline_depth": 1},
-    {"prefix_cache": True, "prefix_block": 4, "pipeline_depth": 2},
+    {"prefix_cache": True, "kv_block_tokens": 4, "pipeline_depth": 1},
+    {"prefix_cache": True, "kv_block_tokens": 4, "pipeline_depth": 2},
 ], ids=["plain_d1", "plain_d2", "prefix_d1", "prefix_d2"])
 def test_sharded_token_identity_matrix(nano_model, mode, features):
     """Every tp degree produces the SAME tokens as solo `generate`
@@ -116,7 +116,7 @@ def test_sharded_chunked_prefill_identity(nano_model):
     cfg, params = nano_model
     prompts = _prompts(4, cfg, seed=31, lo=6, hi=14)
     budgets = [5, 7, 4, 6]
-    kw = {"prefill_chunk": 3, "prefix_cache": True, "prefix_block": 4}
+    kw = {"prefill_chunk": 3, "prefix_cache": True, "kv_block_tokens": 4}
     ref, _ = _run(params, cfg, prompts, budgets, 1, eng_kw=kw)
     for tp in (2, 4):
         got, _ = _run(params, cfg, prompts, budgets, tp, eng_kw=kw)
@@ -143,8 +143,8 @@ def test_sharded_identity_under_eviction_pressure(nano_model):
         pref = rng.randint(1, cfg.vocab_size, size=8).tolist()
         prompts += [pref + [30 + i], pref + [40 + i]]
     budgets = [5] * 6
-    kw = {"prefix_cache": True, "prefix_block": 4,
-          "prefix_cache_bytes": 4 * bb, "pipeline_depth": 2}
+    kw = {"prefix_cache": True, "kv_block_tokens": 4,
+          "kv_pool_bytes": 4 * bb, "pipeline_depth": 2}
     ref, eng1 = _run(params, cfg, prompts, budgets, 1, eng_kw=kw)
     assert eng1.stats()["prefix_evictions"] > 0   # pressure was real
     for tp in (2, 4):
@@ -238,20 +238,22 @@ def test_mesh_knob_and_validation(nano_model, tp_mesh):
 
 
 def test_kv_rule_degrades_by_divisibility(nano_model):
-    """nano has n_kv_heads=2: tp=2 shards the KV cache's head axis;
+    """nano has n_kv_heads=2: tp=2 shards the KV pool's head lanes;
     tp=4 can't divide it, so KV replicates while heads (4) and vocab
     (256) still shard — prune_rules_for_mesh per-axis divisibility."""
     cfg, params = nano_model
     e2 = DecodeEngine(params, cfg, batch_slots=2, max_len=64, tp=2,
                       enable_metrics=False)
     assert e2._rules["kv"] == "tp"
-    assert e2.cache["k"].sharding.spec[3] == "tp"
+    assert e2._pool_k.sharding.spec[3] == "tp"
+    assert e2._pool_k.sharding.shard_shape(e2._pool_k.shape)[3] == \
+        cfg.head_dim                       # one KV head's lanes a chip
     e4 = DecodeEngine(params, cfg, batch_slots=2, max_len=64, tp=4,
                       enable_metrics=False)
     assert e4._rules["kv"] is None
     assert e4._rules["heads"] == "tp"
     assert e4._rules["vocab"] == "tp"
-    assert e4.cache["k"].sharding.spec[3] is None
+    assert e4._pool_k.sharding.spec[3] is None
     # Weights really shard: a head-axis param's per-chip slice shrinks.
     wq4 = e4.params["layers"]["wq"]
     assert wq4.sharding.shard_shape(wq4.shape)[2] == cfg.n_heads // 4
@@ -281,17 +283,19 @@ def test_tp_plane_reaches_stats_and_registry(nano_model):
         == s["host_transfer_bytes"]
 
 
-def test_microbench_sharded_dispatch_section_cpu_quick():
-    """The microbench sharded-dispatch section runs on CPU and shows
-    the choke-point invariant: host bytes/token is IDENTICAL at tp=1
-    and tp=4 (the [H, B] block is pinned replicated), and the sharded
-    engine still reports a positive wall/device split per step."""
-    import microbench
-
-    rows = {name: value for name, value, _unit
-            in microbench._sharded_dispatch_section(quick=True)}
-    assert rows["engine_sharded_host_bytes_per_token_tp1"] == \
-        rows["engine_sharded_host_bytes_per_token_tp4"]
-    for tp in (1, 4):
-        assert rows[f"engine_sharded_wall_ms_per_step_tp{tp}"] > 0.0
-        assert rows[f"engine_sharded_device_ms_per_step_tp{tp}"] > 0.0
+def test_sharded_int8_pool_identity(nano_model):
+    """A quantized pool under a mesh: the scale slabs shard by KV head
+    like the pages they scale, so tp=2 emits exactly what the
+    one-device int8 engine emits, prefix sharing and chunking on."""
+    cfg, params = nano_model
+    shared = list(range(3, 11))
+    a, b = (shared + p for p in _prompts(2, cfg, seed=43))
+    prompts = [a] + _prompts(2, cfg, seed=44) + [b]   # b admits warm
+    budgets = [6, 8, 5, 7]
+    kw = {"kv_quant": "int8", "kv_block_tokens": 4,
+          "prefix_cache": True, "prefill_chunk": 3}
+    ref, _ = _run(params, cfg, prompts, budgets, 1, eng_kw=kw)
+    got, eng = _run(params, cfg, prompts, budgets, 2, eng_kw=kw)
+    assert got == ref
+    assert eng._scale_k.sharding.spec[2] == "tp"
+    assert eng.stats()["kv_blocks_shared"] >= 2

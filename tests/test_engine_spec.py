@@ -39,7 +39,7 @@ def _solo(params, cfg, prompt, n, **kw):
 
 PROMPTS = [[5, 6, 7], [9, 8, 7, 6, 5], [1, 2], [3, 1, 4, 1, 5, 9]]
 BUDGETS = [4, 6, 3, 5]
-T = 4   # paged block tokens
+T = 4   # kv_block_tokens of the small-block cases
 
 
 def _pool_bytes(cfg, n_blocks):
@@ -51,16 +51,16 @@ def _pool_bytes(cfg, n_blocks):
 def _features(cfg):
     pb = lambda n: _pool_bytes(cfg, n)
     return {
-        "dense": {},
+        "blocks32": {},                 # the default: one block a row
         "pipeline": dict(pipeline_depth=2),
         "chunked": dict(prefill_chunk=2),
-        "prefix-dense": dict(prefix_cache=True, prefix_block=4),
-        "paged": dict(paged=True, kv_block_tokens=T,
-                      kv_pool_bytes=pb(40)),
-        "paged+prefix": dict(paged=True, kv_block_tokens=T,
-                             kv_pool_bytes=pb(40), prefix_cache=True),
-        "paged+pipeline": dict(paged=True, kv_block_tokens=T,
-                               kv_pool_bytes=pb(40), pipeline_depth=2),
+        "chunked+prefix": dict(prefill_chunk=2, prefix_cache=True,
+                               kv_block_tokens=T),
+        "blocks4": dict(kv_block_tokens=T, kv_pool_bytes=pb(40)),
+        "blocks4+prefix": dict(kv_block_tokens=T,
+                               kv_pool_bytes=pb(40), prefix_cache=True),
+        "blocks4+pipeline": dict(kv_block_tokens=T,
+                                 kv_pool_bytes=pb(40), pipeline_depth=2),
     }
 
 
@@ -68,9 +68,10 @@ def _features(cfg):
 # Token identity across the feature matrix
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("feature", ["dense", "pipeline", "chunked",
-                                     "prefix-dense", "paged",
-                                     "paged+prefix", "paged+pipeline"])
+@pytest.mark.parametrize("feature", ["blocks32", "pipeline", "chunked",
+                                     "chunked+prefix", "blocks4",
+                                     "blocks4+prefix",
+                                     "blocks4+pipeline"])
 def test_spec_identity_feature_matrix(nano_model, feature):
     """Independent nano draft (near-zero acceptance — the adversarial
     case for cache alignment): output must still match solo greedy
@@ -163,7 +164,7 @@ def test_spec_preempt(nano_model, preempt):
     holds and preemptions actually happened."""
     cfg, params, _ = nano_model
     eng = DecodeEngine(params, cfg, batch_slots=3, max_len=32,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 10),
                        preempt=preempt, draft_params=params,
                        draft_cfg=cfg, spec_window=4)

@@ -227,8 +227,8 @@ def test_engine_trace_reconstructs_lifecycle(nano_model):
     {"prefix_cache": True},
     {"prefix_cache": True, "pipeline_depth": 2},
     {"prefill_chunk": 3, "prefix_cache": True},
-    {"paged": True, "kv_block_tokens": 4, "prefix_cache": True},
-], ids=["prefix", "pipeline", "chunked", "paged"])
+    {"kv_block_tokens": 4, "prefix_cache": True},
+], ids=["prefix", "pipeline", "chunked", "blocks4"])
 def test_traced_engine_token_identity(nano_model, mode, features):
     """The gold contract survives tracing: outputs with the tracer ON
     are identical to solo generate across the feature matrix (the
@@ -268,7 +268,7 @@ def test_trace_preempt_swap_spans(nano_model):
                             cfg.head_dim,
                             jnp.dtype(cfg.dtype).itemsize)
     eng = DecodeEngine(params, cfg, batch_slots=4, max_len=32,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        kv_pool_bytes=pool, prefix_cache=False,
                        trace=True)
     prompts = [[7, 8, 9, 10, 11], [3, 1, 4, 1, 5],

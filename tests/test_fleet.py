@@ -75,7 +75,7 @@ def test_fleet_identity_matrix(nano_model, router, scenario, mode):
     cfg, params = nano_model
     kw = SAMPLING_MODES[mode]
     fleet = LLMFleet(
-        _factory(params, cfg, prefix_cache=True, prefix_block=4, **kw),
+        _factory(params, cfg, prefix_cache=True, kv_block_tokens=4, **kw),
         initial_replicas=2, router=router,
         fleet_id=f"id-{router}-{scenario}-{mode}")
     keys = [jax.random.PRNGKey(40 + i) for i in range(len(PROMPTS))]
@@ -210,7 +210,7 @@ def test_router_prefix_affinity_routes_warm(nano_model):
 
     def run(router):
         fleet = LLMFleet(
-            _factory(params, cfg, prefix_cache=True, prefix_block=4),
+            _factory(params, cfg, prefix_cache=True, kv_block_tokens=4),
             initial_replicas=2, router=router,
             fleet_id=f"affinity-{getattr(router, 'name', router)}")
         for i in range(6):
@@ -541,7 +541,7 @@ def test_fleet_soak_churn_identity(nano_model, fake_clock):
                                   upscale_hold_s=2.0,
                                   downscale_hold_s=4.0)
     fleet = LLMFleet(
-        _factory(params, cfg, prefix_cache=True, prefix_block=4,
+        _factory(params, cfg, prefix_cache=True, kv_block_tokens=4,
                  clock=fake_clock),
         initial_replicas=1, autoscaling=auto, fleet_id="soak",
         clock=fake_clock)

@@ -68,12 +68,12 @@ SAMPLING_MODES = {
 }
 
 ENGINE_COMBOS = {
-    "paged": {"paged": True, "kv_block_tokens": 4},
-    "paged_quant": {"paged": True, "kv_block_tokens": 4,
-                    "kv_quant": "int8"},
-    "dense": {},
-    "paged_prefix": {"paged": True, "kv_block_tokens": 4,
-                     "prefix_cache": True},
+    "blocks4": {"kv_block_tokens": 4},
+    "blocks4_quant": {"kv_block_tokens": 4,
+                      "kv_quant": "int8"},
+    "blocks32": {},
+    "blocks4_prefix": {"kv_block_tokens": 4,
+                       "prefix_cache": True},
     "pipeline": {"pipeline_depth": 3},
 }
 
@@ -127,9 +127,8 @@ def test_disagg_token_identity_matrix(nano_model, combo, mode):
     assert st["handoffs_out"] == st["handoffs_in"] == len(PROMPTS)
     assert st["handoff_parked"] == 0.0
     assert dis.tokens_lost_to_failure == 0
-    if eng_kw.get("paged"):
-        assert st["handoff_out_bytes"] > 0      # KV actually moved
-        assert st["handoff_in_bytes"] == st["handoff_out_bytes"]
+    assert st["handoff_out_bytes"] > 0          # KV actually moved
+    assert st["handoff_in_bytes"] == st["handoff_out_bytes"]
     _pools_empty(dis)
 
 
@@ -192,7 +191,7 @@ def test_handoff_parks_when_decode_wont_import(nano_model):
 
     cfg, params = nano_model
     dis = LLMFleet(_factory(params, cfg,
-                            paged=True, kv_block_tokens=4),
+                            kv_block_tokens=4),
                    rng_seed=5, disaggregated=True, fleet_id="dis-park",
                    prefill_replicas=1, decode_replicas=1)
     dec = next(r for r in dis.replicas if r.replica_class == "decode")
@@ -244,7 +243,7 @@ def test_mid_handoff_decode_death_is_gapless(nano_model, kill_step,
     with ``tokens_lost_to_failure == 0`` and every block-pool ledger
     back at baseline."""
     cfg, params = nano_model
-    eng_kw = dict(SAMPLING_MODES[mode], paged=True, kv_block_tokens=4)
+    eng_kw = dict(SAMPLING_MODES[mode], kv_block_tokens=4)
     prompts, budgets = PROMPTS[:4], BUDGETS[:4]
 
     ref_fleet = LLMFleet(_factory(params, cfg, **eng_kw), rng_seed=11,
@@ -452,7 +451,7 @@ def test_state_api_handoff_status_and_replica_class(nano_model):
 
     cfg, params = nano_model
     dis = LLMFleet(_factory(params, cfg,
-                            paged=True, kv_block_tokens=4),
+                            kv_block_tokens=4),
                    rng_seed=9, disaggregated=True, fleet_id="dis-api",
                    prefill_replicas=1, decode_replicas=1)
     pre = next(r for r in dis.replicas
@@ -496,10 +495,10 @@ def test_scheduler_queued_state_carries_handoff_flag(nano_model):
     request object); ordinary queued requests read False."""
     cfg, params = nano_model
     pre = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       paged=True, kv_block_tokens=4, engine_id="pre")
+                       kv_block_tokens=4, engine_id="pre")
     pre.prefill_only = True
     dec = DecodeEngine(params, cfg, batch_slots=1, max_len=32,
-                       paged=True, kv_block_tokens=4, engine_id="dec")
+                       kv_block_tokens=4, engine_id="dec")
     rids = [pre.submit(p, 4) for p in PROMPTS[:3]]
     for _ in range(30):
         pre.step()
@@ -551,10 +550,10 @@ def test_sanitizer_clean_on_handoff_path(nano_model):
         return [out[r] for r in moved]
 
     pre = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       paged=True, kv_block_tokens=4, engine_id="sp")
+                       kv_block_tokens=4, engine_id="sp")
     pre.prefill_only = True
     dec = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       paged=True, kv_block_tokens=4, engine_id="sd")
+                       kv_block_tokens=4, engine_id="sd")
     handoff_workload(pre, dec)          # cold compiles
     handoff_workload(pre, dec)          # warm-hit paths
     san = pre.arm_sanitizer()

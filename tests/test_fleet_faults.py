@@ -559,8 +559,8 @@ def test_trace_report_failover_summary(nano_model, tmp_path):
 # ---------------------------------------------------------------------------
 
 ENGINE_CONFIGS = {
-    "prefix": {"prefix_cache": True, "prefix_block": 4},
-    "paged": {"paged": True, "kv_block_tokens": 4},
+    "prefix": {"prefix_cache": True, "kv_block_tokens": 4},
+    "blocks4": {"kv_block_tokens": 4},
     "pipeline": {"pipeline_depth": 2},
 }
 

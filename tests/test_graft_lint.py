@@ -422,7 +422,7 @@ def test_sharding_pin_gated_on_sharding_machinery():
     src = """
         class Engine:
             def swap(self, row):
-                self.cache = self.host_cache[row]
+                self._pool_k = self.host_pool[row]
     """
     # force_hot opts the snippet in even without `_shardings` in source
     assert len(_open(_lint(src), "sharding-pin")) == 1

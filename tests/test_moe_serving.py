@@ -232,8 +232,7 @@ def test_sorted_regime_through_the_engine():
     params = moe_init(jax.random.PRNGKey(11), cfg)
     rng = np.random.RandomState(0)
     prompts = [rng.randint(1, 256, size=150).tolist() for _ in range(4)]
-    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=256, paged=True,
-                       kv_block_tokens=32, decode_horizon=1)
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=256, kv_block_tokens=32, decode_horizon=1)
     ids = [eng.submit(p, 1) for p in prompts]
     out = eng.run()
     for rid, p in zip(ids, prompts):
@@ -251,8 +250,7 @@ def test_sorted_regime_through_the_engine():
 def _lowered_dense_programs():
     cfg = LlamaConfig.nano()
     params = llama_init(jax.random.PRNGKey(0), cfg)
-    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32, paged=True,
-                       kv_block_tokens=4)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32, kv_block_tokens=4)
     B = eng.B
     z = jnp.zeros((B,), jnp.int32)
     decode = engine_mod._decode_multi_paged.lower(

@@ -161,12 +161,12 @@ def test_gate_actor_storm(gate_cluster):
 
 
 def test_gate_warm_admission_zero_copy_bytes():
-    """Gate (r8, paged KV): a warm prefix admission on the paged
-    engine moves ZERO device->device KV bytes — shared blocks are
-    increfed into the new row's block table, never gathered. Counting,
-    not timing, so it holds on any box: the gate fails if a future
-    change reintroduces a copy-in program (or any CoW block) on a
-    non-aligned warm admission."""
+    """Gate (r8, paged KV): a warm prefix admission moves ZERO
+    device->device KV bytes — shared blocks are increfed into the new
+    row's block table, never gathered. Counting, not timing, so it
+    holds on any box: the gate fails if a future change dispatches
+    any program for a warm admission but its suffix's prefill (or pays
+    a CoW block on a non-aligned one)."""
     jax = pytest.importorskip("jax")
     from ray_tpu.models import LlamaConfig, llama_init
     from ray_tpu.models.engine import DecodeEngine
@@ -175,7 +175,7 @@ def test_gate_warm_admission_zero_copy_bytes():
     params = llama_init(jax.random.PRNGKey(0), cfg)
     sys_p = list(range(1, 17))       # 4 full blocks at T=4
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       paged=True, kv_block_tokens=4,
+                       kv_block_tokens=4,
                        prefix_cache=True)
     eng.submit(sys_p + [50, 51], 4)  # cold: commits the chain
     eng.run()
@@ -186,10 +186,8 @@ def test_gate_warm_admission_zero_copy_bytes():
     s1 = eng.stats()
     assert s1["prefix_hits"] - s0["prefix_hits"] == 3
     assert s1["kv_blocks_shared"] - s0["kv_blocks_shared"] == 12
-    copies = s1["prefix_copy_dispatches"] - s0["prefix_copy_dispatches"]
-    assert copies == 0, (
-        f"warm admission dispatched {copies} KV copy program(s); "
-        "paged prefix hits must be zero-copy block shares")
+    assert s1["prefill_real_tokens"] - s0["prefill_real_tokens"] == 6, \
+        "a warm admission prefills its 2-token suffix and nothing else"
     assert s1["kv_block_cows"] == s0["kv_block_cows"], \
         "non-aligned warm admissions must not pay copy-on-write"
 
@@ -208,7 +206,7 @@ def test_gate_warm_admission_zero_copy_bytes_quant():
     params = llama_init(jax.random.PRNGKey(0), cfg)
     sys_p = list(range(1, 17))       # 4 full blocks at T=4
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
-                       paged=True, kv_block_tokens=4,
+                       kv_block_tokens=4,
                        prefix_cache=True, kv_quant="int8")
     eng.submit(sys_p + [50, 51], 4)  # cold: commits the chain
     eng.run()
@@ -219,10 +217,8 @@ def test_gate_warm_admission_zero_copy_bytes_quant():
     s1 = eng.stats()
     assert s1["prefix_hits"] - s0["prefix_hits"] == 3
     assert s1["kv_blocks_shared"] - s0["kv_blocks_shared"] == 12
-    copies = s1["prefix_copy_dispatches"] - s0["prefix_copy_dispatches"]
-    assert copies == 0, (
-        f"warm quantized admission dispatched {copies} KV copy "
-        "program(s); paged prefix hits must be zero-copy block shares")
+    assert s1["prefill_real_tokens"] - s0["prefill_real_tokens"] == 6, \
+        "a warm admission prefills its 2-token suffix and nothing else"
     assert s1["kv_block_cows"] == s0["kv_block_cows"], \
         "non-aligned warm admissions must not pay copy-on-write"
 
@@ -347,7 +343,7 @@ def test_gate_state_snapshot_bounded_allocations():
     cfg = LlamaConfig.nano()
     params = llama_init(jax.random.PRNGKey(0), cfg)
     eng = DecodeEngine(params, cfg, batch_slots=4, max_len=32,
-                       prefix_cache=True, prefix_block=4)
+                       prefix_cache=True, kv_block_tokens=4)
     for i in range(8):
         eng.submit([5 + i, 6, 7, 8 + i], 16)
     eng.step()                       # genuinely busy: queue + slots
@@ -488,30 +484,29 @@ def test_gate_spec_host_syncs_quartered():
 # they need no quiesce and hold on any box. Contract: after a warmup
 # that exercises the exact steady workload (two full passes — pass 1
 # compiles the cold paths, pass 2 compiles warm-hit paths like the
-# prefix-cache copy-in), an armed pass over the same workload must (a)
+# prefix cache's suffix-only prefill), an armed pass over the same workload must (a)
 # never grow a fused entry point's compile cache, (b) never pull
 # device->host outside the _device_get/_host_async choke points, and
 # (c) still emit token streams identical to solo `generate`.
 
 SANITIZER_COMBOS = {
-    "dense": {},
+    "plain": {},                    # blocks of 32: two a row
     "prefix": {"prefix_cache": True},
-    "paged": {"paged": True},
-    "paged_prefix": {"paged": True, "prefix_cache": True},
+    "blocks4": {"kv_block_tokens": 4},
+    "chunked_prefix": {"prefix_cache": True, "kv_block_tokens": 4,
+                       "prefill_chunk": 3},
     "pipeline": {"pipeline_depth": 3},
     "spec": {"spec": True},
-    "spec_paged": {"spec": True, "paged": True},
+    "spec_blocks4": {"spec": True, "kv_block_tokens": 4},
     "tp": {"tp": 2},
-    # Quantized-KV twins of the paged combos: the int8 pool + scale
-    # slab must introduce no retraces and no stray pulls either. Token
-    # streams under quant are tolerance-gated (test_engine_kv_quant),
-    # not solo-identical, so the identity assert softens to
-    # budget-shape only for these.
-    "paged_quant": {"paged": True, "kv_quant": "int8"},
-    "paged_prefix_quant": {"paged": True, "prefix_cache": True,
-                           "kv_quant": "int8"},
-    "spec_paged_quant": {"spec": True, "paged": True,
-                         "kv_quant": "int8"},
+    # Quantized-KV twins: the int8 pool + scale slab must introduce no
+    # retraces and no stray pulls either. Token streams under quant are
+    # tolerance-gated (test_engine_kv_quant), not solo-identical, so
+    # the identity assert softens to budget-shape only for these.
+    "quant": {"kv_quant": "int8"},
+    "prefix_quant": {"prefix_cache": True, "kv_quant": "int8"},
+    "spec_quant": {"spec": True, "kv_quant": "int8"},
+    "tp_quant": {"tp": 2, "kv_quant": "int8", "kv_block_tokens": 4},
 }
 
 _SAN_PROMPTS = [[5, 6, 7], [9, 8, 7, 6, 5]]

@@ -74,8 +74,7 @@ def _assert_agrees_with_engine(eng):
     assert c.get("prefilling", 0) == len(eng._row_prefill)
     assert c.get("prefilling", 0) + c.get("decoding", 0) == \
         s["live_slots"]
-    if eng.paged:
-        assert c.get("swapped", 0) == len(eng._swapped)
+    assert c.get("swapped", 0) == len(eng._swapped)
     # No request appears twice, and every row names this engine.
     ids = [r["req_id"] for r in rows]
     assert len(ids) == len(set(ids))
@@ -89,14 +88,14 @@ def _assert_agrees_with_engine(eng):
 
 @pytest.mark.parametrize("features", [
     {},
-    {"prefix_cache": True, "prefix_block": T},
-    {"prefill_chunk": 3, "prefix_cache": True, "prefix_block": T},
-    {"prefix_cache": True, "prefix_block": T, "pipeline_depth": 3},
-    {"paged": True, "kv_block_tokens": T},
-    {"paged": True, "kv_block_tokens": T, "prefill_chunk": 3,
+    {"prefix_cache": True, "kv_block_tokens": T},
+    {"prefill_chunk": 3, "prefix_cache": True, "kv_block_tokens": T},
+    {"prefix_cache": True, "kv_block_tokens": T, "pipeline_depth": 3},
+    {"kv_block_tokens": T, "tight_pool": True},
+    {"kv_block_tokens": T, "tight_pool": True, "prefill_chunk": 3,
      "pipeline_depth": 2},
-], ids=["plain", "prefix", "chunked", "pipeline", "paged",
-        "paged_chunked_pipeline"])
+], ids=["plain", "prefix", "chunked", "pipeline", "tight_pool",
+        "tight_chunked_pipeline"])
 def test_list_requests_identity_matrix(nano_model, features):
     """At EVERY engine step of a run that churns 6 requests through 2
     slots, the state API's phase counts equal the engine's own
@@ -104,7 +103,7 @@ def test_list_requests_identity_matrix(nano_model, features):
     stream (output matches an unobserved run)."""
     cfg, params = nano_model
     kw = dict(features)
-    if kw.get("paged"):
+    if kw.pop("tight_pool", False):
         kw["kv_pool_bytes"] = _pool_bytes(cfg, 16)
     prompts = _prompts(6, cfg)
     budgets = [4, 6, 3, 5, 2, 4]
@@ -133,7 +132,7 @@ def test_swapped_requests_surface_in_state(nano_model):
     prompts = [[7, 8, 9, 10, 11], [3, 1, 4, 1, 5],
                [2, 7, 1, 8, 2], [9, 9, 8, 8, 7]]
     eng = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 10),
                        prefix_cache=False)
     for p in prompts:
@@ -213,7 +212,7 @@ def test_draining_filter_spans_phases(nano_model):
 def test_engine_state_row_and_kv_pools(nano_model):
     cfg, params = nano_model
     eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
-                       paged=True, kv_block_tokens=T,
+                       kv_block_tokens=T,
                        kv_pool_bytes=_pool_bytes(cfg, 16),
                        engine_id="rowcheck")
     for p in _prompts(3, cfg):
@@ -230,14 +229,13 @@ def test_engine_state_row_and_kv_pools(nano_model):
     assert row["slot_occupancy"] == pytest.approx(s["slot_occupancy"])
     assert row["kv_used_fraction"] == pytest.approx(
         eng.kv_used_fraction())
-    assert row["paged"] is True and row["draining"] is False
+    assert row["draining"] is False
     assert row["fleet"] is None and row["replica"] is None
     assert row["uptime_s"] >= 0.0 and row["steps_total"] >= 1
 
     pool, = [p for p in serving.list_kv_pools()
              if p["engine_id"] == "rowcheck"]
-    assert pool["kind"] == "paged"
-    assert pool["blocks_total"] == 16
+    assert pool["block_tokens"] == T and pool["blocks_total"] == 16
     assert pool["blocks_in_use"] == eng.kv_pool.blocks_in_use
     assert 0.0 < pool["occupancy"] <= 1.0
     eng.run()
@@ -448,7 +446,7 @@ def test_status_cli_renders_live_fleet(nano_model):
     def factory(name):
         return DecodeEngine(params, cfg, engine_id=name, batch_slots=2,
                             max_len=MAX_LEN, prefix_cache=True,
-                            prefix_block=T)
+                            kv_block_tokens=T)
 
     fleet = LLMFleet(factory, initial_replicas=2, router="round_robin",
                      fleet_id="clifleet")

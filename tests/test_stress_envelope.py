@@ -57,6 +57,14 @@ def multi_cluster():
     c.shutdown()
 
 
+# Also `slow`: the driver's tier-1 command passes -m 'not slow', which
+# REPLACES pytest.ini's "not slow and not stress", so `stress` alone ran
+# this beside five other xdist workers, where 2,000 worker processes in
+# waves of 250 starve and the test failed in every driver run the
+# ledger holds (rcs [1], PR 21-27). It is load, not logic: it passes
+# alone (`-m stress`). The rest of the stress tier passes there and
+# stays in tier-1.
+@pytest.mark.slow
 def test_2000_actors_multi_raylet(multi_cluster):
     from ray_tpu._private.worker import global_worker
 

@@ -174,8 +174,7 @@ def format_status(data: Dict[str, Any], top: int = 5) -> str:
              # Replica class column (disaggregated fleets): colocated
              # replicas stay untagged so mixed pools read cleanly.
              f" class={klass}" if klass else "",
-             f" tp={e['tp_degree']}" if e["tp_degree"] > 1 else "",
-             " paged" if e["paged"] else ""])
+             f" tp={e['tp_degree']}" if e["tp_degree"] > 1 else ""])
         lines.append(
             f"{e['engine_id']:>16} "
             f"occ {_bar(e['slot_occupancy'], 10)} "
