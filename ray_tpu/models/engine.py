@@ -93,18 +93,14 @@ from ray_tpu.models.block_pool import BlockPool
 from ray_tpu.models.engine_metrics import EngineMetrics, NullEngineMetrics
 from ray_tpu.models.engine_trace import resolve_tracer
 from ray_tpu.models.generate import (_check_sampling_knobs,
-                                     _layer_body, forward_cached_rows,
-                                     sample_rows)
+                                     _layer_body, sample_rows)
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
 from ray_tpu.models.moe import MoeConfig
 from ray_tpu.models.prefix_cache import PrefixCacheIndex, block_bytes
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention, spmd_mesh_scope
-from ray_tpu.ops.kv_quant import (KVQuantSpec, block_scale as
-                                  _kv_block_scale, dequantize as
-                                  _kv_dequantize, paged_quant_write,
-                                  quantize as _kv_quantize,
+from ray_tpu.ops.kv_quant import (KVQuantSpec, paged_quant_write,
                                   resolve_kv_quant)
 from ray_tpu.models.scheduler import (EngineDraining, EngineOverloaded,
                                       FIFOPolicy, SchedulerPolicy,
@@ -239,28 +235,18 @@ def _append_moe_ctr(toks, moe_ctr):
                                 (_MOE_CTR_ROWS, toks.shape[1]))])
 
 
-def _forward_rows_counted(params, prompts, row_cache, starts, cfg,
-                          adapters, row_slot, moe_ctr, rows, last_idx):
-    """`forward_cached_rows` for the prefill programs, adding the chunk's
-    expert-layer counts to ``moe_ctr`` when the engine carries one. Live
-    are positions up to each row's last real token; a group's padding
-    rows repeat the last admission verbatim and count once."""
-    if moe_ctr is None:
-        logits, row_cache = forward_cached_rows(
-            params, prompts, row_cache, starts, cfg, adapters=adapters,
-            row_slot=row_slot)
-        return logits, row_cache, None
-    n, s = prompts.shape
+def _prefill_live(rows, last_idx, chunk: int):
+    """[N, chunk] bool: the positions of a prefill group an `MoeConfig`
+    engine's expert-layer counters count. Live are positions up to each
+    row's last real token; a group's padding rows repeat the last
+    admission verbatim and count once."""
+    n = rows.shape[0]
     with jax.named_scope(sn.MOE_ROUTER):
         earlier = jnp.arange(n)[None, :] < jnp.arange(n)[:, None]
         repeat = jnp.any((rows[:, None] == rows[None, :]) & earlier,
                          axis=1)
-        live = (jnp.arange(s)[None, :] <= last_idx[:, None]) \
+        return (jnp.arange(chunk)[None, :] <= last_idx[:, None]) \
             & ~repeat[:, None]
-    logits, row_cache, st = forward_cached_rows(
-        params, prompts, row_cache, starts, cfg, adapters=adapters,
-        row_slot=row_slot, moe_live=live)
-    return logits, row_cache, moe_ctr.at[:2].add(st[:2])
 
 
 def _spec_accept(chunk, proposals, ver, v_logits, last_logits, row_len,
@@ -321,19 +307,23 @@ def _spec_accept(chunk, proposals, ver, v_logits, last_logits, row_len,
 # The engine has NO per-slot cache: every request's K/V lives in
 # fixed-size token blocks of ONE device pool [L, NB, T, KV*D] (the same
 # pool the prefix cache commits into; a token's KV heads merged
-# head-major into one lane axis, the layout the decode kernel reads
+# head-major into one lane axis, the layout the paged kernel reads
 # pages in) and each program reaches it through the per-row block table
 # bt [B, MB]. Every program takes the pool donated and updates it in
-# place; none holds a second copy of it. MB * T == max_len is enforced
-# at construction, so a row's gathered view has EXACTLY the shape of the
-# cache solo `generate` keeps for it, and prefill is `generate`'s own
-# `forward_cached_rows` evaluated on that view — which is what makes
-# engine output bit-identical to solo `generate`
-# (tests/test_engine_paged.py). Block id 0 is the reserved null block:
-# unallocated table entries point at it, padded gathers/scatters dump
-# garbage into it, and no mask ever admits it. (The `_paged` suffix of
-# the jitted names dates from when a dense twin existed; the
-# benchmark's trace readers match it.)
+# place; none holds a second copy of it, and none builds a dense per-row
+# view of it: prefill, decode, draft and verify are ONE layer core
+# (`_layers_paged`), S tokens a row wide, that scatters a chunk's K/V
+# into the blocks its slots fall in and attends through the table
+# (`ops.attention.paged_attention`). MB * T == max_len is enforced at
+# construction, so off the chip, where `paged_attention` gathers a
+# layer's rows and runs `generate._cached_attention`'s exact op
+# sequence on them, a row attends EXACTLY the cache solo `generate`
+# keeps for it — which is what makes engine output bit-identical to
+# solo `generate` (tests/test_engine_paged.py). Block id 0 is the
+# reserved null block: unallocated table entries point at it, padded
+# scatters dump garbage into it, and no mask ever admits it. (The
+# `_paged` suffix of the jitted names dates from when a dense twin
+# existed; the benchmark's trace readers match it.)
 
 
 def _gather_pages(pools, ids):
@@ -363,7 +353,7 @@ def _gather_pages(pools, ids):
 
     _, outs = jax.lax.while_loop(
         lambda carry: carry[0] < n, body,
-        (jnp.int32(0), tuple(jnp.zeros((L, n, T, W), pool.dtype)
+        (np.int32(0), tuple(jnp.zeros((L, n, T, W), pool.dtype)
                              for pool in pools)))
     return tuple(out.reshape(L, *ids.shape, T, W) for out in outs)
 
@@ -426,102 +416,59 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
     """Batched admission/continuation prefill: N same-bucket chunks
     [N, Cb] in ONE program, each row at its OWN offset ``starts[n]`` (0
     for a cold admission; the shared prefix length for a warm one; the
-    chunk frontier for a chunked continuation). Gathers each row's full
-    [max_len] view through its block table, runs solo `generate`'s
-    `forward_cached_rows` on it, scatters the view back block-by-block,
-    and scatters each row's last-real-token logits into the engine's
-    device-resident `last_logits` [B, vocab]. No logits ever cross to
-    the host: the fused decode samples the first token on device, so an
-    admission costs zero host round-trips.
+    chunk frontier for a chunked continuation). It is `_layers_paged`,
+    the decode program's layer core, Cb tokens wide over the N
+    admission rows: every layer scatters the chunk's K/V into the
+    blocks its slots ``starts[n] + arange(Cb)`` fall in, in place in
+    the donated pool, and attends through the block table. Nothing is
+    gathered out of the pool and no block below ``starts`` is written:
+    shared prefix blocks are only ever read. Each row's last-real-token
+    hidden state goes through the final norm and `lm_head` alone and
+    its logits are scattered into the engine's device-resident
+    `last_logits` [B, vocab]. No logits ever cross to the host: the
+    fused decode samples the first token on device, so an admission
+    costs zero host round-trips.
 
     Cb may exceed a chunk's true length (length-bucketed serving):
-    trailing filler tokens' K/V land at slots >= the true frontier,
-    which every later mask excludes and the next write overwrites —
-    only the logits at `last_idx` (true chunk length - 1) are read out,
-    and only the FINAL chunk's scatter survives in `last_logits`.
-    `rows` may contain duplicates (power-of-two group padding repeats
-    the last admission verbatim): duplicate scatters write identical
-    values.
+    trailing filler tokens' K/V land at slots >= the true frontier (or,
+    where the row's chain ends, in the null block), which every later
+    mask excludes and the next write overwrites — only the position
+    `last_idx` (true chunk length - 1) is read out, and only the FINAL
+    chunk's scatter survives in `last_logits`. `rows` may contain
+    duplicates (power-of-two group padding repeats the last admission
+    verbatim): duplicate scatters write identical values.
 
     ``adapters``/``row_slot`` (the LoRA pool stacks + this chunk's slot
     lane [N]) thread to `_layer_body`'s per-row deltas; ``moe_ctr`` is
     an `MoeConfig` engine's expert-layer counters (`_moe_count`). Both
     default to None, which adds no pytree leaves: the same program.
 
-    The whole-view write-back is safe by construction: each row only
-    MODIFIES view slots [start, start+S) (its own private suffix
-    blocks — shared prefix blocks sit strictly below `start`, so they
-    are rewritten with the unmodified gathered bytes), duplicate
-    block-table entries across rows are either shared blocks (same
-    bytes) or the null block (garbage nobody reads), and duplicate
-    padded rows repeat the last admission verbatim.
-
     Quantized pools (``qspec`` + the f32 ``scale_k``/``scale_v`` slabs)
-    run the identical math on the DEQUANTIZED gathered view — kept f32
-    end to end — then requantize the whole view on write-back with
-    per-block scales recomputed over each row's valid slots (slots at or
-    beyond ``starts + last_idx + 1`` are zeroed first so bucket-padding
-    filler and stale previous-tenant garbage never poison a block's
-    absmax). Shared prefix blocks survive this byte-identically:
-    requantization of an unmodified dequantized block is byte-stable
-    (see ops/kv_quant.py), which is what keeps zero-copy shares safe
-    under the whole-view write-back."""
-    with jax.named_scope(sn.KV_GATHER):
-        # [L, N, MB, T, KV*D]
-        blk_k, blk_v = _gather_pages((pool_k, pool_v), bt)
-    if shardings is not None:
-        # The gathered view carries the pool's KV-head sharding: each
-        # chip gathers ONLY its heads' lanes, no cross-chip block traffic.
-        sp = shardings.pool.spec           # (l, nb, t, kv*d)
-        blk_spec = NamedSharding(
-            shardings.pool.mesh, P(sp[0], None, sp[1], sp[2], sp[3]))
-        blk_k = jax.lax.with_sharding_constraint(blk_k, blk_spec)
-        blk_v = jax.lax.with_sharding_constraint(blk_v, blk_spec)
-    L, N, MB, T = blk_k.shape[:4]
-    KV, D = cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope(sn.KV_GATHER):
-        # The pool keeps a token's KV heads merged into one lane axis;
-        # the gathered VIEW, never the pool, is split for the forward.
-        blk_k = blk_k.reshape(L, N, MB, T, KV, D)
-        blk_v = blk_v.reshape(L, N, MB, T, KV, D)
-        if qspec is not None:
-            blk_k = _kv_dequantize(
-                blk_k, scale_k[:, bt][:, :, :, None, :, None])
-            blk_v = _kv_dequantize(
-                blk_v, scale_v[:, bt][:, :, :, None, :, None])
-        row_cache = {"k": blk_k.reshape(L, N, MB * T, KV, D),
-                     "v": blk_v.reshape(L, N, MB * T, KV, D)}
-    logits, row_cache, moe_ctr = _forward_rows_counted(
-        params, prompts, row_cache, starts, cfg, adapters, row_slot,
-        moe_ctr, rows, last_idx)
-    with jax.named_scope(sn.KV_WRITE):
-        if qspec is None:
-            pool_k = pool_k.at[:, bt].set(row_cache["k"].reshape(
-                L, N, MB, T, KV * D).astype(pool_k.dtype))
-            pool_v = pool_v.at[:, bt].set(row_cache["v"].reshape(
-                L, N, MB, T, KV * D).astype(pool_v.dtype))
-        else:
-            k = row_cache["k"].reshape(L, N, MB, T, KV, D)
-            v = row_cache["v"].reshape(L, N, MB, T, KV, D)
-            valid = starts + last_idx + 1                       # [N]
-            live = (jnp.arange(MB * T)[None, :] < valid[:, None]) \
-                .reshape(1, N, MB, T, 1, 1)
-
-            def _writeback(pool, scales, x):
-                x = jnp.where(live, x.astype(jnp.float32), 0.0)
-                amax = jnp.max(jnp.abs(x), axis=(3, 5))     # [L,N,MB,KV]
-                s = _kv_block_scale(amax, qspec)
-                pool = pool.at[:, bt].set(
-                    _kv_quantize(x, s[:, :, :, None, :, None], qspec)
-                    .reshape(L, N, MB, T, KV * D))
-                return pool, scales.at[:, bt].set(s)
-
-            pool_k, scale_k = _writeback(pool_k, scale_k, k)
-            pool_v, scale_v = _writeback(pool_v, scale_v, v)
-    n = prompts.shape[0]
+    write through `paged_quant_write`, as decode and verify do: the
+    blocks the chunk touches are read, the chunk's REAL tokens laid in
+    (filler past `last_idx` is left out and every slot at or beyond the
+    true frontier zeroed, so neither filler nor a previous tenant's
+    garbage reaches a block's absmax), requantized and written back.
+    The chunk attends ITSELF as computed and what lies below it as the
+    pool stores it (`paged_attention`'s ``own_kv``), which is what it
+    saw in the dense view this program used to build: rounding reaches
+    a token only through what it reads back from the pool. That takes
+    the pure-lax lowering on the chip too (one layer's rows at a time);
+    the kernel has no operand for the chunk's own K/V (ROADMAP S11)."""
+    n, s = prompts.shape
+    h, pool_k, pool_v, scale_k, scale_v, moe_stats = _layers_paged(
+        params, prompts, pool_k, pool_v, bt, starts, cfg,
+        adapters=adapters, row_slot=row_slot, scale_k=scale_k,
+        scale_v=scale_v, qspec=qspec,
+        moe_live=None if moe_ctr is None
+        else _prefill_live(rows, last_idx, s),
+        n_valid=last_idx + 1)
+    if moe_ctr is not None:
+        moe_ctr = moe_ctr.at[:2].add(moe_stats.sum(axis=0)[:2])
+    # the final norm and lm_head see the ONE position a row is read at
+    last = _lm_head(params, h[jnp.arange(n), last_idx][:, None], cfg)
     with jax.named_scope(sn.LM_HEAD):
-        last = logits[jnp.arange(n), last_idx]          # [N, vocab]
-        out_logits = last_logits.at[rows].set(last)
+        out_logits = last_logits.at[rows].set(last[:, 0])
     pool_k, pool_v, scale_k, scale_v = _pin_pools(
         shardings, pool_k, pool_v, scale_k, scale_v)
     if shardings is not None:
@@ -534,10 +481,11 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
                              cfg: LlamaConfig, lora=None,
                              lora_slots=None,
                              qspec: Optional[KVQuantSpec] = None,
-                             moe_live=None):
+                             moe_live=None, n_valid=None):
     """One decoder layer against the pool, S tokens a row (1 in the
-    fused decode, the window in a speculative round), each row writing
-    at its own slots and attending its own prefix. All the per-layer
+    fused decode, the window in a speculative round, a chunk in
+    prefill), each row writing at its own slots and attending its own
+    prefix. All the per-layer
     math lives in generate.py's `_layer_body` (one source of truth with
     solo `generate`); only the cache write and the attention read
     differ. Row b's new K/V scatter into layer ``li`` of the WHOLE
@@ -559,10 +507,10 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
     ``kc``/``vc`` are (pool ``[L, NB, T, KV*D]``, scales ``[L, NB, KV]``
     or None) pairs — `_layer_body` only ever touches them through the
     closures below. A quantized pool's write is `paged_quant_write`'s
-    read-modify-write of the ONE frontier block per row (gather +
-    dequant + token write + stale-slot zero + requant; its static
-    window-block loop handles windows straddling block boundaries),
-    and `paged_attention` gets the scales so dequant happens inside its
+    read-modify-write of the blocks the window touches (gather +
+    dequant + token write + stale-slot zero + requant; ``n_valid`` [B],
+    prefill's, says how many of the S tokens are real), and
+    `paged_attention` gets the scales so dequant happens inside its
     gather."""
     B, S = slots.shape
     T = kc[0].shape[2]
@@ -582,32 +530,43 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
         def write_kv(kc, vc, k, v):
             return tuple(
                 paged_quant_write(pool, scales, li, bt, slots[:, 0], x,
-                                  qspec)
+                                  qspec, n_valid=n_valid)
                 for (pool, scales), x in ((kc, k), (vc, v)))
 
-    def attend(q, kc, vc):
-        return paged_attention(q, kc[0], vc[0], bt, slots, layer=li,
+    # a chunk's bucket filler queries nothing: its result is never read
+    q_slots = slots if n_valid is None else jnp.where(
+        jnp.arange(S)[None, :] < n_valid[:, None], slots, -1)
+
+    def attend(q, k, v, kc, vc):
+        # A quantized pool's CHUNK attends itself as computed and only
+        # what lies below it as stored, as it did through the dense view
+        # (a cold prompt's first token is the dense-precision engine's);
+        # a decode token or a window attends itself as stored.
+        own = (k, v) if qspec is not None and n_valid is not None else None
+        return paged_attention(q, kc[0], vc[0], bt, q_slots, layer=li,
                                kv_valid_len=span, k_scale=kc[1],
-                               v_scale=vc[1])
+                               v_scale=vc[1], own_kv=own)
 
     return _layer_body(h, layer, kc, vc, slots, write_kv, slots, span,
                        cfg, attend=attend, lora=lora,
                        lora_slots=lora_slots, moe_live=moe_live)
 
 
-def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
-                       bt, starts, cfg: LlamaConfig, adapters=None,
-                       row_slot=None, scale_k=None, scale_v=None,
-                       qspec: Optional[KVQuantSpec] = None,
-                       moe_live=None):
-    """One step for ALL slots: feed each row's [S] chunk at slots
-    ``starts + arange(S)``, attending slots up to its own, and return
-    the [B, S, vocab] f32 logits (plus the pool and the expert layers'
-    per-layer counts [L, 3] or None: see `_moe_count`). The fused decode
-    (S = 1), the draft consume/scan steps and the target verify pass
-    are all this one shape family. Dead/frozen rows compute discarded
-    garbage at their frontier slot — one past their real tokens, or the
-    null block for empty rows — which every mask excludes and the next
+def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
+                  bt, starts, cfg: LlamaConfig, adapters=None,
+                  row_slot=None, scale_k=None, scale_v=None,
+                  qspec: Optional[KVQuantSpec] = None,
+                  moe_live=None, n_valid=None):
+    """The layer stack for ALL rows of ``toks`` [B, S]: feed each row's
+    chunk at slots ``starts + arange(S)``, attending slots up to its
+    own, and return the hidden states [B, S, d] ahead of the final norm
+    (plus the pool and the expert layers' per-layer counts [L, 3] or
+    None: see `_moe_count`). The fused decode (S = 1), the draft
+    consume/scan steps, the target verify pass (S = the window) and
+    prefill (S = the chunk's bucket, B = the admission group) are all
+    this one shape family. Dead/frozen rows compute discarded garbage
+    at their frontier slot — one past their real tokens, or the null
+    block for empty rows — which every mask excludes and the next
     occupant's prefill overwrites.
 
     The layer scan takes ``(layer weights, layer index)`` as ``xs`` and
@@ -627,7 +586,8 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
         h, kc, vc, st = _decode_layer_rows_paged(
             h, layer, li, kc, vc, bt, slots, cfg,
             lora=xs[2] if adapters is not None else None,
-            lora_slots=row_slot, qspec=qspec, moe_live=moe_live)
+            lora_slots=row_slot, qspec=qspec, moe_live=moe_live,
+            n_valid=n_valid)
         return (h, kc, vc), st
 
     xs = (params["layers"], jnp.arange(pool_k.shape[0]))
@@ -635,12 +595,26 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
         xs = xs + (adapters,)
     (h, (pool_k, scale_k), (pool_v, scale_v)), moe_stats = jax.lax.scan(
         body, (h, (pool_k, scale_k), (pool_v, scale_v)), xs)
+    return h, pool_k, pool_v, scale_k, scale_v, moe_stats
+
+
+def _lm_head(params: Params, h: jax.Array, cfg: LlamaConfig):
+    """Final norm and vocab projection: [B, S, d] -> f32 [B, S, vocab]."""
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
-        logits = jnp.einsum("bsd,dv->bsv", h,
-                            params["lm_head"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    return logits, pool_k, pool_v, scale_k, scale_v, moe_stats
+        return jnp.einsum("bsd,dv->bsv", h,
+                          params["lm_head"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
+
+
+def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
+                       bt, starts, cfg: LlamaConfig, **kw):
+    """`_layers_paged` and `_lm_head` over every position: the
+    [B, S, vocab] logits the decode, draft and verify steps sample
+    from, beside `_layers_paged`'s other results."""
+    h, *rest = _layers_paged(params, toks, pool_k, pool_v, bt, starts,
+                             cfg, **kw)
+    return (_lm_head(params, h, cfg), *rest)
 
 
 @functools.partial(jax.jit,
@@ -1476,6 +1450,9 @@ class DecodeEngine:
         # `_count_paged_walk`).
         self.paged_walk_pages_total = 0    # live pages, active rows
         self.paged_walk_entries_total = 0  # B * MB per decode token
+        # The same for prefill dispatches (`_count_prefill_walk`).
+        self.prefill_walk_pages_total = 0  # pages the chunks' tiles walk
+        self.prefill_table_entries_total = 0   # n_pad * MB per dispatch
         # Disaggregated prefill/decode plane (plain ints; identically
         # zero on a colocated engine so fleet rollups sum blindly).
         # `prefill_only` is set by the fleet on prefill-class replicas:
@@ -1914,7 +1891,11 @@ class DecodeEngine:
         the oldest step's token block, so the device computes step N+1
         while the host replays step N. Per-call emissions are identical
         to the synchronous engine: each call still drains exactly one
-        block, whose horizon follows the same budget arithmetic."""
+        block, whose horizon follows the same budget arithmetic. A call
+        that finds an admission while blocks are in flight drains THOSE
+        (the flush), dispatches the prefill and the next block, and
+        returns: tokens are handed over when their block has run, never
+        a prefill and a block later."""
         if horizon is not None and horizon < 1:
             raise ValueError("horizon must be >= 1")
         self.steps_total += 1
@@ -1931,6 +1912,12 @@ class DecodeEngine:
         if self._ring and (self.scheduler.admissions_pending()
                            or self._row_prefill):
             self._flush_pipeline(emitted)
+        # Tokens a flush drained are in hand NOW: this call dispatches
+        # what follows (the admissions' prefill, the next decode block)
+        # and returns them, instead of holding them until that block
+        # has run too; the next call drains it (and runs ahead of it:
+        # a second newcomer then waits for one block, not for two).
+        flushed = bool(emitted)
         with self.trace.lane("admit", "admit") as admit:
             budget = self.max_prefills_per_step or self.B
             admissions: List[Tuple[int, _Request]] = []
@@ -2042,8 +2029,9 @@ class DecodeEngine:
 
         if not self._ring:
             decodable = self._dispatch_primary(decodable, live, horizon)
-        self._top_up_pipeline(decodable, horizon)
-        self._drain_one(emitted)
+        if not flushed:
+            self._top_up_pipeline(decodable, horizon)
+            self._drain_one(emitted)
         # End of stream: every request retired, but run-ahead blocks
         # may remain (all-masked overrun). Drain them now so pending()
         # reads true and the ring never outlives its requests.
@@ -2275,6 +2263,36 @@ class DecodeEngine:
             np.minimum(slots // T + 1, self._mb).sum())
         self.paged_walk_entries_total += H * self.B * self._mb
 
+    def _count_prefill_walk(self, starts: np.ndarray,
+                            last_idx: np.ndarray, bucket: int) -> None:
+        """Account one prefill dispatch of ``len(starts)`` (padded) rows
+        x ``bucket`` tokens: the kernel takes a row's chunk one query
+        tile at a time (`walk_shape`), and a tile walks the pages up to
+        the one that holds its last REAL token's slot (a tile of bucket
+        filler alone walks none), where the dense view this replaced
+        covered all ``MB`` table entries of every row: pages asked for
+        per table entry, which passes 1 for a late chunk of a long
+        prompt (each of its tiles walks most of the row). The host's
+        estimate; nothing is counted where no tile walks anything by
+        design, the pure-lax lowering a tp mesh or a quantized pool's
+        chunk takes (off the chip that lowering stands in for the
+        kernel, and is counted)."""
+        from ray_tpu.ops.paged_attention_kernel import walk_shape
+
+        if self.kv_quant_spec is not None or (
+                self.mesh is not None and self.mesh.size > 1):
+            return
+        cfg, T = self.cfg, self.kv_block_tokens
+        _, tq = walk_shape(bucket, cfg.n_heads, cfg.n_kv_heads,
+                           cfg.head_dim, T, self._mb,
+                           self._pool_k.dtype.itemsize)
+        first = np.arange(0, bucket, tq)                    # [tiles]
+        top = np.minimum(first + tq - 1, last_idx[:, None])
+        pages = np.minimum((starts[:, None] + top) // T + 1, self._mb)
+        self.prefill_walk_pages_total += int(
+            pages[first <= last_idx[:, None]].sum())
+        self.prefill_table_entries_total += len(starts) * self._mb
+
     def _top_up_pipeline(self, rows: List[int],
                          horizon: Optional[int]) -> None:
         """Run ahead: keep up to `pipeline_depth` fused steps in flight
@@ -2495,6 +2513,10 @@ class DecodeEngine:
         out["paged_walk_pages_total"] = float(self.paged_walk_pages_total)
         out["paged_walk_entries_total"] = float(
             self.paged_walk_entries_total)
+        out["prefill_walk_pages_total"] = float(
+            self.prefill_walk_pages_total)
+        out["prefill_table_entries_total"] = float(
+            self.prefill_table_entries_total)
         # Expert-layer plane (an `MoeConfig`; identically 0.0 for a dense
         # model): counted on the device over live rows, as of the last
         # token block drained. Speculative rounds are not counted.
@@ -2935,16 +2957,20 @@ class DecodeEngine:
                 rows[n:] = rows[n - 1]          # duplicate scatters write
                 last_idx[n:] = last_idx[n - 1]  # identical values
                 bt_grp = self._bt_d[rows]
-                (self._pool_dk, self._pool_dv, self._scale_dk,
-                 self._scale_dv,
-                 self._d_last_logits, _) = _prefill_rows_paged(
-                    self.draft_params, jnp.asarray(prompts),
-                    self._pool_dk, self._pool_dv, self._d_last_logits,
-                    jnp.asarray(bt_grp), jnp.asarray(rows),
-                    jnp.asarray(starts), jnp.asarray(last_idx),
-                    self.draft_cfg, shardings=self._d_shardings,
-                    scale_k=self._scale_dk, scale_v=self._scale_dv,
-                    qspec=self.kv_quant_spec)
+                # as in `_dispatch_decode`: under a tp mesh the traced
+                # program must not pick a Mosaic kernel
+                with spmd_mesh_scope(self.mesh):
+                    (self._pool_dk, self._pool_dv, self._scale_dk,
+                     self._scale_dv,
+                     self._d_last_logits, _) = _prefill_rows_paged(
+                        self.draft_params, jnp.asarray(prompts),
+                        self._pool_dk, self._pool_dv,
+                        self._d_last_logits, jnp.asarray(bt_grp),
+                        jnp.asarray(rows), jnp.asarray(starts),
+                        jnp.asarray(last_idx), self.draft_cfg,
+                        shardings=self._d_shardings,
+                        scale_k=self._scale_dk, scale_v=self._scale_dv,
+                        qspec=self.kv_quant_spec)
                 self.spec_prefill_dispatches += 1
 
     def _bind_row(self, row: int, req: _Request, chain: List[int],
@@ -3476,18 +3502,25 @@ class DecodeEngine:
                     else:
                         adapters = row_slot = None
                     bt_grp = self._bt[rows]            # [n_pad, MB]
-                    (self._pool_k, self._pool_v, self._scale_k,
-                     self._scale_v, self._last_logits,
-                     self._moe_ctr) = _prefill_rows_paged(
-                        self.params, jnp.asarray(prompts), self._pool_k,
-                        self._pool_v, self._last_logits,
-                        jnp.asarray(bt_grp), jnp.asarray(rows),
-                        jnp.asarray(starts), jnp.asarray(last_idx),
-                        self.cfg, shardings=self._shardings,
-                        adapters=adapters, row_slot=row_slot,
-                        scale_k=self._scale_k, scale_v=self._scale_v,
-                        qspec=self.kv_quant_spec,
-                        moe_ctr=self._moe_ctr)
+                    self._count_prefill_walk(starts, last_idx, Cb)
+                    # the scope only matters while the program traces:
+                    # under a tp mesh paged_attention must not pick a
+                    # Mosaic kernel (GSPMD cannot partition one)
+                    with spmd_mesh_scope(self.mesh):
+                        (self._pool_k, self._pool_v, self._scale_k,
+                         self._scale_v, self._last_logits,
+                         self._moe_ctr) = _prefill_rows_paged(
+                            self.params, jnp.asarray(prompts),
+                            self._pool_k, self._pool_v,
+                            self._last_logits, jnp.asarray(bt_grp),
+                            jnp.asarray(rows), jnp.asarray(starts),
+                            jnp.asarray(last_idx), self.cfg,
+                            shardings=self._shardings,
+                            adapters=adapters, row_slot=row_slot,
+                            scale_k=self._scale_k,
+                            scale_v=self._scale_v,
+                            qspec=self.kv_quant_spec,
+                            moe_ctr=self._moe_ctr)
                     self.prefill_dispatches += 1
                     padded = n_pad * Cb - real
                     self.prefill_real_tokens += real

@@ -97,10 +97,10 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
     The ONLY things that differ between the paths are how this chunk's
     K/V land in storage and how attention reads them back, so exactly
     those are injected: ``write_kv(k_cache, v_cache, k, v) ->
-    (k_cache, v_cache)`` always, and optionally ``attend(q, k_cache,
-    v_cache) -> o`` when the storage is not a dense [B, max_len] cache
-    row (the paged engine passes `ops.attention.paged_attention` over
-    its block pool — which stays op-for-op lockstep with
+    (k_cache, v_cache)`` always, and optionally ``attend(q, k, v,
+    k_cache, v_cache) -> o`` when the storage is not a dense
+    [B, max_len] cache row (the paged engine passes
+    `ops.attention.paged_attention` over its block pool — which stays op-for-op lockstep with
     `_cached_attention`, so token identity across paths holds). Every
     other op is shared by construction (a norm tweak or attention
     change here reaches every engine automatically).
@@ -146,7 +146,7 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
         k_cache, v_cache = write_kv(k_cache, v_cache, k, v)
     if attend is not None:
         with jax.named_scope(sn.PAGED_ATTENTION):
-            o = attend(q, k_cache, v_cache)
+            o = attend(q, k, v, k_cache, v_cache)
     else:
         with jax.named_scope(sn.CACHED_ATTENTION):
             o = _cached_attention(q, k_cache, v_cache, q_slots,
@@ -241,85 +241,58 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
 
 
 def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
-                        starts: jax.Array, cfg: LlamaConfig, *,
-                        adapters: Optional[Params] = None,
-                        row_slot: Optional[jax.Array] = None,
-                        moe_live: Optional[jax.Array] = None):
+                        starts: jax.Array, cfg: LlamaConfig
+                        ) -> Tuple[jax.Array, Cache]:
     """Run a token chunk [B, S] with a PER-ROW cache offset: row b's
     tokens land at cache slots ``starts[b] + i`` (scatter writes) and
     attend that row's whole prefix ``[0, starts[b] + i]``. Returns
     (logits [B, S, vocab] f32, updated cache).
 
-    This is the suffix-offset prefill entry for prefix-reuse serving:
-    `forward_cached` prefills a chunk at ONE shared offset (solo
-    generate, where every row starts at 0), while the engine admits
-    rows whose cached-prefix lengths differ — each suffix must continue
-    from its own row's frontier in the same batched program. Rows'
-    slots below ``starts[b]`` must already hold valid K/V (a copied
-    prefix and/or earlier chunks); slots at or beyond the chunk are
-    excluded by the causal ``slot <= q_slot`` mask, so stale K/V from a
-    slot's previous occupant is never attended. RoPE positions equal
-    cache slots (no left-padding in slot-based serving).
+    `forward_cached` runs a chunk at ONE shared offset; solo
+    speculative decoding (models/speculative.py) feeds rows whose
+    frontiers differ — accepted lengths diverge per row — so each chunk
+    must continue from its own row's frontier in the same batched
+    program. (The serving engine's programs do the same against the
+    block pool instead of a dense cache: `engine._layers_paged`.) Rows'
+    slots below ``starts[b]`` must already hold valid K/V; slots at or
+    beyond the chunk are excluded by the causal ``slot <= q_slot`` mask,
+    so stale K/V is never attended. RoPE positions equal cache slots.
 
     Write-before-attend: the whole chunk's K/V is scattered into the
     cache BEFORE the chunk attends, so re-running a chunk over slots
     whose previous contents are stale simply overwrites them. The
-    engine's speculative path leans on this as its no-rollback cache
-    discipline — a rejected draft window's K/V is left in place and the
-    next round's verify chunk lands exactly on top of it, the causal
-    mask hiding whatever lies beyond the chunk.
-
-    Multi-LoRA: ``adapters`` is the full adapter-pool stack tree
-    ({name: {"a": [L, A, n_in, r], "b": [L, A, r, n_out]}}, leading
-    layer axis unstacked by the scan) and ``row_slot`` [B] int32 maps
-    each row to its adapter slot (0 = base-only). Both absent -> the
-    scan carries its original 3-tuple and the traced program is
-    byte-identical to the pre-LoRA path.
-
-    ``moe_live`` [B, S] bool (the engine's prefill programs, for an
-    `MoeConfig`): the rows that are real tokens. Given, a third result
-    is returned: the expert layers' counters summed over the layers,
-    int32 [3] (see `moe.moe_ffn_dropless`)."""
+    speculative paths (this one and the engine's) lean on this as their
+    no-rollback cache discipline — a rejected draft window's K/V is
+    left in place and the next round's verify chunk lands exactly on
+    top of it, the causal mask hiding whatever lies beyond the chunk."""
     B, S = tokens.shape
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(cfg.dtype)[tokens]
     slot_ids = starts[:, None] + jnp.arange(S)[None, :]      # [B, S]
     bidx = jnp.arange(B)
 
-    def body(carry, xs):
-        h = carry
-        if adapters is None:
-            layer, k_c, v_c = xs
-            lora = None
-        else:
-            layer, k_c, v_c, lora = xs
+    def write_kv(k_cache, v_cache, k, v):
+        k_cache = k_cache.at[bidx[:, None], slot_ids].set(
+            k.astype(k_cache.dtype))
+        v_cache = v_cache.at[bidx[:, None], slot_ids].set(
+            v.astype(v_cache.dtype))
+        return k_cache, v_cache
 
-        def write_kv(k_cache, v_cache, k, v):
-            k_cache = k_cache.at[bidx[:, None], slot_ids].set(
-                k.astype(k_cache.dtype))
-            v_cache = v_cache.at[bidx[:, None], slot_ids].set(
-                v.astype(v_cache.dtype))
-            return k_cache, v_cache
+    def body(h, xs):
+        layer, k_c, v_c = xs
+        h, k_c, v_c, _ = _layer_body(h, layer, k_c, v_c, slot_ids,
+                                     write_kv, slot_ids, k_c.shape[1],
+                                     cfg)
+        return h, (k_c, v_c)
 
-        h, k_c, v_c, st = _layer_body(h, layer, k_c, v_c, slot_ids,
-                                      write_kv, slot_ids, k_c.shape[1],
-                                      cfg, lora=lora,
-                                      lora_slots=row_slot,
-                                      moe_live=moe_live)
-        return h, (k_c, v_c, st)
-
-    xs = (params["layers"], cache["k"], cache["v"])
-    if adapters is not None:
-        xs = xs + (adapters,)
-    h, (k_new, v_new, stats) = jax.lax.scan(body, h, xs)
+    h, (k_new, v_new) = jax.lax.scan(
+        body, h, (params["layers"], cache["k"], cache["v"]))
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         logits = jnp.einsum("bsd,dv->bsv", h,
                             params["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-    if stats is None:
-        return logits, {"k": k_new, "v": v_new}
-    return logits, {"k": k_new, "v": v_new}, stats.sum(axis=0)
+    return logits, {"k": k_new, "v": v_new}
 
 
 def filter_logits(logits: jax.Array, top_k: Optional[int] = None,

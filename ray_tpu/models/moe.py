@@ -38,6 +38,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models.llama import (LlamaConfig, _attention_call,
                                   _layer_shapes, _rmsnorm, _rope)
@@ -283,7 +284,9 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
                 jnp.repeat(lv, k))
             stats = jnp.stack([
                 lv.sum(dtype=jnp.int32) * k,
-                jnp.int32(g * (e if dense else k)),
+                # a numpy scalar: `jnp.int32(...)` would put one on the
+                # device and the trace read it back (PERF.md PR 30)
+                np.int32(g * (e if dense else k)),
                 hit.sum(dtype=jnp.int32)])
     return out.reshape(b, s, d), stats
 

@@ -18,7 +18,7 @@ import contextlib
 import contextvars
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -141,14 +141,16 @@ def paged_attention(q: jax.Array,
                     sm_scale: Optional[float] = None,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
+                    own_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
                     impl: str = "auto") -> jax.Array:
     """Attention over PAGED K/V: each query row reads its keys/values
     through a per-row block table instead of a contiguous cache row —
     the vLLM/PagedAttention access pattern, serving the DecodeEngine's
     one-pool-many-requests memory plane.
 
-      q            [B, S, H, D]   queries (S=1 fused decode; S>1 would
-                                  be a paged prefill chunk)
+      q            [B, S, H, D]   queries (S=1 fused decode; the window
+                                  of a speculative round; a prefill
+                                  chunk, B the admission group)
       k/v_pool     [L, NB, T, KV*D] the shared block pool, WHOLE, as
                                   the engine stores and carries it: L
                                   layers of NB blocks of T tokens, a
@@ -159,6 +161,8 @@ def paged_attention(q: jax.Array,
                                   slots [p*T, (p+1)*T); unallocated
                                   entries point at block 0
       q_slots      [B, S]         the cache slot each query occupies
+                                  (-1: bucket filler that is asked
+                                  nothing; its result is garbage or 0)
       layer        scalar         which layer of the pool to attend
                                   (traced: the engine's layer scan
                                   passes its index). Pages are read
@@ -170,6 +174,13 @@ def paged_attention(q: jax.Array,
                                   scales when the pool is quantized
                                   (int8/fp8 — see ops/kv_quant.py);
                                   None for a dense-precision pool
+      own_kv       2 x [B, S, KV, D] the queries' own keys and values as
+                                  computed, attended at ``q_slots`` in
+                                  place of what the pool holds there (a
+                                  QUANTIZED pool's prefill: a chunk
+                                  attends itself exact and only what
+                                  lies below it as stored). Pure-lax
+                                  path only
 
     Semantics are EXACTLY the dense path's `_cached_attention` (see
     models/generate.py) evaluated on the gathered view: causal mask
@@ -204,10 +215,15 @@ def paged_attention(q: jax.Array,
         raise ValueError("k_scale and v_scale must be given together")
     if impl == "auto":
         mesh = _SPMD_MESH.get()
-        impl = "flash" if jax.default_backend() == "tpu" and (
-            mesh is None or mesh.size == 1) else "reference"
+        impl = "flash" if own_kv is None \
+            and jax.default_backend() == "tpu" and (
+                mesh is None or mesh.size == 1) else "reference"
     if impl == "flash":
         from ray_tpu.ops.paged_attention_kernel import paged_attention_kernel
+
+        if own_kv is not None:
+            raise ValueError("the kernel reads pages only: own_kv needs "
+                             "the reference path")
 
         return paged_attention_kernel(
             q, k_pool, v_pool, block_tables, q_slots, layer=layer,
@@ -222,8 +238,7 @@ def paged_attention(q: jax.Array,
         k = k_pool[layer, block_tables].reshape(B, MB, T, KV, D)
         v = v_pool[layer, block_tables].reshape(B, MB, T, KV, D)
         if k_scale is not None:
-            # dequant-in-gather; the view must stay f32 (requantization
-            # byte-stability — see ops/kv_quant.py)
+            # dequant-in-gather, in f32
             k = k.astype(jnp.float32) \
                 * k_scale[layer, block_tables][:, :, None, :, None]
             v = v.astype(jnp.float32) \
@@ -231,6 +246,12 @@ def paged_attention(q: jax.Array,
         span = MB * T
         k = k.reshape(B, span, KV, D)
         v = v.reshape(B, span, KV, D)
+        if own_kv is not None:
+            # filler queries (slot -1) lay nothing over the view
+            at = (jnp.arange(B)[:, None],
+                  jnp.where(q_slots >= 0, q_slots, span))
+            k = k.at[at].set(own_kv[0].astype(k.dtype), mode="drop")
+            v = v.at[at].set(own_kv[1].astype(v.dtype), mode="drop")
         # -- lockstep with generate._cached_attention from here on --
         rep = H // KV
         k = jnp.repeat(k, rep, axis=2)                 # [B, span, H, D]

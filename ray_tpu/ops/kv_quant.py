@@ -18,10 +18,12 @@ finite), ``q = round_or_cast(clip(x / s, -qmax, qmax))``, dequant
   recomputed ``amax' = max|q|*s`` differs from ``amax`` only by float
   rounding, so ``s'/s = 1 ± O(2^-23)`` and ``round(q * s/s')`` (int8) /
   nearest-fp8 rounding (e4m3, whose relative spacing is ≥ 2^-3) lands
-  back on ``q`` exactly.  This is what keeps shared prefix blocks
-  byte-identical under `_prefill_rows_paged`'s whole-view write-back —
-  provided the dequantized view stays float32 end to end (a bf16
-  round-trip would break it).
+  back on ``q`` exactly.  This is what makes a swap-out / swap-in or a
+  copy-on-write of a block lossless, and what lets `paged_quant_write`
+  take a half-filled frontier block up again — provided the
+  dequantized values stay float32 end to end (a bf16 round-trip would
+  break it). (No program re-writes a block it did not touch: prefill
+  writes the blocks its chunk falls in and none below its start.)
 * **Stale slots are zeroed at every write.** A block's scale is an
   absmax over ALL its slots, so garbage left by a previous tenant (or a
   rejected speculative window) would silently coarsen the valid tokens'
@@ -109,7 +111,8 @@ def dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
 
 def paged_quant_write(pool: jax.Array, scales: jax.Array, layer,
                       bt: jax.Array, start: jax.Array, vals: jax.Array,
-                      qspec: KVQuantSpec
+                      qspec: KVQuantSpec,
+                      n_valid: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """Read-modify-write ``vals`` [B, S, KV, D] into layer ``layer`` of
     the quantized ``pool`` [L, NB, T, KV*D] at contiguous cache slots
@@ -118,15 +121,21 @@ def paged_quant_write(pool: jax.Array, scales: jax.Array, layer,
     every touched block. Only the touched blocks are gathered and
     scattered back, ``pool[layer, block]``: no layer is sliced out.
 
-    This is the decode/spec write site: S == 1 for plain decode, S ==
-    the draft/verify window for speculation.  The window can straddle
-    block boundaries, so the write is a static loop over the (at most
-    ``(S + T - 2)//T + 1``) window blocks; each iteration RMWs ONE block
-    per row — gather + dequant, scatter this window's tokens that land
-    in that block (offset T + ``mode="drop"`` masks the rest), zero
-    every slot at/beyond ``start + S`` (stale garbage from a previous
-    tenant or a rejected speculative window must not leak into the
-    absmax), requantize with the fresh scale, scatter back.
+    This is every write site of a quantized pool: S == 1 for plain
+    decode, the draft/verify window for speculation, a chunk's bucket
+    for prefill. The window can straddle block boundaries, so the write
+    goes block by block over the (at most ``(S + T - 2)//T + 1``) window
+    blocks; each step RMWs ONE block per row — gather + dequant, scatter
+    this window's tokens that land in that block (offset T +
+    ``mode="drop"`` masks the rest), zero every slot at/beyond the
+    written frontier (stale garbage from a previous tenant or a
+    rejected speculative window must not leak into the absmax),
+    requantize with the fresh scale, scatter back. ``n_valid`` [B]
+    (prefill's; default S) is how many of a row's S tokens are real:
+    bucket filler behind them is not written and the frontier is
+    ``start + n_valid``. A window of more than two blocks runs as a
+    `fori_loop` (a prefill chunk's 17 would be traced and lowered 17
+    times in each of the engine's ~35 prefill programs).
 
     Rows whose window block index runs off the table (retired rows, or
     frontiers at max_len) resolve to physical block 0 — the reserved
@@ -140,24 +149,33 @@ def paged_quant_write(pool: jax.Array, scales: jax.Array, layer,
     bidx = jnp.arange(B)
     nbw = (S + T - 2) // T + 1            # max blocks a window can touch
     off0 = start % T                      # [B] offset in first block
-    for w in range(nbw):
+    n_valid = S if n_valid is None else n_valid[:, None]
+    # token s sits at window position off0 + s (filler: nowhere)
+    pos = jnp.where(jnp.arange(S)[None, :] < n_valid,
+                    off0[:, None] + jnp.arange(S)[None, :], nbw * T)
+    frontier = start[:, None] + n_valid                       # [B, 1]
+
+    def one_block(w, carry):
+        pool, scales = carry
         lb = start // T + w               # [B] logical block index
         blk = jnp.where(lb < MB, bt[bidx, jnp.minimum(lb, MB - 1)], 0)
         cur = dequantize(pool[layer, blk].reshape(B, T, KV, D),
                          scales[layer, blk][:, None, :, None])
-        # token s sits at window position off0 + s; it lands in this
-        # iteration's block iff (off0 + s) // T == w.  Offset T is OOB
-        # and dropped.
-        pos = off0[:, None] + jnp.arange(S)[None, :]          # [B, S]
+        # a token lands in this step's block iff pos // T == w. Offset
+        # T is OOB and dropped.
         offs = jnp.where(pos // T == w, pos % T, T)
         cur = cur.at[bidx[:, None], offs].set(vals, mode="drop")
         # zero stale slots at/beyond the written frontier
         slot = (lb * T)[:, None] + jnp.arange(T)[None, :]     # [B, T]
-        live = slot < (start + S)[:, None]
-        cur = jnp.where(live[:, :, None, None], cur, 0.0)
+        cur = jnp.where((slot < frontier)[:, :, None, None], cur, 0.0)
         amax = jnp.max(jnp.abs(cur), axis=(1, 3))             # [B, KV]
         s_new = block_scale(amax, qspec)
         pool = pool.at[layer, blk].set(quantize(
             cur, s_new[:, None, :, None], qspec).reshape(B, T, KV * D))
-        scales = scales.at[layer, blk].set(s_new)
+        return pool, scales.at[layer, blk].set(s_new)
+
+    if nbw > 2:
+        return jax.lax.fori_loop(0, nbw, one_block, (pool, scales))
+    for w in range(nbw):
+        pool, scales = one_block(w, (pool, scales))
     return pool, scales

@@ -1,4 +1,5 @@
-"""Fused block-walking paged decode-attention kernel (Pallas/Mosaic).
+"""Fused block-walking paged attention kernel (Pallas/Mosaic): decode
+tokens, speculative windows and prefill chunks.
 
 The pure-lax reference in `ops/attention.py:paged_attention` gathers a
 per-row dense view ``[B, MB*T, KV, D]`` and lets XLA fuse it — correct,
@@ -6,42 +7,59 @@ but the gathered view is materialization pressure exactly proportional
 to the block-table span. This kernel instead walks each row's block
 table in VMEM with a flash-style online-softmax loop: page gather +
 (optional int8/fp8) dequantization + attend are fused, no dense view
-ever exists, and **the walk is as long as the row**: a page ``j`` with
-``j*T > max_s q_slots[b, s]`` or ``j*T >= kv_valid_len`` is masked for
-every query of the row, the pages that are not form a prefix of the
-table, and `live_pages` hands the kernel that prefix's length per row
-as a scalar-prefetched ``n_live[b]``. Table entries past it are never
-read, fetched or folded (the engine's decode step has a quarter of its
+ever exists, and **the walk is as long as what is live**: a page ``j``
+with ``j*T > max_s q_slots[b, s]`` or ``j*T >= kv_valid_len`` is masked
+for every query of the row, the pages that are not form a prefix of the
+table, and `live_pages` hands the kernel that prefix's length as a
+scalar-prefetched trip count. Table entries past it are never read,
+fetched or folded (the engine's decode step has a quarter of its
 ``B*MB`` entries live at most; walking all of them was 44 % of a decode
 token, PERF.md PR 25).
 
-Grid is ``(B,)``: one grid step per row. The pool stays in HBM
-(``pl.ANY``) WHOLE, every layer of it, in the layout the engine stores:
-``[L, NB, T, KV*D]``, so one page is one contiguous ``[T, KV*D]`` slab
-holding every KV head as a lane-aligned ``[T, D]`` slice, and a page is
-read once per row whatever the GQA group size. The layer to read is one
-more scalar-prefetched operand and a page is addressed
-``pool.at[layer, block]``: nothing pool-sized or layer-sized is sliced,
-reshaped or copied on the way in (the layer scan used to slice a layer
-out and relay it for every layer and token: four fifths of a decode
-token, PERF.md PR 27). Inside the grid step a ``fori_loop`` of
-``cdiv(n_live[b], P)`` compute steps runs, each over ``P`` pages
-(``P*T`` = 512 keys, or what fits 1 MiB a buffer slot), which the kernel
-fetches itself: one ``make_async_copy`` per live page through the
-scalar-prefetched block table into slot ``i % 2`` of a double buffer,
-step ``i+1``'s copies started before step ``i``'s are waited for. A
-page of a row's last step that lies past ``n_live[b]`` is not fetched;
-its place in the V buffer is zeroed first, so stale VMEM (``0 * NaN``)
-cannot reach the accumulator, and stale K only reaches scores the mask
-replaces. A row with ``n_live == 0`` walks nothing and returns 0.
+Grid is ``(B,)``: one grid step per ROW, and a row is all the query
+slots of an engine row while they are few (a decode token, a
+speculative window: one call over all rows, as before prefill came
+here), or one TILE of a prefill chunk's queries: ``tq`` query tokens
+(`walk_shape`: as many as keep the tile's f32 accumulator within 2 MiB
+and a head's scores within 1 MiB, 128 tokens at Mistral's 8 KV heads x
+4, 256 at OLMoE's 16 x 1, so a 512-token chunk is 4 or 2 tiles).
+Causality makes a tile's walk its own: ``n_live`` is `live_pages` of
+the tile's slots, so the first tile of a chunk at ``start`` walks
+``start/T + tq/T`` pages and not the row's, and a tile whose queries
+are all bucket filler (slot -1) walks none (PERF.md PR 30: prefill
+attended a dense ``[rows, max_len]`` view before). All tiles of all
+rows go in one call, rows in eights (`paged_attention_kernel` says what
+that buys).
+
+The pool stays in HBM (``pl.ANY``) WHOLE, every layer of it, in the
+layout the engine stores: ``[L, NB, T, KV*D]``, so one page is one
+contiguous ``[T, KV*D]`` slab holding every KV head as a lane-aligned
+``[T, D]`` slice, and a page is read once per tile whatever the GQA
+group size. The layer to read is one more scalar-prefetched operand and
+a page is addressed ``pool.at[layer, block]``: nothing pool-sized or
+layer-sized is sliced, reshaped or copied on the way in (the layer scan
+used to slice a layer out and relay it for every layer and token: four
+fifths of a decode token, PERF.md PR 27). Inside the grid step a
+``fori_loop`` of ``cdiv(n_live, P)`` compute steps runs, each over
+``P`` pages (``P*T`` = 512 keys, or what fits 1 MiB a buffer slot),
+which the kernel fetches itself: one ``make_async_copy`` per live page
+through the scalar-prefetched block table into slot ``i % 2`` of a
+double buffer, step ``i+1``'s copies started before step ``i``'s are
+waited for. A page of a tile's last step that lies past ``n_live`` is
+not fetched; its place in the V buffer is zeroed first, so stale VMEM
+(``0 * NaN``) cannot reach the accumulator, and stale K only reaches
+scores the mask replaces. A tile with ``n_live == 0`` walks nothing and
+returns 0.
 
 Queries arrive regrouped as ``[B, KV, S*G, D]`` so one KV head's query
-group is one matmul operand against the step's ``[P*T, D]`` keys.
-Scratch is the usual flash trio per KV head — f32 accumulator
-``[KV, S*G, D]`` plus running max/sum ``[KV, S*G, 1]`` — set at the top
-of the grid step and finalized at its end. Masked positions follow the
-reference exactly: causal ``slot <= q_slot`` plus the ``kv_valid_len``
-cap, fully-masked rows produce 0. A fully masked step leaves the state
+group of a row is one ``[S*G, D]`` matmul operand against the step's
+``[P*T, D]`` keys. Scratch is the usual flash trio per KV head — f32
+accumulator ``[KV, tq*G, D]`` plus running max/sum ``[KV, tq*G, 1]`` —
+set at the top of the grid step and finalized at its end. The KV heads
+of a step are straight-line code for rows of few queries and a loop for
+a prefill tile (`_HEAD_LOOP_ROWS`). Masked positions follow the reference
+exactly: causal ``slot <= q_slot`` plus the ``kv_valid_len`` cap,
+fully-masked rows produce 0. A fully masked step leaves the state
 untouched (``p = 0``, ``alpha = 1``), so stopping at ``n_live`` gives
 the bits of a walk over all ``MB`` entries (`_walk`, which the tests
 call both ways); against a walk of one page a step the sums are
@@ -49,38 +67,42 @@ associated differently, within the parity tolerances.
 
 Quantized pages are widened to the query dtype (exact for int8 and
 fp8-e4m3 into bf16 or f32) and the per-block per-head scales multiply
-the ``[S*G, P*T]`` scores (K) and probabilities (V) column-wise instead
-of the pages: same product, rounded in a different order than the
-reference's dequantize-then-matmul. The scales of a row's MB pages are
-gathered outside the kernel into one ``[1, MB*KV]`` SMEM block per row
-(under the ``kv_gather`` scope) and read as scalars; `_page_scales`
+the ``[tq*G, P*T]`` scores (K) and probabilities (V) column-wise
+instead of the pages: same product, rounded in a different order than
+the reference's dequantize-then-matmul. The scales of a row's MB pages
+are gathered outside the kernel into one ``[1, MB*KV]`` SMEM block per
+row (under the ``kv_gather`` scope) and read as scalars; `_page_scales`
 spreads a step's ``P`` of them over its key columns by selects — Mosaic
 has no gather and no broadcast of a ``(1, 1)`` vector over both
 sublanes and lanes.
 
 The layout compiles for a described ``v5e:2x2`` device at Llama-3-8B
 widths and at the benchmark's shapes, block tokens 16-128, bf16 / int8 /
-fp8, one and four query slots, and so does the whole fused decode
-program around it, held there to moving nothing of the pool's size
+fp8, one and four query slots, and so do the whole fused decode program
+and the prefill program around it, held there to moving nothing of the
+pool's size and to building no view of the table
 (tests/test_tpu_compile.py); it runs on the chip in ``chip_smoke.py``'s
 paged variants; the value sweeps against the pure-lax reference, on a
 pool of three layers that hold different data, run in interpret mode
 (tests/test_engine_kv_quant.py). On a TPU `impl="auto"` routes here;
 elsewhere it stays on the reference path and this kernel runs only when
 asked for explicitly (then in interpret mode). Timed on a v5e (PERF.md
-PR 25, PR 27): 0.28 ms a call at the benchmark's shape where the full
-walk took 2.92, and the same through the whole pool's ref as through a
-layer's view; a row costs about 3 us before its first page (the first
-copy is not overlapped with the row before), a 512-key step about 3.4 us
-against 2.6 of HBM time (ROADMAP S2 keeps what is left).
+PR 25, PR 27, PR 30): 0.28 ms a decode call at the benchmark's shape
+where the full walk took 2.92, and the same through the whole pool's
+ref as through a layer's view; a row costs about 3 us before its first
+page (the first copy is not overlapped with the row before), a 512-key
+step about 3.4 us against 2.6 of HBM time (ROADMAP S2 keeps what is
+left); a 512-token chunk at start 0 0.15 ms a layer, at start 2,560
+0.44.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -92,8 +114,47 @@ _NEG_INF = -1e30
 # per buffer slot (two slots each for K and V: 4 MiB of VMEM at most).
 _KEYS_PER_STEP = 512
 _STEP_BYTES = 1 << 20
+# A grid step holds one TILE of a row's queries: as many query tokens as
+# keep the f32 accumulator [KV, tq*G, D] within 2 MiB and one KV head's
+# f32 scores [tq*G, keys per step] within 1 MiB.
+_TILE_ACC_BYTES = 2 << 20
+_TILE_SCORE_BYTES = 1 << 20
+# The trio, the page buffers and the q and out blocks of a 512-row tile
+# come to 18 MiB, above the compiler's default of 16 for a kernel: such
+# a call asks for 32. A call that fits the default asks for nothing:
+# what a kernel may take, XLA cannot give to the program around it, and
+# the decode program keeps a layer-stacked weight in VMEM across its
+# scan (32 MiB for a decode row's kernel cost a decode token 1.4 % on a
+# v5e: `wk` came from HBM again, PERF.md PR 30).
+_VMEM_DEFAULT_BYTES = 16 << 20
+_VMEM_LIMIT_BYTES = 32 << 20
+# From this many query rows a grid step on, the KV heads are a loop of the
+# kernel and not `n_kv` copies of its body: a head's two matmuls then
+# fill the MXU on their own (a prefill tile; measured equal on a v5e),
+# while the few rows of a decode token or a speculative window need the
+# heads side by side in straight-line code to hide each other's
+# latencies (+20 % a call as a loop). The loop is traced, lowered and
+# compiled once a kernel where the copies cost 8 x that (4.2 -> 1.3 s of
+# Mosaic compile a kernel, 0.65 -> 0.15 s of tracing on the benchmark's
+# host).
+_HEAD_LOOP_ROWS = 128
+# Rows (tiles) go into a call in eights (`paged_attention_kernel`).
+_ROWS_PER_CALL = 8
 
 __all__ = ["paged_attention_kernel"]
+
+
+def _scalar_i32(x):
+    """A scalar operand as int32 WITHOUT touching the device when it is
+    static. `jnp.asarray(4096)` puts a scalar on the chip even under a
+    trace, and a traced program that uses it reads it BACK to make it a
+    literal: a read that queues behind whatever the chip is running.
+    While the engine warms up it is running the program dispatched just
+    before, so every trace of this kernel stood still for 0.2-2 s
+    (PERF.md PR 30: 17 s of a 38 s warm-up)."""
+    if isinstance(x, (int, np.integer)):
+        return np.int32(x)
+    return jnp.asarray(x, jnp.int32)
 
 
 def live_pages(q_slots, kv_valid_len, block_tokens: int,
@@ -104,16 +165,73 @@ def live_pages(q_slots, kv_valid_len, block_tokens: int,
     ``j * T >= kv_valid_len``, and the pages that are not are a prefix
     of the table."""
     by_slot = jnp.max(q_slots.astype(jnp.int32), axis=1) // block_tokens + 1
-    by_len = (jnp.asarray(kv_valid_len, jnp.int32) + block_tokens - 1) \
+    by_len = (_scalar_i32(kv_valid_len) + (block_tokens - 1)) \
         // block_tokens
     return jnp.clip(jnp.minimum(by_slot, by_len), 0, max_blocks)
 
 
+def walk_shape(n_slots: int, n_heads: int, n_kv: int, head_dim: int,
+               block_tokens: int, max_blocks: int,
+               itemsize: int) -> Tuple[int, int]:
+    """(pages a compute step folds, query tokens a grid step holds),
+    from the static shapes alone. A step folds as many pages as make
+    `_KEYS_PER_STEP` keys or fit `_STEP_BYTES`. A grid step holds all
+    ``n_slots`` queries of a row while they fit the tile's budgets (a
+    decode token, a speculative window), else the largest power of two
+    that does (a prefill chunk: 128 tokens x 4 heads a group at 8 KV
+    heads of 128, 256 x 1 at 16). The engine's prefill counter asks here
+    too (`DecodeEngine._count_prefill_walk`)."""
+    page_bytes = block_tokens * n_kv * head_dim * itemsize
+    pps = max(1, min(_KEYS_PER_STEP // block_tokens,
+                     _STEP_BYTES // page_bytes, max_blocks))
+    group = n_heads // n_kv
+    fit = min(_TILE_ACC_BYTES // (n_heads * head_dim * 4),
+              _TILE_SCORE_BYTES // (group * pps * block_tokens * 4))
+    if n_slots <= fit:
+        return pps, n_slots
+    return pps, max(8, 1 << (max(fit, 1).bit_length() - 1))
+
+
+# The kernel's body is written in `jax.lax` primitives, with no `jnp`
+# function and no operator on a traced value. Each of those is a jitted
+# helper, and under a kernel's trace every use of one is traced anew: on
+# the benchmark's host that was 0.45 s a kernel, 20 s of an engine's
+# warm-up over its 44 kernels (PERF.md PR 30). `lax` binds the primitive
+# and nothing else, and the jaxpr is the same.
+_lax = jax.lax
+
+
+def _i32(x):
+    return np.int32(x) if isinstance(x, int) else x
+
+
+def _add(a, b):
+    return _lax.add(_i32(a), _i32(b))
+
+
+def _mul(a, b):
+    return _lax.mul(_i32(a), _i32(b))
+
+
+def _select(pred, x, other):
+    """``where(pred, x, other)`` for a scalar or same-shape ``other``."""
+    if not hasattr(other, "shape"):
+        other = _lax.full_like(x, other)
+    return _lax.select(pred, x, other)
+
+
+def _row_reduce(reduce, x):
+    """``reduce(x, axis=-1, keepdims=True)`` of a [rows, cols] value."""
+    return _lax.broadcast_in_dim(reduce(x, (1,)), (x.shape[0], 1), (0,))
+
+
 def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
-            head_dim, block_tokens, pages_per_step, max_blocks, has_scale):
+            head_dim, block_tokens, pages_per_step, max_blocks, has_scale,
+            head_loop):
     """Grid step ``b``: walk row b's live pages, ``pages_per_step`` at a
-    time, folding each step into every KV head's online softmax.
-    Scalar-prefetch refs: ``bt_ref`` [B*MB] flat block table,
+    time, folding each step into every KV head's online softmax (a row
+    is a decode row's query slots, or one query tile of a prefill
+    chunk). Scalar-prefetch refs: ``bt_ref`` [B*MB] flat block table,
     ``lim_ref`` [1] the valid-length cap, ``nl_ref`` [B] live pages per
     row (the walk's trip count), ``lay_ref`` [1] the pool's layer."""
     if has_scale:
@@ -127,18 +245,22 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     span = pps * t                                      # keys per step
     n_live = nl_ref[b]
     layer = lay_ref[0]
-    n_steps = (n_live + pps - 1) // pps
-    rows = q_ref.shape[2]                               # S * G
+    n_steps = _lax.div(_add(n_live, pps - 1), np.int32(pps))
+    rows = q_ref.shape[2]                               # tq * G
+    row0 = _mul(b, max_blocks)                          # of the flat table
 
     def live_in(step):
         """How many of the step's pages lie inside the live prefix."""
-        return jnp.minimum(pps, n_live - step * pps)
+        return _lax.min(np.int32(pps), _lax.sub(n_live, _mul(step, pps)))
+
+    def page_at(p):
+        return pl.ds(pl.multiple_of(_mul(p, t), t), t)
 
     def page_copies(step, buf, p):
         """The K and V copy of the step's p-th page into buffer `buf`
         (a start and its wait build the same descriptor)."""
-        blk = bt_ref[b * max_blocks + step * pps + p]
-        dst = pl.ds(pl.multiple_of(p * t, t), t)
+        blk = bt_ref[_add(_add(row0, _mul(step, pps)), p)]
+        dst = page_at(p)
         return (pltpu.make_async_copy(k_hbm.at[layer, blk],
                                       k_buf.at[buf, dst], sem.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[layer, blk],
@@ -149,7 +271,7 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
             for copy in page_copies(step, buf, p):
                 act(copy)
             return carry
-        jax.lax.fori_loop(0, live_in(step), one, 0)
+        _lax.fori_loop(0, live_in(step), one, 0)
 
     def start(step, buf):
         each_live_page(step, buf, lambda copy: copy.start())
@@ -157,71 +279,86 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     def wait(step, buf):
         each_live_page(step, buf, lambda copy: copy.wait())
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = _lax.full(acc_ref.shape, 0.0, jnp.float32)
+    m_ref[...] = _lax.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = _lax.full(l_ref.shape, 0.0, jnp.float32)
     start(0, 0)
 
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
-    page_of_col = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1) // t
-    q_slot = qs_ref[0]                                  # [S*G, 1]
+    col = _lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+    page_of_col = _lax.div(
+        _lax.broadcasted_iota(jnp.int32, (1, span), 1), np.int32(t))
+    q_slot = qs_ref[0]                                  # [tq*G, 1]
     lim = lim_ref[0]
+    zero_page = _lax.full((t, v_buf.shape[2]), 0, v_buf.dtype)
 
     def step_body(i, carry):
-        buf = i % 2
+        buf = _lax.rem(i, np.int32(2))
 
-        start(i + 1, 1 - buf)       # past the last step: no live page
+        start(_add(i, 1), _lax.sub(np.int32(1), buf))   # past the last
+        #                                      step: no live page
         wait(i, buf)
         # A page of the last step past the row's live prefix was not
         # fetched: what the V buffer holds there is stale, and 0 * NaN
         # would reach the accumulator through the matmul. (Stale K only
         # reaches scores the mask replaces.)
         def zero(p, carry):
-            v_buf[buf, pl.ds(pl.multiple_of(p * t, t), t), :] = jnp.zeros(
-                (t, v_buf.shape[2]), v_buf.dtype)
+            v_buf[buf, page_at(p), :] = zero_page
             return carry
-        jax.lax.fori_loop(live_in(i), pps, zero, 0)
+        _lax.fori_loop(live_in(i), pps, zero, 0)
 
-        slot = i * span + col
-        mask = (slot <= q_slot) & (slot < lim)
-        for kv in range(n_kv):
-            q = q_ref[0, kv]                            # [S*G, D]
-            lanes = slice(kv * head_dim, (kv + 1) * head_dim)
-            k = k_buf[buf, :, lanes].astype(q.dtype)    # [pps*T, D]
-            v = v_buf[buf, :, lanes].astype(q.dtype)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        slot = _add(_mul(i, span), col)
+        mask = _lax.bitwise_and(_lax.le(slot, q_slot), _lax.lt(slot, lim))
+
+        def head(kv, carry):
+            q = q_ref[0, kv]                            # [tq*G, D]
+            lanes = pl.ds(kv * head_dim, head_dim) if isinstance(kv, int) \
+                else pl.ds(pl.multiple_of(_mul(kv, head_dim), head_dim),
+                           head_dim)
+            k = _lax.convert_element_type(k_buf[buf, :, lanes], q.dtype)
+            v = _lax.convert_element_type(v_buf[buf, :, lanes], q.dtype)
+            s = _lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
             if has_scale:
-                ks, vs = (_page_scales(r, i * pps, kv, page_of_col,
+                ks, vs = (_page_scales(r, _mul(i, pps), kv, page_of_col,
                                        n_kv, pps, max_blocks)
                           for r in (ks_ref, vs_ref))
-                s = s * ks
-            s = s * sm_scale
-            s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_ref[kv]                          # [S*G, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                s = _lax.mul(s, ks)
+            s = _lax.mul(s, np.float32(sm_scale))
+            s = _select(mask, s, _NEG_INF)
+            m_prev = m_ref[kv]                          # [tq*G, 1]
+            m_new = _lax.max(m_prev, _row_reduce(_lax.reduce_max, s))
             # explicit zero (not just exp underflow): a fully-masked
             # step with m still at -inf would otherwise yield
             # exp(0) == 1 per position
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[kv] = l_ref[kv] * alpha + jnp.sum(p, axis=-1,
-                                                    keepdims=True)
+            p = _select(mask, _lax.exp(_lax.sub(s, m_new)), 0.0)
+            alpha = _lax.exp(_lax.sub(m_prev, m_new))
+            l_ref[kv] = _lax.add(_lax.mul(l_ref[kv], alpha),
+                                 _row_reduce(_lax.reduce_sum, p))
             if has_scale:
-                p = p * vs
-            pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_ref[kv] = acc_ref[kv] * alpha + pv
+                p = _lax.mul(p, vs)
+            pv = _lax.dot_general(_lax.convert_element_type(p, v.dtype), v,
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+            acc_ref[kv] = _lax.add(_lax.mul(acc_ref[kv], alpha), pv)
             m_ref[kv] = m_new
+            return carry
+
+        if head_loop:
+            _lax.fori_loop(0, n_kv, head, 0)
+        else:
+            for kv in range(n_kv):
+                head(kv, 0)
         return carry
 
-    jax.lax.fori_loop(0, n_steps, step_body, 0)
+    _lax.fori_loop(0, n_steps, step_body, 0)
 
     l = l_ref[...]
-    l = jnp.where(l == 0.0, 1.0, l)
-    row_live = m_ref[...] > _NEG_INF / 2
-    o_ref[0] = jnp.where(row_live, acc_ref[...] / l, 0.0).astype(o_ref.dtype)
+    l = _select(_lax.eq(l, np.float32(0.0)), _lax.full_like(l, 1.0), l)
+    row_live = _lax.broadcast_in_dim(
+        _lax.gt(m_ref[...], np.float32(_NEG_INF / 2)), acc_ref.shape,
+        (0, 1, 2))
+    o_ref[0] = _lax.convert_element_type(
+        _select(row_live, _lax.div(acc_ref[...], l), 0.0), o_ref.dtype)
 
 
 def _page_scales(scale_ref, first_page, kv, page_of_col, n_kv,
@@ -230,12 +367,13 @@ def _page_scales(scale_ref, first_page, kv, page_of_col, n_kv,
     each key column of the step that starts at page ``first_page``, from
     the row's ``[1, MB*KV]`` SMEM block. Built from scalars by selects:
     Mosaic has no gather."""
-    out = jnp.zeros(page_of_col.shape, jnp.float32)
+    out = _lax.full(page_of_col.shape, 0.0, jnp.float32)
     for p in range(pages_per_step):
         # the last step may reach past the table; such a column is masked
-        page = jnp.minimum(first_page + p, max_blocks - 1)
-        out = jnp.where(page_of_col == p,
-                        scale_ref[0, 0, page * n_kv + kv], out)
+        page = _lax.min(_add(first_page, p), np.int32(max_blocks - 1))
+        scale = scale_ref[0, 0, _add(_mul(page, n_kv), kv)]
+        out = _select(_lax.eq(page_of_col, np.int32(p)),
+                      _lax.broadcast_in_dim(scale, out.shape, ()), out)
     return out
 
 
@@ -252,26 +390,66 @@ def paged_attention_kernel(q: jax.Array,
                            v_scale: Optional[jax.Array] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Same contract as `ops.attention.paged_attention` (reference
-    impl), fused. ``interpret=None`` resolves to True off-TPU."""
+    impl), fused. ``interpret=None`` resolves to True off-TPU.
+
+    The grid runs over ROWS OF ONE TILE: a decode token's or a
+    speculative window's rows as they come, a prefill chunk cut into its
+    query tiles (`walk_shape`), each a row of its own with the chunk
+    row's table. Rows go into the call in eights (a filler row sits at
+    slot -1 and walks nothing) and `_walk` is jitted, so an engine's ~40
+    prefill programs (group sizes x length buckets) trace the kernel
+    once for each tile size and count of eights, about ten times between
+    them and not once each, and its decode programs once. On the
+    benchmark's host a trace of this kernel costs 0.15-0.5 s, a whole
+    prefill program without it 0.08 (PERF.md PR 30).
+    """
+    B, S, H, D = q.shape
     T, MB = k_pool.shape[2], block_tables.shape[1]
-    pool_kv_heads(k_pool, q)
+    KV = pool_kv_heads(k_pool, q)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    n_live = live_pages(q_slots, kv_valid_len, T, MB)
-    return _walk(q, k_pool, v_pool, block_tables, q_slots, n_live,
-                 layer=layer, kv_valid_len=kv_valid_len,
-                 sm_scale=sm_scale, k_scale=k_scale, v_scale=v_scale,
-                 interpret=interpret)
+    pps, tq = walk_shape(S, H, KV, D, T, MB, k_pool.dtype.itemsize)
+    head_loop = tq * (H // KV) >= _HEAD_LOOP_ROWS
+    walk = functools.partial(
+        _walk, k_pool=k_pool, v_pool=v_pool, k_scale=k_scale,
+        v_scale=v_scale, layer=_scalar_i32(layer),
+        kv_valid_len=_scalar_i32(kv_valid_len), n_live=None,
+        sm_scale=sm_scale if sm_scale is not None else D ** -0.5,
+        interpret=interpret, pps=pps, head_loop=head_loop)
+    q_slots = q_slots.astype(jnp.int32)
+    block_tables = block_tables.astype(jnp.int32)
+    tiles = -(-S // tq)
+    if tiles * tq != S:
+        # the last tile's filler queries sit at the row's last slot
+        # (they see what it sees, and are cut off below)
+        fill = ((0, 0), (0, tiles * tq - S))
+        q = jnp.pad(q, fill + ((0, 0), (0, 0)))
+        q_slots = jnp.pad(q_slots, fill, mode="edge")
+    if tiles > 1:
+        q = q.reshape(B * tiles, tq, H, D)
+        q_slots = q_slots.reshape(B * tiles, tq)
+        block_tables = jnp.repeat(block_tables, tiles, axis=0)
+    pad = -(B * tiles) % _ROWS_PER_CALL
+    if pad:
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0)))
+        block_tables = jnp.pad(block_tables, ((0, pad), (0, 0)))
+        q_slots = jnp.pad(q_slots, ((0, pad), (0, 0)), constant_values=-1)
+    out = walk(q, block_tables, q_slots)[:B * tiles]
+    return out.reshape(B, tiles * tq, H, D)[:, :S]
 
 
-def _walk(q, k_pool, v_pool, block_tables, q_slots, n_live, *, layer,
-          kv_valid_len, sm_scale, k_scale, v_scale, interpret):
-    """The kernel call, with each row's trip count ``n_live`` [B] given:
-    `paged_attention_kernel` passes `live_pages`; a walk of all ``MB``
-    entries gives the same bits (a fully masked step leaves the softmax
-    state untouched), which is what the tests hold it to."""
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "pps",
+                                             "head_loop"))
+def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
+          v_scale, layer, kv_valid_len, sm_scale, interpret, pps,
+          head_loop):
+    """The kernel call: rows of ``S`` query slots, ``pps`` pages a
+    compute step. ``n_live`` [B] is each row's trip count: None takes
+    `live_pages`; a walk of all ``MB`` entries gives the same bits (a
+    fully masked step leaves the softmax state untouched), which is what
+    the tests hold it to."""
     B, S, H, D = q.shape
     T = k_pool.shape[2]
     KV = pool_kv_heads(k_pool, q)
@@ -279,15 +457,13 @@ def _walk(q, k_pool, v_pool, block_tables, q_slots, n_live, *, layer,
     g = H // KV
     rows = S * g
     has_scale = k_scale is not None
-    page_bytes = T * KV * D * k_pool.dtype.itemsize
-    pps = max(1, min(_KEYS_PER_STEP // T, _STEP_BYTES // page_bytes, MB))
+    if n_live is None:
+        n_live = live_pages(q_slots, kv_valid_len, T, MB)
 
     # query row r = s*g + i of KV head kv is query s, head kv*g + i
     qg = q.reshape(B, S, KV, g, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, KV, rows, D)
-    qs = jnp.repeat(q_slots.astype(jnp.int32), g, axis=1)[..., None]
-    bt = block_tables.astype(jnp.int32).reshape(-1)
-    lim = jnp.asarray(kv_valid_len, jnp.int32).reshape(1)
+    qs = jnp.repeat(q_slots, g, axis=1)[..., None]
 
     def row_map(b, *_):
         return (b, 0, 0)
@@ -324,18 +500,28 @@ def _walk(q, k_pool, v_pool, block_tables, q_slots, n_live, *, layer,
         ],
     )
     kernel = functools.partial(
-        _kernel, sm_scale=sm_scale if sm_scale is not None else D ** -0.5,
-        n_kv=KV, head_dim=D, block_tokens=T, pages_per_step=pps,
-        max_blocks=MB, has_scale=has_scale)
+        _kernel, sm_scale=sm_scale, n_kv=KV, head_dim=D, block_tokens=T,
+        pages_per_step=pps, max_blocks=MB, has_scale=has_scale,
+        head_loop=head_loop)
+    # What the call holds in VMEM, by hand: page buffers; the trio (m
+    # and l a lane tile wide); the q and out blocks, double-buffered; a
+    # head's scores, probabilities and mask. Mosaic's own temporaries
+    # are not in it, so the call asks for more from half the default on.
+    vmem = (4 * pps * T * KV * D * k_pool.dtype.itemsize
+            + KV * rows * (D + 2 * 128) * 4
+            + 4 * KV * rows * D * q.dtype.itemsize
+            + 3 * rows * pps * T * 4)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES
+            if vmem > _VMEM_DEFAULT_BYTES // 2 else None),
         interpret=interpret,
         name=sn.PAGED_KERNEL,
-    )(bt, lim, n_live.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), *args)
+    )(block_tables.reshape(-1), kv_valid_len.reshape(1),
+      n_live.astype(jnp.int32).reshape(-1), layer.reshape(1), *args)
     return out.reshape(B, KV, S, g, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, S, H, D)

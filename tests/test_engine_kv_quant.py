@@ -197,6 +197,27 @@ def test_quant_on_token_tolerance_gate(nano_model, quant, mode):
 
 
 @pytest.mark.parametrize("quant", ["int8", "fp8_e4m3"])
+def test_quant_prefill_chunk_attends_itself_exact(nano_model, quant):
+    """A prefill chunk of a quantized pool attends its OWN keys and
+    values as computed, and only what lies below it as the pool stores
+    it (`paged_attention`'s ``own_kv``): a cold prompt that fits one
+    chunk gives the dense-precision engine's first token, bit for bit
+    in its logits. Rounding starts with the second token, which reads
+    the prompt back from the pool."""
+    cfg, params = nano_model
+    prompts = _prompts(4, cfg, seed=9)
+    kw = {"kv_block_tokens": T}
+    dense, d_eng = _run(params, cfg, prompts[:2], [1, 1], eng_kw=kw)
+    qtoks, q_eng = _run(params, cfg, prompts[:2], [1, 1],
+                        eng_kw={**kw, "kv_quant": quant})
+    assert qtoks == dense
+    assert jnp.array_equal(q_eng._last_logits, d_eng._last_logits)
+    # ... and the pool holds the chunk quantized all the same
+    assert q_eng._pool_k.dtype == resolve_kv_quant(quant).dtype
+    assert float(jnp.abs(q_eng._scale_k).max()) > 0
+
+
+@pytest.mark.parametrize("quant", ["int8", "fp8_e4m3"])
 def test_quant_logit_error_bound(quant):
     """Op-level bound: attention over a quantized pool stays within a
     small max-abs-err of attention over the f32 original. Per-block
@@ -614,9 +635,15 @@ def test_kernel_ragged_rows_match_reference_and_full_walk(
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(ref, np.float32),
             atol=tol, rtol=tol)
-        full = pak._walk(q, kf, vf, bt, q_slots,
-                         jnp.full((q.shape[0],), _RMB, jnp.int32),
-                         layer=li, sm_scale=None, interpret=True, **kw)
+        pps, tq = pak.walk_shape(slots, q.shape[2], 2, q.shape[3], _RT,
+                                 _RMB, kf.dtype.itemsize)
+        assert tq == slots                       # one tile a row
+        full = pak._walk(q, bt, q_slots,
+                         n_live=jnp.full((q.shape[0],), _RMB, jnp.int32),
+                         k_pool=kf, v_pool=vf, k_scale=sk, v_scale=sv,
+                         layer=np.int32(li), kv_valid_len=np.int32(valid),
+                         sm_scale=q.shape[3] ** -0.5, interpret=True,
+                         pps=pps, head_loop=False)
         assert jnp.array_equal(got, full)
         seen.append(np.asarray(got, np.float32))
     assert not np.allclose(seen[0], seen[2], atol=1e-3)   # other data
@@ -671,3 +698,155 @@ def test_paged_walk_counters_match_the_hand_count(nano_model, lengths):
     assert 0 < pages / s["paged_walk_entries_total"] < 1
     dense = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN)
     assert dense.stats()["paged_walk_entries_total"] == 0.0
+
+
+def test_own_kv_is_attended_in_place_of_the_pools_slots():
+    """`own_kv` lays the queries' own keys and values over the gathered
+    rows at `q_slots`: the result is what a pool holding them there
+    gives, whatever the pool really holds at those slots; a filler query
+    (slot -1) lays nothing; the kernel has no such operand."""
+    rng = np.random.RandomState(23)
+    q, kf, vf, bt, q_slots, _, _ = _ragged_case(4, "bf16", rng)
+    B, S = q_slots.shape
+    KV = kf.shape[3] // q.shape[3]
+    own = tuple(jnp.asarray(rng.randn(B, S, KV, q.shape[3]), q.dtype)
+                for _ in range(2))
+    kw = dict(layer=1, kv_valid_len=_RT * _RMB, impl="reference")
+    got = paged_attention(q, kf, vf, bt, q_slots, own_kv=own, **kw)
+    blk = bt[jnp.arange(B)[:, None], q_slots // _RT]
+    want = paged_attention(
+        q, *(pool.at[1, blk, q_slots % _RT].set(x.reshape(B, S, -1))
+             for pool, x in ((kf, own[0]), (vf, own[1]))),
+        bt, q_slots, **kw)
+    assert jnp.array_equal(got, want)
+    assert not jnp.array_equal(
+        got, paged_attention(q, kf, vf, bt, q_slots, **kw))
+    filler = q_slots.at[:, -1].set(-1)
+    assert jnp.array_equal(
+        paged_attention(q, kf, vf, bt, filler, own_kv=own, **kw)[:, :-1],
+        got[:, :-1])
+    with pytest.raises(ValueError, match="own_kv"):
+        paged_attention(q, kf, vf, bt, q_slots, own_kv=own,
+                        **{**kw, "impl": "flash"})
+
+
+# ---------------------------------------------------------------------------
+# Query tiles: a prefill chunk through the kernel (PR 30)
+# ---------------------------------------------------------------------------
+
+# sha256[:16] of the kernel's float32 output bytes on `_ragged_case(S,
+# quant, RandomState(17 + S))`, layer 1, every slot valid, recorded from
+# the kernel as it stood BEFORE it gained query tiles (grid `(B,)`, one
+# step holding all S*G query rows of a row).
+_ONE_TILE_BITS = {
+    (None, 1): "a6c496149e19db26", (None, 4): "32db758252d42342",
+    ("bf16", 1): "8e36b600eac233f7", ("bf16", 4): "b4ba18a027e66db3",
+    ("int8", 1): "1c4b102b9b337fb7", ("int8", 4): "95b603bc93899c70",
+    ("fp8_e4m3", 1): "58772e22a3298d1b",
+    ("fp8_e4m3", 4): "b541bc566ae39f3b",
+}
+
+
+@pytest.mark.parametrize("slots", [1, 4], ids=["s1", "s4"])
+@pytest.mark.parametrize("quant", [None, "bf16", "int8", "fp8_e4m3"],
+                         ids=["dense", "bf16", "int8", "fp8"])
+def test_kernel_one_tile_bits_are_what_they_were(monkeypatch, quant, slots):
+    """A decode token and a speculative window are ONE tile a row, all
+    rows in one call as before tiles existed, so the output is, bit for
+    bit, what the untiled kernel gave. And tiling is only a
+    grouping of query rows: the same call cut into tiles of one query
+    (each walking no further than its own slot) gives the same values
+    (a matmul of fewer rows may round its sums in another order)."""
+    import hashlib
+
+    rng = np.random.RandomState(17 + slots)
+    q, kf, vf, bt, q_slots, sk, sv = _ragged_case(slots, quant, rng)
+    kw = dict(impl="flash", layer=jnp.int32(1), kv_valid_len=_RT * _RMB,
+              k_scale=sk, v_scale=sv)
+    got = paged_attention(q, kf, vf, bt, q_slots, **kw)
+    assert pak.walk_shape(slots, 4, 2, 16, _RT, _RMB, 4)[1] == slots
+    assert hashlib.sha256(np.asarray(got, np.float32).tobytes()) \
+        .hexdigest()[:16] == _ONE_TILE_BITS[quant, slots]
+    real = pak.walk_shape
+    monkeypatch.setattr(pak, "walk_shape", lambda *a: (real(*a)[0], 1))
+    np.testing.assert_allclose(
+        np.asarray(paged_attention(q, kf, vf, bt, q_slots, **kw),
+                   np.float32),
+        np.asarray(got, np.float32), atol=2e-6, rtol=2e-6)
+
+
+_CT = 8                     # chunk sweep: pages of 8 tokens
+
+
+def _chunk_case(S, quant, group, rng):
+    """Prefill-shaped rows for the tiled kernel: chunks of ``S`` queries
+    at start 0, mid-block (3), several blocks in (5 pages + 5), a row
+    whose chunk is all bucket filler (every slot -1: it is asked
+    nothing) and a group's padding row (the third, verbatim). Pool of
+    two layers, ``KV = 2`` heads of 16, ``group`` query heads each."""
+    KV, D = 2, 16
+    starts = [0, 3, 5 * _CT + 5, 0, 5 * _CT + 5]
+    B = len(starts)
+    MB = -(-(max(starts) + S) // _CT) + 1
+    q_slots = np.asarray(starts)[:, None] + np.arange(S)[None]
+    q_slots[3] = -1
+    NB = 1 + B * MB
+    dt = jnp.bfloat16 if quant == "bf16" else jnp.float32
+    kf = jnp.asarray(rng.randn(2, NB, _CT, KV, D), dt)
+    vf = jnp.asarray(rng.randn(2, NB, _CT, KV, D), dt)
+    bt = 1 + np.arange(B * MB).reshape(B, MB)
+    bt[4] = bt[2]
+    q = rng.randn(B, S, KV * group, D)
+    q[4] = q[2]
+    sk = sv = None
+    if quant != "bf16":
+        qspec = resolve_kv_quant(quant)
+        sk = block_scale(jnp.max(jnp.abs(kf), axis=(2, 4)), qspec)
+        sv = block_scale(jnp.max(jnp.abs(vf), axis=(2, 4)), qspec)
+        kf = quantize(kf, sk[:, :, None, :, None], qspec)
+        vf = quantize(vf, sv[:, :, None, :, None], qspec)
+    return (jnp.asarray(q, dt), kf.reshape(2, NB, _CT, KV * D),
+            vf.reshape(2, NB, _CT, KV * D), jnp.asarray(bt, jnp.int32),
+            jnp.asarray(q_slots, jnp.int32), sk, sv, MB)
+
+
+@pytest.mark.parametrize("group", [1, 4], ids=["g1", "g4"])
+@pytest.mark.parametrize("quant", ["bf16", "int8", "fp8_e4m3"],
+                         ids=["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("slots", [1, 4, 128, 512])
+def test_kernel_tiled_chunks_match_reference(monkeypatch, slots, quant,
+                                             group):
+    """A chunk goes tile by tile: with the tile's budget set to 32
+    query tokens a 128-token chunk is 4 tiles and a 512-token chunk 16
+    (1 and 4 stay one tile, in one call over the rows), steps of 4
+    pages, chunks that start inside a page so that tiles straddle pages
+    and pages straddle tiles.
+    Against the pure-lax reference; a tile walks no further than its own
+    last slot (`live_pages` of the tile); the all-filler row walks
+    nothing and reads 0; the padding row reads what the row it repeats
+    reads."""
+    monkeypatch.setattr(pak, "_KEYS_PER_STEP", 4 * _CT)
+    monkeypatch.setattr(pak, "_TILE_ACC_BYTES", 32 * 2 * group * 16 * 4)
+    rng = np.random.RandomState(31 + slots + group)
+    q, kf, vf, bt, q_slots, sk, sv, MB = _chunk_case(slots, quant, group,
+                                                     rng)
+    pps, tq = pak.walk_shape(slots, 2 * group, 2, 16, _CT, MB,
+                             kf.dtype.itemsize)
+    assert (pps, tq) == (4, min(slots, 32))
+    kw = dict(layer=1, kv_valid_len=MB * _CT, k_scale=sk, v_scale=sv)
+    ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference", **kw)
+    got = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
+    tol = 2e-2 if quant == "bf16" else 2e-5
+    live = [0, 1, 2, 4]
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(ref, np.float32)[live],
+        atol=tol, rtol=tol)
+    assert not np.asarray(got[3], np.float32).any()
+    assert jnp.array_equal(got[4], got[2])
+    # what each tile is asked to walk: the pages up to its last slot
+    tiles = -(-slots // tq)
+    n_live = pak.live_pages(q_slots.reshape(-1, tq), MB * _CT, _CT, MB) \
+        .reshape(-1, tiles)
+    assert n_live[3].tolist() == [0] * tiles
+    assert n_live[1].tolist() == [
+        (3 + min((j + 1) * tq, slots) - 1) // _CT + 1 for j in range(tiles)]

@@ -249,8 +249,12 @@ def test_decode_and_prefill_programs_carry_the_scopes(nano_model):
              sn.EMBED, sn.LM_HEAD}
     assert layer | {sn.SAMPLE, sn.PAGED_ATTENTION, sn.KV_GATHER} \
         <= _scopes_in(seen["_decode_multi_paged"])
-    assert layer | {sn.CACHED_ATTENTION, sn.KV_GATHER} \
+    # prefill is the decode's layer core, a chunk wide: the same scopes,
+    # and none of a dense cache row's
+    assert layer | {sn.PAGED_ATTENTION, sn.KV_GATHER} \
         <= _scopes_in(seen["_prefill_rows_paged"])
+    assert sn.CACHED_ATTENTION not in _scopes_in(
+        seen["_prefill_rows_paged"])
     # the names of the jitted programs are what the readers match
     assert all(n.startswith("jit(_decode_multi_paged)/")
                for n in seen["_decode_multi_paged"] if "/" in n

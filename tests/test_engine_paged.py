@@ -283,6 +283,149 @@ def test_fused_decode_touches_only_what_it_writes(nano_model, monkeypatch,
     assert advanced >= 2 * (9 + 3 + 6 - 3)       # K and V, both checked
 
 
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["dense", "int8"])
+def test_prefill_touches_only_what_it_writes(nano_model, monkeypatch,
+                                             quant):
+    """Prefill writes its chunk's blocks in place and nothing else:
+    every `_prefill_rows_paged` dispatch of a run with shared prefix
+    blocks, chunked continuations, bucket filler and a padded group is
+    compared, K and V (and a quantized pool's scales), against the pool
+    that went in. Allowed to differ: ``[layer, bt[n, slot // T],
+    slot % T]`` for the chunk's slots ``starts[n] .. starts[n] + Cb``
+    (a quantized pool rewrites the blocks those slots fall in, and the
+    one behind them) and the null block. Stronger than what the
+    whole-view write-back of before PR 30 could promise (shared blocks
+    rewritten with the bytes they held): a block that lies wholly below
+    a row's ``starts`` is never a write target, so shared prefix blocks
+    are only ever read. The real tokens' slots did change, in every
+    layer."""
+    import ray_tpu.models.engine as engine_mod
+
+    cfg, params = nano_model
+    real = engine_mod._prefill_rows_paged
+    seen = []
+
+    def spy(params, prompts, pool_k, pool_v, last_logits, bt, rows,
+            starts, last_idx, *a, **kw):
+        before = [np.array(x) for x in (pool_k, pool_v)]
+        s_before = [None if kw.get(n) is None else np.array(kw[n])
+                    for n in ("scale_k", "scale_v")]
+        out = real(params, prompts, pool_k, pool_v, last_logits, bt, rows,
+                   starts, last_idx, *a, **kw)
+        seen.append((before, [np.asarray(out[0]), np.asarray(out[1])],
+                     s_before, [None if x is None else np.array(x)
+                                for x in (out[2], out[3])], np.array(bt),
+                     np.array(starts), np.array(last_idx),
+                     prompts.shape[1]))
+        return out
+
+    monkeypatch.setattr(engine_mod, "_prefill_rows_paged", spy)
+    sys_p = list(range(1, 13))                   # 3 full shared blocks
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
+                       kv_block_tokens=T, prefix_cache=True,
+                       prefill_chunk=8, kv_quant=quant)
+    eng.submit(sys_p + [50, 51], 2)
+    eng.run()                                    # commits the prefix
+    for prompt, n in ((sys_p + list(range(60, 71)), 2), (sys_p + [70], 2),
+                      (list(range(100, 119)), 2)):
+        eng.submit(prompt, n)
+    eng.run()
+    assert eng.stats()["kv_blocks_shared"] >= 6
+    assert any(starts.min() >= 12 for *_, starts, _, _ in seen)   # warm
+    assert any(len(starts) > len(set(starts.tolist()))
+               or len(starts) > 1 for *_, starts, _, _ in seen)
+    L, NB = seen[0][0][0].shape[:2]
+    written = 0
+    for before, after, s_before, s_after, bt, starts, last_idx, Cb in seen:
+        allowed = np.zeros((NB, T), bool)
+        allowed[0] = True                        # the null block
+        below = np.zeros((NB,), bool)            # wholly below a start
+        for n in range(bt.shape[0]):
+            below[bt[n, :starts[n] // T]] = True
+            for slot in range(starts[n], min(starts[n] + Cb, MAX_LEN)):
+                if quant is None:
+                    allowed[bt[n, slot // T], slot % T] = True
+                else:
+                    allowed[bt[n, slot // T]] = True
+            if quant is not None:
+                nxt = (starts[n] + Cb - 1) // T + 1
+                if nxt < bt.shape[1]:
+                    allowed[bt[n, nxt]] = True
+        below[0] = False
+        assert not (allowed.any(axis=1) & below).any()
+        for old, new in zip(before, after):
+            same = (old.view(np.uint8) == new.view(np.uint8)) \
+                .reshape(L, NB, T, -1).all(axis=-1)
+            assert (same | allowed[None]).all()
+            assert same[:, below].all()
+            for n in range(bt.shape[0]):
+                for slot in range(starts[n], starts[n] + last_idx[n] + 1):
+                    assert not same[:, bt[n, slot // T], slot % T].any()
+                    written += 1
+        for old, new in zip(s_before, s_after):
+            if old is not None:
+                same = (old == new).all(axis=-1)                # [L, NB]
+                assert (same | allowed.any(axis=1)[None]).all()
+                assert same[:, below].all()
+    assert written >= 2 * (14 + 11 + 1 + 19)    # K and V, both checked
+
+
+def test_prefill_walk_counters_match_the_hand_count(nano_model,
+                                                    monkeypatch):
+    """`prefill_walk_pages_total / prefill_table_entries_total` is the
+    share of its rows' table entries a prefill dispatch asks the kernel
+    to walk. A dispatch of ``n_pad`` rows counts ``n_pad * MB`` entries
+    (what the dense view covered); a row's chunk goes tile by tile
+    (`walk_shape`: the tile's budget is set to 8 query tokens here), and
+    a tile walks the pages up to its last REAL token's slot, none if it
+    holds bucket filler alone."""
+    from ray_tpu.ops import paged_attention_kernel as pak
+
+    cfg, params = nano_model
+    monkeypatch.setattr(pak, "_TILE_ACC_BYTES",
+                        8 * cfg.n_heads * cfg.head_dim * 4)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
+                       kv_block_tokens=T, prefill_chunk=16)
+    MB = MAX_LEN // T
+    rng = np.random.RandomState(3)
+    # 27 tokens: a chunk of 16 at 0 (tiles 0-7 and 8-15), then 11 in a
+    # bucket of 16 at 16 (tile 16-23 whole; tile 24-31 holds real tokens
+    # up to slot 26)
+    eng.submit(rng.randint(1, cfg.vocab_size, size=27).tolist(), 2)
+    eng.run()
+    s = eng.stats()
+    assert s["prefill_dispatches"] == 2
+    assert s["prefill_walk_pages_total"] == \
+        (7 // T + 1) + (15 // T + 1) + (23 // T + 1) + (26 // T + 1)
+    assert s["prefill_table_entries_total"] == 2 * MB
+    # 18 tokens unchunked: a bucket of 32 whose last tile (slots 24-31)
+    # is filler alone and whose third holds real tokens up to slot 17
+    whole = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
+                         kv_block_tokens=T)
+    whole.submit(rng.randint(1, cfg.vocab_size, size=18).tolist(), 1)
+    whole.run()
+    assert whole.stats()["prefill_walk_pages_total"] == \
+        (7 // T + 1) + (15 // T + 1) + (17 // T + 1) + 0
+    # a group of three pads to four rows: the padding row counts again
+    for n in (3, 3, 3):
+        eng.submit(rng.randint(1, cfg.vocab_size, size=n).tolist(), 1)
+    before = s
+    eng.run()
+    s = eng.stats()
+    assert s["prefill_table_entries_total"] \
+        - before["prefill_table_entries_total"] <= 4 * MB
+    assert s["prefill_walk_pages_total"] > before["prefill_walk_pages_total"]
+    # a quantized pool's chunk takes the pure-lax lowering by design (it
+    # attends itself exact): no tile walks anything, nothing is counted
+    quant = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
+                         kv_block_tokens=T, kv_quant="int8")
+    quant.submit(rng.randint(1, cfg.vocab_size, size=9).tolist(), 1)
+    quant.run()
+    s = quant.stats()
+    assert s["prefill_dispatches"] == 1
+    assert s["prefill_table_entries_total"] == 0
+
+
 # ---------------------------------------------------------------------------
 # Preempt-and-swap
 # ---------------------------------------------------------------------------

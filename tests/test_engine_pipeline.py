@@ -192,6 +192,38 @@ def test_flush_before_admission(nano_model):
     assert len(out[a]) == 16 and len(out[b]) == 16
 
 
+def test_a_flushing_step_returns_what_the_flush_drained(nano_model):
+    """A step that finds an admission while a run-ahead block is in
+    flight drains that block, admits, dispatches the prefill and the
+    next decode block, and RETURNS the drained tokens: it does not hold
+    them until the block it just dispatched has run as well (a request
+    whose last tokens ride the flushed block would end a whole prefill
+    and decode block late, or not, by when the newcomer arrived). The
+    newcomer's first tokens come from the next call; every stream is the
+    synchronous engine's."""
+    cfg, params = nano_model
+    prompts = _prompts(2, cfg, seed=21)
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=64,
+                       pipeline_depth=2, decode_horizon=4)
+    a = eng.submit(prompts[0], 16)
+    first = eng.step()              # admit, dispatch, run ahead, drain one
+    assert len(first[a]) == 4 and eng.stats()["host_lag_steps"] == 1.0
+    c = eng.submit(prompts[1], 6)   # pending admission -> flush
+    syncs0 = eng.stats()["host_syncs"]
+    got = eng.step()
+    s = eng.stats()
+    assert s["host_syncs"] == syncs0 + 1        # the flushed block only
+    assert got == {a: got[a]} and len(got[a]) == 4
+    # the new block alone: a second newcomer would wait for one block
+    assert s["host_lag_steps"] == 1.0
+    assert s["prefill_dispatches"] >= 2.0       # the newcomer IS admitted
+    nxt = eng.step()
+    assert len(nxt[a]) == 4 and 1 <= len(nxt[c]) <= 4
+    out = eng.run()
+    ref, _ = _run(params, cfg, prompts, [16, 6], 1)
+    assert [out[a], out[c]] == ref
+
+
 def test_end_of_stream_flush_never_strands_blocks(nano_model):
     """When the last live row finishes while run-ahead blocks remain,
     the same step drains them (all-masked overrun): pending() turns

@@ -299,3 +299,29 @@ def test_sharded_int8_pool_identity(nano_model):
     assert got == ref
     assert eng._scale_k.sharding.spec[2] == "tp"
     assert eng.stats()["kv_blocks_shared"] >= 2
+
+
+def test_no_program_of_a_tp_engine_picks_the_mosaic_kernel(nano_model,
+                                                           monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel, so while a tp engine's
+    programs are traced `paged_attention(impl="auto")` must stay on the
+    pure-lax path even where the backend is a TPU. Every dispatch that
+    traces one announces the mesh (`spmd_mesh_scope`): the decode
+    always did; prefill attends through the block table since PR 30 and
+    forgot to, which only a four-chip run showed (`chip_smoke.py
+    --chips 4`: "Mosaic kernels cannot be automatically partitioned").
+    Here the backend is made to answer "tpu" and the kernel to refuse:
+    a prefill, a chunked continuation and decode steps all pass."""
+    from ray_tpu.ops import paged_attention_kernel as pak
+
+    def refuse(*a, **k):
+        raise AssertionError("a tp program reached the Mosaic kernel")
+
+    monkeypatch.setattr(pak, "paged_attention_kernel", refuse)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = nano_model
+    prompts = _prompts(3, cfg, seed=13, lo=5, hi=20)
+    toks, eng = _run(params, cfg, prompts, [4, 3, 5], 2,
+                     eng_kw={"prefill_chunk": 8})
+    assert [len(t) for t in toks] == [4, 3, 5]
+    assert eng.stats()["prefill_dispatches"] >= 3

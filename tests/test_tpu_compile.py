@@ -10,7 +10,9 @@ attention widths for the paged decode kernel, the 551M flagship's
 The fused decode PROGRAM is compiled whole as well, at the benchmark's
 shapes, and held to what PR 27 bought: the KV pool is one buffer updated
 in place, so the program holds nothing else of the pool's or a layer's
-size and its workspace is a fraction of the pool.
+size and its workspace is a fraction of the pool. So is the prefill
+program, held to what PR 30 bought: no dense per-row view of the table,
+a workspace that does not grow with `max_len`.
 Nothing runs, so these say nothing about values (the interpret-mode
 sweeps do) or times (only a chip run does).
 """
@@ -219,6 +221,11 @@ def test_decode_program_moves_nothing_of_the_pools_size(v5e, case):
                if re.search(r"= \w+\[%s\]\S* fusion\(" % dims, ln)]
     assert len(fusions) == 2 and all("kv_write" in ln for ln in fusions)
     assert text.count("tpu_custom_call") == 1
+    # a decode row's kernel asks for no VMEM of its own: what it may
+    # take, XLA cannot give the program, which then reads a stacked
+    # weight it kept in VMEM from HBM again (+1.4 % a token, PR 30)
+    call = next(ln for ln in text.splitlines() if "tpu_custom_call" in ln)
+    assert '"scoped_memory_configs":[]' in call
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (1 << 30), f"{temp / 2**30:.2f} GiB of workspace"
 
@@ -234,18 +241,97 @@ def test_decode_program_fits_16_layers_beside_a_5_gib_pool(v5e):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.5 * 2**30
 
 
+def _prefill_program(v5e, cfg, n_blocks, max_blocks, rows, chunk,
+                     slots=32, block_tokens=32):
+    """`_prefill_rows_paged` compiled for the described chip for a group
+    of ``rows`` x ``chunk`` tokens, with the kernel selected as in
+    `_decode_program`. Returns the compiled program and the pool's
+    shape."""
+    from ray_tpu.models import MoeConfig, engine
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    shape = (cfg.n_layers, n_blocks, block_tokens,
+             cfg.n_kv_heads * cfg.head_dim)
+    pool = arg(shape, jnp.bfloat16)
+    moe_ctr = arg((4,)) if isinstance(cfg, MoeConfig) else None
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = engine._prefill_rows_paged.lower(
+            _param_shapes(v5e, cfg), arg((rows, chunk)), pool, pool,
+            arg((slots, cfg.vocab_size), jnp.float32),
+            arg((rows, max_blocks)), arg((rows,)), arg((rows,)),
+            arg((rows,)), cfg, moe_ctr=moe_ctr)
+    return lowered.compile(), shape
+
+
+def _row_scatters(text, pool_shape):
+    """The in-place writes of a chunk's K/V: the compiler flattens the
+    pool to ``[L*NB*T, KV*D]`` and scatters token rows into it."""
+    flat = "%d,%d" % (math.prod(pool_shape[:3]), pool_shape[3])
+    return [ln for ln in text.splitlines()
+            if re.search(r"= \w+\[%s\]\S* scatter\(" % flat, ln)]
+
+
+@pytest.mark.parametrize("case", ["mistral_4x512", "mistral_8x512",
+                                  "olmoe_4x512"])
+def test_prefill_program_builds_no_view_of_the_table(v5e, case):
+    """The benchmark's two engines' largest prefill groups. Until PR 30
+    the program gathered every table entry of every admission row into
+    a dense view ``[layers, rows, max_len, KV, D]``, attended all of it
+    and wrote it back whole: 3.45 GiB of workspace at 4 x 512 and 6.97
+    at 8 x 512 for Mistral, whatever the pool's size. Now a chunk's K/V
+    is scattered into the pool in place (two row scatters, K and V),
+    attention goes through the block table in ONE Pallas kernel, and
+    `lm_head` sees one position a row: no array of the view's shape, of
+    a layer of it, or of ``[rows, chunk, vocab]`` is left, nothing else
+    of the pool's size, and the workspace is under 1 GiB (0.06, 0.11 and
+    0.32 GiB as compiled here)."""
+    cfg, nb, mb, n = {
+        "mistral_4x512": (_mistral(12), 1878, 128, 4),
+        "mistral_8x512": (_mistral(12), 1878, 128, 8),
+        "olmoe_4x512": (_olmoe(12), 615, 64, 4),
+    }[case]
+    compiled, shape = _prefill_program(v5e, cfg, nb, mb, n, 512)
+    text = compiled.as_text()
+    kv, d, span = cfg.n_kv_heads, cfg.head_dim, mb * 32
+    for view in ((n, span, kv, d), (n, span, kv * d), (n, mb, 32, kv * d),
+                 (n, 512, cfg.vocab_size)):
+        dims = ",".join(map(str, view))
+        assert not re.search(r"\[(\d+,)?%s\]" % dims, text), dims
+    assert _pool_sized_instructions(text, shape) == []
+    assert len(_row_scatters(text, shape)) == 2
+    assert "mini-gather" not in text
+    # the expert layer brings its own kernels (`ragged_dot`)
+    assert (text.count("tpu_custom_call") == 1) == (case != "olmoe_4x512")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (1 << 30), f"{temp / 2**30:.2f} GiB of workspace"
+
+
+def test_prefill_program_fits_16_layers_beside_a_5_gib_pool(v5e):
+    """What ROADMAP R0 needs to re-size the Mistral cells: 16 layers
+    (7.0 GiB) and a 5 GiB pool (2,561 blocks) with a FULL prefill group
+    of 8 rows x 512 tokens, beside the decode program
+    (`test_decode_program_fits_16_layers_beside_a_5_gib_pool`). The view
+    alone was 6.97 GiB there."""
+    compiled, _ = _prefill_program(v5e, _mistral(16), 2561, 128, 8, 512)
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < (1 << 30)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.5 * 2**30
+
+
 @pytest.mark.parametrize("program", ["prefill", "cow", "swap_out"])
 def test_other_holders_of_the_pool_copy_none_of_it(v5e, program):
-    """The programs beside the decode that read pages out of the pool,
-    at the Mistral cell's shape: the prefill of one row x 512 tokens, the
-    copy-on-write of four blocks, the swap-out of sixteen. Each gets its
-    pages through `engine._gather_pages`; as a gather of whole pages
-    (1,024 lanes) the chip's compiler sliced the POOL into 512-lane
-    halves first (`mini-gather-slice`), a copy of the pool per call. What
-    is left of the pool's size are the in-place scatters back into it."""
+    """The programs beside the decode that touch pages of the pool, at
+    the Mistral cell's shape: the prefill of one row x 512 tokens, the
+    copy-on-write of four blocks, the swap-out of sixteen. The last two
+    get their pages through `engine._gather_pages`; as a gather of whole
+    pages (1,024 lanes) the chip's compiler sliced the POOL into
+    512-lane halves first (`mini-gather-slice`), a copy of the pool per
+    call. What is left of the pool's size are the in-place scatters back
+    into it; prefill gathers nothing and scatters token rows."""
     from ray_tpu.models import engine
 
-    cfg = _mistral(12)
     shape = (12, 1878, 32, 1024)
 
     def arg(shape, dtype=jnp.int32):
@@ -253,11 +339,16 @@ def test_other_holders_of_the_pool_copy_none_of_it(v5e, program):
 
     pool = arg(shape, jnp.bfloat16)
     if program == "prefill":
-        lowered = engine._prefill_rows_paged.lower(
-            _param_shapes(v5e, cfg), arg((1, 512)), pool, pool,
-            arg((32, cfg.vocab_size), jnp.float32), arg((1, 128)),
-            arg((1,)), arg((1,)), arg((1,)), cfg)
-    elif program == "cow":
+        compiled, _ = _prefill_program(v5e, _mistral(12), 1878, 128, 1, 512)
+        text = compiled.as_text()
+        assert "mini-gather" not in text
+        # one row: the two scatters keep the pool's own shape
+        dims = ",".join(map(str, shape))
+        assert sorted(_pool_sized_instructions(text, shape)) \
+            == [("fusion", dims)] * 2 + [("scatter", dims)] * 2
+        assert _row_scatters(text, shape) == []
+        return
+    if program == "cow":
         lowered = engine._cow_blocks.lower(pool, pool, arg((4,)), arg((4,)))
     else:
         lowered = engine._swap_out_gather.lower(pool, pool, arg((16,)))
