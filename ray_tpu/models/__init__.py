@@ -22,6 +22,7 @@ from ray_tpu.models.vit import (
     vit_loss,
     vit_param_specs,
 )
+from ray_tpu.models.hybrid import HybridConfig, hybrid_init
 from ray_tpu.models.moe import (
     MoeConfig,
     moe_init,
@@ -92,6 +93,8 @@ __all__ = [
     "MLPConfig",
     "mlp_init",
     "mlp_forward",
+    "HybridConfig",
+    "hybrid_init",
     "MoeConfig",
     "moe_init",
     "moe_ffn_dropless",

@@ -92,8 +92,10 @@ from ray_tpu.models.adapter_pool import AdapterPool
 from ray_tpu.models.block_pool import BlockPool
 from ray_tpu.models.engine_metrics import EngineMetrics, NullEngineMetrics
 from ray_tpu.models.engine_trace import resolve_tracer
+from ray_tpu.models import hybrid as _hybrid
 from ray_tpu.models.generate import (_check_sampling_knobs,
                                      _layer_body, sample_rows)
+from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
 from ray_tpu.models.moe import MoeConfig
@@ -400,9 +402,9 @@ def _pin_pools(shardings, pool_k, pool_v, scale_k, scale_v,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "shardings",
-                                             "qspec"),
+                                             "qspec", "final"),
                    donate_argnames=("pool_k", "pool_v", "scale_k",
-                                    "scale_v", "last_logits"))
+                                    "scale_v", "last_logits", "hyb"))
 def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
                         pool_v, last_logits, bt: jax.Array,
                         rows: jax.Array, starts: jax.Array,
@@ -412,7 +414,10 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
                         row_slot: Optional[jax.Array] = None,
                         scale_k=None, scale_v=None,
                         qspec: Optional[KVQuantSpec] = None,
-                        moe_ctr: Optional[jax.Array] = None):
+                        moe_ctr: Optional[jax.Array] = None,
+                        hyb: Optional[Params] = None,
+                        bt_w: Optional[jax.Array] = None,
+                        final: bool = True):
     """Batched admission/continuation prefill: N same-bucket chunks
     [N, Cb] in ONE program, each row at its OWN offset ``starts[n]`` (0
     for a cold admission; the shared prefix length for a warm one; the
@@ -454,27 +459,43 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
     saw in the dense view this program used to build: rounding reaches
     a token only through what it reads back from the pool. That takes
     the pure-lax lowering on the chip too (one layer's rows at a time);
-    the kernel has no operand for the chunk's own K/V (ROADMAP S11)."""
+    the kernel has no operand for the chunk's own K/V (ROADMAP S11).
+
+    A `HybridConfig` brings its own device state ``hyb`` (window pools
+    and recurrent state, donated like the pool) and the rows' window
+    table ``bt_w``; ``rows`` then also says which slot's recurrent state
+    a chunk continues (zero where ``starts`` is 0) and stores. Its
+    chunks that are not a prompt's last (static ``final`` False) stop
+    after the layers that see every token and leave `last_logits` as
+    it is; a last chunk's cross-decoder layers and head run for the
+    row's last real position alone (`hybrid.layers_paged`). For the
+    other families ``hyb`` is None and adds no leaf."""
     n, s = prompts.shape
-    h, pool_k, pool_v, scale_k, scale_v, moe_stats = _layers_paged(
+    h, pool_k, pool_v, scale_k, scale_v, moe_stats, hyb = _layers_paged(
         params, prompts, pool_k, pool_v, bt, starts, cfg,
         adapters=adapters, row_slot=row_slot, scale_k=scale_k,
         scale_v=scale_v, qspec=qspec,
         moe_live=None if moe_ctr is None
         else _prefill_live(rows, last_idx, s),
-        n_valid=last_idx + 1)
+        n_valid=last_idx + 1, hyb=hyb, bt_w=bt_w, rows=rows,
+        last_idx=last_idx, final=final)
     if moe_ctr is not None:
         moe_ctr = moe_ctr.at[:2].add(moe_stats.sum(axis=0)[:2])
-    # the final norm and lm_head see the ONE position a row is read at
-    last = _lm_head(params, h[jnp.arange(n), last_idx][:, None], cfg)
-    with jax.named_scope(sn.LM_HEAD):
-        out_logits = last_logits.at[rows].set(last[:, 0])
+    if hyb is None:
+        h = h[jnp.arange(n), last_idx][:, None]
+    if final:
+        # the final norm and lm_head see the ONE position a row is read at
+        last = _lm_head(params, h, cfg)
+        with jax.named_scope(sn.LM_HEAD):
+            out_logits = last_logits.at[rows].set(last[:, 0])
+    else:
+        out_logits = last_logits
     pool_k, pool_v, scale_k, scale_v = _pin_pools(
         shardings, pool_k, pool_v, scale_k, scale_v)
     if shardings is not None:
         out_logits = jax.lax.with_sharding_constraint(
             out_logits, shardings.logits)
-    return pool_k, pool_v, scale_k, scale_v, out_logits, moe_ctr
+    return pool_k, pool_v, scale_k, scale_v, out_logits, moe_ctr, hyb
 
 
 def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
@@ -556,7 +577,8 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
                   bt, starts, cfg: LlamaConfig, adapters=None,
                   row_slot=None, scale_k=None, scale_v=None,
                   qspec: Optional[KVQuantSpec] = None,
-                  moe_live=None, n_valid=None):
+                  moe_live=None, n_valid=None, hyb=None, bt_w=None,
+                  live=None, rows=None, last_idx=None, final: bool = True):
     """The layer stack for ALL rows of ``toks`` [B, S]: feed each row's
     chunk at slots ``starts + arange(S)``, attending slots up to its
     own, and return the hidden states [B, S, d] ahead of the final norm
@@ -574,7 +596,21 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     activations: each layer writes its tokens into ``pool[li]`` in
     place and attends ``pool[li]`` where it lies. Plain function so
     `_decode_multi_paged`'s scan can inline it and keep the pool in its
-    own carry."""
+    own carry.
+
+    A `HybridConfig`'s layers are not of one kind: its stack is
+    `hybrid.layers_paged`, per-period scans built from the config's
+    `layer_plan`, over this pool (its full-attention layer's), its own
+    state ``hyb`` and the window table ``bt_w``; the seventh result is
+    that state (None for the other families, whose scan is below)."""
+    if hyb is not None:
+        if live is None:
+            live = jnp.arange(toks.shape[1])[None, :] < n_valid[:, None]
+        h, pool_k, pool_v, hyb = _hybrid.layers_paged(
+            params, toks, pool_k, pool_v, bt, starts, cfg, hyb, bt_w,
+            live=live, rows=rows, n_valid=n_valid, last_idx=last_idx,
+            final=final)
+        return h, pool_k, pool_v, scale_k, scale_v, None, hyb
     S = toks.shape[1]
     slots = starts[:, None] + jnp.arange(S)[None, :]
     with jax.named_scope(sn.EMBED):
@@ -595,11 +631,13 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
         xs = xs + (adapters,)
     (h, (pool_k, scale_k), (pool_v, scale_v)), moe_stats = jax.lax.scan(
         body, (h, (pool_k, scale_k), (pool_v, scale_v)), xs)
-    return h, pool_k, pool_v, scale_k, scale_v, moe_stats
+    return h, pool_k, pool_v, scale_k, scale_v, moe_stats, None
 
 
 def _lm_head(params: Params, h: jax.Array, cfg: LlamaConfig):
     """Final norm and vocab projection: [B, S, d] -> f32 [B, S, vocab]."""
+    if isinstance(cfg, HybridConfig):
+        return _hybrid.lm_head(params, h, cfg)
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         return jnp.einsum("bsd,dv->bsv", h,
@@ -622,7 +660,7 @@ def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
                                     "top_k", "top_p", "eos_id",
                                     "shardings", "qspec"),
                    donate_argnames=("pool_k", "pool_v", "scale_k",
-                                    "scale_v", "last_logits"))
+                                    "scale_v", "last_logits", "hyb"))
 def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
                         last_logits, row_len, active, budget, tok_idx,
                         row_keys, row_greedy, temperature,
@@ -635,7 +673,9 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
                         row_slot: Optional[jax.Array] = None,
                         scale_k=None, scale_v=None,
                         qspec: Optional[KVQuantSpec] = None,
-                        moe_ctr: Optional[jax.Array] = None):
+                        moe_ctr: Optional[jax.Array] = None,
+                        hyb: Optional[Params] = None,
+                        bt_w: Optional[jax.Array] = None):
     """Fuse `horizon` decode iterations into ONE program: a `lax.scan`
     whose body samples every row's next token ON DEVICE from the
     carried `last_logits` (greedy argmax, or per-row rng streams — see
@@ -672,12 +712,15 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
     pool and moves nothing of a layer's size (tests/test_tpu_compile.py
     holds the compiled program to that). A quantized pool adds its
     scale slabs to the carry; qspec=None leaves every pytree and the
-    traced program as they were."""
+    traced program as they were. So does ``hyb`` None: a `HybridConfig`'s
+    window pools and recurrent state ride the carry the same way, and
+    only rows that go on (``cont``) advance their recurrent state, so a
+    frozen, dead or mid-prefill row's state stays what it was."""
     max_len = bt.shape[1] * pool_k.shape[2]
 
     def body(carry, _):
         pool_k, pool_v, scale_k, scale_v, last_logits, row_len, \
-            active, budget, tok_idx, moe_ctr = carry
+            active, budget, tok_idx, moe_ctr, hyb = carry
         with jax.named_scope(sn.SAMPLE):
             tok = sample_rows(last_logits, row_keys, tok_idx,
                               greedy=greedy, temperature=temperature,
@@ -695,12 +738,14 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
             if eos_id is not None:
                 done_now = done_now | (tok == eos_id)
             cont = active & ~done_now
-        logits, pool_k, pool_v, scale_k, scale_v, moe_stats = \
+        logits, pool_k, pool_v, scale_k, scale_v, moe_stats, hyb = \
             _decode_core_paged(
                 params, tok[:, None], pool_k, pool_v, bt, row_len, cfg,
                 adapters=adapters, row_slot=row_slot, scale_k=scale_k,
                 scale_v=scale_v, qspec=qspec,
-                moe_live=None if moe_ctr is None else cont[:, None])
+                moe_live=None if moe_ctr is None else cont[:, None],
+                hyb=hyb, bt_w=bt_w,
+                live=None if hyb is None else cont[:, None])
         logits = logits[:, 0]
         if moe_ctr is not None:
             moe_ctr = _moe_count(moe_ctr, moe_stats)
@@ -717,12 +762,12 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
             last_logits = jax.lax.with_sharding_constraint(
                 last_logits, shardings.logits)
         return (pool_k, pool_v, scale_k, scale_v, last_logits, row_len,
-                cont, budget, tok_idx, moe_ctr), emit
+                cont, budget, tok_idx, moe_ctr, hyb), emit
 
     (pool_k, pool_v, scale_k, scale_v, last_logits, row_len, active,
-     budget, tok_idx, moe_ctr), toks = jax.lax.scan(
+     budget, tok_idx, moe_ctr, hyb), toks = jax.lax.scan(
             body, (pool_k, pool_v, scale_k, scale_v, last_logits,
-                   row_len, active, budget, tok_idx, moe_ctr),
+                   row_len, active, budget, tok_idx, moe_ctr, hyb),
             None, length=horizon)
     if moe_ctr is not None:
         toks = _append_moe_ctr(toks, moe_ctr)
@@ -733,7 +778,7 @@ def _decode_multi_paged(params: Params, pool_k, pool_v, bt,
         toks = jax.lax.with_sharding_constraint(
             toks, shardings.replicated)
     return (toks, pool_k, pool_v, scale_k, scale_v, last_logits,
-            row_len, active, budget, tok_idx, moe_ctr)
+            row_len, active, budget, tok_idx, moe_ctr, hyb)
 
 
 @functools.partial(jax.jit,
@@ -804,7 +849,7 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
 
     pend = jnp.where(d_lag == 1, d_tok, t0)
     chunk2 = jnp.stack([pend, t0], axis=1)
-    d_logits, pool_dk, pool_dv, scale_dk, scale_dv, _ = \
+    d_logits, pool_dk, pool_dv, scale_dk, scale_dv, *_ = \
         _decode_core_paged(
             d_params, chunk2, pool_dk, pool_dv, bt_d, row_len - d_lag,
             d_cfg, scale_k=scale_dk, scale_v=scale_dv, qspec=qspec)
@@ -813,7 +858,7 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
 
     def dstep(carry, j):
         tok, pool_dk, pool_dv, scale_dk, scale_dv = carry
-        lg, pool_dk, pool_dv, scale_dk, scale_dv, _ = _decode_core_paged(
+        lg, pool_dk, pool_dv, scale_dk, scale_dv, *_ = _decode_core_paged(
             d_params, tok[:, None], pool_dk, pool_dv, bt_d,
             row_len + 1 + j, d_cfg, scale_k=scale_dk, scale_v=scale_dv,
             qspec=qspec)
@@ -827,7 +872,7 @@ def _spec_round_paged(params: Params, d_params: Params, pool_k, pool_v,
         if W > 1 else lastp[:, None]
 
     chunk = jnp.concatenate([t0[:, None], proposals], axis=1)
-    v_logits, pool_k, pool_v, scale_k, scale_v, _ = _decode_core_paged(
+    v_logits, pool_k, pool_v, scale_k, scale_v, *_ = _decode_core_paged(
         params, chunk, pool_k, pool_v, bt, row_len, cfg,
         scale_k=scale_k, scale_v=scale_v, qspec=qspec)
     ver = jnp.argmax(v_logits, axis=-1).astype(jnp.int32)
@@ -1083,7 +1128,10 @@ class DecodeEngine:
 
     bucket_lens=True rounds each admission's prefill to the next power
     of two, so a handful of XLA compiles (one per length bucket x
-    power-of-two admission-group size) cover all traffic; adaptive
+    power-of-two admission-group size) cover all traffic
+    (`min_prefill_bucket` is the smallest bucket: a chunk of a few tokens
+    costs a read of the weights whatever its width, so a floor of 32
+    saves five programs a group size and no time a chunk); adaptive
     stepping rounds the horizon down to a power of two, so the fused
     decode program compiles at most log2(decode_horizon)+1 variants.
 
@@ -1122,6 +1170,7 @@ class DecodeEngine:
                  top_p: Optional[float] = None,
                  eos_id: Optional[int] = None,
                  bucket_lens: bool = True,
+                 min_prefill_bucket: int = 1,
                  rng: Optional[jax.Array] = None,
                  scheduler: Union[str, SchedulerPolicy] = "fifo",
                  max_queue: Optional[int] = None,
@@ -1174,6 +1223,9 @@ class DecodeEngine:
                 "keyword")
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
+        if min_prefill_bucket < 1 or \
+                min_prefill_bucket & (min_prefill_bucket - 1):
+            raise ValueError("min_prefill_bucket must be a power of two")
         if preempt not in ("swap", "recompute"):
             raise ValueError(f"preempt must be 'swap' or 'recompute', "
                              f"got {preempt!r}")
@@ -1216,6 +1268,35 @@ class DecodeEngine:
                     f"lora= targets {ffn} name the dense feed-forward, "
                     "which an MoeConfig does not have (attention "
                     "targets wq/wk/wv/wo are served)")
+        # A `HybridConfig` (state-space, window, full and shared-cache
+        # layers in one stack) is served by the same programs and step
+        # loop; what would need its recurrent state or its window pool
+        # moved, shared or split is refused here, by name.
+        hybrid = isinstance(cfg, HybridConfig)
+        if hybrid:
+            for bad, what in (
+                    (prefix_cache, "prefix_cache=True: a prefix hit "
+                     "needs a snapshot of the recurrent state at the "
+                     "block boundary it resumes from, and none is kept"),
+                    (preempt == "swap", "preempt='swap': the swap ledger "
+                     "carries K/V blocks only, not a row's recurrent "
+                     "state or its window blocks (pass "
+                     "preempt='recompute')"),
+                    (draft_params is not None or draft_cfg is not None,
+                     "draft_params=/draft_cfg=: a rejected draft token "
+                     "has already advanced the recurrent state, and "
+                     "there is no roll-back of it"),
+                    (kv_quant is not None, "kv_quant=: the window pool "
+                     "and the pair layout differential attention reads "
+                     "have no quantized write"),
+                    (lora is not None, "lora=: the adapter targets name "
+                     "the dense family's projections"),
+                    (tp is not None or mesh is not None, "tp=/mesh=: "
+                     "the state-space and memory-unit weights and the "
+                     "recurrent state have no sharding rule")):
+                if bad:
+                    raise ValueError(
+                        f"a HybridConfig cannot be served with {what}")
         if draft_cfg is not None and \
                 isinstance(draft_cfg, MoeConfig) != sparse:
             raise ValueError(
@@ -1235,6 +1316,7 @@ class DecodeEngine:
         self.top_p = top_p
         self.eos_id = eos_id
         self.bucket_lens = bucket_lens
+        self.min_prefill_bucket = min_prefill_bucket
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
 
         self.scheduler = make_policy(scheduler)
@@ -1490,7 +1572,13 @@ class DecodeEngine:
         # blocks instead of copying them. `kv_pool_bytes` sizes it
         # (default: room for two full batches of max_len tokens) plus
         # the reserved null block 0.
+        # (A `HybridConfig` keeps ONE layer in this pool, its
+        # full-attention layer's, which its cross-attention layers read
+        # too; its window layers' pool and its recurrent state follow
+        # below.)
         L, KV, D = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        if hybrid:
+            L = 1
         T = kv_block_tokens
         if self.kv_quant_spec is not None:
             # Quantized pool: 1-byte values + the per-block scale
@@ -1519,6 +1607,38 @@ class DecodeEngine:
          self._scale_v) = _zero_pools(
             L, n_blocks, T, KV, D, pool_dtype,
             self.kv_quant_spec is not None, shardings=self._shardings)
+        # A `HybridConfig`'s other device state: the WINDOW plane (a
+        # second `BlockPool` and table over a pool of its own geometry,
+        # `n_window_layers` deep; a row holds only the blocks that
+        # intersect its last `sliding_window` slots plus what it is
+        # about to write, `_window_release`) and the recurrent state of
+        # every slot. None/empty for the other families, which pass no
+        # leaf of it to any program.
+        self._hybrid = hybrid
+        self._hyb: Optional[Params] = None
+        self.kv_pool_w: Optional[BlockPool] = None
+        self.kv_walk_tokens_window_total = 0   # token-layers decode asks
+        self.kv_walk_tokens_full_total = 0     # ... per READER of the pool
+        self.window_blocks_freed_total = 0     # released behind the window
+        self.window_pool_peak_blocks = 0
+        self.ssm_state_resets_total = 0        # admissions from zero state
+        self.ssm_row_steps_total = 0           # live rows x decode tokens
+        self.prefill_layer_tokens_total = 0    # token-layers of a dense stack
+        self.prefill_layer_tokens_skipped_total = 0   # ... not run (YOCO)
+        if hybrid:
+            W = cfg.sliding_window
+            chunk = min(prefill_chunk or self.max_len, self.max_len)
+            mid = min(self.B, 2 * (max_prefills_per_step or self.B))
+            # a decoding row: the window, misaligned, and the horizons in
+            # flight; a row mid-prefill its chunk more
+            n_blocks_w = 1 + self.B * (-(-W // T) + 3) \
+                + mid * (-(-chunk // T) + 1)
+            self.kv_pool_w = BlockPool(n_blocks_w, label="window_kv")
+            self._bt_w = np.zeros((self.B, self._mb), np.int32)
+            self._row_blocks_w: List[List[int]] = [
+                [] for _ in range(self.B)]
+            self._w_lo = np.zeros((self.B,), np.int64)  # first held block
+            self._hyb = _hybrid.zero_state(cfg, self.B, n_blocks_w, T)
         self._prefix: Optional[PrefixCacheIndex] = None
         if prefix_cache:
             self._prefix = PrefixCacheIndex(
@@ -2206,13 +2326,15 @@ class DecodeEngine:
                 adapters = row_slot = None
             self._count_paged_walk(H, rows)
             bt_dev = self._table_snapshot(self._bt)
+            btw_dev = self._table_snapshot(self._bt_w) \
+                if self._hybrid else None
             # the scope only matters while the program traces: under
             # a tp mesh paged_attention must not pick a Mosaic kernel
             with spmd_mesh_scope(self.mesh):
                 (toks, self._pool_k, self._pool_v, self._scale_k,
                  self._scale_v, self._last_logits,
                  rl, ac, bu, ti,
-                 self._moe_ctr) = _decode_multi_paged(
+                 self._moe_ctr, self._hyb) = _decode_multi_paged(
                     self.params, self._pool_k, self._pool_v, bt_dev,
                     self._last_logits, *args,
                     jnp.asarray(self._row_keys), rg, self.temperature,
@@ -2220,7 +2342,8 @@ class DecodeEngine:
                     self.eos_id, shardings=self._shardings,
                     adapters=adapters, row_slot=row_slot,
                     scale_k=self._scale_k, scale_v=self._scale_v,
-                    qspec=self.kv_quant_spec, moe_ctr=self._moe_ctr)
+                    qspec=self.kv_quant_spec, moe_ctr=self._moe_ctr,
+                    hyb=self._hyb, bt_w=btw_dev)
             _host_async(toks)
             self._ring.append(_InflightStep(toks, H, list(rows),
                                             run_ahead=chain is not None,
@@ -2262,6 +2385,16 @@ class DecodeEngine:
         self.paged_walk_pages_total += int(
             np.minimum(slots // T + 1, self._mb).sum())
         self.paged_walk_entries_total += H * self.B * self._mb
+        if self._hybrid:
+            # tokens the kernel is asked to read, a token-layer each: the
+            # full layer's cache once a READER (itself and every
+            # cross-attention layer), a window layer's at most the window
+            cfg = self.cfg
+            self.kv_walk_tokens_full_total += int((slots + 1).sum()) \
+                * cfg.full_cache_readers
+            self.kv_walk_tokens_window_total += int(np.minimum(
+                slots + 1, cfg.sliding_window).sum()) * cfg.n_window_layers
+            self.ssm_row_steps_total += H * len(rows)
 
     def _count_prefill_walk(self, starts: np.ndarray,
                             last_idx: np.ndarray, bucket: int) -> None:
@@ -2279,7 +2412,7 @@ class DecodeEngine:
         kernel, and is counted)."""
         from ray_tpu.ops.paged_attention_kernel import walk_shape
 
-        if self.kv_quant_spec is not None or (
+        if self.kv_quant_spec is not None or self._hybrid or (
                 self.mesh is not None and self.mesh.size > 1):
             return
         cfg, T = self.cfg, self.kv_block_tokens
@@ -2526,6 +2659,19 @@ class DecodeEngine:
                             "moe_decode_layer_steps_total"),
                            self._moe_totals):
             out[name] = float(n)
+        # Hybrid plane (a `HybridConfig`; identically 0.0 otherwise):
+        # host estimates at dispatch, like the paged-walk ones.
+        for name in ("kv_walk_tokens_window_total",
+                     "kv_walk_tokens_full_total",
+                     "window_blocks_freed_total", "window_pool_peak_blocks",
+                     "ssm_state_resets_total", "ssm_row_steps_total",
+                     "prefill_layer_tokens_total",
+                     "prefill_layer_tokens_skipped_total"):
+            out[name] = float(getattr(self, name))
+        out["window_pool_blocks_total"] = float(
+            self.kv_pool_w.blocks_total if self._hybrid else 0)
+        out["window_pool_blocks_in_use"] = float(
+            self.kv_pool_w.blocks_in_use if self._hybrid else 0)
         # Disaggregated-handoff plane: identically 0.0 on a colocated
         # engine (prefill_only never set, import never called) so
         # fleet rollups sum blindly.
@@ -2730,9 +2876,14 @@ class DecodeEngine:
     def kv_used_fraction(self) -> float:
         """Unreclaimable KV pressure in [0, 1] — the fleet router's
         occupancy signal: the fraction of pool blocks neither free nor
-        evictable-cold."""
-        return max(0.0, 1.0 - self.kv_free_blocks()
+        evictable-cold; the fuller of the two pools for a
+        `HybridConfig`, which a row needs both of."""
+        used = max(0.0, 1.0 - self.kv_free_blocks()
                    / self.kv_pool.blocks_total)
+        if self._hybrid:
+            used = max(used, self.kv_pool_w.blocks_in_use
+                       / self.kv_pool_w.blocks_total)
+        return used
 
     def prefix_match_tokens(self, prompt: List[int]) -> int:
         """Prompt tokens this engine could SHARE from its prefix cache
@@ -2751,7 +2902,8 @@ class DecodeEngine:
     def _bucket(self, n: int) -> int:
         if not self.bucket_lens:
             return n
-        return min(1 << (n - 1).bit_length(), self.max_len)
+        return min(max(1 << (n - 1).bit_length(), self.min_prefill_bucket),
+                   self.max_len)
 
     def _req_key(self, req: _Request) -> np.ndarray:
         """Per-request sampling stream: the submitted key verbatim, or
@@ -2962,7 +3114,7 @@ class DecodeEngine:
                 with spmd_mesh_scope(self.mesh):
                     (self._pool_dk, self._pool_dv, self._scale_dk,
                      self._scale_dv,
-                     self._d_last_logits, _) = _prefill_rows_paged(
+                     self._d_last_logits, _, _) = _prefill_rows_paged(
                         self.draft_params, jnp.asarray(prompts),
                         self._pool_dk, self._pool_dv,
                         self._d_last_logits, jnp.asarray(bt_grp),
@@ -2981,6 +3133,12 @@ class DecodeEngine:
         self._row_blocks[row] = list(chain)
         self._bt[row, :] = 0
         self._bt[row, :len(chain)] = chain
+        if self._hybrid:
+            # the window chain is grown chunk by chunk (`_window_cover`);
+            # the slot's recurrent state is zeroed by the first chunk
+            assert not self._row_blocks_w[row]
+            self._bt_w[row, :] = 0
+            self._w_lo[row] = 0
         self.row_req[row] = req
         self.row_len[row] = start
         self.row_budget[row] = req.max_new_tokens
@@ -3048,7 +3206,54 @@ class DecodeEngine:
                 self._bt[b, have:have + len(got)] = got
             if self.spec_enabled and not self._ensure_draft_blocks(b, nb):
                 return False
+            if self._hybrid:
+                # every dispatch still to come queries at or past the
+                # host's replayed row_len
+                self._window_release(b, int(self.row_len[b]))
+                if not self._window_cover(b, need_slots):
+                    return False
         return True
+
+    # -- the window plane (a `HybridConfig`) -------------------------------
+
+    def _window_cover(self, b: int, upto: int) -> bool:
+        """Grow row ``b``'s WINDOW chain to hold slots below ``upto``.
+        The chain is the row's logical blocks ``[_w_lo, _w_lo + len)``;
+        entries before it were released and point at the null block.
+        False when the window pool cannot cover it (the caller preempts,
+        or leaves the row's chunk for a later step)."""
+        T = self.kv_block_tokens
+        have = int(self._w_lo[b]) + len(self._row_blocks_w[b])
+        nb = -(-upto // T)
+        if nb > have:
+            got = self.kv_pool_w.alloc(nb - have)
+            if got is None:
+                return False
+            self._row_blocks_w[b].extend(got)
+            self._bt_w[b, have:nb] = got
+            self.window_pool_peak_blocks = max(
+                self.window_pool_peak_blocks, self.kv_pool_w.blocks_in_use)
+        return True
+
+    def _window_release(self, b: int, next_q: int) -> None:
+        """Release row ``b``'s window blocks that lie WHOLLY behind the
+        window of every query still to come, the earliest at slot
+        ``next_q``: a query at t sees ``t - W < s <= t``, so block j is
+        dead once ``(j + 1) * T <= next_q - W + 1``. A step already in
+        flight reads them through its own table snapshot, and the device
+        runs it before any later program can write them for another
+        row."""
+        keep = max(0, next_q - self.cfg.sliding_window + 1) \
+            // self.kv_block_tokens
+        lo = int(self._w_lo[b])
+        n = min(keep - lo, len(self._row_blocks_w[b]))
+        if n <= 0:
+            return
+        self.kv_pool_w.decref(self._row_blocks_w[b][:n])
+        del self._row_blocks_w[b][:n]
+        self._bt_w[b, lo:lo + n] = 0
+        self._w_lo[b] = lo + n
+        self.window_blocks_freed_total += n
 
     def _ensure_draft_blocks(self, b: int, nb: int) -> bool:
         """Grow row ``b``'s DRAFT chain to ``nb`` blocks. The draft
@@ -3268,6 +3473,7 @@ class DecodeEngine:
         preemption at tok_idx=0: the first decode token is sampled
         from the carried logits with `step_rng_key(rng, 0)`, exactly
         what this engine would have done next."""
+        self._refuse_handoff("export_request")
         row = None
         for b in range(self.B):
             r = self.row_req[b]
@@ -3350,6 +3556,7 @@ class DecodeEngine:
         fall back to recompute (prompt + any emitted
         tokens replay), which is slower but bit-identical. Returns the
         request id on this engine."""
+        self._refuse_handoff("import_request")
         kv = handoff.get("kv")
         toks = handoff.get("tokens") or []
         rng = handoff.get("rng")
@@ -3391,6 +3598,13 @@ class DecodeEngine:
                  "swap" if compatible else "recompute"})
         return rid
 
+    def _refuse_handoff(self, what: str) -> None:
+        if self._hybrid:
+            raise ValueError(
+                f"a HybridConfig cannot be served with {what}: a hand-off "
+                "carries K/V blocks only, not a row's recurrent state or "
+                "its window blocks")
+
     @property
     def _kv_geometry(self) -> Tuple[int, int, int]:
         """(layers, KV heads, head dim): what a handoff's K/V payload
@@ -3406,6 +3620,12 @@ class DecodeEngine:
             self.kv_pool.decref(ids)
         self._row_blocks[row] = []
         self._bt[row, :] = 0
+        if self._hybrid:
+            if self._row_blocks_w[row]:
+                self.kv_pool_w.decref(self._row_blocks_w[row])
+            self._row_blocks_w[row] = []
+            self._bt_w[row, :] = 0
+            self._w_lo[row] = 0
         if self.spec_enabled:
             # Draft chains are private (never trie-shared), so decref
             # frees them outright; the plane is re-seeded from scratch
@@ -3439,6 +3659,11 @@ class DecodeEngine:
                     need -= len(ids) - 1   # tail block is CoW'd
                 else:
                     need -= len(ids)
+        if self._hybrid:
+            # the window plane must hold the first chunk at least
+            first = min(len(req.prompt), self.prefill_chunk or self.max_len)
+            if -(-first // T) > self.kv_pool_w.free_blocks:
+                return False
         return need <= self.kv_free_blocks()
 
     def _commit_covered(self, row: int, st: _PrefillState) -> None:
@@ -3463,7 +3688,11 @@ class DecodeEngine:
             return
         with self.trace.lane("advance_prefills", "dispatch",
                              rows=len(self._row_prefill)):
-            groups: Dict[int, List[Tuple[int, _PrefillState, int]]] = {}
+            # A group is one program: the chunks of one bucket and, for a
+            # `HybridConfig`, of one kind, a prompt's last chunk or not
+            # (the others are always "last": one program a bucket).
+            groups: Dict[Tuple[int, bool],
+                         List[Tuple[int, _PrefillState, int]]] = {}
             for row, st in self._row_prefill.items():
                 C = len(st.prompt) - st.pos
                 if self.prefill_chunk is not None:
@@ -3471,9 +3700,20 @@ class DecodeEngine:
                 # Bucket the chunk, capped so the scatter never runs past
                 # max_len (starts differ per row; the cap is per-row).
                 Cb = min(self._bucket(C), self.max_len - st.pos)
-                groups.setdefault(Cb, []).append((row, st, C))
-            for Cb in sorted(groups):
-                grp = groups[Cb]
+                final = True
+                if self._hybrid:
+                    if not self._window_cover(row, st.pos + C):
+                        continue   # the window pool is dry: next step
+                    final = st.pos + C >= len(st.prompt)
+                groups.setdefault((Cb, final), []).append((row, st, C))
+            if self._hybrid and not groups and len(self._row_prefill) \
+                    == sum(r is not None for r in self.row_req):
+                raise RuntimeError(
+                    "window pool exhausted with every live row mid-"
+                    "prefill: nothing can free a block (batch_slots or "
+                    "prefill_chunk too large for it)")
+            for Cb, final in sorted(groups):
+                grp = groups[Cb, final]
                 n = len(grp)
                 with self.trace.lane("prefill_dispatch", "dispatch",
                                      bucket=Cb, rows=n) as span:
@@ -3502,6 +3742,17 @@ class DecodeEngine:
                     else:
                         adapters = row_slot = None
                     bt_grp = self._bt[rows]            # [n_pad, MB]
+                    btw_grp = None
+                    if self._hybrid:
+                        btw_grp = jnp.asarray(self._bt_w[rows])
+                        skipped = self.cfg.n_layers \
+                            - self.cfg.prefill_layers()
+                        self.ssm_state_resets_total += sum(
+                            st.pos == 0 for _, st, _ in grp)
+                        self.prefill_layer_tokens_total += \
+                            real * self.cfg.n_layers
+                        self.prefill_layer_tokens_skipped_total += \
+                            (real - n * final) * skipped
                     self._count_prefill_walk(starts, last_idx, Cb)
                     # the scope only matters while the program traces:
                     # under a tp mesh paged_attention must not pick a
@@ -3509,7 +3760,7 @@ class DecodeEngine:
                     with spmd_mesh_scope(self.mesh):
                         (self._pool_k, self._pool_v, self._scale_k,
                          self._scale_v, self._last_logits,
-                         self._moe_ctr) = _prefill_rows_paged(
+                         self._moe_ctr, self._hyb) = _prefill_rows_paged(
                             self.params, jnp.asarray(prompts),
                             self._pool_k, self._pool_v,
                             self._last_logits, jnp.asarray(bt_grp),
@@ -3520,7 +3771,8 @@ class DecodeEngine:
                             scale_k=self._scale_k,
                             scale_v=self._scale_v,
                             qspec=self.kv_quant_spec,
-                            moe_ctr=self._moe_ctr)
+                            moe_ctr=self._moe_ctr, hyb=self._hyb,
+                            bt_w=btw_grp, final=final)  # graftlint: disable=jit-hygiene -- a bool, part of the group's key: two programs a bucket at most, and only for a HybridConfig
                     self.prefill_dispatches += 1
                     padded = n_pad * Cb - real
                     self.prefill_real_tokens += real
@@ -3539,6 +3791,8 @@ class DecodeEngine:
                              "prompt_tokens": len(st.prompt)})
                     if self._prefix is not None:
                         self._commit_covered(row, st)
+                    if self._hybrid:
+                        self._window_release(row, st.pos)
                     if st.pos >= len(st.prompt):
                         done_rows.append(row)
             for row in done_rows:

@@ -19,6 +19,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import hybrid as _hybrid
+from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models.llama import LlamaConfig, _rmsnorm, _rope
 from ray_tpu.models.moe import MoeConfig, moe_ffn_dropless, qk_norm
 from ray_tpu.ops import scope_names as sn
@@ -33,8 +35,13 @@ def init_cache(cfg: LlamaConfig, batch_size: int,
     """Zero KV cache ``[L, B, max_len, KV, D]``. ``sharding`` (an
     optional `jax.sharding.Sharding`) commits both arrays to a device
     mesh — the tensor-parallel engine shards the KV-head axis so each
-    chip holds only its heads' cache."""
+    chip holds only its heads' cache.
+
+    A `HybridConfig` keeps another state (two pools behind a trivial
+    block table, and recurrent state): `hybrid.init_cache`."""
     max_len = max_len or cfg.max_seq_len
+    if isinstance(cfg, HybridConfig):
+        return _hybrid.init_cache(cfg, batch_size, max_len)
     shape = (cfg.n_layers, batch_size, max_len, cfg.n_kv_heads,
              cfg.head_dim)
     cache = {"k": jnp.zeros(shape, cfg.dtype),
@@ -213,7 +220,19 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
     the whole prompt; decode is S=1 calls. ``positions`` overrides the
     RoPE position ids (ragged batches: left-pad rows start their real
     tokens at position 0); ``slot_live`` [B, max_len] masks dead (pad)
-    cache slots out of every attention."""
+    cache slots out of every attention.
+
+    A `HybridConfig` has no position ids and cannot skip a pad (its
+    state-space layers would consume it), so it takes neither; its
+    logits are the chunk's LAST position's alone, [B, 1, vocab]
+    (`hybrid.forward_cached`)."""
+    if isinstance(cfg, HybridConfig):
+        if slot_live is not None:
+            raise ValueError(
+                "a HybridConfig cannot generate from left-padded prompts "
+                "(prompt_live=): a state-space layer consumes every token "
+                "it is fed; batch prompts of one length, or use the engine")
+        return _hybrid.forward_cached(params, tokens, cache, start, cfg)
     B, S = tokens.shape
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(cfg.dtype)[tokens]

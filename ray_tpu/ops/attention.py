@@ -142,6 +142,7 @@ def paged_attention(q: jax.Array,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
                     own_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
+                    window: Optional[int] = None,
                     impl: str = "auto") -> jax.Array:
     """Attention over PAGED K/V: each query row reads its keys/values
     through a per-row block table instead of a contiguous cache row —
@@ -181,6 +182,13 @@ def paged_attention(q: jax.Array,
                                   attends itself exact and only what
                                   lies below it as stored). Pure-lax
                                   path only
+      window       static int     a query at slot t sees slots
+                                  ``t - window < s <= t`` only (a
+                                  sliding-window layer). Table entries
+                                  wholly behind every query's window are
+                                  never read, so the engine may have
+                                  freed their blocks; None: causal over
+                                  everything
 
     Semantics are EXACTLY the dense path's `_cached_attention` (see
     models/generate.py) evaluated on the gathered view: causal mask
@@ -228,7 +236,7 @@ def paged_attention(q: jax.Array,
         return paged_attention_kernel(
             q, k_pool, v_pool, block_tables, q_slots, layer=layer,
             kv_valid_len=kv_valid_len, sm_scale=sm_scale,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, window=window)
     # Gather the per-row dense view straight out of the whole pool:
     # [B, MB, T, KV*D] -> [B, MB*T, KV, D] (logical slot p*T + t of row
     # b is block_tables[b, p] slot t, so the reshape restores contiguous
@@ -262,6 +270,9 @@ def paged_attention(q: jax.Array,
     slots = jnp.arange(span)
     mask = (slots[None, None, None, :] <= q_slots[:, None, :, None]) \
         & (slots[None, None, None, :] < kv_valid_len)
+    if window is not None:
+        mask = mask & (slots[None, None, None, :]
+                       > q_slots[:, None, :, None] - window)
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhst,bthd->bshd", probs, v,

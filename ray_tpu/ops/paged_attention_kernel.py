@@ -170,6 +170,20 @@ def live_pages(q_slots, kv_valid_len, block_tokens: int,
     return jnp.clip(jnp.minimum(by_slot, by_len), 0, max_blocks)
 
 
+def first_page(q_slots, window: int, block_tokens: int, n_live) -> jax.Array:
+    """[B] int32: the first block-table entry of each row that holds a
+    slot some query of a sliding-window row may see. A query at slot t
+    sees ``t - window < s <= t``, so every page before the one that holds
+    ``min_s q_slots[b, s] - window + 1`` is masked for all of them
+    (filler queries, slot -1, ask nothing and are left out of the
+    minimum). Never past ``n_live``: a row that walks nothing starts
+    where it ends."""
+    qs = q_slots.astype(jnp.int32)
+    lo = jnp.min(jnp.where(qs >= 0, qs, jnp.iinfo(jnp.int32).max), axis=1)
+    first = jnp.maximum(lo - (window - 1), 0) // block_tokens
+    return jnp.minimum(first, n_live).astype(jnp.int32)
+
+
 def walk_shape(n_slots: int, n_heads: int, n_kv: int, head_dim: int,
                block_tokens: int, max_blocks: int,
                itemsize: int) -> Tuple[int, int]:
@@ -227,13 +241,18 @@ def _row_reduce(reduce, x):
 
 def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
             head_dim, block_tokens, pages_per_step, max_blocks, has_scale,
-            head_loop):
+            head_loop, window=None):
     """Grid step ``b``: walk row b's live pages, ``pages_per_step`` at a
     time, folding each step into every KV head's online softmax (a row
     is a decode row's query slots, or one query tile of a prefill
     chunk). Scalar-prefetch refs: ``bt_ref`` [B*MB] flat block table,
     ``lim_ref`` [1] the valid-length cap, ``nl_ref`` [B] live pages per
-    row (the walk's trip count), ``lay_ref`` [1] the pool's layer."""
+    row (the walk's end), ``lay_ref`` [1] the pool's layer and, for a
+    sliding-window layer (``window``), one more: [B] the page each row's
+    walk STARTS at (`first_page`); keys at or behind ``q_slot - window``
+    are masked, inside the first page too."""
+    if window is not None:
+        fp_ref, *refs = refs
     if has_scale:
         (qs_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, k_buf, v_buf,
          sem, acc_ref, m_ref, l_ref) = refs
@@ -245,9 +264,15 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     span = pps * t                                      # keys per step
     n_live = nl_ref[b]
     layer = lay_ref[0]
-    n_steps = _lax.div(_add(n_live, pps - 1), np.int32(pps))
     rows = q_ref.shape[2]                               # tq * G
     row0 = _mul(b, max_blocks)                          # of the flat table
+    if window is not None:
+        # the walk is the pages [first, n_live): everything below counts
+        # from the row's first live page
+        first = fp_ref[b]
+        n_live = _lax.sub(n_live, first)
+        row0 = _add(row0, first)
+    n_steps = _lax.div(_add(n_live, pps - 1), np.int32(pps))
 
     def live_in(step):
         """How many of the step's pages lie inside the live prefix."""
@@ -307,7 +332,12 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
         _lax.fori_loop(live_in(i), pps, zero, 0)
 
         slot = _add(_mul(i, span), col)
+        if window is not None:
+            slot = _add(slot, _mul(first, t))
         mask = _lax.bitwise_and(_lax.le(slot, q_slot), _lax.lt(slot, lim))
+        if window is not None:
+            mask = _lax.bitwise_and(
+                mask, _lax.gt(slot, _lax.sub(q_slot, np.int32(window))))
 
         def head(kv, carry):
             q = q_ref[0, kv]                            # [tq*G, D]
@@ -319,7 +349,9 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
             s = _lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
             if has_scale:
-                ks, vs = (_page_scales(r, _mul(i, pps), kv, page_of_col,
+                page0 = _mul(i, pps) if window is None \
+                    else _add(first, _mul(i, pps))
+                ks, vs = (_page_scales(r, page0, kv, page_of_col,
                                        n_kv, pps, max_blocks)
                           for r in (ks_ref, vs_ref))
                 s = _lax.mul(s, ks)
@@ -388,6 +420,7 @@ def paged_attention_kernel(q: jax.Array,
                            sm_scale: Optional[float] = None,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
+                           window: Optional[int] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Same contract as `ops.attention.paged_attention` (reference
     impl), fused. ``interpret=None`` resolves to True off-TPU.
@@ -417,7 +450,7 @@ def paged_attention_kernel(q: jax.Array,
         v_scale=v_scale, layer=_scalar_i32(layer),
         kv_valid_len=_scalar_i32(kv_valid_len), n_live=None,
         sm_scale=sm_scale if sm_scale is not None else D ** -0.5,
-        interpret=interpret, pps=pps, head_loop=head_loop)
+        interpret=interpret, pps=pps, head_loop=head_loop, window=window)
     q_slots = q_slots.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
     tiles = -(-S // tq)
@@ -441,10 +474,10 @@ def paged_attention_kernel(q: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "pps",
-                                             "head_loop"))
+                                             "head_loop", "window"))
 def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
           v_scale, layer, kv_valid_len, sm_scale, interpret, pps,
-          head_loop):
+          head_loop, window=None):
     """The kernel call: rows of ``S`` query slots, ``pps`` pages a
     compute step. ``n_live`` [B] is each row's trip count: None takes
     `live_pages`; a walk of all ``MB`` entries gives the same bits (a
@@ -485,8 +518,12 @@ def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
             args += [s[layer, block_tables].astype(jnp.float32)
                      .reshape(B, 1, MB * KV) for s in (k_scale, v_scale)]
 
+    prefetch = [block_tables.reshape(-1), kv_valid_len.reshape(1),
+                n_live.astype(jnp.int32).reshape(-1), layer.reshape(1)]
+    if window is not None:
+        prefetch.append(first_page(q_slots, window, T, prefetch[2]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(prefetch),
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, rows, D), q_map),
@@ -502,7 +539,7 @@ def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
     kernel = functools.partial(
         _kernel, sm_scale=sm_scale, n_kv=KV, head_dim=D, block_tokens=T,
         pages_per_step=pps, max_blocks=MB, has_scale=has_scale,
-        head_loop=head_loop)
+        head_loop=head_loop, window=window)
     # What the call holds in VMEM, by hand: page buffers; the trio (m
     # and l a lane tile wide); the q and out blocks, double-buffered; a
     # head's scores, probabilities and mask. Mosaic's own temporaries
@@ -521,7 +558,6 @@ def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
             if vmem > _VMEM_DEFAULT_BYTES // 2 else None),
         interpret=interpret,
         name=sn.PAGED_KERNEL,
-    )(block_tables.reshape(-1), kv_valid_len.reshape(1),
-      n_live.astype(jnp.int32).reshape(-1), layer.reshape(1), *args)
+    )(*prefetch, *args)
     return out.reshape(B, KV, S, g, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, S, H, D)

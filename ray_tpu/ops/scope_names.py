@@ -12,7 +12,7 @@ which change with every compile.
 """
 
 EMBED = "embed"                      # token ids -> hidden states
-NORM = "norm"                        # every rmsnorm
+NORM = "norm"                        # every rmsnorm and LayerNorm
 ATTN_QKV = "attn_qkv"                # q/k/v projections and rope
 KV_WRITE = "kv_write"                # this chunk's K/V into cache or pool
 KV_GATHER = "kv_gather"              # slices/reshapes/gathers of cached
@@ -26,6 +26,14 @@ MOE_ROUTER = "moe_router"            # router matmul, softmax, top-k
 MOE_DISPATCH = "moe_dispatch"        # sort, gather, scatter, combine: moves
 #                                      tokens to experts, computes nothing
 MOE_EXPERTS = "moe_experts"          # the three expert matmuls and silu
+SSM_PROJ = "ssm_proj"                # state-space layer: in/out projections,
+#                                      causal conv, gate
+SSM_SCAN = "ssm_scan"                # state-space layer: x_proj, dt and the
+#                                      recurrence over the state
+GMU = "gmu"                          # gated memory unit (reads the memory a
+#                                      state-space layer handed on)
+DIFF_COMBINE = "diff_combine"        # differential attention: subtraction,
+#                                      sub-norm and scale of a head pair
 LM_HEAD = "lm_head"                  # final vocab projection
 SAMPLE = "sample"                    # on-device sampling and row freezing
 LOSS = "loss"                        # log-softmax and token nll
@@ -33,7 +41,8 @@ OPTIMIZER = "optimizer"              # update rule and parameter apply
 
 SCOPES = (EMBED, NORM, ATTN_QKV, KV_WRITE, KV_GATHER, PAGED_ATTENTION,
           CACHED_ATTENTION, ATTENTION, ATTN_OUT, MLP, LM_HEAD, SAMPLE,
-          LOSS, OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS)
+          LOSS, OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
+          SSM_PROJ, SSM_SCAN, GMU, DIFF_COMBINE)
 
 # Scopes whose ops move cached K/V without computing on it.
 KV_MOVE = (KV_WRITE, KV_GATHER)

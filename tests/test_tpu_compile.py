@@ -113,6 +113,27 @@ def test_paged_kernel_compiles_at_olmoe_shape(v5e):
                                   KV=16)
 
 
+def test_paged_kernel_compiles_with_a_window_at_the_pair_layout(v5e):
+    """`benchmark/configs/phi-4-mini-flash-serve.json`: key heads of 64
+    read as PAIRS of 128 lanes (queries zero-padded to the pair, 40 query
+    heads over 10 pairs), value pairs 128 wide, a walk that starts at the
+    row's first live page of the 8-layer window pool: a decode token of 64
+    rows and a 4 x 512 chunk (tiles of 64 queries)."""
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def fn(q, k, v, bt, slots, layer):
+        return paged_attention_kernel(
+            q, k, v, bt, slots, layer=layer, kv_valid_len=160 * 32,
+            sm_scale=0.125, window=512, interpret=False)
+
+    pool = arg((8, 1353, 32, 1280), jnp.bfloat16)
+    for rows, slots in ((64, 1), (4, 512)):
+        assert _compiles_with_kernel(
+            fn, arg((rows, slots, 40, 128), jnp.bfloat16), pool, pool,
+            arg((rows, 160)), arg((rows, slots)), arg(()))
+
+
 # -- the fused decode program, whole ------------------------------------------
 
 def _param_shapes(v5e, cfg):
@@ -419,3 +440,64 @@ def test_flash_fwd_compiles_under_gqa(v5e):
 
     assert _compiles_with_kernel(fn, *_flash_args(v5e, 1, 32, 8, 2048,
                                                   128))
+
+
+# -- the hybrid cell's programs ------------------------------------------------
+
+def _hybrid_program(v5e, program):
+    """The `phi4flash-reason` cell's engine as `DecodeEngine` builds it
+    (64 slots, 160 table entries of 32 tokens, a full-layer pool of 8,193
+    blocks, a window pool of 1,353, recurrent state for every slot):
+    `_decode_multi_paged` at horizon 8 or `_prefill_rows_paged` for 4 x 512
+    tokens, compiled for the described chip with the kernel selected."""
+    from ray_tpu.models import HybridConfig, engine, hybrid, hybrid_init
+
+    cfg = HybridConfig.phi4_mini_flash(max_seq_len=5120)
+    B, T, MB = 64, 32, 160
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def shapes(fn):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype),
+                            jax.eval_shape(fn))
+
+    params = shapes(lambda: hybrid_init(jax.random.PRNGKey(0), cfg))
+    hyb = shapes(lambda: hybrid.zero_state(cfg, B, 1353, T))
+    pool = arg((1, 8193, T, 1280), jnp.bfloat16)
+    logits = arg((B, cfg.vocab_size), jnp.float32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        if program == "decode":
+            lane, flag = arg((B,)), arg((B,), jnp.bool_)
+            lowered = engine._decode_multi_paged.lower(
+                params, pool, pool, arg((B, MB)), logits, lane, flag, lane,
+                lane, arg((B, 2), jnp.uint32), flag, 1.0, cfg, 8, True,
+                None, None, None, hyb=hyb, bt_w=arg((B, MB)))
+        else:
+            lowered = engine._prefill_rows_paged.lower(
+                params, arg((4, 512)), pool, pool, logits, arg((4, MB)),
+                arg((4,)), arg((4,)), arg((4,)), cfg, hyb=hyb,
+                bt_w=arg((4, MB)), final=program == "prefill_last")
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_last",
+                                     "prefill_not_last"])
+def test_hybrid_cell_programs_fit_the_chip(v5e, program):
+    """All 32 layers of Phi-4-mini-flash-reasoning in bf16 (7.18 GiB)
+    beside both pools and the state: 10.32 GiB of arguments, and no
+    program adds a GiB of workspace (0.13 decode, 0.21 / 0.24 prefill as
+    compiled here), within the chip's 15.75. Three kernel calls a program
+    (window, full, cross layers), one body; a chunk that is not a prompt's
+    last stops before the cross-decoder: one call, and the cross-decoder's
+    2.7 GiB of weights are not even arguments."""
+    compiled = _hybrid_program(v5e, program)
+    m = compiled.memory_analysis()
+    last = program != "prefill_not_last"
+    assert m.temp_size_in_bytes < (1 << 30)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 11 * 2**30
+    assert (m.argument_size_in_bytes > 10 * 2**30) == last
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (3 if last else 1)
+    # the pools and the state are updated in place: donated and aliased
+    assert m.alias_size_in_bytes > 3 * 2**30
