@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import attention
 from ray_tpu.ops import scope_names as sn
+from ray_tpu.ops.flash_attention import FLASH_RESIDUAL_NAMES
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import logical_to_mesh, LogicalAxisRules
 
@@ -66,12 +67,24 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16          # activation dtype
     param_dtype: Any = jnp.float32     # master weights
     remat: bool = True
-    # Per-layer checkpoint policy: "full" recomputes everything (min
-    # HBM), "save_dots" keeps matmul outputs (recompute only cheap
-    # elementwise — more HBM, fewer recomputed FLOPs), or
+    # Per-layer checkpoint policy: "full" recomputes the layer from its
+    # input (min HBM), "save_dots" keeps matmul outputs (recompute only
+    # cheap elementwise — more HBM, fewer recomputed FLOPs), or
     # "save:<name>+<name>+..." keeps only the NAMED dot outputs
     # (checkpoint_name tags in _decoder_layer) — the HBM/recompute
     # frontier in between. Valid names: REMAT_SAVE_NAMES.
+    # One exception under EVERY policy, "full" included: where the flash
+    # kernel runs, its output and row logsumexp are kept
+    # (_layer_checkpoint), so the backward pass never re-runs the
+    # forward kernel. Cost: one activation-dtype [B, S, H*D] array a
+    # layer (+ a [B, H, S] f32 statistic), i.e. "full" saves two carries
+    # a layer instead of one: L x B x S x H*D x 2 bytes a chip more
+    # (InternLM2-1.8B, 4 sequences of 4,096 a chip: +1.52 GiB; a 7B at
+    # one sequence of 4,096 a chip: +1 GiB). There is no switch: no
+    # other byte buys as much recompute (64 MiB a layer for 6 % of that
+    # step; the FFN's activations would need 512 MiB for 7.7 %), and the
+    # recompute it removes grows with S as the array does. A trainer at
+    # its memory limit gives the bytes back through batch or S.
     remat_policy: str = "full"
     attn_impl: str = "auto"            # auto|flash|reference|ring
     ring_axis: str = "sp"
@@ -290,6 +303,25 @@ def _decoder_layer(h: jax.Array, layer: Params, positions: jax.Array,
     return h
 
 
+def _layer_checkpoint(layer_fn, remat_policy: str):
+    """`jax.checkpoint` of one decoder layer under `remat_policy`
+    (validated in LlamaConfig.__post_init__). Every policy also keeps
+    the flash kernel's output and row statistics
+    (`FLASH_RESIDUAL_NAMES`), so the backward pass recomputes what XLA
+    computes and never re-runs the attention kernel. Where no kernel
+    runs (`attn_impl="reference"`) no such name exists and each policy
+    saves what it names and nothing more."""
+    policies = jax.checkpoint_policies
+    names = list(FLASH_RESIDUAL_NAMES)
+    if remat_policy.startswith("save:"):
+        names += _parse_save_names(remat_policy)
+    keep = policies.save_only_these_names(*names)
+    if remat_policy == "save_dots":
+        keep = policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, keep)
+    return jax.checkpoint(layer_fn, policy=keep)
+
+
 def llama_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
                  positions: Optional[jax.Array] = None) -> jax.Array:
     """tokens [B, S] int32 -> final-norm hidden states [B, S, dim]
@@ -302,18 +334,7 @@ def llama_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
 
     layer_fn = functools.partial(_decoder_layer, positions=positions, cfg=cfg)
     if cfg.remat:
-        if cfg.remat_policy == "save_dots":
-            layer_fn = jax.checkpoint(
-                layer_fn,
-                policy=jax.checkpoint_policies
-                .dots_with_no_batch_dims_saveable)
-        elif cfg.remat_policy.startswith("save:"):
-            layer_fn = jax.checkpoint(
-                layer_fn,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *_parse_save_names(cfg.remat_policy)))
-        else:  # "full" — validated in LlamaConfig.__post_init__
-            layer_fn = jax.checkpoint(layer_fn)
+        layer_fn = _layer_checkpoint(layer_fn, cfg.remat_policy)
 
     def scan_body(h, layer):
         return layer_fn(h, layer), None

@@ -41,7 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.llama import (LlamaConfig, _attention_call,
-                                  _layer_shapes, _rmsnorm, _rope)
+                                  _layer_checkpoint, _layer_shapes,
+                                  _rmsnorm, _rope)
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_to_mesh
 
@@ -66,10 +67,9 @@ class MoeConfig(LlamaConfig):
         super().__post_init__()
         if self.remat_policy != "full":
             raise ValueError(
-                "MoeConfig supports remat_policy='full' only: moe_forward "
-                "ignores remat_policy and always applies plain per-layer "
-                "jax.checkpoint (and _moe_decoder_layer carries no "
-                "checkpoint_name tags for named policies either)")
+                "MoeConfig supports remat_policy='full' only: "
+                "_moe_decoder_layer carries no checkpoint_name tags, so a "
+                "named policy would save nothing it names")
 
     @staticmethod
     def mixtral_8x7b(**kw) -> "MoeConfig":
@@ -375,7 +375,7 @@ def moe_forward(params: Params, tokens: jax.Array, cfg: MoeConfig,
     layer_fn = functools.partial(_moe_decoder_layer, positions=positions,
                                  cfg=cfg)
     if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
+        layer_fn = _layer_checkpoint(layer_fn, cfg.remat_policy)
 
     def scan_body(carry, layer):
         return layer_fn(carry, layer), None

@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -412,17 +413,33 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
 
 
+# What a rematerialised layer keeps of this kernel: its output and the
+# compact [B, H, Sq] row logsumexp, tagged in `_flash_vjp_fwd`. A
+# `jax.checkpoint` whose policy saves these two names never re-runs the
+# forward kernel in its backward pass (models/llama.py:_layer_checkpoint).
+# Outside `jax.checkpoint` a name lowers to nothing.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k,
                           interpret, with_lse=True)
+    # The TAGGED `out` is the primal output too: a recompute of what
+    # consumes the attention output then reads the saved array instead of
+    # asking the kernel for it. The kernel's [B, H, Sq, 1] statistic is
+    # kept without its trailing axis, which a tiled layout would pad
+    # 128-fold once stacked over the layers.
+    out_name, lse_name = FLASH_RESIDUAL_NAMES
+    out = checkpoint_name(out, out_name)
+    lse = checkpoint_name(lse[..., 0], lse_name)
     return out, (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret,
                    residuals, g):
     q, k, v, out, lse = residuals
-    return _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q,
-                      block_k, interpret)
+    return _flash_bwd(q, k, v, out, lse[..., None], g, sm_scale, causal,
+                      block_q, block_k, interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

@@ -1,7 +1,10 @@
 """Model-layer tests on the 8-device CPU mesh: forward shapes, sharded
 train step convergence, graft entry points."""
 
+import functools
+
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -721,3 +724,211 @@ def test_llama_streaming_matches_batch_and_ragged():
         params, jnp.asarray(padded), cfg, max_new_tokens=4,
         prompt_live=jnp.asarray(live))), axis=1)
     np.testing.assert_array_equal(streamed_r, batch_r[:, -4:])
+
+
+# -- what a rematerialised layer keeps: the flash kernel's output and row
+# statistics (PR 32) -----------------------------------------------------------
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (list, tuple)) else [v]):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr, nested ones (scan, shard_map, remat,
+    custom_vjp, pjit) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk(sub)
+
+
+def _kernels(jaxpr):
+    """Names of the Pallas calls in a jaxpr, nested ones included."""
+    return [str(e.params["name"]) for e in _walk(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def _kernels_by_scan_body(fn, *args):
+    """The kernels inside each `scan` of `fn`'s jaxpr, in program order:
+    a layer stack under `value_and_grad` gives the forward pass's body,
+    then the backward pass's. `fn` may be a jaxpr already traced."""
+    traced = fn if hasattr(fn, "jaxpr") else jax.make_jaxpr(fn)(*args)
+    return [sorted(_kernels(e.params["jaxpr"].jaxpr))
+            for e in _walk(traced.jaxpr) if e.primitive.name == "scan"]
+
+
+def _flash_names_in(jaxpr):
+    from ray_tpu.ops.flash_attention import FLASH_RESIDUAL_NAMES
+
+    return [e.params["name"] for e in _walk(jaxpr)
+            if e.primitive.name == "name"
+            and e.params["name"] in FLASH_RESIDUAL_NAMES]
+
+
+def _plain_checkpoint(monkeypatch):
+    """The layer under `jax.checkpoint` with no policy: what every
+    policy ran before the kernel's residuals were kept."""
+    from ray_tpu.models import llama, moe
+
+    for mod in (llama, moe):
+        monkeypatch.setattr(mod, "_layer_checkpoint",
+                            lambda fn, policy: jax.checkpoint(fn))
+
+
+def _assert_bitwise(a, b, what):
+    jax.tree_util.tree_map(
+        lambda x, y: np.testing.assert_array_equal(
+            np.asarray(x), np.asarray(y), err_msg=what), a, b)
+
+
+def _flash_llama(policy="full"):
+    from ray_tpu.models import LlamaConfig, llama_init
+
+    cfg = LlamaConfig.nano(remat=True, remat_policy=policy,
+                           attn_impl="flash", max_seq_len=32)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 33),
+                                          0, cfg.vocab_size)}
+    return cfg, params, batch
+
+
+@pytest.mark.parametrize("policy", ["full", "save:qkv", "save_dots"])
+def test_remat_layer_never_reruns_the_flash_forward(policy, monkeypatch):
+    """Under every policy the layer's checkpoint keeps the kernel's
+    output and row statistics: the backward scan body holds the two
+    backward kernels and NO forward kernel (the plain checkpoint re-ran
+    it), and loss and every gradient leaf are bitwise what the plain
+    checkpoint gives: the same kernels on the same operands, one of
+    them once instead of twice."""
+    from ray_tpu.models import llama_loss
+
+    cfg, params, batch = _flash_llama(policy)
+
+    def fn():       # a new function each time: JAX caches traces by it
+        return jax.value_and_grad(lambda p: llama_loss(p, batch, cfg))
+
+    assert _kernels_by_scan_body(fn(), params) == [
+        ["flash_fwd"], ["flash_bwd_dkv", "flash_bwd_dq"]]
+    got = jax.jit(fn())(params)
+    with monkeypatch.context() as mp:
+        _plain_checkpoint(mp)
+        assert _kernels_by_scan_body(fn(), params) == [
+            ["flash_fwd"], ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]]
+        want = jax.jit(fn())(params)
+    _assert_bitwise(got, want, policy)
+
+
+def test_remat_layer_keeps_flash_residuals_inside_shard_map(monkeypatch):
+    """The train step's form: the kernel sits in a `shard_map` over an
+    `{"fsdp": 4}` mesh, which hands the checkpoint's policy to its body."""
+    from ray_tpu.models import llama_loss
+    from ray_tpu.ops.attention import spmd_mesh_scope
+    from ray_tpu.parallel import create_mesh
+
+    cfg, params, batch = _flash_llama()
+    mesh = create_mesh({"fsdp": 4}, jax.devices()[:4])
+
+    def fn():
+        def step(p):
+            with spmd_mesh_scope(mesh):
+                return jax.value_and_grad(
+                    lambda q: llama_loss(q, batch, cfg))(p)
+        return step
+
+    bodies = jax.make_jaxpr(fn())(params)
+    assert any(e.primitive.name == "shard_map" for e in _walk(bodies.jaxpr))
+    assert _kernels_by_scan_body(bodies) == [
+        ["flash_fwd"], ["flash_bwd_dkv", "flash_bwd_dq"]]
+    got = jax.jit(fn())(params)
+    with monkeypatch.context() as mp:
+        _plain_checkpoint(mp)
+        assert len(_kernels_by_scan_body(fn(), params)[1]) == 3
+        want = jax.jit(fn())(params)
+    _assert_bitwise(got, want, "fsdp4")
+
+
+def test_moe_remat_layer_never_reruns_the_flash_forward(monkeypatch):
+    """`moe_forward` takes the same helper: its layer holds the same
+    attention call."""
+    from ray_tpu.models.moe import MoeConfig, moe_init, moe_loss
+
+    cfg = MoeConfig.nano_moe(remat=True, attn_impl="flash", max_seq_len=32,
+                             dtype=jnp.float32)
+    params = moe_init(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 33),
+                                          0, cfg.vocab_size)}
+
+    def fn():
+        return jax.value_and_grad(lambda p: moe_loss(p, batch, cfg))
+
+    assert _kernels_by_scan_body(fn(), params) == [
+        ["flash_fwd"], ["flash_bwd_dkv", "flash_bwd_dq"]]
+    got = jax.jit(fn())(params)
+    with monkeypatch.context() as mp:
+        _plain_checkpoint(mp)
+        assert len(_kernels_by_scan_body(fn(), params)[1]) == 3
+        want = jax.jit(fn())(params)
+    _assert_bitwise(got, want, "moe")
+
+
+@pytest.mark.parametrize("program", ["flash_forward", "reference_train",
+                                     "engine_decode", "engine_prefill"])
+def test_flash_residual_names_exist_only_where_the_kernel_is_differentiated(
+        program):
+    """The two names are made in the kernel's VJP forward rule and
+    nowhere else: a forward-only kernel call, a train step on the
+    reference attention and the serving engine's programs trace no
+    `name` of theirs, so nothing of this reaches a serving cell."""
+    from ray_tpu.models import (DecodeEngine, LlamaConfig, engine, llama_init,
+                                llama_loss)
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    if program == "flash_forward":
+        q = jnp.ones((1, 2, 16, 8), jnp.float32)
+        traced = jax.make_jaxpr(functools.partial(
+            flash_attention, interpret=True))(q, q, q)
+    elif program == "reference_train":
+        cfg = LlamaConfig.nano(remat=True, attn_impl="reference")
+        params = llama_init(jax.random.PRNGKey(0), cfg)
+        batch = {"tokens": jnp.zeros((2, 9), jnp.int32)}
+        traced = jax.make_jaxpr(jax.grad(
+            lambda p: llama_loss(p, batch, cfg)))(params)
+    else:
+        cfg = LlamaConfig.nano()
+        params = llama_init(jax.random.PRNGKey(0), cfg)
+        eng = DecodeEngine(params, cfg, batch_slots=2, max_len=32,
+                           kv_block_tokens=4)
+        z = jnp.zeros((eng.B,), jnp.int32)
+        live = jnp.ones((eng.B,), bool)
+        if program == "engine_decode":
+            traced = engine._decode_multi_paged.trace(
+                params, eng._pool_k, eng._pool_v, jnp.asarray(eng._bt),
+                eng._last_logits, z, live, z, z, jnp.asarray(eng._row_keys),
+                live, 1.0, cfg, 2, True, None, None, None)
+        else:
+            traced = engine._prefill_rows_paged.trace(
+                params, jnp.zeros((2, 8), jnp.int32), eng._pool_k,
+                eng._pool_v, eng._last_logits, jnp.asarray(eng._bt),
+                jnp.arange(2), z, z, cfg)
+    assert _flash_names_in(traced.jaxpr) == []
+
+
+def test_flash_residual_names_are_made_where_the_kernel_is_differentiated():
+    """The check above can see a name: the differentiated kernel has
+    both, the statistic without the kernel's trailing axis."""
+    from ray_tpu.models import llama_loss
+    from ray_tpu.ops.flash_attention import FLASH_RESIDUAL_NAMES
+
+    cfg, params, batch = _flash_llama()
+    traced = jax.make_jaxpr(jax.grad(
+        lambda p: llama_loss(p, batch, cfg)))(params)
+    assert sorted(set(_flash_names_in(traced.jaxpr))) \
+        == sorted(FLASH_RESIDUAL_NAMES)
+    lse = [e.outvars[0].aval.shape for e in _walk(traced.jaxpr)
+           if e.primitive.name == "name"
+           and e.params["name"] == "flash_lse"]
+    assert lse and all(shape == (4, cfg.n_heads, 32) for shape in lse)

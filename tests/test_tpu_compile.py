@@ -12,7 +12,9 @@ shapes, and held to what PR 27 bought: the KV pool is one buffer updated
 in place, so the program holds nothing else of the pool's or a layer's
 size and its workspace is a fraction of the pool. So is the prefill
 program, held to what PR 30 bought: no dense per-row view of the table,
-a workspace that does not grow with `max_len`.
+a workspace that does not grow with `max_len`. And the train cell's step
+over the four described chips, held to what PR 32 bought: three kernels
+a layer, the flash kernel's residuals stacked once and in place.
 Nothing runs, so these say nothing about values (the interpret-mode
 sweeps do) or times (only a chip run does).
 """
@@ -36,13 +38,17 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def v5e_2x2():
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler on this box
         pytest.skip(f"cannot describe v5e:2x2 here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -501,3 +507,96 @@ def test_hybrid_cell_programs_fit_the_chip(v5e, program):
     assert text.count("tpu_custom_call") == (3 if last else 1)
     # the pools and the state are updated in place: donated and aliased
     assert m.alias_size_in_bytes > 3 * 2**30
+
+
+# -- the train cell's step -------------------------------------------------------
+
+def _train_cell_step(topo):
+    """`internlm2-train-fsdp4`'s step as `benchmark/harness/drivers/
+    train_step.py` builds it (published InternLM2-1.8B widths, 24 layers,
+    `{"fsdp": 4}`, batch 16 x 4,097, AdamW, `remat_policy="full"`,
+    `loss_chunk` 1024, bf16 activations over f32 weights), compiled whole
+    for the four described chips with the flash kernel selected."""
+    import numpy as np
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ray_tpu.models import (LlamaConfig, llama_init, llama_loss,
+                                llama_param_specs)
+    from ray_tpu.models.training import (batch_sharding_fn,
+                                         make_sharded_train_step)
+    from ray_tpu.parallel import create_mesh
+
+    cfg = LlamaConfig(vocab_size=92544, dim=2048, n_layers=24, n_heads=16,
+                      n_kv_heads=8, ffn_dim=8192, max_seq_len=4096,
+                      rope_theta=1e6, dtype=jnp.bfloat16,
+                      param_dtype=jnp.float32, remat=True,
+                      remat_policy="full", attn_impl="flash",
+                      loss_chunk=1024)
+    mesh = create_mesh({"fsdp": 4}, topo.devices)
+    specs = llama_param_specs(cfg)
+    shapes = jax.eval_shape(lambda: llama_init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        shapes, specs)
+    # the optimizer state sharded like the parameters it mirrors, as the
+    # benchmark's driver places it
+    by_shape = {(a.shape, a.dtype): a.sharding
+                for a in jax.tree.leaves(params)}
+    opt = optax.adamw(3e-4, weight_decay=0.1)
+    opt_state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=by_shape.get(
+                (a.shape, a.dtype), NamedSharding(mesh, PartitionSpec()))),
+        jax.eval_shape(opt.init, shapes))
+    tokens = np.zeros((16, 4097), np.int32)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        tokens.shape, tokens.dtype,
+        sharding=batch_sharding_fn(mesh, ("batch", None))(tokens))}
+    _, step_fn = make_sharded_train_step(
+        lambda p, b: llama_loss(p, b, cfg), opt, mesh, specs)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = step_fn.lower(params, opt_state, batch)
+    return lowered.compile()
+
+
+def _makers(text, type_):
+    """Opcodes of the instructions whose result is one array of exactly
+    `type_`, the tuples and loops that pass it on left out."""
+    return sorted(op for types, op in _HLO_LINE.findall(text)
+                  if op not in _PLUMBING
+                  and re.fullmatch(re.escape(type_) + r"(\{\S*)?", types))
+
+
+def test_train_cell_step_keeps_the_flash_residuals_once(v5e_2x2):
+    """A rematerialised layer never re-runs the attention kernel: the
+    step holds THREE Pallas calls (forward, backward dq, backward dk/dv;
+    four while the backward pass re-ran the forward, before PR 32). What
+    is kept instead is, a chip, one `bf16[24, 4, 16, 4096, 128]` stack of
+    kernel outputs (1.50 GiB) and one compact `f32[24, 4, 16, 4096]` stack
+    of row statistics (24 MiB): each allocated once and written by one
+    in-place `dynamic-update-slice` fusion, no copy, no transposed twin,
+    and no stack of the kernel's own `[..., 4096, 1]` layout (which a
+    tiled layout pads 128-fold).
+
+    Memory, two accountings. What the chip's loader reserves is the
+    arguments plus ONE heap, `peak_memory_in_bytes`: 12.55 GiB of the
+    chip's 15.75 (11.03 before PR 32: the difference is the two stacks),
+    which the chip confirmed (a step still ran beside 3,200 MiB of
+    ballast a chip and not beside 3,328; PERF.md section 6, PR 32).
+    `argument_size + temp_size` reads 15.72 GiB (13.03 before): it counts
+    every scan-stacked residual twice, once a loop, so it overstates
+    what is reserved by 3.2 GiB here; it is held under the chip's memory
+    all the same, because it is the figure earlier notes quote."""
+    compiled = _train_cell_step(v5e_2x2)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    in_place = ["custom-call", "dynamic-update-slice", "fusion"]
+    assert _makers(text, "bf16[24,4,16,4096,128]") == in_place
+    assert _makers(text, "f32[24,4,16,4096]") == in_place
+    assert "[24,4,16,4096,1]" not in text
+    m = compiled.memory_analysis()
+    gib = float(1 << 30)
+    assert m.peak_memory_in_bytes / gib < 12.6
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) / gib < 15.75
