@@ -23,6 +23,7 @@ from ray_tpu.models.vit import (
     vit_param_specs,
 )
 from ray_tpu.models.hybrid import HybridConfig, hybrid_init
+from ray_tpu.models.mla import MlaConfig, mla_init
 from ray_tpu.models.moe import (
     MoeConfig,
     moe_init,
@@ -95,6 +96,8 @@ __all__ = [
     "mlp_forward",
     "HybridConfig",
     "hybrid_init",
+    "MlaConfig",
+    "mla_init",
     "MoeConfig",
     "moe_init",
     "moe_ffn_dropless",

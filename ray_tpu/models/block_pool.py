@@ -21,7 +21,37 @@ out by ``alloc``.
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Any, List, NamedTuple, Optional
+
+
+class CachePlane(NamedTuple):
+    """One array of a family's cache: what a token stores in the layers
+    that write it. A config answers `cache_planes()` with these, and the
+    engine sizes its pools, prices a block and reports its geometry from
+    them alone. Planes of one ``table`` advance together behind one block
+    table a row and one `BlockPool`."""
+
+    name: str        # "k" | "v" | "latent" | "index" | "window_k" | ...
+    table: str       # "full" | "window"
+    layers: int      # layers that write this plane
+    lanes: int       # values a token stores in one of them, as laid out
+    dtype: Any
+
+    def block_bytes(self, block_tokens: int,
+                    itemsize: Optional[int] = None) -> int:
+        """Device bytes of one block of this plane, every layer of it."""
+        size = self.dtype.itemsize if itemsize is None else itemsize
+        return self.layers * block_tokens * self.lanes * size
+
+
+def kv_planes(table: str, layers: int, kv_heads: int, head_dim: int,
+              dtype, prefix: str = "") -> tuple:
+    """The K and V planes of ``layers`` attention layers that store
+    ``kv_heads`` heads of ``head_dim`` a token, head-major in one lane
+    axis."""
+    lanes = kv_heads * head_dim
+    return (CachePlane(prefix + "k", table, layers, lanes, dtype),
+            CachePlane(prefix + "v", table, layers, lanes, dtype))
 
 
 class BlockPool:

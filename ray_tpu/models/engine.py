@@ -96,10 +96,12 @@ from ray_tpu.models import hybrid as _hybrid
 from ray_tpu.models.generate import (_check_sampling_knobs,
                                      _layer_body, sample_rows)
 from ray_tpu.models.hybrid import HybridConfig
+from ray_tpu.models import mla as _mla
+from ray_tpu.models.mla import MlaConfig
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
 from ray_tpu.models.moe import MoeConfig
-from ray_tpu.models.prefix_cache import PrefixCacheIndex, block_bytes
+from ray_tpu.models.prefix_cache import PrefixCacheIndex
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention, spmd_mesh_scope
 from ray_tpu.ops.kv_quant import (KVQuantSpec, paged_quant_write,
@@ -217,24 +219,41 @@ class _EngineShardings:
 # decode programs add to them over LIVE rows only; a decode program
 # also appends them to its token block as `_MOE_CTR_ROWS` more rows, so
 # they reach the host in the pull that fetches the tokens anyway. A
-# dense model passes None everywhere: no leaves, the same programs.
+# dense model passes None everywhere: no leaves, the same programs. A
+# layer that holds a share of its experts (`held_experts`) counts one
+# thing more, after those four: the live assignments that landed on an
+# expert held here.
 _MOE_CTR_ROWS = 4
+_MOE_CTR_NAMES = ("moe_assignments_total", "moe_rows_computed_total",
+                  "moe_decode_experts_hit_total",
+                  "moe_decode_layer_steps_total",
+                  "moe_assignments_landed_total")
+
+
+def _moe_ctr_rows(cfg) -> int:
+    """Counters an expert-layer config keeps on the device (0: none)."""
+    if not hasattr(cfg, "n_experts"):
+        return 0
+    return _MOE_CTR_ROWS + (cfg.held_experts is not None)
 
 
 def _moe_count(moe_ctr, layer_stats):
     """One decode iteration's per-layer counts [L, 3] into the [4]
-    counters; the fourth goes up by L, the expert layers that ran."""
-    return moe_ctr + jnp.concatenate(
-        [layer_stats.sum(axis=0),
-         jnp.full((1,), layer_stats.shape[0], jnp.int32)])
+    counters; the fourth goes up by L, the expert layers that ran (a
+    fourth column, the assignments that landed here, is the fifth)."""
+    s = layer_stats.sum(axis=0)
+    runs = jnp.full((1,), layer_stats.shape[0], jnp.int32)
+    if layer_stats.shape[1] == 3:
+        return moe_ctr + jnp.concatenate([s, runs])
+    return moe_ctr + jnp.concatenate([s[:3], runs, s[3:]])
 
 
 def _append_moe_ctr(toks, moe_ctr):
-    """[H, B] token block -> [H + _MOE_CTR_ROWS, B]: counter i fills
+    """[H, B] token block -> [H + len(moe_ctr), B]: counter i fills
     row H + i, so the drain's one transfer carries both."""
     return jnp.concatenate(
         [toks, jnp.broadcast_to(moe_ctr[:, None],
-                                (_MOE_CTR_ROWS, toks.shape[1]))])
+                                (moe_ctr.shape[0], toks.shape[1]))])
 
 
 def _prefill_live(rows, last_idx, chunk: int):
@@ -360,21 +379,26 @@ def _gather_pages(pools, ids):
     return tuple(out.reshape(L, *ids.shape, T, W) for out in outs)
 
 
-def _zero_pools(n_layers: int, n_blocks: int, block_tokens: int,
-                kv_heads: int, head_dim: int, dtype, quantized: bool,
+def _zero_pools(planes, n_blocks: int, block_tokens: int, kv_heads: int,
+                dtype, quantized: bool,
                 shardings: Optional[_EngineShardings]):
-    """A plane's zeroed (pool_k, pool_v, scale_k, scale_v): pools
-    [L, NB, T, KV*D] — the layout the decode kernel reads, one page one
-    contiguous [T, KV*D] slab, heads merged head-major — and, quantized,
+    """The zeroed (pool_k, pool_v, scale_k, scale_v) of the two
+    `CachePlane`s behind one table: pools [L, NB, T, lanes] — K and V
+    ``KV*D`` wide, the layout the decode kernel reads, one page one
+    contiguous [T, KV*D] slab, heads merged head-major; or whatever two
+    planes the config names — and, quantized,
     their f32 scale slabs [L, NB, KV] (else None). Zero scales: dequant
     of the zero-initialised pool (incl. the null block) is exactly 0.0
     everywhere. Under a mesh both are placed by ``shardings`` (the
     plane's own pool/scale in the primary slots)."""
-    shape = (n_layers, n_blocks, block_tokens, kv_heads * head_dim)
-    out = [jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)]
+    if len(planes) != 2:
+        raise ValueError("the engine's programs carry two planes behind a "
+                         f"row's table, the config names {len(planes)}")
+    out = [jnp.zeros((pl.layers, n_blocks, block_tokens, pl.lanes), dtype)
+           for pl in planes]
     if quantized:
-        out += [jnp.zeros((n_layers, n_blocks, kv_heads), jnp.float32)
-                for _ in range(2)]
+        out += [jnp.zeros((pl.layers, n_blocks, kv_heads), jnp.float32)
+                for pl in planes]
     else:
         out += [None, None]
     if shardings is not None:
@@ -480,8 +504,11 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
         n_valid=last_idx + 1, hyb=hyb, bt_w=bt_w, rows=rows,
         last_idx=last_idx, final=final)
     if moe_ctr is not None:
-        moe_ctr = moe_ctr.at[:2].add(moe_stats.sum(axis=0)[:2])
-    if hyb is None:
+        seen = moe_stats.sum(axis=0)
+        moe_ctr = moe_ctr.at[:2].add(seen[:2])
+        if seen.shape[0] > 3:         # held experts: what landed here
+            moe_ctr = moe_ctr.at[_MOE_CTR_ROWS:].add(seen[3:])
+    if _own_stack(cfg) is None:
         h = h[jnp.arange(n), last_idx][:, None]
     if final:
         # the final norm and lm_head see the ONE position a row is read at
@@ -573,6 +600,18 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
                        lora_slots=lora_slots, moe_live=moe_live)
 
 
+def _own_stack(cfg):
+    """The module of a family whose layers are not `generate._layer_body`'s
+    and that brings its own stack (`layers_paged`, `lm_head`) against the
+    engine's pools: `hybrid` for a `HybridConfig`, `mla` for an
+    `MlaConfig`; None for the dense and sparse families."""
+    if isinstance(cfg, HybridConfig):
+        return _hybrid
+    if isinstance(cfg, MlaConfig):
+        return _mla
+    return None
+
+
 def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
                   bt, starts, cfg: LlamaConfig, adapters=None,
                   row_slot=None, scale_k=None, scale_v=None,
@@ -602,7 +641,15 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     `hybrid.layers_paged`, per-period scans built from the config's
     `layer_plan`, over this pool (its full-attention layer's), its own
     state ``hyb`` and the window table ``bt_w``; the seventh result is
-    that state (None for the other families, whose scan is below)."""
+    that state (None for the other families, whose scan is below). An
+    `MlaConfig`'s is `mla.layers_paged`, two scans from its `layer_plan`
+    over these two pools as its latent and index planes; it has no other
+    state and counts its expert layers like an `MoeConfig`."""
+    if isinstance(cfg, MlaConfig):
+        h, pool_k, pool_v, moe_stats = _mla.layers_paged(
+            params, toks, pool_k, pool_v, bt, starts, cfg,
+            moe_live=moe_live, n_valid=n_valid, last_idx=last_idx)
+        return h, pool_k, pool_v, scale_k, scale_v, moe_stats, None
     if hyb is not None:
         if live is None:
             live = jnp.arange(toks.shape[1])[None, :] < n_valid[:, None]
@@ -636,8 +683,9 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
 
 def _lm_head(params: Params, h: jax.Array, cfg: LlamaConfig):
     """Final norm and vocab projection: [B, S, d] -> f32 [B, S, vocab]."""
-    if isinstance(cfg, HybridConfig):
-        return _hybrid.lm_head(params, h, cfg)
+    own = _own_stack(cfg)
+    if own is not None:
+        return own.lm_head(params, h, cfg)
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         return jnp.einsum("bsd,dv->bsv", h,
@@ -1142,8 +1190,14 @@ class DecodeEngine:
       max_queue + on_full ("reject"|"block") — bounded queue
         backpressure: reject raises EngineOverloaded, block drives
         step() until a queue slot frees;
-      max_prefills_per_step — per-step prefill admission budget so a
-        burst of long prompts cannot starve in-flight decode rows.
+      max_prefills_per_step — how many rows may prefill in a step, so
+        that a burst of long prompts cannot starve in-flight decode
+        rows. A row mid-prompt advances a chunk every step and counts:
+        the gate admits only while fewer rows are mid-prompt, so a
+        step's prefill is never a larger group than this (with prompts
+        many chunks long the admissions alone would let it grow to
+        every slot, 24 rows x 512 tokens in one program, and decode
+        wait behind it);
 
     Tensor parallelism: ``tp=n`` (or a prebuilt ``mesh=`` with a "tp"
     axis) shards the model weights, the KV block pool and the fused
@@ -1297,6 +1351,33 @@ class DecodeEngine:
                 if bad:
                     raise ValueError(
                         f"a HybridConfig cannot be served with {what}")
+        # An `MlaConfig` (latent attention with selection, held experts)
+        # likewise: what would need its two planes shared, quantized,
+        # moved or split is refused here, by name.
+        if isinstance(cfg, MlaConfig):
+            for bad, what in (
+                    (prefix_cache, "prefix_cache=True: the trie's copy-on-"
+                     "write and eviction know K and V planes of one width, "
+                     "not a latent and an index plane (ROADMAP M3)"),
+                    (kv_quant is not None, "kv_quant=: the latent and index "
+                     "planes have no quantized write, and a quantized "
+                     "indexer key changes what is selected (ROADMAP M3)"),
+                    (preempt == "swap", "preempt='swap': the swap ledger "
+                     "gathers and scatters K and V planes of one width "
+                     "(pass preempt='recompute'; ROADMAP M3)"),
+                    (tp is not None or mesh is not None, "tp=/mesh=: the "
+                     "latent is ONE head's cache and the held experts have "
+                     "no exchange: neither has a sharding rule (ROADMAP "
+                     "M2)"),
+                    (lora is not None, "lora=: the adapter targets name "
+                     "the dense family's projections"),
+                    (draft_params is not None or draft_cfg is not None,
+                     "draft_params=/draft_cfg=: the verify window has no "
+                     "selection per drafted token, and the model's own "
+                     "drafting head is not built (ROADMAP M7)")):
+                if bad:
+                    raise ValueError(
+                        f"an MlaConfig cannot be served with {what}")
         if draft_cfg is not None and \
                 isinstance(draft_cfg, MoeConfig) != sparse:
             raise ValueError(
@@ -1484,10 +1565,10 @@ class DecodeEngine:
         # Expert-layer counters (see `_moe_count`): the device's wrapping
         # int32 [4], what the host last saw of them, and the unwrapped
         # totals `stats()` reports. None/zeros for a dense model.
-        self._moe_ctr = jnp.zeros((_MOE_CTR_ROWS,), jnp.int32) \
-            if sparse else None
-        self._moe_seen = np.zeros((_MOE_CTR_ROWS,), np.uint32)
-        self._moe_totals = np.zeros((_MOE_CTR_ROWS,), np.int64)
+        n_ctr = _moe_ctr_rows(cfg)
+        self._moe_ctr = jnp.zeros((n_ctr,), jnp.int32) if n_ctr else None
+        self._moe_seen = np.zeros((n_ctr,), np.uint32)
+        self._moe_totals = np.zeros((n_ctr,), np.int64)
         self.row_len = np.zeros((self.B,), np.int32)   # written slots
         self.row_req: List[Optional[_Request]] = [None] * self.B
         self.row_budget = np.zeros((self.B,), np.int32)
@@ -1576,20 +1657,24 @@ class DecodeEngine:
         # full-attention layer's, which its cross-attention layers read
         # too; its window layers' pool and its recurrent state follow
         # below.)
-        L, KV, D = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        if hybrid:
-            L = 1
+        # What a token stores is the config's answer (`cache_planes`):
+        # the two planes behind the row's table are this pool's two
+        # arrays (K and V; an `MlaConfig`'s latent and index planes, of
+        # unlike widths and with no V), and a block's bytes are theirs.
+        self._planes = tuple(pl for pl in cfg.cache_planes()
+                             if pl.table == "full")
         T = kv_block_tokens
         if self.kv_quant_spec is not None:
             # Quantized pool: 1-byte values + the per-block scale
             # slab's footprint (2 slabs x L x KV f32 scales per
             # block) — the ~2x concurrency-per-HBM-byte lever.
             pool_dtype = self.kv_quant_spec.dtype
-            bb = block_bytes(L, T, KV, D, self.kv_quant_spec.itemsize) \
-                + 2 * L * KV * 4
+            bb = sum(pl.block_bytes(T, self.kv_quant_spec.itemsize)
+                     + pl.layers * cfg.n_kv_heads * 4
+                     for pl in self._planes)
         else:
             pool_dtype = jnp.dtype(cfg.dtype)
-            bb = block_bytes(L, T, KV, D, pool_dtype.itemsize)
+            bb = sum(pl.block_bytes(T) for pl in self._planes)
         self.kv_bytes_per_block = float(bb)
         self.kv_bytes_per_token = bb / T
         if kv_pool_bytes is None:
@@ -1605,8 +1690,9 @@ class DecodeEngine:
         self._row_admit_seq = np.zeros((self.B,), np.int64)
         (self._pool_k, self._pool_v, self._scale_k,
          self._scale_v) = _zero_pools(
-            L, n_blocks, T, KV, D, pool_dtype,
-            self.kv_quant_spec is not None, shardings=self._shardings)
+            self._planes, n_blocks, T, getattr(cfg, "n_kv_heads", 1),
+            pool_dtype, self.kv_quant_spec is not None,
+            shardings=self._shardings)
         # A `HybridConfig`'s other device state: the WINDOW plane (a
         # second `BlockPool` and table over a pool of its own geometry,
         # `n_window_layers` deep; a row holds only the blocks that
@@ -1617,6 +1703,10 @@ class DecodeEngine:
         self._hybrid = hybrid
         self._hyb: Optional[Params] = None
         self.kv_pool_w: Optional[BlockPool] = None
+        self.indexer_tokens_scored_total = 0   # token-layers, decode and
+        self.indexer_tokens_selected_total = 0  # prefill (an MlaConfig)
+        self.indexer_decode_tokens_scored_total = 0    # ... decode alone
+        self.indexer_decode_tokens_selected_total = 0
         self.kv_walk_tokens_window_total = 0   # token-layers decode asks
         self.kv_walk_tokens_full_total = 0     # ... per READER of the pool
         self.window_blocks_freed_total = 0     # released behind the window
@@ -1686,8 +1776,8 @@ class DecodeEngine:
                 [] for _ in range(self.B)]
             (self._pool_dk, self._pool_dv, self._scale_dk,
              self._scale_dv) = _zero_pools(
-                draft_cfg.n_layers, n_blocks_d, T, draft_cfg.n_kv_heads,
-                draft_cfg.head_dim,
+                draft_cfg.cache_planes(), n_blocks_d, T,
+                draft_cfg.n_kv_heads,
                 self.kv_quant_spec.dtype if self.kv_quant_spec is not None
                 else jnp.dtype(draft_cfg.dtype),
                 self.kv_quant_spec is not None,
@@ -1990,8 +2080,9 @@ class DecodeEngine:
 
     def step(self, horizon: Optional[int] = None) -> Dict[int, List[int]]:
         """Admit queued requests into free slots (at most
-        max_prefills_per_step of them, same-bucket admissions batched
-        into one prefill program each), then advance every live slot up
+        max_prefills_per_step of them less the rows still mid-prompt,
+        same-bucket admissions batched into one prefill program each),
+        then advance every live slot up
         to `horizon` tokens in ONE fused device program with ONE
         device->host transfer. Returns {req_id: [tokens]} emitted this
         step — up to `horizon` per request; a request that finishes
@@ -2039,7 +2130,9 @@ class DecodeEngine:
         # a second newcomer then waits for one block, not for two).
         flushed = bool(emitted)
         with self.trace.lane("admit", "admit") as admit:
-            budget = self.max_prefills_per_step or self.B
+            # rows mid-prompt prefill a chunk this step too
+            budget = (self.max_prefills_per_step or self.B) \
+                - len(self._row_prefill)
             admissions: List[Tuple[int, _Request]] = []
             begin = getattr(self.scheduler, "begin_admission_round", None)
             if begin is not None:
@@ -2385,6 +2478,8 @@ class DecodeEngine:
         self.paged_walk_pages_total += int(
             np.minimum(slots // T + 1, self._mb).sum())
         self.paged_walk_entries_total += H * self.B * self._mb
+        if isinstance(self.cfg, MlaConfig):
+            self._count_selection(slots + 1, decode=True)
         if self._hybrid:
             # tokens the kernel is asked to read, a token-layer each: the
             # full layer's cache once a READER (itself and every
@@ -2395,6 +2490,20 @@ class DecodeEngine:
             self.kv_walk_tokens_window_total += int(np.minimum(
                 slots + 1, cfg.sliding_window).sum()) * cfg.n_window_layers
             self.ssm_row_steps_total += H * len(rows)
+
+    def _count_selection(self, live: np.ndarray,
+                         decode: bool = False) -> None:
+        """Account the queries of one dispatch of an `MlaConfig`: each
+        sees ``live`` tokens, the indexer scores them all and attention
+        reads `index_topk` of them at most, in every layer."""
+        cfg = self.cfg
+        scored = int(live.sum()) * cfg.n_layers
+        selected = int(np.minimum(live, cfg.index_topk).sum()) * cfg.n_layers
+        self.indexer_tokens_scored_total += scored
+        self.indexer_tokens_selected_total += selected
+        if decode:
+            self.indexer_decode_tokens_scored_total += scored
+            self.indexer_decode_tokens_selected_total += selected
 
     def _count_prefill_walk(self, starts: np.ndarray,
                             last_idx: np.ndarray, bucket: int) -> None:
@@ -2412,6 +2521,11 @@ class DecodeEngine:
         kernel, and is counted)."""
         from ray_tpu.ops.paged_attention_kernel import walk_shape
 
+        if isinstance(self.cfg, MlaConfig):
+            real = np.arange(bucket)[None, :] <= last_idx[:, None]
+            live = starts[:, None] + np.arange(bucket)[None, :] + 1
+            self._count_selection(live[real])
+            return
         if self.kv_quant_spec is not None or self._hybrid or (
                 self.mesh is not None and self.mesh.size > 1):
             return
@@ -2653,12 +2767,25 @@ class DecodeEngine:
         # Expert-layer plane (an `MoeConfig`; identically 0.0 for a dense
         # model): counted on the device over live rows, as of the last
         # token block drained. Speculative rounds are not counted.
-        for name, n in zip(("moe_assignments_total",
-                            "moe_rows_computed_total",
-                            "moe_decode_experts_hit_total",
-                            "moe_decode_layer_steps_total"),
-                           self._moe_totals):
+        # `moe_assignments_landed_total`: those of them that landed on an
+        # expert HELD here (`held_experts`; all of them where all are).
+        totals = list(self._moe_totals) + [0] * len(_MOE_CTR_NAMES)
+        for name, n in zip(_MOE_CTR_NAMES, totals):
             out[name] = float(n)
+        if len(self._moe_totals) <= _MOE_CTR_ROWS:
+            out["moe_assignments_landed_total"] = \
+                out["moe_assignments_total"]
+        # Selection plane (an `MlaConfig`; identically 0.0 otherwise):
+        # token-layers the indexer scored and those it selected for
+        # attention, host estimates at dispatch like the paged-walk ones.
+        out["indexer_tokens_scored_total"] = float(
+            self.indexer_tokens_scored_total)
+        out["indexer_tokens_selected_total"] = float(
+            self.indexer_tokens_selected_total)
+        out["indexer_decode_tokens_scored_total"] = float(
+            self.indexer_decode_tokens_scored_total)
+        out["indexer_decode_tokens_selected_total"] = float(
+            self.indexer_decode_tokens_selected_total)
         # Hybrid plane (a `HybridConfig`; identically 0.0 otherwise):
         # host estimates at dispatch, like the paged-walk ones.
         for name in ("kv_walk_tokens_window_total",
@@ -3599,6 +3726,11 @@ class DecodeEngine:
         return rid
 
     def _refuse_handoff(self, what: str) -> None:
+        if isinstance(self.cfg, MlaConfig):
+            raise ValueError(
+                f"an MlaConfig cannot be served with {what}: a hand-off "
+                "carries K and V planes of one width, not a latent and an "
+                "index plane (ROADMAP M3)")
         if self._hybrid:
             raise ValueError(
                 f"a HybridConfig cannot be served with {what}: a hand-off "
@@ -3606,10 +3738,11 @@ class DecodeEngine:
                 "its window blocks")
 
     @property
-    def _kv_geometry(self) -> Tuple[int, int, int]:
-        """(layers, KV heads, head dim): what a handoff's K/V payload
-        ``[L, n, T, KV*D]`` and scales ``[L, n, KV]`` must agree on."""
-        return (self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim)
+    def _kv_geometry(self) -> Tuple[Tuple[str, int, int], ...]:
+        """(name, layers, lanes) of each plane behind a row's table
+        (`cache_planes`): what a handoff's payload ``[L, n, T, lanes]``
+        must agree on."""
+        return tuple((pl.name, pl.layers, pl.lanes) for pl in self._planes)
 
     def _release_row_blocks(self, row: int) -> None:
         """Drop the row's reference on its chain (trie-shared blocks

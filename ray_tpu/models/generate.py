@@ -21,6 +21,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models import hybrid as _hybrid
 from ray_tpu.models.hybrid import HybridConfig
+from ray_tpu.models import mla as _mla
+from ray_tpu.models.mla import MlaConfig
 from ray_tpu.models.llama import LlamaConfig, _rmsnorm, _rope
 from ray_tpu.models.moe import MoeConfig, moe_ffn_dropless, qk_norm
 from ray_tpu.ops import scope_names as sn
@@ -42,6 +44,8 @@ def init_cache(cfg: LlamaConfig, batch_size: int,
     max_len = max_len or cfg.max_seq_len
     if isinstance(cfg, HybridConfig):
         return _hybrid.init_cache(cfg, batch_size, max_len)
+    if isinstance(cfg, MlaConfig):
+        return _mla.init_cache(cfg, batch_size, max_len)
     shape = (cfg.n_layers, batch_size, max_len, cfg.n_kv_heads,
              cfg.head_dim)
     cache = {"k": jnp.zeros(shape, cfg.dtype),
@@ -233,6 +237,14 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
                 "(prompt_live=): a state-space layer consumes every token "
                 "it is fed; batch prompts of one length, or use the engine")
         return _hybrid.forward_cached(params, tokens, cache, start, cfg)
+    if isinstance(cfg, MlaConfig):
+        if slot_live is not None:
+            raise ValueError(
+                "an MlaConfig cannot generate from left-padded prompts "
+                "(prompt_live=): its rotary positions are its cache slots "
+                "and the indexer scores every slot below a query; batch "
+                "prompts of one length, or use the engine")
+        return _mla.forward_cached(params, tokens, cache, start, cfg)
     B, S = tokens.shape
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(cfg.dtype)[tokens]

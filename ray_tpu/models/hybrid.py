@@ -59,6 +59,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.block_pool import kv_planes
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention
 
@@ -69,9 +70,9 @@ class LayerKind(NamedTuple):
     """What a layer is made of: its mixer, the cache it WRITES (None: it
     writes none), the cache it reads, and the recurrent state it owns."""
 
-    mixer: str                     # "ssm" | "attn" | "gmu" | "cross"
-    writes: Optional[str] = None   # "window" | "full"
-    reads: Optional[str] = None    # "window" | "full"
+    mixer: str                     # "ssm" | "attn" | "gmu" | "cross" | "mla"
+    writes: Optional[str] = None   # "window" | "full" | "latent"
+    reads: Optional[str] = None    # "window" | "full" | "latent"
     state: Optional[str] = None    # "ssm"
 
 
@@ -169,6 +170,16 @@ class HybridConfig:
         """One `LayerKind` a layer, in stack order."""
         return tuple(k for seg in self.layer_plan()
                      for _ in range(seg.periods) for k in seg.kinds)
+
+    def cache_planes(self):
+        """What a token stores (`block_pool.CachePlane`): K and V of the
+        ONE full-attention layer behind the row's table (the
+        cross-attention layers read it and store nothing), and K and V of
+        the window layers behind the window table."""
+        dt = jnp.dtype(self.dtype)
+        return kv_planes("full", 1, self.n_kv_heads, self.head_dim, dt) \
+            + kv_planes("window", self.n_window_layers, self.n_kv_heads,
+                        self.head_dim, dt, prefix="window_")
 
     def prefill_layers(self) -> int:
         """Layers that see every prompt token (the rest run for the one

@@ -118,6 +118,14 @@ class LlamaConfig:
             f"unknown remat_policy {self.remat_policy!r} "
             "(expected 'full', 'save_dots', or 'save:<names>')")
 
+    def cache_planes(self):
+        """What a token stores (`block_pool.CachePlane`): keys and values
+        of every KV head in every layer."""
+        from ray_tpu.models.block_pool import kv_planes
+
+        return kv_planes("full", self.n_layers, self.n_kv_heads,
+                         self.head_dim, jnp.dtype(self.dtype))
+
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads

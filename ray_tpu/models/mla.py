@@ -1,0 +1,666 @@
+"""Latent-attention decoder LM with a learned sparse-attention indexer and
+held experts beside a shared one (the DeepSeek-V3.2-Exp shape).
+
+No layer of this family is the Llama layer, so like `hybrid` it brings its
+own stack (`layers_paged`) and shares everything around it: the serving
+engine's programs, step loop, ring and scheduler, its block pool and
+tables, chunked prefill in place, sampling, and the expert layer of
+`moe.moe_ffn_dropless` (told which experts it holds).
+
+The stack is read from the config alone (`MlaConfig.layer_plan`):
+
+    segment "dense":  n_dense_layers of [latent attention, dense FFN]
+    segment "moe":    the rest, of      [latent attention, expert layer]
+
+each ``h = h + Attn(N(h)); h = h + F(N(h))``, each segment one `lax.scan`
+over its layers with the pools in the carry.
+
+What a token stores (`MlaConfig.cache_planes`), for every layer, through
+ONE block table a row:
+
+    latent plane  [L, NB, T, 640]  ``[c 512 | k_rope 64 | 0 x 64]``: the
+                  normed latent and the ONE rotary key all heads share.
+                  576 values; the chip's tiled layout pads a 576-lane row
+                  to 640 lanes whatever is asked for, so the plane says
+                  640 and the pool's bytes are priced at that (1,280 B a
+                  token a layer, 11 % padding). There is NO value plane:
+                  values are the first 512 lanes of the same row.
+    index plane   [L, NB, T, 128]  the indexer's key ``k^I`` (256 B).
+
+Attention is in the ABSORBED form: ``q'_h = q_nope_h W_kb^h`` (512 wide)
+scores against ``c`` directly, the output is ``W_vb^h (sum_s p_s c_s)``,
+so the 128 heads read one shared row a token and nothing is expanded.
+
+Selection, per query t of a row: (1) score every live slot s <= t from
+the index plane, ``I = sum_j w_j relu(q^I_j . k^I_s)`` in float32; (2) the
+EXACT top `index_topk` of them; (3) read those slots' latent rows; (4)
+attend them. A context at or under `index_topk` attends all of it (the
+selection then picks every live slot). The selection is a MASK
+(`select_mask`: the k-th largest score by counting, no sort, and
+`lax.top_k`'s own members), a block of queries at a time, and the row's
+keys are attended densely under it: a chunk's queries would each gather
+their own 2,048 rows (1.3 GB a 512-token chunk a layer), and a decode
+token's gather and sort were half its time. `attend_chunk` lays the row's
+pages side by side (`ops/sparse_latent_attention.py`: the kernel on the
+chip, its plain form elsewhere); `attend_token`, a decode token on the
+chip, reads the pages where they lie.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models.block_pool import CachePlane
+from ray_tpu.models.hybrid import LayerKind, Segment
+from ray_tpu.models.llama import _rmsnorm
+from ray_tpu.models.moe import moe_ffn_dropless
+from ray_tpu.ops import scope_names as sn
+
+Params = Dict[str, Any]
+
+# every layer's mixer: latent attention over the slots its indexer chose
+MLA = LayerKind("mla", writes="latent", reads="latent")
+
+# Queries are scored and selected this many a row at a time: a block's
+# indexer scores [rows, block, heads, max_len] float32 are the program's
+# largest temporary.
+_QUERY_BLOCK = 16
+_LANE = 128
+# std of the SEEDED selection bias (`mla_init`; a checkpoint brings its
+# own). The published bias is trained until the experts' loads are even; a
+# seeded one unbalances them instead: at std 0.1 the share of assignments
+# that landed on 16 held experts of 256 swung 4.0-7.5 % between seeds where
+# 6.25 is even, and a cell's throughput with it (PERF.md PR 33); at 0.003
+# it is 6.29 +- 0.1. Not zero: a program that drops the bias moves logits.
+_ROUTER_BIAS_STD = 0.003
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaConfig:
+    vocab_size: int = 129280
+    dim: int = 7168
+    n_layers: int = 61
+    n_dense_layers: int = 3            # first_k_dense_replace
+    n_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    ffn_dim: int = 18432               # a leading dense layer's FFN
+    expert_dim: int = 2048             # one expert's (moe_intermediate_size)
+    n_experts: int = 256               # routed, over the whole deployment
+    n_shared_experts: int = 1
+    top_k: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    # (lo, hi): the routed experts THIS program holds and computes; the
+    # router is over all `n_experts` whatever this says. None: all.
+    held_experts: Optional[Tuple[int, int]] = None
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    # yarn: (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale, mscale_all_dim); None: plain rotary
+    rope_scaling: Optional[Tuple[float, int, float, float, float, float]] = \
+        (40.0, 4096, 32.0, 1.0, 1.0, 1.0)
+    max_seq_len: int = 163840
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    router = "sigmoid_grouped"         # `moe.moe_ffn_dropless` reads it
+
+    def __post_init__(self):
+        if not 0 <= self.n_dense_layers < self.n_layers:
+            raise ValueError("MlaConfig: n_dense_layers must leave at "
+                             "least one expert layer")
+        if self.n_experts % self.n_group or self.topk_group > self.n_group:
+            raise ValueError("MlaConfig: n_group must divide n_experts and "
+                             "topk_group cannot exceed it")
+        if self.n_experts // self.n_group < 2:
+            raise ValueError("MlaConfig: a group's score is the sum of its "
+                             "two largest: groups of at least 2 experts")
+        if self.held_experts is not None:
+            lo, hi = self.held_experts
+            if not 0 <= lo < hi <= self.n_experts:
+                raise ValueError("MlaConfig: held_experts (lo, hi) must be "
+                                 "a non-empty range of the routed experts")
+        if self.qk_rope_head_dim % 2 \
+                or self.qk_rope_head_dim > self.index_head_dim:
+            raise ValueError("MlaConfig: the rotary width must be even and "
+                             "fit the indexer's head")
+
+    # -- what `moe_ffn_dropless` and the engine read -----------------------
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_experts or (0, self.n_experts)
+        return hi - lo
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes of a latent row as stored: kv_lora_rank + rotary, up to
+        the lane tile (576 -> 640)."""
+        w = self.kv_lora_rank + self.qk_rope_head_dim
+        return -(-w // _LANE) * _LANE
+
+    @property
+    def sm_scale(self) -> float:
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.rope_scaling and self.rope_scaling[5]:
+            m = 0.1 * self.rope_scaling[5] * math.log(self.rope_scaling[0]) \
+                + 1.0 if self.rope_scaling[0] > 1 else 1.0
+            s *= m * m
+        return s
+
+    def layer_plan(self) -> Tuple[Segment, ...]:
+        """The stack as segments, in `hybrid.Segment`'s terms: the ONE
+        description the scans, the pools and the counters are built from."""
+        segs = []
+        if self.n_dense_layers:
+            segs.append(Segment("dense", (MLA,), self.n_dense_layers, 0))
+        segs.append(Segment("moe", (MLA,), self.n_moe_layers,
+                            self.n_dense_layers))
+        return tuple(segs)
+
+    def cache_planes(self) -> Tuple[CachePlane, ...]:
+        """What a token stores: a latent row and an indexer key in every
+        layer, behind one table; no value plane."""
+        return (CachePlane("latent", "full", self.n_layers,
+                           self.latent_lanes, jnp.dtype(self.dtype)),
+                CachePlane("index", "full", self.n_layers,
+                           self.index_head_dim, jnp.dtype(self.dtype)))
+
+    @staticmethod
+    def deepseek_v32_exp(**kw) -> "MlaConfig":
+        """deepseek-ai/DeepSeek-V3.2-Exp, every width, all 61 layers."""
+        return MlaConfig(**kw)
+
+    @staticmethod
+    def nano_mla(**kw) -> "MlaConfig":
+        defaults = dict(vocab_size=256, dim=64, n_layers=3, n_dense_layers=1,
+                        n_heads=4, q_lora_rank=32, kv_lora_rank=32,
+                        qk_nope_head_dim=16, qk_rope_head_dim=8,
+                        v_head_dim=16, ffn_dim=128, expert_dim=32,
+                        n_experts=16, top_k=4, n_group=4, topk_group=2,
+                        index_n_heads=4, index_head_dim=16, index_topk=16,
+                        rope_scaling=(40.0, 32, 32.0, 1.0, 1.0, 1.0),
+                        max_seq_len=256, dtype=jnp.float32,
+                        param_dtype=jnp.float32)
+        defaults.update(kw)
+        return MlaConfig(**defaults)
+
+    def _attn_params(self) -> int:
+        d, H = self.dim, self.n_heads
+        n, r, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                   self.v_head_dim)
+        rq, rc = self.q_lora_rank, self.kv_lora_rank
+        IH, ID = self.index_n_heads, self.index_head_dim
+        return (d * rq + rq + rq * H * (n + r) + d * (rc + r) + rc
+                + rc * H * (n + v) + H * v * d
+                + rq * IH * ID + d * ID + 2 * ID + d * IH + 2 * d)
+
+    def num_params(self) -> int:
+        """Parameters HELD here (held experts, this vocabulary)."""
+        d = self.dim
+        shared = 3 * d * self.expert_dim * self.n_shared_experts
+        moe = self.n_held * 3 * d * self.expert_dim + shared \
+            + d * self.n_experts + self.n_experts
+        return (2 * self.vocab_size * d + d
+                + self.n_layers * self._attn_params()
+                + self.n_dense_layers * 3 * d * self.ffn_dim
+                + self.n_moe_layers * moe)
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+def mla_init(key: jax.Array, cfg: MlaConfig) -> Params:
+    """Random weights. Matrices normal with std ``fan_in ** -0.5``, the
+    embedding std 0.02, RMSNorm weights 1; the indexer key's LayerNorm
+    bias normal std 0.02 and the router's selection bias normal std
+    `_ROUTER_BIAS_STD` (not zero: a program that drops either moves
+    logits). The expert
+    stacks hold the `held_experts` alone. Jit it with `cfg` static to
+    build a real-size model on the device in one program."""
+    d, H = cfg.dim, cfg.n_heads
+    n, r, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rq, rc = cfg.q_lora_rank, cfg.kv_lora_rank
+    IH, ID = cfg.index_n_heads, cfg.index_head_dim
+    pdt = cfg.param_dtype
+    keys = iter(jax.random.split(key, 64))
+
+    def mat(lead, n_in, n_out, std=None):
+        std = n_in ** -0.5 if std is None else std
+        return (jax.random.normal(next(keys), (*lead, n_in, n_out),
+                                  jnp.float32) * std).astype(pdt)
+
+    def vec(lead, width, std):
+        return (jax.random.normal(next(keys), (*lead, width), jnp.float32)
+                * std).astype(pdt)
+
+    def ones(lead, width):
+        return jnp.ones((*lead, width), pdt)
+
+    def attn(lead):
+        return {
+            "attn_norm": ones(lead, d), "mlp_norm": ones(lead, d),
+            "wq_a": mat(lead, d, rq), "q_norm": ones(lead, rq),
+            "wq_b": mat(lead, rq, H * (n + r)),
+            "wkv_a": mat(lead, d, rc + r), "kv_norm": ones(lead, rc),
+            "wk_b": mat(lead, rc, H * n), "wv_b": mat(lead, rc, H * v),
+            "wo": mat(lead, H * v, d),
+            "wi_q": mat(lead, rq, IH * ID), "wi_k": mat(lead, d, ID),
+            "ik_norm_w": ones(lead, ID), "ik_norm_b": vec(lead, ID, 0.02),
+            "wi_w": mat(lead, d, IH),
+        }
+
+    def dense(lead):
+        f = cfg.ffn_dim
+        return {**attn(lead), "w_gate": mat(lead, d, f),
+                "w_up": mat(lead, d, f), "w_down": mat(lead, f, d)}
+
+    def moe(lead):
+        f, eh = cfg.expert_dim, cfg.n_held
+        fs = f * cfg.n_shared_experts
+        out = {**attn(lead), "w_router": mat(lead, d, cfg.n_experts),
+               "router_bias": vec(lead, cfg.n_experts, _ROUTER_BIAS_STD)
+               .astype(jnp.float32),
+               "we_gate": mat((*lead, eh), d, f),
+               "we_up": mat((*lead, eh), d, f),
+               "we_down": mat((*lead, eh), f, d)}
+        if fs:
+            out.update(ws_gate=mat(lead, d, fs), ws_up=mat(lead, d, fs),
+                       ws_down=mat(lead, fs, d))
+        return out
+
+    params = {
+        "tok_embed": mat((), cfg.vocab_size, d, std=0.02),
+        "moe": moe((cfg.n_moe_layers,)),
+        "final_norm": ones((), d),
+        "lm_head": mat((), d, cfg.vocab_size),
+    }
+    if cfg.n_dense_layers:
+        params["dense"] = dense((cfg.n_dense_layers,))
+    return params
+
+
+# ---------------------------------------------------------------------------
+# Rotary
+# ---------------------------------------------------------------------------
+
+def yarn_inv_freq(cfg: MlaConfig) -> np.ndarray:
+    """[qk_rope_head_dim / 2] float32 inverse frequencies: plain rotary's,
+    blended with their `factor`-times slower copies by yarn's ramp between
+    the correction dimensions of `beta_fast` and `beta_slow` rotations at
+    the original context length (as `deepseek_v3` computes them)."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    pos = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not cfg.rope_scaling:
+        return (1.0 / pos).astype(np.float32)
+    factor, orig, beta_fast, beta_slow = cfg.rope_scaling[:4]
+
+    def correction_dim(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1 - np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    return ((1.0 / (factor * pos)) * (1 - extra)
+            + (1.0 / pos) * extra).astype(np.float32)
+
+
+def _rope_pairs(x, cos, sin, interleaved: bool):
+    """Rotate the last axis of ``x`` [..., r] by the angles behind
+    ``cos``/``sin`` [..., r / 2]: on pairs (2i, 2i + 1) when `interleaved`
+    (the attention's queries and key), else on (i, i + r / 2) (the
+    indexer's). The result is laid out halves-first either way: a query
+    and the key it meets are rotated alike, and their product does not
+    see the order."""
+    if interleaved:
+        pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+        x0, x1 = pairs[..., 0], pairs[..., 1]
+    else:
+        half = x.shape[-1] // 2
+        x0, x1 = x[..., :half], x[..., half:]
+    x0, x1 = x0.astype(jnp.float32), x1.astype(jnp.float32)
+    return jnp.concatenate([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Selection inside paged attention
+# ---------------------------------------------------------------------------
+
+def indexer_scores(qi, wt, q_slots, pool_i, bt, li, cfg: MlaConfig):
+    """Step (1): ``I`` [B, Sq, span] float32 of every slot of each query's
+    row, -inf where the query may not see it (s > t, filler)."""
+    B = q_slots.shape[0]
+    span = bt.shape[1] * pool_i.shape[2]
+    with jax.named_scope(sn.INDEXER_SCORE):
+        keys = pool_i[li, bt].reshape(B, span, cfg.index_head_dim)
+        dots = jax.nn.relu(jnp.einsum("bqjd,bsd->bqjs", qi, keys,
+                                      preferred_element_type=jnp.float32))
+        scores = jnp.einsum("bqj,bqjs->bqs", wt, dots)
+        seen = jnp.arange(span)[None, None, :] <= q_slots[:, :, None]
+        return jnp.where(seen, scores, -jnp.inf)
+
+
+def kth_largest(scores, k: int):
+    """The k-th largest value of each row of ``scores`` [..., n] float32,
+    EXACTLY, without sorting: the float's bits as an order-preserving
+    unsigned key, and the largest key that at least k entries reach,
+    built a bit at a time from the top (32 counts over the row). A row
+    with fewer than k entries above -inf gives -inf."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    key = jnp.where(bits >> 31 == 0, bits | jnp.uint32(1 << 31), ~bits)
+
+    def step(i, t):
+        cand = t | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = (key >= cand[..., None]).sum(-1) >= k
+        return jnp.where(enough, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, step,
+                          jnp.zeros(scores.shape[:-1], jnp.uint32))
+    back = jnp.where(t >> 31 == 1, t & jnp.uint32((1 << 31) - 1), ~t)
+    kth = jax.lax.bitcast_convert_type(back, jnp.float32)
+    return jnp.where(t == 0, -jnp.inf, kth)
+
+
+def select_mask(qi, wt, q_slots, pool_i, bt, li, cfg: MlaConfig):
+    """Steps (1) and (2) as a MASK, for queries [B, Sq]: the additive bias
+    [B, Sq, span] float32 that is 0 on the slots a query chose and -1e30
+    elsewhere. The mask is the top-k's own members: every slot above the
+    k-th value (`kth_largest`: a mask needs no order, so nothing is
+    sorted), and of the slots AT it the lowest ones, as many as
+    `lax.top_k` would take."""
+    scores = indexer_scores(qi, wt, q_slots, pool_i, bt, li, cfg)
+    k = min(cfg.index_topk, scores.shape[-1])
+    with jax.named_scope(sn.INDEXER_TOPK):
+        kth = kth_largest(scores, k)[..., None]
+        above = scores > kth
+        at = (scores == kth) & (scores > -jnp.inf)
+        room = k - above.sum(-1, keepdims=True)
+        take = above | (at & (jnp.cumsum(at, axis=-1) <= room))
+        return jnp.where(take, 0.0, -1e30).astype(jnp.float32)
+
+
+def attend_token(q_full, bias, q_slots, pool_c, bt, li, cfg: MlaConfig):
+    """Steps (3) and (4) for ONE query a row (a decode token) on the chip:
+    the row's pages read where they lie under the selection's mask
+    (`ops.sparse_latent_attention.sparse_latent_decode`): no gather of the
+    chosen rows. [B, 1, H, lanes] -> [B, 1, H, kv_lora_rank]."""
+    from ray_tpu.ops import sparse_latent_attention as sla
+
+    with jax.named_scope(sn.SPARSE_ATTENTION):
+        o = sla.sparse_latent_decode(
+            q_full[:, 0], pool_c, bt, bias[:, 0], q_slots[:, 0], li,
+            rc=cfg.kv_lora_rank, sm_scale=cfg.sm_scale, interpret=False)
+    return o[:, None]
+
+
+def attend_chunk(q_full, bias, q_slots, pool_c, bt, li, cfg: MlaConfig):
+    """Steps (3) and (4) for a chunk: the row's latent pages side by side,
+    attended densely under the selection's mask
+    (`ops.sparse_latent_attention`: the kernel on the chip, its plain
+    form elsewhere). [B, S, H, lanes] -> [B, S, H, kv_lora_rank]."""
+    from ray_tpu.ops import sparse_latent_attention as sla
+
+    B, S = q_slots.shape
+    with jax.named_scope(sn.LATENT_GATHER):
+        lat = pool_c[li, bt].reshape(B, -1, pool_c.shape[3])
+    with jax.named_scope(sn.SPARSE_ATTENTION):
+        q = jnp.swapaxes(q_full, 1, 2)                  # [B, H, S, lanes]
+        if jax.default_backend() == "tpu" and S % 8 == 0 \
+                and lat.shape[1] % 128 == 0:
+            o = sla.sparse_latent_attention(
+                q, lat, bias, q_slots, rc=cfg.kv_lora_rank,
+                sm_scale=cfg.sm_scale)
+        else:
+            o = sla.sparse_latent_attention_reference(
+                q, lat, bias, rc=cfg.kv_lora_rank, sm_scale=cfg.sm_scale)
+        return jnp.swapaxes(o, 1, 2)
+
+
+def _by_query_blocks(fn, Sq: int, *per_query):
+    """``fn`` over blocks of `_QUERY_BLOCK` queries (axis 1 of every
+    argument), results concatenated; one call when the queries fit one."""
+    if Sq <= _QUERY_BLOCK:
+        return fn(*per_query)
+    n = -(-Sq // _QUERY_BLOCK)
+    pad = n * _QUERY_BLOCK - Sq
+
+    def split(x, fill):
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2),
+                    constant_values=fill)
+        x = x.reshape(x.shape[0], n, _QUERY_BLOCK, *x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    # the last argument is the slots: padding asks nothing (-1)
+    blocks = [split(x, 0) for x in per_query[:-1]] \
+        + [split(per_query[-1], -1)]
+    out = jax.lax.map(lambda xs: fn(*xs), tuple(blocks))
+
+    def join(y):
+        y = jnp.moveaxis(y, 0, 1)
+        return y.reshape(y.shape[0], n * _QUERY_BLOCK, *y.shape[3:])[:, :Sq]
+
+    return jax.tree_util.tree_map(join, out)
+
+
+# ---------------------------------------------------------------------------
+# The stack against the engine's pools
+# ---------------------------------------------------------------------------
+
+def _attention(h, p, li, pool_c, pool_i, bt, slots, q_slots, cfg: MlaConfig,
+               want_selection: bool = False):
+    """One layer's ``h + Attn(N(h))`` for rows [B, S] at ``slots``: writes
+    the chunk's latent rows and indexer keys into layer ``li`` of the two
+    planes, then selects and attends through the table. Returns (h, the
+    planes and, asked, which slots each query chose [B, S, span] bool)."""
+    dt = cfg.dtype
+    B, S, _ = h.shape
+    H = cfg.n_heads
+    n, r, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rc = cfg.kv_lora_rank
+    IH, ID = cfg.index_n_heads, cfg.index_head_dim
+    T = pool_c.shape[2]
+    f32 = jnp.float32
+    a = _rmsnorm(h, p["attn_norm"], cfg.norm_eps)
+    with jax.named_scope(sn.MLA_PROJ):
+        ang = jnp.maximum(slots, 0).astype(f32)[..., None] \
+            * yarn_inv_freq(cfg)                          # [B, S, r / 2]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        c_q = _rmsnorm(jnp.einsum("bsd,de->bse", a, p["wq_a"].astype(dt)),
+                       p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bse,ef->bsf", c_q, p["wq_b"].astype(dt)) \
+            .reshape(B, S, H, n + r)
+        q_rope = _rope_pairs(q[..., n:], cos[:, :, None], sin[:, :, None],
+                             True)
+        kv = jnp.einsum("bsd,de->bse", a, p["wkv_a"].astype(dt))
+        c = _rmsnorm(kv[..., :rc], p["kv_norm"], cfg.norm_eps)
+        k_rope = _rope_pairs(kv[..., rc:], cos, sin, True)
+        # absorbed: the key map goes to the query's side
+        q_abs = jnp.einsum("bshn,chn->bshc", q[..., :n],
+                           p["wk_b"].astype(dt).reshape(rc, H, n))
+        lanes = cfg.latent_lanes
+        fill = lanes - rc - r
+        q_full = jnp.concatenate(
+            [q_abs, q_rope] + ([jnp.zeros((B, S, H, fill), dt)]
+                               if fill else []), axis=-1)
+        latent = jnp.concatenate(
+            [c, k_rope] + ([jnp.zeros((B, S, fill), dt)] if fill else []),
+            axis=-1)
+        # the indexer's inputs
+        qi = jnp.einsum("bse,ef->bsf", c_q, p["wi_q"].astype(dt)) \
+            .reshape(B, S, IH, ID)
+        qi = jnp.concatenate(
+            [_rope_pairs(qi[..., :r], cos[:, :, None], sin[:, :, None],
+                         False), qi[..., r:]], axis=-1)
+        ki = jnp.einsum("bsd,de->bse", a, p["wi_k"].astype(dt)).astype(f32)
+        mu = ki.mean(-1, keepdims=True)
+        kc = ki - mu
+        ki = (kc * jax.lax.rsqrt((kc * kc).mean(-1, keepdims=True) + 1e-6)
+              * p["ik_norm_w"].astype(f32)
+              + p["ik_norm_b"].astype(f32)).astype(dt)
+        ki = jnp.concatenate(
+            [_rope_pairs(ki[..., :r], cos, sin, False), ki[..., r:]],
+            axis=-1)
+        wt = jnp.einsum("bsd,dj->bsj", a, p["wi_w"].astype(dt),
+                        preferred_element_type=f32) \
+            * np.float32(IH ** -0.5 * ID ** -0.5)
+    with jax.named_scope(sn.KV_WRITE):
+        blk = bt[jnp.arange(B)[:, None], slots // T]
+        off = slots % T
+        pool_c = pool_c.at[li, blk, off].set(latent.astype(pool_c.dtype))
+        pool_i = pool_i.at[li, blk, off].set(ki.astype(pool_i.dtype))
+
+    # the selection as a mask, a block of queries at a time; then a decode
+    # token on the chip walks its row's pages under it, and everything
+    # else attends the row's keys densely under it
+    def mask(qi, wt, q_slots):
+        return select_mask(qi, wt, q_slots, pool_i, bt, li, cfg)
+
+    bias = _by_query_blocks(mask, S, qi, wt, q_slots)
+    if S == 1 and T % _LANE == 0 and jax.default_backend() == "tpu":
+        o_lat = attend_token(q_full, bias, q_slots, pool_c, bt, li, cfg)
+    else:
+        o_lat = attend_chunk(q_full, bias, q_slots, pool_c, bt, li, cfg)
+    with jax.named_scope(sn.ATTN_OUT):
+        o = jnp.einsum("bshc,chv->bshv", o_lat,
+                       p["wv_b"].astype(dt).reshape(rc, H, v))
+        h = h + jnp.einsum("bse,ed->bsd", o.reshape(B, S, H * v),
+                           p["wo"].astype(dt))
+    return h, pool_c, pool_i, (bias == 0 if want_selection else None)
+
+
+def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
+                 cfg: MlaConfig, *, moe_live=None, n_valid=None,
+                 last_idx=None, want_selection: bool = False):
+    """The stack for all rows of ``toks`` [B, S] against the two planes
+    (latent ``pool_c``, index ``pool_i``) through ``bt``: what
+    `engine._layers_paged` is for the dense and sparse families.
+
+      moe_live  [B, S] bool or None: the positions the expert layers'
+                counters count (None: none are traced)
+      n_valid   [B] real tokens of a prefill chunk (None: all S)
+      last_idx  [B] the position whose hidden state is wanted (prefill);
+                None: every position
+
+    Returns (h [B, S, d] or [B, 1, d], pool_c, pool_i, expert-layer
+    counts [n_moe_layers, 4] or None) and, with ``want_selection`` (the
+    benchmark's `select_overlap`), which slots each query chose in every
+    layer [n_layers, B, S, span] bool."""
+    B, S = toks.shape
+    dt = cfg.dtype
+    slots = starts[:, None] + jnp.arange(S)[None, :]
+    q_slots = slots if n_valid is None else jnp.where(
+        jnp.arange(S)[None, :] < n_valid[:, None], slots, -1)
+    with jax.named_scope(sn.EMBED):
+        h = params["tok_embed"].astype(dt)[toks]
+
+    def dense_body(carry, xs):
+        h, pc, pi = carry
+        p, li = xs
+        h, pc, pi, sel = _attention(h, p, li, pc, pi, bt, slots, q_slots,
+                                    cfg, want_selection)
+        x = _rmsnorm(h, p["mlp_norm"], cfg.norm_eps)
+        with jax.named_scope(sn.MLP):
+            gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(dt))
+            up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(dt))
+            h = h + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                               p["w_down"].astype(dt))
+        return (h, pc, pi), (None, sel if want_selection else None)
+
+    def moe_body(carry, xs):
+        h, pc, pi = carry
+        p, li = xs
+        h, pc, pi, sel = _attention(h, p, li, pc, pi, bt, slots, q_slots,
+                                    cfg, want_selection)
+        x = _rmsnorm(h, p["mlp_norm"], cfg.norm_eps)
+        # the expert stacks of ALL expert layers go in whole, with this
+        # layer's index: nothing of a layer's size is sliced out of them
+        out, st = moe_ffn_dropless(x, {**p, **experts}, cfg, live=moe_live,
+                                   expert_stack_layer=li - cfg.n_dense_layers)
+        return (h + out, pc, pi), (st, sel if want_selection else None)
+
+    stacks = ("we_gate", "we_up", "we_down")
+    experts = {n: params["moe"][n].reshape(-1, *params["moe"][n].shape[2:])
+               for n in stacks}
+    carry = (h, pool_c, pool_i)
+    stats, chosen = None, []
+    for seg in cfg.layer_plan():
+        body = dense_body if seg.name == "dense" else moe_body
+        layers = {n: v for n, v in params[seg.name].items()
+                  if n not in stacks}
+        carry, (st, sel) = jax.lax.scan(
+            body, carry,
+            (layers, seg.first_layer + jnp.arange(seg.periods)))
+        chosen.append(sel)
+        if seg.name == "moe":
+            stats = st
+    h, pool_c, pool_i = carry
+    if last_idx is not None:
+        h = h[jnp.arange(B), last_idx][:, None]
+    if want_selection:
+        return h, pool_c, pool_i, stats, jnp.concatenate(chosen)
+    return h, pool_c, pool_i, stats
+
+
+def lm_head(params: Params, h, cfg: MlaConfig):
+    """Final RMSNorm and the untied head: [B, S, d] -> f32 [B, S, vocab]."""
+    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope(sn.LM_HEAD):
+        return jnp.einsum("bsd,dv->bsv", h, params["lm_head"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Solo generation: the same stack over a private pool
+# ---------------------------------------------------------------------------
+
+_SOLO_BLOCK = 32
+
+
+def init_cache(cfg: MlaConfig, batch_size: int, max_len: int):
+    """What `generate.init_cache` is for the other families: the engine's
+    two planes with a trivial table (row b owns blocks ``1 + b * MB ..
+    (b + 1) * MB`` for good)."""
+    T = _SOLO_BLOCK
+    mb = -(-max_len // T)
+    nb = 1 + batch_size * mb
+    latent, index = cfg.cache_planes()
+    return {"c": jnp.zeros((latent.layers, nb, T, latent.lanes), latent.dtype),
+            "i": jnp.zeros((index.layers, nb, T, index.lanes), index.dtype),
+            "bt": 1 + jnp.arange(batch_size * mb,
+                                 dtype=jnp.int32).reshape(batch_size, mb)}
+
+
+def forward_cached(params: Params, tokens, cache, start, cfg: MlaConfig):
+    """`generate.forward_cached` for this family: run a chunk [B, S] at
+    slot ``start`` of every row. Returns (logits of each row's LAST
+    position [B, 1, vocab] f32, cache)."""
+    B, S = tokens.shape
+    h, c, i, _ = layers_paged(
+        params, tokens, cache["c"], cache["i"], cache["bt"],
+        jnp.full((B,), start, jnp.int32), cfg,
+        last_idx=jnp.full((B,), S - 1, jnp.int32))
+    return lm_head(params, h, cfg), {"c": c, "i": i, "bt": cache["bt"]}

@@ -62,6 +62,23 @@ class MoeConfig(LlamaConfig):
     norm_topk_prob: bool = True
     # RMSNorm over the whole q / k projection ahead of RoPE (OLMoE).
     qk_norm: bool = False
+    # The expert layer's other knobs, read by `moe_ffn_dropless` from any
+    # config that has them (`mla.MlaConfig` sets them all). `held_experts`
+    # (lo, hi): the router still scores all `n_experts`, the stacks
+    # ``we_*`` hold experts lo..hi-1 alone and only the assignments that
+    # land on those are computed (one chip's share of an expert-parallel
+    # layer, without its exchange); None: every expert is here.
+    # `n_shared_experts`: a dense gated FFN beside the routed ones
+    # (``ws_*``), added once. `router`: "softmax" over all experts, or
+    # "sigmoid_grouped" (DeepSeek-V3: sigmoid scores, a selection bias
+    # ``router_bias`` that chooses but does not weigh, `topk_group` of
+    # `n_group` groups kept, weights times `routed_scaling_factor`).
+    held_experts: Optional[Tuple[int, int]] = None
+    n_shared_experts: int = 0
+    router: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         super().__post_init__()
@@ -217,10 +234,144 @@ def qk_norm(q: jax.Array, k: jax.Array, layer: Params, cfg: MoeConfig
 # ms a layer, all-experts / sorted: 64 tokens 1.22 / 2.60, 256 1.25 /
 # 2.75, 512 2.30 / 3.03; sorted alone: 2048 4.57, 4096 7.83.
 DENSE_EXPERTS_MAX_TOKENS = 512
+# A layer that HOLDS a share of the experts computes, all-experts, E_held
+# rows a token where top_k * E_held / E land (32 times the work at 16 of
+# 256, 8 a token): there the all-experts form stops at the tokens whose
+# extra FLOPs still hide under the read of the held weights.
+DENSE_HELD_MAX_TOKENS = 256
+# The sorted form of a layer that holds a share works through the
+# assignments that landed in windows of this many times their expected
+# number (rounded up to `_HELD_ROWS_ALIGN`): one window nearly always,
+# more for ANY routing, all assignments on held experts included.
+HELD_ROWS_SLACK = 2.0
+_HELD_ROWS_ALIGN = 256
+
+
+def route_sigmoid_grouped(logits: jax.Array, bias: jax.Array, cfg
+                          ) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek-V3's router (`topk_method` noaux_tc): f32 logits [G, E] ->
+    (weights [G, k], expert ids [G, k]). Sigmoid scores; ``bias`` is added
+    to CHOOSE only; a group's score is the sum of its two largest choice
+    scores, the `topk_group` best groups stay and the others' choice
+    scores become 0; the weights are the chosen experts' scores (without
+    the bias), renormalised when `norm_topk_prob`, times
+    `routed_scaling_factor`."""
+    g, e = logits.shape
+    ng, per = cfg.n_group, e // cfg.n_group
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + bias.astype(jnp.float32)
+    if ng > 1:
+        gscore = jax.lax.top_k(choice.reshape(g, ng, per), 2)[0].sum(-1)
+        kept = jax.lax.top_k(gscore, cfg.topk_group)[1]          # [G, kg]
+        keep = jnp.any(kept[:, :, None] == jnp.arange(ng)[None, None, :],
+                       axis=1)                                   # [G, ng]
+        choice = jnp.where(jnp.repeat(keep, per, axis=1), choice, 0.0)
+    idx = jax.lax.top_k(choice, cfg.top_k)[1]
+    weights = jnp.take_along_axis(scores, idx, axis=1)
+    if cfg.norm_topk_prob:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return weights * cfg.routed_scaling_factor, idx
+
+
+def _gated_ffn(x, w_gate, w_up, w_down, dt):
+    gate = jnp.einsum("gd,df->gf", x, w_gate.astype(dt))
+    up = jnp.einsum("gd,df->gf", x, w_up.astype(dt))
+    return jnp.einsum("gf,fd->gd", jax.nn.silu(gate) * up,
+                      w_down.astype(dt))
+
+
+def _held_hit(xf, combine, w1, w3, w2, first, eh: int, dt):
+    """The all-experts form of a layer that HOLDS a share, for few tokens
+    (a decode step): an expert's three matrices are read only if some row
+    chose it. ``combine`` [G, E_held] holds each row's weight for each
+    held expert (0: not chosen); the experts are ``first .. first + eh -
+    1`` of the stacks ``w1``/``w3`` [*, d, f], ``w2`` [*, f, d]. At 24
+    rows x 8 of 256 experts a third of the 16 held are hit a step: the
+    other two thirds of the layer's bytes stay in HBM."""
+    g, d = xf.shape
+
+    def one(j, out):
+        w = jax.lax.dynamic_index_in_dim(combine, j, axis=1,
+                                         keepdims=True)      # [G, 1]
+
+        def run(out):
+            e = first + j
+            gate = jnp.einsum("gd,df->gf", xf, w1[e])
+            up = jnp.einsum("gd,df->gf", xf, w3[e])
+            act = jax.nn.silu(gate) * up * w.astype(dt)
+            return out + jnp.einsum("gf,fd->gd", act, w2[e],
+                                    preferred_element_type=jnp.float32)
+
+        return jax.lax.cond(jnp.any(w != 0.0), run, lambda out: out, out)
+
+    return jax.lax.fori_loop(0, eh, one,
+                             jnp.zeros((g, d), jnp.float32)).astype(dt)
+
+
+def _held_sorted(xf, weights, idx, w1, w3, w2, lo: int, e: int, dt,
+                 first=0, eh: Optional[int] = None):
+    """The sorted form over the assignments that land on the held experts
+    ``lo .. lo + E_held - 1`` alone: the rows of ``idx`` [G, k] that do
+    are sorted by expert to the front and multiplied as ragged groups, a
+    window of ``c`` rows at a time (`HELD_ROWS_SLACK` times their
+    expected number) until all that landed are done, each row's result
+    weighted and added to its token. One window nearly always; as many as
+    it takes for ANY routing. The held experts are ``first .. first + eh
+    - 1`` of the stacks (all of them by default): the stacks of SEVERAL
+    layers go in whole and the groups of the others stay empty, so no
+    layer's weights are sliced out and copied for the ragged product (a
+    sixth of a prefill program's time, PERF.md PR 33). Returns ([G, d] in
+    ``dt``, the rows the matmuls computed, a traced int32)."""
+    g, d = xf.shape
+    k = idx.shape[1]
+    eh = w1.shape[0] if eh is None else eh
+    n = g * k
+    c = min(n, -(-int(HELD_ROWS_SLACK * n * eh / e) // _HELD_ROWS_ALIGN)
+            * _HELD_ROWS_ALIGN)
+    with jax.named_scope(sn.MOE_DISPATCH):
+        local = idx.reshape(n) - lo
+        key = jnp.where((local >= 0) & (local < eh), local, eh)
+        # landed assignments first, by expert; a window may run past n
+        order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
+                        (0, c))
+        counts = jnp.bincount(key, length=eh + 1)[:eh].astype(jnp.int32)
+        ends = jnp.cumsum(counts).astype(jnp.int32)
+        starts = ends - counts
+        n_landed = ends[-1]
+        wflat = weights.reshape(n)
+
+    def window(i, out):
+        base = i * c
+        with jax.named_scope(sn.MOE_DISPATCH):
+            rows = jax.lax.dynamic_slice(order, (base,), (c,))
+            ok = base + jnp.arange(c, dtype=jnp.int32) < n_landed
+            tok = rows // k
+            xs = xf[tok]                                        # [c, d]
+            sizes = jnp.clip(ends, base, base + c) \
+                - jnp.clip(starts, base, base + c)
+            if w1.shape[0] != eh:     # this layer's groups among all
+                sizes = jax.lax.dynamic_update_slice(
+                    jnp.zeros((w1.shape[0],), jnp.int32), sizes, (first,))
+        with jax.named_scope(sn.MOE_EXPERTS):
+            gate = jax.lax.ragged_dot(xs, w1, sizes)
+            up = jax.lax.ragged_dot(xs, w3, sizes)
+            ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes)
+        with jax.named_scope(sn.MOE_DISPATCH):
+            # rows past what landed belong to no group: whatever the
+            # ragged product left there is not added
+            ys = jnp.where(ok[:, None], ys.astype(jnp.float32)
+                           * wflat[rows][:, None], 0.0)
+            return out.at[tok].add(ys)
+
+    n_win = (n_landed + (c - 1)) // c
+    out = jax.lax.fori_loop(0, n_win, window,
+                            jnp.zeros((g, d), jnp.float32))
+    return out.astype(dt), n_win * np.int32(c)
 
 
 def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
-                     live: Optional[jax.Array] = None
+                     live: Optional[jax.Array] = None,
+                     expert_stack_layer=None
                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The serving expert layer: x [B,S,d] (already normed) -> (sum over
     each token's top-k experts of p_e * down_e(silu(gate_e x) * up_e x)
@@ -228,38 +379,67 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
     for ANY routing. Rows are independent of one another, so a dead
     slot's or a padded position's row changes no live row's output.
 
+    ``cfg`` is an `MoeConfig` or any config with its expert-layer fields
+    (`mla.MlaConfig`). With `held_experts` the sum runs over the chosen
+    experts that are HELD here and leaves the others' part out; with
+    `n_shared_experts` the shared FFN's output is added once.
+
     ``live`` [B,S] bool marks the rows that are real tokens; given, the
     second result is int32 [3]: (live assignments = live tokens * top_k,
     token-expert rows the expert matmuls computed, experts with at least
-    one live assignment). None -> no counters are traced at all."""
+    one live assignment), and with `held_experts` a fourth: the live
+    assignments that landed on a held expert. None -> no counters are
+    traced at all.
+
+    ``expert_stack_layer`` (a layer that holds a share only): the stacks
+    ``we_*`` are then those of ALL layers, ``[L * E_held, ...]``, and this
+    is the index of the layer whose experts to use (traced: a layer
+    scan's). None: the stacks are this layer's."""
     dt = cfg.dtype
     b, s, d = x.shape
     g, e, k = b * s, cfg.n_experts, cfg.top_k
+    held = cfg.held_experts
     xf = x.reshape(g, d)
     with jax.named_scope(sn.MOE_ROUTER):
         logits = jnp.einsum("gd,de->ge", xf, layer["w_router"].astype(dt),
                             preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, idx = _route_topk(probs, k, cfg.norm_topk_prob)  # [G,k]
+        if cfg.router == "sigmoid_grouped":
+            weights, idx = route_sigmoid_grouped(
+                logits, layer["router_bias"], cfg)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            weights, idx = _route_topk(probs, k, cfg.norm_topk_prob)  # [G,k]
     w1, w3, w2 = (layer[n].astype(dt)
                   for n in ("we_gate", "we_up", "we_down"))
-    dense = g <= DENSE_EXPERTS_MAX_TOKENS
+    lo, eh = (0, e) if held is None else (held[0], held[1] - held[0])
+    dense = g <= (DENSE_EXPERTS_MAX_TOKENS if held is None
+                  else DENSE_HELD_MAX_TOKENS)
+    rows = np.int32(g * (eh if dense else k))
+    first = 0 if expert_stack_layer is None else expert_stack_layer * eh
     if dense:
         with jax.named_scope(sn.MOE_DISPATCH):
-            # [G,E] combine matrix: p_e where token g chose e, else 0
+            # [G,E_held] combine matrix: p_e where token g chose held
+            # expert e, else 0 (an id outside the held range is no row
+            # of the one-hot)
             combine = jnp.sum(
-                jax.nn.one_hot(idx, e, dtype=jnp.float32)
+                jax.nn.one_hot(idx if held is None else idx - lo, eh,
+                               dtype=jnp.float32)
                 * weights[..., None], axis=1)
         with jax.named_scope(sn.MOE_EXPERTS):
-            gate = jnp.einsum("gd,edf->gef", xf, w1)
-            up = jnp.einsum("gd,edf->gef", xf, w3)
-            act = jax.nn.silu(gate) * up
-            # the combine folds into the down projection: one
-            # contraction over (expert, f), accumulated in float32
-            out = jnp.einsum(
-                "gef,efd->gd", act * combine[..., None].astype(dt), w2,
-                preferred_element_type=jnp.float32).astype(dt)
-    else:
+            if held is not None:
+                out = _held_hit(xf, combine, w1, w3, w2, first, eh, dt)
+                rows = jnp.any(combine != 0.0, axis=0).sum(
+                    dtype=jnp.int32) * np.int32(g)
+            else:
+                gate = jnp.einsum("gd,edf->gef", xf, w1)
+                up = jnp.einsum("gd,edf->gef", xf, w3)
+                act = jax.nn.silu(gate) * up
+                # the combine folds into the down projection: one
+                # contraction over (expert, f), accumulated in float32
+                out = jnp.einsum(
+                    "gef,efd->gd", act * combine[..., None].astype(dt), w2,
+                    preferred_element_type=jnp.float32).astype(dt)
+    elif held is None:
         with jax.named_scope(sn.MOE_DISPATCH):
             flat = idx.reshape(g * k)
             order = jnp.argsort(flat, stable=True)   # by expert
@@ -276,18 +456,33 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
                 "gkd,gk->gd", ys[inverse].reshape(g, k, d),
                 weights.astype(dt),
                 preferred_element_type=jnp.float32).astype(dt)
+    else:
+        out, rows = _held_sorted(xf, weights, idx, w1, w3, w2, lo, e, dt,
+                                 first, eh)
+    if cfg.n_shared_experts:
+        with jax.named_scope(sn.MOE_SHARED):
+            out = out + _gated_ffn(xf, layer["ws_gate"], layer["ws_up"],
+                                   layer["ws_down"], dt)
     stats = None
     if live is not None:
         with jax.named_scope(sn.MOE_ROUTER):
             lv = live.reshape(g)
-            hit = jnp.zeros((e,), bool).at[idx.reshape(-1)].max(
-                jnp.repeat(lv, k))
+            if held is None:
+                hit = jnp.zeros((e,), bool).at[idx.reshape(-1)].max(
+                    jnp.repeat(lv, k))
+                here = []
+            else:
+                landed = (idx >= lo) & (idx < lo + eh) & lv[:, None]
+                hit = jnp.zeros((eh,), bool).at[
+                    jnp.clip(idx - lo, 0, eh - 1).reshape(-1)].max(
+                    landed.reshape(-1))
+                here = [landed.sum(dtype=jnp.int32)]
             stats = jnp.stack([
                 lv.sum(dtype=jnp.int32) * k,
                 # a numpy scalar: `jnp.int32(...)` would put one on the
                 # device and the trace read it back (PERF.md PR 30)
-                np.int32(g * (e if dense else k)),
-                hit.sum(dtype=jnp.int32)])
+                rows,
+                hit.sum(dtype=jnp.int32)] + here)
     return out.reshape(b, s, d), stats
 
 

@@ -35,7 +35,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Tuple
 
-from ray_tpu.models.block_pool import BlockPool
+from ray_tpu.models.block_pool import BlockPool, kv_planes
 
 
 def block_bytes(n_layers: int, block_tokens: int, kv_heads: int,
@@ -66,7 +66,8 @@ def block_bytes(n_layers: int, block_tokens: int, kv_heads: int,
     reserved null block 0 rides on top — it is part of the pool
     allocation but never holds cached data)."""
     layers = 1 if per_layer else n_layers
-    return 2 * layers * block_tokens * kv_heads * head_dim * dtype_bytes
+    return sum(plane.block_bytes(block_tokens, dtype_bytes) for plane in
+               kv_planes("full", layers, kv_heads, head_dim, None))
 
 
 class _Node:

@@ -19,7 +19,8 @@ server grows:
   decode step, so a burst of long prompts admitted at once would stall
   every in-flight decode row for the full burst; capping admissions
   per step bounds the inter-token latency in-flight requests can lose
-  to newcomers.
+  to newcomers. A row still mid-prompt (chunked prefill) prefills a
+  chunk every step and counts against the budget.
 
 Scheduling only changes WHICH request is admitted when a slot frees —
 and, via `horizon_hint`, how many decode iterations the engine fuses

@@ -26,6 +26,15 @@ MOE_ROUTER = "moe_router"            # router matmul, softmax, top-k
 MOE_DISPATCH = "moe_dispatch"        # sort, gather, scatter, combine: moves
 #                                      tokens to experts, computes nothing
 MOE_EXPERTS = "moe_experts"          # the three expert matmuls and silu
+MOE_SHARED = "moe_shared"            # the shared expert beside the routed ones
+MLA_PROJ = "mla_proj"                # latent attention: low-rank q and kv
+#                                      projections, their norms and rotary,
+#                                      the absorbed key and value maps
+INDEXER_SCORE = "indexer_score"      # sparse attention's indexer: every live
+#                                      token of a row scored for a query
+INDEXER_TOPK = "indexer_topk"        # ... and the exact top-k of the scores
+LATENT_GATHER = "latent_gather"      # a row's latent pages side by side
+SPARSE_ATTENTION = "sparse_attention"    # attention over the chosen rows
 SSM_PROJ = "ssm_proj"                # state-space layer: in/out projections,
 #                                      causal conv, gate
 SSM_SCAN = "ssm_scan"                # state-space layer: x_proj, dt and the
@@ -42,7 +51,8 @@ OPTIMIZER = "optimizer"              # update rule and parameter apply
 SCOPES = (EMBED, NORM, ATTN_QKV, KV_WRITE, KV_GATHER, PAGED_ATTENTION,
           CACHED_ATTENTION, ATTENTION, ATTN_OUT, MLP, LM_HEAD, SAMPLE,
           LOSS, OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
-          SSM_PROJ, SSM_SCAN, GMU, DIFF_COMBINE)
+          SSM_PROJ, SSM_SCAN, GMU, DIFF_COMBINE, MOE_SHARED, MLA_PROJ,
+          INDEXER_SCORE, INDEXER_TOPK, LATENT_GATHER, SPARSE_ATTENTION)
 
 # Scopes whose ops move cached K/V without computing on it.
 KV_MOVE = (KV_WRITE, KV_GATHER)
@@ -53,6 +63,9 @@ FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
 PAGED_KERNEL = "paged_attention"
+SPARSE_LATENT_KERNEL = "sparse_latent_attention"
+SPARSE_LATENT_DECODE_KERNEL = "sparse_latent_decode"
 
-KERNELS = (PAGED_KERNEL, FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+KERNELS = (PAGED_KERNEL, FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV,
+           SPARSE_LATENT_KERNEL, SPARSE_LATENT_DECODE_KERNEL)
 

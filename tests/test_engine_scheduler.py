@@ -160,6 +160,39 @@ def test_prefill_budget_guards_decode_rows(nano_model):
     assert out[first] == _solo(params, cfg, [5, 6, 7], 8)
 
 
+def test_prefill_budget_counts_rows_mid_prompt(nano_model):
+    """A row mid-prompt prefills a chunk every step, so it counts against
+    max_prefills_per_step: with prompts four chunks long, a budget of 2
+    and six free slots, no step's prefill holds more than two rows (the
+    admissions alone would stack all six), and tokens are solo's."""
+    cfg, params = nano_model
+    eng = DecodeEngine(params, cfg, batch_slots=6, max_len=64,
+                       prefill_chunk=8, max_prefills_per_step=2)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+               for n in (30, 27, 32, 9, 25, 31)]
+    ids = [eng.submit(p, 4) for p in prompts]
+    mid, out = [], {}
+    while len(out) < len(ids):
+        eng.step()
+        mid.append(len(eng._row_prefill))
+        for rid in list(eng.finished):
+            out[rid] = eng.pop_result(rid)
+    assert max(mid) == 2
+    for rid, p in zip(ids, prompts):
+        assert out[rid] == _solo(params, cfg, p, 4), f"req {rid}"
+    # one chunk a prompt: the budget is the admissions', as it was
+    eng = DecodeEngine(params, cfg, batch_slots=6, max_len=64,
+                       prefill_chunk=8, max_prefills_per_step=2)
+    for _ in range(6):
+        eng.submit([3, 4, 5], 8)
+    live = []
+    for _ in range(3):
+        eng.step(horizon=1)
+        live.append(sum(r is not None for r in eng.row_req))
+    assert live == [2, 4, 6]
+
+
 def test_knob_validation(nano_model):
     cfg, params = nano_model
     with pytest.raises(ValueError, match="max_queue"):

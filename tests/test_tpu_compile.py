@@ -509,6 +509,66 @@ def test_hybrid_cell_programs_fit_the_chip(v5e, program):
     assert m.alias_size_in_bytes > 3 * 2**30
 
 
+# -- the latent-attention cell's programs ------------------------------------------
+
+def _mla_program(v5e, program):
+    """The `dsv32-longdoc` cell's engine as `DecodeEngine` builds it (24
+    slots, 72 table entries of 256 tokens, a 3 GiB pool of a latent and an
+    index plane for 5 layers, experts [0, 16) of 256 held, 1/8 of the
+    vocabulary): `_decode_multi_paged` at the cell's horizon of 2 or
+    `_prefill_rows_paged` for 4 x 512 tokens, compiled for the described
+    chip with the kernels selected."""
+    from ray_tpu.models import MlaConfig, engine, mla_init
+
+    cfg = MlaConfig(vocab_size=16160, n_layers=5, n_dense_layers=1,
+                    held_experts=(0, 16), max_seq_len=18432)
+    B, T, MB = 24, 256, 72
+    latent, index = cfg.cache_planes()
+    nb = 1 + (3 << 30) // (latent.block_bytes(T) + index.block_bytes(T))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree.map(
+        lambda a: arg(a.shape, a.dtype),
+        jax.eval_shape(lambda: mla_init(jax.random.PRNGKey(0), cfg)))
+    pc = arg((5, nb, T, latent.lanes), jnp.bfloat16)
+    pi = arg((5, nb, T, index.lanes), jnp.bfloat16)
+    logits = arg((B, cfg.vocab_size), jnp.float32)
+    ctr = arg((5,))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        if program == "decode":
+            lane, flag = arg((B,)), arg((B,), jnp.bool_)
+            lowered = engine._decode_multi_paged.lower(
+                params, pc, pi, arg((B, MB)), logits, lane, flag, lane,
+                lane, arg((B, 2), jnp.uint32), flag, 1.0, cfg, 2, True,
+                None, None, None, moe_ctr=ctr)
+        else:
+            lowered = engine._prefill_rows_paged.lower(
+                params, arg((4, 512)), pc, pi, logits, arg((4, MB)),
+                arg((4,)), arg((4,)), arg((4,)), cfg, moe_ctr=ctr)
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_mla_cell_programs_fit_the_chip(v5e, program):
+    """One chip's share of DeepSeek-V3.2-Exp in bf16 (4.64 B parameters,
+    8.63 GiB) beside a 3 GiB pool of two planes: 11.64 GiB of arguments,
+    and no program adds 2 GiB of workspace (0.66 decode, 1.54 the 4 x 512
+    prefill as compiled here), within the chip's 15.75. Each program holds
+    its form of the selection's kernel, one call a segment; both planes
+    are updated in place."""
+    compiled = _mla_program(v5e, program)
+    m = compiled.memory_analysis()
+    assert 11 * 2**30 < m.argument_size_in_bytes < 12 * 2**30
+    assert m.temp_size_in_bytes < 2 * 2**30
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2**30
+    assert m.alias_size_in_bytes > 3 * 2**30
+    # the selection's kernel: a token's pages read where they lie, a
+    # chunk's keys under its mask
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 # -- the train cell's step -------------------------------------------------------
 
 def _train_cell_step(topo):
