@@ -93,7 +93,7 @@ from ray_tpu.models.block_pool import BlockPool
 from ray_tpu.models.engine_metrics import EngineMetrics, NullEngineMetrics
 from ray_tpu.models.engine_trace import resolve_tracer
 from ray_tpu.models import hybrid as _hybrid
-from ray_tpu.models.generate import (_check_sampling_knobs,
+from ray_tpu.models.generate import (_check_sampling_knobs, _expert_stacks,
                                      _layer_body, sample_rows)
 from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models import mla as _mla
@@ -529,7 +529,7 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
                              cfg: LlamaConfig, lora=None,
                              lora_slots=None,
                              qspec: Optional[KVQuantSpec] = None,
-                             moe_live=None, n_valid=None):
+                             moe_live=None, n_valid=None, experts=None):
     """One decoder layer against the pool, S tokens a row (1 in the
     fused decode, the window in a speculative round, a chunk in
     prefill), each row writing at its own slots and attending its own
@@ -582,8 +582,9 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
                 for (pool, scales), x in ((kc, k), (vc, v)))
 
     # a chunk's bucket filler queries nothing: its result is never read
-    q_slots = slots if n_valid is None else jnp.where(
-        jnp.arange(S)[None, :] < n_valid[:, None], slots, -1)
+    real = None if n_valid is None else \
+        jnp.arange(S)[None, :] < n_valid[:, None]
+    q_slots = slots if real is None else jnp.where(real, slots, -1)
 
     def attend(q, k, v, kc, vc):
         # A quantized pool's CHUNK attends itself as computed and only
@@ -595,9 +596,13 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
                                kv_valid_len=span, k_scale=kc[1],
                                v_scale=vc[1], own_kv=own)
 
+    # a chunk group's padding rows repeat another row: `moe_live` counts
+    # them once, but their K/V land on their twin's, so they are READ
     return _layer_body(h, layer, kc, vc, slots, write_kv, slots, span,
                        cfg, attend=attend, lora=lora,
-                       lora_slots=lora_slots, moe_live=moe_live)
+                       lora_slots=lora_slots, moe_live=moe_live,
+                       experts=None if experts is None else (experts, li),
+                       moe_read=real)
 
 
 def _own_stack(cfg):
@@ -670,10 +675,13 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
             h, layer, li, kc, vc, bt, slots, cfg,
             lora=xs[2] if adapters is not None else None,
             lora_slots=row_slot, qspec=qspec, moe_live=moe_live,
-            n_valid=n_valid)
+            n_valid=n_valid, experts=experts)
         return (h, kc, vc), st
 
-    xs = (params["layers"], jnp.arange(pool_k.shape[0]))
+    # an `MoeConfig`'s expert stacks stay out of the scan's slices where
+    # a kernel reads them (`generate._expert_stacks`)
+    layers, experts = _expert_stacks(params["layers"], cfg, toks.size)
+    xs = (layers, jnp.arange(pool_k.shape[0]))
     if adapters is not None:
         xs = xs + (adapters,)
     (h, (pool_k, scale_k), (pool_v, scale_v)), moe_stats = jax.lax.scan(
@@ -1805,6 +1813,40 @@ class DecodeEngine:
         self.steps_total = 0
         from ray_tpu.util.state.serving import register_engine
         register_engine(self)
+        if self.preempt_mode == "swap":
+            self._warm_swap()
+
+    def _warm_swap(self) -> None:
+        """Run preempt-and-swap's device programs once for every chain
+        length (a power of two of blocks; here all the null block, whose
+        contents nothing reads), so that the FIRST preemption compiles
+        nothing. It comes when the pool has just run dry under a burst,
+        the worst moment to hold `step()` for a compile, and a shape the
+        benchmark's warm-up never reaches (PERF.md PR 34: a 15 s stall in
+        front of an open loop). The gather, the scatter, and the two small
+        programs that move a row's logits out and back."""
+        kw = dict(shardings=self._shardings, scale_k=self._scale_k,
+                  scale_v=self._scale_v)
+        n = 1
+        while n <= _pow2(self._mb):
+            bids = jnp.asarray(np.zeros((n,), np.int32))
+            host = [None if x is None else jnp.asarray(
+                np.zeros(x.shape, x.dtype)) for x in _swap_out_gather(
+                    self._pool_k, self._pool_v, bids, **kw)]
+            (self._pool_k, self._pool_v, self._scale_k,
+             self._scale_v) = _swap_in_scatter(
+                self._pool_k, self._pool_v, host[0], host[1], bids,
+                host_sk=host[2], host_sv=host[3], **kw)
+            kw.update(scale_k=self._scale_k, scale_v=self._scale_v)
+            n *= 2
+        self._set_row_logits(0, np.asarray(self._last_logits[0]))
+
+    def _set_row_logits(self, row: int, logits: np.ndarray) -> None:
+        self._last_logits = self._last_logits.at[row].set(
+            jnp.asarray(logits))
+        if self._shardings is not None:
+            self._last_logits = jax.device_put(self._last_logits,
+                                               self._shardings.logits)
 
     # -- public API --------------------------------------------------------
 
@@ -3551,11 +3593,7 @@ class DecodeEngine:
             scale_v=self._scale_v,
             host_sk=None if swap.sk is None else jnp.asarray(swap.sk),
             host_sv=None if swap.sv is None else jnp.asarray(swap.sv))
-        self._last_logits = self._last_logits.at[row].set(
-            jnp.asarray(swap.logits))
-        if self._shardings is not None:
-            self._last_logits = jax.device_put(self._last_logits,
-                                               self._shardings.logits)
+        self._set_row_logits(row, swap.logits)
         self._bind_row(row, req, ids, swap.row_len)
         self.row_budget[row] = swap.budget
         self._tok_idx[row] = swap.tok_idx

@@ -24,7 +24,8 @@ from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models import mla as _mla
 from ray_tpu.models.mla import MlaConfig
 from ray_tpu.models.llama import LlamaConfig, _rmsnorm, _rope
-from ray_tpu.models.moe import MoeConfig, moe_ffn_dropless, qk_norm
+from ray_tpu.models.moe import (MoeConfig, hit_experts_only,
+                                moe_ffn_dropless, qk_norm)
 from ray_tpu.ops import scope_names as sn
 
 Params = Dict[str, Any]
@@ -95,10 +96,30 @@ def _lora_delta(x, ab, slots, dt):
                       jnp.einsum("bsi,bir->bsr", x, a), b)
 
 
+_EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+
+
+def _expert_stacks(layers: Params, cfg: LlamaConfig, tokens: int):
+    """What a layer scan over ``layers`` should slice a layer at a time,
+    and what it should not: (the scan's layers, the expert stacks of ALL
+    layers as ``[L * E, ...]`` or None). For the few tokens at which an
+    `MoeConfig`'s expert layer reads only the experts that were hit
+    (`moe.hit_experts_only`) the stacks stay whole beside the scan and
+    `_layer_body` is told the layer's index (``experts``): a kernel's
+    operand sliced out of the scan's would be COPIED, 805 MB a layer at
+    OLMoE's widths. Any other config or size: ``layers`` as they are."""
+    if not (isinstance(cfg, MoeConfig) and hit_experts_only(cfg, tokens)):
+        return layers, None
+    stacks = {n: layers[n].reshape(-1, *layers[n].shape[2:])
+              for n in _EXPERT_STACKS}
+    return ({n: v for n, v in layers.items() if n not in stacks}, stacks)
+
+
 def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
                 q_slots, kv_valid_len, cfg: LlamaConfig,
                 slot_live=None, attend=None, lora=None,
-                lora_slots=None, moe_live=None):
+                lora_slots=None, moe_live=None, experts=None,
+                moe_read=None):
     """The decoder-layer math shared by ALL cached decode paths —
     generate.py's contiguous-chunk writes, engine.py's per-row
     scatter writes, and the paged engine's block-pool writes: rmsnorm
@@ -131,7 +152,11 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
     traces exactly what it always did. The KV side is the same for
     both. ``moe_live`` [B, S] bool (engine programs) marks the rows
     that are real tokens and asks the expert layer for its counters,
-    the fourth result (None for a dense model or without the mask)."""
+    the fourth result (None for a dense model or without the mask).
+    ``experts`` (`_expert_stacks`): all layers' expert stacks and this
+    layer's index, where the scan's ``layer`` came without them;
+    ``moe_read`` [B, S] bool: the rows anyone reads, where those are not
+    ``moe_live`` (`moe.moe_ffn_dropless`)."""
     dt = cfg.dtype
     sparse = isinstance(cfg, MoeConfig)
     x = _rmsnorm(h, layer["attn_norm"], cfg.norm_eps)
@@ -171,7 +196,10 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
         h = h + attn_out
     x = _rmsnorm(h, layer["mlp_norm"], cfg.norm_eps)
     if sparse:
-        moe_out, moe_stats = moe_ffn_dropless(x, layer, cfg, moe_live)
+        stacks, li = ({}, None) if experts is None else experts
+        moe_out, moe_stats = moe_ffn_dropless(
+            x, {**layer, **stacks}, cfg, moe_live, expert_stack_layer=li,
+            read=moe_read)
         return h + moe_out, k_cache, v_cache, moe_stats
     with jax.named_scope(sn.MLP):
         gate = jnp.einsum("bsd,df->bsf", x, layer["w_gate"].astype(dt))
@@ -194,7 +222,7 @@ def _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
 
 def _cached_layer(h, layer, k_cache, v_cache, positions, slot_ids,
                   start, kv_valid_len, cfg: LlamaConfig,
-                  slot_live=None):
+                  slot_live=None, experts=None):
     """One decoder layer over a chunk [B, S, d] whose K/V are WRITTEN
     into the cache at slots [start, start+S); ``positions`` are the
     ROPE position ids (per-row, pad-adjusted in ragged batches) while
@@ -210,7 +238,16 @@ def _cached_layer(h, layer, k_cache, v_cache, positions, slot_ids,
 
     return _layer_body(h, layer, k_cache, v_cache, positions, write_kv,
                        slot_ids, kv_valid_len, cfg,
-                       slot_live=slot_live)[:3]
+                       slot_live=slot_live, experts=experts)[:3]
+
+
+def _layer_xs(layers: Params, cache: Cache, stacks):
+    """A dense cache's layer scan's ``xs``: a layer's weights and cache
+    rows, and the layer's index where the expert stacks stay whole."""
+    xs = (layers, cache["k"], cache["v"])
+    if stacks is not None:
+        xs += (jnp.arange(cache["k"].shape[0]),)
+    return xs
 
 
 def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
@@ -253,16 +290,19 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
         positions = slot_ids
     kv_valid_len = start + S
 
+    layers, stacks = _expert_stacks(params["layers"], cfg, B * S)
+
     def body(carry, xs):
         h = carry
-        layer, k_c, v_c = xs
-        h, k_c, v_c = _cached_layer(h, layer, k_c, v_c, positions,
-                                    slot_ids, start, kv_valid_len, cfg,
-                                    slot_live=slot_live)
+        layer, k_c, v_c = xs[:3]
+        h, k_c, v_c = _cached_layer(
+            h, layer, k_c, v_c, positions, slot_ids, start, kv_valid_len,
+            cfg, slot_live=slot_live,
+            experts=None if stacks is None else (stacks, xs[3]))
         return h, (k_c, v_c)
 
     h, (k_new, v_new) = jax.lax.scan(
-        body, h, (params["layers"], cache["k"], cache["v"]))
+        body, h, _layer_xs(layers, cache, stacks))
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         logits = jnp.einsum("bsd,dv->bsv", h,
@@ -309,15 +349,18 @@ def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
             v.astype(v_cache.dtype))
         return k_cache, v_cache
 
+    layers, stacks = _expert_stacks(params["layers"], cfg, B * S)
+
     def body(h, xs):
-        layer, k_c, v_c = xs
-        h, k_c, v_c, _ = _layer_body(h, layer, k_c, v_c, slot_ids,
-                                     write_kv, slot_ids, k_c.shape[1],
-                                     cfg)
+        layer, k_c, v_c = xs[:3]
+        h, k_c, v_c, _ = _layer_body(
+            h, layer, k_c, v_c, slot_ids, write_kv, slot_ids,
+            k_c.shape[1], cfg,
+            experts=None if stacks is None else (stacks, xs[3]))
         return h, (k_c, v_c)
 
     h, (k_new, v_new) = jax.lax.scan(
-        body, h, (params["layers"], cache["k"], cache["v"]))
+        body, h, _layer_xs(layers, cache, stacks))
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(sn.LM_HEAD):
         logits = jnp.einsum("bsd,dv->bsv", h,

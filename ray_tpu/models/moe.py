@@ -12,12 +12,16 @@ new-framework original. Two expert layers live here, one per job:
   expert included), because a served token that loses an expert is a
   wrong answer. Shapes are static in (rows, chunk), nothing syncs with
   the host, and it is the body of the layer `lax.scan` and of the fused
-  decode horizon. Two regimes, chosen from the shapes it is traced with:
-  few tokens (decode) multiply every row by every expert, since every
-  expert's weights are read whatever the routing and the extra FLOPs
-  hide under that read; many tokens (prefill) sort the assignments by
-  expert and multiply them as ragged groups (`jax.lax.ragged_dot`), so
-  the work follows the `tokens x top_k` assignments.
+  decode horizon. Three regimes, chosen from the shapes it is traced
+  with: a few tokens (a decode step, most of whose rows may be dead
+  slots) multiply every row by every expert some LIVE row chose and read
+  no other expert's weights (`ops.hit_experts`: what is read follows the
+  number hit, which the program counts each step); a chunk multiplies
+  every row by every expert, since its tokens hit them all and the extra
+  FLOPs hide under that read; many tokens (a prefill group) sort the
+  assignments by expert and multiply them as ragged groups
+  (`jax.lax.ragged_dot`), so the work follows the `tokens x top_k`
+  assignments.
 - TRAINING, `_moe_ffn` under `moe_forward`: GShard/Switch-style static
   capacity, dispatch/combine as one-hot einsums; assignments over an
   expert's capacity ARE dropped there (the aux load-balancing loss keeps
@@ -44,6 +48,7 @@ from ray_tpu.models.llama import (LlamaConfig, _attention_call,
                                   _layer_checkpoint, _layer_shapes,
                                   _rmsnorm, _rope)
 from ray_tpu.ops import scope_names as sn
+from ray_tpu.ops.hit_experts import hit_experts_ffn
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_to_mesh
 
 Params = Dict[str, Any]
@@ -232,8 +237,22 @@ def qk_norm(q: jax.Array, k: jax.Array, layer: Params, cfg: MoeConfig
 # the sort, the gathers and `ragged_dot`'s small groups up to about 700
 # tokens. Measured on a v5e at OLMoE's widths (PR 26, PERF.md section 6),
 # ms a layer, all-experts / sorted: 64 tokens 1.22 / 2.60, 256 1.25 /
-# 2.75, 512 2.30 / 3.03; sorted alone: 2048 4.57, 4096 7.83.
+# 2.75, 512 2.30 / 3.03; sorted alone: 2048 4.57, 4096 7.83. The decode
+# program alone, 32 slots of which 4 / 8 / 16 / 32 live (PR 34), ms a
+# token, this form / the one below: 17.46 / 9.39 (26.7 experts hit), 17.81
+# / 12.72 (42.0), 18.43 / 16.20 (56.4), 19.75 / 18.75 (62.7).
 DENSE_EXPERTS_MAX_TOKENS = 512
+# Up to this many tokens the all-experts form of a whole layer reads only
+# the experts a row that is READ chose (`ops.hit_experts`): every hit
+# expert still multiplies every row, which costs the MXU no more while a
+# weight tile it is handed serves all the rows in one pass (128 of them).
+# What is read follows the number hit, counted on the device each step;
+# with every expert hit the kernel still streams no slower than XLA's
+# einsum. Measured on a v5e at OLMoE's widths (PR 34, PERF.md section 6),
+# ms for 12 layers, all-experts einsum / hit-only: 32 rows of which 8 live
+# 13.82 / 8.63 (42 experts hit), 32 live 13.82 / 12.80 (63.3); 64 rows,
+# all live 13.49 / 12.96; 128 rows 13.53 / 13.00 (64 hit).
+HIT_EXPERTS_MAX_TOKENS = 128
 # A layer that HOLDS a share of the experts computes, all-experts, E_held
 # rows a token where top_k * E_held / E land (32 times the work at 16 of
 # 256, 8 a token): there the all-experts form stops at the tokens whose
@@ -308,6 +327,29 @@ def _held_hit(xf, combine, w1, w3, w2, first, eh: int, dt):
                              jnp.zeros((g, d), jnp.float32)).astype(dt)
 
 
+def hit_experts_only(cfg, tokens: int) -> bool:
+    """Whether `moe_ffn_dropless` takes, for this many tokens, the form
+    that reads only the experts a live row chose (and so wants the stacks
+    of all layers: ``expert_stack_layer``)."""
+    return cfg.held_experts is None and tokens <= HIT_EXPERTS_MAX_TOKENS
+
+
+def _compact_hit(combine):
+    """``combine`` [G, E] -> (the columns of the experts some row chose,
+    moved to the front in expert order, as rows [E, G]; their ids [E]
+    int32; how many, int32). Rows and ids past the count are 0. A one-hot
+    select and a sum: no sort, no scatter."""
+    e = combine.shape[1]
+    chosen = jnp.any(combine != 0.0, axis=0)                     # [E]
+    place = jnp.cumsum(chosen, dtype=jnp.int32) - 1
+    # [E entries, E experts]: entry i is expert e
+    at = chosen[None, :] & (place[None, :] == jnp.arange(e)[:, None])
+    ids = jnp.sum(jnp.where(at, jnp.arange(e, dtype=jnp.int32)[None, :], 0),
+                  axis=1)
+    cw = jnp.sum(jnp.where(at[:, None, :], combine[None], 0.0), axis=2)
+    return cw, ids, chosen.sum(dtype=jnp.int32)
+
+
 def _held_sorted(xf, weights, idx, w1, w3, w2, lo: int, e: int, dt,
                  first=0, eh: Optional[int] = None):
     """The sorted form over the assignments that land on the held experts
@@ -371,7 +413,8 @@ def _held_sorted(xf, weights, idx, w1, w3, w2, lo: int, e: int, dt,
 
 def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
                      live: Optional[jax.Array] = None,
-                     expert_stack_layer=None
+                     expert_stack_layer=None,
+                     read: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The serving expert layer: x [B,S,d] (already normed) -> (sum over
     each token's top-k experts of p_e * down_e(silu(gate_e x) * up_e x)
@@ -391,10 +434,17 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
     assignments that landed on a held expert. None -> no counters are
     traced at all.
 
-    ``expert_stack_layer`` (a layer that holds a share only): the stacks
-    ``we_*`` are then those of ALL layers, ``[L * E_held, ...]``, and this
-    is the index of the layer whose experts to use (traced: a layer
-    scan's). None: the stacks are this layer's."""
+    ``expert_stack_layer`` (a layer that holds a share, or one at the few
+    tokens of `hit_experts_only`): the stacks ``we_*`` are then those of
+    ALL layers, ``[L * E_held, ...]``, and this is the index of the layer
+    whose experts to use (traced: a layer scan's). None: the stacks are
+    this layer's.
+
+    ``read`` [B,S] bool, where it differs from ``live``: the rows whose
+    output anyone reads (a prefill group's padding rows repeat another
+    row, are counted once and READ twice). At the few tokens of
+    `hit_experts_only` a row nobody reads chooses nothing: its expert
+    output is zero, and an expert only such rows chose is not read."""
     dt = cfg.dtype
     b, s, d = x.shape
     g, e, k = b * s, cfg.n_experts, cfg.top_k
@@ -414,6 +464,7 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
     lo, eh = (0, e) if held is None else (held[0], held[1] - held[0])
     dense = g <= (DENSE_EXPERTS_MAX_TOKENS if held is None
                   else DENSE_HELD_MAX_TOKENS)
+    hit_only = hit_experts_only(cfg, g)
     rows = np.int32(g * (eh if dense else k))
     first = 0 if expert_stack_layer is None else expert_stack_layer * eh
     if dense:
@@ -425,8 +476,17 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
                 jax.nn.one_hot(idx if held is None else idx - lo, eh,
                                dtype=jnp.float32)
                 * weights[..., None], axis=1)
+            if hit_only:
+                read = live if read is None else read
+                if read is not None:   # a dead row chooses nothing
+                    combine = jnp.where(read.reshape(g, 1), combine, 0.0)
+                cw, ids, n_hit = _compact_hit(combine)
         with jax.named_scope(sn.MOE_EXPERTS):
-            if held is not None:
+            if hit_only:
+                out = hit_experts_ffn(xf, cw, first + ids, n_hit,
+                                      w1, w3, w2).astype(dt)
+                rows = n_hit * np.int32(g)
+            elif held is not None:
                 out = _held_hit(xf, combine, w1, w3, w2, first, eh, dt)
                 rows = jnp.any(combine != 0.0, axis=0).sum(
                     dtype=jnp.int32) * np.int32(g)

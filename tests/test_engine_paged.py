@@ -475,6 +475,31 @@ def _preempt_and_swap_round_trip(nano_model, mode):
     assert eng.kv_pool.blocks_in_use == 0        # all returned
 
 
+def test_the_first_preemption_compiles_nothing(nano_model):
+    """A swap engine runs its gather and scatter once a chain length (a
+    power of two of blocks) when it is built, so the first preemption,
+    which comes when the pool has just run dry, finds them compiled: the
+    two programs' caches do not grow while rows are swapped out and in."""
+    from ray_tpu.models import engine as E
+
+    cfg, params = nano_model
+    jax.clear_caches()
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=MAX_LEN,
+                       kv_block_tokens=T,
+                       kv_pool_bytes=_pool_bytes(cfg, 10),
+                       prefix_cache=False, greedy=True)
+    sizes = (E._swap_out_gather._cache_size(),
+             E._swap_in_scatter._cache_size())
+    assert min(sizes) >= (MAX_LEN // T).bit_length()
+    for p in ([7, 8, 9, 10, 11], [3, 1, 4, 1, 5], [2, 7, 1, 8, 2],
+              [9, 9, 8, 8, 7]):
+        eng.submit(p, 12)
+    eng.run()
+    assert eng.stats()["swap_ins"] >= 1
+    assert sizes == (E._swap_out_gather._cache_size(),
+                     E._swap_in_scatter._cache_size())
+
+
 @pytest.mark.parametrize("family", ["llama", "olmoe"])
 def test_preempt_recompute_identity(nano_model, nano_olmoe, family):
     """preempt="recompute" drops the victim's blocks and replays

@@ -201,6 +201,114 @@ def test_dead_rows_change_no_live_row_and_no_counter():
         assert int(stats[0]) == (rows // 2) * chunk * cfg.top_k
 
 
+# -- (d2) few tokens: only the experts a live row chose are read ----------------
+
+def _routed_layer(routing, cfg, rows):
+    """A layer, rows `x` and the (expert, expert) each row chooses: the
+    router is solved for (`pinv`: 32 rows of 64 values have full rank) so
+    that ``x @ w_router`` IS a table of logits with the two chosen experts
+    on top."""
+    e = cfg.n_experts
+    layer = jax.tree_util.tree_map(
+        lambda a: a[0], moe_init(jax.random.PRNGKey(21), cfg)["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(22), (rows, 1, cfg.dim))
+    g = np.arange(rows)
+    if routing == "even":
+        rng = np.random.RandomState(3)
+        choice = np.stack([rng.permutation(e)[:2] for _ in g])
+    elif routing == "collapsed":
+        choice = np.tile([7, 3], (rows, 1))
+    elif routing == "every_expert":
+        choice = np.stack([2 * g % e, (2 * g + 1) % e], axis=1)
+    else:   # a table by hand: rows 0..7 keep to experts 0..8, the others
+        #     to 9..15, so with 8 live rows seven experts have only dead
+        #     rows on them
+        choice = np.where((g < 8)[:, None],
+                          np.stack([g % 6, 6 + g % 3], axis=1),
+                          np.stack([12 + g % 4, 9 + g % 3], axis=1))
+    logits = np.random.RandomState(4).uniform(-1, 1, (rows, e))
+    logits[g, choice[:, 0]] = 4.0
+    logits[g, choice[:, 1]] = 3.0
+    layer["w_router"] = jnp.asarray(
+        np.linalg.pinv(np.asarray(x[:, 0], np.float64)) @ logits,
+        jnp.float32)
+    return layer, x, choice
+
+
+@pytest.mark.parametrize("live", ["none", "one", "8_of_32", "all", "no_mask"])
+@pytest.mark.parametrize("routing", ["even", "collapsed", "every_expert",
+                                     "table"])
+def test_few_tokens_read_only_the_experts_a_live_row_chose(routing, live,
+                                                           monkeypatch):
+    """32 rows through the hit-only form (the kernel in interpret mode)
+    and through the all-experts einsum on the same inputs: live rows'
+    outputs agree, a dead row's expert output is zero, the form visits
+    exactly the experts some LIVE row chose, in order (one chosen by dead
+    rows alone is not among them), and `rows` is what `_held_hit` reports:
+    experts hit x all the rows."""
+    from ray_tpu.ops import hit_experts
+
+    rows = 32
+    cfg = _cfg(n_experts=16)
+    layer, x, choice = _routed_layer(routing, cfg, rows)
+    mask = {"none": np.zeros(rows, bool), "one": np.arange(rows) == 5,
+            "8_of_32": np.arange(rows) < 8, "all": np.ones(rows, bool),
+            "no_mask": np.ones(rows, bool)}[live]
+    lv = None if live == "no_mask" else jnp.asarray(mask)[:, None]
+    seen = {}
+
+    def spy(x, cw, ids, n_hit, *stacks, **kw):
+        seen.update(ids=np.asarray(ids), n=int(n_hit), cw=np.asarray(cw))
+        got = hit_experts.hit_experts_ffn(x, cw, ids, n_hit, *stacks, **kw)
+        np.testing.assert_allclose(
+            got, hit_experts.hit_experts_ffn_reference(
+                x, cw, ids, n_hit, *stacks), atol=1e-5, rtol=0)
+        return got
+
+    monkeypatch.setattr(moe, "hit_experts_ffn", spy)
+    assert moe.hit_experts_only(cfg, rows)
+    got, stats = moe.moe_ffn_dropless(x, layer, cfg, lv)
+    monkeypatch.setattr(moe, "HIT_EXPERTS_MAX_TOKENS", 0)
+    assert not moe.hit_experts_only(cfg, rows)
+    want, want_stats = moe.moe_ffn_dropless(x, layer, cfg, lv)
+
+    chosen = sorted(set(choice[mask].reshape(-1).tolist()))
+    assert seen["ids"][:seen["n"]].tolist() == chosen
+    assert not seen["cw"][seen["n"]:].any()
+    np.testing.assert_allclose(got[mask], want[mask], atol=1e-5, rtol=0)
+    assert not np.asarray(got)[~mask].any()
+    if lv is None:
+        assert stats is None and want_stats is None
+    else:
+        assert stats.tolist() == [int(mask.sum()) * cfg.top_k,
+                                  len(chosen) * rows, len(chosen)]
+        assert want_stats.tolist() == [int(mask.sum()) * cfg.top_k,
+                                       cfg.n_experts * rows, len(chosen)]
+
+
+def test_a_prefill_groups_padding_row_is_read_though_counted_once():
+    """Three short prompts admitted together prefill as a group of four:
+    the fourth row repeats the third, is left out of the counters, and
+    writes its K/V onto its twin's. At these few tokens the expert layer
+    zeroes the rows nobody reads, so the repeat must count as read (a
+    zeroed twin wrote other K/V over the third prompt's: caught as wrong
+    tokens). Same tokens as solo generate."""
+    cfg = _cfg()
+    params = moe_init(jax.random.PRNGKey(12), cfg)
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(1, 256, size=n).tolist() for n in (5, 7, 6)]
+    assert moe.hit_experts_only(cfg, 4 * 8)
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=64,
+                       kv_block_tokens=8, decode_horizon=2)
+    ids = [eng.submit(p, 6) for p in prompts]
+    out = eng.run()
+    assert eng.stats()["prefill_dispatches"] == 1
+    for rid, p in zip(ids, prompts):
+        assert out[rid] == _solo(params, cfg, p, 6)
+    assert eng.stats()["moe_assignments_total"] == \
+        (sum(map(len, prompts)) + 3 * 5) * cfg.top_k * cfg.n_layers
+
+
 @pytest.mark.parametrize("slots,bucket", [(1, False), (4, True)])
 def test_counters_count_live_tokens_only(slots, bucket):
     """One request of 5 prompt tokens and 4 new ones, alone in an engine
@@ -239,7 +347,8 @@ def test_sorted_regime_through_the_engine():
         assert out[rid] == _solo(params, cfg, p, 1)
     s = eng.stats()
     assert s["prefill_dispatches"] == 1
-    decode_rows = s["moe_decode_layer_steps_total"] * 4 * cfg.n_experts
+    # a decode step multiplies its 4 rows by the experts that were hit
+    decode_rows = s["moe_decode_experts_hit_total"] * 4
     assert s["moe_rows_computed_total"] - decode_rows \
         == 4 * 256 * cfg.top_k * cfg.n_layers
     assert s["moe_assignments_total"] == 4 * 150 * cfg.top_k * cfg.n_layers

@@ -233,7 +233,7 @@ def test_decode_program_moves_nothing_of_the_pools_size(v5e, case):
     in-place `kv_write` scatter fusions (K and V) — no copy, slice,
     reshape or restack (before PR 27: two copies of the whole pool a
     token and six layer-sized ops a layer, 4.23 GiB of workspace) — and
-    the program keeps ONE Pallas kernel."""
+    the program keeps ONE Pallas kernel of attention."""
     cfg, nb, mb, quant = {
         "mistral": (_mistral(12), 1878, 128, None),
         "olmoe": (_olmoe(12), 615, 64, None),
@@ -247,14 +247,51 @@ def test_decode_program_moves_nothing_of_the_pools_size(v5e, case):
     fusions = [ln for ln in text.splitlines()
                if re.search(r"= \w+\[%s\]\S* fusion\(" % dims, ln)]
     assert len(fusions) == 2 and all("kv_write" in ln for ln in fusions)
-    assert text.count("tpu_custom_call") == 1
+    # ... beside, for OLMoE, the expert layer's (`ops/hit_experts.py`)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == (2 if case == "olmoe" else 1)
     # a decode row's kernel asks for no VMEM of its own: what it may
     # take, XLA cannot give the program, which then reads a stacked
     # weight it kept in VMEM from HBM again (+1.4 % a token, PR 30)
-    call = next(ln for ln in text.splitlines() if "tpu_custom_call" in ln)
-    assert '"scoped_memory_configs":[]' in call
+    assert all('"scoped_memory_configs":[]' in ln for ln in calls)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (1 << 30), f"{temp / 2**30:.2f} GiB of workspace"
+
+
+def test_olmoe_decode_program_reads_experts_where_they_lie(v5e):
+    """The `olmoe-chat-short` cell's decode program (12 layers, 615
+    blocks, 32 slots) with the expert layer that reads only the experts a
+    live row chose (PR 34): the kernel is handed ALL layers' expert stacks
+    and the layer's index, so the optimized HLO makes nothing of a layer's
+    64 experts (a `copy` or `dynamic-slice` of one, which a kernel operand
+    sliced out of the layer scan's would be: 0.75 GiB written and read
+    again a layer) and nothing of the stacks' size, and its workspace
+    stays what the all-experts program's was: next to nothing (peak
+    12.44 GB = the arguments, both as compiled here). A prefill chunk of
+    256 tokens keeps the all-experts einsum: no second kernel there."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops import scope_names as sn
+
+    cfg = _olmoe(12)
+    compiled, _ = _decode_program(v5e, cfg, 615, 64)
+    text = compiled.as_text()
+    assert moe.hit_experts_only(cfg, 32)
+    assert sum(sn.HIT_EXPERTS_KERNEL in ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln) == 1
+    e, d, f = cfg.n_experts, cfg.dim, cfg.ffn_dim
+    stacks = {f"{n},{d},{f}" for n in (e, e * cfg.n_layers)} \
+        | {f"{n},{f},{d}" for n in (e, e * cfg.n_layers)} \
+        | {f"{cfg.n_layers},{e},{d},{f}", f"{cfg.n_layers},{e},{f},{d}"}
+    made = [(op, dims) for types, op in _HLO_LINE.findall(text)
+            if op not in _PLUMBING
+            for dims in re.findall(r"\w+\[([\d,]+)\]", types)
+            if dims in stacks]
+    assert not made, made
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (64 << 20), f"{temp / 2**20:.1f} MiB of workspace"
+    assert not moe.hit_experts_only(cfg, 256)
+    chunk, _ = _prefill_program(v5e, cfg, 615, 64, 1, 256)
+    assert chunk.as_text().count("tpu_custom_call") == 1
 
 
 def test_decode_program_fits_16_layers_beside_a_5_gib_pool(v5e):
