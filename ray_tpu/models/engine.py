@@ -113,6 +113,7 @@ from ray_tpu.parallel.mesh import create_mesh
 from ray_tpu.parallel.sharding import (DEFAULT_RULES, named_sharding,
                                        prune_rules_for_mesh,
                                        shard_pytree)
+from ray_tpu.util.compile_cache import ledger as _compile_ledger
 
 Params = Dict[str, Any]
 
@@ -1430,6 +1431,10 @@ class DecodeEngine:
                                        if enable_metrics else "engine")
         self.trace = resolve_tracer(trace, engine_id=self.engine_id,
                                     clock=clock)
+        # The PROCESS's compile ledger (util/compile_cache.py), installed
+        # here at the latest so that every program this engine builds is
+        # counted; `stats()` carries its three totals.
+        self._compiles = _compile_ledger()
         # Runtime sanitizer (_private/sanitize.py): `sanitize=` takes a
         # Sanitizer, True (build a strict one), False (force off), or
         # None — defer to the RAY_TPU_SANITIZE env gate. When present it
@@ -2743,6 +2748,11 @@ class DecodeEngine:
         # time less this is the host's own work.
         out["device_waits"] = float(self.device_waits)
         out["device_wait_s"] = float(self.device_wait_s)
+        # Compile plane: programs JAX built since the PROCESS started
+        # (every engine of a process reports the same three; a rollup
+        # takes them once). A warmed engine holds them still: one that
+        # rises met a shape its warm-up missed (docs/serving.md).
+        out.update(self._compiles.counters())
         # Tensor-parallel plane: tp_degree is 1 for an unsharded
         # engine; transfer bytes count the [H, B] token blocks pulled
         # at drain — the replicated choke point, so bytes/token must

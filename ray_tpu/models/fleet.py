@@ -93,6 +93,7 @@ from ray_tpu.models.engine import _key_data
 from ray_tpu.models.engine_metrics import _Agg
 from ray_tpu.models.engine_trace import resolve_tracer
 from ray_tpu.models.scheduler import EngineDraining, EngineOverloaded
+from ray_tpu.util.compile_cache import ledger as _compile_ledger
 from ray_tpu.util.metrics import Counter, Gauge
 
 __all__ = [
@@ -1842,6 +1843,10 @@ class LLMFleet:
                 sum(s.get("kv_bytes_per_token", 0.0) for s in per)
                 / len(per)) if per else 0.0,
         }
+        # Compile plane: the process's own totals, taken ONCE (every
+        # in-process replica's stats() repeats them; summing would
+        # multiply a compile by the replica count).
+        out.update(_compile_ledger().counters())
         # Speculative plane (all-zero when no replica carries a draft
         # model). Rates are re-derived from the summed raw counters —
         # a proposal-weighted mean — so a busy replica's acceptance

@@ -136,6 +136,42 @@ def test_device_wait_counts_the_seconds_inside_device_get(
     assert s["device_wait_s"] == pytest.approx(0.25 * s["device_waits"])
 
 
+def test_compile_counters_hold_still_when_warm_and_rise_with_a_new_bucket(
+        nano_model):
+    """`stats()` carries the PROCESS's compile ledger: nothing moves over
+    twenty steady decode steps, and a prompt bucket met for the first time
+    raises `compiles_total` by exactly the programs it built."""
+    from ray_tpu.util.compile_cache import ledger
+
+    cfg, params = nano_model
+    keys = ("compiles_total", "compile_cache_misses_total",
+            "compile_s_total")
+    # a shape no other engine of this module has, so its programs are new
+    eng = DecodeEngine(params, cfg, batch_slots=3, max_len=96,
+                       decode_horizon=2)
+    assert set(keys) <= set(eng.stats())
+    eng.submit([5, 6, 7], 60)
+    eng.run()                                   # warm-up
+    eng.submit([5, 6, 8], 60)
+    eng.step()                                  # admitted and prefilled
+    warm = [eng.stats()[k] for k in keys]
+    assert warm[0] >= 2 and warm[2] > 0         # prefill + decode at least
+    for _ in range(20):
+        assert eng.pending()
+        eng.step()
+        assert [eng.stats()[k] for k in keys] == warm
+    eng.run()
+    n = len(ledger().events())
+    eng.submit(list(range(1, 20)), 4)           # bucket 32: never met
+    eng.run()
+    built = [e for e in ledger().events()[n:] if e[4] is not None]
+    assert "_prefill_rows_paged" in {e[0] for e in built}
+    after = [eng.stats()[k] for k in keys]
+    assert after[0] - warm[0] == len(built) >= 1
+    assert after[1] - warm[1] == len(built)     # no persistent cache here
+    assert after[2] - warm[2] == pytest.approx(sum(e[3] for e in built))
+
+
 @pytest.mark.parametrize("chunk", [None, 4], ids=["unchunked", "chunked"])
 @pytest.mark.parametrize("preempt", ["swap", "recompute"])
 def test_ttft_is_the_sum_of_its_three_parts(nano_model, fake_clock, chunk,

@@ -265,6 +265,60 @@ def test_gate_null_tracer_zero_allocations_on_decode_path():
         + "; ".join(str(s) for s in stats[:5]))
 
 
+def test_gate_compile_ledger_silent_on_a_warmed_decode_loop():
+    """Gate (PR 36, set-up accounting): the compile ledger is fed by
+    `jax.monitoring`, and a `jit` call that is already compiled fires no
+    event: over 200 warmed decode steps JAX calls no listener at all (so
+    none of the ledger's) and not a byte is allocated inside
+    compile_cache.py. It fails the day a JAX upgrade puts an event on the
+    cached path, or the engine's step loop starts touching the ledger."""
+    import tracemalloc
+
+    jax = pytest.importorskip("jax")
+    import jax.monitoring as monitoring
+    from ray_tpu.models import LlamaConfig, llama_init
+    from ray_tpu.models.engine import DecodeEngine
+    from ray_tpu.util import compile_cache
+
+    cfg = LlamaConfig.nano()
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=128,
+                       decode_horizon=1)
+    led = compile_cache.ledger()
+    assert eng._compiles is led
+    eng.submit([5, 6, 7], 100)
+    eng.run()                        # compile outside the window
+    calls = []
+
+    def heard(event, *a, **k):       # beside the ledger's own listeners
+        calls.append(event)
+
+    monitoring.register_scalar_listener(heard)
+    monitoring.register_event_listener(heard)
+    monitoring.register_event_duration_secs_listener(heard)
+    n, built = len(led.events()), led.builds
+    tracemalloc.start()
+    try:
+        for i in range(200):
+            if not eng.pending():    # the same shapes over again
+                eng.submit([5, 6, 8 + i], 100)
+            eng.step()
+        snap = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+        monitoring.unregister_scalar_listener(heard)
+        monitoring.unregister_event_listener(heard)
+        monitoring.unregister_event_duration_listener(heard)
+    stats = snap.filter_traces([tracemalloc.Filter(
+        True, compile_cache.__file__)]).statistics("lineno")
+    total = sum(s.size for s in stats)
+    assert eng.steps_total >= 200 and calls == []
+    assert len(led.events()) == n and led.builds == built
+    assert total == 0, (
+        f"the compile ledger allocated {total} bytes on the decode path: "
+        + "; ".join(str(s) for s in stats[:5]))
+
+
 def test_gate_armed_idle_fault_injector_zero_allocations():
     """Gate (r13, fault injection): a FaultInjector ARMED on an engine
     but with nothing to inject (no script for this replica, no random
