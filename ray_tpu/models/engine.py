@@ -1626,6 +1626,11 @@ class DecodeEngine:
         # `_count_paged_walk`).
         self.paged_walk_pages_total = 0    # live pages, active rows
         self.paged_walk_entries_total = 0  # B * MB per decode token
+        # Rows (decode) or query tiles (prefill) the kernel walks, and
+        # those whose first pages the row before them in the call
+        # fetched: the kernel's rule, a row that walks hands on.
+        self.paged_walk_rows_total = 0
+        self.paged_walk_rows_chained_total = 0
         # The same for prefill dispatches (`_count_prefill_walk`).
         self.prefill_walk_pages_total = 0  # pages the chunks' tiles walk
         self.prefill_table_entries_total = 0   # n_pad * MB per dispatch
@@ -2525,6 +2530,10 @@ class DecodeEngine:
         self.paged_walk_pages_total += int(
             np.minimum(slots // T + 1, self._mb).sum())
         self.paged_walk_entries_total += H * self.B * self._mb
+        # every slot of the grid has a query slot, a dead row's too: all
+        # B rows walk and all but a call's first find their pages coming
+        self.paged_walk_rows_total += H * self.B
+        self.paged_walk_rows_chained_total += H * (self.B - 1)
         if isinstance(self.cfg, MlaConfig):
             self._count_selection(slots + 1, decode=True)
         if self._hybrid:
@@ -2583,9 +2592,15 @@ class DecodeEngine:
         first = np.arange(0, bucket, tq)                    # [tiles]
         top = np.minimum(first + tq - 1, last_idx[:, None])
         pages = np.minimum((starts[:, None] + top) // T + 1, self._mb)
-        self.prefill_walk_pages_total += int(
-            pages[first <= last_idx[:, None]].sum())
+        walks = first <= last_idx[:, None]                  # [rows, tiles]
+        self.prefill_walk_pages_total += int(pages[walks].sum())
         self.prefill_table_entries_total += len(starts) * self._mb
+        # the call's grid is the tiles row by row: one that walks finds
+        # its first pages fetched if the tile before it walks too
+        walks = walks.reshape(-1)
+        self.paged_walk_rows_total += int(walks.sum())
+        self.paged_walk_rows_chained_total += int(
+            (walks[1:] & walks[:-1]).sum())
 
     def _top_up_pipeline(self, rows: List[int],
                          horizon: Optional[int]) -> None:
@@ -2812,6 +2827,9 @@ class DecodeEngine:
         out["paged_walk_pages_total"] = float(self.paged_walk_pages_total)
         out["paged_walk_entries_total"] = float(
             self.paged_walk_entries_total)
+        out["paged_walk_rows_total"] = float(self.paged_walk_rows_total)
+        out["paged_walk_rows_chained_total"] = float(
+            self.paged_walk_rows_chained_total)
         out["prefill_walk_pages_total"] = float(
             self.prefill_walk_pages_total)
         out["prefill_table_entries_total"] = float(

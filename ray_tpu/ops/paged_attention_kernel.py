@@ -43,13 +43,24 @@ fifths of a decode token, PERF.md PR 27). Inside the grid step a
 ``fori_loop`` of ``cdiv(n_live, P)`` compute steps runs, each over
 ``P`` pages (``P*T`` = 512 keys, or what fits 1 MiB a buffer slot),
 which the kernel fetches itself: one ``make_async_copy`` per live page
-through the scalar-prefetched block table into slot ``i % 2`` of a
-double buffer, step ``i+1``'s copies started before step ``i``'s are
-waited for. A page of a tile's last step that lies past ``n_live`` is
-not fetched; its place in the V buffer is zeroed first, so stale VMEM
-(``0 * NaN``) cannot reach the accumulator, and stale K only reaches
-scores the mask replaces. A tile with ``n_live == 0`` walks nothing and
-returns 0.
+through the scalar-prefetched block table into one slot of a double
+buffer, the next step's copies started into the other slot before this
+step's are waited for. **The rows of a call are ONE such pipeline, in
+grid order**: at a row's last step "the next step" is step 0 of the row
+after it, read from the scalar-prefetched refs at ``b + 1``, so a row
+finds its first pages in flight and does not start them itself (PERF.md
+PR 38; before, every row stood still for its first copy). The slot a row
+starts in and whether the row before it ran a step (a row that walks
+nothing runs no step and fetches for nobody: the row after it starts its
+own copies, as the first row of a call does) are two words of SMEM
+scratch that a row leaves for the next, so the grid axis is
+``"arbitrary"``: the rows of a call are ordered. Nothing else passes
+from row to row: a row's output is, bit for bit, what it is in a call of
+its own. A page of a tile's last step that lies past ``n_live`` is
+not fetched, by whichever row started the step; its place in the V
+buffer is zeroed first, so stale VMEM (``0 * NaN``) cannot reach the
+accumulator, and stale K only reaches scores the mask replaces. A tile
+with ``n_live == 0`` walks nothing and returns 0.
 
 Queries arrive regrouped as ``[B, KV, S*G, D]`` so one KV head's query
 group of a row is one ``[S*G, D]`` matmul operand against the step's
@@ -87,13 +98,17 @@ pool of three layers that hold different data, run in interpret mode
 (tests/test_engine_kv_quant.py). On a TPU `impl="auto"` routes here;
 elsewhere it stays on the reference path and this kernel runs only when
 asked for explicitly (then in interpret mode). Timed on a v5e (PERF.md
-PR 25, PR 27, PR 30): 0.28 ms a decode call at the benchmark's shape
-where the full walk took 2.92, and the same through the whole pool's
-ref as through a layer's view; a row costs about 3 us before its first
-page (the first copy is not overlapped with the row before), a 512-key
-step about 3.4 us against 2.6 of HBM time (ROADMAP S2 keeps what is
-left); a 512-token chunk at start 0 0.15 ms a layer, at start 2,560
-0.44.
+PR 25, PR 27, PR 30, PR 38): 0.28 ms a decode call at the benchmark's
+shape where the full walk took 2.92, and the same through the whole
+pool's ref as through a layer's view. Until PR 38 a row stood still for
+its first copy, about 3 us; with the rows one pipeline the decode
+PROGRAM, timed alone, lost 1.5-2.1 us a live row of a call (Mistral, 32
+rows x 12 layers: 9.41 -> 8.83 ms a token at 256 tokens a row, 11.85 ->
+11.05 at 1,024; Phi-4-mini-flash, 64 rows x 16 calls: 24.04 -> 21.87)
+and 0.6 us a dead row (one short copy); a call's first row, and a row
+behind one that walks nothing, still wait. A 512-key step takes about
+3.4 us against 2.6 of HBM time (ROADMAP S2 keeps what is left); a
+512-token chunk at start 0 0.15 ms a layer, at start 2,560 0.44.
 """
 from __future__ import annotations
 
@@ -250,9 +265,13 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     row (the walk's end), ``lay_ref`` [1] the pool's layer and, for a
     sliding-window layer (``window``), one more: [B] the page each row's
     walk STARTS at (`first_page`); keys at or behind ``q_slot - window``
-    are masked, inside the first page too."""
+    are masked, inside the first page too. The last scratch ref,
+    ``chain_ref`` (SMEM [2]), is what row b - 1 left for this one: the
+    buffer slot its last step fetched into (this row's step 0 runs
+    there) and whether it ran a step at all."""
     if window is not None:
         fp_ref, *refs = refs
+    *refs, chain_ref = refs
     if has_scale:
         (qs_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, k_buf, v_buf,
          sem, acc_ref, m_ref, l_ref) = refs
@@ -260,54 +279,71 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
         (qs_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc_ref,
          m_ref, l_ref) = refs
     b = pl.program_id(0)
+    last_row = _lax.sub(pl.num_programs(0), np.int32(1))
     t, pps = block_tokens, pages_per_step
     span = pps * t                                      # keys per step
-    n_live = nl_ref[b]
     layer = lay_ref[0]
     rows = q_ref.shape[2]                               # tq * G
-    row0 = _mul(b, max_blocks)                          # of the flat table
-    if window is not None:
+
+    def walk_of(r):
+        """Row r's walk: the flat table index of its first page, how
+        many pages, and the page of the row's table it starts at (None
+        without a window: 0)."""
+        row0, n_live = _mul(r, max_blocks), nl_ref[r]
+        if window is None:
+            return row0, n_live, None
         # the walk is the pages [first, n_live): everything below counts
         # from the row's first live page
-        first = fp_ref[b]
-        n_live = _lax.sub(n_live, first)
-        row0 = _add(row0, first)
-    n_steps = _lax.div(_add(n_live, pps - 1), np.int32(pps))
+        first = fp_ref[r]
+        return _add(row0, first), _lax.sub(n_live, first), first
 
-    def live_in(step):
+    row0, n_live, first = walk_of(b)
+    n_steps = _lax.div(_add(n_live, pps - 1), np.int32(pps))
+    # the row after this one, whose step 0 this row's last step fetches;
+    # the last row of the call fetches for nobody
+    next_row0, next_live, _ = walk_of(_lax.min(_add(b, 1), last_row))
+    next_live = _select(_lax.lt(b, last_row), next_live, 0)
+    # what the row before left: the buffer slot this row starts in, and
+    # whether it ran a step (then this row's step 0 is in flight there)
+    slot0 = _select(_lax.eq(b, np.int32(0)), np.int32(0), chain_ref[0])
+    chained = _lax.bitwise_and(_lax.gt(b, np.int32(0)),
+                               _lax.eq(chain_ref[1], np.int32(1)))
+
+    def live_in(step, n_live=n_live):
         """How many of the step's pages lie inside the live prefix."""
         return _lax.min(np.int32(pps), _lax.sub(n_live, _mul(step, pps)))
 
     def page_at(p):
         return pl.ds(pl.multiple_of(_mul(p, t), t), t)
 
-    def page_copies(step, buf, p):
-        """The K and V copy of the step's p-th page into buffer `buf`
-        (a start and its wait build the same descriptor)."""
-        blk = bt_ref[_add(_add(row0, _mul(step, pps)), p)]
+    def page_copies(entry, buf, p):
+        """The K and V copy into buffer `buf` of the p-th page of the
+        step whose first table entry is `entry` (a start and its wait
+        build the same descriptor, be they one row's or two)."""
+        blk = bt_ref[_add(entry, p)]
         dst = page_at(p)
         return (pltpu.make_async_copy(k_hbm.at[layer, blk],
                                       k_buf.at[buf, dst], sem.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[layer, blk],
                                       v_buf.at[buf, dst], sem.at[1, buf]))
 
-    def each_live_page(step, buf, act):
+    def each_page(entry, n_pages, buf, act):
         def one(p, carry):
-            for copy in page_copies(step, buf, p):
+            for copy in page_copies(entry, buf, p):
                 act(copy)
             return carry
-        _lax.fori_loop(0, live_in(step), one, 0)
+        _lax.fori_loop(0, n_pages, one, 0)
 
-    def start(step, buf):
-        each_live_page(step, buf, lambda copy: copy.start())
+    def start(entry, n_pages, buf):
+        each_page(entry, n_pages, buf, lambda copy: copy.start())
 
-    def wait(step, buf):
-        each_live_page(step, buf, lambda copy: copy.wait())
+    def wait(entry, n_pages, buf):
+        each_page(entry, n_pages, buf, lambda copy: copy.wait())
 
     acc_ref[...] = _lax.full(acc_ref.shape, 0.0, jnp.float32)
     m_ref[...] = _lax.full(m_ref.shape, _NEG_INF, jnp.float32)
     l_ref[...] = _lax.full(l_ref.shape, 0.0, jnp.float32)
-    start(0, 0)
+    start(row0, _select(chained, np.int32(0), live_in(0)), slot0)
 
     col = _lax.broadcasted_iota(jnp.int32, (rows, span), 1)
     page_of_col = _lax.div(
@@ -317,11 +353,16 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     zero_page = _lax.full((t, v_buf.shape[2]), 0, v_buf.dtype)
 
     def step_body(i, carry):
-        buf = _lax.rem(i, np.int32(2))
-
-        start(_add(i, 1), _lax.sub(np.int32(1), buf))   # past the last
-        #                                      step: no live page
-        wait(i, buf)
+        buf = _lax.rem(_add(slot0, i), np.int32(2))
+        # the copies of the step after this one, into the other slot:
+        # the row's own next step or, at its last, step 0 of the row
+        # after it, which then finds them in flight
+        ahead = _add(i, 1)
+        at_last = _lax.eq(ahead, n_steps)
+        start(_select(at_last, next_row0, _add(row0, _mul(ahead, pps))),
+              _select(at_last, live_in(0, next_live), live_in(ahead)),
+              _lax.sub(np.int32(1), buf))
+        wait(_add(row0, _mul(i, pps)), live_in(i), buf)
         # A page of the last step past the row's live prefix was not
         # fetched: what the V buffer holds there is stale, and 0 * NaN
         # would reach the accumulator through the matmul. (Stale K only
@@ -383,6 +424,10 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
         return carry
 
     _lax.fori_loop(0, n_steps, step_body, 0)
+    # a row that ran no step left nothing in flight and the slot as it was
+    chain_ref[0] = _lax.rem(_add(slot0, n_steps), np.int32(2))
+    chain_ref[1] = _lax.convert_element_type(
+        _lax.gt(n_steps, np.int32(0)), jnp.int32)
 
     l = l_ref[...]
     l = _select(_lax.eq(l, np.float32(0.0)), _lax.full_like(l, 1.0), l)
@@ -534,6 +579,7 @@ def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
             pltpu.VMEM((KV, rows, D), jnp.float32),
             pltpu.VMEM((KV, rows, 1), jnp.float32),
             pltpu.VMEM((KV, rows, 1), jnp.float32),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     kernel = functools.partial(
@@ -553,7 +599,7 @@ def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES
             if vmem > _VMEM_DEFAULT_BYTES // 2 else None),
         interpret=interpret,

@@ -558,6 +558,20 @@ _RAGGED_STARTS = [0, 2 * _RT - 2, 2 * _RT - 1, 2 * _RT, None, 0]
 _RL = 3                     # layers of the ragged sweep's pool
 
 
+def _quantized(kf, vf, quant):
+    """[L, NB, T, KV, D] f32 draws -> the engine's flat pools and their
+    scales (None for "bf16" and None, which only set the dtype)."""
+    sk = sv = None
+    if quant not in (None, "bf16"):
+        qspec = resolve_kv_quant(quant)
+        sk = block_scale(jnp.max(jnp.abs(kf), axis=(2, 4)), qspec)
+        sv = block_scale(jnp.max(jnp.abs(vf), axis=(2, 4)), qspec)
+        kf = quantize(kf, sk[:, :, None, :, None], qspec)
+        vf = quantize(vf, sv[:, :, None, :, None], qspec)
+    flat = kf.shape[:3] + (-1,)
+    return kf.reshape(flat), vf.reshape(flat), sk, sv
+
+
 def _ragged_case(S, quant, rng, poison=False):
     """q, pools, table, slots, scales for the ragged rows above, every
     table entry of a live row a distinct real block, so that an entry
@@ -575,20 +589,13 @@ def _ragged_case(S, quant, rng, poison=False):
     bt = 1 + np.arange(B * _RMB).reshape(B, _RMB)
     bt[-1] = 0                                       # the retired row
     q = jnp.asarray(rng.randn(B, S, KV * gm, D), dt)
-    sk = sv = None
-    if quant not in (None, "bf16"):
-        qspec = resolve_kv_quant(quant)
-        sk = block_scale(jnp.max(jnp.abs(kf), axis=(2, 4)), qspec)
-        sv = block_scale(jnp.max(jnp.abs(vf), axis=(2, 4)), qspec)
-        kf = quantize(kf, sk[:, :, None, :, None], qspec)
-        vf = quantize(vf, sv[:, :, None, :, None], qspec)
+    kf, vf, sk, sv = _quantized(kf, vf, quant)
     if poison:
         live = q_slots.max(axis=1) // _RT + 1
         dead = np.concatenate([bt[b, live[b]:] for b in range(B - 1)])
         kf = kf.at[:, dead].set(jnp.nan)
         vf = vf.at[:, dead].set(jnp.nan)
-    return (q, kf.reshape(_RL, NB, _RT, KV * D),
-            vf.reshape(_RL, NB, _RT, KV * D), jnp.asarray(bt, jnp.int32),
+    return (q, kf, vf, jnp.asarray(bt, jnp.int32),
             jnp.asarray(q_slots, jnp.int32), sk, sv)
 
 
@@ -672,6 +679,171 @@ def test_kernel_never_reads_past_the_live_prefix(monkeypatch, quant, slots,
     assert jnp.array_equal(outs[0], outs[1])
 
 
+# -- the rows of a call are one pipeline: a row's last step fetches the
+# -- first pages of the row after it, and no row's bits know its neighbours
+
+_PMB, _PPS = 8, 2           # pipeline sweep: tables of 8, steps of 2 pages
+# a row by the pages it walks -> its last query's slot: none (a filler row
+# at slot -1), one page, exactly a step, a step and a page, the whole table
+_LAST_SLOT = {0: None, 1: _RT - 1, _PPS: _PPS * _RT - 2,
+              _PPS + 1: (_PPS + 1) * _RT - 3, _PMB: _PMB * _RT - 1}
+_ROW_ORDERS = {
+    "empty_first": [0, 1, _PPS, _PPS + 1, _PMB, 1, _PMB, _PPS],
+    "empty_last": [_PMB, 1, _PPS, _PPS + 1, 1, _PPS + 1, _PMB, 0],
+    "two_empty_in_a_row": [1, 0, 0, _PMB, _PPS, 0, _PPS + 1, 1],
+}
+
+
+def _pipeline_case(last_slots, S, quant, rng, mb=_PMB, t=_RT):
+    """q, pools (two layers, pages of ``t``), table, slots, scales: row
+    b's S queries end at ``last_slots[b]`` (None: every slot -1), every
+    table entry a real block of its own."""
+    B, KV, D, gm = len(last_slots), 2, 16, 2
+    q_slots = np.stack([
+        np.full(S, -1) if last is None else
+        np.maximum(last - S + 1 + np.arange(S), -1) for last in last_slots])
+    NB = 1 + B * mb
+    dt = jnp.bfloat16 if quant == "bf16" else jnp.float32
+    kf, vf, sk, sv = _quantized(
+        jnp.asarray(rng.randn(2, NB, t, KV, D), dt),
+        jnp.asarray(rng.randn(2, NB, t, KV, D), dt), quant)
+    bt = 1 + np.arange(B * mb).reshape(B, mb)
+    q = jnp.asarray(rng.randn(B, S, KV * gm, D), dt)
+    return (q, kf, vf, jnp.asarray(bt, jnp.int32),
+            jnp.asarray(q_slots, jnp.int32), sk, sv)
+
+
+def _each_row_alone(q, kf, vf, bt, q_slots, **kw):
+    """The kernel's answer for every row in a call of its own (with the
+    seven filler rows that make the call's eight)."""
+    return jnp.concatenate([
+        paged_attention(q[b:b + 1], kf, vf, bt[b:b + 1], q_slots[b:b + 1],
+                        impl="flash", **kw) for b in range(q.shape[0])])
+
+
+@pytest.mark.parametrize("n_rows", [8, 16], ids=["one_eight", "two_eights"])
+@pytest.mark.parametrize("order", list(_ROW_ORDERS))
+@pytest.mark.parametrize("slots", [1, 4], ids=["s1", "s4"])
+@pytest.mark.parametrize("quant", ["bf16", "int8", "fp8_e4m3"],
+                         ids=["bf16", "int8", "fp8"])
+def test_kernel_rows_of_a_call_are_one_pipeline_and_each_its_own(
+        monkeypatch, quant, slots, order, n_rows):
+    """Rows that walk 0, 1, exactly pps, pps + 1 and all MB pages, with
+    an empty row first, last and two in a row: the batch gives, BIT FOR
+    BIT, what each row gives in a call of its own (who fetched a row's
+    first pages, and into which buffer slot, leaves no trace), it is the
+    reference's within the parity tolerance, and the live walk is the
+    walk of all MB entries."""
+    monkeypatch.setattr(pak, "_KEYS_PER_STEP", _PPS * _RT)
+    kinds = _ROW_ORDERS[order]
+    if n_rows == 16:
+        kinds = kinds + kinds[::-1]
+    rng = np.random.RandomState(41 + slots + n_rows)
+    q, kf, vf, bt, q_slots, sk, sv = _pipeline_case(
+        [_LAST_SLOT[k] for k in kinds], slots, quant, rng)
+    assert pak.live_pages(q_slots, _PMB * _RT, _RT, _PMB).tolist() == kinds
+    kw = dict(layer=1, kv_valid_len=_PMB * _RT, k_scale=sk, v_scale=sv)
+    got = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
+    assert jnp.array_equal(got, _each_row_alone(q, kf, vf, bt, q_slots,
+                                                **kw))
+    ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference", **kw)
+    tol = 2e-2 if quant == "bf16" else 2e-5
+    asked = np.asarray(q_slots) >= 0
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[asked],
+        np.asarray(ref, np.float32)[asked], atol=tol, rtol=tol)
+    assert not np.asarray(got, np.float32)[~asked].any()
+    pps, tq = pak.walk_shape(slots, 4, 2, 16, _RT, _PMB, kf.dtype.itemsize)
+    assert (pps, tq) == (_PPS, slots)
+    full = pak._walk(q, bt, q_slots,
+                     n_live=jnp.full((n_rows,), _PMB, jnp.int32),
+                     k_pool=kf, v_pool=vf, k_scale=sk, v_scale=sv,
+                     layer=np.int32(1), kv_valid_len=np.int32(_PMB * _RT),
+                     sm_scale=16 ** -0.5, interpret=True, pps=pps,
+                     head_loop=False)
+    assert jnp.array_equal(got, full)
+
+
+@pytest.mark.parametrize("order", ["empty_first", "empty_last",
+                                   "two_empty_in_a_row"])
+@pytest.mark.parametrize("slots", [1, 4], ids=["s1", "s4"])
+def test_kernel_window_rows_chain_past_a_row_that_starts_where_it_ends(
+        monkeypatch, slots, order):
+    """Sliding-window rows in one call: a valid length of 4 pages and a
+    window of 10 slots make the row at slot 31 start at page 4 == its
+    live pages (it walks NOTHING and reads 0, with a table row of real
+    blocks), beside rows that start at page 0, 2 and 3. Batch against
+    each row alone bit for bit, and against the reference where a row
+    sees anything."""
+    monkeypatch.setattr(pak, "_KEYS_PER_STEP", _PPS * _RT)
+    W, valid = 10, 4 * _RT
+    nothing = _PMB * _RT - 1
+    lasts = {"empty_first": [nothing, 12, 2, 10, 24, 7, 18, 12],
+             "empty_last": [12, 2, 10, 24, 7, 18, 12, nothing],
+             "two_empty_in_a_row": [2, nothing, nothing, 12, 24, 10,
+                                    nothing, 7]}[order]
+    rng = np.random.RandomState(53 + slots)
+    q, kf, vf, bt, q_slots, _, _ = _pipeline_case(lasts, slots, None, rng)
+    n_live = pak.live_pages(q_slots, valid, _RT, _PMB)
+    first = pak.first_page(q_slots, W, _RT, n_live)
+    walked = (n_live - first).tolist()
+    assert [w for w, last in zip(walked, lasts) if last == nothing] \
+        == [0] * lasts.count(nothing)
+    assert {1, _PPS, _PPS + 1, 2 * _PPS} <= set(walked)
+    assert 0 < int(first[lasts.index(nothing)]) \
+        == int(n_live[lasts.index(nothing)])
+    kw = dict(layer=1, kv_valid_len=valid, window=W)
+    got = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
+    assert jnp.array_equal(got, _each_row_alone(q, kf, vf, bt, q_slots,
+                                                **kw))
+    ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference", **kw)
+    # a row with nothing to see reads 0 here; the reference's softmax
+    # over a row of fills is an average of whatever the table points at
+    sees = np.asarray(lasts) != nothing
+    asked = (np.asarray(q_slots) >= 0) & sees[:, None]
+    np.testing.assert_allclose(np.asarray(got)[asked],
+                               np.asarray(ref)[asked], atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got)[~sees].any()
+
+
+@pytest.mark.parametrize("quant", ["bf16", "int8", "fp8_e4m3"],
+                         ids=["bf16", "int8", "fp8"])
+def test_kernel_chunk_tiles_chain_across_rows_and_filler(monkeypatch, quant):
+    """Prefill chunks of 128 queries as 4 tiles of 32 each, three chunk
+    rows in one call of 16 grid rows (two eights): a chunk at start 0
+    (its first tile walks one step), one several pages in whose last two
+    tiles are bucket filler (they walk nothing, and the next row's first
+    tile starts its own copies), one all real. The batch is each chunk
+    alone, bit for bit, and the reference's."""
+    monkeypatch.setattr(pak, "_KEYS_PER_STEP", 4 * _CT)
+    monkeypatch.setattr(pak, "_TILE_ACC_BYTES", 32 * 2 * 2 * 16 * 4)
+    S, starts, n_real = 128, [0, 3 * _CT + 5, 2], [128, 60, 128]
+    mb = -(-(max(starts) + S) // _CT) + 1
+    rng = np.random.RandomState(67)
+    q, kf, vf, bt, _, sk, sv = _pipeline_case([None] * 3, S, quant, rng,
+                                              mb=mb, t=_CT)
+    pos = np.arange(S)[None, :]
+    q_slots = jnp.asarray(np.where(
+        pos < np.asarray(n_real)[:, None],
+        np.asarray(starts)[:, None] + pos, -1), jnp.int32)
+    pps, tq = pak.walk_shape(S, 4, 2, 16, _CT, mb, kf.dtype.itemsize)
+    assert (pps, tq) == (4, 32)
+    tiles = pak.live_pages(q_slots.reshape(-1, tq), mb * _CT, _CT, mb)
+    assert tiles.tolist()[:4] == [4, 8, 12, 16]        # start 0: one step
+    assert tiles.tolist()[4:8] == [8, 12, 0, 0]        # filler tiles
+    kw = dict(layer=1, kv_valid_len=mb * _CT, k_scale=sk, v_scale=sv)
+    got = paged_attention(q, kf, vf, bt, q_slots, impl="flash", **kw)
+    assert jnp.array_equal(got, _each_row_alone(q, kf, vf, bt, q_slots,
+                                                **kw))
+    ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference", **kw)
+    tol = 2e-2 if quant == "bf16" else 2e-5
+    asked = np.asarray(q_slots) >= 0
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[asked],
+        np.asarray(ref, np.float32)[asked], atol=tol, rtol=tol)
+    assert not np.asarray(got, np.float32)[~asked].any()
+
+
 @pytest.mark.parametrize("lengths", [
     [(3, 5), (6, 2)],            # inside a page, and across a boundary
     [(9, 7), (4, 7), (1, 3)],    # three requests on two slots
@@ -698,6 +870,67 @@ def test_paged_walk_counters_match_the_hand_count(nano_model, lengths):
     assert 0 < pages / s["paged_walk_entries_total"] < 1
     dense = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN)
     assert dense.stats()["paged_walk_entries_total"] == 0.0
+
+
+def test_paged_walk_row_counters_of_a_decode_call(nano_model):
+    """`paged_walk_rows_total` counts the rows the kernel walks,
+    `paged_walk_rows_chained_total` those whose first pages the row
+    before them in the call fetched: every row of a decode call but its
+    first (a dead row has a query slot too)."""
+    cfg, params = nano_model
+    B = 2
+    eng = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN,
+                       kv_block_tokens=T, pipeline_depth=1)
+    rng = np.random.RandomState(5)
+    for L, n in [(5, 5), (6, 2)]:       # one bucket: one prefill call
+        eng.submit(rng.randint(1, cfg.vocab_size, size=L).tolist(), n)
+    eng.step(horizon=1)                 # prefills both, decodes a token
+    before = eng.stats()
+    assert (before["prefill_dispatches"],
+            before["decode_dispatches"]) == (1, 1)
+    assert (before["paged_walk_rows_total"],
+            before["paged_walk_rows_chained_total"]) == (2 + B, 1 + 1)
+    while eng.pending():
+        eng.step(horizon=1)
+    s = eng.stats()
+    calls = s["decode_dispatches"] - before["decode_dispatches"]
+    rows = s["paged_walk_rows_total"] - before["paged_walk_rows_total"]
+    chained = s["paged_walk_rows_chained_total"] \
+        - before["paged_walk_rows_chained_total"]
+    # one slot decodes alone at the end: its dead neighbour walks too
+    assert calls >= 4 and rows == calls * B and chained == rows - calls
+
+
+def test_paged_walk_row_counters_of_prefill_tiles(nano_model, monkeypatch):
+    """In prefill the grid is the query tiles row by row (the tile's
+    budget is set to 8 tokens here): a tile that walks is counted, and
+    counted chained if the tile before it walks too; a tile of bucket
+    filler walks nothing and breaks the chain."""
+    cfg, params = nano_model
+    B = 2
+    monkeypatch.setattr(pak, "_TILE_ACC_BYTES",
+                        8 * cfg.n_heads * cfg.head_dim * 4)
+    eng = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN,
+                       kv_block_tokens=T, prefill_chunk=16)
+    # 27 tokens: a chunk of 16 (two tiles of 8), then 11 in a bucket of 16
+    # (both tiles hold a real token): two calls of two walking tiles
+    rng = np.random.RandomState(3)
+    eng.submit(rng.randint(1, cfg.vocab_size, size=27).tolist(), 1)
+    eng.run()
+    s = eng.stats()
+    assert (s["prefill_dispatches"], s["decode_dispatches"]) == (2, 1)
+    assert (s["paged_walk_rows_total"],
+            s["paged_walk_rows_chained_total"]) == (4 + B, 2 + 1)
+    # three rows x four tiles: all real; real up to token 9 (two filler
+    # tiles, so the next row's first tile starts its own copies); real up
+    # to 17 (one filler tile)
+    eng._count_prefill_walk(np.array([0, 0, 8]), np.array([31, 9, 17]), 32)
+    t = eng.stats()
+    assert t["paged_walk_rows_total"] \
+        - s["paged_walk_rows_total"] == 4 + 2 + 3
+    assert t["paged_walk_rows_chained_total"] \
+        - s["paged_walk_rows_chained_total"] == 3 + 2 + 2
+    assert t["paged_walk_rows_chained_total"] <= t["paged_walk_rows_total"]
 
 
 def test_own_kv_is_attended_in_place_of_the_pools_slots():
@@ -798,15 +1031,8 @@ def _chunk_case(S, quant, group, rng):
     bt[4] = bt[2]
     q = rng.randn(B, S, KV * group, D)
     q[4] = q[2]
-    sk = sv = None
-    if quant != "bf16":
-        qspec = resolve_kv_quant(quant)
-        sk = block_scale(jnp.max(jnp.abs(kf), axis=(2, 4)), qspec)
-        sv = block_scale(jnp.max(jnp.abs(vf), axis=(2, 4)), qspec)
-        kf = quantize(kf, sk[:, :, None, :, None], qspec)
-        vf = quantize(vf, sv[:, :, None, :, None], qspec)
-    return (jnp.asarray(q, dt), kf.reshape(2, NB, _CT, KV * D),
-            vf.reshape(2, NB, _CT, KV * D), jnp.asarray(bt, jnp.int32),
+    kf, vf, sk, sv = _quantized(kf, vf, quant)
+    return (jnp.asarray(q, dt), kf, vf, jnp.asarray(bt, jnp.int32),
             jnp.asarray(q_slots, jnp.int32), sk, sv, MB)
 
 

@@ -140,6 +140,73 @@ def test_paged_kernel_compiles_with_a_window_at_the_pair_layout(v5e):
             arg((rows, 160)), arg((rows, slots)), arg(()))
 
 
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+# rows, query slots, Q heads, KV heads, table entries, pool blocks, window
+_CELL_CALLS = {
+    "mistral_decode": (32, 1, 32, 8, 128, 1878, None),
+    "mistral_4x512": (4, 512, 32, 8, 128, 1878, None),
+    "olmoe_decode": (32, 1, 16, 16, 64, 615, None),
+    "olmoe_4x512": (4, 512, 16, 16, 64, 615, None),
+    "phi4flash_decode": (64, 1, 40, 10, 160, 1353, 512),
+    "phi4flash_4x512": (4, 512, 40, 10, 160, 1353, 512),
+}
+
+
+@pytest.mark.parametrize("case", list(_CELL_CALLS))
+def test_paged_kernel_call_orders_its_rows_and_asks_for_what_it_did(case):
+    """The kernel's call at the three serving cells' shapes, as traced:
+    the rows of a call are one pipeline, so the grid axis is
+    "arbitrary"; what carries it from row to row is two words of SMEM;
+    the VMEM scratch is what it was, two slots of a step's keys each for
+    K and V beside the softmax trio, so a decode row's call still asks
+    for no `vmem_limit_bytes` (what a kernel may take, XLA cannot give
+    the program around it: PERF.md PR 30) and a chunk's tiles for the 32
+    MiB they asked for before."""
+    from ray_tpu.ops.paged_attention_kernel import walk_shape
+
+    B, S, H, KV, MB, NB, window = _CELL_CALLS[case]
+    T, D = 32, 128
+    sd = jax.ShapeDtypeStruct
+
+    def fn(q, k, v, bt, slots, layer):
+        return paged_attention_kernel(
+            q, k, v, bt, slots, layer=layer, kv_valid_len=MB * T,
+            window=window, interpret=False)
+
+    pool = sd((3, NB, T, KV * D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(fn)(
+        sd((B, S, H, D), jnp.bfloat16), pool, pool, sd((B, MB), jnp.int32),
+        sd((B, S), jnp.int32), sd((), jnp.int32))
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    params = call.params["compiler_params"]["mosaic_tpu"]
+    assert params.dimension_semantics == ("arbitrary",)
+    pps, tq = walk_shape(S, H, KV, D, T, MB, 2)
+    keys = pps * T          # 512, or what fits 1 MiB a slot
+    assert keys == {8: 512, 16: 256, 10: 384}[KV]
+    tiles = -(-S // tq)
+    grid = call.params["grid_mapping"].grid
+    assert grid == (-(-B * tiles // 8) * 8,)
+    rows = tq * (H // KV)
+    scratch = [str(a) for a in call.params["grid_mapping"].scratch_avals]
+    assert scratch == [
+        f"Ref<vmem>{{bfloat16[2,{keys},{KV * D}]}}"] * 2 + [
+        "Ref<semaphore_mem>{dma_sem[2,2]}",
+        f"Ref<vmem>{{float32[{KV},{rows},{D}]}}",
+        f"Ref<vmem>{{float32[{KV},{rows},1]}}",
+        f"Ref<vmem>{{float32[{KV},{rows},1]}}",
+        "Ref<smem>{int32[2]}"]
+    assert params.vmem_limit_bytes == (None if S == 1 else 32 << 20)
+
+
 # -- the fused decode program, whole ------------------------------------------
 
 def _param_shapes(v5e, cfg):
@@ -256,6 +323,10 @@ def test_decode_program_moves_nothing_of_the_pools_size(v5e, case):
     assert all('"scoped_memory_configs":[]' in ln for ln in calls)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (1 << 30), f"{temp / 2**30:.2f} GiB of workspace"
+    # PERF.md section 4's figures, to the MiB (0.38 GiB; OLMoE's decode
+    # program holds nothing beside its arguments)
+    if quant is None:
+        assert temp >> 20 == {"mistral": 384, "olmoe": 0}[case]
 
 
 def test_olmoe_decode_program_reads_experts_where_they_lie(v5e):
@@ -370,6 +441,8 @@ def test_prefill_program_builds_no_view_of_the_table(v5e, case):
     assert (text.count("tpu_custom_call") == 1) == (case != "olmoe_4x512")
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (1 << 30), f"{temp / 2**30:.2f} GiB of workspace"
+    if n == 4:      # PERF.md section 4's figures, to the MiB
+        assert temp >> 20 == {"mistral_4x512": 57, "olmoe_4x512": 330}[case]
 
 
 def test_prefill_program_fits_16_layers_beside_a_5_gib_pool(v5e):
@@ -538,6 +611,9 @@ def test_hybrid_cell_programs_fit_the_chip(v5e, program):
     m = compiled.memory_analysis()
     last = program != "prefill_not_last"
     assert m.temp_size_in_bytes < (1 << 30)
+    if last:        # PERF.md section 4's figures, to the MiB
+        assert m.temp_size_in_bytes >> 20 == {"decode": 135,
+                                              "prefill_last": 212}[program]
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 11 * 2**30
     assert (m.argument_size_in_bytes > 10 * 2**30) == last
     text = compiled.as_text()

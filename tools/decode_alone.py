@@ -6,9 +6,9 @@ cost the program around it, and a traced benchmark run's
     python tools/decode_alone.py <tree root> <tag> [--cell NAME]
         [--live N[,N...]] [--len L[,L...]] [--rehearse]
 
-builds the engine of a serving cell of driver `serve_engine` or
-`serve_model` (default `mistral7b-rollout`) from THAT tree (run it from the
-tree's root), brings N rows of L tokens into decode (N: all the slots by
+builds the engine of a serving cell of driver `serve_engine`,
+`serve_model` or `serve_hybrid` (default `mistral7b-rollout`) from THAT
+tree (run it from the tree's root), brings N rows of L tokens into decode (N: all the slots by
 default; the other slots stay dead) for every N and L, then calls
 `_decode_multi_paged` (horizon 8) 3 x 40 times on the SAME row state and
 times it on the device's queue (async dispatch, one wait at the end).
@@ -35,7 +35,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from benchmark.harness import common, spec  # noqa: E402
-from benchmark.harness.drivers import serve_model  # noqa: E402
+from benchmark.harness.drivers import serve_hybrid, serve_model  # noqa: E402
 from benchmark.harness.model import llama_config  # noqa: E402
 from ray_tpu.models import engine as E  # noqa: E402
 from ray_tpu.models import llama_init  # noqa: E402
@@ -48,14 +48,22 @@ if a.rehearse:
     model.update(cell.config["rehearsal"]["model"])
     opts.update(cell.config["rehearsal"]["engine"])
 opts.pop("warm_groups")
-if model.get("model_type") in serve_model.FAMILIES:
+hybrid = cell.config.get("driver") == "serve_hybrid"
+if hybrid:
+    cfg, init, _ = serve_hybrid.program_config(model, opts["max_len"])
+elif model.get("model_type") in serve_model.FAMILIES:
     cfg, init, _ = serve_model.program_config(model, opts["max_len"])
 else:
     cfg, init = llama_config(model, opts["max_len"],
                              activation_dtype=model["torch_dtype"],
                              param_dtype=model["torch_dtype"],
                              remat=False), llama_init
-params = jax.jit(init, static_argnums=1)(common.seed_key(7), cfg)
+# an `rbg` key as the hybrid driver draws its weights with (threefry
+# compiles 40 s longer for that model's initialiser, PERF.md PR 31)
+key = jax.random.wrap_key_data(
+    jnp.tile(jax.random.key_data(common.seed_key(7)), 2), impl="rbg") \
+    if hybrid else common.seed_key(7)
+params = jax.jit(init, static_argnums=1)(key, cfg)
 jax.block_until_ready(params)
 H, CALLS = 8, (2 if a.rehearse else 40)
 lens = [int(x) for x in a.len.split(",") if x] or (
@@ -78,25 +86,29 @@ for L in lens:
         assert eng._ensure_decode_blocks(rows, H, 0)
         args = eng._row_state()
         bt_dev = eng._table_snapshot(eng._bt)
+        # a hybrid engine's second table, and its recurrent state, which
+        # the program is given to keep (donated) like the pools
+        btw_dev = eng._table_snapshot(eng._bt_w) if eng._hybrid else None
         fixed = (jnp.asarray(eng._row_keys), jnp.asarray(eng._row_greedy),
                  eng.temperature, eng.cfg, H, bool(eng._row_greedy.all()),
                  eng.top_k, eng.top_p, eng.eos_id)
-        pk, pv, ll, ctr = eng._pool_k, eng._pool_v, eng._last_logits, \
-            eng._moe_ctr
+        pk, pv, ll, ctr, hyb = eng._pool_k, eng._pool_v, eng._last_logits, \
+            eng._moe_ctr, eng._hyb
 
-        def call(pk, pv, ll):
+        def call(pk, pv, ll, hyb):
             r = E._decode_multi_paged(eng.params, pk, pv, bt_dev, ll, *args,
-                                      *fixed, moe_ctr=ctr)
-            return r[1], r[2], r[5], r[10]
+                                      *fixed, moe_ctr=ctr, hyb=hyb,
+                                      bt_w=btw_dev)
+            return r[1], r[2], r[5], r[11], r[10]
 
         for _ in range(3):
-            pk, pv, ll, seen = call(pk, pv, ll)
+            pk, pv, ll, hyb, seen = call(pk, pv, ll, hyb)
         jax.block_until_ready(ll)
         reps = []
         for _ in range(3):
             t = time.perf_counter()
             for _ in range(CALLS):
-                pk, pv, ll, seen = call(pk, pv, ll)
+                pk, pv, ll, hyb, seen = call(pk, pv, ll, hyb)
             jax.block_until_ready(ll)
             reps.append((time.perf_counter() - t) / (CALLS * H) * 1e3)
         key = f"L{L}_live{n_live}"
@@ -106,5 +118,5 @@ for L in lens:
         if seen is not None:    # the last call's counts: hit / layer-steps
             d = np.asarray(seen) - np.asarray(ctr)
             out[f"experts_hit_{key}"] = float(d[2]) / float(d[3])
-        del eng, pk, pv, ll
+        del eng, pk, pv, ll, hyb
 print("DECODE_AB " + json.dumps(out), flush=True)
