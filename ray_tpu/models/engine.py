@@ -31,15 +31,17 @@ slot-based continuous batching is first-class here, built the XLA way:
   (row_len stops, emits masked to -1) and are retired by the host
   replay of the token block.
 - The decode loop is ASYNC double-buffered (`pipeline_depth`, default
-  2): during pure-decode stretches (queue empty, nothing mid-prefill)
-  the engine keeps a bounded ring of fused steps in flight, chaining
-  each run-ahead dispatch off the previous one's device-carried row
-  state and issuing `copy_to_host_async` on every token block, so the
-  host replays step N's tokens while the device computes step N+1.
-  The ring is flushed before any admission/prefill (those mutate the
-  donated pool from the host side), and run-ahead iterations on rows
-  that finished mid-flight are masked on device and accounted as
-  `pipeline_overrun_tokens`.
+  2): during pure-decode stretches (nothing mid-prefill, and either
+  the queue empty or every slot taken by a row whose budget outlasts
+  the blocks in flight: no admission can happen before they are
+  drained) the engine keeps a bounded ring of fused steps in flight,
+  chaining each run-ahead dispatch off the previous one's
+  device-carried row state and issuing `copy_to_host_async` on every
+  token block, so the host replays step N's tokens while the device
+  computes step N+1. The ring is flushed before any admission/prefill
+  (those mutate the donated pool from the host side), and run-ahead
+  iterations on rows that finished mid-flight are masked on device and
+  accounted as `pipeline_overrun_tokens`.
 - SPECULATIVE decoding composes with all of the above
   (`draft_params=`/`draft_cfg=`/`spec_window=`): the engine keeps a
   second (draft) KV plane per slot — a second block pool — and each
@@ -1170,10 +1172,12 @@ class DecodeEngine:
     BEFORE step N's token block is pulled to the host (the block's
     `copy_to_host_async` overlaps N+1's compute), chained through the
     device-carried row state, and the host drains/replays one step
-    behind. The ring flushes whenever the scheduler reports pending
-    admissions or a row is mid-chunked-prefill, so scheduling decisions
-    always see fully-replayed host state; depth 1 is the synchronous
-    engine. Output is token-identical at every depth.
+    behind. The ring flushes whenever a queued request finds a free
+    slot or a row is mid-chunked-prefill, so scheduling decisions
+    always see fully-replayed host state; a queue behind FULL slots is
+    no pending admission, and the ring runs ahead of every block in
+    which no row's budget ends. Depth 1 is the synchronous engine.
+    Output is token-identical at every depth.
 
     Greedy by default; sampling mode (greedy=False) applies the same
     temperature/top_k/top_p semantics as `generate`, with a PER-REQUEST
@@ -1599,6 +1603,8 @@ class DecodeEngine:
         # Dispatch/transfer accounting (plain ints so the benchmark's
         # enable_metrics=False engines still report them):
         self.decode_dispatches = 0     # fused decode program launches
+        self.decode_dispatches_chained = 0         # ... made run-ahead
+        self.decode_dispatches_chained_queued = 0  # ... a request queued
         self.prefill_dispatches = 0    # batched prefill launches
         self.host_syncs = 0            # device->host transfers
         self.device_waits = 0          # blocking pulls (`_device_wait`)
@@ -2147,18 +2153,27 @@ class DecodeEngine:
         remaining budget (no trailing iterations run fully frozen) and
         rounded down to a power of two (bounded compile count).
 
-        With `pipeline_depth >= 2` and a pure-decode stretch (queue
-        empty, nothing mid-prefill), the step dispatches ahead: it tops
-        the in-flight ring up to `pipeline_depth` fused steps (each
-        chained off the previous one's device row state) BEFORE pulling
-        the oldest step's token block, so the device computes step N+1
-        while the host replays step N. Per-call emissions are identical
+        With `pipeline_depth >= 2` and a pure-decode stretch (nothing
+        mid-prefill, and no admission possible before the blocks in
+        flight are drained: the queue is empty, or every slot is taken
+        and no row's budget ends inside them), the step dispatches
+        ahead: it tops the in-flight ring up to `pipeline_depth` fused
+        steps (each chained off the previous one's device row state)
+        BEFORE pulling the oldest step's token block, so the device
+        computes step N+1 while the host replays step N. Per-call emissions are identical
         to the synchronous engine: each call still drains exactly one
         block, whose horizon follows the same budget arithmetic. A call
         that finds an admission while blocks are in flight drains THOSE
         (the flush), dispatches the prefill and the next block, and
         returns: tokens are handed over when their block has run, never
-        a prefill and a block later."""
+        a prefill and a block later.
+
+        A slot freed by BUDGET admits its newcomer in the step the
+        synchronous engine would: nothing is dispatched ahead of a
+        block in which a row is known to end. A row that ends by EOS
+        inside block N while N+1 is already in flight frees its slot
+        one block (at most `decode_horizon` tokens) later, queue or no
+        queue: the host cannot know an EOS before it has the block."""
         if horizon is not None and horizon < 1:
             raise ValueError("horizon must be >= 1")
         self.steps_total += 1
@@ -2171,9 +2186,9 @@ class DecodeEngine:
         # paths mutate the pool from the host side and
         # read row/slot state, so every in-flight run-ahead block must
         # be replayed first (freed slots, retired requests) for the
-        # admission decision to see true state.
-        if self._ring and (self.scheduler.admissions_pending()
-                           or self._row_prefill):
+        # admission decision to see true state. A queue behind full
+        # slots is no admission: the gate below skips every taken row.
+        if self._ring and self._batch_may_change():
             self._flush_pipeline(emitted)
         # Tokens a flush drained are in hand NOW: this call dispatches
         # what follows (the admissions' prefill, the next decode block)
@@ -2192,8 +2207,8 @@ class DecodeEngine:
             # Commit any landed adapter prefetches before gating: the
             # commit donates the stacks, so it must never race an
             # in-flight dispatch — with the ring empty (flushed above
-            # whenever admissions were pending) nothing on device still
-            # reads the old stack buffers.
+            # whenever a queued request had a free slot) nothing on
+            # device still reads the old stack buffers.
             if self.adapter_pool is not None and not self._ring:
                 self.adapter_pool.drain_prefetches()
             deferred = False
@@ -2438,7 +2453,7 @@ class DecodeEngine:
                 toks, W + 1, list(rows), run_ahead=chain is not None,
                 chain=(rl, ac, bu, ti, dl, dt), spec=True, w_max=W,
                 w_row=np.array(w_row, np.int32)))
-            self.decode_dispatches += 1
+            self._count_decode_dispatch(chain)
             self.spec_dispatches += 1
             self.metrics.on_dispatch(W + 1, host_syncs=0)
 
@@ -2493,8 +2508,18 @@ class DecodeEngine:
             self._ring.append(_InflightStep(toks, H, list(rows),
                                             run_ahead=chain is not None,
                                             chain=(rl, ac, bu, ti)))
-            self.decode_dispatches += 1
+            self._count_decode_dispatch(chain)
             self.metrics.on_dispatch(H, host_syncs=0)
+
+    def _count_decode_dispatch(self, chain: Optional[tuple]) -> None:
+        """One fused decode (or speculative) launch; a chained one ran
+        ahead of a block the host had not pulled, with or without a
+        request waiting in the queue."""
+        self.decode_dispatches += 1
+        if chain is not None:
+            self.decode_dispatches_chained += 1
+            if len(self.scheduler):
+                self.decode_dispatches_chained_queued += 1
 
     def _row_state(self) -> tuple:
         """(row_len, active, budget, tok_idx) from replayed host state:
@@ -2612,10 +2637,18 @@ class DecodeEngine:
         budgets minus everything already in flight — pessimistic, so a
         queued step is never provably all-frozen; rows that finish
         mid-flight still mask their tail iterations on device
-        (`pipeline_overrun_tokens`)."""
-        if (self.pipeline_depth < 2 or self._row_prefill
-                or self.scheduler.admissions_pending()):
+        (`pipeline_overrun_tokens`).
+
+        With requests queued (behind full slots: `_batch_may_change`
+        ruled out a free one) the ring stops short of a block in which a
+        row's budget ends: draining that block frees a slot, and the newcomer
+        must not wait a block it need not. Blocks that retire nobody by
+        budget free no slot (short of an EOS), so the gate after them
+        would admit nothing and dispatching ahead of them loses
+        nothing."""
+        if self.pipeline_depth < 2 or self._batch_may_change():
             return
+        queued = self.scheduler.admissions_pending()
         while len(self._ring) < self.pipeline_depth:
             last = self._ring[-1]
             inflight = sum(e.H for e in self._ring)
@@ -2623,6 +2656,9 @@ class DecodeEngine:
             if rem <= 0:
                 break              # every further iteration would be
                 #                    overrun — nothing left to compute
+            if queued and int(self.row_budget[rows].min()) <= inflight:
+                break              # a slot frees inside what is in
+                #                    flight: the next step admits
             if last.spec:
                 # Chain another speculative round at the SAME widths:
                 # the adaptive window can only move once the host has
@@ -2652,6 +2688,17 @@ class DecodeEngine:
                 break
             self._dispatch_decode(Hn, rows,
                                   chain=self._ring[-1].chain)
+
+    def _batch_may_change(self) -> bool:
+        """Could the next admission gate or chunk cadence change the
+        batch: a row is mid-prompt, or a request is queued AND a slot is
+        free for it. A queue behind full slots is not a pending
+        admission (the gate skips every taken row), which is what lets
+        a saturated engine run ahead; `admissions_pending()` keeps its
+        meaning, something is queued."""
+        return bool(self._row_prefill) or (
+            self.scheduler.admissions_pending()
+            and any(r is None for r in self.row_req))
 
     def _drain_one(self, emitted: Dict[int, List[int]]) -> None:
         """Pull the OLDEST in-flight token block to the host (its async
@@ -2755,6 +2802,12 @@ class DecodeEngine:
             return num / den if den else 0.0
 
         out["decode_dispatches"] = float(self.decode_dispatches)
+        # of those, dispatched ahead of a block not yet pulled; and of
+        # THOSE, while a request waited in the queue behind full slots
+        out["decode_dispatches_chained"] = float(
+            self.decode_dispatches_chained)
+        out["decode_dispatches_chained_queued"] = float(
+            self.decode_dispatches_chained_queued)
         out["prefill_dispatches"] = float(self.prefill_dispatches)
         out["host_syncs"] = float(self.host_syncs)
         out["host_syncs_per_token"] = _ratio(self.host_syncs,

@@ -12,7 +12,10 @@ pin the contract:
   at every depth, every sampling mode, with and without the prefix
   cache and chunked prefill;
 - the ring FLUSHES before any admission (scheduling sees fully
-  replayed host state) and at end of stream (no stranded blocks);
+  replayed host state) and at end of stream (no stranded blocks); a
+  queue behind FULL slots is no admission: the ring runs ahead of every
+  block in which no row's budget ends, and a slot freed by budget
+  admits its newcomer in the step the synchronous engine would;
 - rows finishing mid-flight retire exactly as in the sync engine, and
   their run-ahead iterations are accounted as pipeline_overrun_tokens;
 - the loop never blocks on a host sync before dispatching the next
@@ -57,6 +60,22 @@ def _run(params, cfg, prompts, budgets, depth, *, eng_kw=None,
     return [out[r] for r in ids], eng
 
 
+def _run_saturated(params, cfg, prompts, budgets, depth, *, eng_kw=None):
+    """A queue that is never empty while a request is left to submit:
+    two slots, and before every step the queue is fed up to three, more
+    than a step can admit. Returns (tokens per request, per-call
+    emissions, engine)."""
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                       pipeline_depth=depth, **(eng_kw or {}))
+    work = list(zip(prompts, budgets))
+    ids, calls = [], []
+    while work or eng.pending():
+        while work and len(eng.scheduler) < 3:
+            ids.append(eng.submit(*work.pop(0)))
+        calls.append(eng.step())
+    return [eng.pop_result(r) for r in ids], calls, eng
+
+
 # ---------------------------------------------------------------------------
 # Token identity: depth x sampling mode x prefix cache x chunked prefill
 # ---------------------------------------------------------------------------
@@ -72,29 +91,64 @@ def _run(params, cfg, prompts, budgets, depth, *, eng_kw=None,
     {"prefill_chunk": 3},
     {"prefix_cache": True, "kv_block_tokens": 4, "prefill_chunk": 3},
 ], ids=["plain", "prefix", "chunked", "prefix+chunked"])
-def test_pipeline_token_identity_matrix(nano_model, mode, features):
+@pytest.mark.parametrize("queue", ["drains", "never_empty"])
+def test_pipeline_token_identity_matrix(nano_model, mode, features, queue):
     """Every (depth, sampling, prefix/chunk) combination produces the
     SAME tokens as the synchronous depth-1 engine — the pipeline is a
     pure latency optimization. Shared-prefix prompts exercise the trie
     under the prefix-cache variants; 5 requests through 2 slots churn
-    admissions between pure-decode stretches."""
+    admissions between pure-decode stretches. `never_empty` keeps a
+    request waiting behind two full slots for the whole run, with
+    budgets that outlast a block: the ring runs ahead with a queue
+    behind it, call for call what the synchronous engine emits."""
     cfg, params = nano_model
-    base = _prompts(5, cfg)
+    base = _prompts(6, cfg)
     # Give two prompts a shared 8-token prefix so the prefix cache hits.
     shared = list(range(3, 11))
     prompts = [shared + p for p in base[:2]] + base[2:]
-    budgets = [7, 4, 9, 5, 6]
-    ref, _ = _run(params, cfg, prompts, budgets, 1,
-                  eng_kw={**mode, **features})
+    kw = {**mode, **features}
+    if queue == "drains":
+        prompts, budgets = prompts[:5], [7, 4, 9, 5, 6]
+    else:
+        budgets = [13, 9, 17, 11, 12, 10]
+        kw["decode_horizon"] = 4
+
+    def run(depth):
+        if queue == "never_empty":
+            return _run_saturated(params, cfg, prompts, budgets, depth,
+                                  eng_kw=kw)
+        got, eng = _run(params, cfg, prompts, budgets, depth, eng_kw=kw)
+        return got, None, eng
+
+    ref, ref_calls, _ = run(1)
     for depth in (2, 4):
-        got, eng = _run(params, cfg, prompts, budgets, depth,
-                        eng_kw={**mode, **features})
+        got, calls, eng = run(depth)
+        assert calls == ref_calls, f"depth={depth}: a call differs"
         assert got == ref, f"depth={depth} diverged"
         s = eng.stats()
         # The drained engine holds no in-flight blocks and every
         # dispatch got exactly one drain.
         assert s["host_lag_steps"] == 0.0
         assert s["decode_dispatches"] == s["host_syncs"]
+        if queue == "never_empty":
+            assert s["decode_dispatches_chained_queued"] > 0
+
+
+def test_saturated_queue_identity_sparse_family(nano_olmoe):
+    """The sparse family behind a queue that never empties: depth 2 is
+    the synchronous engine call for call (the dense family's case is the
+    matrix above, the hybrid and latent families' are in their own
+    files)."""
+    cfg, params = nano_olmoe
+    prompts = _prompts(6, cfg, seed=31)
+    budgets = [13, 9, 17, 11, 12, 10]
+    kw = {"decode_horizon": 4}
+    ref, ref_calls, _ = _run_saturated(params, cfg, prompts, budgets, 1,
+                                       eng_kw=kw)
+    got, calls, eng = _run_saturated(params, cfg, prompts, budgets, 2,
+                                     eng_kw=kw)
+    assert got == ref and calls == ref_calls
+    assert eng.stats()["decode_dispatches_chained_queued"] > 0
 
 
 def test_pipeline_identity_under_eviction_pressure(nano_model):
@@ -167,15 +221,19 @@ def test_mid_flight_eos_retires_like_sync(nano_model):
     assert s["host_lag_steps"] == 0.0
 
 
-def test_flush_before_admission(nano_model):
+@pytest.mark.parametrize("slots", [3, 2], ids=["a_slot_free", "slots_full"])
+def test_flush_before_admission(nano_model, slots):
     """Submitting while blocks are in flight forces a pipeline flush
-    BEFORE the admission: the admitted prompt's prefill must not race
-    run-ahead decode blocks that assumed a pure-decode batch. The
-    flush shows up in pipeline_flushes and the newcomer's output is
-    unperturbed."""
+    BEFORE the admission WHEN A SLOT IS FREE for the newcomer: the
+    admitted prompt's prefill must not race run-ahead decode blocks that
+    assumed a pure-decode batch. The flush shows up in pipeline_flushes.
+    With both slots taken by rows whose budgets outlast the ring, the
+    queued request is no admission: nothing is flushed, the ring stays
+    a block ahead, and the newcomer is admitted (after a flush-free
+    drain) once a budget ends. Its output is unperturbed either way."""
     cfg, params = nano_model
     prompts = _prompts(3, cfg, seed=13)
-    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+    eng = DecodeEngine(params, cfg, batch_slots=slots, max_len=64,
                        pipeline_depth=2, decode_horizon=4)
     a = eng.submit(prompts[0], 16)
     b = eng.submit(prompts[1], 16)
@@ -183,13 +241,89 @@ def test_flush_before_admission(nano_model):
     #              dispatches, tops the ring up, drains one behind
     assert eng.stats()["host_lag_steps"] >= 1.0
     flushes0 = eng.stats()["pipeline_flushes"]
-    c = eng.submit(prompts[2], 6)    # pending admission -> flush
+    c = eng.submit(prompts[2], 6)
     eng.step()
-    assert eng.stats()["pipeline_flushes"] == flushes0 + 1
+    s = eng.stats()
+    if slots == 3:               # pending admission -> flush
+        assert s["pipeline_flushes"] == flushes0 + 1
+    else:                        # nobody to admit: still a block ahead
+        assert s["pipeline_flushes"] == flushes0
+        assert s["host_lag_steps"] == 1.0
+        assert s["decode_dispatches_chained_queued"] == 1.0
     out = eng.run()
     ref, _ = _run(params, cfg, [prompts[2]], [6], 1)
     assert out[c] == ref[0]
     assert len(out[a]) == 16 and len(out[b]) == 16
+
+
+def test_no_run_ahead_of_a_block_in_which_a_budget_ends(nano_model):
+    """Two full slots and a request queued: `a` (6 tokens) ends inside
+    the second block of 4. The ring runs ahead of the first block (no
+    budget ends in it) and NOT of the second: when that block is pulled
+    nothing is in flight, so the step after it admits the newcomer, the
+    very step the synchronous engine admits it in."""
+    cfg, params = nano_model
+    prompts = _prompts(3, cfg, seed=37)
+    budgets = [6, 16, 5]
+
+    def drive(depth):
+        eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                           pipeline_depth=depth, decode_horizon=4)
+        ids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+        lag, calls, admitted = [], [], {}
+        while eng.pending():
+            calls.append(eng.step())
+            lag.append(eng.stats()["host_lag_steps"])
+            for r in eng.row_req:
+                if r is not None:
+                    admitted.setdefault(r.req_id, len(calls) - 1)
+        return ids, admitted, calls, lag, eng.stats()
+
+    ids1, adm1, calls1, _, s1 = drive(1)
+    ids2, adm2, calls2, lag2, s2 = drive(2)
+    assert ids1 == ids2 and calls2 == calls1
+    assert adm2 == adm1 and adm1[ids1[2]] == 2     # the third call
+    # call 0 ran ahead of block 0 with the newcomer queued; call 1 pulled
+    # the block in which `a` ends with nothing dispatched behind it
+    assert lag2[:2] == [1.0, 0.0]
+    assert s2["decode_dispatches_chained_queued"] == 1.0
+    assert s2["pipeline_flushes"] == 0.0
+    assert s1["decode_dispatches_chained"] == 0.0
+
+
+def test_eos_behind_a_queue_frees_its_slot_at_most_a_block_late(nano_model):
+    """The one delay run-ahead behind a queue can cost: a row that ends
+    by EOS inside block N while N+1 is in flight (the host cannot know
+    an EOS before it has the block) frees its slot one block later. The
+    newcomer's first tokens come at most one call after the synchronous
+    engine's (here exactly one: the flushing call hands over block N+1
+    and dispatches the newcomer's), and every request's tokens are the
+    synchronous engine's."""
+    cfg, params = nano_model
+    prompts = _prompts(3, cfg, seed=41)
+    budgets = [24, 24, 6]
+    free, _ = _run(params, cfg, prompts[:2], budgets[:2], 1,
+                   eng_kw={"decode_horizon": 4})
+    # an EOS id that row 0 emits first inside its second block of 4
+    eos = next(t for i, t in enumerate(free[0][4:8], 4)
+               if t not in free[0][:i] and t not in free[1][:i + 4])
+    out, first = {}, {}
+    for depth in (1, 2):
+        eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                           pipeline_depth=depth, decode_horizon=4,
+                           eos_id=eos)
+        ids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+        calls = []
+        while eng.pending():
+            calls.append(eng.step())
+        first[depth] = next(i for i, c in enumerate(calls) if ids[2] in c)
+        out[depth] = [eng.pop_result(r) for r in ids]
+        s = eng.stats()
+        assert s["decode_dispatches_chained_queued"] == 2 * (depth - 1)
+        assert (s["pipeline_overrun_tokens"] > 0) == (depth == 2)
+    assert out[2] == out[1]
+    assert out[1][0][-1] == eos and 5 <= len(out[1][0]) <= 8
+    assert first[1] == 2 and first[2] == 3
 
 
 def test_a_flushing_step_returns_what_the_flush_drained(nano_model):
@@ -338,13 +472,19 @@ def test_admissions_pending_hint():
         assert pol.admissions_pending() is False
 
 
-def test_run_ahead_leaves_fewer_dispatch_gaps(nano_model, monkeypatch):
+@pytest.mark.parametrize("waves", [1, 2], ids=["queue_empty",
+                                               "queue_waiting"])
+def test_run_ahead_leaves_fewer_dispatch_gaps(nano_model, monkeypatch,
+                                              waves):
     """A dispatch GAP is a blocking pull issued while nothing else is
     in flight: the device has nothing queued behind the block the host
     is waiting for, so it idles through the host's replay. The
     synchronous engine pays one per decode block; at depth 2 only the
     flushes and the end of the stream do, and the mean ring depth at a
-    drain says the same (exactly 1 against more than 1)."""
+    drain says the same (exactly 1 against more than 1). With a second
+    wave of requests waiting behind the two full slots the count falls
+    the same way: only the block in which the first wave's budgets end
+    is pulled with nothing behind it, and nothing is flushed."""
     cfg, params = nano_model
 
     def drive(depth):
@@ -369,7 +509,7 @@ def test_run_ahead_leaves_fewer_dispatch_gaps(nano_model, monkeypatch):
         try:
             eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
                                pipeline_depth=depth, decode_horizon=4)
-            for p in _prompts(2, cfg, seed=23):
+            for p in _prompts(2 * waves, cfg, seed=23):
                 eng.submit(p, 24)
             eng.run()
         finally:
@@ -381,11 +521,17 @@ def test_run_ahead_leaves_fewer_dispatch_gaps(nano_model, monkeypatch):
 
     gaps1, s1 = drive(1)
     gaps2, s2 = drive(2)
-    assert s1["decode_dispatches"] == s2["decode_dispatches"]
+    assert s1["decode_dispatches"] == s2["decode_dispatches"] == 6 * waves
     assert gaps1 == s1["decode_dispatches"]       # one per block
-    assert gaps2 < gaps1 / 2
+    assert gaps2 == waves                         # a wave's last block
     assert s1["pipeline_depth_effective"] == 1.0
     assert 1.5 < s2["pipeline_depth_effective"] <= 2.0
+    assert s2["pipeline_flushes"] == 0.0
+    # every block but a wave's last ran ahead; the first wave's with the
+    # second waiting in the queue
+    assert s2["decode_dispatches_chained"] == 5 * waves
+    assert s2["decode_dispatches_chained_queued"] == 5 * (waves - 1)
+    assert s1["decode_dispatches_chained"] == 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -404,6 +550,8 @@ def test_fresh_engine_pipeline_stats_are_zero(nano_model):
     assert s["pipeline_flushes"] == 0.0
     assert s["pipeline_overrun_tokens"] == 0.0
     assert s["host_lag_steps"] == 0.0
+    assert s["decode_dispatches_chained"] == 0.0
+    assert s["decode_dispatches_chained_queued"] == 0.0
 
 
 def test_pipeline_plane_reaches_metrics_registry(nano_model):

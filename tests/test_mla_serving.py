@@ -354,6 +354,30 @@ def test_the_fused_horizon_and_the_ring_agree_with_horizon_1(params,
     assert [out[r] for r in ids] == want
 
 
+def test_a_queue_behind_full_slots_runs_ahead_like_depth_1(params):
+    """Four requests on two slots: while a request waits behind two rows
+    whose budgets outlast the block in flight the ring stays a block
+    ahead (no flush: nobody can be admitted), and every call returns what
+    the synchronous engine's call returns."""
+    work = [(prompt_of(12, seed=7), 21), (prompt_of(35, seed=8), 30),
+            (prompt_of(20, seed=9), 14), (prompt_of(9, seed=10), 18)]
+    calls, stats = {}, {}
+    for depth in (1, 2):
+        eng = engine(params, decode_horizon=4, pipeline_depth=depth)
+        for p, m in work:
+            eng.submit(p, max_new_tokens=m)
+        calls[depth] = []
+        while eng.pending():
+            calls[depth].append(eng.step())
+        stats[depth] = eng.stats()
+    assert eng.kv_pool.blocks_in_use == 0
+    assert calls[2] == calls[1]
+    assert stats[2]["decode_dispatches_chained_queued"] >= 4
+    assert stats[2]["pipeline_flushes"] == stats[1]["pipeline_flushes"] == 0
+    assert stats[2]["preemptions"] == 0
+    assert stats[1]["decode_dispatches_chained"] == 0
+
+
 def test_preempt_recompute_mid_decode_gives_the_same_tokens(params):
     """A pool too small for both rows: one is preempted while it decodes,
     its blocks of both planes dropped, and rebuilt by prefill of prompt +
