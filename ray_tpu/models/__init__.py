@@ -24,6 +24,7 @@ from ray_tpu.models.vit import (
 )
 from ray_tpu.models.hybrid import HybridConfig, hybrid_init
 from ray_tpu.models.mla import MlaConfig, mla_init
+from ray_tpu.models.gdn import GdnConfig, gdn_init
 from ray_tpu.models.moe import (
     MoeConfig,
     moe_init,
@@ -98,6 +99,8 @@ __all__ = [
     "hybrid_init",
     "MlaConfig",
     "mla_init",
+    "GdnConfig",
+    "gdn_init",
     "MoeConfig",
     "moe_init",
     "moe_ffn_dropless",

@@ -21,7 +21,7 @@ out by ``alloc``.
 
 from __future__ import annotations
 
-from typing import Any, List, NamedTuple, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 
 class CachePlane(NamedTuple):
@@ -52,6 +52,36 @@ def kv_planes(table: str, layers: int, kv_heads: int, head_dim: int,
     lanes = kv_heads * head_dim
     return (CachePlane(prefix + "k", table, layers, lanes, dtype),
             CachePlane(prefix + "v", table, layers, lanes, dtype))
+
+
+class StatePlane(NamedTuple):
+    """One array of a family's RECURRENT state: what a row keeps in the
+    layers that own it, whatever the row's length (a state-space layer's
+    scan state, a delta-rule layer's matrix state, a conv's last inputs).
+    A config answers `state_planes()` with these, and the engine keeps
+    ``[layers, slots, *shape]`` of each, zeroed, donated through every
+    program and indexed by engine slot; a family without recurrent state
+    has no such method."""
+
+    name: str        # "ssm" | "conv" | "delta"
+    layers: int      # layers that own this plane
+    shape: Tuple[int, ...]   # what ONE row keeps in one of them
+    dtype: Any
+
+    def row_bytes(self) -> int:
+        """Device bytes a row keeps in this plane, every layer of it."""
+        n = self.layers * self.dtype.itemsize
+        for s in self.shape:
+            n *= s
+        return n
+
+
+def zero_state_planes(planes, slots: int) -> Dict[str, Any]:
+    """``{name: zeros [layers, slots, *shape]}`` of `StatePlane`s."""
+    import jax.numpy as jnp
+
+    return {pl.name: jnp.zeros((pl.layers, slots, *pl.shape), pl.dtype)
+            for pl in planes}
 
 
 class BlockPool:

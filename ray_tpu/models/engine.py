@@ -91,7 +91,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu._private import sanitize as _sanitize
 from ray_tpu.models.adapter_pool import AdapterPool
-from ray_tpu.models.block_pool import BlockPool
+from ray_tpu.models.block_pool import BlockPool, zero_state_planes
 from ray_tpu.models.engine_metrics import EngineMetrics, NullEngineMetrics
 from ray_tpu.models.engine_trace import resolve_tracer
 from ray_tpu.models import hybrid as _hybrid
@@ -100,6 +100,8 @@ from ray_tpu.models.generate import (_check_sampling_knobs, _expert_stacks,
 from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models import mla as _mla
 from ray_tpu.models.mla import MlaConfig
+from ray_tpu.models import gdn as _gdn
+from ray_tpu.models.gdn import GdnConfig
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
 from ray_tpu.models.moe import MoeConfig
@@ -612,11 +614,14 @@ def _own_stack(cfg):
     """The module of a family whose layers are not `generate._layer_body`'s
     and that brings its own stack (`layers_paged`, `lm_head`) against the
     engine's pools: `hybrid` for a `HybridConfig`, `mla` for an
-    `MlaConfig`; None for the dense and sparse families."""
+    `MlaConfig`, `gdn` for a `GdnConfig`; None for the dense and sparse
+    families."""
     if isinstance(cfg, HybridConfig):
         return _hybrid
     if isinstance(cfg, MlaConfig):
         return _mla
+    if isinstance(cfg, GdnConfig):
+        return _gdn
     return None
 
 
@@ -652,15 +657,25 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     that state (None for the other families, whose scan is below). An
     `MlaConfig`'s is `mla.layers_paged`, two scans from its `layer_plan`
     over these two pools as its latent and index planes; it has no other
-    state and counts its expert layers like an `MoeConfig`."""
+    state and counts its expert layers like an `MoeConfig`. A
+    `GdnConfig`'s is `gdn.layers_paged`, one scan over its periods, over
+    this pool (its attention layers') and its recurrent state ``hyb``
+    alone (no window pool, no ``bt_w``); it counts its expert layers
+    too."""
     if isinstance(cfg, MlaConfig):
         h, pool_k, pool_v, moe_stats = _mla.layers_paged(
             params, toks, pool_k, pool_v, bt, starts, cfg,
             moe_live=moe_live, n_valid=n_valid, last_idx=last_idx)
         return h, pool_k, pool_v, scale_k, scale_v, moe_stats, None
+    if hyb is not None and live is None:
+        live = jnp.arange(toks.shape[1])[None, :] < n_valid[:, None]
+    if isinstance(cfg, GdnConfig):
+        h, pool_k, pool_v, hyb, moe_stats = _gdn.layers_paged(
+            params, toks, pool_k, pool_v, bt, starts, cfg, hyb, live=live,
+            rows=rows, n_valid=n_valid, last_idx=last_idx,
+            moe_live=moe_live)
+        return h, pool_k, pool_v, scale_k, scale_v, moe_stats, hyb
     if hyb is not None:
-        if live is None:
-            live = jnp.arange(toks.shape[1])[None, :] < n_valid[:, None]
         h, pool_k, pool_v, hyb = _hybrid.layers_paged(
             params, toks, pool_k, pool_v, bt, starts, cfg, hyb, bt_w,
             live=live, rows=rows, n_valid=n_valid, last_idx=last_idx,
@@ -1391,6 +1406,35 @@ class DecodeEngine:
                 if bad:
                     raise ValueError(
                         f"an MlaConfig cannot be served with {what}")
+        # A `GdnConfig` (delta-rule layers with a matrix state a row,
+        # gated attention, held experts) likewise.
+        if isinstance(cfg, GdnConfig):
+            for bad, what in (
+                    (prefix_cache, "prefix_cache=True: a prefix hit needs "
+                     "a snapshot of the recurrent state at the block "
+                     "boundary it resumes from, and none is kept (ROADMAP "
+                     "M4)"),
+                    (preempt == "swap", "preempt='swap': the swap ledger "
+                     "carries K/V blocks only, not a row's recurrent state "
+                     "(pass preempt='recompute'; ROADMAP M4)"),
+                    (draft_params is not None or draft_cfg is not None,
+                     "draft_params=/draft_cfg=: a rejected draft token has "
+                     "already advanced the recurrent state, and there is "
+                     "no roll-back of it; the model's own drafting head is "
+                     "not built (ROADMAP M7)"),
+                    (kv_quant is not None, "kv_quant=: the quantized "
+                     "write's scales are sized from the dense family's "
+                     "layers, and the recurrent state has no quantized "
+                     "form"),
+                    (lora is not None, "lora=: the adapter targets name "
+                     "the dense family's projections"),
+                    (tp is not None or mesh is not None, "tp=/mesh=: the "
+                     "delta-rule weights, the recurrent state and the held "
+                     "experts have no sharding rule and no exchange "
+                     "(ROADMAP M2)")):
+                if bad:
+                    raise ValueError(
+                        f"a GdnConfig cannot be served with {what}")
         if draft_cfg is not None and \
                 isinstance(draft_cfg, MoeConfig) != sparse:
             raise ValueError(
@@ -1606,6 +1650,8 @@ class DecodeEngine:
         self.decode_dispatches_chained = 0         # ... made run-ahead
         self.decode_dispatches_chained_queued = 0  # ... a request queued
         self.prefill_dispatches = 0    # batched prefill launches
+        self.prefill_dispatches_ahead = 0   # ... launched before the
+        #                                step's decode block was pulled
         self.host_syncs = 0            # device->host transfers
         self.device_waits = 0          # blocking pulls (`_device_wait`)
         self.device_wait_s = 0.0       # engine-clock seconds inside them
@@ -1669,6 +1715,9 @@ class DecodeEngine:
         # step via _advance_prefills().
         self.prefill_chunk = prefill_chunk
         self._row_prefill: Dict[int, _PrefillState] = {}
+        # rows whose NEXT step's chunk is already dispatched (behind the
+        # decode block the host was about to wait for): row -> its state
+        self._chunk_ahead: Dict[int, _PrefillState] = {}
 
         # ONE refcounted block pool holds everything: live rows' K/V
         # behind their block tables, and — with `prefix_cache` — the
@@ -1717,14 +1766,20 @@ class DecodeEngine:
             self._planes, n_blocks, T, getattr(cfg, "n_kv_heads", 1),
             pool_dtype, self.kv_quant_spec is not None,
             shardings=self._shardings)
-        # A `HybridConfig`'s other device state: the WINDOW plane (a
-        # second `BlockPool` and table over a pool of its own geometry,
-        # `n_window_layers` deep; a row holds only the blocks that
-        # intersect its last `sliding_window` slots plus what it is
-        # about to write, `_window_release`) and the recurrent state of
-        # every slot. None/empty for the other families, which pass no
+        # A family's other device state ``_hyb``, donated through every
+        # program beside the pool: the RECURRENT state of every slot,
+        # which is the config's answer as the pools are (`state_planes`:
+        # a `HybridConfig`'s scan and conv state, a `GdnConfig`'s matrix
+        # and conv state), zeroed for a row by its first chunk; and, for
+        # a `HybridConfig` alone, the WINDOW plane (a second `BlockPool`
+        # and table over a pool of its own geometry, `n_window_layers`
+        # deep; a row holds only the blocks that intersect its last
+        # `sliding_window` slots plus what it is about to write,
+        # `_window_release`). None for the other families, which pass no
         # leaf of it to any program.
         self._hybrid = hybrid
+        self._state_planes = tuple(
+            getattr(cfg, "state_planes", lambda: ())())
         self._hyb: Optional[Params] = None
         self.kv_pool_w: Optional[BlockPool] = None
         self.indexer_tokens_scored_total = 0   # token-layers, decode and
@@ -1735,6 +1790,7 @@ class DecodeEngine:
         self.kv_walk_tokens_full_total = 0     # ... per READER of the pool
         self.window_blocks_freed_total = 0     # released behind the window
         self.window_pool_peak_blocks = 0
+        # (recurrent state of any kind: `engine_metrics` says which)
         self.ssm_state_resets_total = 0        # admissions from zero state
         self.ssm_row_steps_total = 0           # live rows x decode tokens
         self.prefill_layer_tokens_total = 0    # token-layers of a dense stack
@@ -1753,6 +1809,8 @@ class DecodeEngine:
                 [] for _ in range(self.B)]
             self._w_lo = np.zeros((self.B,), np.int64)  # first held block
             self._hyb = _hybrid.zero_state(cfg, self.B, n_blocks_w, T)
+        elif self._state_planes:
+            self._hyb = zero_state_planes(self._state_planes, self.B)
         self._prefix: Optional[PrefixCacheIndex] = None
         if prefix_cache:
             self._prefix = PrefixCacheIndex(
@@ -2168,6 +2226,13 @@ class DecodeEngine:
         returns: tokens are handed over when their block has run, never
         a prefill and a block later.
 
+        Rows mid-prompt take one chunk a step, and the chunk of the
+        NEXT step goes out as soon as this step's decode block is
+        dispatched (`_advance_prefills(ahead=True)`): the device runs it
+        while the host waits for the block, replays it, gates and
+        dispatches, and the order of programs on the device is the
+        synchronous engine's.
+
         A slot freed by BUDGET admits its newcomer in the step the
         synchronous engine would: nothing is dispatched ahead of a
         block in which a row is known to end. A row that ends by EOS
@@ -2197,9 +2262,13 @@ class DecodeEngine:
         # a second newcomer then waits for one block, not for two).
         flushed = bool(emitted)
         with self.trace.lane("admit", "admit") as admit:
-            # rows mid-prompt prefill a chunk this step too
+            # rows mid-prompt prefill a chunk this step too, and so did
+            # a row that left its prompt in the chunk sent ahead for
+            # this step
             budget = (self.max_prefills_per_step or self.B) \
-                - len(self._row_prefill)
+                - len(self._row_prefill) \
+                - sum(r not in self._row_prefill
+                      for r in self._chunk_ahead)
             admissions: List[Tuple[int, _Request]] = []
             begin = getattr(self.scheduler, "begin_admission_round", None)
             if begin is not None:
@@ -2311,6 +2380,13 @@ class DecodeEngine:
             decodable = self._dispatch_primary(decodable, live, horizon)
         if not flushed:
             self._top_up_pipeline(decodable, horizon)
+        # A block is in flight that this call (below) or the next one
+        # (its flush) waits for: the rows still mid-prompt take the
+        # NEXT step's chunk now (the prompt is known), so the device
+        # has it to run while the host replays the block, gates and
+        # dispatches.
+        self._advance_prefills(ahead=True)
+        if not flushed:
             self._drain_one(emitted)
         # End of stream: every request retired, but run-ahead blocks
         # may remain (all-masked overrun). Drain them now so pending()
@@ -2561,15 +2637,19 @@ class DecodeEngine:
         self.paged_walk_rows_chained_total += H * (self.B - 1)
         if isinstance(self.cfg, MlaConfig):
             self._count_selection(slots + 1, decode=True)
-        if self._hybrid:
+        if self._state_planes:
             # tokens the kernel is asked to read, a token-layer each: the
-            # full layer's cache once a READER (itself and every
-            # cross-attention layer), a window layer's at most the window
+            # full layers' cache once a READER (a `HybridConfig`'s one
+            # full layer and every cross-attention layer, each of a
+            # `GdnConfig`'s attention layers), a window layer's at most
+            # the window
             cfg = self.cfg
             self.kv_walk_tokens_full_total += int((slots + 1).sum()) \
                 * cfg.full_cache_readers
-            self.kv_walk_tokens_window_total += int(np.minimum(
-                slots + 1, cfg.sliding_window).sum()) * cfg.n_window_layers
+            if self._hybrid:
+                self.kv_walk_tokens_window_total += int(np.minimum(
+                    slots + 1, cfg.sliding_window).sum()) \
+                    * cfg.n_window_layers
             self.ssm_row_steps_total += H * len(rows)
 
     def _count_selection(self, live: np.ndarray,
@@ -2691,12 +2771,14 @@ class DecodeEngine:
 
     def _batch_may_change(self) -> bool:
         """Could the next admission gate or chunk cadence change the
-        batch: a row is mid-prompt, or a request is queued AND a slot is
-        free for it. A queue behind full slots is not a pending
-        admission (the gate skips every taken row), which is what lets
-        a saturated engine run ahead; `admissions_pending()` keeps its
-        meaning, something is queued."""
-        return bool(self._row_prefill) or (
+        batch: a row is mid-prompt (or left its prompt in a chunk sent
+        ahead, after the block in flight was dispatched: `_chunk_ahead`
+        holds it until the next step's chunks), or a request is queued
+        AND a slot is free for it. A queue behind full slots is not a
+        pending admission (the gate skips every taken row), which is
+        what lets a saturated engine run ahead; `admissions_pending()`
+        keeps its meaning, something is queued."""
+        return bool(self._row_prefill) or bool(self._chunk_ahead) or (
             self.scheduler.admissions_pending()
             and any(r is None for r in self.row_req))
 
@@ -2809,6 +2891,8 @@ class DecodeEngine:
         out["decode_dispatches_chained_queued"] = float(
             self.decode_dispatches_chained_queued)
         out["prefill_dispatches"] = float(self.prefill_dispatches)
+        out["prefill_dispatches_ahead"] = float(
+            self.prefill_dispatches_ahead)
         out["host_syncs"] = float(self.host_syncs)
         out["host_syncs_per_token"] = _ratio(self.host_syncs,
                                              self.tokens_out)
@@ -2909,8 +2993,10 @@ class DecodeEngine:
             self.indexer_decode_tokens_scored_total)
         out["indexer_decode_tokens_selected_total"] = float(
             self.indexer_decode_tokens_selected_total)
-        # Hybrid plane (a `HybridConfig`; identically 0.0 otherwise):
-        # host estimates at dispatch, like the paged-walk ones.
+        # Recurrent-state and window planes (a `HybridConfig`; the
+        # `ssm_*` and `kv_walk_tokens_full_total` also a `GdnConfig`;
+        # identically 0.0 otherwise): host estimates at dispatch, like
+        # the paged-walk ones.
         for name in ("kv_walk_tokens_window_total",
                      "kv_walk_tokens_full_total",
                      "window_blocks_freed_total", "window_pool_peak_blocks",
@@ -3059,6 +3145,7 @@ class DecodeEngine:
                       "inflight_steps": len(self._ring)})
         self._ring.clear()
         self._row_prefill.clear()
+        self._chunk_ahead.clear()
         for row in range(self.B):
             try:
                 self._release_row_blocks(row)
@@ -3855,6 +3942,11 @@ class DecodeEngine:
                 f"a HybridConfig cannot be served with {what}: a hand-off "
                 "carries K/V blocks only, not a row's recurrent state or "
                 "its window blocks")
+        if self._state_planes:
+            raise ValueError(
+                f"a {type(self.cfg).__name__} cannot be served with "
+                f"{what}: a hand-off carries K/V blocks only, not a row's "
+                "recurrent state (ROADMAP M4)")
 
     @property
     def _kv_geometry(self) -> Tuple[Tuple[str, int, int], ...]:
@@ -3928,24 +4020,45 @@ class DecodeEngine:
             _, node = st.nodes.pop(0)
             self._prefix.commit(node)
 
-    def _advance_prefills(self) -> None:
+    def _advance_prefills(self, ahead: bool = False) -> None:
         """Advance every mid-prefill row by one chunk (the whole
         remaining suffix when `prefill_chunk` is None), same-bucket
         chunks batched into ONE `_prefill_rows_paged` program. A row
         whose frontier reaches its prompt length leaves `_row_prefill`
         and is decodable THIS step (its last chunk scattered the true
         last-prompt logits). Pending prefix blocks are committed as the
-        frontier passes them."""
-        if not self._row_prefill:
+        frontier passes them.
+
+        ``ahead``: the call a step makes once its decode block is
+        dispatched, before it (or the next step's flush) waits for the
+        block. It dispatches the chunk these rows would take at
+        the start of the NEXT step (a chunk needs nothing the block
+        returns: the prompt is known, the row's blocks are its own), and
+        the next step's call skips them: a row still takes one chunk a
+        step, in the same order on the device behind the same block,
+        and the device has the chunk to run while the host replays the
+        block, gates and dispatches. A newcomer's first chunk is then a
+        program of its own behind it. Not with an adapter pool (a
+        landed prefetch donates the stacks at the gate, which an
+        in-flight chunk still reads) nor a draft plane."""
+        if ahead:
+            if self.adapter_pool is not None or self.spec_enabled:
+                return
+            todo = dict(self._row_prefill)
+        else:
+            todo = {row: st for row, st in self._row_prefill.items()
+                    if self._chunk_ahead.get(row) is not st}
+            self._chunk_ahead = {}
+        if not todo:
             return
         with self.trace.lane("advance_prefills", "dispatch",
-                             rows=len(self._row_prefill)):
+                             rows=len(todo), ahead=ahead):
             # A group is one program: the chunks of one bucket and, for a
             # `HybridConfig`, of one kind, a prompt's last chunk or not
             # (the others are always "last": one program a bucket).
             groups: Dict[Tuple[int, bool],
                          List[Tuple[int, _PrefillState, int]]] = {}
-            for row, st in self._row_prefill.items():
+            for row, st in todo.items():
                 C = len(st.prompt) - st.pos
                 if self.prefill_chunk is not None:
                     C = min(C, self.prefill_chunk)
@@ -3958,7 +4071,8 @@ class DecodeEngine:
                         continue   # the window pool is dry: next step
                     final = st.pos + C >= len(st.prompt)
                 groups.setdefault((Cb, final), []).append((row, st, C))
-            if self._hybrid and not groups and len(self._row_prefill) \
+            if self._hybrid and not groups and not ahead \
+                    and len(todo) == len(self._row_prefill) \
                     == sum(r is not None for r in self.row_req):
                 raise RuntimeError(
                     "window pool exhausted with every live row mid-"
@@ -3995,12 +4109,13 @@ class DecodeEngine:
                         adapters = row_slot = None
                     bt_grp = self._bt[rows]            # [n_pad, MB]
                     btw_grp = None
+                    if self._state_planes:
+                        self.ssm_state_resets_total += sum(
+                            st.pos == 0 for _, st, _ in grp)
                     if self._hybrid:
                         btw_grp = jnp.asarray(self._bt_w[rows])
                         skipped = self.cfg.n_layers \
                             - self.cfg.prefill_layers()
-                        self.ssm_state_resets_total += sum(
-                            st.pos == 0 for _, st, _ in grp)
                         self.prefill_layer_tokens_total += \
                             real * self.cfg.n_layers
                         self.prefill_layer_tokens_skipped_total += \
@@ -4026,6 +4141,7 @@ class DecodeEngine:
                             moe_ctr=self._moe_ctr, hyb=self._hyb,
                             bt_w=btw_grp, final=final)  # graftlint: disable=jit-hygiene -- a bool, part of the group's key: two programs a bucket at most, and only for a HybridConfig
                     self.prefill_dispatches += 1
+                    self.prefill_dispatches_ahead += ahead
                     padded = n_pad * Cb - real
                     self.prefill_real_tokens += real
                     self.prefill_padded_tokens += padded
@@ -4034,6 +4150,8 @@ class DecodeEngine:
             done_rows = []
             for grp in groups.values():
                 for row, st, C in grp:
+                    if ahead:
+                        self._chunk_ahead[row] = st
                     st.pos += C
                     self.row_len[row] = st.pos
                     if self.trace.enabled:

@@ -26,6 +26,19 @@ feeding python/ray/_private/metrics_agent.py). Outside a cluster the
 registry is still populated locally — tests and notebooks read
 `stats()` or `ray_tpu._private.metrics.snapshots()` directly.
 
+`DecodeEngine.stats()` adds the engine's own counters to these series.
+Two of them are named for the family they first counted and count MORE:
+``ssm_state_resets_total`` (rows admitted from zero recurrent state: a
+request's first chunk, a recompute) and ``ssm_row_steps_total`` (live rows
+x decode tokens whose recurrent state a dispatch advances) count recurrent
+state of ANY kind a config declares with `state_planes()`: a
+`HybridConfig`'s state-space layers and a `GdnConfig`'s delta-rule layers
+alike; ``kv_walk_tokens_full_total`` counts, a decode dispatch, the tokens
+the paged kernel is asked to read once for each layer that READS the
+table's pool (a `HybridConfig`'s one full layer and its cross-attention
+readers, each attention layer of a `GdnConfig`). They are 0 for a family
+without recurrent state.
+
 All instruments carry an ``engine`` tag (one DecodeEngine = one tag
 value) so several engines in one process — or one per replica — stay
 separable in the same Prometheus plane.

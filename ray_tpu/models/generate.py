@@ -23,6 +23,7 @@ from ray_tpu.models import hybrid as _hybrid
 from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models import mla as _mla
 from ray_tpu.models.mla import MlaConfig
+from ray_tpu.models.gdn import GdnConfig
 from ray_tpu.models.llama import LlamaConfig, _rmsnorm, _rope
 from ray_tpu.models.moe import (MoeConfig, hit_experts_only,
                                 moe_ffn_dropless, qk_norm)
@@ -47,6 +48,11 @@ def init_cache(cfg: LlamaConfig, batch_size: int,
         return _hybrid.init_cache(cfg, batch_size, max_len)
     if isinstance(cfg, MlaConfig):
         return _mla.init_cache(cfg, batch_size, max_len)
+    if isinstance(cfg, GdnConfig):
+        raise ValueError(
+            "a GdnConfig has no solo generation path: its stack runs "
+            "over the engine's pool, table and state slots; serve it "
+            "through DecodeEngine")
     shape = (cfg.n_layers, batch_size, max_len, cfg.n_kv_heads,
              cfg.head_dim)
     cache = {"k": jnp.zeros(shape, cfg.dtype),

@@ -59,7 +59,8 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.block_pool import kv_planes
+from ray_tpu.models.block_pool import StatePlane, kv_planes, \
+    zero_state_planes
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention
 
@@ -180,6 +181,17 @@ class HybridConfig:
         return kv_planes("full", 1, self.n_kv_heads, self.head_dim, dt) \
             + kv_planes("window", self.n_window_layers, self.n_kv_heads,
                         self.head_dim, dt, prefix="window_")
+
+    def state_planes(self):
+        """What a ROW keeps whatever its length (`block_pool.StatePlane`):
+        a state-space layer's scan state, float32, and its conv's last
+        inputs."""
+        return (StatePlane("ssm", self.n_ssm_layers,
+                           (self.d_state, self.d_inner),
+                           jnp.dtype(jnp.float32)),
+                StatePlane("conv", self.n_ssm_layers,
+                           (self.d_conv - 1, self.d_inner),
+                           jnp.dtype(self.dtype)))
 
     def prefill_layers(self) -> int:
         """Layers that see every prompt token (the rest run for the one
@@ -479,14 +491,10 @@ def zero_state(cfg: HybridConfig, slots: int, n_window_blocks: int,
     and the recurrent state of every engine slot, zeroed."""
     lanes = cfg.n_kv_heads * cfg.head_dim
     wshape = (cfg.n_window_layers, n_window_blocks, block_tokens, lanes)
-    P = cfg.n_ssm_layers
     return {
         "wk": jnp.zeros(wshape, cfg.dtype),
         "wv": jnp.zeros(wshape, cfg.dtype),
-        "ssm": jnp.zeros((P, slots, cfg.d_state, cfg.d_inner),
-                         jnp.float32),
-        "conv": jnp.zeros((P, slots, cfg.d_conv - 1, cfg.d_inner),
-                          cfg.dtype),
+        **zero_state_planes(cfg.state_planes(), slots),
     }
 
 

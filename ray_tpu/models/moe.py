@@ -299,6 +299,14 @@ def _gated_ffn(x, w_gate, w_up, w_down, dt):
                       w_down.astype(dt))
 
 
+def _shared_gate(x, w, dt):
+    """``sigmoid(x . w)`` [G, 1]: what scales a GATED shared expert's
+    output (a config with `shared_expert_gate`)."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "gd,d->g", x, w.astype(dt),
+        preferred_element_type=jnp.float32))[:, None].astype(dt)
+
+
 def _held_hit(xf, combine, w1, w3, w2, first, eh: int, dt):
     """The all-experts form of a layer that HOLDS a share, for few tokens
     (a decode step): an expert's three matrices are read only if some row
@@ -425,7 +433,8 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
     ``cfg`` is an `MoeConfig` or any config with its expert-layer fields
     (`mla.MlaConfig`). With `held_experts` the sum runs over the chosen
     experts that are HELD here and leaves the others' part out; with
-    `n_shared_experts` the shared FFN's output is added once.
+    `n_shared_experts` the shared FFN's output is added once, scaled by
+    `_shared_gate` where the config has `shared_expert_gate`.
 
     ``live`` [B,S] bool marks the rows that are real tokens; given, the
     second result is int32 [3]: (live assignments = live tokens * top_k,
@@ -521,8 +530,11 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
                                  first, eh)
     if cfg.n_shared_experts:
         with jax.named_scope(sn.MOE_SHARED):
-            out = out + _gated_ffn(xf, layer["ws_gate"], layer["ws_up"],
-                                   layer["ws_down"], dt)
+            shared = _gated_ffn(xf, layer["ws_gate"], layer["ws_up"],
+                                layer["ws_down"], dt)
+            if getattr(cfg, "shared_expert_gate", False):
+                shared = _shared_gate(xf, layer["w_sgate"], dt) * shared
+            out = out + shared
     stats = None
     if live is not None:
         with jax.named_scope(sn.MOE_ROUTER):

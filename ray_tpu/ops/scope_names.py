@@ -43,6 +43,16 @@ GMU = "gmu"                          # gated memory unit (reads the memory a
 #                                      state-space layer handed on)
 DIFF_COMBINE = "diff_combine"        # differential attention: subtraction,
 #                                      sub-norm and scale of a head pair
+GDN_PROJ = "gdn_proj"                # gated delta-rule layer: in/out
+#                                      projections, gates, head norm
+GDN_CONV = "gdn_conv"                # ... its causal depthwise conv and the
+#                                      conv state's reads and writes
+GDN_CHUNK = "gdn_chunk"              # ... the delta rule's chunkwise form (a
+#                                      prefill chunk) with the state it moves
+GDN_STEP = "gdn_step"                # ... its one-token update (a decode
+#                                      token) with the state it moves
+ATTN_GATE = "attn_gate"              # gated attention: sigmoid(gate) on the
+#                                      heads' output
 LM_HEAD = "lm_head"                  # final vocab projection
 SAMPLE = "sample"                    # on-device sampling and row freezing
 LOSS = "loss"                        # log-softmax and token nll
@@ -52,7 +62,8 @@ SCOPES = (EMBED, NORM, ATTN_QKV, KV_WRITE, KV_GATHER, PAGED_ATTENTION,
           CACHED_ATTENTION, ATTENTION, ATTN_OUT, MLP, LM_HEAD, SAMPLE,
           LOSS, OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
           SSM_PROJ, SSM_SCAN, GMU, DIFF_COMBINE, MOE_SHARED, MLA_PROJ,
-          INDEXER_SCORE, INDEXER_TOPK, LATENT_GATHER, SPARSE_ATTENTION)
+          INDEXER_SCORE, INDEXER_TOPK, LATENT_GATHER, SPARSE_ATTENTION,
+          GDN_PROJ, GDN_CONV, GDN_CHUNK, GDN_STEP, ATTN_GATE)
 
 # Scopes whose ops move cached K/V without computing on it.
 KV_MOVE = (KV_WRITE, KV_GATHER)

@@ -594,3 +594,142 @@ def test_pipeline_plane_reaches_metrics_registry(nano_model):
     # Pipelining must not break the PR-3 invariant: one transfer per
     # drained horizon, dispatches == syncs once drained.
     assert s["decode_dispatches"] == s["host_syncs"]
+
+
+# ---------------------------------------------------------------------------
+# A mid-prompt row's next chunk goes out before the host waits for the
+# step's decode block
+# ---------------------------------------------------------------------------
+
+def _chunky(params, cfg, depth=2, **kw):
+    return DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                        pipeline_depth=depth, prefill_chunk=3, **kw)
+
+
+def test_chunks_ahead_change_no_token(nano_model):
+    """A decoding row beside a row of seven chunks: the chunks after the
+    first are dispatched ahead of the block the host waits for, and every
+    request's tokens are what an engine that prefills whole prompts
+    returns."""
+    cfg, params = nano_model
+    prompts = _prompts(4, cfg, seed=11, lo=4, hi=8) \
+        + _prompts(2, cfg, seed=12, lo=19, hi=22)
+    budgets = [12, 9, 14, 6, 7, 10]
+    whole, _ = _run(params, cfg, prompts, budgets, 2)
+    eng = _chunky(params, cfg)
+    ids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+    out = eng.run()
+    assert [out[r] for r in ids] == whole
+    s = eng.stats()
+    assert 0 < s["prefill_dispatches_ahead"] < s["prefill_dispatches"]
+    assert not eng._chunk_ahead or not eng._row_prefill
+
+
+def test_a_row_takes_one_chunk_a_step_ahead_or_not(nano_model):
+    """The cadence is the synchronous engine's: a mid-prompt row's
+    frontier moves one chunk a `step()`, whether the chunk went out at the
+    step's start or at the end of the step before."""
+    cfg, params = nano_model
+    eng = _chunky(params, cfg, decode_horizon=1)
+    eng.submit(_prompts(1, cfg, seed=3, lo=5, hi=6)[0], 30)
+    for _ in range(4):
+        eng.step()                   # one row decoding, nothing mid-prompt
+    assert not eng._row_prefill and eng.tokens_out
+    eng.submit(_prompts(1, cfg, seed=4, lo=20, hi=21)[0], 4)
+    seen = []
+    while True:
+        eng.step()
+        if not eng._row_prefill:
+            break
+        seen.append(next(iter(eng._row_prefill.values())).pos)
+    # the first step's own chunk and the one it sent ahead: 6; then one
+    # chunk a step up to the last, which leaves the row decodable
+    assert seen == [6, 9, 12, 15, 18]
+    assert eng.stats()["prefill_dispatches_ahead"] == 6
+    assert eng.stats()["prefill_dispatches"] == 2 + 7
+
+
+def test_no_chunk_goes_ahead_where_nothing_is_waited_for(nano_model):
+    """A lone row mid-prompt has no decode block to wait for: its steps
+    return at once and nothing is sent ahead."""
+    cfg, params = nano_model
+    eng = _chunky(params, cfg)
+    rid = eng.submit(_prompts(1, cfg, seed=5, lo=20, hi=21)[0], 3)
+    out = eng.run()
+    assert len(out[rid]) == 3
+    assert eng.stats()["prefill_dispatches_ahead"] == 0
+    assert eng.stats()["prefill_dispatches"] == 7
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_newcomer_beside_a_chunk_sent_ahead_is_a_group_of_its_own(
+        nano_model, depth):
+    """Three slots: a row decodes, a long prompt is mid-way (its next
+    chunk already out), and a newcomer is admitted: its first chunk is a
+    second program of that step, the long row is not advanced twice, and
+    all three streams are the whole-prompt engine's."""
+    cfg, params = nano_model
+    p = [_prompts(1, cfg, seed=21, lo=5, hi=6)[0],
+         _prompts(1, cfg, seed=22, lo=20, hi=21)[0],
+         _prompts(1, cfg, seed=23, lo=7, hi=8)[0]]
+    ref = DecodeEngine(params, cfg, batch_slots=3, max_len=64,
+                       pipeline_depth=depth, decode_horizon=1)
+    want = []
+    for q, n in zip(p, (25, 5, 6)):
+        r = ref.submit(q, n)
+        want.append(ref.run()[r])
+    eng = DecodeEngine(params, cfg, batch_slots=3, max_len=64,
+                       pipeline_depth=depth, prefill_chunk=3,
+                       decode_horizon=1)
+    a = eng.submit(p[0], 25)
+    eng.step()
+    eng.step()
+    eng.step()
+    b = eng.submit(p[1], 5)
+    eng.step()
+    eng.step()
+    before = eng.stats()["prefill_dispatches"]
+    pos = next(iter(eng._row_prefill.values())).pos
+    c = eng.submit(p[2], 6)
+    eng.step()
+    # the newcomer's chunk + the long row's next one sent ahead, and the
+    # newcomer's second sent ahead with it (one group: one bucket)
+    assert eng.stats()["prefill_dispatches"] == before + 2
+    assert sorted(st.pos for st in eng._row_prefill.values()) == [
+        6, pos + 3]
+    out = eng.run()
+    assert [out[a], out[b], out[c]] == want
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("mpps", [1, 2])
+def test_chunks_ahead_keep_the_schedule(nano_model, monkeypatch, depth,
+                                        mpps):
+    """Call for call, what `step()` returns is what an engine that sends
+    no chunk ahead returns: the same rows admitted in the same steps (a
+    row that left its prompt in a chunk sent ahead still counts against
+    that step's `max_prefills_per_step`), the same tokens."""
+    cfg, params = nano_model
+    prompts = _prompts(10, cfg, seed=31, lo=4, hi=21)
+    budgets = [5, 9, 3, 12, 7, 4, 10, 6, 8, 5]
+
+    def calls(ahead):
+        eng = DecodeEngine(params, cfg, batch_slots=4, max_len=64,
+                           pipeline_depth=depth, prefill_chunk=3,
+                           max_prefills_per_step=mpps)
+        if not ahead:
+            plain = eng._advance_prefills
+            monkeypatch.setattr(
+                eng, "_advance_prefills",
+                lambda ahead=False: None if ahead else plain())
+        for p, n in zip(prompts, budgets):
+            eng.submit(p, n)
+        out = []
+        while eng.pending():
+            out.append(eng.step())
+        return out, eng.stats()["prefill_dispatches_ahead"]
+
+    want, none = calls(False)
+    got, some = calls(True)
+    assert none == 0 and some > 0
+    assert got == want

@@ -682,6 +682,70 @@ def test_mla_cell_programs_fit_the_chip(v5e, program):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# -- the delta-rule cell's programs ----------------------------------------------
+
+def _gdn_program(v5e, program):
+    """The `qwen3next-longctx` cell's engine as `DecodeEngine` builds it
+    (64 slots, 132 table entries of 256 tokens, a 4 GiB K/V pool of the 2
+    attention layers, a matrix and a conv state for 6 delta layers a slot,
+    experts [0, 128) of 512 held, 1/4 of the vocabulary):
+    `_decode_multi_paged` at the cell's horizon of 8 or
+    `_prefill_rows_paged` for 4 x 512 tokens, compiled for the described
+    chip with the paged kernel selected."""
+    from ray_tpu.models import GdnConfig, engine, gdn_init
+    from ray_tpu.models.block_pool import zero_state_planes
+
+    cfg = GdnConfig(vocab_size=37984, n_layers=8, held_experts=(0, 128),
+                    max_seq_len=33792)
+    B, T, MB = 64, 256, 132
+    k, v = cfg.cache_planes()
+    nb = 1 + (4 << 30) // (k.block_bytes(T) + v.block_bytes(T))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def shapes(fn):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype),
+                            jax.eval_shape(fn))
+
+    params = shapes(lambda: gdn_init(jax.random.PRNGKey(0), cfg))
+    state = shapes(lambda: zero_state_planes(cfg.state_planes(), B))
+    pool = arg((k.layers, nb, T, k.lanes), jnp.bfloat16)
+    logits = arg((B, cfg.vocab_size), jnp.float32)
+    ctr = arg((5,))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        if program == "decode":
+            lane, flag = arg((B,)), arg((B,), jnp.bool_)
+            lowered = engine._decode_multi_paged.lower(
+                params, pool, pool, arg((B, MB)), logits, lane, flag, lane,
+                lane, arg((B, 2), jnp.uint32), flag, 1.0, cfg, 8, True,
+                None, None, None, moe_ctr=ctr, hyb=state)
+        else:
+            lowered = engine._prefill_rows_paged.lower(
+                params, arg((4, 512)), pool, pool, logits, arg((4, MB)),
+                arg((4,)), arg((4,)), arg((4,)), cfg, moe_ctr=ctr,
+                hyb=state)
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_gdn_cell_programs_fit_the_chip(v5e, program):
+    """One chip's share of Qwen3-Next-80B-A3B in bf16 (two periods, 128 of
+    512 experts a layer, a quarter of the vocabulary: 3.67 B parameters,
+    6.83 GiB) beside a 4 GiB K/V pool and 0.77 GiB of recurrent state: the
+    bytes `engine_notes` of the cell's configuration states. The paged
+    kernel compiles at 2 KV heads of 256 with 8 query heads each; the pool
+    and the state are updated in place."""
+    compiled = _gdn_program(v5e, program)
+    m = compiled.memory_analysis()
+    assert 11.5 * 2**30 < m.argument_size_in_bytes < 12 * 2**30
+    assert m.temp_size_in_bytes < 2 * 2**30
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2**30
+    # donated and aliased: pool 4 GiB, state 0.77, logits
+    assert m.alias_size_in_bytes > 4.7 * 2**30
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
 # -- the train cell's step -------------------------------------------------------
 
 def _train_cell_step(topo):
