@@ -104,7 +104,7 @@ from ray_tpu.models import gdn as _gdn
 from ray_tpu.models.gdn import GdnConfig
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
-from ray_tpu.models.moe import MoeConfig
+from ray_tpu.models.moe import MoeConfig, held_grouped_prefill
 from ray_tpu.models.prefix_cache import PrefixCacheIndex
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention, spmd_mesh_scope
@@ -1652,6 +1652,8 @@ class DecodeEngine:
         self.prefill_dispatches = 0    # batched prefill launches
         self.prefill_dispatches_ahead = 0   # ... launched before the
         #                                step's decode block was pulled
+        self.moe_grouped_prefill_dispatches = 0   # ... whose held experts
+        #                    went through `ops.held_grouped_ffn`'s kernel
         self.host_syncs = 0            # device->host transfers
         self.device_waits = 0          # blocking pulls (`_device_wait`)
         self.device_wait_s = 0.0       # engine-clock seconds inside them
@@ -2893,6 +2895,8 @@ class DecodeEngine:
         out["prefill_dispatches"] = float(self.prefill_dispatches)
         out["prefill_dispatches_ahead"] = float(
             self.prefill_dispatches_ahead)
+        out["moe_grouped_prefill_dispatches_total"] = float(
+            self.moe_grouped_prefill_dispatches)
         out["host_syncs"] = float(self.host_syncs)
         out["host_syncs_per_token"] = _ratio(self.host_syncs,
                                              self.tokens_out)
@@ -4142,6 +4146,8 @@ class DecodeEngine:
                             bt_w=btw_grp, final=final)  # graftlint: disable=jit-hygiene -- a bool, part of the group's key: two programs a bucket at most, and only for a HybridConfig
                     self.prefill_dispatches += 1
                     self.prefill_dispatches_ahead += ahead
+                    self.moe_grouped_prefill_dispatches += \
+                        held_grouped_prefill(self.cfg, n_pad * Cb)
                     padded = n_pad * Cb - real
                     self.prefill_real_tokens += real
                     self.prefill_padded_tokens += padded

@@ -48,6 +48,8 @@ from ray_tpu.models.llama import (LlamaConfig, _attention_call,
                                   _layer_checkpoint, _layer_shapes,
                                   _rmsnorm, _rope)
 from ray_tpu.ops import scope_names as sn
+from ray_tpu.ops.held_grouped_ffn import (ROW_TILE, held_grouped_ffn,
+                                          held_grouped_tiles, visit_schedule)
 from ray_tpu.ops.hit_experts import hit_experts_ffn
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_to_mesh
 
@@ -261,7 +263,17 @@ DENSE_HELD_MAX_TOKENS = 256
 # The sorted form of a layer that holds a share works through the
 # assignments that landed in windows of this many times their expected
 # number (rounded up to `_HELD_ROWS_ALIGN`): one window nearly always,
-# more for ANY routing, all assignments on held experts included.
+# more for ANY routing, all assignments on held experts included. The
+# slack costs the window's gather and scatter-add, not matmuls, where the
+# grouped kernel serves (`ops.held_grouped_ffn`: a row tile past what
+# landed is never visited; Qwen3-Next's 4 x 512 chunk gathers 10,240 rows
+# and multiplies 167 tiles of 128 for the 5,120 that land). Measured on a
+# v5e at Qwen3-Next's widths, 128 held of 512, the stacks of 8 layers (PR
+# 45, PERF.md section 6), ms a layer-call, three `ragged_dot` / the
+# kernel: 5,120 landed 2.82 / 1.33, 1,280 landed 2.64 / 1.16, 10,240
+# landed 3.05 / 1.55 (the 805 MB of a layer take 0.98); the prefill
+# program alone, 1 / 2 / 4 rows of 512: 30.5 / 40.5 / 61.1 -> 18.8 / 29.0
+# / 48.8 ms.
 HELD_ROWS_SLACK = 2.0
 _HELD_ROWS_ALIGN = 256
 
@@ -358,6 +370,17 @@ def _compact_hit(combine):
     return cw, ids, chosen.sum(dtype=jnp.int32)
 
 
+def held_grouped_prefill(cfg, tokens: int) -> bool:
+    """Whether `moe_ffn_dropless` takes, for this many tokens, the sorted
+    form over a held range through the grouped kernel of
+    `ops.held_grouped_ffn` (what `_held_sorted` decides from its operands'
+    shapes, said from the config: the engine counts such programs)."""
+    return getattr(cfg, "held_experts", None) is not None \
+        and tokens > DENSE_HELD_MAX_TOKENS and held_grouped_tiles(
+            cfg.dim, cfg.expert_dim if hasattr(cfg, "expert_dim")
+            else cfg.ffn_dim, cfg.dtype) is not None
+
+
 def _held_sorted(xf, weights, idx, w1, w3, w2, lo: int, e: int, dt,
                  first=0, eh: Optional[int] = None):
     """The sorted form over the assignments that land on the held experts
@@ -368,16 +391,28 @@ def _held_sorted(xf, weights, idx, w1, w3, w2, lo: int, e: int, dt,
     weighted and added to its token. One window nearly always; as many as
     it takes for ANY routing. The held experts are ``first .. first + eh
     - 1`` of the stacks (all of them by default): the stacks of SEVERAL
-    layers go in whole and the groups of the others stay empty, so no
-    layer's weights are sliced out and copied for the ragged product (a
-    sixth of a prefill program's time, PERF.md PR 33). Returns ([G, d] in
-    ``dt``, the rows the matmuls computed, a traced int32)."""
+    layers go in whole, so no layer's weights are sliced out and copied
+    (a sixth of a prefill program's time, PERF.md PR 33).
+
+    The matmuls are `ops.held_grouped_ffn`, one kernel a window whose
+    grid visits the (group, 128-row tile) pairs that hold rows and reads
+    each such group's matrices once: the other layers' groups, a held
+    group nobody chose and the window's rows past what landed cost
+    nothing. Where a visit's working set does not fit the default scoped
+    VMEM (`held_grouped_tiles`: whole rows of d 7,168 beside three weight
+    tiles) they stay XLA's `ragged_dot` with the other layers' group
+    sizes 0: an empty group costs it 0.05 us, a group that holds rows a
+    masked row tile of some hundreds of rows whatever it holds (7.3 us
+    at Qwen3-Next's widths where its 2 MiB take 2.6 to read, near the
+    bytes at DeepSeek's 29 MB a matrix: PERF.md PR 45). Returns ([G, d]
+    in ``dt``, the rows the matmuls computed, a traced int32)."""
     g, d = xf.shape
     k = idx.shape[1]
     eh = w1.shape[0] if eh is None else eh
     n = g * k
     c = min(n, -(-int(HELD_ROWS_SLACK * n * eh / e) // _HELD_ROWS_ALIGN)
             * _HELD_ROWS_ALIGN)
+    grouped = held_grouped_tiles(d, w1.shape[2], dt) is not None
     with jax.named_scope(sn.MOE_DISPATCH):
         local = idx.reshape(n) - lo
         key = jnp.where((local >= 0) & (local < eh), local, eh)
@@ -390,32 +425,44 @@ def _held_sorted(xf, weights, idx, w1, w3, w2, lo: int, e: int, dt,
         n_landed = ends[-1]
         wflat = weights.reshape(n)
 
-    def window(i, out):
+    def window(i, carry):
+        out, visits = carry if grouped else (carry, None)
         base = i * c
         with jax.named_scope(sn.MOE_DISPATCH):
             rows = jax.lax.dynamic_slice(order, (base,), (c,))
             ok = base + jnp.arange(c, dtype=jnp.int32) < n_landed
             tok = rows // k
             xs = xf[tok]                                        # [c, d]
-            sizes = jnp.clip(ends, base, base + c) \
-                - jnp.clip(starts, base, base + c)
-            if w1.shape[0] != eh:     # this layer's groups among all
+            upto = jnp.clip(ends, base, base + c)
+            begin = jnp.clip(starts, base, base + c)
+            sizes = upto - begin
+            if grouped:
+                sched = visit_schedule(begin - base, sizes, c)
+            elif w1.shape[0] != eh:   # this layer's groups among all
                 sizes = jax.lax.dynamic_update_slice(
                     jnp.zeros((w1.shape[0],), jnp.int32), sizes, (first,))
         with jax.named_scope(sn.MOE_EXPERTS):
-            gate = jax.lax.ragged_dot(xs, w1, sizes)
-            up = jax.lax.ragged_dot(xs, w3, sizes)
-            ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes)
+            if grouped:
+                ys = held_grouped_ffn(xs, sched, first, w1, w3, w2)
+            else:
+                gate = jax.lax.ragged_dot(xs, w1, sizes)
+                up = jax.lax.ragged_dot(xs, w3, sizes)
+                ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes)
         with jax.named_scope(sn.MOE_DISPATCH):
             # rows past what landed belong to no group: whatever the
-            # ragged product left there is not added
+            # matmuls left there is not added
             ys = jnp.where(ok[:, None], ys.astype(jnp.float32)
                            * wflat[rows][:, None], 0.0)
-            return out.at[tok].add(ys)
+            out = out.at[tok].add(ys)
+        return (out, visits + sched.n) if grouped else out
 
     n_win = (n_landed + (c - 1)) // c
-    out = jax.lax.fori_loop(0, n_win, window,
-                            jnp.zeros((g, d), jnp.float32))
+    zero = jnp.zeros((g, d), jnp.float32)
+    if grouped:     # the kernel multiplied its visits' row tiles
+        out, visits = jax.lax.fori_loop(0, n_win, window,
+                                        (zero, np.int32(0)))
+        return out.astype(dt), visits * np.int32(ROW_TILE)
+    out = jax.lax.fori_loop(0, n_win, window, zero)
     return out.astype(dt), n_win * np.int32(c)
 
 

@@ -77,8 +77,9 @@ PAGED_KERNEL = "paged_attention"
 SPARSE_LATENT_KERNEL = "sparse_latent_attention"
 SPARSE_LATENT_DECODE_KERNEL = "sparse_latent_decode"
 HIT_EXPERTS_KERNEL = "moe_hit_experts"
+HELD_GROUPED_KERNEL = "moe_held_grouped"
 
 KERNELS = (PAGED_KERNEL, FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV,
            SPARSE_LATENT_KERNEL, SPARSE_LATENT_DECODE_KERNEL,
-           HIT_EXPERTS_KERNEL)
+           HIT_EXPERTS_KERNEL, HELD_GROUPED_KERNEL)
 

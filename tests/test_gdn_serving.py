@@ -499,3 +499,69 @@ def test_a_gdn_engine_refuses_a_hand_off(params, call):
 def test_a_config_that_is_no_such_stack_is_refused(bad):
     with pytest.raises(ValueError, match="GdnConfig"):
         nano_gdn(**bad)
+
+
+# -- the grouped kernel: which programs take it, and who counts them -------------
+
+def _kernel_names(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield str(eqn.params.get("name") or eqn.params.get(
+                "name_and_src_info"))
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _kernel_names(inner)
+
+
+@pytest.mark.parametrize("tokens,grouped", [(2, False), (64, False),
+                                            (256, False), (264, True)],
+                         ids=["decode_2", "decode_64", "chunk_256",
+                              "chunk_264"])
+def test_only_a_chunk_past_the_all_experts_form_traces_the_grouped_kernel(
+        params, tokens, grouped):
+    """A fused decode program multiplies at most `batch_slots` rows a
+    layer (64 in the cell): its expert layer is `_held_hit`, and no call
+    of `ops.held_grouped_ffn`'s kernel is traced into it; a prefill
+    program over `DENSE_HELD_MAX_TOKENS` tokens holds one a layer."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops import scope_names as sn
+
+    layer = jax.tree_util.tree_map(lambda x: x[0, 0],
+                                   params["period"]["moe"])
+    x = jnp.zeros((1, tokens, CFG.dim), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x: moe.moe_ffn_dropless(
+        x, layer, CFG, live=jnp.ones((1, tokens), bool)))(x)
+    names = list(_kernel_names(jaxpr.jaxpr))
+    assert any(sn.HELD_GROUPED_KERNEL in n for n in names) == grouped
+    assert moe.held_grouped_prefill(CFG, tokens) == grouped
+
+
+@pytest.mark.parametrize("family", ["held", "no_held_range"])
+def test_grouped_prefill_dispatches_are_counted_where_the_kernel_runs(family):
+    """A prompt of three chunks, 264 + 264 + 24 tokens: the two programs
+    over `DENSE_HELD_MAX_TOKENS` tokens of a config that holds a share go
+    through the grouped kernel and are counted; the last chunk is not,
+    and an `MoeConfig` with every expert here counts nothing."""
+    from ray_tpu.models import MoeConfig, moe, moe_init
+
+    assert moe.DENSE_HELD_MAX_TOKENS < 264
+    if family == "held":
+        cfg = nano_gdn(held_experts=(0, 4), n_layers=4, max_seq_len=640)
+        p = jax.jit(gdn_init, static_argnums=1)(jax.random.PRNGKey(1), cfg)
+    else:
+        cfg = MoeConfig.nano_moe(max_seq_len=640)
+        p = jax.jit(moe_init, static_argnums=1)(jax.random.PRNGKey(1), cfg)
+    eng = DecodeEngine(p, cfg, batch_slots=2, max_len=640,
+                       kv_block_tokens=T, prefill_chunk=264,
+                       preempt="recompute", pipeline_depth=1)
+    rid = eng.submit(prompt_of(552, seed=3), max_new_tokens=2)
+    assert len(eng.run()[rid]) == 2
+    st = eng.stats()
+    assert st["prefill_dispatches"] == 3
+    assert st["moe_grouped_prefill_dispatches_total"] \
+        == (2 if family == "held" else 0)
+    if family == "held":
+        # what the kernel multiplied: 128-row visits, not whole windows
+        assert st["moe_rows_computed_total"] \
+            >= st["moe_assignments_landed_total"] > 0

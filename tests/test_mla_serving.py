@@ -479,33 +479,61 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                np.asarray(whole), atol=5e-5, rtol=0)
 
 
+# what differs from CFG, held, layers in the stack, this layer, the least
+# slack: the nano DeepSeek share (4 of 16, the layer's own stacks) and
+# Qwen3-Next's ratio (128 of 512, 10 a token, the second of three layers'
+# stacks, the others' NaN: no form may read them; 10 windows of 75 rows,
+# not 94 of 8)
+_SHARES = {
+    "dsv32": ({}, (4, 8), 1, 0, 0.0),
+    "qwen3next": ({"n_experts": 512, "top_k": 10, "n_group": 1,
+                   "topk_group": 1}, (128, 256), 3, 1, 0.1)}
+
+
+@pytest.mark.parametrize("share", list(_SHARES))
 @pytest.mark.parametrize("tokens,slack", [(40, 2.0), (300, 2.0),
                                           (300, 0.01)],
                          ids=["all_experts", "sorted", "sorted_many_windows"])
-def test_held_layer_forms_agree_for_any_routing(tokens, slack, monkeypatch):
+def test_held_layer_forms_agree_for_any_routing(tokens, slack, share,
+                                                monkeypatch):
     """Few tokens multiply every held expert, many sort the assignments
     that landed and work through them a window at a time; a window far
     too small for what landed takes many turns and drops nothing."""
+    other, (lo, hi), n_stack, li, least = _SHARES[share]
+    slack = max(slack, least)
     monkeypatch.setattr(moe, "HELD_ROWS_SLACK", slack)
     monkeypatch.setattr(moe, "_HELD_ROWS_ALIGN", 8)
-    cfg_all = dataclasses.replace(CFG, held_experts=None)
+    cfg_all = dataclasses.replace(CFG, held_experts=None, **other)
+    top_k = cfg_all.top_k
+    model = model_of(cfg_all)
     layer = _full_layer(jax.random.PRNGKey(5), cfg_all)
-    mine = dict(layer, **{k: layer[k][4:8]
-                          for k in ("we_gate", "we_up", "we_down")})
+    stacks = ("we_gate", "we_up", "we_down")
+    mine = dict(layer, **{k: layer[k][lo:hi] for k in stacks})
     u = jax.random.normal(jax.random.PRNGKey(6), (tokens, CFG.dim),
                           jnp.float32)
-    cfg = dataclasses.replace(CFG, held_experts=(4, 8))
+    cfg = dataclasses.replace(cfg_all, held_experts=(lo, hi))
     live = jnp.ones((1, tokens), bool)
-    got, stats = moe.moe_ffn_dropless(u[None], mine, cfg, live=live)
+    stacked = dict(mine, **{k: jnp.concatenate(
+        [mine[k] if i == li else jnp.full_like(mine[k], jnp.nan)
+         for i in range(n_stack)]) for k in stacks})
+    got, stats = moe.moe_ffn_dropless(
+        u[None], stacked, cfg, live=live,
+        expert_stack_layer=li if n_stack > 1 else None)
     with jax.default_matmul_precision("highest"):
-        want = ref.expert_layer(u, mine, MODEL, held=(4, 8))
-        _, idx = ref.route(u, layer, MODEL)
+        want = ref.expert_layer(u, mine, model, held=(lo, hi))
+        _, idx = ref.route(u, layer, model)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                atol=5e-5, rtol=0)
-    landed = int(((idx >= 4) & (idx < 8)).sum())
-    assert int(stats[0]) == tokens * CFG.top_k and int(stats[3]) == landed
+    landed = int(((idx >= lo) & (idx < hi)).sum())
+    assert int(stats[0]) == tokens * top_k and int(stats[3]) == landed
     if tokens > moe.DENSE_HELD_MAX_TOKENS:
-        assert int(stats[1]) >= landed           # rows the windows covered
+        assert int(stats[1]) >= landed           # rows the matmuls covered
+        # the grouped kernel's 128-row visits: in one window at most a
+        # tile more a group than what landed fills
+        assert moe.held_grouped_prefill(cfg, tokens)
+        assert int(stats[1]) % 128 == 0
+        if slack == 2.0:
+            assert int(stats[1]) <= 128 * (-(-landed // 128) + hi - lo)
 
 
 def test_counters_count_routed_and_landed(params):

@@ -19,6 +19,7 @@ Nothing runs, so these say nothing about values (the interpret-mode
 sweeps do) or times (only a chip run does).
 """
 
+import functools
 import math
 import os
 import re
@@ -679,7 +680,13 @@ def test_mla_cell_programs_fit_the_chip(v5e, program):
     assert m.alias_size_in_bytes > 3 * 2**30
     # the selection's kernel: a token's pages read where they lie, a
     # chunk's keys under its mask
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the held experts' matmuls stay XLA's at 7,168 x 2,048 an expert
+    # (`held_grouped_tiles`): no program of this cell holds the grouped
+    # kernel
+    from ray_tpu.ops.scope_names import HELD_GROUPED_KERNEL
+    assert HELD_GROUPED_KERNEL not in text
 
 
 # -- the delta-rule cell's programs ----------------------------------------------
@@ -743,7 +750,60 @@ def test_gdn_cell_programs_fit_the_chip(v5e, program):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2**30
     # donated and aliased: pool 4 GiB, state 0.77, logits
     assert m.alias_size_in_bytes > 4.7 * 2**30
-    assert compiled.as_text().count("tpu_custom_call") >= 1
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    # the held experts' matmuls: the grouped kernel in a 4 x 512 chunk
+    # (one call a layer body: the delta period's and the attention
+    # layer's), `_held_hit`'s conds in the decode program's 64 rows
+    from ray_tpu.ops.scope_names import HELD_GROUPED_KERNEL
+    assert (HELD_GROUPED_KERNEL in text) == (program == "prefill")
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+
+
+# -- the held experts' grouped kernel ---------------------------------------------
+
+# window rows, d, f, held groups, groups in the stacks
+_HELD_PREFILL = {
+    "qwen3next_4x512": (10240, 2048, 512, 128, 1024),
+    "qwen3next_1x512": (2560, 2048, 512, 128, 1024),
+    "dsv32_4x512": (2048, 7168, 2048, 16, 64),
+}
+
+
+@pytest.mark.parametrize("case", list(_HELD_PREFILL))
+def test_held_grouped_kernel_at_the_held_cells_prefill_shapes(v5e, case):
+    """Qwen3-Next's experts (2,048 x 512) go through the grouped kernel
+    inside the default scoped VMEM (no `vmem_limit_bytes`: PERF.md PR 30).
+    DeepSeek-V3.2's (7,168 x 2,048) do not fit there, whole rows of d
+    beside three weight tiles: `held_grouped_tiles` says so from the
+    widths, `_held_sorted` keeps `ragged_dot`, and Mosaic refuses the
+    narrowest tile when it is forced."""
+    from ray_tpu.ops import held_grouped_ffn as hg
+
+    c, d, f, eh, n = _HELD_PREFILL[case]
+    bf = jnp.bfloat16
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    args = (arg((c, d), bf), arg((eh,)), arg((eh,)), arg(()),
+            arg((n, d, f), bf), arg((n, d, f), bf), arg((n, f, d), bf))
+
+    def fn(xs, starts, sizes, first, w1, w3, w2, tf=None):
+        return hg.held_grouped_ffn(
+            xs, hg.visit_schedule(starts, sizes, c), first, w1, w3, w2,
+            interpret=False, tf=tf)
+
+    tiles = hg.held_grouped_tiles(d, f, bf)
+    if case.startswith("qwen3next"):
+        assert tiles is not None
+        compiled = jax.jit(fn).lower(*args).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and "vmem_limit" not in text
+    else:
+        assert tiles is None
+        with pytest.raises(Exception, match="(?i)vmem"):
+            jax.jit(functools.partial(fn, tf=128)).lower(*args).compile()
 
 
 # -- the train cell's step -------------------------------------------------------

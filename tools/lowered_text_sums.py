@@ -5,7 +5,7 @@ that a change left a family's programs as they were (PERF.md section 7:
     python tools/lowered_text_sums.py <tree root>
 
 lowers, from THAT tree and for a described v5e (no chip), the decode and
-prefill programs of the Mistral, Phi-4-mini-flash, dsv32 and OLMoE cells at
+prefill programs of the Mistral, Phi-4-mini-flash, dsv32, qwen3next and OLMoE cells at
 the cells' shapes (the builders are `tests/test_tpu_compile.py`'s), masks
 the Pallas kernels' payloads and source locations, and prints one sha256
 and the text's length a program. Run it on the parent's tree and on the
@@ -42,6 +42,8 @@ progs = {
     "phi4flash_prefill_4x512_not_last": lambda: T._hybrid_program(v5e, "prefill_not_last"),
     "dsv32_decode": lambda: T._mla_program(v5e, "decode"),
     "dsv32_prefill_4x512": lambda: T._mla_program(v5e, "prefill"),
+    "qwen3next_decode": lambda: T._gdn_program(v5e, "decode"),
+    "qwen3next_prefill_4x512": lambda: T._gdn_program(v5e, "prefill"),
     "olmoe_prefill_1x256": lambda: T._prefill_program(v5e, T._olmoe(12), 615, 64, 1, 256),
     "olmoe_prefill_1x512": lambda: T._prefill_program(v5e, T._olmoe(12), 615, 64, 1, 512),
     "olmoe_prefill_4x512": lambda: T._prefill_program(v5e, T._olmoe(12), 615, 64, 4, 512),
