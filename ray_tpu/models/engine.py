@@ -104,7 +104,8 @@ from ray_tpu.models import gdn as _gdn
 from ray_tpu.models.gdn import GdnConfig
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   llama_param_specs)
-from ray_tpu.models.moe import MoeConfig, held_grouped_prefill
+from ray_tpu.models.moe import (MoeConfig, held_grouped_prefill,
+                                held_hit_kernel)
 from ray_tpu.models.prefix_cache import PrefixCacheIndex
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention, spmd_mesh_scope
@@ -1649,6 +1650,8 @@ class DecodeEngine:
         self.decode_dispatches = 0     # fused decode program launches
         self.decode_dispatches_chained = 0         # ... made run-ahead
         self.decode_dispatches_chained_queued = 0  # ... a request queued
+        self.moe_hit_kernel_decode_dispatches = 0  # fused decode blocks
+        #    whose HELD experts went through `ops.hit_experts`'s kernel
         self.prefill_dispatches = 0    # batched prefill launches
         self.prefill_dispatches_ahead = 0   # ... launched before the
         #                                step's decode block was pulled
@@ -2587,6 +2590,8 @@ class DecodeEngine:
                                             run_ahead=chain is not None,
                                             chain=(rl, ac, bu, ti)))
             self._count_decode_dispatch(chain)
+            self.moe_hit_kernel_decode_dispatches += \
+                held_hit_kernel(self.cfg, self.B)
             self.metrics.on_dispatch(H, host_syncs=0)
 
     def _count_decode_dispatch(self, chain: Optional[tuple]) -> None:
@@ -2897,6 +2902,8 @@ class DecodeEngine:
             self.prefill_dispatches_ahead)
         out["moe_grouped_prefill_dispatches_total"] = float(
             self.moe_grouped_prefill_dispatches)
+        out["moe_hit_kernel_decode_dispatches_total"] = float(
+            self.moe_hit_kernel_decode_dispatches)
         out["host_syncs"] = float(self.host_syncs)
         out["host_syncs_per_token"] = _ratio(self.host_syncs,
                                              self.tokens_out)

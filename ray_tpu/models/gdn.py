@@ -475,8 +475,10 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
     dt = cfg.dtype
     nd = cfg.delta_per_period
     slots = starts[:, None] + jnp.arange(S)[None, :]
-    q_slots = slots if n_valid is None else jnp.where(
-        jnp.arange(S)[None, :] < n_valid[:, None], slots, -1)
+    # a chunk's bucket filler queries nothing: its result is never read
+    real = None if n_valid is None else \
+        jnp.arange(S)[None, :] < n_valid[:, None]
+    q_slots = slots if real is None else jnp.where(real, slots, -1)
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(dt)[toks]
     bidx = jnp.arange(B)[:, None]
@@ -530,8 +532,11 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
         x = _rmsnorm1p(h, p["norm"], cfg.norm_eps)
         # the expert stacks of ALL layers go in whole, with this layer's
         # index: nothing of a layer's size is sliced out of them
+        # a chunk group's padding rows repeat another row: `moe_live`
+        # counts them once, but their K/V and state land on their twin's,
+        # so they are READ
         out, st = moe_ffn_dropless(x, {**p, **experts}, cfg, live=moe_live,
-                                   expert_stack_layer=li)
+                                   expert_stack_layer=li, read=real)
         return h + out, st
 
     def delta_body(carry, xs):

@@ -572,8 +572,12 @@ def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
     B, S = toks.shape
     dt = cfg.dtype
     slots = starts[:, None] + jnp.arange(S)[None, :]
-    q_slots = slots if n_valid is None else jnp.where(
-        jnp.arange(S)[None, :] < n_valid[:, None], slots, -1)
+    # a chunk's bucket filler queries nothing: its result is never read; a
+    # chunk group's padding rows repeat another row, are counted once by
+    # `moe_live` and land on their twin's slots, so they ARE read
+    real = None if n_valid is None else \
+        jnp.arange(S)[None, :] < n_valid[:, None]
+    q_slots = slots if real is None else jnp.where(real, slots, -1)
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(dt)[toks]
 
@@ -599,7 +603,8 @@ def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
         # the expert stacks of ALL expert layers go in whole, with this
         # layer's index: nothing of a layer's size is sliced out of them
         out, st = moe_ffn_dropless(x, {**p, **experts}, cfg, live=moe_live,
-                                   expert_stack_layer=li - cfg.n_dense_layers)
+                                   expert_stack_layer=li - cfg.n_dense_layers,
+                                   read=real)
         return (h + out, pc, pi), (st, sel if want_selection else None)
 
     stacks = ("we_gate", "we_up", "we_down")

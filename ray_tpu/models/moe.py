@@ -16,7 +16,8 @@ new-framework original. Two expert layers live here, one per job:
   with: a few tokens (a decode step, most of whose rows may be dead
   slots) multiply every row by every expert some LIVE row chose and read
   no other expert's weights (`ops.hit_experts`: what is read follows the
-  number hit, which the program counts each step); a chunk multiplies
+  number hit, which the program counts each step; a layer that HOLDS a
+  share of the experts reads the held ones so); a chunk multiplies
   every row by every expert, since its tokens hit them all and the extra
   FLOPs hide under that read; many tokens (a prefill group) sort the
   assignments by expert and multiply them as ragged groups
@@ -50,7 +51,7 @@ from ray_tpu.models.llama import (LlamaConfig, _attention_call,
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.held_grouped_ffn import (ROW_TILE, held_grouped_ffn,
                                           held_grouped_tiles, visit_schedule)
-from ray_tpu.ops.hit_experts import hit_experts_ffn
+from ray_tpu.ops.hit_experts import hit_experts_ffn, hit_experts_tile
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_to_mesh
 
 Params = Dict[str, Any]
@@ -253,7 +254,19 @@ DENSE_EXPERTS_MAX_TOKENS = 512
 # einsum. Measured on a v5e at OLMoE's widths (PR 34, PERF.md section 6),
 # ms for 12 layers, all-experts einsum / hit-only: 32 rows of which 8 live
 # 13.82 / 8.63 (42 experts hit), 32 live 13.82 / 12.80 (63.3); 64 rows,
-# all live 13.49 / 12.96; 128 rows 13.53 / 13.00 (64 hit).
+# all live 13.49 / 12.96; 128 rows 13.53 / 13.00 (64 hit). A layer that
+# HOLDS a share reads its held experts through the same kernel
+# (`held_hit_kernel`), where it ran a `cond` an expert (`_held_hit`)
+# across which nothing is fetched ahead. The decode program alone on a
+# v5e, rows of 4,096 tokens (PR 46, `tools/decode_alone.py`, PERF.md
+# section 6), ms a token, `cond` form / kernel: Qwen3-Next (128 held of
+# 512, 6.3 MB an expert, one step an expert) 16 live rows 12.53 / 9.95
+# (33.7 experts hit a layer), 32 15.51 / 11.88 (58.5), 64 19.38 / 14.56
+# (88.3); DeepSeek-V3.2 (16 held of 256, 88 MB an expert, 16 steps of 128
+# values of f) 12 rows 12.81 / 12.59 (5.25 and 4.75 hit), 24 rows 15.41 /
+# 15.05 (8.88 and 8.12: the tokens differ, and 0.76 experts of 88 MB in 4
+# layers are 0.33 ms): no slower where 88 MB an expert hid the `cond`, so
+# one form serves both and the tile follows from the widths.
 HIT_EXPERTS_MAX_TOKENS = 128
 # A layer that HOLDS a share of the experts computes, all-experts, E_held
 # rows a token where top_k * E_held / E land (32 times the work at 16 of
@@ -320,13 +333,15 @@ def _shared_gate(x, w, dt):
 
 
 def _held_hit(xf, combine, w1, w3, w2, first, eh: int, dt):
-    """The all-experts form of a layer that HOLDS a share, for few tokens
-    (a decode step): an expert's three matrices are read only if some row
-    chose it. ``combine`` [G, E_held] holds each row's weight for each
-    held expert (0: not chosen); the experts are ``first .. first + eh -
-    1`` of the stacks ``w1``/``w3`` [*, d, f], ``w2`` [*, f, d]. At 24
-    rows x 8 of 256 experts a third of the 16 held are hit a step: the
-    other two thirds of the layer's bytes stay in HBM."""
+    """The all-experts form of a layer that HOLDS a share, in plain XLA,
+    for the tokens the kernel of `ops.hit_experts` does not take
+    (`held_hit_kernel`: a chunk of 129 to `DENSE_HELD_MAX_TOKENS` tokens,
+    or rows and widths whose step does not fit the scoped VMEM): an
+    expert's three matrices are read only if some row chose it, a `cond`
+    an expert, across which nothing is fetched ahead. ``combine`` [G,
+    E_held] holds each row's weight for each held expert (0: not chosen);
+    the experts are ``first .. first + eh - 1`` of the stacks ``w1``/``w3``
+    [*, d, f], ``w2`` [*, f, d]."""
     g, d = xf.shape
 
     def one(j, out):
@@ -354,6 +369,23 @@ def hit_experts_only(cfg, tokens: int) -> bool:
     return cfg.held_experts is None and tokens <= HIT_EXPERTS_MAX_TOKENS
 
 
+def _expert_width(cfg) -> int:
+    return cfg.expert_dim if hasattr(cfg, "expert_dim") else cfg.ffn_dim
+
+
+def held_hit_kernel(cfg, tokens: int) -> bool:
+    """Whether `moe_ffn_dropless` takes, for this many tokens, the kernel
+    of `ops.hit_experts` over a HELD range (the held families' stacks are
+    those of all layers always, so this is not `hit_experts_only`'s to
+    say): at most `HIT_EXPERTS_MAX_TOKENS` rows, and experts of widths at
+    which `hit_experts_tile` finds a step that fits the default scoped
+    VMEM beside the rows. Said from the config: the engine counts such
+    decode blocks."""
+    return getattr(cfg, "held_experts", None) is not None \
+        and tokens <= HIT_EXPERTS_MAX_TOKENS and hit_experts_tile(
+            tokens, cfg.dim, _expert_width(cfg), cfg.dtype) is not None
+
+
 def _compact_hit(combine):
     """``combine`` [G, E] -> (the columns of the experts some row chose,
     moved to the front in expert order, as rows [E, G]; their ids [E]
@@ -377,8 +409,7 @@ def held_grouped_prefill(cfg, tokens: int) -> bool:
     shapes, said from the config: the engine counts such programs)."""
     return getattr(cfg, "held_experts", None) is not None \
         and tokens > DENSE_HELD_MAX_TOKENS and held_grouped_tiles(
-            cfg.dim, cfg.expert_dim if hasattr(cfg, "expert_dim")
-            else cfg.ffn_dim, cfg.dtype) is not None
+            cfg.dim, _expert_width(cfg), cfg.dtype) is not None
 
 
 def _held_sorted(xf, weights, idx, w1, w3, w2, lo: int, e: int, dt,
@@ -499,8 +530,9 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
     ``read`` [B,S] bool, where it differs from ``live``: the rows whose
     output anyone reads (a prefill group's padding rows repeat another
     row, are counted once and READ twice). At the few tokens of
-    `hit_experts_only` a row nobody reads chooses nothing: its expert
-    output is zero, and an expert only such rows chose is not read."""
+    `hit_experts_only` or `held_hit_kernel` a row nobody reads chooses
+    nothing: its expert output is zero, and an expert only such rows
+    chose is not read."""
     dt = cfg.dtype
     b, s, d = x.shape
     g, e, k = b * s, cfg.n_experts, cfg.top_k
@@ -520,7 +552,7 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
     lo, eh = (0, e) if held is None else (held[0], held[1] - held[0])
     dense = g <= (DENSE_EXPERTS_MAX_TOKENS if held is None
                   else DENSE_HELD_MAX_TOKENS)
-    hit_only = hit_experts_only(cfg, g)
+    hit_only = hit_experts_only(cfg, g) or held_hit_kernel(cfg, g)
     rows = np.int32(g * (eh if dense else k))
     first = 0 if expert_stack_layer is None else expert_stack_layer * eh
     if dense:
@@ -539,8 +571,9 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
                 cw, ids, n_hit = _compact_hit(combine)
         with jax.named_scope(sn.MOE_EXPERTS):
             if hit_only:
-                out = hit_experts_ffn(xf, cw, first + ids, n_hit,
-                                      w1, w3, w2).astype(dt)
+                out = hit_experts_ffn(
+                    xf, cw, first + ids, n_hit, w1, w3, w2,
+                    tf=hit_experts_tile(g, d, w1.shape[2], dt)).astype(dt)
                 rows = n_hit * np.int32(g)
             elif held is not None:
                 out = _held_hit(xf, combine, w1, w3, w2, first, eh, dt)

@@ -40,7 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import scope_names as sn
 
-__all__ = ["hit_experts_ffn", "hit_experts_ffn_reference"]
+__all__ = ["hit_experts_ffn", "hit_experts_ffn_reference",
+           "hit_experts_tile"]
 
 # Values of f a step: 3 x d x tf x 2 B = 6 MiB at d 2048, 12 double
 # buffered, inside the default scoped VMEM (asking for more takes room XLA
@@ -49,6 +50,9 @@ __all__ = ["hit_experts_ffn", "hit_experts_ffn_reference"]
 # 512 8.63 (90 % of the HBM peak; fewer, larger steps).
 _TF = 512
 _ROWS_ALIGN = 16          # a bf16 tile's sublanes
+# What a step may hold in VMEM: the default scoped 16 MiB, never more (as
+# `ops.held_grouped_ffn`'s budget: PERF.md PR 30).
+_VMEM_BUDGET = 16 * 2 ** 20
 
 
 def _kernel(ids_ref, nh_ref, x_ref, cw_ref, w1_ref, w3_ref, w2_ref, o_ref):
@@ -69,13 +73,33 @@ def _kernel(ids_ref, nh_ref, x_ref, cw_ref, w1_ref, w3_ref, w2_ref, o_ref):
                               preferred_element_type=jnp.float32)
 
 
-def _tile(f: int, want: int) -> int:
-    """The largest multiple of 128 that divides f and is <= want; f itself
-    where there is none."""
-    for t in range(min(want, f) // 128 * 128, 0, -128):
-        if f % t == 0:
-            return t
-    return f
+def _tiles(f: int, want: int) -> list:
+    """The multiples of 128 that divide f and are <= want, largest first;
+    f itself where there is none."""
+    return [t for t in range(min(want, f) // 128 * 128, 0, -128)
+            if f % t == 0] or [f]
+
+
+def hit_experts_tile(rows: int, d: int, f: int, dtype) -> Optional[int]:
+    """The values of f a step for ``rows`` rows and experts of ``d x f``:
+    the largest tile up to `_TF` whose step fits the budget, or None where
+    none does. All the rows and their float32 result are whole rows of d
+    and stay in VMEM beside three weight blocks, double-buffered: OLMoE's
+    and Qwen3-Next's 2,048 x 1,024 and x 512 take 512 at up to 128 rows
+    (one step an expert of the latter); DeepSeek-V3.2's 7,168 x 2,048
+    takes 128 (5.25 MiB a step, 16 an expert) at up to 64 rows and nothing
+    at 128. Counted so that what it names Mosaic compiles for a v5e
+    (`tests/test_tpu_compile.py`)."""
+    isz = jnp.dtype(dtype).itemsize
+    gp = rows + -rows % _ROWS_ALIGN
+    for tf in _tiles(f, _TF):
+        held = (2 * 3 * d * tf * isz          # weight blocks, two buffers
+                + 2 * gp * d * (isz + 4)      # rows in, result out
+                + 2 * gp * 128 * 4            # a weight a row, a lane used
+                + 3 * gp * tf * 4)            # gate, up, act
+        if held <= _VMEM_BUDGET:
+            return tf
+    return None
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tf"))
@@ -128,13 +152,15 @@ def _call(x, cw, ids, n_hit, w1, w3, w2, *, interpret: bool, tf: int):
 
 
 def hit_experts_ffn(x, cw, ids, n_hit, w1, w3, w2, *,
-                    interpret: Optional[bool] = None, tf: int = _TF):
+                    interpret: Optional[bool] = None,
+                    tf: Optional[int] = None):
     """See the module docstring. ``interpret=None`` resolves to True off
-    the TPU."""
+    the TPU; ``tf`` None is `_TF` (`hit_experts_tile` names the one that
+    fits)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _call(x, cw, ids, n_hit, w1, w3, w2, interpret=bool(interpret),
-                 tf=_tile(w1.shape[2], tf))
+                 tf=_tiles(w1.shape[2], tf or _TF)[0])
 
 
 def hit_experts_ffn_reference(x, cw, ids, n_hit, w1, w3, w2):

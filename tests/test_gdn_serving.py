@@ -514,16 +514,20 @@ def _kernel_names(jaxpr):
                 yield from _kernel_names(inner)
 
 
-@pytest.mark.parametrize("tokens,grouped", [(2, False), (64, False),
-                                            (256, False), (264, True)],
-                         ids=["decode_2", "decode_64", "chunk_256",
-                              "chunk_264"])
+@pytest.mark.parametrize("tokens,hit,grouped", [
+    (2, True, False), (64, True, False), (128, True, False),
+    (256, False, False), (264, False, True)],
+    ids=["decode_2", "decode_64", "chunk_128", "chunk_256", "chunk_264"])
 def test_only_a_chunk_past_the_all_experts_form_traces_the_grouped_kernel(
-        params, tokens, grouped):
+        params, tokens, hit, grouped):
     """A fused decode program multiplies at most `batch_slots` rows a
-    layer (64 in the cell): its expert layer is `_held_hit`, and no call
-    of `ops.held_grouped_ffn`'s kernel is traced into it; a prefill
-    program over `DENSE_HELD_MAX_TOKENS` tokens holds one a layer."""
+    layer (64 in the cell): its held experts go through the kernel of
+    `ops.hit_experts` (widths that fit: `moe.held_hit_kernel`), as any
+    program of at most `HIT_EXPERTS_MAX_TOKENS` tokens, and no call of
+    `ops.held_grouped_ffn`'s kernel is traced into it; up to
+    `DENSE_HELD_MAX_TOKENS` tokens the form is `_held_hit`'s `cond` an
+    expert, without a kernel; a prefill program over that many holds the
+    grouped kernel, one a layer."""
     from ray_tpu.models import moe
     from ray_tpu.ops import scope_names as sn
 
@@ -534,7 +538,39 @@ def test_only_a_chunk_past_the_all_experts_form_traces_the_grouped_kernel(
         x, layer, CFG, live=jnp.ones((1, tokens), bool)))(x)
     names = list(_kernel_names(jaxpr.jaxpr))
     assert any(sn.HELD_GROUPED_KERNEL in n for n in names) == grouped
+    assert any(sn.HIT_EXPERTS_KERNEL in n for n in names) == hit
+    assert len(names) == hit + grouped
     assert moe.held_grouped_prefill(CFG, tokens) == grouped
+    assert moe.held_hit_kernel(CFG, tokens) == hit
+
+
+@pytest.mark.parametrize("family", ["held", "no_held_range", "dense"])
+def test_decode_blocks_through_the_hit_kernel_are_counted(family):
+    """Every fused decode block of a config that holds a share of its
+    experts (at widths the kernel's step fits) is counted by
+    `moe_hit_kernel_decode_dispatches_total`; an `MoeConfig` with every
+    expert here takes the kernel by `hit_experts_only` and counts nothing,
+    as a dense `LlamaConfig`."""
+    from ray_tpu.models import (LlamaConfig, MoeConfig, llama_init, moe,
+                                moe_init)
+
+    cfg, init = {
+        "held": (nano_gdn(held_experts=(0, 4), n_layers=4), gdn_init),
+        "no_held_range": (MoeConfig.nano_moe(), moe_init),
+        "dense": (LlamaConfig.nano(), llama_init)}[family]
+    p = jax.jit(init, static_argnums=1)(jax.random.PRNGKey(1), cfg)
+    eng = DecodeEngine(p, cfg, batch_slots=2, max_len=64,
+                       kv_block_tokens=T, decode_horizon=2,
+                       preempt="recompute")
+    assert moe.held_hit_kernel(cfg, eng.B) == (family == "held")
+    ids = [eng.submit(prompt_of(n, seed=n), max_new_tokens=7)
+           for n in (5, 9, 6)]
+    out = eng.run()
+    assert all(len(out[i]) == 7 for i in ids)
+    st = eng.stats()
+    assert st["decode_dispatches"] > 3
+    assert st["moe_hit_kernel_decode_dispatches_total"] \
+        == (st["decode_dispatches"] if family == "held" else 0)
 
 
 @pytest.mark.parametrize("family", ["held", "no_held_range"])

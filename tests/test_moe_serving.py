@@ -213,7 +213,9 @@ def _routed_layer(routing, cfg, rows):
         lambda a: a[0], moe_init(jax.random.PRNGKey(21), cfg)["layers"])
     x = jax.random.normal(jax.random.PRNGKey(22), (rows, 1, cfg.dim))
     g = np.arange(rows)
-    if routing == "even":
+    if isinstance(routing, np.ndarray):     # the choices themselves
+        choice = routing
+    elif routing == "even":
         rng = np.random.RandomState(3)
         choice = np.stack([rng.permutation(e)[:2] for _ in g])
     elif routing == "collapsed":
@@ -284,6 +286,81 @@ def test_few_tokens_read_only_the_experts_a_live_row_chose(routing, live,
                                   len(chosen) * rows, len(chosen)]
         assert want_stats.tolist() == [int(mask.sum()) * cfg.top_k,
                                        cfg.n_experts * rows, len(chosen)]
+
+
+@pytest.mark.parametrize("live", ["none", "one", "8_of_32", "all", "no_mask"])
+@pytest.mark.parametrize("routing", ["even", "collapsed", "every_held",
+                                     "none_held"])
+def test_few_tokens_over_a_held_range_go_through_the_hit_kernel(
+        routing, live, monkeypatch):
+    """32 rows of a layer that HOLDS experts 4..11 of 16, the third layer
+    of stacks that hold three (``first`` = 16): the kernel (interpret
+    mode) is handed exactly the held experts some LIVE row chose, as their
+    rows in the stacks, and equals its reference and the `cond` form
+    (`_held_hit`) on the live rows; a dead row's expert output is zero and
+    its input changes no live row's; the counters are the `cond` form's
+    over live rows (that form also reads an expert only a dead row
+    chose)."""
+    from ray_tpu.ops import hit_experts
+
+    rows, lo, eh, li = 32, 4, 8, 2
+    cfg = _cfg(n_experts=16, held_experts=(lo, lo + eh))
+    g = np.arange(rows)
+    away = np.asarray([0, 1, 2, 3, 12, 13, 14, 15])
+    layer, x, choice = _routed_layer(
+        {"even": "even",
+         "collapsed": np.tile([7, 3], (rows, 1)),           # 3 is not held
+         "every_held": np.stack([lo + g % eh, away[g % 8]], 1),
+         "none_held": np.tile([0, 13], (rows, 1))}[routing], cfg, rows)
+    for i, n in enumerate(("we_gate", "we_up", "we_down")):
+        layer[n] = 0.1 * jax.random.normal(
+            jax.random.PRNGKey(30 + i), (3 * eh,) + layer[n].shape[1:])
+    mask = {"none": np.zeros(rows, bool), "one": g == 5, "8_of_32": g < 8,
+            "all": np.ones(rows, bool), "no_mask": np.ones(rows, bool)}[live]
+    lv = None if live == "no_mask" else jnp.asarray(mask)[:, None]
+    seen = {}
+
+    def spy(x, cw, ids, n_hit, *stacks, **kw):
+        seen.update(ids=np.asarray(ids), n=int(n_hit), cw=np.asarray(cw))
+        got = hit_experts.hit_experts_ffn(x, cw, ids, n_hit, *stacks, **kw)
+        np.testing.assert_allclose(
+            got, hit_experts.hit_experts_ffn_reference(
+                x, cw, ids, n_hit, *stacks), atol=1e-5, rtol=0)
+        return got
+
+    def run(x):
+        return moe.moe_ffn_dropless(x, layer, cfg, lv,
+                                    expert_stack_layer=li)
+
+    monkeypatch.setattr(moe, "hit_experts_ffn", spy)
+    assert moe.held_hit_kernel(cfg, rows) \
+        and not moe.hit_experts_only(cfg, rows)
+    got, stats = run(x)
+    junk, _ = run(jnp.where(mask[:, None, None], x, 1e3 * x[::-1]))
+    monkeypatch.setattr(moe, "HIT_EXPERTS_MAX_TOKENS", 0)
+    assert not moe.held_hit_kernel(cfg, rows)
+    want, want_stats = run(x)
+
+    def held(c):
+        return sorted({e for e in c.reshape(-1).tolist()
+                       if lo <= e < lo + eh})
+
+    chosen = held(choice[mask])
+    assert seen["ids"][:seen["n"]].tolist() == \
+        [li * eh + e - lo for e in chosen]
+    assert not seen["cw"][seen["n"]:].any()
+    np.testing.assert_allclose(got[mask], want[mask], atol=1e-5, rtol=0)
+    np.testing.assert_array_equal(junk[mask], got[mask])
+    assert not np.asarray(got)[~mask].any()
+    if lv is None:
+        assert stats is None and want_stats is None
+    else:
+        landed = int(np.isin(choice[mask], chosen).sum())
+        assert stats.tolist() == [int(mask.sum()) * cfg.top_k,
+                                  len(chosen) * rows, len(chosen), landed]
+        assert want_stats.tolist() == [
+            int(mask.sum()) * cfg.top_k, len(held(choice)) * rows,
+            len(chosen), landed]
 
 
 def test_a_prefill_groups_padding_row_is_read_though_counted_once():

@@ -682,11 +682,14 @@ def test_mla_cell_programs_fit_the_chip(v5e, program):
     # chunk's keys under its mask
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    # the held experts' matmuls stay XLA's at 7,168 x 2,048 an expert
+    # a chunk's held experts stay XLA's at 7,168 x 2,048 an expert
     # (`held_grouped_tiles`): no program of this cell holds the grouped
-    # kernel
-    from ray_tpu.ops.scope_names import HELD_GROUPED_KERNEL
+    # kernel; the decode program's 24 rows read theirs through the
+    # hit-experts kernel at an f-tile of 128 (`moe.held_hit_kernel`)
+    from ray_tpu.ops.scope_names import (HELD_GROUPED_KERNEL,
+                                         HIT_EXPERTS_KERNEL)
     assert HELD_GROUPED_KERNEL not in text
+    assert (HIT_EXPERTS_KERNEL in text) == (program == "decode")
 
 
 # -- the delta-rule cell's programs ----------------------------------------------
@@ -754,9 +757,13 @@ def test_gdn_cell_programs_fit_the_chip(v5e, program):
     assert text.count("tpu_custom_call") >= 1
     # the held experts' matmuls: the grouped kernel in a 4 x 512 chunk
     # (one call a layer body: the delta period's and the attention
-    # layer's), `_held_hit`'s conds in the decode program's 64 rows
-    from ray_tpu.ops.scope_names import HELD_GROUPED_KERNEL
+    # layer's), the hit-experts kernel over the held range in the decode
+    # program's 64 rows (`moe.held_hit_kernel`: one step an expert at
+    # 2,048 x 512), and neither holds the other's
+    from ray_tpu.ops.scope_names import (HELD_GROUPED_KERNEL,
+                                         HIT_EXPERTS_KERNEL)
     assert (HELD_GROUPED_KERNEL in text) == (program == "prefill")
+    assert (HIT_EXPERTS_KERNEL in text) == (program == "decode")
     assert "ragged-dot" not in text and "ragged_dot" not in text
 
 
@@ -804,6 +811,54 @@ def test_held_grouped_kernel_at_the_held_cells_prefill_shapes(v5e, case):
         assert tiles is None
         with pytest.raises(Exception, match="(?i)vmem"):
             jax.jit(functools.partial(fn, tf=128)).lower(*args).compile()
+
+
+# -- the hit-experts kernel over a held range ----------------------------------------
+
+# rows, d, f, groups in the stacks, the f-tile `hit_experts_tile` names
+_HELD_DECODE = {
+    "qwen3next_decode_64": (64, 2048, 512, 1024, 512),
+    "qwen3next_chunk_128": (128, 2048, 512, 1024, 512),
+    "dsv32_decode_24": (24, 7168, 2048, 64, 128),
+    "dsv32_decode_64": (64, 7168, 2048, 64, 128),
+    "dsv32_chunk_128": (128, 7168, 2048, 64, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_HELD_DECODE))
+def test_hit_experts_kernel_at_the_held_cells_decode_shapes(v5e, case):
+    """The decode step's held experts at the two held cells' shapes:
+    Qwen3-Next's (2,048 x 512) take one step an expert, DeepSeek-V3.2's
+    (7,168 x 2,048) sixteen of 128 values of f, both inside the default
+    scoped VMEM (no `vmem_limit_bytes`: PERF.md PR 30) with the tile
+    `hit_experts_tile` names from the rows and the widths; where it names
+    none (128 rows of d 7,168 and their float32 result beside three weight
+    blocks) Mosaic refuses the narrowest tile, and the next wider one than
+    it names where it names one."""
+    from ray_tpu.ops import hit_experts as he
+
+    g, d, f, n, want = _HELD_DECODE[case]
+    bf = jnp.bfloat16
+    eh = n // 8 if d == 2048 else n // 4
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    args = (arg((g, d), bf), arg((eh, g), jnp.float32), arg((eh,)), arg(()),
+            arg((n, d, f), bf), arg((n, d, f), bf), arg((n, f, d), bf))
+
+    def lowered(tf):
+        return jax.jit(functools.partial(
+            he.hit_experts_ffn, interpret=False, tf=tf)).lower(*args)
+
+    tf = he.hit_experts_tile(g, d, f, bf)
+    assert tf == want
+    if tf is not None:
+        text = lowered(tf).compile().as_text()
+        assert "tpu_custom_call" in text and "vmem_limit" not in text
+    if tf != f:     # a wider step than the one it names does not fit
+        with pytest.raises(Exception, match="(?i)vmem"):
+            lowered(128 if tf is None else 2 * tf).compile()
 
 
 # -- the train cell's step -------------------------------------------------------

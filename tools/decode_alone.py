@@ -7,15 +7,18 @@ cost the program around it, and a traced benchmark run's
         [--live N[,N...]] [--len L[,L...]] [--rehearse]
 
 builds the engine of a serving cell of driver `serve_engine`,
-`serve_model` or `serve_hybrid` (default `mistral7b-rollout`) from THAT
-tree (run it from the tree's root), brings N rows of L tokens into decode (N: all the slots by
-default; the other slots stay dead) for every N and L, then calls
-`_decode_multi_paged` (horizon 8) 3 x 40 times on the SAME row state and
-times it on the device's queue (async dispatch, one wait at the end).
+`serve_model`, `serve_hybrid`, `serve_gdn` or `serve_mla` (default
+`mistral7b-rollout`) from THAT tree (run it from the tree's root), brings
+N rows of L tokens into decode (N: all the slots by default; the other
+slots stay dead) for every N and L, then calls `_decode_multi_paged` (the
+cell's `decode_horizon`, 8 where it names none) 3 x 40 times on the SAME
+row state and times it on the device's queue (async dispatch, one wait at
+the end).
 Prints `DECODE_AB {json}`: ms a token and, for an expert-layer model, the
 experts hit a layer-step. On the chip: parent, change, change, parent in
 one `chiprun` call; `--rehearse` runs the cell's rehearsal size on the CPU."""
 import argparse
+import importlib
 import json
 import sys
 import time
@@ -35,7 +38,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from benchmark.harness import common, spec  # noqa: E402
-from benchmark.harness.drivers import serve_hybrid, serve_model  # noqa: E402
+from benchmark.harness.drivers import serve_model  # noqa: E402
 from benchmark.harness.model import llama_config  # noqa: E402
 from ray_tpu.models import engine as E  # noqa: E402
 from ray_tpu.models import llama_init  # noqa: E402
@@ -48,9 +51,12 @@ if a.rehearse:
     model.update(cell.config["rehearsal"]["model"])
     opts.update(cell.config["rehearsal"]["engine"])
 opts.pop("warm_groups")
-hybrid = cell.config.get("driver") == "serve_hybrid"
-if hybrid:
-    cfg, init, _ = serve_hybrid.program_config(model, opts["max_len"])
+driver = cell.config.get("driver")
+own = driver in ("serve_hybrid", "serve_gdn", "serve_mla")
+if own:     # a family that brings its own stack, and its own driver
+    cfg, init, _ = importlib.import_module(
+        "benchmark.harness.drivers." + driver).program_config(
+        model, opts["max_len"])
 elif model.get("model_type") in serve_model.FAMILIES:
     cfg, init, _ = serve_model.program_config(model, opts["max_len"])
 else:
@@ -58,18 +64,19 @@ else:
                              activation_dtype=model["torch_dtype"],
                              param_dtype=model["torch_dtype"],
                              remat=False), llama_init
-# an `rbg` key as the hybrid driver draws its weights with (threefry
-# compiles 40 s longer for that model's initialiser, PERF.md PR 31)
+# an `rbg` key as those drivers draw their weights with (threefry
+# compiles 40 s longer for the hybrid model's initialiser, PERF.md PR 31)
 key = jax.random.wrap_key_data(
     jnp.tile(jax.random.key_data(common.seed_key(7)), 2), impl="rbg") \
-    if hybrid else common.seed_key(7)
+    if own else common.seed_key(7)
 params = jax.jit(init, static_argnums=1)(key, cfg)
 jax.block_until_ready(params)
-H, CALLS = 8, (2 if a.rehearse else 40)
+H, CALLS = opts.get("decode_horizon", 8), (2 if a.rehearse else 40)
 lens = [int(x) for x in a.len.split(",") if x] or (
     [16, 24] if a.rehearse else [512, 1024])
 lives = [int(x) for x in a.live.split(",") if x] or [opts["batch_slots"]]
-out = {"tag": a.tag, "cell": a.cell, "device": str(jax.devices()[0])}
+out = {"tag": a.tag, "cell": a.cell, "device": str(jax.devices()[0]),
+       "horizon": H}
 for L in lens:
     for n_live in lives:
         eng = E.DecodeEngine(params, cfg, **opts)
