@@ -21,7 +21,7 @@ out by ``alloc``.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Protocol, Tuple
 
 
 class CachePlane(NamedTuple):
@@ -36,6 +36,7 @@ class CachePlane(NamedTuple):
     layers: int      # layers that write this plane
     lanes: int       # values a token stores in one of them, as laid out
     dtype: Any
+    heads: int = 1   # KV heads the lanes hold: a quantized block's scales
 
     def block_bytes(self, block_tokens: int,
                     itemsize: Optional[int] = None) -> int:
@@ -50,8 +51,8 @@ def kv_planes(table: str, layers: int, kv_heads: int, head_dim: int,
     ``kv_heads`` heads of ``head_dim`` a token, head-major in one lane
     axis."""
     lanes = kv_heads * head_dim
-    return (CachePlane(prefix + "k", table, layers, lanes, dtype),
-            CachePlane(prefix + "v", table, layers, lanes, dtype))
+    return (CachePlane(prefix + "k", table, layers, lanes, dtype, kv_heads),
+            CachePlane(prefix + "v", table, layers, lanes, dtype, kv_heads))
 
 
 class StatePlane(NamedTuple):
@@ -61,7 +62,7 @@ class StatePlane(NamedTuple):
     A config answers `state_planes()` with these, and the engine keeps
     ``[layers, slots, *shape]`` of each, zeroed, donated through every
     program and indexed by engine slot; a family without recurrent state
-    has no such method."""
+    answers ``()``."""
 
     name: str        # "ssm" | "conv" | "delta"
     layers: int      # layers that own this plane
@@ -82,6 +83,59 @@ def zero_state_planes(planes, slots: int) -> Dict[str, Any]:
 
     return {pl.name: jnp.zeros((pl.layers, slots, *pl.shape), pl.dtype)
             for pl in planes}
+
+
+class ServedConfig(Protocol):
+    """What `DecodeEngine` and solo `generate` ask a model family, and all
+    they know of it (`docs/serving.md`, "Adding a family"). A declaration:
+    nothing inherits it or is checked against it at run time."""
+
+    def cache_planes(self) -> Tuple[CachePlane, ...]:
+        """Two planes of table "full" (the pool behind a row's table) and
+        none or two of table "window": a second pool and table, kept in
+        the stack's state under the planes' names, of which a row holds
+        its last `sliding_window` slots (the config's, as is
+        `n_window_layers`)."""
+
+    def state_planes(self) -> Tuple[StatePlane, ...]:
+        """What a row keeps whatever its length; ``()`` for none."""
+
+    def refusals(self) -> Dict[str, str]:
+        """The engine options it cannot be served with and the sentence
+        each raises, in the order they are checked: keys among
+        "prefix_cache", "preempt_swap", "draft", "kv_quant", "lora", "tp"
+        and "handoff" (its sentence keeps ``{}`` for the refused call)."""
+
+    def stack(self):
+        """The module of its own layers, or None for `generate._layer_body`'s,
+        which the engine scans itself: `lm_head`, solo `generate`'s
+        `init_cache` and `forward_cached`, and `layers_paged(params, toks,
+        pool_a, pool_b, bt, starts, cfg, *, ...)`, all rows of ``toks``
+        [B, S] at slots ``starts + arange(S)`` against the two "full"
+        pools through ``bt``, each family ignoring what it has no use for:
+
+          state     {name: array} of its window and state planes, or None
+          bt_w      the rows' window table
+          live      [B, S] bool: the positions that advance recurrent state
+                    (a prefix of each row)
+          rows      [B] the engine slot of each row (prefill's admission
+                    group; None: row b is slot b, decode). A row with
+                    ``starts == 0`` begins from ZERO state, whatever its
+                    slot holds: that is how a slot is reset at admission
+          n_valid   [B] real tokens of a prefill chunk (None: all S)
+          last_idx  [B] the position whose hidden state is wanted
+                    (prefill); None: every position (decode)
+          final     False: a chunk that is not a prompt's last: stop after
+                    `prefill_layers` and return no hidden state
+          moe_live  [B, S] bool or None: the positions the expert layers'
+                    counters count (None: none are traced)
+
+        -> (h [B, S, d] or [B, 1, d] with ``last_idx``, pool_a, pool_b,
+        expert-layer counts or None, state or None)."""
+
+    def prefill_layers(self) -> int:
+        """Layers that see every prompt token: fewer than `n_layers` where
+        a chunk that is not a prompt's last stops early."""
 
 
 class BlockPool:

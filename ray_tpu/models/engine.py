@@ -94,16 +94,9 @@ from ray_tpu.models.adapter_pool import AdapterPool
 from ray_tpu.models.block_pool import BlockPool, zero_state_planes
 from ray_tpu.models.engine_metrics import EngineMetrics, NullEngineMetrics
 from ray_tpu.models.engine_trace import resolve_tracer
-from ray_tpu.models import hybrid as _hybrid
 from ray_tpu.models.generate import (_check_sampling_knobs, _expert_stacks,
-                                     _layer_body, sample_rows)
-from ray_tpu.models.hybrid import HybridConfig
-from ray_tpu.models import mla as _mla
-from ray_tpu.models.mla import MlaConfig
-from ray_tpu.models import gdn as _gdn
-from ray_tpu.models.gdn import GdnConfig
-from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
-                                  llama_param_specs)
+                                     _layer_body, lm_head, sample_rows)
+from ray_tpu.models.llama import LlamaConfig, llama_param_specs
 from ray_tpu.models.moe import (MoeConfig, held_grouped_prefill,
                                 held_hit_kernel)
 from ray_tpu.models.prefix_cache import PrefixCacheIndex
@@ -385,9 +378,8 @@ def _gather_pages(pools, ids):
     return tuple(out.reshape(L, *ids.shape, T, W) for out in outs)
 
 
-def _zero_pools(planes, n_blocks: int, block_tokens: int, kv_heads: int,
-                dtype, quantized: bool,
-                shardings: Optional[_EngineShardings]):
+def _zero_pools(planes, n_blocks: int, block_tokens: int, dtype,
+                quantized: bool, shardings: Optional[_EngineShardings]):
     """The zeroed (pool_k, pool_v, scale_k, scale_v) of the two
     `CachePlane`s behind one table: pools [L, NB, T, lanes] — K and V
     ``KV*D`` wide, the layout the decode kernel reads, one page one
@@ -403,7 +395,7 @@ def _zero_pools(planes, n_blocks: int, block_tokens: int, kv_heads: int,
     out = [jnp.zeros((pl.layers, n_blocks, block_tokens, pl.lanes), dtype)
            for pl in planes]
     if quantized:
-        out += [jnp.zeros((pl.layers, n_blocks, kv_heads), jnp.float32)
+        out += [jnp.zeros((pl.layers, n_blocks, pl.heads), jnp.float32)
                 for pl in planes]
     else:
         out += [None, None]
@@ -489,17 +481,16 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
     saw in the dense view this program used to build: rounding reaches
     a token only through what it reads back from the pool. That takes
     the pure-lax lowering on the chip too (one layer's rows at a time);
-    the kernel has no operand for the chunk's own K/V (ROADMAP S11).
+    the kernel has no operand for the chunk's own K/V (ROADMAP S17).
 
-    A `HybridConfig` brings its own device state ``hyb`` (window pools
-    and recurrent state, donated like the pool) and the rows' window
-    table ``bt_w``; ``rows`` then also says which slot's recurrent state
-    a chunk continues (zero where ``starts`` is 0) and stores. Its
-    chunks that are not a prompt's last (static ``final`` False) stop
-    after the layers that see every token and leave `last_logits` as
-    it is; a last chunk's cross-decoder layers and head run for the
-    row's last real position alone (`hybrid.layers_paged`). For the
-    other families ``hyb`` is None and adds no leaf."""
+    ``hyb`` is a family's own device state (window pools and recurrent
+    state, donated like the pool; None adds no leaf) and ``bt_w`` the
+    rows' window table; ``rows`` then also says which slot's recurrent
+    state a chunk continues (zero where ``starts`` is 0) and stores.
+    Where `prefill_layers` is not every layer, a chunk that is not a
+    prompt's last (static ``final`` False) stops after those and leaves
+    `last_logits` as it is; a last chunk's other layers and head run for
+    the row's last real position alone (`hybrid.layers_paged`)."""
     n, s = prompts.shape
     h, pool_k, pool_v, scale_k, scale_v, moe_stats, hyb = _layers_paged(
         params, prompts, pool_k, pool_v, bt, starts, cfg,
@@ -514,7 +505,7 @@ def _prefill_rows_paged(params: Params, prompts: jax.Array, pool_k,
         moe_ctr = moe_ctr.at[:2].add(seen[:2])
         if seen.shape[0] > 3:         # held experts: what landed here
             moe_ctr = moe_ctr.at[_MOE_CTR_ROWS:].add(seen[3:])
-    if _own_stack(cfg) is None:
+    if cfg.stack() is None:
         h = h[jnp.arange(n), last_idx][:, None]
     if final:
         # the final norm and lm_head see the ONE position a row is read at
@@ -611,21 +602,6 @@ def _decode_layer_rows_paged(h, layer, li, kc, vc, bt, slots,
                        moe_read=real)
 
 
-def _own_stack(cfg):
-    """The module of a family whose layers are not `generate._layer_body`'s
-    and that brings its own stack (`layers_paged`, `lm_head`) against the
-    engine's pools: `hybrid` for a `HybridConfig`, `mla` for an
-    `MlaConfig`, `gdn` for a `GdnConfig`; None for the dense and sparse
-    families."""
-    if isinstance(cfg, HybridConfig):
-        return _hybrid
-    if isinstance(cfg, MlaConfig):
-        return _mla
-    if isinstance(cfg, GdnConfig):
-        return _gdn
-    return None
-
-
 def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
                   bt, starts, cfg: LlamaConfig, adapters=None,
                   row_slot=None, scale_k=None, scale_v=None,
@@ -651,37 +627,21 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
     `_decode_multi_paged`'s scan can inline it and keep the pool in its
     own carry.
 
-    A `HybridConfig`'s layers are not of one kind: its stack is
-    `hybrid.layers_paged`, per-period scans built from the config's
-    `layer_plan`, over this pool (its full-attention layer's), its own
-    state ``hyb`` and the window table ``bt_w``; the seventh result is
-    that state (None for the other families, whose scan is below). An
-    `MlaConfig`'s is `mla.layers_paged`, two scans from its `layer_plan`
-    over these two pools as its latent and index planes; it has no other
-    state and counts its expert layers like an `MoeConfig`. A
-    `GdnConfig`'s is `gdn.layers_paged`, one scan over its periods, over
-    this pool (its attention layers') and its recurrent state ``hyb``
-    alone (no window pool, no ``bt_w``); it counts its expert layers
-    too."""
-    if isinstance(cfg, MlaConfig):
-        h, pool_k, pool_v, moe_stats = _mla.layers_paged(
-            params, toks, pool_k, pool_v, bt, starts, cfg,
-            moe_live=moe_live, n_valid=n_valid, last_idx=last_idx)
-        return h, pool_k, pool_v, scale_k, scale_v, moe_stats, None
-    if hyb is not None and live is None:
-        live = jnp.arange(toks.shape[1])[None, :] < n_valid[:, None]
-    if isinstance(cfg, GdnConfig):
-        h, pool_k, pool_v, hyb, moe_stats = _gdn.layers_paged(
-            params, toks, pool_k, pool_v, bt, starts, cfg, hyb, live=live,
-            rows=rows, n_valid=n_valid, last_idx=last_idx,
-            moe_live=moe_live)
+    A family whose layers are not `generate._layer_body`'s runs its own
+    stack (`block_pool.ServedConfig.stack`) over the same two pools, its
+    state ``hyb`` (the seventh result; None where it has none) and the
+    window table ``bt_w``, and is handed the rest as it came; the scan
+    below is the dense and sparse families'."""
+    own = cfg.stack()
+    if own is not None:
+        if hyb is not None and live is None:
+            # a chunk's real tokens advance recurrent state, no filler
+            live = jnp.arange(toks.shape[1])[None, :] < n_valid[:, None]
+        h, pool_k, pool_v, moe_stats, hyb = own.layers_paged(
+            params, toks, pool_k, pool_v, bt, starts, cfg, state=hyb,
+            bt_w=bt_w, live=live, rows=rows, n_valid=n_valid,
+            last_idx=last_idx, final=final, moe_live=moe_live)
         return h, pool_k, pool_v, scale_k, scale_v, moe_stats, hyb
-    if hyb is not None:
-        h, pool_k, pool_v, hyb = _hybrid.layers_paged(
-            params, toks, pool_k, pool_v, bt, starts, cfg, hyb, bt_w,
-            live=live, rows=rows, n_valid=n_valid, last_idx=last_idx,
-            final=final)
-        return h, pool_k, pool_v, scale_k, scale_v, None, hyb
     S = toks.shape[1]
     slots = starts[:, None] + jnp.arange(S)[None, :]
     with jax.named_scope(sn.EMBED):
@@ -710,14 +670,8 @@ def _layers_paged(params: Params, toks: jax.Array, pool_k, pool_v,
 
 def _lm_head(params: Params, h: jax.Array, cfg: LlamaConfig):
     """Final norm and vocab projection: [B, S, d] -> f32 [B, S, vocab]."""
-    own = _own_stack(cfg)
-    if own is not None:
-        return own.lm_head(params, h, cfg)
-    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope(sn.LM_HEAD):
-        return jnp.einsum("bsd,dv->bsv", h,
-                          params["lm_head"].astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+    own = cfg.stack()
+    return (lm_head if own is None else own.lm_head)(params, h, cfg)
 
 
 def _decode_core_paged(params: Params, toks: jax.Array, pool_k, pool_v,
@@ -1299,7 +1253,7 @@ class DecodeEngine:
         if paged is not True:
             # The benchmark's configuration files still pass
             # `"paged": true`; the keyword goes when they drop it
-            # (ROADMAP D1).
+            # (ROADMAP D16).
             raise ValueError(
                 "paged=False: the dense KV path was removed in PR 29 — "
                 "the block pool is the engine's one KV path; drop the "
@@ -1336,14 +1290,20 @@ class DecodeEngine:
                     "speculative decoding is follow-up work)")
             if max_live_adapters < 1:
                 raise ValueError("max_live_adapters must be >= 1")
-        # A sparse model (`MoeConfig`) is served by the same programs;
-        # what reads the DENSE feed-forward's names is refused here.
+        # What a family cannot be served with is its config's answer
+        # (`refusals`), raised in the order the family names it.
+        asked = {"prefix_cache": prefix_cache,
+                 "preempt_swap": preempt == "swap",
+                 "draft": draft_params is not None or draft_cfg is not None,
+                 "kv_quant": kv_quant is not None,
+                 "lora": lora is not None,
+                 "tp": tp is not None or mesh is not None}
+        for option, why in cfg.refusals().items():
+            if asked.get(option):
+                raise ValueError(why)
+        # A sparse model's two refusals that read an option's VALUE: LoRA
+        # targets of the DENSE feed-forward, a draft of the other family.
         sparse = isinstance(cfg, MoeConfig)
-        if sparse and (tp is not None or mesh is not None):
-            raise ValueError(
-                "tp=/mesh= cannot serve an MoeConfig: the serving "
-                "sharding rules split the dense 'mlp' width, and the "
-                "expert stacks have no rule yet")
         if sparse and lora is not None:
             ffn = sorted(set(lora.targets) & {"w_gate", "w_up", "w_down"})
             if ffn:
@@ -1351,91 +1311,6 @@ class DecodeEngine:
                     f"lora= targets {ffn} name the dense feed-forward, "
                     "which an MoeConfig does not have (attention "
                     "targets wq/wk/wv/wo are served)")
-        # A `HybridConfig` (state-space, window, full and shared-cache
-        # layers in one stack) is served by the same programs and step
-        # loop; what would need its recurrent state or its window pool
-        # moved, shared or split is refused here, by name.
-        hybrid = isinstance(cfg, HybridConfig)
-        if hybrid:
-            for bad, what in (
-                    (prefix_cache, "prefix_cache=True: a prefix hit "
-                     "needs a snapshot of the recurrent state at the "
-                     "block boundary it resumes from, and none is kept"),
-                    (preempt == "swap", "preempt='swap': the swap ledger "
-                     "carries K/V blocks only, not a row's recurrent "
-                     "state or its window blocks (pass "
-                     "preempt='recompute')"),
-                    (draft_params is not None or draft_cfg is not None,
-                     "draft_params=/draft_cfg=: a rejected draft token "
-                     "has already advanced the recurrent state, and "
-                     "there is no roll-back of it"),
-                    (kv_quant is not None, "kv_quant=: the window pool "
-                     "and the pair layout differential attention reads "
-                     "have no quantized write"),
-                    (lora is not None, "lora=: the adapter targets name "
-                     "the dense family's projections"),
-                    (tp is not None or mesh is not None, "tp=/mesh=: "
-                     "the state-space and memory-unit weights and the "
-                     "recurrent state have no sharding rule")):
-                if bad:
-                    raise ValueError(
-                        f"a HybridConfig cannot be served with {what}")
-        # An `MlaConfig` (latent attention with selection, held experts)
-        # likewise: what would need its two planes shared, quantized,
-        # moved or split is refused here, by name.
-        if isinstance(cfg, MlaConfig):
-            for bad, what in (
-                    (prefix_cache, "prefix_cache=True: the trie's copy-on-"
-                     "write and eviction know K and V planes of one width, "
-                     "not a latent and an index plane (ROADMAP M3)"),
-                    (kv_quant is not None, "kv_quant=: the latent and index "
-                     "planes have no quantized write, and a quantized "
-                     "indexer key changes what is selected (ROADMAP M3)"),
-                    (preempt == "swap", "preempt='swap': the swap ledger "
-                     "gathers and scatters K and V planes of one width "
-                     "(pass preempt='recompute'; ROADMAP M3)"),
-                    (tp is not None or mesh is not None, "tp=/mesh=: the "
-                     "latent is ONE head's cache and the held experts have "
-                     "no exchange: neither has a sharding rule (ROADMAP "
-                     "M2)"),
-                    (lora is not None, "lora=: the adapter targets name "
-                     "the dense family's projections"),
-                    (draft_params is not None or draft_cfg is not None,
-                     "draft_params=/draft_cfg=: the verify window has no "
-                     "selection per drafted token, and the model's own "
-                     "drafting head is not built (ROADMAP M7)")):
-                if bad:
-                    raise ValueError(
-                        f"an MlaConfig cannot be served with {what}")
-        # A `GdnConfig` (delta-rule layers with a matrix state a row,
-        # gated attention, held experts) likewise.
-        if isinstance(cfg, GdnConfig):
-            for bad, what in (
-                    (prefix_cache, "prefix_cache=True: a prefix hit needs "
-                     "a snapshot of the recurrent state at the block "
-                     "boundary it resumes from, and none is kept (ROADMAP "
-                     "M4)"),
-                    (preempt == "swap", "preempt='swap': the swap ledger "
-                     "carries K/V blocks only, not a row's recurrent state "
-                     "(pass preempt='recompute'; ROADMAP M4)"),
-                    (draft_params is not None or draft_cfg is not None,
-                     "draft_params=/draft_cfg=: a rejected draft token has "
-                     "already advanced the recurrent state, and there is "
-                     "no roll-back of it; the model's own drafting head is "
-                     "not built (ROADMAP M7)"),
-                    (kv_quant is not None, "kv_quant=: the quantized "
-                     "write's scales are sized from the dense family's "
-                     "layers, and the recurrent state has no quantized "
-                     "form"),
-                    (lora is not None, "lora=: the adapter targets name "
-                     "the dense family's projections"),
-                    (tp is not None or mesh is not None, "tp=/mesh=: the "
-                     "delta-rule weights, the recurrent state and the held "
-                     "experts have no sharding rule and no exchange "
-                     "(ROADMAP M2)")):
-                if bad:
-                    raise ValueError(
-                        f"a GdnConfig cannot be served with {what}")
         if draft_cfg is not None and \
                 isinstance(draft_cfg, MoeConfig) != sparse:
             raise ValueError(
@@ -1731,16 +1606,15 @@ class DecodeEngine:
         # blocks instead of copying them. `kv_pool_bytes` sizes it
         # (default: room for two full batches of max_len tokens) plus
         # the reserved null block 0.
-        # (A `HybridConfig` keeps ONE layer in this pool, its
-        # full-attention layer's, which its cross-attention layers read
-        # too; its window layers' pool and its recurrent state follow
-        # below.)
         # What a token stores is the config's answer (`cache_planes`):
-        # the two planes behind the row's table are this pool's two
-        # arrays (K and V; an `MlaConfig`'s latent and index planes, of
-        # unlike widths and with no V), and a block's bytes are theirs.
+        # the two planes of table "full" are this pool's two arrays (K
+        # and V; an `MlaConfig`'s latent and index planes, of unlike
+        # widths and with no V), and a block's bytes are theirs. Planes
+        # of table "window" get a pool of their own below.
         self._planes = tuple(pl for pl in cfg.cache_planes()
                              if pl.table == "full")
+        planes_w = tuple(pl for pl in cfg.cache_planes()
+                         if pl.table == "window")
         T = kv_block_tokens
         if self.kv_quant_spec is not None:
             # Quantized pool: 1-byte values + the per-block scale
@@ -1748,7 +1622,7 @@ class DecodeEngine:
             # block) — the ~2x concurrency-per-HBM-byte lever.
             pool_dtype = self.kv_quant_spec.dtype
             bb = sum(pl.block_bytes(T, self.kv_quant_spec.itemsize)
-                     + pl.layers * cfg.n_kv_heads * 4
+                     + pl.layers * pl.heads * 4
                      for pl in self._planes)
         else:
             pool_dtype = jnp.dtype(cfg.dtype)
@@ -1768,24 +1642,25 @@ class DecodeEngine:
         self._row_admit_seq = np.zeros((self.B,), np.int64)
         (self._pool_k, self._pool_v, self._scale_k,
          self._scale_v) = _zero_pools(
-            self._planes, n_blocks, T, getattr(cfg, "n_kv_heads", 1),
-            pool_dtype, self.kv_quant_spec is not None,
-            shardings=self._shardings)
+            self._planes, n_blocks, T, pool_dtype,
+            self.kv_quant_spec is not None, shardings=self._shardings)
         # A family's other device state ``_hyb``, donated through every
         # program beside the pool: the RECURRENT state of every slot,
         # which is the config's answer as the pools are (`state_planes`:
         # a `HybridConfig`'s scan and conv state, a `GdnConfig`'s matrix
-        # and conv state), zeroed for a row by its first chunk; and, for
-        # a `HybridConfig` alone, the WINDOW plane (a second `BlockPool`
-        # and table over a pool of its own geometry, `n_window_layers`
-        # deep; a row holds only the blocks that intersect its last
+        # and conv state), zeroed for a row by its first chunk; and the
+        # WINDOW planes where the config names any (a second `BlockPool`
+        # and table over pools of their own geometry, under the planes'
+        # names; a row holds only the blocks that intersect its last
         # `sliding_window` slots plus what it is about to write,
-        # `_window_release`). None for the other families, which pass no
-        # leaf of it to any program.
-        self._hybrid = hybrid
-        self._state_planes = tuple(
-            getattr(cfg, "state_planes", lambda: ())())
-        self._hyb: Optional[Params] = None
+        # `_window_release`). None for a family with neither, which
+        # passes no leaf of it to any program.
+        self._state_planes = cfg.state_planes()
+        # a chunk that is not a prompt's last stops after these layers
+        self._prefill_stops_early = cfg.prefill_layers() < cfg.n_layers
+        # an "index" plane: attention reads `index_topk` selected tokens
+        self._selects = any(pl.name == "index" for pl in self._planes)
+        hyb = zero_state_planes(self._state_planes, self.B)
         self.kv_pool_w: Optional[BlockPool] = None
         self.indexer_tokens_scored_total = 0   # token-layers, decode and
         self.indexer_tokens_selected_total = 0  # prefill (an MlaConfig)
@@ -1800,7 +1675,7 @@ class DecodeEngine:
         self.ssm_row_steps_total = 0           # live rows x decode tokens
         self.prefill_layer_tokens_total = 0    # token-layers of a dense stack
         self.prefill_layer_tokens_skipped_total = 0   # ... not run (YOCO)
-        if hybrid:
+        if planes_w:
             W = cfg.sliding_window
             chunk = min(prefill_chunk or self.max_len, self.max_len)
             mid = min(self.B, 2 * (max_prefills_per_step or self.B))
@@ -1813,9 +1688,10 @@ class DecodeEngine:
             self._row_blocks_w: List[List[int]] = [
                 [] for _ in range(self.B)]
             self._w_lo = np.zeros((self.B,), np.int64)  # first held block
-            self._hyb = _hybrid.zero_state(cfg, self.B, n_blocks_w, T)
-        elif self._state_planes:
-            self._hyb = zero_state_planes(self._state_planes, self.B)
+            pools_w = _zero_pools(planes_w, n_blocks_w, T,
+                                  jnp.dtype(cfg.dtype), False, None)
+            hyb.update((pl.name, x) for pl, x in zip(planes_w, pools_w))
+        self._hyb: Optional[Params] = hyb or None
         self._prefix: Optional[PrefixCacheIndex] = None
         if prefix_cache:
             self._prefix = PrefixCacheIndex(
@@ -1864,7 +1740,6 @@ class DecodeEngine:
             (self._pool_dk, self._pool_dv, self._scale_dk,
              self._scale_dv) = _zero_pools(
                 draft_cfg.cache_planes(), n_blocks_d, T,
-                draft_cfg.n_kv_heads,
                 self.kv_quant_spec.dtype if self.kv_quant_spec is not None
                 else jnp.dtype(draft_cfg.dtype),
                 self.kv_quant_spec is not None,
@@ -2568,7 +2443,7 @@ class DecodeEngine:
             self._count_paged_walk(H, rows)
             bt_dev = self._table_snapshot(self._bt)
             btw_dev = self._table_snapshot(self._bt_w) \
-                if self._hybrid else None
+                if self.kv_pool_w is not None else None
             # the scope only matters while the program traces: under
             # a tp mesh paged_attention must not pick a Mosaic kernel
             with spmd_mesh_scope(self.mesh):
@@ -2642,7 +2517,7 @@ class DecodeEngine:
         # B rows walk and all but a call's first find their pages coming
         self.paged_walk_rows_total += H * self.B
         self.paged_walk_rows_chained_total += H * (self.B - 1)
-        if isinstance(self.cfg, MlaConfig):
+        if self._selects:
             self._count_selection(slots + 1, decode=True)
         if self._state_planes:
             # tokens the kernel is asked to read, a token-layer each: the
@@ -2653,7 +2528,7 @@ class DecodeEngine:
             cfg = self.cfg
             self.kv_walk_tokens_full_total += int((slots + 1).sum()) \
                 * cfg.full_cache_readers
-            if self._hybrid:
+            if self.kv_pool_w is not None:
                 self.kv_walk_tokens_window_total += int(np.minimum(
                     slots + 1, cfg.sliding_window).sum()) \
                     * cfg.n_window_layers
@@ -2661,9 +2536,10 @@ class DecodeEngine:
 
     def _count_selection(self, live: np.ndarray,
                          decode: bool = False) -> None:
-        """Account the queries of one dispatch of an `MlaConfig`: each
-        sees ``live`` tokens, the indexer scores them all and attention
-        reads `index_topk` of them at most, in every layer."""
+        """Account the queries of one dispatch of a config that selects
+        (an `MlaConfig`): each sees ``live`` tokens, the indexer scores
+        them all and attention reads `index_topk` of them at most, in
+        every layer."""
         cfg = self.cfg
         scored = int(live.sum()) * cfg.n_layers
         selected = int(np.minimum(live, cfg.index_topk).sum()) * cfg.n_layers
@@ -2689,13 +2565,13 @@ class DecodeEngine:
         kernel, and is counted)."""
         from ray_tpu.ops.paged_attention_kernel import walk_shape
 
-        if isinstance(self.cfg, MlaConfig):
+        if self._selects:
             real = np.arange(bucket)[None, :] <= last_idx[:, None]
             live = starts[:, None] + np.arange(bucket)[None, :] + 1
             self._count_selection(live[real])
             return
-        if self.kv_quant_spec is not None or self._hybrid or (
-                self.mesh is not None and self.mesh.size > 1):
+        if self.kv_quant_spec is not None or self.kv_pool_w is not None \
+                or (self.mesh is not None and self.mesh.size > 1):
             return
         cfg, T = self.cfg, self.kv_block_tokens
         _, tq = walk_shape(bucket, cfg.n_heads, cfg.n_kv_heads,
@@ -3016,9 +2892,9 @@ class DecodeEngine:
                      "prefill_layer_tokens_skipped_total"):
             out[name] = float(getattr(self, name))
         out["window_pool_blocks_total"] = float(
-            self.kv_pool_w.blocks_total if self._hybrid else 0)
+            self.kv_pool_w.blocks_total if self.kv_pool_w else 0)
         out["window_pool_blocks_in_use"] = float(
-            self.kv_pool_w.blocks_in_use if self._hybrid else 0)
+            self.kv_pool_w.blocks_in_use if self.kv_pool_w else 0)
         # Disaggregated-handoff plane: identically 0.0 on a colocated
         # engine (prefill_only never set, import never called) so
         # fleet rollups sum blindly.
@@ -3224,11 +3100,11 @@ class DecodeEngine:
     def kv_used_fraction(self) -> float:
         """Unreclaimable KV pressure in [0, 1] — the fleet router's
         occupancy signal: the fraction of pool blocks neither free nor
-        evictable-cold; the fuller of the two pools for a
-        `HybridConfig`, which a row needs both of."""
+        evictable-cold; the fuller of the two pools where there is a
+        window pool, which a row needs both of."""
         used = max(0.0, 1.0 - self.kv_free_blocks()
                    / self.kv_pool.blocks_total)
-        if self._hybrid:
+        if self.kv_pool_w is not None:
             used = max(used, self.kv_pool_w.blocks_in_use
                        / self.kv_pool_w.blocks_total)
         return used
@@ -3481,7 +3357,7 @@ class DecodeEngine:
         self._row_blocks[row] = list(chain)
         self._bt[row, :] = 0
         self._bt[row, :len(chain)] = chain
-        if self._hybrid:
+        if self.kv_pool_w is not None:
             # the window chain is grown chunk by chunk (`_window_cover`);
             # the slot's recurrent state is zeroed by the first chunk
             assert not self._row_blocks_w[row]
@@ -3554,7 +3430,7 @@ class DecodeEngine:
                 self._bt[b, have:have + len(got)] = got
             if self.spec_enabled and not self._ensure_draft_blocks(b, nb):
                 return False
-            if self._hybrid:
+            if self.kv_pool_w is not None:
                 # every dispatch still to come queries at or past the
                 # host's replayed row_len
                 self._window_release(b, int(self.row_len[b]))
@@ -3562,7 +3438,7 @@ class DecodeEngine:
                     return False
         return True
 
-    # -- the window plane (a `HybridConfig`) -------------------------------
+    # -- the window plane (`kv_pool_w`: a `HybridConfig`'s) -----------------
 
     def _window_cover(self, b: int, upto: int) -> bool:
         """Grow row ``b``'s WINDOW chain to hold slots below ``upto``.
@@ -3943,21 +3819,9 @@ class DecodeEngine:
         return rid
 
     def _refuse_handoff(self, what: str) -> None:
-        if isinstance(self.cfg, MlaConfig):
-            raise ValueError(
-                f"an MlaConfig cannot be served with {what}: a hand-off "
-                "carries K and V planes of one width, not a latent and an "
-                "index plane (ROADMAP M3)")
-        if self._hybrid:
-            raise ValueError(
-                f"a HybridConfig cannot be served with {what}: a hand-off "
-                "carries K/V blocks only, not a row's recurrent state or "
-                "its window blocks")
-        if self._state_planes:
-            raise ValueError(
-                f"a {type(self.cfg).__name__} cannot be served with "
-                f"{what}: a hand-off carries K/V blocks only, not a row's "
-                "recurrent state (ROADMAP M4)")
+        why = self.cfg.refusals().get("handoff")
+        if why is not None:
+            raise ValueError(why.format(what))
 
     @property
     def _kv_geometry(self) -> Tuple[Tuple[str, int, int], ...]:
@@ -3975,7 +3839,7 @@ class DecodeEngine:
             self.kv_pool.decref(ids)
         self._row_blocks[row] = []
         self._bt[row, :] = 0
-        if self._hybrid:
+        if self.kv_pool_w is not None:
             if self._row_blocks_w[row]:
                 self.kv_pool_w.decref(self._row_blocks_w[row])
             self._row_blocks_w[row] = []
@@ -4014,7 +3878,7 @@ class DecodeEngine:
                     need -= len(ids) - 1   # tail block is CoW'd
                 else:
                     need -= len(ids)
-        if self._hybrid:
+        if self.kv_pool_w is not None:
             # the window plane must hold the first chunk at least
             first = min(len(req.prompt), self.prefill_chunk or self.max_len)
             if -(-first // T) > self.kv_pool_w.free_blocks:
@@ -4064,9 +3928,10 @@ class DecodeEngine:
             return
         with self.trace.lane("advance_prefills", "dispatch",
                              rows=len(todo), ahead=ahead):
-            # A group is one program: the chunks of one bucket and, for a
-            # `HybridConfig`, of one kind, a prompt's last chunk or not
-            # (the others are always "last": one program a bucket).
+            # A group is one program: the chunks of one bucket and, where
+            # prefill stops early (a `HybridConfig`), of one kind, a
+            # prompt's last chunk or not (the others are always "last":
+            # one program a bucket).
             groups: Dict[Tuple[int, bool],
                          List[Tuple[int, _PrefillState, int]]] = {}
             for row, st in todo.items():
@@ -4076,13 +3941,13 @@ class DecodeEngine:
                 # Bucket the chunk, capped so the scatter never runs past
                 # max_len (starts differ per row; the cap is per-row).
                 Cb = min(self._bucket(C), self.max_len - st.pos)
-                final = True
-                if self._hybrid:
-                    if not self._window_cover(row, st.pos + C):
-                        continue   # the window pool is dry: next step
-                    final = st.pos + C >= len(st.prompt)
+                if self.kv_pool_w is not None \
+                        and not self._window_cover(row, st.pos + C):
+                    continue   # the window pool is dry: next step
+                final = not self._prefill_stops_early \
+                    or st.pos + C >= len(st.prompt)
                 groups.setdefault((Cb, final), []).append((row, st, C))
-            if self._hybrid and not groups and not ahead \
+            if self.kv_pool_w is not None and not groups and not ahead \
                     and len(todo) == len(self._row_prefill) \
                     == sum(r is not None for r in self.row_req):
                 raise RuntimeError(
@@ -4123,8 +3988,9 @@ class DecodeEngine:
                     if self._state_planes:
                         self.ssm_state_resets_total += sum(
                             st.pos == 0 for _, st, _ in grp)
-                    if self._hybrid:
+                    if self.kv_pool_w is not None:
                         btw_grp = jnp.asarray(self._bt_w[rows])
+                    if self._prefill_stops_early:
                         skipped = self.cfg.n_layers \
                             - self.cfg.prefill_layers()
                         self.prefill_layer_tokens_total += \
@@ -4174,7 +4040,7 @@ class DecodeEngine:
                              "prompt_tokens": len(st.prompt)})
                     if self._prefix is not None:
                         self._commit_covered(row, st)
-                    if self._hybrid:
+                    if self.kv_pool_w is not None:
                         self._window_release(row, st.pos)
                     if st.pos >= len(st.prompt):
                         done_rows.append(row)

@@ -50,14 +50,16 @@ none is built here (the model serves without it).
 from __future__ import annotations
 
 import dataclasses
+import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.block_pool import CachePlane, StatePlane, kv_planes
-from ray_tpu.models.hybrid import LayerKind, Segment
-from ray_tpu.models.moe import moe_ffn_dropless
+from ray_tpu.models.hybrid import (STATE_REFUSALS, LayerKind, Segment,
+                                   _starts_fresh)
+from ray_tpu.models.moe import EXPERT_STACKS, moe_ffn_dropless
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention
 from ray_tpu.ops.gated_delta import delta_chunks, delta_step, l2norm
@@ -66,8 +68,6 @@ Params = Dict[str, Any]
 
 DELTA = LayerKind("delta", state="delta")
 GATED_ATTN = LayerKind("gated_attn", writes="full", reads="full")
-
-_EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,6 +192,31 @@ class GdnConfig:
                 StatePlane("conv", self.n_delta_layers,
                            (self.conv_kernel - 1, self.conv_dim),
                            jnp.dtype(self.dtype)))
+
+    def prefill_layers(self) -> int:
+        return self.n_layers
+
+    def refusals(self) -> Dict[str, str]:
+        """What would need the recurrent state moved, shared, rolled back
+        or split (`block_pool.ServedConfig`)."""
+        no, why = "a GdnConfig cannot be served with ", STATE_REFUSALS
+        return {
+            "prefix_cache": no + why["prefix_cache"] + " (ROADMAP M4)",
+            "preempt_swap": no + why["preempt_swap"]
+            + " (pass preempt='recompute'; ROADMAP M4)",
+            "draft": no + why["draft"] + "; the model's own drafting head "
+            "is not built (ROADMAP M7)",
+            "kv_quant": no + "kv_quant=: the quantized write's scales are "
+            "sized from the dense family's layers, and the recurrent state "
+            "has no quantized form",
+            "lora": no + why["lora"],
+            "tp": no + "tp=/mesh=: the delta-rule weights, the recurrent "
+            "state and the held experts have no sharding rule and no "
+            "exchange (ROADMAP M2)",
+            "handoff": no + why["handoff"] + " (ROADMAP M4)"}
+
+    def stack(self):
+        return sys.modules[__name__]
 
     def num_params(self) -> int:
         """Parameters HELD here (held experts, this vocabulary)."""
@@ -329,13 +354,6 @@ def _rmsnorm1p(x, w, eps: float):
         return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
-def _starts_fresh(starts):
-    """[B] bool: the rows of a prefill group that begin from ZERO
-    recurrent state whatever their slot holds: a chunk at slot 0 is an
-    admission (or a recompute), every other continues its row."""
-    return starts == 0
-
-
 def _log_decay(a, p):
     """``g = -exp(A_log) * softplus(a + dt_bias)`` [B, S, Hv] float32:
     what a head's state decays by at a token, in logs."""
@@ -446,28 +464,17 @@ def lm_head(params: Params, h, cfg: GdnConfig):
 # ---------------------------------------------------------------------------
 
 def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
-                 cfg: GdnConfig, state, *, live, rows=None, n_valid=None,
-                 last_idx=None, moe_live=None):
-    """The stack for all rows of ``toks`` [B, S] against the pool and the
-    recurrent state: what `engine._layers_paged` is for the other
-    families.
+                 cfg: GdnConfig, *, state, bt_w=None, live, rows=None,
+                 n_valid=None, last_idx=None, final: bool = True,
+                 moe_live=None):
+    """This family's stack against the pool and the recurrent state, as
+    `block_pool.ServedConfig.stack` describes it:
 
       pool_k/v  the K/V pool [n_attn_layers, NB, T, KV*D], through ``bt``
       state     {"delta", "conv"} (`GdnConfig.state_planes`), a slot a row
-      live      [B, S] bool: the positions that advance recurrent state
-                (a prefix of each row)
-      rows      [B] the engine slot of each row (prefill's admission
-                group; None: row b is slot b, decode). A row with
-                ``starts == 0`` begins from ZERO state, whatever its slot
-                holds: that is how a slot is reset at admission
-      n_valid   [B] real tokens of a prefill chunk (None: all S)
-      last_idx  [B] the position whose hidden state is wanted (prefill);
-                None: every position (decode)
-      moe_live  [B, S] bool or None: the positions the expert layers'
-                counters count (None: none are traced)
 
-    Returns (h [B, S, d] or [B, 1, d], pool_k, pool_v, state, expert-layer
-    counts [n_layers, 3 or 4] or None)."""
+    No window plane and every layer for every chunk: ``bt_w`` and
+    ``final`` are ignored. The expert-layer counts are [n_layers, 3 or 4]."""
     B, S = toks.shape
     T = pool_k.shape[2]
     span = bt.shape[1] * T
@@ -524,9 +531,9 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
 
     period = params["period"]
     experts = {n: period["moe"][n].reshape(-1, *period["moe"][n].shape[3:])
-               for n in _EXPERT_STACKS}
+               for n in EXPERT_STACKS}
     moe_small = {n: v for n, v in period["moe"].items()
-                 if n not in _EXPERT_STACKS}
+                 if n not in EXPERT_STACKS}
 
     def expert_layer(h, p, li):
         x = _rmsnorm1p(h, p["norm"], cfg.norm_eps)
@@ -596,4 +603,12 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
         stats = stats.reshape(cfg.n_layers, -1)
     if last_idx is not None:
         h = h[jnp.arange(B), last_idx][:, None]
-    return h, pool_k, pool_v, {"delta": sd, "conv": sc}, stats
+    return h, pool_k, pool_v, stats, {"delta": sd, "conv": sc}
+
+
+def init_cache(cfg: GdnConfig, batch_size: int, max_len: int):
+    """`generate.init_cache` for this family: there is none to make."""
+    raise ValueError(
+        "a GdnConfig has no solo generation path: its stack runs "
+        "over the engine's pool, table and state slots; serve it "
+        "through DecodeEngine")

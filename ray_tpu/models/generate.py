@@ -19,13 +19,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import hybrid as _hybrid
-from ray_tpu.models.hybrid import HybridConfig
-from ray_tpu.models import mla as _mla
-from ray_tpu.models.mla import MlaConfig
-from ray_tpu.models.gdn import GdnConfig
 from ray_tpu.models.llama import LlamaConfig, _rmsnorm, _rope
-from ray_tpu.models.moe import (MoeConfig, hit_experts_only,
+from ray_tpu.models.moe import (EXPERT_STACKS, MoeConfig, hit_experts_only,
                                 moe_ffn_dropless, qk_norm)
 from ray_tpu.ops import scope_names as sn
 
@@ -41,18 +36,13 @@ def init_cache(cfg: LlamaConfig, batch_size: int,
     mesh — the tensor-parallel engine shards the KV-head axis so each
     chip holds only its heads' cache.
 
-    A `HybridConfig` keeps another state (two pools behind a trivial
-    block table, and recurrent state): `hybrid.init_cache`."""
+    A family that brings its own stack (`block_pool.ServedConfig.stack`)
+    keeps another state, its stack's own `init_cache`: pools behind a
+    trivial block table, and what recurrent state it has."""
     max_len = max_len or cfg.max_seq_len
-    if isinstance(cfg, HybridConfig):
-        return _hybrid.init_cache(cfg, batch_size, max_len)
-    if isinstance(cfg, MlaConfig):
-        return _mla.init_cache(cfg, batch_size, max_len)
-    if isinstance(cfg, GdnConfig):
-        raise ValueError(
-            "a GdnConfig has no solo generation path: its stack runs "
-            "over the engine's pool, table and state slots; serve it "
-            "through DecodeEngine")
+    own = cfg.stack()
+    if own is not None:
+        return own.init_cache(cfg, batch_size, max_len)
     shape = (cfg.n_layers, batch_size, max_len, cfg.n_kv_heads,
              cfg.head_dim)
     cache = {"k": jnp.zeros(shape, cfg.dtype),
@@ -102,9 +92,6 @@ def _lora_delta(x, ab, slots, dt):
                       jnp.einsum("bsi,bir->bsr", x, a), b)
 
 
-_EXPERT_STACKS = ("we_gate", "we_up", "we_down")
-
-
 def _expert_stacks(layers: Params, cfg: LlamaConfig, tokens: int):
     """What a layer scan over ``layers`` should slice a layer at a time,
     and what it should not: (the scan's layers, the expert stacks of ALL
@@ -117,7 +104,7 @@ def _expert_stacks(layers: Params, cfg: LlamaConfig, tokens: int):
     if not (isinstance(cfg, MoeConfig) and hit_experts_only(cfg, tokens)):
         return layers, None
     stacks = {n: layers[n].reshape(-1, *layers[n].shape[2:])
-              for n in _EXPERT_STACKS}
+              for n in EXPERT_STACKS}
     return ({n: v for n, v in layers.items() if n not in stacks}, stacks)
 
 
@@ -256,6 +243,16 @@ def _layer_xs(layers: Params, cache: Cache, stacks):
     return xs
 
 
+def lm_head(params: Params, h: jax.Array, cfg: LlamaConfig):
+    """Final norm and vocab projection, [B, S, d] -> f32 [B, S, vocab], of
+    the families whose layers are `_layer_body`'s."""
+    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope(sn.LM_HEAD):
+        return jnp.einsum("bsd,dv->bsv", h,
+                          params["lm_head"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
+
+
 def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
                    start, cfg: LlamaConfig, *,
                    positions: Optional[jax.Array] = None,
@@ -269,25 +266,14 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
     tokens at position 0); ``slot_live`` [B, max_len] masks dead (pad)
     cache slots out of every attention.
 
-    A `HybridConfig` has no position ids and cannot skip a pad (its
-    state-space layers would consume it), so it takes neither; its
-    logits are the chunk's LAST position's alone, [B, 1, vocab]
-    (`hybrid.forward_cached`)."""
-    if isinstance(cfg, HybridConfig):
-        if slot_live is not None:
-            raise ValueError(
-                "a HybridConfig cannot generate from left-padded prompts "
-                "(prompt_live=): a state-space layer consumes every token "
-                "it is fed; batch prompts of one length, or use the engine")
-        return _hybrid.forward_cached(params, tokens, cache, start, cfg)
-    if isinstance(cfg, MlaConfig):
-        if slot_live is not None:
-            raise ValueError(
-                "an MlaConfig cannot generate from left-padded prompts "
-                "(prompt_live=): its rotary positions are its cache slots "
-                "and the indexer scores every slot below a query; batch "
-                "prompts of one length, or use the engine")
-        return _mla.forward_cached(params, tokens, cache, start, cfg)
+    A family that brings its own stack runs its own `forward_cached`:
+    it takes no position ids, refuses ``slot_live`` (it cannot skip a
+    pad), and its logits are the chunk's LAST position's alone,
+    [B, 1, vocab]."""
+    own = cfg.stack()
+    if own is not None:
+        return own.forward_cached(params, tokens, cache, start, cfg,
+                                  slot_live=slot_live)
     B, S = tokens.shape
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(cfg.dtype)[tokens]
@@ -309,12 +295,7 @@ def forward_cached(params: Params, tokens: jax.Array, cache: Cache,
 
     h, (k_new, v_new) = jax.lax.scan(
         body, h, _layer_xs(layers, cache, stacks))
-    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope(sn.LM_HEAD):
-        logits = jnp.einsum("bsd,dv->bsv", h,
-                            params["lm_head"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
+    return lm_head(params, h, cfg), {"k": k_new, "v": v_new}
 
 
 def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
@@ -367,12 +348,7 @@ def forward_cached_rows(params: Params, tokens: jax.Array, cache: Cache,
 
     h, (k_new, v_new) = jax.lax.scan(
         body, h, _layer_xs(layers, cache, stacks))
-    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope(sn.LM_HEAD):
-        logits = jnp.einsum("bsd,dv->bsv", h,
-                            params["lm_head"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
+    return lm_head(params, h, cfg), {"k": k_new, "v": v_new}
 
 
 def filter_logits(logits: jax.Array, top_k: Optional[int] = None,

@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import sys
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -82,6 +83,22 @@ WINDOW_ATTN = LayerKind("attn", writes="window", reads="window")
 FULL_ATTN = LayerKind("attn", writes="full", reads="full")
 GMU = LayerKind("gmu")
 CROSS_ATTN = LayerKind("cross", reads="full")
+
+
+# What recurrent state rules out (`ServedConfig.refusals`), said once:
+# `gdn.py` adds its ROADMAP ids, and "lora" is `mla.py`'s sentence too.
+STATE_REFUSALS = {
+    "prefix_cache": "prefix_cache=True: a prefix hit needs a snapshot of "
+                    "the recurrent state at the block boundary it resumes "
+                    "from, and none is kept",
+    "preempt_swap": "preempt='swap': the swap ledger carries K/V blocks "
+                    "only, not a row's recurrent state",
+    "draft": "draft_params=/draft_cfg=: a rejected draft token has already "
+             "advanced the recurrent state, and there is no roll-back of it",
+    "lora": "lora=: the adapter targets name the dense family's projections",
+    "handoff": "{}: a hand-off carries K/V blocks only, not a row's "
+               "recurrent state",
+}
 
 
 class Segment(NamedTuple):
@@ -176,11 +193,12 @@ class HybridConfig:
         """What a token stores (`block_pool.CachePlane`): K and V of the
         ONE full-attention layer behind the row's table (the
         cross-attention layers read it and store nothing), and K and V of
-        the window layers behind the window table."""
+        the window layers behind the window table (``wk`` and ``wv`` of
+        the state `layers_paged` is handed)."""
         dt = jnp.dtype(self.dtype)
         return kv_planes("full", 1, self.n_kv_heads, self.head_dim, dt) \
             + kv_planes("window", self.n_window_layers, self.n_kv_heads,
-                        self.head_dim, dt, prefix="window_")
+                        self.head_dim, dt, prefix="w")
 
     def state_planes(self):
         """What a ROW keeps whatever its length (`block_pool.StatePlane`):
@@ -197,6 +215,25 @@ class HybridConfig:
         """Layers that see every prompt token (the rest run for the one
         position whose logits are wanted)."""
         return self.half + 2
+
+    def refusals(self) -> Dict[str, str]:
+        """What would need the recurrent state or the window pool moved,
+        shared or split (`block_pool.ServedConfig`)."""
+        no, why = "a HybridConfig cannot be served with ", STATE_REFUSALS
+        return {
+            "prefix_cache": no + why["prefix_cache"],
+            "preempt_swap": no + why["preempt_swap"] + " or its window "
+            "blocks (pass preempt='recompute')",
+            "draft": no + why["draft"],
+            "kv_quant": no + "kv_quant=: the window pool and the pair "
+            "layout differential attention reads have no quantized write",
+            "lora": no + why["lora"],
+            "tp": no + "tp=/mesh=: the state-space and memory-unit weights "
+            "and the recurrent state have no sharding rule",
+            "handoff": no + why["handoff"] + " or its window blocks"}
+
+    def stack(self):
+        return sys.modules[__name__]
 
     @staticmethod
     def phi4_mini_flash(**kw) -> "HybridConfig":
@@ -485,43 +522,18 @@ def lm_head(params: Params, h, cfg: HybridConfig):
 # The stack against the engine's pools
 # ---------------------------------------------------------------------------
 
-def zero_state(cfg: HybridConfig, slots: int, n_window_blocks: int,
-               block_tokens: int) -> Dict[str, jax.Array]:
-    """This family's device state beside the full pool: the window pools
-    and the recurrent state of every engine slot, zeroed."""
-    lanes = cfg.n_kv_heads * cfg.head_dim
-    wshape = (cfg.n_window_layers, n_window_blocks, block_tokens, lanes)
-    return {
-        "wk": jnp.zeros(wshape, cfg.dtype),
-        "wv": jnp.zeros(wshape, cfg.dtype),
-        **zero_state_planes(cfg.state_planes(), slots),
-    }
-
-
 def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
-                 cfg: HybridConfig, hyb, bt_w, *, live, rows=None,
-                 n_valid=None, last_idx=None, final: bool = True):
-    """The stack for all rows of ``toks`` [B, S] against the pools: what
-    `engine._layers_paged` is for the other families.
+                 cfg: HybridConfig, *, state, bt_w, live, rows=None,
+                 n_valid=None, last_idx=None, final: bool = True,
+                 moe_live=None):
+    """This family's stack against the pools, as
+    `block_pool.ServedConfig.stack` describes it:
 
       pool_k/v  the FULL pool [1, NB, T, KV*D], through ``bt``
-      hyb       {"wk", "wv", "ssm", "conv"} (`zero_state`), ``wk``/``wv``
-                through ``bt_w``
-      live      [B, S] bool: the positions that advance recurrent state
-                (a prefix of each row)
-      rows      [B] the engine slot of each row (prefill's admission
-                group; None: row b is slot b, decode). A row with
-                ``starts == 0`` begins from ZERO state, whatever its slot
-                holds: that is how a slot is reset at admission
-      n_valid   [B] real tokens of a prefill chunk (None: all S)
-      last_idx  [B] the position whose hidden state is wanted (prefill);
-                None: every position (decode)
-      final     False: stop after the layers that see every token and
-                return no hidden state (a chunk that is not a prompt's
-                last)
+      state     {"wk", "wv", "ssm", "conv"} (the window `cache_planes` and
+                the `state_planes`), ``wk``/``wv`` through ``bt_w``
 
-    Returns (h, pool_k, pool_v, hyb): h [B, S, d], or [B, 1, d] with
-    ``last_idx``, or None without ``final``."""
+    No expert layers: ``moe_live`` is ignored and the counts are None."""
     B, S = toks.shape
     T = pool_k.shape[2]
     span = bt.shape[1] * T
@@ -607,17 +619,17 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
             seg.kinds[1])
         return (h, st, wk, wv), None
 
-    st = {"ssm": hyb["ssm"], "conv": hyb["conv"]}
+    st = {"ssm": state["ssm"], "conv": state["conv"]}
     (h, st, wk, wv), _ = jax.lax.scan(
-        self_body, (h, st, hyb["wk"], hyb["wv"]),
+        self_body, (h, st, state["wk"], state["wv"]),
         (params["self"], jnp.arange(seg.periods)))
     seg = plan["mid"]
     h, (st, (pool_k, pool_v, _)), mem = self_period(
         h, params["mid"], (st, (pool_k, pool_v, bt)),
         plan["self"].periods, 0, seg.first_layer, seg.kinds[1])
-    hyb = {"wk": wk, "wv": wv, **st}
+    state = {"wk": wk, "wv": wv, **st}
     if not final:
-        return None, pool_k, pool_v, hyb
+        return None, pool_k, pool_v, None, state
     if last_idx is not None:
         # the cross-decoder and the head see ONE position a row
         at = (jnp.arange(B), last_idx)
@@ -648,7 +660,7 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
 
     h, _ = jax.lax.scan(cross_body, h,
                         (params["cross"], jnp.arange(seg.periods)))
-    return h, pool_k, pool_v, hyb
+    return h, pool_k, pool_v, None, state
 
 
 # ---------------------------------------------------------------------------
@@ -667,26 +679,31 @@ def init_cache(cfg: HybridConfig, batch_size: int, max_len: int):
     T = _SOLO_BLOCK
     mb = -(-max_len // T)
     nb = 1 + batch_size * mb
-    lanes = cfg.n_kv_heads * cfg.head_dim
-    shape = (1, nb, T, lanes)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype),
+    return {**{pl.name: jnp.zeros((pl.layers, nb, T, pl.lanes), pl.dtype)
+               for pl in cfg.cache_planes()},
             "bt": 1 + jnp.arange(batch_size * mb,
                                  dtype=jnp.int32).reshape(batch_size, mb),
-            **zero_state(cfg, batch_size, nb, T)}
+            **zero_state_planes(cfg.state_planes(), batch_size)}
 
 
-def forward_cached(params: Params, tokens, cache, start, cfg: HybridConfig):
+def forward_cached(params: Params, tokens, cache, start, cfg: HybridConfig,
+                   slot_live=None):
     """`generate.forward_cached` for this family: run a chunk [B, S] at
     slot ``start`` of every row. Returns (logits of each row's LAST
     position [B, 1, vocab] f32, cache): the layers after the
     full-attention layer and the head see that one position, as in the
-    engine's prefill."""
+    engine's prefill. It has no position ids and cannot skip a pad."""
+    if slot_live is not None:
+        raise ValueError(
+            "a HybridConfig cannot generate from left-padded prompts "
+            "(prompt_live=): a state-space layer consumes every token "
+            "it is fed; batch prompts of one length, or use the engine")
     B, S = tokens.shape
-    hyb = {n: cache[n] for n in ("wk", "wv", "ssm", "conv")}
-    h, k, v, hyb = layers_paged(
+    h, k, v, _, state = layers_paged(
         params, tokens, cache["k"], cache["v"], cache["bt"],
-        jnp.full((B,), start, jnp.int32), cfg, hyb, cache["bt"],
-        live=jnp.ones((B, S), bool),
+        jnp.full((B,), start, jnp.int32), cfg,
+        state={n: cache[n] for n in ("wk", "wv", "ssm", "conv")},
+        bt_w=cache["bt"], live=jnp.ones((B, S), bool),
         last_idx=jnp.full((B,), S - 1, jnp.int32) if S > 1 else None)
     return lm_head(params, h, cfg), {"k": k, "v": v, "bt": cache["bt"],
-                                     **hyb}
+                                     **state}

@@ -126,6 +126,20 @@ class LlamaConfig:
         return kv_planes("full", self.n_layers, self.n_kv_heads,
                          self.head_dim, jnp.dtype(self.dtype))
 
+    # `block_pool.ServedConfig`'s other questions: no recurrent state, no
+    # option refused, `generate._layer_body`'s layers, all for every token.
+    def state_planes(self):
+        return ()
+
+    def refusals(self) -> Dict[str, str]:
+        return {}
+
+    def stack(self):
+        return None
+
+    def prefill_layers(self) -> int:
+        return self.n_layers
+
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads

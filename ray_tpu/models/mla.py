@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -57,9 +58,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.block_pool import CachePlane
-from ray_tpu.models.hybrid import LayerKind, Segment
+from ray_tpu.models.hybrid import STATE_REFUSALS, LayerKind, Segment
 from ray_tpu.models.llama import _rmsnorm
-from ray_tpu.models.moe import moe_ffn_dropless
+from ray_tpu.models.moe import EXPERT_STACKS, moe_ffn_dropless
 from ray_tpu.ops import scope_names as sn
 
 Params = Dict[str, Any]
@@ -184,6 +185,39 @@ class MlaConfig:
                            self.latent_lanes, jnp.dtype(self.dtype)),
                 CachePlane("index", "full", self.n_layers,
                            self.index_head_dim, jnp.dtype(self.dtype)))
+
+    def state_planes(self):
+        return ()
+
+    def prefill_layers(self) -> int:
+        return self.n_layers
+
+    def refusals(self) -> Dict[str, str]:
+        """What would need the two planes shared, quantized, moved or
+        split (`block_pool.ServedConfig`)."""
+        no = "an MlaConfig cannot be served with "
+        return {
+            "prefix_cache": no + "prefix_cache=True: the trie's copy-on-"
+            "write and eviction know K and V planes of one width, not a "
+            "latent and an index plane (ROADMAP M3)",
+            "kv_quant": no + "kv_quant=: the latent and index planes have "
+            "no quantized write, and a quantized indexer key changes what "
+            "is selected (ROADMAP M3)",
+            "preempt_swap": no + "preempt='swap': the swap ledger gathers "
+            "and scatters K and V planes of one width (pass "
+            "preempt='recompute'; ROADMAP M3)",
+            "tp": no + "tp=/mesh=: the latent is ONE head's cache and the "
+            "held experts have no exchange: neither has a sharding rule "
+            "(ROADMAP M2)",
+            "lora": no + STATE_REFUSALS["lora"],
+            "draft": no + "draft_params=/draft_cfg=: the verify window has "
+            "no selection per drafted token, and the model's own drafting "
+            "head is not built (ROADMAP M7)",
+            "handoff": no + "{}: a hand-off carries K and V planes of one "
+            "width, not a latent and an index plane (ROADMAP M3)"}
+
+    def stack(self):
+        return sys.modules[__name__]
 
     @staticmethod
     def deepseek_v32_exp(**kw) -> "MlaConfig":
@@ -553,22 +587,17 @@ def _attention(h, p, li, pool_c, pool_i, bt, slots, q_slots, cfg: MlaConfig,
 
 
 def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
-                 cfg: MlaConfig, *, moe_live=None, n_valid=None,
-                 last_idx=None, want_selection: bool = False):
-    """The stack for all rows of ``toks`` [B, S] against the two planes
-    (latent ``pool_c``, index ``pool_i``) through ``bt``: what
-    `engine._layers_paged` is for the dense and sparse families.
-
-      moe_live  [B, S] bool or None: the positions the expert layers'
-                counters count (None: none are traced)
-      n_valid   [B] real tokens of a prefill chunk (None: all S)
-      last_idx  [B] the position whose hidden state is wanted (prefill);
-                None: every position
-
-    Returns (h [B, S, d] or [B, 1, d], pool_c, pool_i, expert-layer
-    counts [n_moe_layers, 4] or None) and, with ``want_selection`` (the
-    benchmark's `select_overlap`), which slots each query chose in every
-    layer [n_layers, B, S, span] bool."""
+                 cfg: MlaConfig, *, state=None, bt_w=None, live=None,
+                 rows=None, n_valid=None, last_idx=None, final: bool = True,
+                 moe_live=None, want_selection: bool = False):
+    """This family's stack against its two planes (latent ``pool_c``,
+    index ``pool_i``) through ``bt``, as `block_pool.ServedConfig.stack`
+    describes it. No recurrent state, no window plane, every layer for
+    every chunk: ``state``, ``bt_w``, ``live``, ``rows`` and ``final``
+    are ignored. The expert-layer counts are [n_moe_layers, 4]; the fifth
+    result, the state it does not have, is None or, with
+    ``want_selection`` (the benchmark's `select_overlap`), which slots
+    each query chose in every layer [n_layers, B, S, span] bool."""
     B, S = toks.shape
     dt = cfg.dtype
     slots = starts[:, None] + jnp.arange(S)[None, :]
@@ -607,15 +636,14 @@ def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
                                    read=real)
         return (h + out, pc, pi), (st, sel if want_selection else None)
 
-    stacks = ("we_gate", "we_up", "we_down")
     experts = {n: params["moe"][n].reshape(-1, *params["moe"][n].shape[2:])
-               for n in stacks}
+               for n in EXPERT_STACKS}
     carry = (h, pool_c, pool_i)
     stats, chosen = None, []
     for seg in cfg.layer_plan():
         body = dense_body if seg.name == "dense" else moe_body
         layers = {n: v for n, v in params[seg.name].items()
-                  if n not in stacks}
+                  if n not in EXPERT_STACKS}
         carry, (st, sel) = jax.lax.scan(
             body, carry,
             (layers, seg.first_layer + jnp.arange(seg.periods)))
@@ -625,9 +653,8 @@ def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
     h, pool_c, pool_i = carry
     if last_idx is not None:
         h = h[jnp.arange(B), last_idx][:, None]
-    if want_selection:
-        return h, pool_c, pool_i, stats, jnp.concatenate(chosen)
-    return h, pool_c, pool_i, stats
+    return h, pool_c, pool_i, stats, \
+        jnp.concatenate(chosen) if want_selection else None
 
 
 def lm_head(params: Params, h, cfg: MlaConfig):
@@ -659,12 +686,19 @@ def init_cache(cfg: MlaConfig, batch_size: int, max_len: int):
                                  dtype=jnp.int32).reshape(batch_size, mb)}
 
 
-def forward_cached(params: Params, tokens, cache, start, cfg: MlaConfig):
+def forward_cached(params: Params, tokens, cache, start, cfg: MlaConfig,
+                   slot_live=None):
     """`generate.forward_cached` for this family: run a chunk [B, S] at
     slot ``start`` of every row. Returns (logits of each row's LAST
     position [B, 1, vocab] f32, cache)."""
+    if slot_live is not None:
+        raise ValueError(
+            "an MlaConfig cannot generate from left-padded prompts "
+            "(prompt_live=): its rotary positions are its cache slots "
+            "and the indexer scores every slot below a query; batch "
+            "prompts of one length, or use the engine")
     B, S = tokens.shape
-    h, c, i, _ = layers_paged(
+    h, c, i, _, _ = layers_paged(
         params, tokens, cache["c"], cache["i"], cache["bt"],
         jnp.full((B,), start, jnp.int32), cfg,
         last_idx=jnp.full((B,), S - 1, jnp.int32))

@@ -56,6 +56,9 @@ from ray_tpu.parallel.sharding import LogicalAxisRules, logical_to_mesh
 
 Params = Dict[str, Any]
 
+# an expert layer's three weight stacks, [experts, ...] each
+EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+
 
 @dataclasses.dataclass(frozen=True)
 class MoeConfig(LlamaConfig):
@@ -95,6 +98,14 @@ class MoeConfig(LlamaConfig):
                 "MoeConfig supports remat_policy='full' only: "
                 "_moe_decoder_layer carries no checkpoint_name tags, so a "
                 "named policy would save nothing it names")
+
+    def refusals(self) -> Dict[str, str]:
+        """The engine options a sparse model cannot be served with
+        (`block_pool.ServedConfig`); `DecodeEngine` itself refuses LoRA
+        targets that name the dense feed-forward and a dense draft."""
+        return {"tp": "tp=/mesh= cannot serve an MoeConfig: the serving "
+                      "sharding rules split the dense 'mlp' width, and the "
+                      "expert stacks have no rule yet"}
 
     @staticmethod
     def mixtral_8x7b(**kw) -> "MoeConfig":
@@ -547,8 +558,7 @@ def moe_ffn_dropless(x: jax.Array, layer: Params, cfg: MoeConfig,
         else:
             probs = jax.nn.softmax(logits, axis=-1)
             weights, idx = _route_topk(probs, k, cfg.norm_topk_prob)  # [G,k]
-    w1, w3, w2 = (layer[n].astype(dt)
-                  for n in ("we_gate", "we_up", "we_down"))
+    w1, w3, w2 = (layer[n].astype(dt) for n in EXPERT_STACKS)
     lo, eh = (0, e) if held is None else (held[0], held[1] - held[0])
     dense = g <= (DENSE_EXPERTS_MAX_TOKENS if held is None
                   else DENSE_HELD_MAX_TOKENS)

@@ -33,7 +33,7 @@ from ray_tpu.models import gdn, moe  # noqa: E402
 from ray_tpu.models.block_pool import zero_state_planes  # noqa: E402
 from ray_tpu.models.engine import DecodeEngine  # noqa: E402
 from ray_tpu.models.generate import generate  # noqa: E402
-from ray_tpu.models.hybrid import HybridConfig, zero_state  # noqa: E402
+from ray_tpu.models.hybrid import HybridConfig  # noqa: E402
 from ray_tpu.models.lora import LoraConfig  # noqa: E402
 from ray_tpu.ops import gated_delta as gd  # noqa: E402
 
@@ -369,7 +369,7 @@ def test_the_pool_and_the_state_are_the_configs_planes(params):
     assert eng._hyb["delta"].shape == (6, 3, 4, 16, 16)
     assert eng._hyb["delta"].dtype == jnp.float32
     assert eng._hyb["conv"].shape == (6, 3, 3, 2 * 32 + 64)
-    assert eng.kv_pool_w is None and not eng._hybrid
+    assert eng.kv_pool_w is None and not eng._prefill_stops_early
     assert eng.stats()["window_pool_blocks_total"] == 0
 
 
@@ -387,13 +387,92 @@ def test_published_state_is_two_mebibytes_a_row_a_layer():
 
 def test_a_hybrid_configs_state_is_its_declaration_too():
     """One path: the engine sizes a `HybridConfig`'s recurrent state from
-    `state_planes` as well, and it is what `hybrid.zero_state` made."""
+    `state_planes` as well, and its window pools from the "window"
+    `cache_planes`, all under the planes' own names."""
+    from ray_tpu.models import hybrid_init
+
     cfg = HybridConfig.nano_hybrid()
-    want = zero_state(cfg, 3, 5, 8)
-    got = zero_state_planes(cfg.state_planes(), 3)
-    assert set(got) == {"ssm", "conv"}
-    for name, x in got.items():
-        assert (x.shape, x.dtype) == (want[name].shape, want[name].dtype)
+    eng = engine(hybrid_init(jax.random.PRNGKey(0), cfg), cfg, batch_slots=3)
+    window = [pl for pl in cfg.cache_planes() if pl.table == "window"]
+    want = zero_state_planes(cfg.state_planes(), 3)
+    assert set(want) == {"ssm", "conv"}
+    assert [pl.name for pl in window] == ["wk", "wv"]
+    assert set(eng._hyb) == {"ssm", "conv", "wk", "wv"}
+    for name, x in want.items():
+        assert (x.shape, x.dtype) == (eng._hyb[name].shape,
+                                      eng._hyb[name].dtype)
+    for pl in window:
+        assert eng._hyb[pl.name].shape == (
+            pl.layers, eng.kv_pool_w.n_blocks, T, pl.lanes)
+        assert eng._hyb[pl.name].dtype == pl.dtype
+
+
+# -- the seam: what the engine and solo `generate` ask a family ---------------
+
+def _families():
+    from ray_tpu.models import LlamaConfig, MoeConfig
+    from ray_tpu.models.mla import MlaConfig
+    return {"llama": LlamaConfig.nano, "moe": MoeConfig.nano_moe,
+            "hybrid": HybridConfig.nano_hybrid, "mla": MlaConfig.nano_mla,
+            "gdn": nano_gdn}
+
+
+@pytest.mark.parametrize("family", ["llama", "moe", "hybrid", "mla", "gdn"])
+def test_every_family_answers_the_engines_questions(family):
+    """`block_pool.ServedConfig`, under one name for all five: what the
+    engine and solo `generate` read in place of the config's class."""
+    from ray_tpu.models.block_pool import CachePlane, StatePlane
+
+    cfg = _families()[family]()
+    refusals = cfg.refusals()
+    assert set(refusals) <= {"prefix_cache", "preempt_swap", "draft",
+                             "kv_quant", "lora", "tp", "handoff"}
+    for option, why in refusals.items():
+        assert isinstance(why, str) and type(cfg).__name__ in why, option
+        assert ("{}" in why) == (option == "handoff"), option
+    assert (family == "llama") == (refusals == {})
+    planes = cfg.cache_planes()
+    assert all(isinstance(pl, CachePlane) for pl in planes)
+    assert [pl.table for pl in planes].count("full") == 2
+    assert {pl.table for pl in planes} <= {"full", "window"}
+    if any(pl.table == "window" for pl in planes):
+        assert cfg.sliding_window > 0 and cfg.n_window_layers > 0
+    state = cfg.state_planes()
+    assert isinstance(state, tuple)
+    assert all(isinstance(pl, StatePlane) for pl in state)
+    assert bool(state) == (family in ("hybrid", "gdn"))
+    own = cfg.stack()
+    assert (own is None) == (family in ("llama", "moe"))
+    if own is not None:
+        assert callable(own.layers_paged) and callable(own.lm_head)
+        assert callable(own.init_cache)
+    assert 0 < cfg.prefill_layers() <= cfg.n_layers
+    assert (cfg.prefill_layers() < cfg.n_layers) == (family == "hybrid")
+
+
+@pytest.mark.parametrize("module", ["engine", "generate"])
+def test_the_engine_and_generate_name_no_family_class(module):
+    """The seam stays closed: neither file imports `hybrid`, `mla` or
+    `gdn`, or names one of their config classes, outside comments and
+    docstrings."""
+    import ast
+    import ray_tpu.models as models
+
+    path = os.path.join(os.path.dirname(models.__file__), module + ".py")
+    classes = {"HybridConfig", "MlaConfig", "GdnConfig"}
+    modules = {"hybrid", "mla", "gdn"}
+    for node in ast.walk(ast.parse(open(path).read())):
+        if isinstance(node, ast.ImportFrom):
+            assert node.module.split(".")[-1] not in modules, node.lineno
+        if isinstance(node, (ast.Import, ast.ImportFrom)):
+            for a in node.names:
+                assert a.name.split(".")[-1] not in classes | modules, \
+                    node.lineno
+        if isinstance(node, ast.Name):
+            assert node.id not in classes, node.lineno
+        if isinstance(node, ast.Attribute):
+            assert node.attr not in classes, node.lineno
+    assert "_hybrid" not in open(path).read()
 
 
 # -- held experts, the gated shared one, the router ---------------------------

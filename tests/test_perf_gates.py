@@ -13,8 +13,9 @@ median of 3 trials.
 
 Ambient-load skip (same posture as the stress tier's budgets): a
 loaded box cannot attest a floor, so each gate first waits briefly for
-quiesce and SKIPS (visibly, with the load it saw) if the machine never
-settles — a skip is "could not measure", never "passed".
+quiesce (not at all as an xdist worker, whose siblings are the load) and
+SKIPS (visibly, with the load it saw) if the machine never settles — a
+skip is "could not measure", never "passed".
 """
 
 import os
@@ -31,15 +32,19 @@ QUIESCE_WAIT_S = 120.0
 
 
 def _quiesce_or_skip():
-    deadline = time.monotonic() + QUIESCE_WAIT_S
-    load = 0.0
-    while time.monotonic() < deadline:
+    # An xdist worker's siblings ARE the load and run for as long as it
+    # does: waiting buys nothing there, so it reads the load once.
+    wait = 0.0 if "PYTEST_XDIST_WORKER" in os.environ else QUIESCE_WAIT_S
+    deadline = time.monotonic() + wait
+    while True:
         try:
             load = os.getloadavg()[0]
         except OSError:
             return
         if load < LOAD_THRESHOLD:
             return
+        if time.monotonic() >= deadline:
+            break
         time.sleep(5.0)
     pytest.skip(f"box never quiesced (1-min load {load:.1f} >= "
                 f"{LOAD_THRESHOLD}); perf floors need a quiet box")

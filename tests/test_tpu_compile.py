@@ -567,7 +567,8 @@ def _hybrid_program(v5e, program):
     blocks, a window pool of 1,353, recurrent state for every slot):
     `_decode_multi_paged` at horizon 8 or `_prefill_rows_paged` for 4 x 512
     tokens, compiled for the described chip with the kernel selected."""
-    from ray_tpu.models import HybridConfig, engine, hybrid, hybrid_init
+    from ray_tpu.models import HybridConfig, engine, hybrid_init
+    from ray_tpu.models.block_pool import zero_state_planes
 
     cfg = HybridConfig.phi4_mini_flash(max_seq_len=5120)
     B, T, MB = 64, 32, 160
@@ -580,7 +581,10 @@ def _hybrid_program(v5e, program):
                             jax.eval_shape(fn))
 
     params = shapes(lambda: hybrid_init(jax.random.PRNGKey(0), cfg))
-    hyb = shapes(lambda: hybrid.zero_state(cfg, B, 1353, T))
+    hyb = shapes(lambda: {
+        **{pl.name: jnp.zeros((pl.layers, 1353, T, pl.lanes), pl.dtype)
+           for pl in cfg.cache_planes() if pl.table == "window"},
+        **zero_state_planes(cfg.state_planes(), B)})
     pool = arg((1, 8193, T, 1280), jnp.bfloat16)
     logits = arg((B, cfg.vocab_size), jnp.float32)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):

@@ -95,7 +95,8 @@ for L in lens:
         bt_dev = eng._table_snapshot(eng._bt)
         # a hybrid engine's second table, and its recurrent state, which
         # the program is given to keep (donated) like the pools
-        btw_dev = eng._table_snapshot(eng._bt_w) if eng._hybrid else None
+        btw_dev = eng._table_snapshot(eng._bt_w) \
+            if eng.kv_pool_w is not None else None
         fixed = (jnp.asarray(eng._row_keys), jnp.asarray(eng._row_greedy),
                  eng.temperature, eng.cfg, H, bool(eng._row_greedy.all()),
                  eng.top_k, eng.top_p, eng.eos_id)

@@ -88,7 +88,8 @@ for S in starts_at:
             last_idx=jnp.full((n_pad,), chunk - 1, jnp.int32), cfg=eng.cfg,
             shardings=eng._shardings, qspec=eng.kv_quant_spec,
             moe_ctr=eng._moe_ctr,
-            bt_w=jnp.asarray(eng._bt_w[rows]) if eng._hybrid else None)
+            bt_w=jnp.asarray(eng._bt_w[rows])
+            if eng.kv_pool_w is not None else None)
         state = (eng._pool_k, eng._pool_v, eng._scale_k, eng._scale_v,
                  eng._last_logits, eng._hyb)
 
