@@ -131,7 +131,11 @@ class ServedConfig(Protocol):
                     counters count (None: none are traced)
 
         -> (h [B, S, d] or [B, 1, d] with ``last_idx``, pool_a, pool_b,
-        expert-layer counts or None, state or None)."""
+        expert-layer counts or None, state or None).
+
+        A stack whose decode token updates recurrent state through a
+        kernel of its own also has `state_step_kernel(cfg) -> bool`, which
+        says when: the engine counts such decode blocks."""
 
     def prefill_layers(self) -> int:
         """Layers that see every prompt token: fewer than `n_layers` where

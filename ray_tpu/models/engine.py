@@ -1527,6 +1527,8 @@ class DecodeEngine:
         self.decode_dispatches_chained_queued = 0  # ... a request queued
         self.moe_hit_kernel_decode_dispatches = 0  # fused decode blocks
         #    whose HELD experts went through `ops.hit_experts`'s kernel
+        self.state_kernel_decode_dispatches = 0    # ... whose recurrent
+        #    state was updated in place by the stack's own kernel
         self.prefill_dispatches = 0    # batched prefill launches
         self.prefill_dispatches_ahead = 0   # ... launched before the
         #                                step's decode block was pulled
@@ -2467,6 +2469,11 @@ class DecodeEngine:
             self._count_decode_dispatch(chain)
             self.moe_hit_kernel_decode_dispatches += \
                 held_hit_kernel(self.cfg, self.B)
+            # a stack that has a kernel for its one-token update says
+            # when a decode program takes it (`state_step_kernel(cfg)`)
+            in_place = getattr(self.cfg.stack(), "state_step_kernel", None)
+            self.state_kernel_decode_dispatches += \
+                bool(in_place and in_place(self.cfg))
             self.metrics.on_dispatch(H, host_syncs=0)
 
     def _count_decode_dispatch(self, chain: Optional[tuple]) -> None:
@@ -2780,6 +2787,8 @@ class DecodeEngine:
             self.moe_grouped_prefill_dispatches)
         out["moe_hit_kernel_decode_dispatches_total"] = float(
             self.moe_hit_kernel_decode_dispatches)
+        out["state_kernel_decode_dispatches_total"] = float(
+            self.state_kernel_decode_dispatches)
         out["host_syncs"] = float(self.host_syncs)
         out["host_syncs_per_token"] = _ratio(self.host_syncs,
                                              self.tokens_out)

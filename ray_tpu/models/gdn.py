@@ -29,9 +29,10 @@ head, ``q`` scaled by ``dk ** -0.5``, a key head serving ``value_heads /
 key_heads`` consecutive value heads; ``beta = sigmoid(b)``, ``g = -exp(A_log)
 * softplus(a + dt_bias)`` in float32 a value head. The recurrence is
 `ops.gated_delta`'s: its chunkwise form over a prefill chunk, its one-token
-update for a decode token. Output ``W_o (RMSNorm_head(o) * w * silu(z))``.
-A row keeps, a delta layer, the state ``[value_heads, dk, dv]`` float32
-and the conv's last ``conv_kernel - 1`` inputs.
+update for a decode token (on the chip one kernel pass in place on the
+state plane: `state_step_kernel`). Output ``W_o (RMSNorm_head(o) * w *
+silu(z))``. A row keeps, a delta layer, the state ``[value_heads, dk,
+dv]`` float32 and the conv's last ``conv_kernel - 1`` inputs.
 
 Gated attention (`_attention`). ``q_proj`` gives a head's query AND its
 output gate; q and k get a zero-centred RMSNorm a head, rotary on the
@@ -62,7 +63,9 @@ from ray_tpu.models.hybrid import (STATE_REFUSALS, LayerKind, Segment,
 from ray_tpu.models.moe import EXPERT_STACKS, moe_ffn_dropless
 from ray_tpu.ops import scope_names as sn
 from ray_tpu.ops.attention import paged_attention
-from ray_tpu.ops.gated_delta import delta_chunks, delta_step, l2norm
+from ray_tpu.ops.gated_delta import (delta_chunks, delta_step,
+                                     delta_step_heads, delta_step_plane,
+                                     l2norm, live_rows)
 
 Params = Dict[str, Any]
 
@@ -396,14 +399,28 @@ def _rope(x, positions, cfg: GdnConfig):
         axis=-1).astype(x.dtype)
 
 
-def delta_mixer(a, p, s0, conv0, live, cfg: GdnConfig):
+def state_step_kernel(cfg: GdnConfig) -> bool:
+    """Whether a decode token's one-token update goes through the kernel
+    that works in place on the state plane (`ops.gated_delta.
+    delta_step_plane`): on the chip, at head shapes that are whole float32
+    tiles and a block of heads that fits the default scoped VMEM. Anywhere
+    else `delta_step` on the layer's slice, the plain form. Said from the
+    platform and the config: the engine counts such decode blocks."""
+    return jax.default_backend() == "tpu" and delta_step_heads(
+        cfg.value_heads, cfg.key_head_dim, cfg.value_head_dim) is not None
+
+
+def delta_mixer(a, p, s0, conv0, live, cfg: GdnConfig, *, in_plane=None):
     """A Gated DeltaNet mixer over a chunk. ``a`` [B, S, d] the normed
     input; ``s0`` [B, Hv, dk, dv] float32 and ``conv0`` [B, dc-1, conv_dim]
     the state the chunk starts from; ``live`` [B, S] bool, a PREFIX of each
     row (bucket filler, frozen and dead rows are not live): only live
     positions advance the state. Returns (mixer output [B, S, d], s1,
     conv1). S == 1 (a decode token) is `delta_step`, a chunk is
-    `delta_chunks`."""
+    `delta_chunks`. With ``in_plane`` = (layer, `live_rows` of the token),
+    S == 1, ``s0`` is the whole state plane [layers, B, Hv, dk, dv] and so
+    is s1: `delta_step_plane` updates the layer's live rows where they
+    lie."""
     dt_ = cfg.dtype
     B, S, _ = a.shape
     Hk, Hv, dk, dv = (cfg.key_heads, cfg.value_heads, cfg.key_head_dim,
@@ -435,8 +452,12 @@ def delta_mixer(a, p, s0, conv0, live, cfg: GdnConfig):
         q = jnp.repeat(q, Hv // Hk, axis=2)
         k = jnp.repeat(k, Hv // Hk, axis=2)
     if S == 1:
-        o, s1 = delta_step(s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                           beta[:, 0], live[:, 0])
+        step = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], live[:, 0])
+        if in_plane is None:
+            o, s1 = delta_step(s0, *step)
+        else:
+            o, s1 = delta_step_plane(s0, in_plane[0], *step,
+                                     walk=in_plane[1])
         o = o[:, None]
     else:
         o, s1 = delta_chunks(s0, q, k, v, g, beta, live, dt_)
@@ -500,6 +521,13 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
     # a row (a padding row repeats a real one and writes the same again),
     # never a layer's ``[slots, ...]`` of it.
     n_slots = state["delta"].shape[1]
+    # A decode token on the chip hands the mixer the matrix-state plane
+    # itself, with the layer's index and the token's walk over the live
+    # rows: the kernel reads and writes a live row's state where it lies.
+    in_place = rows is None and S == 1 and state_step_kernel(cfg)
+    if in_place:
+        with jax.named_scope(rule):
+            walk = live_rows(live[:, 0])
 
     def rows_of(x, pi, zero):
         flat = x.reshape(-1, *x.shape[2:])
@@ -516,7 +544,12 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
 
     def read_state(sd, sc, pi):
         with jax.named_scope(rule):
-            s0 = sd[pi] if rows is None else rows_of(sd, pi, 0.0)
+            if in_place:
+                s0 = sd
+            elif rows is None:
+                s0 = sd[pi]
+            else:
+                s0 = rows_of(sd, pi, 0.0)
         with jax.named_scope(sn.GDN_CONV):
             c0 = sc[pi] if rows is None else rows_of(
                 sc, pi, jnp.zeros((), sc.dtype))
@@ -524,7 +557,12 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
 
     def write_state(sd, sc, pi, s1, c1):
         with jax.named_scope(rule):
-            sd = sd.at[pi].set(s1) if rows is None else rows_into(sd, pi, s1)
+            if in_place:
+                sd = s1
+            elif rows is None:
+                sd = sd.at[pi].set(s1)
+            else:
+                sd = rows_into(sd, pi, s1)
         with jax.named_scope(sn.GDN_CONV):
             sc = sc.at[pi].set(c1) if rows is None else rows_into(sc, pi, c1)
         return sd, sc
@@ -550,7 +588,9 @@ def layers_paged(params: Params, toks, pool_k, pool_v, bt, starts,
         h, sd, sc = carry
         p, pm, pi, li = xs
         a = _rmsnorm1p(h, p["norm"], cfg.norm_eps)
-        out, s1, c1 = delta_mixer(a, p, *read_state(sd, sc, pi), live, cfg)
+        out, s1, c1 = delta_mixer(
+            a, p, *read_state(sd, sc, pi), live, cfg,
+            in_plane=(pi, walk) if in_place else None)
         sd, sc = write_state(sd, sc, pi, s1, c1)
         h, st = expert_layer(h + out, pm, li)
         return (h, sd, sc), st

@@ -1,4 +1,4 @@
-"""The gated delta rule (Gated DeltaNet's recurrence), in the two forms a
+"""The gated delta rule (Gated DeltaNet's recurrence), in the forms a
 serving engine needs.
 
 A head keeps a matrix state ``S`` ``[dk, dv]`` float32. A token with key
@@ -10,7 +10,18 @@ log-decay ``g <= 0`` and write strength ``beta`` in (0, 1) does
 `delta_step` is that, for one token a row (a decode step): two reductions
 over the state as it was (``S^T k`` and ``S^T q``; ``o`` follows from them
 without a second look at the new state: ``o = exp(g) S^T q + (k . q) d``)
-and one pass that writes the new state.
+and one pass that writes the new state. It is the plain form: what the
+CPU runs, what a one-token chunk of a prefill group takes, what the kernel
+is tested against.
+
+`delta_step_plane` is the same update as ONE pass, in place on the state
+plane ``[layers, slots, H, dk, dv]`` (Pallas/Mosaic), for a decode token on
+the chip: a grid step brings one live row's block of heads into VMEM once,
+takes both reductions from it there and writes ``exp(g) S + k d^T`` back
+to the same place (the plane is an aliased input and output), where XLA's
+`delta_step` on ``plane[layer]`` reads a row's state twice and puts the
+layer back through a slice (PERF.md PR 49). The work is bytes: a row's
+2 MiB read and written once a layer is the floor.
 
 `delta_chunks` is the chunkwise form for a prefill chunk (the published
 algorithm: Yang et al., "Gated Delta Networks", and the WY representation
@@ -46,8 +57,14 @@ the triangular inverse runs at the highest precision.
 
 from __future__ import annotations
 
+import functools
+import math
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import scope_names as sn
 
@@ -76,6 +93,198 @@ def delta_step(state, q, k, v, g, beta, live):
         o = decay * qs + jnp.sum(k * q, axis=-1, keepdims=True) * d
         new = decay[..., None] * state + k[..., :, None] * d[..., None, :]
         return o, jnp.where(live[:, None, None, None], new, state)
+
+
+# Heads of one row a grid step of `delta_step_plane`. The step's state
+# block goes in and out, each double-buffered: 4 x heads x dk x dv x 4 B,
+# 8 MiB at the published 32 heads of 128 x 128, inside the default scoped
+# 16 MiB (no `vmem_limit_bytes`: PERF.md PR 30). Measured on a v5e, 64
+# live rows, one layer of a 6-layer plane (PR 49, PERF.md section 6), ms a
+# layer-call: 8 heads a step 0.502, 16 0.449, 32 0.438, so a whole row is
+# asked for (XLA's `delta_step` on the layer's slice: 0.609). The DMAs
+# bound it: the same blocks copied in and out with no arithmetic take
+# 0.418, which is what this HBM gives a read and a write side by side
+# (727 GB/s reading alone, 627 writing alone, 0.399 one after the other).
+# The VPU's work a head (two sums over dk, the columns' lane broadcasts,
+# the rank-one update) just hides under the head's DMA: a third sum a
+# head (k . q taken in the kernel) cost 16 %.
+_STEP_HEADS = 32
+# Heads a trip of the kernel's loop over a block's heads (the body is
+# traced once a trip's heads: 32 heads unrolled cost the cell 0.9 s of
+# tracing and lowering in its four decode programs). Mosaic unrolls a loop
+# whole or not at all, so a trip's heads are written out: the same call,
+# ms a layer-call, 1 head a trip 0.513 (the VPU's chain a head is exposed),
+# 2 0.453, 4 0.440, 8 0.438, all 32 unrolled 0.440.
+_TRIP_HEADS = 8
+_VMEM_BUDGET = 16 * 2 ** 20
+
+
+def delta_step_heads(heads: int, dk: int, dv: int) -> Optional[int]:
+    """The heads a grid step of `delta_step_plane` takes: the largest
+    divisor of ``heads`` up to `_STEP_HEADS` that is whole tiles of the
+    per-head rows (a multiple of 8 sublanes, or all the heads) and whose
+    blocks fit the default scoped VMEM, or None where a head's ``dk x dv``
+    is not whole float32 tiles or nothing fits."""
+    if dk % 8 or dv % 128:
+        return None
+    for hb in range(min(_STEP_HEADS, heads), 0, -1):
+        if heads % hb or (hb % 8 and hb != heads):
+            continue
+        held = (4 * hb * dk * dv * 4              # state in and out, twice
+                + 2 * dk * (2 * heads + -2 * heads % 128) * 4     # columns
+                + 2 * 2 * (hb + -hb % 8) * dv * 4     # v in, o out
+                + 3 * _TRIP_HEADS * dk * dv * 4)  # a trip's temporaries
+        if held <= _VMEM_BUDGET:
+            return hb
+    return None
+
+
+def live_rows(live):
+    """``live`` [B] bool -> (the live rows' ids first, in order, [B] int32;
+    how many, int32): what `delta_step_plane`'s grid walks. Ids past the
+    count are 0. A one-hot select and a sum: no sort, no scatter."""
+    b = live.shape[0]
+    place = jnp.cumsum(live, dtype=jnp.int32) - 1
+    at = live[None, :] & (place[None, :] == jnp.arange(b)[:, None])
+    ids = jnp.sum(jnp.where(at, jnp.arange(b, dtype=jnp.int32)[None, :], 0),
+                  axis=1)
+    return ids, live.sum(dtype=jnp.int32)
+
+
+def _step_kernel(layer_ref, rows_ref, n_ref, c_ref, s_ref, col_ref, v_ref,
+                 out_ref, o_ref, *, hb: int, heads: int):
+    del layer_ref                          # the index maps read it
+    i, j = pl.program_id(0), pl.program_id(1)
+    n = n_ref[0]
+    lanes = col_ref.shape[2]
+
+    # no live row: the one block every step maps to goes back as it came
+    @pl.when((n == 0) & (i == 0) & (j == 0))
+    def _():
+        out_ref[...] = s_ref[...]
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    def head(hl, scalars):
+        """Head ``hl`` of this step's block (a traced index: the loop's body
+        is traced once). What meets the state's SUBLANE axis is a column
+        ``[dk, 1]`` that broadcasts along the lanes: a head's key and query
+        lie a lane a head in ``col`` [dk, 2 H ..], and a lane roll by the
+        head's index brings them to lanes 0 and H. What scales a row
+        ``[1, dv]`` or the whole block is a scalar from SMEM."""
+        h = j * hb + hl
+        s = s_ref[0, 0, hl]                                   # [dk, dv]
+        decay, beta, kq = (c_ref[scalars + r * heads + h] for r in range(3))
+        cols = pltpu.roll(col_ref[0], lanes - h, 1)
+        kc, qc = cols[:, 0:1], cols[:, heads:heads + 1]       # [dk, 1]
+        # both reductions over dk from the block as it lies in VMEM
+        ks = jnp.sum(kc * s, axis=0, keepdims=True)           # [1, dv]
+        qs = jnp.sum(qc * s, axis=0, keepdims=True)
+        d = beta * (v_ref[0, pl.ds(hl, 1), :] - decay * ks)
+        o_ref[0, pl.ds(hl, 1), :] = decay * qs + kq * d
+        out_ref[0, 0, hl] = decay * s + kc * d
+
+    # a step past the live rows holds the last live block and does nothing
+    @pl.when(i < n)
+    def _():
+        scalars = rows_ref[i] * (3 * heads)
+        trip = math.gcd(hb, _TRIP_HEADS)
+
+        def some(t, carry):
+            for u in range(trip):
+                head(t * trip + u, scalars)
+            return carry
+
+        jax.lax.fori_loop(0, hb // trip, some, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "hb"))
+def _step_call(plane, layer, rows, n_live, q, k, v, g, beta, *,
+               interpret: bool, hb: int):
+    _, b, hv, dk, dv = plane.shape
+    n_blocks = hv // hb
+    f32 = jnp.float32
+    # a head's three scalars: exp(g), beta, k . q, taken here as
+    # `delta_step` takes them (the kernel's VPU has no room for a third
+    # sum a head: PERF.md PR 49), prefetched into SMEM: [B, 3, H] flat
+    c = jnp.stack([jnp.exp(g), beta, jnp.sum(k * q, axis=-1)],
+                  axis=1).astype(f32).reshape(-1)
+    # a head's key and query as columns, a lane a head: [B, dk, 2 H] padded
+    # to whole lane tiles, 64 KiB a row at the published widths (``[..,
+    # dk, 1]`` in HBM would be padded 128-fold by the tiling)
+    col = jnp.concatenate([jnp.swapaxes(k.astype(f32), 1, 2),
+                           jnp.swapaxes(q.astype(f32), 1, 2)], axis=2)
+    col = jnp.pad(col, ((0, 0), (0, 0), (0, -2 * hv % 128)))
+
+    def row(i, rows, n):
+        return rows[jnp.minimum(i, jnp.maximum(n[0] - 1, 0))]
+
+    def block(i, j, n):
+        return jnp.where(i < n[0], j, n_blocks - 1)
+
+    def state_map(i, j, layer, rows, n, c):
+        return (layer[0], row(i, rows, n), block(i, j, n), 0, 0)
+
+    def row_map(i, j, layer, rows, n, c):
+        return (row(i, rows, n), 0, 0)
+
+    def heads_map(i, j, layer, rows, n, c):
+        return (row(i, rows, n), block(i, j, n), 0)
+
+    state_spec = pl.BlockSpec((1, 1, hb, dk, dv), state_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(b, n_blocks),
+        in_specs=[state_spec,
+                  pl.BlockSpec((1, dk, col.shape[2]), row_map),
+                  pl.BlockSpec((1, hb, dv), heads_map)],
+        out_specs=[state_spec, pl.BlockSpec((1, hb, dv), heads_map)])
+    new, o = pl.pallas_call(
+        functools.partial(_step_kernel, hb=hb, heads=hv),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(plane.shape, f32),
+                   jax.ShapeDtypeStruct((b, hv, dv), f32)],
+        # operand 4 (after the four prefetched into SMEM) is the plane, and
+        # it is output 0: updated where it lies
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name=sn.DELTA_STEP_KERNEL,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
+      jnp.reshape(n_live, (1,)).astype(jnp.int32), c, plane, col,
+      v.astype(f32))
+    return o, new
+
+
+def delta_step_plane(plane, layer, q, k, v, g, beta, live, *, walk=None,
+                     interpret: Optional[bool] = None,
+                     hb: Optional[int] = None):
+    """`delta_step` on ``plane[layer]``, in place. ``plane`` [L, B, H, dk,
+    dv] float32, the state of all layers and slots (inside a program that
+    owns it the result takes its place: no copy); ``layer`` int32; ``q``,
+    ``k``, ``v``, ``g``, ``beta``, ``live`` as `delta_step` takes them;
+    ``walk`` is `live_rows(live)`, for a caller whose layers share it.
+    Returns (o [B, H, dv] float32, the plane). Only the live rows' blocks
+    of layer ``layer`` are read or written: everything else of the plane,
+    a dead row's state included, stays bit for bit; a dead row's ``o`` is
+    0 (`delta_step` reads its state for one; nothing reads it).
+
+    Grid ``(B, H / hb)`` over the live rows first: step (i, j) holds the
+    block ``(layer, rows[i], j)``; steps past the count map to the LAST
+    live block, row and head block both, so they fetch nothing, write
+    nothing and the block the last live step wrote goes back once, as
+    written. Float32 throughout, multiplies and adds on the VPU (no MXU
+    product: its operands would be rounded or its latency paid a head):
+    against `delta_step` only the order of the two sums over ``dk``
+    differs. ``interpret=None`` resolves to True off the TPU; ``hb`` None
+    is `delta_step_heads`'s."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    _, _, hv, dk, dv = plane.shape
+    hb = hb or delta_step_heads(hv, dk, dv)
+    with jax.named_scope(sn.GDN_STEP):
+        rows, n_live = live_rows(live) if walk is None else walk
+        o, new = _step_call(plane, layer, rows, n_live, q, k, v, g, beta,
+                            interpret=bool(interpret), hb=hb)
+        return jnp.where(live[:, None, None], o, 0.0), new
 
 
 def _unit_lower_inverse(a):

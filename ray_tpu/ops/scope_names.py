@@ -78,8 +78,9 @@ SPARSE_LATENT_KERNEL = "sparse_latent_attention"
 SPARSE_LATENT_DECODE_KERNEL = "sparse_latent_decode"
 HIT_EXPERTS_KERNEL = "moe_hit_experts"
 HELD_GROUPED_KERNEL = "moe_held_grouped"
+DELTA_STEP_KERNEL = "gdn_delta_step"     # under the scope `gdn_step`
 
 KERNELS = (PAGED_KERNEL, FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV,
            SPARSE_LATENT_KERNEL, SPARSE_LATENT_DECODE_KERNEL,
-           HIT_EXPERTS_KERNEL, HELD_GROUPED_KERNEL)
+           HIT_EXPERTS_KERNEL, HELD_GROUPED_KERNEL, DELTA_STEP_KERNEL)
 

@@ -202,6 +202,94 @@ def test_one_token_update_is_the_published_recurrence():
     np.testing.assert_array_equal(s1[1], s0[1])      # a dead row stays
 
 
+# -- the one-token update in place on the plane (the kernel, interpreted) -------
+
+def _plane_inputs(L, B, H, dk, dv, seed):
+    s0, q, k, v, g, beta = _rule_inputs(B, L, H, dk, dv, seed=seed)
+    plane = jax.random.normal(jax.random.PRNGKey(seed + 50),
+                              (L, B, H, dk, dv), jnp.float32)
+    # [B, L, ...] -> a layer's operands first
+    return plane, tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+
+
+_LIVE = {"all": (True,) * 5, "some": (False, True, True, False, True),
+         "last": (False,) * 4 + (True,), "none": (False,) * 5}
+
+
+@pytest.mark.parametrize("hb", [8, 16])
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("live", list(_LIVE))
+def test_the_step_kernel_is_delta_step_on_the_planes_layer(live, layer, hb):
+    """`delta_step_plane` (interpreted) against `delta_step` on the
+    layer's slice: the live rows' state and output within 1e-6 of the
+    state's largest value (the two sums over dk are taken in another
+    order; everything else is the same float32 multiplies and adds), every
+    dead row's state and every other layer of the plane bit for bit, with
+    all rows live, some, the last alone and none, on a first and a last
+    layer, at two head blocks (one and two grid steps a row)."""
+    plane, xs = _plane_inputs(3, 5, 16, 16, 128, seed=3 * layer + hb)
+    lv = jnp.asarray(_LIVE[live])
+    step = tuple(x[layer] for x in xs)
+    o, new = gd.delta_step_plane(plane, jnp.int32(layer), *step, lv, hb=hb)
+    o_want, s_want = gd.delta_step(plane[layer], *step, lv)
+    tol = 1e-6 * float(jnp.abs(plane).max())
+    m = np.asarray(lv)
+    np.testing.assert_allclose(new[layer], s_want, atol=tol, rtol=0)
+    np.testing.assert_allclose(np.asarray(o)[m], np.asarray(o_want)[m],
+                               atol=tol, rtol=0)
+    np.testing.assert_array_equal(np.asarray(o)[~m], 0.0)
+    np.testing.assert_array_equal(np.asarray(new[layer])[~m],
+                                  np.asarray(plane[layer])[~m])
+    others = [i for i in range(3) if i != layer]
+    np.testing.assert_array_equal(np.asarray(new)[others],
+                                  np.asarray(plane)[others])
+
+
+@pytest.mark.parametrize("hb", [8, None], ids=["hb8", "all_heads"])
+def test_two_tokens_through_the_step_kernel_are_two_delta_steps(hb):
+    """The fused horizon: a second token reads what the first wrote where
+    it lies, a row that died between them stays as the first left it, and
+    the walk over the live rows may be handed in (`live_rows`)."""
+    plane, xs = _plane_inputs(2, 4, 8, 16, 128, seed=11)
+    lives = [jnp.asarray([True, True, False, True]),
+             jnp.asarray([True, False, False, True])]
+    got, want = plane, plane[1]
+    for t, lv in enumerate(lives):
+        step = tuple(jnp.roll(x[1], t, axis=0) for x in xs)
+        o, got = gd.delta_step_plane(got, jnp.int32(1), *step, lv, hb=hb,
+                                     walk=gd.live_rows(lv))
+        o_want, want = gd.delta_step(want, *step, lv)
+        np.testing.assert_allclose(np.asarray(o)[np.asarray(lv)],
+                                   np.asarray(o_want)[np.asarray(lv)],
+                                   atol=5e-6, rtol=0)
+    np.testing.assert_allclose(got[1], want, atol=5e-6, rtol=0)
+    np.testing.assert_array_equal(got[1, 2], plane[1, 2])
+    np.testing.assert_array_equal(got[0], plane[0])
+
+
+@pytest.mark.parametrize("heads,dk,dv,hb", [
+    (32, 128, 128, 32), (16, 128, 128, 16), (48, 128, 128, 24),
+    (4, 16, 128, 4), (12, 16, 128, 12), (4, 16, 16, None),
+    (4, 12, 128, None)])
+def test_the_step_kernels_head_block_follows_from_the_shapes(heads, dk, dv,
+                                                             hb):
+    """The published 32 heads of 128 x 128 go a whole row a step (8 MiB of
+    the default scoped 16 for the state's blocks in and out, twice each);
+    a block is whole sublane tiles of the per-head rows or all the heads;
+    a head that is not whole float32 tiles has no kernel."""
+    assert gd.delta_step_heads(heads, dk, dv) == hb
+    if hb is not None:
+        assert heads % hb == 0 and (hb % 8 == 0 or hb == heads)
+
+
+def test_live_rows_walks_the_live_rows_first_in_order():
+    lv = jnp.asarray([False, True, True, False, True, False])
+    rows, n = gd.live_rows(lv)
+    assert int(n) == 3 and rows.tolist() == [1, 2, 4, 0, 0, 0]
+    rows, n = gd.live_rows(jnp.zeros(4, bool))
+    assert int(n) == 0 and rows.tolist() == [0, 0, 0, 0]
+
+
 # -- the engine against the reference, logits -------------------------------
 
 @pytest.mark.parametrize("n_prompt,n_new", [
@@ -680,3 +768,133 @@ def test_grouped_prefill_dispatches_are_counted_where_the_kernel_runs(family):
         # what the kernel multiplied: 128-row visits, not whole windows
         assert st["moe_rows_computed_total"] \
             >= st["moe_assignments_landed_total"] > 0
+
+
+# -- the one-token kernel: which program takes it, and who counts it ---------------
+
+# the nano widths with a value head of a whole lane tile: what the kernel
+# takes (16 x 128 float32 a head)
+KCFG = nano_gdn(held_experts=(0, 4), value_head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def kparams():
+    return jax.jit(gdn_init, static_argnums=1)(jax.random.PRNGKey(0), KCFG)
+
+
+@pytest.fixture
+def through_the_step_kernel(monkeypatch):
+    """The decode branch as the chip takes it, the kernel interpreted: the
+    predicate answers for the shapes alone."""
+    monkeypatch.setattr(
+        gdn, "state_step_kernel", lambda cfg: gd.delta_step_heads(
+            cfg.value_heads, cfg.key_head_dim, cfg.value_head_dim)
+        is not None)
+
+
+def test_off_the_chip_a_decode_token_takes_the_plain_form():
+    """`state_step_kernel` is said from the platform and the shapes: the
+    CPU runs `delta_step` whatever the shapes."""
+    assert gd.delta_step_heads(KCFG.value_heads, KCFG.key_head_dim,
+                               KCFG.value_head_dim) == 4
+    assert not gdn.state_step_kernel(KCFG)
+    assert not gdn.state_step_kernel(CFG)
+
+
+@pytest.mark.parametrize("forced", [False, True], ids=["plain", "kernel"])
+def test_the_decode_program_holds_the_step_kernel_where_the_predicate_says(
+        kparams, forced, monkeypatch):
+    """One call of the kernel in the decode program's delta layer body
+    (the scan traces one) where the predicate holds and none where it
+    does not; a prefill group's programs (a one-token chunk too) never
+    take it."""
+    from ray_tpu.ops import scope_names as sn
+    if forced:
+        monkeypatch.setattr(gdn, "state_step_kernel", lambda cfg: True)
+    B = 2
+    state = zero_state_planes(KCFG.state_planes(), B)
+    k, v = KCFG.cache_planes()
+    pool = jnp.zeros((k.layers, 9, T, k.lanes), jnp.float32)
+
+    def program(S, rows):
+        return jax.make_jaxpr(lambda st: gdn.layers_paged(
+            kparams, jnp.ones((B, S), jnp.int32), pool, pool,
+            jnp.zeros((B, 4), jnp.int32), jnp.full((B,), 3, jnp.int32),
+            KCFG, state=st, live=jnp.ones((B, S), bool), rows=rows))(state)
+
+    names = list(_kernel_names(program(1, None).jaxpr))
+    assert sum(sn.DELTA_STEP_KERNEL in n for n in names) == int(forced)
+    chunk = list(_kernel_names(program(1, jnp.arange(B)).jaxpr)) \
+        + list(_kernel_names(program(4, jnp.arange(B)).jaxpr))
+    assert not any(sn.DELTA_STEP_KERNEL in n for n in chunk)
+
+
+@pytest.mark.parametrize("n_prompt,n_new", [(5, 9), (37, 10)],
+                         ids=["short", "ragged"])
+def test_decode_through_the_step_kernel_gives_the_reference_logits(
+        kparams, through_the_step_kernel, n_prompt, n_new):
+    """`test_prefill_then_decode_gives_the_reference_logits` with every
+    decode token's state updated by the kernel, in place on the plane."""
+    model = model_of(KCFG)
+    eng = engine(kparams, KCFG)
+    prompt = prompt_of(n_prompt, seed=n_prompt)
+    toks, seen = served_logits(eng, prompt, n_new)
+    err, want = worst_error(kparams, prompt, toks, seen, model=model)
+    assert err <= TOL
+    st = eng.stats()
+    assert st["ssm_row_steps_total"] == n_new
+    assert st["state_kernel_decode_dispatches_total"] \
+        == st["decode_dispatches"] > 0
+
+
+def test_the_step_kernel_and_the_plain_form_serve_the_same_tokens(
+        kparams, monkeypatch):
+    """Two rows of unlike lengths through a fused horizon of 8 with a ring
+    two deep, rows finishing inside a block (dead for the rest of it): the
+    kernel's engine returns the plain form's tokens."""
+    work = [(prompt_of(12, seed=7), 19), (prompt_of(35, seed=8), 10),
+            (prompt_of(9, seed=9), 6)]
+
+    def served():
+        eng = engine(kparams, KCFG, decode_horizon=8, pipeline_depth=2)
+        ids = [eng.submit(p, max_new_tokens=m) for p, m in work]
+        out = eng.run()
+        return [out[r] for r in ids], eng.stats()
+
+    want, st = served()
+    assert st["state_kernel_decode_dispatches_total"] == 0
+    monkeypatch.setattr(gdn, "state_step_kernel", lambda cfg: True)
+    got, st = served()
+    assert got == want
+    assert st["state_kernel_decode_dispatches_total"] \
+        == st["decode_dispatches"] > 0
+
+
+@pytest.mark.parametrize("family", ["gdn", "hybrid", "dense"])
+def test_decode_blocks_through_a_stacks_own_state_kernel_are_counted(
+        family, monkeypatch):
+    """`state_kernel_decode_dispatches_total` counts the fused decode
+    blocks of a stack that says it takes its own kernel
+    (`state_step_kernel`): a `GdnConfig` where the predicate holds; a
+    `HybridConfig` (recurrent state, no such kernel) and a dense
+    `LlamaConfig` count nothing."""
+    from ray_tpu.models import LlamaConfig, hybrid_init, llama_init
+
+    cfg, init = {
+        "gdn": (nano_gdn(held_experts=(0, 4), n_layers=4,
+                         value_head_dim=128), gdn_init),
+        "hybrid": (HybridConfig.nano_hybrid(), hybrid_init),
+        "dense": (LlamaConfig.nano(), llama_init)}[family]
+    monkeypatch.setattr(gdn, "state_step_kernel", lambda cfg: True)
+    p = jax.jit(init, static_argnums=1)(jax.random.PRNGKey(1), cfg)
+    eng = DecodeEngine(p, cfg, batch_slots=2, max_len=64,
+                       kv_block_tokens=T, decode_horizon=2,
+                       preempt="recompute")
+    ids = [eng.submit(prompt_of(n, seed=n), max_new_tokens=5)
+           for n in (5, 9)]
+    out = eng.run()
+    assert all(len(out[i]) == 5 for i in ids)
+    st = eng.stats()
+    assert st["decode_dispatches"] > 1
+    assert st["state_kernel_decode_dispatches_total"] \
+        == (st["decode_dispatches"] if family == "gdn" else 0)

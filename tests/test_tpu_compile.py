@@ -698,6 +698,7 @@ def test_mla_cell_programs_fit_the_chip(v5e, program):
 
 # -- the delta-rule cell's programs ----------------------------------------------
 
+@functools.lru_cache(maxsize=None)
 def _gdn_program(v5e, program):
     """The `qwen3next-longctx` cell's engine as `DecodeEngine` builds it
     (64 slots, 132 table entries of 256 tokens, a 4 GiB K/V pool of the 2
@@ -769,6 +770,80 @@ def test_gdn_cell_programs_fit_the_chip(v5e, program):
     assert (HELD_GROUPED_KERNEL in text) == (program == "prefill")
     assert (HIT_EXPERTS_KERNEL in text) == (program == "decode")
     assert "ragged-dot" not in text and "ragged_dot" not in text
+
+
+def test_gdn_decode_program_updates_the_state_plane_in_place(v5e):
+    """The decode program's delta layers take the one-token kernel
+    (`ops.gated_delta.delta_step_plane`, one call in the delta layer's
+    scan body) on the 0.77 GiB state plane WHERE IT LIES, through the
+    carries of the horizon's, the periods' and the delta layers' scans:
+    the call's first result aliases its plane operand, nothing else in
+    the program produces a value of the plane's shape (no copy ahead of
+    the call), the temporaries stay under the plane's size, and no op
+    under `gdn_step` slices a layer's ``[64, 32, 128, 128]`` out of the
+    plane or puts one back. The prefill program keeps the chunk form and
+    `delta_step` on its rows: no such kernel."""
+    from ray_tpu.ops.scope_names import DELTA_STEP_KERNEL, GDN_STEP
+
+    compiled = _gdn_program(v5e, "decode")
+    text = compiled.as_text()
+    plane, layer = "f32[6,64,32,128,128]", "f32[64,32,128,128]"
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and f"%{DELTA_STEP_KERNEL}" in ln]
+    assert len(calls) == 1
+    call = calls[0]
+    assert plane in call.split(" custom-call(")[0]
+    # result 0 is operand 4: after the layer, the live rows, their count
+    # and the heads' scalars, all four prefetched into SMEM
+    assert "output_to_operand_aliasing={{0}: (4, {})}" in call
+    assert f"/{GDN_STEP}/" in call
+    makers = [ln for ln in text.splitlines()
+              if re.match(rf"\s*(ROOT )?%\S+ = {re.escape(plane)}", ln)
+              and " get-tuple-element(" not in ln
+              and " parameter(" not in ln]
+    assert makers == []
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 6 * 64 * 32 * 128 * 128 * 4 // 2
+    for ln in text.splitlines():
+        if f"/{GDN_STEP}/" in ln and re.search(
+                r"dynamic-(update-)?slice\(", ln):
+            assert layer not in ln and plane not in ln, ln[:200]
+    assert DELTA_STEP_KERNEL not in _gdn_program(v5e, "prefill").as_text()
+
+
+@pytest.mark.parametrize("hb", [8, 16, 32])
+def test_delta_step_kernel_compiles_at_the_cells_shapes(v5e, hb):
+    """The one-token kernel alone on the cell's plane (6 layers, 64 slots,
+    32 heads of 128 x 128 float32), at a whole row a step (what
+    `delta_step_heads` names: 8 MiB of state blocks) and at 16 and 8
+    heads, inside the default scoped VMEM; a donated plane comes back as
+    the call's own buffer. Four heads a step is no whole tile of the
+    per-head rows and is refused."""
+    from ray_tpu.ops import gated_delta as gd
+
+    L, B, H, dk, dv = 6, 64, 32, 128, 128
+    assert gd.delta_step_heads(H, dk, dv) == 32
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    args = (arg((L, B, H, dk, dv)), arg((), jnp.int32), arg((B, H, dk)),
+            arg((B, H, dk)), arg((B, H, dv)), arg((B, H)), arg((B, H)),
+            arg((B,), jnp.bool_))
+
+    def fn(hb, *a):
+        return gd.delta_step_plane(*a, interpret=False, hb=hb)
+
+    compiled = jax.jit(functools.partial(fn, hb), donate_argnums=0) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "vmem_limit" not in text
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == L * B * H * dk * dv * 4
+    assert m.temp_size_in_bytes < 4 << 20
+    if hb == 8:
+        with pytest.raises(Exception):
+            jax.jit(functools.partial(fn, 4)).lower(*args).compile()
 
 
 # -- the held experts' grouped kernel ---------------------------------------------
