@@ -1,11 +1,18 @@
 """Pallas TPU flash attention (forward AND backward kernels).
 
 Online-softmax tiling keeps the working set in VMEM and the score matmuls
-on the MXU; the kv-block grid axis iterates fastest so the (m, l, acc)
+on the MXU; the kv block of a pair changes fastest so the (m, l, acc)
 scratch accumulators persist across kv blocks for a fixed q block.
 Backward is flash-style recompute in Pallas under `jax.custom_vjp`:
 _bwd_dq_kernel (kv innermost) and _bwd_dkv_kernel (q innermost) re-derive
 p from the saved row logsumexp.
+
+A kernel's grid is `(b, h, pairs)`: the (q block, kv block) pairs of ONE
+static schedule (`pair_schedule`), built in Python from the shapes and the
+`causal` flag and handed to the kernel as a scalar-prefetch operand. The
+index maps read a pair's block numbers from it, so a block the causal mask
+rules out is neither a grid step nor a fetch, and the mask is built only in
+a pair the diagonal (or kv padding) touches.
 
 Semantics match `ray_tpu.ops.attention.mha_reference` exactly, including
 the kv-prefix causal offset when Sq != Sk (decode) and GQA. Sequence
@@ -15,11 +22,13 @@ columns are masked by global index, padded q rows are sliced off.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -31,17 +40,28 @@ DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
 
 
-def _attn_mask(i, j, block_q, block_k, q_offset, sk_orig, causal):
+def _mask_rule(qi, ki, sk_orig, causal, kv_padded=True):
+    """Which (global q row, global kv column) a score keeps. The ONE rule:
+    `_attn_mask` evaluates it on a pair's iotas, `pair_schedule` at a
+    pair's corner. `kv_padded` False (no zero-padded kv column exists)
+    leaves the column compare out: it is true by construction."""
+    mask = None
+    if kv_padded:
+        mask = ki < sk_orig  # zero-padded kv columns
+    if causal:
+        mask = (qi >= ki) if mask is None else mask & (qi >= ki)
+    return mask
+
+
+def _attn_mask(i, j, block_q, block_k, q_offset, sk_orig, causal,
+               kv_padded=True):
     """Single source of truth for the fwd AND bwd score mask (they must
     agree exactly or the backward's recomputed softmax diverges)."""
     qi = q_offset + i * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     ki = j * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    mask = ki < sk_orig  # zero-padded kv columns
-    if causal:
-        mask = mask & (qi >= ki)
-    return mask
+    return _mask_rule(qi, ki, sk_orig, causal, kv_padded)
 
 
 def _block_contributes(i, j, block_q, block_k, q_offset, causal):
@@ -52,26 +72,131 @@ def _block_contributes(i, j, block_q, block_k, q_offset, causal):
     return j * block_k <= q_offset + i * block_q + block_q - 1
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                sm_scale: float, causal: bool, block_q: int, block_k: int,
-                q_offset: int, sk_orig: int):
-    """q_offset = sk_orig - sq_orig (kv-prefix shift for decode);
-    sk_orig masks zero-padded kv columns."""
-    i = pl.program_id(2)  # q block
-    j = pl.program_id(3)  # kv block (fastest)
-    nk = pl.num_programs(3)
+# Rows of a schedule's `pairs` operand, and what a pair's KIND says.
+_OUTER, _INNER, _FIRST, _LAST, _KIND = range(5)
+_DEAD, _INTERIOR, _MASKED = range(3)
 
-    @pl.when(j == 0)
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class PairSchedule:
+    """The (outer block, inner block) pairs one kernel steps through, in
+    order: a (batch, head)'s whole grid. `first` / `last` bracket an outer
+    block's run (init / write its accumulators); `masked` says the pair
+    needs `_attn_mask` (the diagonal crosses it, or it holds padded kv
+    columns); `dead` marks the one pair an outer block with NO live inner
+    block keeps so that it is initialised and written (zeros): rows before
+    every key when sq > sk."""
+    pairs: np.ndarray           # int32 [5, pairs]: rows `_OUTER`..`_KIND`,
+    #                             the kernels' scalar-prefetch operand
+    rectangle: int              # pairs of the full nq x nk grid
+    kv_padded: bool             # a zero-padded kv column exists
+    # The grid axes behind (batch, head): `(pairs,)`, or, where the pairs
+    # ARE the rectangle in row-major order, its `(outer, inner)` blocks, so
+    # that an index map is the grid index and reads no table (a table read
+    # costs a step 0.06-0.09 us, PERF.md PR 50; nothing is skipped to pay
+    # for it).
+    grid: Tuple[int, ...]
+
+    outer = property(lambda self: self.pairs[_OUTER])
+    inner = property(lambda self: self.pairs[_INNER])
+    first = property(lambda self: self.pairs[_FIRST] == 1)
+    last = property(lambda self: self.pairs[_LAST] == 1)
+    masked = property(lambda self: self.pairs[_KIND] == _MASKED)
+    dead = property(lambda self: self.pairs[_KIND] == _DEAD)
+
+    def counts(self) -> Dict[str, int]:
+        return {"live": int((~self.dead).sum()),
+                "masked": int(self.masked.sum()),
+                "rectangle": self.rectangle}
+
+
+@functools.lru_cache(maxsize=None)
+def pair_schedule(kernel: str, sq: int, sk: int, block_q: int, block_k: int,
+                  causal: bool) -> PairSchedule:
+    """The static schedule of `kernel` (a `scope_names.FLASH_*`) for q of
+    `sq` rows against `sk` keys at these tiles (already clipped to the
+    lengths): every pair `_block_contributes` accepts, once, inner block
+    fastest. The forward and dq kernels run q blocks outermost, the dk/dv
+    kernel kv blocks: the order every output accumulates in is the full
+    rectangle's with its skipped steps left out."""
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
+    q_offset = sk - sq
+    kv_padded = nk * block_k != sk
+    q_outer = kernel != sn.FLASH_BWD_DKV
+    rows = []
+    for o in range(nq if q_outer else nk):
+        run = []
+        for n in range(nk if q_outer else nq):
+            i, j = (o, n) if q_outer else (n, o)
+            if not _block_contributes(i, j, block_q, block_k, q_offset,
+                                      causal):
+                continue
+            # The rule is monotone: if the pair's first row keeps its last
+            # column, every score of the pair is kept.
+            whole = _mask_rule(q_offset + i * block_q,
+                               j * block_k + block_k - 1, sk, causal)
+            run.append([o, n, 0, 0, _INTERIOR if whole else _MASKED])
+        run = run or [[o, 0, 0, 0, _DEAD]]
+        run[0][_FIRST] = run[-1][_LAST] = 1
+        rows += run
+    pairs = np.asarray(rows, np.int32).T
+    whole = len(rows) == nq * nk and _DEAD not in pairs[_KIND]
+    return PairSchedule(pairs, rectangle=nq * nk, kv_padded=kv_padded,
+                        grid=((nq, nk) if q_outer else (nk, nq)) if whole
+                        else (len(rows),))
+
+
+_PAIR_COUNTERS = {}
+
+
+def _count_pairs(kernel: str, sched: PairSchedule, programs: int) -> None:
+    """`flash_pairs_{live,masked,rectangle}_total`, labelled by kernel:
+    what a call built here steps through and what the full grid would have
+    held, counted once where the call is BUILT (at trace time). Imported
+    here: a process that builds no flash call never loads the registry."""
+    from ray_tpu.util.metrics import Counter
+
+    for what, n in sched.counts().items():
+        name = f"flash_pairs_{what}_total"
+        if name not in _PAIR_COUNTERS:
+            _PAIR_COUNTERS[name] = Counter(
+                name, f"(q block, kv block) pairs of flash calls built: "
+                f"{what}", tag_keys=("kernel",))
+        if n:
+            _PAIR_COUNTERS[name].inc(n * programs, tags={"kernel": kernel})
+
+
+def _pair(pairs_ref, rectangular):
+    """This grid step's (outer block, inner block, first, last, kind)."""
+    p = pl.program_id(2)
+    if rectangular:     # `PairSchedule.grid`: axes (outer, inner)
+        p = p * pl.num_programs(3) + pl.program_id(3)
+    return tuple(pairs_ref[r, p] for r in range(5))
+
+
+def _per_kind(kind, kinds, body):
+    """`body(masked)` for an interior pair and again, with the mask, for a
+    masked one, each only if the schedule holds such a pair (`kinds`); a
+    dead pair runs neither."""
+    for this in (_INTERIOR, _MASKED):
+        if this in kinds:
+            pl.when(kind == this)(functools.partial(body, this == _MASKED))
+
+
+def _fwd_kernel(pairs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                l_ref, *, sm_scale: float, kinds, rectangular, **mask_kw):
+    """One (q block, kv block) pair of `pair_schedule`, kv fastest.
+    `mask_kw` is `_attn_mask`'s: q_offset = sk_orig - sq_orig (kv-prefix
+    shift for decode); sk_orig masks zero-padded kv columns."""
+    i, j, first, last, kind = _pair(pairs_ref, rectangular)
+
+    @pl.when(first == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    should_compute = _block_contributes(i, j, block_q, block_k, q_offset,
-                                        causal)
-
-    @pl.when(should_compute)
-    def _body():
+    def _body(masked):
         # Matmul inputs keep their storage dtype: bf16 activations hit
         # the MXU's native bf16xbf16->f32 path (upcasting to f32 first
         # would force multi-pass f32 matmuls at a fraction of peak);
@@ -82,9 +207,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        mask = _attn_mask(i, j, block_q, block_k, q_offset, sk_orig,
-                          causal)
-        s = jnp.where(mask, s, _NEG_INF)
+        if masked:
+            s = jnp.where(_attn_mask(i, j, **mask_kw), s, _NEG_INF)
         m_prev = m_ref[:]                      # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -96,7 +220,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == nk - 1)
+    _per_kind(kind, kinds, _body)
+
+    @pl.when(last == 1)
     def _finalize():
         # l == 0 for zero-padded q rows (sliced off by the caller).
         # m == -inf marks FULLY-MASKED rows (decode with Sq > Sk): they
@@ -108,15 +234,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                 0.0).astype(o_ref.dtype)
 
 
-def _fwd_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                    l_ref, **kw):
+def _fwd_kernel_lse(pairs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
+                    m_ref, l_ref, **kw):
     """Forward that also writes the row logsumexp (for the Pallas
     backward): lse = m + log(l)."""
-    _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, **kw)
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
+    _fwd_kernel(pairs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                **kw)
 
-    @pl.when(j == nk - 1)
+    @pl.when(_pair(pairs_ref, kw["rectangular"])[_LAST] == 1)
     def _write_lse():
         l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
         lse_ref[0, 0] = m_ref[:] + jnp.log(l)  # [bq, 1]
@@ -130,6 +255,65 @@ def _pad_seq(x, block):
     return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
 
+def _scheduled_call(kernel_fn, name, programs, sq, sk, block_q, block_k,
+                    causal, sm_scale, *, in_specs, out_specs, out_shape,
+                    scratch_shapes, interpret):
+    """`pallas_call` of `kernel_fn` as kernel `name` over grid (b, h,
+    *`sched.grid`) of its `pair_schedule`, the schedule its one
+    scalar-prefetch operand. The kernel's static arguments: `_attn_mask`'s,
+    and the pair kinds the schedule holds (a body is emitted only for a
+    kind that occurs)."""
+    sched = pair_schedule(name, sq, sk, block_q, block_k, causal)
+    _count_pairs(name, sched, programs[0] * programs[1])
+    kernel = functools.partial(
+        kernel_fn, sm_scale=sm_scale,
+        kinds=frozenset(sched.pairs[_KIND].tolist()),
+        rectangular=len(sched.grid) == 2, causal=causal, block_q=block_q,
+        block_k=block_k, q_offset=sk - sq, sk_orig=sk,
+        kv_padded=sched.kv_padded)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(*programs, *sched.grid),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * (1 + len(sched.grid))
+            + ("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )
+    return functools.partial(call, jnp.asarray(sched.pairs))
+
+
+def _block_specs(block_q, block_k, d, grp, q_row):
+    """(q-side spec of a given width, kv-side spec of q head h's KV head,
+    kv-side spec a q head) for a grid (b, h, *`sched.grid`) whose pairs
+    name the q block in row `q_row` of the schedule and the kv block in
+    the other."""
+    kv_row = _INNER if q_row == _OUTER else _OUTER
+
+    def block(row, *at):
+        """The pair's block number: `at` is the grid position behind
+        (batch, head), then the schedule."""
+        *ids, pairs = at
+        return ids[row] if len(ids) == 2 else pairs[row, ids[0]]
+
+    def q_side(width):
+        return pl.BlockSpec(
+            (1, 1, block_q, width),
+            lambda b_, h_, *at: (b_, h_, block(q_row, *at), 0))
+
+    kv_side = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda b_, h_, *at: (b_, h_ // grp, block(kv_row, *at), 0))
+    kv_side_per_head = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda b_, h_, *at: (b_, h_, block(kv_row, *at), 0))
+    return q_side, kv_side, kv_side_per_head
+
+
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                with_lse=False):
     b, h, sq, d = q.shape
@@ -141,36 +325,20 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     block_k = min(block_k, max(sk, 1))
     qp, kp, vp = _pad_seq(q, block_q), _pad_seq(k, block_k), _pad_seq(v,
                                                                       block_k)
-    sq_p, sk_p = qp.shape[2], kp.shape[2]
-    grid = (b, h, sq_p // block_q, sk_p // block_k)
-
-    kernel_fn = _fwd_kernel_lse if with_lse else _fwd_kernel
-    kernel = functools.partial(
-        kernel_fn, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k,
-        q_offset=sk - sq, sk_orig=sk)
-    out_specs = pl.BlockSpec((1, 1, block_q, d),
-                             lambda b_, h_, i, j: (b_, h_, i, 0))
+    sq_p = qp.shape[2]
+    q_side, kv_side, _ = _block_specs(block_q, block_k, d, g, _OUTER)
+    out_specs = q_side(d)
     out_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
     if with_lse:
         # [B,H,Sq,1] keeps the last-two block dims TPU-tileable
         # ((block_q, 1) with 1 == full trailing dim).
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, 1, block_q, 1),
-                                  lambda b_, h_, i, j: (b_, h_, i, 0))]
+        out_specs = [out_specs, q_side(1)]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((b, h, sq_p, 1), jnp.float32)]
-    result = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, i, j, g=g: (b_, h_ // g, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, i, j, g=g: (b_, h_ // g, j, 0)),
-        ],
+    result = _scheduled_call(
+        _fwd_kernel_lse if with_lse else _fwd_kernel, sn.FLASH_FWD, (b, h),
+        sq, sk, block_q, block_k, causal, sm_scale,
+        in_specs=[q_side(d), kv_side, kv_side],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -178,11 +346,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
         interpret=interpret,
-        name=sn.FLASH_FWD,
     )(qp, kp, vp)
     if with_lse:
         out, lse = result
@@ -193,23 +357,18 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     return out[:, :, :sq] if sq_p != sq else out
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, acc_ref, *, sm_scale, causal, block_q,
-                   block_k, q_offset, sk_orig):
-    """dq for one q block, accumulated over kv blocks (innermost axis).
-    ds = p * (dO v^T - delta) * scale; dq += ds k."""
-    i = pl.program_id(2)
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
+def _bwd_dq_kernel(pairs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, acc_ref, *, sm_scale, kinds,
+                   rectangular, **mask_kw):
+    """dq for one q block, accumulated over its kv blocks (the pair's
+    inner block). ds = p * (dO v^T - delta) * scale; dq += ds k."""
+    i, j, first, last, kind = _pair(pairs_ref, rectangular)
 
-    @pl.when(j == 0)
+    @pl.when(first == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    should = _block_contributes(i, j, block_q, block_k, q_offset, causal)
-
-    @pl.when(should)
-    def _body():
+    def _body(masked):
         # Storage-dtype matmul inputs (native bf16 MXU path; f32 stats).
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -220,9 +379,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        mask = _attn_mask(i, j, block_q, block_k, q_offset, sk_orig,
-                          causal)
-        s = jnp.where(mask, s, _NEG_INF)
+        if masked:
+            s = jnp.where(_attn_mask(i, j, **mask_kw), s, _NEG_INF)
         # Fully-masked rows (decode with Sq > Sk, or padded rows) have
         # lse ~ -inf: their softmax is empty, p must be 0 — not
         # exp(-inf - -inf).
@@ -235,29 +393,26 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == nk - 1)
+    _per_kind(kind, kinds, _body)
+
+    @pl.when(last == 1)
     def _fin():
         dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
-                    block_q, block_k, q_offset, sk_orig):
+def _bwd_dkv_kernel(pairs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale,
+                    kinds, rectangular, **mask_kw):
     """dk/dv for one kv block (per q head — GQA groups reduced outside),
-    accumulated over q blocks (innermost axis)."""
-    j = pl.program_id(2)  # kv block
-    i = pl.program_id(3)  # q block (innermost)
-    nq = pl.num_programs(3)
+    accumulated over its q blocks (the pair's inner block)."""
+    j, i, first, last, kind = _pair(pairs_ref, rectangular)  # kv outermost
 
-    @pl.when(i == 0)
+    @pl.when(first == 1)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    should = _block_contributes(i, j, block_q, block_k, q_offset, causal)
-
-    @pl.when(should)
-    def _body():
+    def _body(masked):
         # Storage-dtype matmul inputs (native bf16 MXU path; f32 stats).
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -268,9 +423,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        mask = _attn_mask(i, j, block_q, block_k, q_offset, sk_orig,
-                          causal)
-        s = jnp.where(mask, s, _NEG_INF)
+        if masked:
+            s = jnp.where(_attn_mask(i, j, **mask_kw), s, _NEG_INF)
         p = jnp.where(lse <= _NEG_INF / 2, 0.0,
                       jnp.exp(s - lse))         # [bq, bk]
         dv_acc[:] += jax.lax.dot_general(
@@ -284,7 +438,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(i == nq - 1)
+    _per_kind(kind, kinds, _body)
+
+    @pl.when(last == 1)
     def _fin():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -323,78 +479,34 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
         lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
 
-    common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                  block_k=block_k, q_offset=sk - sq, sk_orig=sk)
-
-    # --- dq: grid (b, h, nq, nk), kv innermost (axis2=q, axis3=kv) ---
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        grid=(b, h, sq_p // block_q, sk_p // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, i, j, g_=grp:
-                         (b_, h_ // g_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, i, j, g_=grp:
-                         (b_, h_ // g_, j, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda b_, h_, i, j: (b_, h_, i, 0)),
+    # --- dq: q blocks outermost, a q block's kv blocks innermost ---
+    schedule = (sq, sk, block_q, block_k, causal, sm_scale)
+    q_side, kv_side, _ = _block_specs(block_q, block_k, d, grp, _OUTER)
+    dq = _scheduled_call(
+        _bwd_dq_kernel, sn.FLASH_BWD_DQ, (b, h), *schedule,
+        in_specs=[q_side(d), kv_side, kv_side, q_side(d), q_side(1),
+                  q_side(1)],
+        out_specs=q_side(d),
         out_shape=jax.ShapeDtypeStruct(qp.shape, dq_dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
         interpret=interpret,
-        name=sn.FLASH_BWD_DQ,
     )(qp, kp, vp, gp, lse, delta)
 
-    # --- dk/dv: grid (b, h, nk, nq), q innermost (axis2=kv, axis3=q);
+    # --- dk/dv: kv blocks outermost, a kv block's q blocks innermost;
     # per-q-head then group reduce (GQA) ---
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common),
-        grid=(b, h, sk_p // block_k, sq_p // block_q),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, j, i, g_=grp:
-                         (b_, h_ // g_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, j, i, g_=grp:
-                         (b_, h_ // g_, j, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, j, i: (b_, h_, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, j, i: (b_, h_, j, 0)),
-        ],
+    q_side, kv_side, kv_out = _block_specs(block_q, block_k, d, grp, _INNER)
+    dk_h, dv_h = _scheduled_call(
+        _bwd_dkv_kernel, sn.FLASH_BWD_DKV, (b, h), *schedule,
+        in_specs=[q_side(d), kv_side, kv_side, q_side(d), q_side(1),
+                  q_side(1)],
+        out_specs=[kv_out, kv_out],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sk_p, d), dk_dtype),
             jax.ShapeDtypeStruct((b, h, sk_p, d), dv_dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
         interpret=interpret,
-        name=sn.FLASH_BWD_DKV,
     )(qp, kp, vp, gp, lse, delta)
 
     dq = dq[:, :, :sq] if sq_p != sq else dq

@@ -144,6 +144,102 @@ def test_flash_decode_shapes_and_padding():
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
+_SCHEDULE_CASES = [
+    # sq, sk, block_q, block_k, causal, (live, masked, rectangle) or None
+    pytest.param(4096, 4096, 512, 512, True, (36, 8, 64), id="train-cell"),
+    pytest.param(4096, 4096, 512, 512, False, (64, 0, 64), id="full"),
+    pytest.param(100, 100, 64, 64, True, (3, 2, 4), id="padded"),
+    pytest.param(100, 100, 64, 64, False, (4, 2, 4), id="padded-full"),
+    pytest.param(1, 96, 64, 64, True, (2, 1, 2), id="decode-prefix"),
+    pytest.param(16, 8, 8, 8, True, (1, 1, 2), id="rows-before-every-key"),
+    pytest.param(24, 40, 8, 16, True, None, id="prefix-uneven-tiles"),
+]
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,counts", _SCHEDULE_CASES)
+def test_flash_pair_schedule(kernel, sq, sk, bq, bk, causal, counts):
+    """The kernels' grid: every pair `_block_contributes` accepts, once,
+    and no other (but ONE dead pair for an outer block with no live inner
+    block); `first` / `last` bracket an outer block's run; a pair goes
+    unmasked only where `_attn_mask` is all true."""
+    import importlib
+
+    # `ray_tpu.ops.flash_attention` the attribute is the function
+    F = importlib.import_module("ray_tpu.ops.flash_attention")
+
+    bq, bk = min(bq, sq), min(bk, sk)       # as `_flash_fwd` clips them
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    off = sk - sq
+    sched = F.pair_schedule(kernel, sq, sk, bq, bk, causal)
+    q_outer = kernel != "flash_bwd_dkv"
+    n_outer, n_inner = (nq, nk) if q_outer else (nk, nq)
+
+    def qk(o, n):
+        return (o, n) if q_outer else (n, o)
+
+    want = {(o, n) for o in range(n_outer) for n in range(n_inner)
+            if F._block_contributes(*qk(o, n), bq, bk, off, causal)}
+    pairs = list(zip(sched.outer.tolist(), sched.inner.tolist()))
+    live = [p for p, dead in zip(pairs, sched.dead) if not dead]
+    assert len(live) == len(set(live)) and set(live) == want
+    # a dead pair stands for an outer block nothing contributes to
+    assert ({o for (o, _), dead in zip(pairs, sched.dead) if dead}
+            == set(range(n_outer)) - {o for o, _ in want})
+    assert not (sched.dead & sched.masked).any()
+    # order: outer blocks ascending, each ONE run, inner ascending in it
+    assert pairs == sorted(pairs)
+    assert sorted({o for o, _ in pairs}) == list(range(n_outer))
+    for x, (o, _) in enumerate(pairs):
+        assert sched.first[x] == (x == 0 or pairs[x - 1][0] != o)
+        assert sched.last[x] == (x == len(pairs) - 1
+                                 or pairs[x + 1][0] != o)
+    for (o, n), masked, dead in zip(pairs, sched.masked, sched.dead):
+        if not dead:
+            whole = bool(np.asarray(F._attn_mask(
+                *qk(o, n), bq, bk, off, sk, causal)).all())
+            assert masked == (not whole), (o, n)
+    assert sched.kv_padded == (sk % bk != 0)
+    # the grid is the pairs, or the rectangle itself where nothing is
+    # skipped (an index map then reads no table)
+    assert sched.grid == ((n_outer, n_inner) if len(want) == nq * nk
+                          else (len(pairs),))
+    if counts is not None:
+        assert sched.counts() == dict(zip(("live", "masked", "rectangle"),
+                                          counts))
+    # the operand the kernels read: a row a field, a column a pair
+    op = sched.pairs
+    assert op.dtype == np.int32 and op.shape == (5, len(pairs))
+    assert set(op[4].tolist()) <= {0, 1, 2}
+    assert ((op[4] == 0) == sched.dead).all()
+    assert ((op[4] == 2) == sched.masked).all()
+
+
+def test_flash_pair_counters():
+    """A call built counts its pairs once, by kernel: the triangle it
+    steps through, the masked pairs in it, and the rectangle."""
+    from ray_tpu.ops import flash_attention
+    from ray_tpu.util import metrics
+
+    def read():
+        return {(r["name"], r["tags"]["kernel"]): r["value"]
+                for r in metrics.snapshots()
+                if r["name"].startswith("flash_pairs_")}
+
+    q, k, v = _rand_qkv(b=1, h=4, hkv=2, s=256, d=32)
+    before = read()
+    jax.grad(lambda q: flash_attention(
+        q, k, v, causal=True, block_q=64, block_k=64,
+        interpret=True).sum())(q)
+    now = read()
+    for kern in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        got = tuple((now[f"flash_pairs_{what}_total", kern]
+                     - before.get((f"flash_pairs_{what}_total", kern), 0))
+                    / 4 for what in ("live", "masked", "rectangle"))
+        assert got == (10, 4, 16), kern
+
+
 def test_flash_rejects_bad_gqa():
     from ray_tpu.ops import flash_attention
 

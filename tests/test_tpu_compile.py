@@ -6,7 +6,10 @@ mode hides what Mosaic refuses — a block layout, an unaligned slice, too
 much VMEM — so every kernel `impl="auto"` can select on a TPU is compiled
 here, `interpret=False`, at the shapes `chip_smoke.py` runs: Llama-3-8B
 attention widths for the paged decode kernel, the 551M flagship's
-`[8, 12, 2048, 128]` at 1024x1024 tiles for flash forward and backward.
+`[8, 12, 2048, 128]` at 1024x1024 tiles for flash forward and backward,
+and the train cell's `[4, 16, 4096, 128]` / 8 KV heads at the default
+512x512 tiles, whose calls step through the causal triangle (36 pairs of
+64, PR 50).
 The fused decode PROGRAM is compiled whole as well, at the benchmark's
 shapes, and held to what PR 27 bought: the KV pool is one buffer updated
 in place, so the program holds nothing else of the pool's or a layer's
@@ -548,6 +551,43 @@ def test_flash_bwd_compiles_at_flagship_shape(v5e):
 
     assert _compiles_with_kernel(jax.grad(loss, argnums=(0, 1, 2)),
                                  *_flash_args(v5e, 8, 12, 12, 2048, 128))
+
+
+def _flash_pair_counts(kernel):
+    """`flash_pairs_*_total` of `kernel` in this process's registry."""
+    from ray_tpu.util import metrics
+
+    return {row["name"]: row["value"] for row in metrics.snapshots()
+            if row["name"].startswith("flash_pairs_")
+            and row["tags"].get("kernel") == kernel}
+
+
+@pytest.mark.parametrize("program", ["fwd", "bwd"])
+def test_flash_compiles_at_the_train_cell_shape(v5e, program):
+    """`internlm2-train-fsdp4`: a chip's `q [4,16,4096,128]`, 8 KV heads,
+    the default 512 x 512 tiles. A call steps through the causal triangle:
+    36 pairs a (batch, head), 8 of them masked, where the rectangle is
+    64."""
+    from ray_tpu.ops import scope_names as sn
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, sm_scale=128 ** -0.5,
+                               interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    kernels = {"fwd": [sn.FLASH_FWD],
+               "bwd": [sn.FLASH_FWD, sn.FLASH_BWD_DQ, sn.FLASH_BWD_DKV]}
+    before = {kern: _flash_pair_counts(kern) for kern in kernels[program]}
+    fn = fwd if program == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    assert _compiles_with_kernel(fn, *_flash_args(v5e, 4, 16, 8, 4096, 128))
+    for kern in kernels[program]:
+        now = _flash_pair_counts(kern)
+        got = {what: (now[f"flash_pairs_{what}_total"]
+                      - before[kern].get(f"flash_pairs_{what}_total", 0))
+               / (4 * 16) for what in ("live", "masked", "rectangle")}
+        assert got == {"live": 36, "masked": 8, "rectangle": 64}, kern
 
 
 def test_flash_fwd_compiles_under_gqa(v5e):
