@@ -1565,6 +1565,10 @@ class DecodeEngine:
         # fetched: the kernel's rule, a row that walks hands on.
         self.paged_walk_rows_total = 0
         self.paged_walk_rows_chained_total = 0
+        # Those whose call took the row's KV heads as one operand of a
+        # step (`walk_shape`'s third word: every decode row of the served
+        # families, no 128-row prefill tile); the rest loop over heads.
+        self.paged_walk_rows_stacked_total = 0
         # The same for prefill dispatches (`_count_prefill_walk`).
         self.prefill_walk_pages_total = 0  # pages the chunks' tiles walk
         self.prefill_table_entries_total = 0   # n_pad * MB per dispatch
@@ -2524,8 +2528,11 @@ class DecodeEngine:
         # B rows walk and all but a call's first find their pages coming
         self.paged_walk_rows_total += H * self.B
         self.paged_walk_rows_chained_total += H * (self.B - 1)
-        if self._selects:
+        if self._selects:       # its attention is not this kernel's
             self._count_selection(slots + 1, decode=True)
+        else:
+            self.paged_walk_rows_stacked_total += \
+                H * self.B * self._walk_shape(1)[2]
         if self._state_planes:
             # tokens the kernel is asked to read, a token-layer each: the
             # full layers' cache once a READER (a `HybridConfig`'s one
@@ -2570,8 +2577,6 @@ class DecodeEngine:
         design, the pure-lax lowering a tp mesh or a quantized pool's
         chunk takes (off the chip that lowering stands in for the
         kernel, and is counted)."""
-        from ray_tpu.ops.paged_attention_kernel import walk_shape
-
         if self._selects:
             real = np.arange(bucket)[None, :] <= last_idx[:, None]
             live = starts[:, None] + np.arange(bucket)[None, :] + 1
@@ -2580,10 +2585,8 @@ class DecodeEngine:
         if self.kv_quant_spec is not None or self.kv_pool_w is not None \
                 or (self.mesh is not None and self.mesh.size > 1):
             return
-        cfg, T = self.cfg, self.kv_block_tokens
-        _, tq = walk_shape(bucket, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, T, self._mb,
-                           self._pool_k.dtype.itemsize)
+        T = self.kv_block_tokens
+        _, tq, stacked = self._walk_shape(bucket)
         first = np.arange(0, bucket, tq)                    # [tiles]
         top = np.minimum(first + tq - 1, last_idx[:, None])
         pages = np.minimum((starts[:, None] + top) // T + 1, self._mb)
@@ -2596,6 +2599,18 @@ class DecodeEngine:
         self.paged_walk_rows_total += int(walks.sum())
         self.paged_walk_rows_chained_total += int(
             (walks[1:] & walks[:-1]).sum())
+        self.paged_walk_rows_stacked_total += int(walks.sum()) * stacked
+
+    def _walk_shape(self, n_slots: int):
+        """What the paged kernel makes of a call of ``n_slots`` queries
+        a row (`walk_shape`: pages a step, query tile, heads stacked),
+        from the same static shapes it reads."""
+        from ray_tpu.ops.paged_attention_kernel import walk_shape
+
+        cfg = self.cfg
+        return walk_shape(n_slots, cfg.n_heads, cfg.n_kv_heads,
+                          cfg.head_dim, self.kv_block_tokens, self._mb,
+                          self._pool_k.dtype.itemsize)
 
     def _top_up_pipeline(self, rows: List[int],
                          horizon: Optional[int]) -> None:
@@ -2863,6 +2878,8 @@ class DecodeEngine:
         out["paged_walk_rows_total"] = float(self.paged_walk_rows_total)
         out["paged_walk_rows_chained_total"] = float(
             self.paged_walk_rows_chained_total)
+        out["paged_walk_rows_stacked_total"] = float(
+            self.paged_walk_rows_stacked_total)
         out["prefill_walk_pages_total"] = float(
             self.prefill_walk_pages_total)
         out["prefill_table_entries_total"] = float(

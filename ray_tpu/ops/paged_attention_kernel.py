@@ -62,25 +62,42 @@ buffer is zeroed first, so stale VMEM (``0 * NaN``) cannot reach the
 accumulator, and stale K only reaches scores the mask replaces. A tile
 with ``n_live == 0`` walks nothing and returns 0.
 
-Queries arrive regrouped as ``[B, KV, S*G, D]`` so one KV head's query
-group of a row is one ``[S*G, D]`` matmul operand against the step's
-``[P*T, D]`` keys. Scratch is the usual flash trio per KV head — f32
-accumulator ``[KV, tq*G, D]`` plus running max/sum ``[KV, tq*G, 1]`` —
-set at the top of the grid step and finalized at its end. The KV heads
-of a step are straight-line code for rows of few queries and a loop for
-a prefill tile (`_HEAD_LOOP_ROWS`). Masked positions follow the reference
-exactly: causal ``slot <= q_slot`` plus the ``kv_valid_len`` cap,
-fully-masked rows produce 0. A fully masked step leaves the state
-untouched (``p = 0``, ``alpha = 1``), so stopping at ``n_live`` gives
-the bits of a walk over all ``MB`` entries (`_walk`, which the tests
-call both ways); against a walk of one page a step the sums are
-associated differently, within the parity tolerances.
+Queries arrive regrouped as ``[B, KV, S*G, D]``. While all the query
+rows of a grid step, every head's, are within the MXU's 128 (`walk_shape`:
+a decode token, a speculative window of a few slots) **a step takes the
+row's KV heads as ONE operand**: the queries are laid out once a grid
+step, in VMEM, as a block-diagonal ``Qd [KV*S*G, KV*D]`` (head ``kv``'s
+rows hold their query in lanes ``kv*D:(kv+1)*D``, zeros elsewhere), so
+``Qd x K^T`` against the step's ``[P*T, KV*D]`` slab is every head's
+scores at once (a zero times a key adds an exact 0.0 to the f32 sum),
+ONE mask, max, exp and sum serve all the rows, and ``p x V`` lands in an
+f32 accumulator ``[KV*S*G, KV*D]`` of which a row's own head's ``D``
+lanes are its answer (the other lanes are finite products nobody reads);
+the diagonal blocks are cut out once, at the grid step's end
+(`_own_lanes`), into the ``[KV, S*G, D]`` output block. Two matmuls and
+one softmax a step, where until PR 51 a decode row ran ``KV``
+straight-line bodies of 1-4 query rows each (8 half-empty ``[4, 512]``
+score tiles at Mistral's shape; 16 of one row in eight at OLMoE's). A
+prefill tile (128 query rows a head and more) fills the MXU a head:
+there, and for a speculative window too wide to stack, the heads are a
+``fori_loop`` over one KV head's ``[S*G, D]`` operand against its ``D``
+lanes of the slab, with the usual flash trio per KV head (f32 accumulator
+``[KV, tq*G, D]`` plus running max/sum ``[KV, tq*G, 1]``). Either way the
+trio is set at the top of the grid step and finalized at its end. Masked
+positions follow the reference exactly: causal ``slot <= q_slot`` plus the
+``kv_valid_len`` cap, fully-masked rows produce 0. A fully masked step
+leaves the state untouched (``p = 0``, ``alpha = 1``), so stopping at
+``n_live`` gives the bits of a walk over all ``MB`` entries (`_walk`,
+which the tests call both ways); against a walk of one page a step the
+sums are associated differently, within the parity tolerances.
 
 Quantized pages are widened to the query dtype (exact for int8 and
 fp8-e4m3 into bf16 or f32) and the per-block per-head scales multiply
 the ``[tq*G, P*T]`` scores (K) and probabilities (V) column-wise
 instead of the pages: same product, rounded in a different order than
-the reference's dequantize-then-matmul. The scales of a row's MB pages
+the reference's dequantize-then-matmul (the stacked body spreads each
+head's column scales over that head's rows by selects: a quantized pool
+takes the same one body a step). The scales of a row's MB pages
 are gathered outside the kernel into one ``[1, MB*KV]`` SMEM block per
 row (under the ``kv_gather`` scope) and read as scalars; `_page_scales`
 spreads a step's ``P`` of them over its key columns by selects — Mosaic
@@ -93,9 +110,10 @@ fp8, one and four query slots, and so do the whole fused decode program
 and the prefill program around it, held there to moving nothing of the
 pool's size and to building no view of the table
 (tests/test_tpu_compile.py); it runs on the chip in ``chip_smoke.py``'s
-paged variants; the value sweeps against the pure-lax reference, on a
-pool of three layers that hold different data, run in interpret mode
-(tests/test_engine_kv_quant.py). On a TPU `impl="auto"` routes here;
+paged variants; the value sweeps against the pure-lax reference run in
+interpret mode (tests/test_engine_kv_quant.py, on a pool whose layers
+hold different data; tests/test_paged_kernel_stacked.py, the cells' head
+layouts stacked and looped). On a TPU `impl="auto"` routes here;
 elsewhere it stays on the reference path and this kernel runs only when
 asked for explicitly (then in interpret mode). Timed on a v5e (PERF.md
 PR 25, PR 27, PR 30, PR 38): 0.28 ms a decode call at the benchmark's
@@ -106,13 +124,28 @@ PROGRAM, timed alone, lost 1.5-2.1 us a live row of a call (Mistral, 32
 rows x 12 layers: 9.41 -> 8.83 ms a token at 256 tokens a row, 11.85 ->
 11.05 at 1,024; Phi-4-mini-flash, 64 rows x 16 calls: 24.04 -> 21.87)
 and 0.6 us a dead row (one short copy); a call's first row, and a row
-behind one that walks nothing, still wait. A 512-key step takes about
-3.4 us against 2.6 of HBM time (ROADMAP S2 keeps what is left); a
+behind one that walks nothing, still wait. **What a step costs (PR 51,
+`tools/decode_alone.py`, one v5e, the decode program alone):** with the
+heads as straight-line bodies one more 512-key step of a Mistral row
+cost 3.86 us against 2.56 of HBM time at 819 GB/s (32 rows, between 9
+and 33 pages a row); 3.23 with ONE body of the eight (what the copies
+and one chain leave), 5.08 for the same keys in two steps of 256 (1.2 us
+a step that no byte explains: eight dependent chains and 2 x pps copies
+issued and waited for in scalar loops). Stacked it costs **3.51**, and a
+row's only step 1.26 us less than before (8.83 -> 8.35 ms a token at 9
+pages a row, 11.05 -> 10.37 at 33); a 384-key step of a Phi-4-mini-flash
+row 2.72 -> **2.43** against 2.40 of HBM time (64 rows x 16 calls: 21.86
+-> 18.47 ms a token at 1,024 tokens a row, 20.01 -> 16.81 at 512);
+qwen3next's two heads of 256 equal either way (12.50 ms a token at 1,024,
+a step of two 256-token pages 1.43 us against 1.28). ROADMAP S2 keeps
+what is left of a Mistral step: the 2 x 16 copies a step from the scalar
+core. A
 512-token chunk at start 0 0.15 ms a layer, at start 2,560 0.44.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -143,16 +176,14 @@ _TILE_SCORE_BYTES = 1 << 20
 # v5e: `wk` came from HBM again, PERF.md PR 30).
 _VMEM_DEFAULT_BYTES = 16 << 20
 _VMEM_LIMIT_BYTES = 32 << 20
-# From this many query rows a grid step on, the KV heads are a loop of the
-# kernel and not `n_kv` copies of its body: a head's two matmuls then
-# fill the MXU on their own (a prefill tile; measured equal on a v5e),
-# while the few rows of a decode token or a speculative window need the
-# heads side by side in straight-line code to hide each other's
-# latencies (+20 % a call as a loop). The loop is traced, lowered and
-# compiled once a kernel where the copies cost 8 x that (4.2 -> 1.3 s of
-# Mosaic compile a kernel, 0.65 -> 0.15 s of tracing on the benchmark's
-# host).
-_HEAD_LOOP_ROWS = 128
+# While ALL the query rows of a grid step, every KV head's, are no more
+# than the MXU's 128, the step takes the row's KV heads as one operand
+# (`_kernel`, the stacked body): the zeros of the block-diagonal query
+# then ride passes the array makes anyway. Past that they would cost
+# passes of their own, a head's two matmuls fill the array alone, and the
+# heads are a `fori_loop` of the kernel (a prefill tile, a wide
+# speculative window).
+_STACK_ROWS = 128
 # Rows (tiles) go into a call in eights (`paged_attention_kernel`).
 _ROWS_PER_CALL = 8
 
@@ -201,24 +232,33 @@ def first_page(q_slots, window: int, block_tokens: int, n_live) -> jax.Array:
 
 def walk_shape(n_slots: int, n_heads: int, n_kv: int, head_dim: int,
                block_tokens: int, max_blocks: int,
-               itemsize: int) -> Tuple[int, int]:
-    """(pages a compute step folds, query tokens a grid step holds),
-    from the static shapes alone. A step folds as many pages as make
+               itemsize: int) -> Tuple[int, int, bool]:
+    """(pages a compute step folds, query tokens a grid step holds,
+    whether a step takes the row's KV heads as one operand), from the
+    static shapes alone. A step folds as many pages as make
     `_KEYS_PER_STEP` keys or fit `_STEP_BYTES`. A grid step holds all
     ``n_slots`` queries of a row while they fit the tile's budgets (a
     decode token, a speculative window), else the largest power of two
     that does (a prefill chunk: 128 tokens x 4 heads a group at 8 KV
-    heads of 128, 256 x 1 at 16). The engine's prefill counter asks here
-    too (`DecodeEngine._count_prefill_walk`)."""
+    heads of 128, 256 x 1 at 16). The heads are stacked while the grid
+    step's query rows, all heads', are within `_STACK_ROWS` and the
+    stacked accumulator ``[rows, KV*D]`` and scores ``[rows, keys]`` are
+    within the same two budgets. The engine's walk counters ask here too
+    (`DecodeEngine._count_paged_walk`, `_count_prefill_walk`)."""
     page_bytes = block_tokens * n_kv * head_dim * itemsize
     pps = max(1, min(_KEYS_PER_STEP // block_tokens,
                      _STEP_BYTES // page_bytes, max_blocks))
     group = n_heads // n_kv
+    keys = pps * block_tokens
     fit = min(_TILE_ACC_BYTES // (n_heads * head_dim * 4),
-              _TILE_SCORE_BYTES // (group * pps * block_tokens * 4))
-    if n_slots <= fit:
-        return pps, n_slots
-    return pps, max(8, 1 << (max(fit, 1).bit_length() - 1))
+              _TILE_SCORE_BYTES // (group * keys * 4))
+    tq = n_slots if n_slots <= fit \
+        else max(8, 1 << (max(fit, 1).bit_length() - 1))
+    rows = tq * n_heads
+    stacked = (rows <= _STACK_ROWS
+               and rows * n_kv * head_dim * 4 <= _TILE_ACC_BYTES
+               and rows * keys * 4 <= _TILE_SCORE_BYTES)
+    return pps, tq, stacked
 
 
 # The kernel's body is written in `jax.lax` primitives, with no `jnp`
@@ -256,11 +296,13 @@ def _row_reduce(reduce, x):
 
 def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
             head_dim, block_tokens, pages_per_step, max_blocks, has_scale,
-            head_loop, window=None):
+            stacked, window=None):
     """Grid step ``b``: walk row b's live pages, ``pages_per_step`` at a
     time, folding each step into every KV head's online softmax (a row
     is a decode row's query slots, or one query tile of a prefill
-    chunk). Scalar-prefetch refs: ``bt_ref`` [B*MB] flat block table,
+    chunk): all heads in one body of two matmuls and one softmax when
+    ``stacked`` (`walk_shape`), else a loop over the heads.
+    Scalar-prefetch refs: ``bt_ref`` [B*MB] flat block table,
     ``lim_ref`` [1] the valid-length cap, ``nl_ref`` [B] live pages per
     row (the walk's end), ``lay_ref`` [1] the pool's layer and, for a
     sliding-window layer (``window``), one more: [B] the page each row's
@@ -272,6 +314,8 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     if window is not None:
         fp_ref, *refs = refs
     *refs, chain_ref = refs
+    if stacked:
+        *refs, qd_ref, slot_ref = refs
     if has_scale:
         (qs_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, k_buf, v_buf,
          sem, acc_ref, m_ref, l_ref) = refs
@@ -284,6 +328,7 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     span = pps * t                                      # keys per step
     layer = lay_ref[0]
     rows = q_ref.shape[2]                               # tq * G
+    n_rows = n_kv * rows if stacked else rows           # of a head body
 
     def walk_of(r):
         """Row r's walk: the flat table index of its first page, how
@@ -340,15 +385,32 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     def wait(entry, n_pages, buf):
         each_page(entry, n_pages, buf, lambda copy: copy.wait())
 
+    start(row0, _select(chained, np.int32(0), live_in(0)), slot0)
     acc_ref[...] = _lax.full(acc_ref.shape, 0.0, jnp.float32)
     m_ref[...] = _lax.full(m_ref.shape, _NEG_INF, jnp.float32)
     l_ref[...] = _lax.full(l_ref.shape, 0.0, jnp.float32)
-    start(row0, _select(chained, np.int32(0), live_in(0)), slot0)
+    if stacked:
+        # The row's queries as ONE block-diagonal operand [KV*rows, KV*D]:
+        # head kv's rows hold their query in that head's D lanes and
+        # zeros elsewhere, so one product against the step's [keys, KV*D]
+        # slab is every head's scores (a zero times a key adds an exact
+        # 0.0 to the f32 sum). Laid out through the zeroed accumulator,
+        # whose 32-bit rows take a store at any sublane; the query slots
+        # repeat over the heads beside it.
+        for kv in range(n_kv):
+            at = pl.ds(kv * rows, rows)
+            acc_ref[at, pl.ds(kv * head_dim, head_dim)] = \
+                _lax.convert_element_type(q_ref[0, kv], jnp.float32)
+            slot_ref[at, :] = qs_ref[0]
+        qd_ref[...] = _lax.convert_element_type(acc_ref[...], qd_ref.dtype)
+        acc_ref[...] = _lax.full(acc_ref.shape, 0.0, jnp.float32)
+        q_slot = slot_ref[...]                          # [KV*tq*G, 1]
+    else:
+        q_slot = qs_ref[0]                              # [tq*G, 1]
 
-    col = _lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+    col = _lax.broadcasted_iota(jnp.int32, (n_rows, span), 1)
     page_of_col = _lax.div(
         _lax.broadcasted_iota(jnp.int32, (1, span), 1), np.int32(t))
-    q_slot = qs_ref[0]                                  # [tq*G, 1]
     lim = lim_ref[0]
     zero_page = _lax.full((t, v_buf.shape[2]), 0, v_buf.dtype)
 
@@ -380,47 +442,75 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
             mask = _lax.bitwise_and(
                 mask, _lax.gt(slot, _lax.sub(q_slot, np.int32(window))))
 
-        def head(kv, carry):
-            q = q_ref[0, kv]                            # [tq*G, D]
-            lanes = pl.ds(kv * head_dim, head_dim) if isinstance(kv, int) \
-                else pl.ds(pl.multiple_of(_mul(kv, head_dim), head_dim),
-                           head_dim)
-            k = _lax.convert_element_type(k_buf[buf, :, lanes], q.dtype)
-            v = _lax.convert_element_type(v_buf[buf, :, lanes], q.dtype)
+        def scales(ref, kv):
+            page0 = _mul(i, pps) if window is None \
+                else _add(first, _mul(i, pps))
+            return _page_scales(ref, page0, kv, page_of_col, n_kv, pps,
+                                max_blocks)
+
+        def fold(at, q, k, v, ks, vs):
+            """One online-softmax update of the trio's rows `at` with
+            the step's keys: ``q`` [rows, C] against ``k``, ``v``
+            [keys, C] (C a head's lanes, or all heads')."""
+            k = _lax.convert_element_type(k, q.dtype)
+            v = _lax.convert_element_type(v, q.dtype)
             s = _lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
             if has_scale:
-                page0 = _mul(i, pps) if window is None \
-                    else _add(first, _mul(i, pps))
-                ks, vs = (_page_scales(r, page0, kv, page_of_col,
-                                       n_kv, pps, max_blocks)
-                          for r in (ks_ref, vs_ref))
                 s = _lax.mul(s, ks)
             s = _lax.mul(s, np.float32(sm_scale))
             s = _select(mask, s, _NEG_INF)
-            m_prev = m_ref[kv]                          # [tq*G, 1]
+            m_prev = m_ref[at]                          # [rows, 1]
             m_new = _lax.max(m_prev, _row_reduce(_lax.reduce_max, s))
             # explicit zero (not just exp underflow): a fully-masked
             # step with m still at -inf would otherwise yield
             # exp(0) == 1 per position
             p = _select(mask, _lax.exp(_lax.sub(s, m_new)), 0.0)
             alpha = _lax.exp(_lax.sub(m_prev, m_new))
-            l_ref[kv] = _lax.add(_lax.mul(l_ref[kv], alpha),
+            l_ref[at] = _lax.add(_lax.mul(l_ref[at], alpha),
                                  _row_reduce(_lax.reduce_sum, p))
             if has_scale:
                 p = _lax.mul(p, vs)
             pv = _lax.dot_general(_lax.convert_element_type(p, v.dtype), v,
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-            acc_ref[kv] = _lax.add(_lax.mul(acc_ref[kv], alpha), pv)
-            m_ref[kv] = m_new
+            acc_ref[at] = _lax.add(_lax.mul(acc_ref[at], alpha), pv)
+            m_ref[at] = m_new
+
+        def head(kv, carry):
+            lanes = pl.ds(pl.multiple_of(_mul(kv, head_dim), head_dim),
+                          head_dim)
+            ks, vs = (scales(r, kv) for r in (ks_ref, vs_ref)) \
+                if has_scale else (None, None)
+            fold(kv, q_ref[0, kv], k_buf[buf, :, lanes],
+                 v_buf[buf, :, lanes], ks, vs)
             return carry
 
-        if head_loop:
+        if not stacked:
             _lax.fori_loop(0, n_kv, head, 0)
-        else:
-            for kv in range(n_kv):
-                head(kv, 0)
+            return carry
+        ks = vs = None
+        if has_scale:
+            # a head's column scales, spread over that head's rows
+            head_of_row = _lax.div(
+                _lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0),
+                np.int32(rows))
+
+            def of_rows(ref):
+                out = _lax.full((n_rows, span), 0.0, jnp.float32)
+                for kv in range(n_kv):
+                    out = _select(
+                        _lax.broadcast_in_dim(
+                            _lax.eq(head_of_row, np.int32(kv)), out.shape,
+                            (0, 1)),
+                        _lax.broadcast_in_dim(scales(ref, kv), out.shape,
+                                              (0, 1)), out)
+                return out
+            ks, vs = of_rows(ks_ref), of_rows(vs_ref)
+        # every lane of a row's accumulator takes p x V; the row's own
+        # head's D lanes are its answer (cut out at the grid step's end),
+        # the others finite products nobody reads
+        fold(Ellipsis, qd_ref[...], k_buf[buf], v_buf[buf], ks, vs)
         return carry
 
     _lax.fori_loop(0, n_steps, step_body, 0)
@@ -433,9 +523,22 @@ def _kernel(bt_ref, lim_ref, nl_ref, lay_ref, *refs, sm_scale, n_kv,
     l = _select(_lax.eq(l, np.float32(0.0)), _lax.full_like(l, 1.0), l)
     row_live = _lax.broadcast_in_dim(
         _lax.gt(m_ref[...], np.float32(_NEG_INF / 2)), acc_ref.shape,
-        (0, 1, 2))
-    o_ref[0] = _lax.convert_element_type(
-        _select(row_live, _lax.div(acc_ref[...], l), 0.0), o_ref.dtype)
+        tuple(range(acc_ref.ndim)))
+    out = _select(row_live, _lax.div(acc_ref[...], l), 0.0)
+    if not stacked:
+        o_ref[0] = _lax.convert_element_type(out, o_ref.dtype)
+        return
+    for kv in range(n_kv):
+        o_ref[0, kv] = _lax.convert_element_type(
+            _own_lanes(out, kv, rows, head_dim), o_ref.dtype)
+
+
+def _own_lanes(out, kv, rows, head_dim):
+    """Head ``kv``'s answer in the stacked ``[KV*rows, KV*D]`` result:
+    its rows, and of their lanes its own head's ``D`` (the diagonal
+    block; the rest of a row is p x the other heads' values)."""
+    return _lax.slice(out, (kv * rows, kv * head_dim),
+                      ((kv + 1) * rows, (kv + 1) * head_dim))
 
 
 def _page_scales(scale_ref, first_page, kv, page_of_col, n_kv,
@@ -488,14 +591,14 @@ def paged_attention_kernel(q: jax.Array,
         raise ValueError("k_scale and v_scale must be given together")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    pps, tq = walk_shape(S, H, KV, D, T, MB, k_pool.dtype.itemsize)
-    head_loop = tq * (H // KV) >= _HEAD_LOOP_ROWS
+    pps, tq, stacked = walk_shape(S, H, KV, D, T, MB,
+                                  k_pool.dtype.itemsize)
     walk = functools.partial(
         _walk, k_pool=k_pool, v_pool=v_pool, k_scale=k_scale,
         v_scale=v_scale, layer=_scalar_i32(layer),
         kv_valid_len=_scalar_i32(kv_valid_len), n_live=None,
         sm_scale=sm_scale if sm_scale is not None else D ** -0.5,
-        interpret=interpret, pps=pps, head_loop=head_loop, window=window)
+        interpret=interpret, pps=pps, stacked=stacked, window=window)
     q_slots = q_slots.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
     tiles = -(-S // tq)
@@ -519,15 +622,16 @@ def paged_attention_kernel(q: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "pps",
-                                             "head_loop", "window"))
+                                             "stacked", "window"))
 def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
           v_scale, layer, kv_valid_len, sm_scale, interpret, pps,
-          head_loop, window=None):
+          stacked, window=None):
     """The kernel call: rows of ``S`` query slots, ``pps`` pages a
-    compute step. ``n_live`` [B] is each row's trip count: None takes
-    `live_pages`; a walk of all ``MB`` entries gives the same bits (a
-    fully masked step leaves the softmax state untouched), which is what
-    the tests hold it to."""
+    compute step, the KV heads of a step ``stacked`` or a loop
+    (`walk_shape` says which). ``n_live`` [B] is each row's trip count:
+    None takes `live_pages`; a walk of all ``MB`` entries gives the same
+    bits (a fully masked step leaves the softmax state untouched), which
+    is what the tests hold it to."""
     B, S, H, D = q.shape
     T = k_pool.shape[2]
     KV = pool_kv_heads(k_pool, q)
@@ -567,33 +671,43 @@ def _walk(q, block_tables, q_slots, *, n_live, k_pool, v_pool, k_scale,
                 n_live.astype(jnp.int32).reshape(-1), layer.reshape(1)]
     if window is not None:
         prefetch.append(first_page(q_slots, window, T, prefetch[2]))
+    # the flash trio: a head's [rows, D] accumulator and [rows, 1] max
+    # and sum, KV of them; or, the heads stacked, all heads' rows by all
+    # heads' lanes, with the block-diagonal query and its rows' slots
+    trio = (KV * rows, KV * D) if stacked else (KV, rows, D)
+    scratch_shapes = [
+        pltpu.VMEM((2, pps * T, KV * D), k_pool.dtype),
+        pltpu.VMEM((2, pps * T, KV * D), v_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM(trio, jnp.float32),
+        pltpu.VMEM(trio[:-1] + (1,), jnp.float32),
+        pltpu.VMEM(trio[:-1] + (1,), jnp.float32),
+    ]
+    if stacked:
+        scratch_shapes += [pltpu.VMEM(trio, q.dtype),
+                           pltpu.VMEM((KV * rows, 1), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, rows, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((2, pps * T, KV * D), k_pool.dtype),
-            pltpu.VMEM((2, pps * T, KV * D), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((KV, rows, D), jnp.float32),
-            pltpu.VMEM((KV, rows, 1), jnp.float32),
-            pltpu.VMEM((KV, rows, 1), jnp.float32),
-            pltpu.SMEM((2,), jnp.int32),
-        ],
+        scratch_shapes=scratch_shapes + [pltpu.SMEM((2,), jnp.int32)],
     )
     kernel = functools.partial(
         _kernel, sm_scale=sm_scale, n_kv=KV, head_dim=D, block_tokens=T,
         pages_per_step=pps, max_blocks=MB, has_scale=has_scale,
-        head_loop=head_loop, window=window)
+        stacked=stacked, window=window)
     # What the call holds in VMEM, by hand: page buffers; the trio (m
-    # and l a lane tile wide); the q and out blocks, double-buffered; a
-    # head's scores, probabilities and mask. Mosaic's own temporaries
-    # are not in it, so the call asks for more from half the default on.
+    # and l a lane tile wide) and, stacked, the query beside it; the q
+    # and out blocks, double-buffered; a body's scores, probabilities
+    # and mask. Mosaic's own temporaries are not in it, so the call asks
+    # for more from half the default on.
+    body_rows = KV * rows if stacked else rows
     vmem = (4 * pps * T * KV * D * k_pool.dtype.itemsize
-            + KV * rows * (D + 2 * 128) * 4
+            + math.prod(trio) * 4 + KV * rows * 2 * 128 * 4
+            + stacked * math.prod(trio) * q.dtype.itemsize
             + 4 * KV * rows * D * q.dtype.itemsize
-            + 3 * rows * pps * T * 4)
+            + 3 * body_rows * pps * T * 4)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

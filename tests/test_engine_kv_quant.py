@@ -642,15 +642,15 @@ def test_kernel_ragged_rows_match_reference_and_full_walk(
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(ref, np.float32),
             atol=tol, rtol=tol)
-        pps, tq = pak.walk_shape(slots, q.shape[2], 2, q.shape[3], _RT,
-                                 _RMB, kf.dtype.itemsize)
+        pps, tq, stacked = pak.walk_shape(slots, q.shape[2], 2, q.shape[3],
+                                          _RT, _RMB, kf.dtype.itemsize)
         assert tq == slots                       # one tile a row
         full = pak._walk(q, bt, q_slots,
                          n_live=jnp.full((q.shape[0],), _RMB, jnp.int32),
                          k_pool=kf, v_pool=vf, k_scale=sk, v_scale=sv,
                          layer=np.int32(li), kv_valid_len=np.int32(valid),
                          sm_scale=q.shape[3] ** -0.5, interpret=True,
-                         pps=pps, head_loop=False)
+                         pps=pps, stacked=stacked)
         assert jnp.array_equal(got, full)
         seen.append(np.asarray(got, np.float32))
     assert not np.allclose(seen[0], seen[2], atol=1e-3)   # other data
@@ -694,11 +694,13 @@ _ROW_ORDERS = {
 }
 
 
-def _pipeline_case(last_slots, S, quant, rng, mb=_PMB, t=_RT):
+def _pipeline_case(last_slots, S, quant, rng, mb=_PMB, t=_RT, KV=2, gm=2,
+                   D=16):
     """q, pools (two layers, pages of ``t``), table, slots, scales: row
     b's S queries end at ``last_slots[b]`` (None: every slot -1), every
-    table entry a real block of its own."""
-    B, KV, D, gm = len(last_slots), 2, 16, 2
+    table entry a real block of its own; ``KV`` heads of ``D`` with
+    ``gm`` query heads each."""
+    B = len(last_slots)
     q_slots = np.stack([
         np.full(S, -1) if last is None else
         np.maximum(last - S + 1 + np.arange(S), -1) for last in last_slots])
@@ -753,14 +755,15 @@ def test_kernel_rows_of_a_call_are_one_pipeline_and_each_its_own(
         np.asarray(got, np.float32)[asked],
         np.asarray(ref, np.float32)[asked], atol=tol, rtol=tol)
     assert not np.asarray(got, np.float32)[~asked].any()
-    pps, tq = pak.walk_shape(slots, 4, 2, 16, _RT, _PMB, kf.dtype.itemsize)
+    pps, tq, stacked = pak.walk_shape(slots, 4, 2, 16, _RT, _PMB,
+                                      kf.dtype.itemsize)
     assert (pps, tq) == (_PPS, slots)
     full = pak._walk(q, bt, q_slots,
                      n_live=jnp.full((n_rows,), _PMB, jnp.int32),
                      k_pool=kf, v_pool=vf, k_scale=sk, v_scale=sv,
                      layer=np.int32(1), kv_valid_len=np.int32(_PMB * _RT),
                      sm_scale=16 ** -0.5, interpret=True, pps=pps,
-                     head_loop=False)
+                     stacked=stacked)
     assert jnp.array_equal(got, full)
 
 
@@ -826,7 +829,7 @@ def test_kernel_chunk_tiles_chain_across_rows_and_filler(monkeypatch, quant):
     q_slots = jnp.asarray(np.where(
         pos < np.asarray(n_real)[:, None],
         np.asarray(starts)[:, None] + pos, -1), jnp.int32)
-    pps, tq = pak.walk_shape(S, 4, 2, 16, _CT, mb, kf.dtype.itemsize)
+    pps, tq, _ = pak.walk_shape(S, 4, 2, 16, _CT, mb, kf.dtype.itemsize)
     assert (pps, tq) == (4, 32)
     tiles = pak.live_pages(q_slots.reshape(-1, tq), mb * _CT, _CT, mb)
     assert tiles.tolist()[:4] == [4, 8, 12, 16]        # start 0: one step
@@ -933,6 +936,54 @@ def test_paged_walk_row_counters_of_prefill_tiles(nano_model, monkeypatch):
     assert t["paged_walk_rows_chained_total"] <= t["paged_walk_rows_total"]
 
 
+def test_paged_walk_stacked_counter_of_a_decode_dispatch(nano_model):
+    """`paged_walk_rows_stacked_total` counts the rows whose call took
+    the row's KV heads as one operand of a step: a decode dispatch of H
+    tokens over B slots counts H x B, every row the dispatch walks (the
+    rule is `walk_shape`'s, from the call's static shapes: at Mistral's
+    a decode row is stacked, at any horizon)."""
+    cfg, params = nano_model
+    B = 2
+    assert pak.walk_shape(1, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, T,
+                          MAX_LEN // T, 4)[2]
+    assert pak.walk_shape(1, 32, 8, 128, 32, 128, 2) == (16, 1, True)
+    eng = DecodeEngine(params, cfg, batch_slots=B, max_len=MAX_LEN,
+                       kv_block_tokens=T, pipeline_depth=1)
+    rng = np.random.RandomState(5)
+    for L, n in [(5, 9), (6, 9)]:
+        eng.submit(rng.randint(1, cfg.vocab_size, size=L).tolist(), n)
+    eng.step(horizon=1)                 # prefills both, decodes a token
+    before = eng.stats()
+    for H in (1, 2, 4):
+        eng.step(horizon=H)
+    s = eng.stats()
+    assert s["decode_dispatches"] - before["decode_dispatches"] == 3
+    assert s["paged_walk_rows_stacked_total"] \
+        - before["paged_walk_rows_stacked_total"] == (1 + 2 + 4) * B
+    assert s["paged_walk_rows_stacked_total"] \
+        - before["paged_walk_rows_stacked_total"] \
+        == s["paged_walk_rows_total"] - before["paged_walk_rows_total"]
+
+
+def test_paged_walk_stacked_counter_of_128_row_prefill_tiles(nano_model,
+                                                             monkeypatch):
+    """A 4 x 512 prefill at a tile of 128 query tokens counts 0: a
+    tile's heads fill the MXU one at a time and loop, at the nano
+    engine's widths (the tile's budget set to 128 tokens) as at
+    Mistral's (128 tokens x 32 heads, by `walk_shape` itself)."""
+    cfg, params = nano_model
+    assert pak.walk_shape(512, 32, 8, 128, 32, 128, 2) == (16, 128, False)
+    monkeypatch.setattr(pak, "_TILE_ACC_BYTES",
+                        128 * cfg.n_heads * cfg.head_dim * 4)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=MAX_LEN,
+                       kv_block_tokens=T)
+    assert eng._walk_shape(512)[1:] == (128, False)
+    eng._count_prefill_walk(np.zeros(4, np.int64), np.full(4, 511), 512)
+    s = eng.stats()
+    assert s["paged_walk_rows_total"] == 4 * 4      # four tiles a row
+    assert s["paged_walk_rows_stacked_total"] == 0
+
+
 def test_own_kv_is_attended_in_place_of_the_pools_slots():
     """`own_kv` lays the queries' own keys and values over the gathered
     rows at `q_slots`: the result is what a pool holding them there
@@ -968,15 +1019,22 @@ def test_own_kv_is_attended_in_place_of_the_pools_slots():
 # ---------------------------------------------------------------------------
 
 # sha256[:16] of the kernel's float32 output bytes on `_ragged_case(S,
-# quant, RandomState(17 + S))`, layer 1, every slot valid, recorded from
-# the kernel as it stood BEFORE it gained query tiles (grid `(B,)`, one
-# step holding all S*G query rows of a row).
+# quant, RandomState(17 + S))`, layer 1, every slot valid. The bf16 pair
+# is what the kernel gave BEFORE it gained query tiles (grid `(B,)`, one
+# step holding all S*G query rows of a row), and held through PR 51. The
+# six with float32 queries were RETAKEN in PR 51: a step's scores are now
+# one product over all the row's heads' lanes (a head's query beside
+# zeros), and the CPU's float32 matmul associates a contraction of 32
+# otherwise than one of 16: at most 2.4e-7 from the old kernel's output
+# on values up to 2.5 (dense 1.2e-7 / 2.4e-7 for 1 / 4 slots, int8 1.8e-7
+# / 1.8e-7, fp8 2.4e-7 / 2.4e-7), one or two units in the last place.
+# bf16 products are exact in float32 and their sums came out the same.
 _ONE_TILE_BITS = {
-    (None, 1): "a6c496149e19db26", (None, 4): "32db758252d42342",
+    (None, 1): "e5d1ae45eb6db9b5", (None, 4): "9321599ced3f83c6",
     ("bf16", 1): "8e36b600eac233f7", ("bf16", 4): "b4ba18a027e66db3",
-    ("int8", 1): "1c4b102b9b337fb7", ("int8", 4): "95b603bc93899c70",
-    ("fp8_e4m3", 1): "58772e22a3298d1b",
-    ("fp8_e4m3", 4): "b541bc566ae39f3b",
+    ("int8", 1): "373d94ce23dd14ba", ("int8", 4): "8ad4e2bc2bb44d96",
+    ("fp8_e4m3", 1): "629f61050d7b951e",
+    ("fp8_e4m3", 4): "37d04a17d03623dd",
 }
 
 
@@ -986,7 +1044,9 @@ _ONE_TILE_BITS = {
 def test_kernel_one_tile_bits_are_what_they_were(monkeypatch, quant, slots):
     """A decode token and a speculative window are ONE tile a row, all
     rows in one call as before tiles existed, so the output is, bit for
-    bit, what the untiled kernel gave. And tiling is only a
+    bit, what the untiled kernel gave (what the stacked body gave when
+    it replaced the heads' own, where float32 queries round another way:
+    `_ONE_TILE_BITS`). And tiling is only a
     grouping of query rows: the same call cut into tiles of one query
     (each walking no further than its own slot) gives the same values
     (a matmul of fewer rows may round its sums in another order)."""
@@ -1001,7 +1061,8 @@ def test_kernel_one_tile_bits_are_what_they_were(monkeypatch, quant, slots):
     assert hashlib.sha256(np.asarray(got, np.float32).tobytes()) \
         .hexdigest()[:16] == _ONE_TILE_BITS[quant, slots]
     real = pak.walk_shape
-    monkeypatch.setattr(pak, "walk_shape", lambda *a: (real(*a)[0], 1))
+    monkeypatch.setattr(pak, "walk_shape",
+                        lambda *a: (real(*a)[0], 1, real(*a)[2]))
     np.testing.assert_allclose(
         np.asarray(paged_attention(q, kf, vf, bt, q_slots, **kw),
                    np.float32),
@@ -1056,8 +1117,8 @@ def test_kernel_tiled_chunks_match_reference(monkeypatch, slots, quant,
     rng = np.random.RandomState(31 + slots + group)
     q, kf, vf, bt, q_slots, sk, sv, MB = _chunk_case(slots, quant, group,
                                                      rng)
-    pps, tq = pak.walk_shape(slots, 2 * group, 2, 16, _CT, MB,
-                             kf.dtype.itemsize)
+    pps, tq, _ = pak.walk_shape(slots, 2 * group, 2, 16, _CT, MB,
+                                kf.dtype.itemsize)
     assert (pps, tq) == (4, min(slots, 32))
     kw = dict(layer=1, kv_valid_len=MB * _CT, k_scale=sk, v_scale=sv)
     ref = paged_attention(q, kf, vf, bt, q_slots, impl="reference", **kw)

@@ -73,9 +73,7 @@ def _compiles_with_kernel(fn, *args) -> bool:
 
 
 def _paged_kernel_compiles(v5e, B, S, T, MB, NB, quant, H=32,
-                           KV=8) -> bool:
-    D = 128
-
+                           KV=8, D=128) -> bool:
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
@@ -123,6 +121,15 @@ def test_paged_kernel_compiles_at_olmoe_shape(v5e):
                                   KV=16)
 
 
+def test_paged_kernel_compiles_at_qwen3next_shape(v5e):
+    """`benchmark/configs/qwen3-next-80b-a3b-serve.json` as the engine
+    runs its gated-attention layers: 16 query heads over 2 KV heads of
+    256 (a head is two lane tiles of the stacked operand), 64 rows, 132
+    table entries of 256 tokens, two pages a step."""
+    assert _paged_kernel_compiles(v5e, 64, 1, 256, 132, 4097, None, H=16,
+                                  KV=2, D=256)
+
+
 def test_paged_kernel_compiles_with_a_window_at_the_pair_layout(v5e):
     """`benchmark/configs/phi-4-mini-flash-serve.json`: key heads of 64
     read as PAIRS of 128 lanes (queries zero-padded to the pair, 40 query
@@ -154,31 +161,36 @@ def _pallas_calls(jaxpr):
                 yield from _pallas_calls(inner)
 
 
-# rows, query slots, Q heads, KV heads, table entries, pool blocks, window
+# rows, query slots, Q heads, KV heads, head size, block tokens, table
+# entries, pool blocks, window
 _CELL_CALLS = {
-    "mistral_decode": (32, 1, 32, 8, 128, 1878, None),
-    "mistral_4x512": (4, 512, 32, 8, 128, 1878, None),
-    "olmoe_decode": (32, 1, 16, 16, 64, 615, None),
-    "olmoe_4x512": (4, 512, 16, 16, 64, 615, None),
-    "phi4flash_decode": (64, 1, 40, 10, 160, 1353, 512),
-    "phi4flash_4x512": (4, 512, 40, 10, 160, 1353, 512),
+    "mistral_decode": (32, 1, 32, 8, 128, 32, 128, 1878, None),
+    "mistral_4x512": (4, 512, 32, 8, 128, 32, 128, 1878, None),
+    "olmoe_decode": (32, 1, 16, 16, 128, 32, 64, 615, None),
+    "olmoe_4x512": (4, 512, 16, 16, 128, 32, 64, 615, None),
+    "phi4flash_decode": (64, 1, 40, 10, 128, 32, 160, 1353, 512),
+    "phi4flash_4x512": (4, 512, 40, 10, 128, 32, 160, 1353, 512),
+    "qwen3next_decode": (64, 1, 16, 2, 256, 256, 132, 4097, None),
 }
 
 
 @pytest.mark.parametrize("case", list(_CELL_CALLS))
 def test_paged_kernel_call_orders_its_rows_and_asks_for_what_it_did(case):
-    """The kernel's call at the three serving cells' shapes, as traced:
-    the rows of a call are one pipeline, so the grid axis is
-    "arbitrary"; what carries it from row to row is two words of SMEM;
-    the VMEM scratch is what it was, two slots of a step's keys each for
-    K and V beside the softmax trio, so a decode row's call still asks
-    for no `vmem_limit_bytes` (what a kernel may take, XLA cannot give
-    the program around it: PERF.md PR 30) and a chunk's tiles for the 32
-    MiB they asked for before."""
+    """The kernel's call at the serving cells' shapes, as traced: the
+    rows of a call are one pipeline, so the grid axis is "arbitrary";
+    what carries it from row to row is two words of SMEM; the VMEM
+    scratch is two slots of a step's keys each for K and V beside the
+    softmax trio, a head's at a prefill tile and, at a decode row, the
+    stacked one: all heads' rows by all heads' lanes (128 KiB at
+    Mistral's and OLMoE's shapes, 200 KiB at phi4flash's, 32 KiB at
+    qwen3next's; the per-head trio's accumulators were 16 / 8 / 20 / 16),
+    the block-diagonal query of that shape and the rows' slots. A decode
+    row's call still asks for no `vmem_limit_bytes` (what a kernel may
+    take, XLA cannot give the program around it: PERF.md PR 30) and a
+    chunk's tiles for the 32 MiB they asked for before."""
     from ray_tpu.ops.paged_attention_kernel import walk_shape
 
-    B, S, H, KV, MB, NB, window = _CELL_CALLS[case]
-    T, D = 32, 128
+    B, S, H, KV, D, T, MB, NB, window = _CELL_CALLS[case]
     sd = jax.ShapeDtypeStruct
 
     def fn(q, k, v, bt, slots, layer):
@@ -193,21 +205,28 @@ def test_paged_kernel_call_orders_its_rows_and_asks_for_what_it_did(case):
     (call,) = _pallas_calls(jaxpr.jaxpr)
     params = call.params["compiler_params"]["mosaic_tpu"]
     assert params.dimension_semantics == ("arbitrary",)
-    pps, tq = walk_shape(S, H, KV, D, T, MB, 2)
+    pps, tq, stacked = walk_shape(S, H, KV, D, T, MB, 2)
+    assert stacked == (S == 1)      # a decode row; a chunk's tiles loop
     keys = pps * T          # 512, or what fits 1 MiB a slot
-    assert keys == {8: 512, 16: 256, 10: 384}[KV]
+    assert keys == {(8, 128): 512, (16, 128): 256, (10, 128): 384,
+                    (2, 256): 512}[KV, D]
     tiles = -(-S // tq)
     grid = call.params["grid_mapping"].grid
     assert grid == (-(-B * tiles // 8) * 8,)
     rows = tq * (H // KV)
     scratch = [str(a) for a in call.params["grid_mapping"].scratch_avals]
+    trio = [f"Ref<vmem>{{float32[{KV},{rows},{D}]}}",
+            f"Ref<vmem>{{float32[{KV},{rows},1]}}",
+            f"Ref<vmem>{{float32[{KV},{rows},1]}}"]
+    if stacked:
+        trio = [f"Ref<vmem>{{float32[{KV * rows},{KV * D}]}}",
+                f"Ref<vmem>{{float32[{KV * rows},1]}}",
+                f"Ref<vmem>{{float32[{KV * rows},1]}}",
+                f"Ref<vmem>{{bfloat16[{KV * rows},{KV * D}]}}",
+                f"Ref<vmem>{{int32[{KV * rows},1]}}"]
     assert scratch == [
         f"Ref<vmem>{{bfloat16[2,{keys},{KV * D}]}}"] * 2 + [
-        "Ref<semaphore_mem>{dma_sem[2,2]}",
-        f"Ref<vmem>{{float32[{KV},{rows},{D}]}}",
-        f"Ref<vmem>{{float32[{KV},{rows},1]}}",
-        f"Ref<vmem>{{float32[{KV},{rows},1]}}",
-        "Ref<smem>{int32[2]}"]
+        "Ref<semaphore_mem>{dma_sem[2,2]}"] + trio + ["Ref<smem>{int32[2]}"]
     assert params.vmem_limit_bytes == (None if S == 1 else 32 << 20)
 
 

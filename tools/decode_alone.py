@@ -14,8 +14,13 @@ slots stay dead) for every N and L, then calls `_decode_multi_paged` (the
 cell's `decode_horizon`, 8 where it names none) 3 x 40 times on the SAME
 row state and times it on the device's queue (async dispatch, one wait at
 the end).
-Prints `DECODE_AB {json}`: ms a token and, for an expert-layer model, the
-experts hit a layer-step. On the chip: parent, change, change, parent in
+Prints `DECODE_AB {json}`: ms a token, the pages the paged kernel walks a
+token in full steps' worth (pages over `walk_shape`'s pages a step, summed
+over every layer that calls it: a last step of one page is a sixteenth of
+a 16-page step), for an expert-layer model the experts hit a layer-step,
+and, where two lengths differ in pages, `us_per_step`: what one more FULL
+step of keys costs the program, the difference between the longest and
+the shortest length over the difference in steps' worth. On the chip: parent, change, change, parent in
 one `chiprun` call; `--rehearse` runs the cell's rehearsal size on the CPU."""
 import argparse
 import importlib
@@ -42,6 +47,7 @@ from benchmark.harness.drivers import serve_model  # noqa: E402
 from benchmark.harness.model import llama_config  # noqa: E402
 from ray_tpu.models import engine as E  # noqa: E402
 from ray_tpu.models import llama_init  # noqa: E402
+from ray_tpu.ops.paged_attention_kernel import walk_shape  # noqa: E402
 
 assert E.__file__.startswith(a.root + "/ray_tpu"), E.__file__
 cell = spec.load_cell(a.cell)
@@ -77,6 +83,25 @@ lens = [int(x) for x in a.len.split(",") if x] or (
 lives = [int(x) for x in a.live.split(",") if x] or [opts["batch_slots"]]
 out = {"tag": a.tag, "cell": a.cell, "device": str(jax.devices()[0]),
        "horizon": H}
+
+
+def kernel_steps(eng, row_len):
+    """Full steps' worth of pages the paged kernel walks for a decode
+    token of these rows, the mean over the block's H tokens: a layer
+    that reads the full cache walks the pages up to the query's slot, a
+    window layer those from its window's first page."""
+    cfg, T = eng.cfg, eng.kv_block_tokens
+    pps = walk_shape(1, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, T,
+                     eng._mb, eng._pool_k.dtype.itemsize)[0]
+    slots = row_len[:, None] + np.arange(H)[None, :]
+    pages = (slots // T + 1) \
+        * getattr(cfg, "full_cache_readers", cfg.n_layers)
+    if eng.kv_pool_w is not None:
+        first = np.maximum(slots - (cfg.sliding_window - 1), 0) // T
+        pages = pages + (slots // T + 1 - first) * cfg.n_window_layers
+    return float(pages.sum()) / (pps * H)
+
+
 for L in lens:
     for n_live in lives:
         eng = E.DecodeEngine(params, cfg, **opts)
@@ -123,8 +148,16 @@ for L in lens:
         out[f"ms_per_token_{key}"] = reps
         out[f"row_len_{key}"] = [int(eng.row_len[rows].min()),
                                  int(eng.row_len[rows].max())]
+        out[f"kernel_steps_{key}"] = kernel_steps(eng, eng.row_len[rows])
         if seen is not None:    # the last call's counts: hit / layer-steps
             d = np.asarray(seen) - np.asarray(ctr)
             out[f"experts_hit_{key}"] = float(d[2]) / float(d[3])
         del eng, pk, pv, ll, hyb
+for n_live in lives:
+    lo, hi = (f"L{L}_live{n_live}" for L in (min(lens), max(lens)))
+    more = out[f"kernel_steps_{hi}"] - out[f"kernel_steps_{lo}"]
+    if more > 0:
+        out[f"us_per_step_live{n_live}"] = 1e3 * (
+            min(out[f"ms_per_token_{hi}"])
+            - min(out[f"ms_per_token_{lo}"])) / more
 print("DECODE_AB " + json.dumps(out), flush=True)
