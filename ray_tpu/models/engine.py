@@ -1676,6 +1676,11 @@ class DecodeEngine:
         self.kv_walk_tokens_full_total = 0     # ... per READER of the pool
         self.window_blocks_freed_total = 0     # released behind the window
         self.window_pool_peak_blocks = 0
+        # a selecting family's window layers (an `MlaConfig` with
+        # `layer_types`), a decode dispatch: the rows that asked and the
+        # slots one window layer reads for them, min(len, window) each
+        self.swa_window_rows_total = 0
+        self.swa_window_slots_total = 0
         # (recurrent state of any kind: `engine_metrics` says which)
         self.ssm_state_resets_total = 0        # admissions from zero state
         self.ssm_row_steps_total = 0           # live rows x decode tokens
@@ -1694,9 +1699,11 @@ class DecodeEngine:
             self._row_blocks_w: List[List[int]] = [
                 [] for _ in range(self.B)]
             self._w_lo = np.zeros((self.B,), np.int64)  # first held block
-            pools_w = _zero_pools(planes_w, n_blocks_w, T,
-                                  jnp.dtype(cfg.dtype), False, None)
-            hyb.update((pl.name, x) for pl, x in zip(planes_w, pools_w))
+            # a pool a plane, each of its own geometry: a K and a V plane
+            # (a `HybridConfig`), one latent plane (an `MlaConfig`)
+            hyb.update((pl.name, jnp.zeros(
+                (pl.layers, n_blocks_w, T, pl.lanes), jnp.dtype(cfg.dtype)))
+                for pl in planes_w)
         self._hyb: Optional[Params] = hyb or None
         self._prefix: Optional[PrefixCacheIndex] = None
         if prefix_cache:
@@ -2530,6 +2537,10 @@ class DecodeEngine:
         self.paged_walk_rows_chained_total += H * (self.B - 1)
         if self._selects:       # its attention is not this kernel's
             self._count_selection(slots + 1, decode=True)
+            if self.kv_pool_w is not None:
+                self.swa_window_rows_total += slots.size
+                self.swa_window_slots_total += int(np.minimum(
+                    slots + 1, self.cfg.sliding_window).sum())
         else:
             self.paged_walk_rows_stacked_total += \
                 H * self.B * self._walk_shape(1)[2]
@@ -2553,10 +2564,11 @@ class DecodeEngine:
         """Account the queries of one dispatch of a config that selects
         (an `MlaConfig`): each sees ``live`` tokens, the indexer scores
         them all and attention reads `index_topk` of them at most, in
-        every layer."""
+        every layer that selects (all but its window layers)."""
         cfg = self.cfg
-        scored = int(live.sum()) * cfg.n_layers
-        selected = int(np.minimum(live, cfg.index_topk).sum()) * cfg.n_layers
+        scored = int(live.sum()) * cfg.n_select_layers
+        selected = int(np.minimum(live, cfg.index_topk).sum()) \
+            * cfg.n_select_layers
         self.indexer_tokens_scored_total += scored
         self.indexer_tokens_selected_total += selected
         if decode:
@@ -2912,6 +2924,7 @@ class DecodeEngine:
         # the paged-walk ones.
         for name in ("kv_walk_tokens_window_total",
                      "kv_walk_tokens_full_total",
+                     "swa_window_rows_total", "swa_window_slots_total",
                      "window_blocks_freed_total", "window_pool_peak_blocks",
                      "ssm_state_resets_total", "ssm_row_steps_total",
                      "prefill_layer_tokens_total",

@@ -37,7 +37,13 @@ alike; ``kv_walk_tokens_full_total`` counts, a decode dispatch, the tokens
 the paged kernel is asked to read once for each layer that READS the
 table's pool (a `HybridConfig`'s one full layer and its cross-attention
 readers, each attention layer of a `GdnConfig`). They are 0 for a family
-without recurrent state.
+without recurrent state. A SELECTING family (an `MlaConfig`) counts its
+own: the ``indexer_*`` counters are token-layers over the layers that
+select (all but its window layers), and where it has window layers
+``swa_window_rows_total`` / ``swa_window_slots_total`` count, a decode
+dispatch, the row-tokens that asked and the slots ONE window layer reads
+for them (min(row length, `sliding_window`) each; times the config's
+window layers for what the stack reads).
 
 All instruments carry an ``engine`` tag (one DecodeEngine = one tag
 value) so several engines in one process — or one per replica — stay

@@ -1,5 +1,8 @@
 """Latent-attention decoder LM with a learned sparse-attention indexer and
-held experts beside a shared one (the DeepSeek-V3.2-Exp shape).
+held experts beside a shared one (the DeepSeek-V3.2-Exp shape), and, where
+the config names window layers, latent attention of a SECOND geometry
+beside it in one stack (the dots3-note shape: selected full layers beside
+window layers with their own head count, ranks, rotary base and scale).
 
 No layer of this family is the Llama layer, so like `hybrid` it brings its
 own stack (`layers_paged`) and shares everything around it: the serving
@@ -13,7 +16,20 @@ The stack is read from the config alone (`MlaConfig.layer_plan`):
     segment "moe":    the rest, of      [latent attention, expert layer]
 
 each ``h = h + Attn(N(h)); h = h + F(N(h))``, each segment one `lax.scan`
-over its layers with the pools in the carry.
+over its layers with the pools in the carry. A config with `layer_types`
+mixes two kinds of attention: the expert layers are then periods of unlike
+kinds (F S S S), a period's runs of one kind scanned inside the scan over
+periods, and a layer's parameters lie with its kind's
+(``params["moe"]["full" | "window"]``, stack order inside a kind).
+
+A WINDOW layer (`SWA`) is the same latent attention with its own widths
+and no indexer: a query at t attends the slots ``0 <= t - s <
+sliding_window``. Its latent rows live in a plane of their own behind the
+engine's WINDOW table (`bt_w`), of which a row keeps the blocks that cover
+its last `sliding_window` slots: the layer reads the few pages that cover
+its queries' windows (`_window_pages`) with the window as the mask the
+kernels already take. Every plane holds the layers of ITS kind only and is
+indexed by a layer's place among them.
 
 What a token stores (`MlaConfig.cache_planes`), for every layer, through
 ONE block table a row:
@@ -49,9 +65,10 @@ chip, reads the pages where they lie.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import sys
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,8 +82,27 @@ from ray_tpu.ops import scope_names as sn
 
 Params = Dict[str, Any]
 
-# every layer's mixer: latent attention over the slots its indexer chose
+# a full layer's mixer: latent attention over the slots its indexer chose
 MLA = LayerKind("mla", writes="latent", reads="latent")
+# a window layer's: latent attention of its own geometry over the last
+# `sliding_window` slots, through the window table
+SWA = LayerKind("mla", writes="window", reads="window")
+_KIND_NAMES = {"full": MLA, "window": SWA}
+
+
+class _Geometry(NamedTuple):
+    """One layer kind's attention as the shared code reads it."""
+
+    heads: int
+    nope: int
+    rope: int
+    v: int
+    q_rank: int
+    kv_rank: int
+    lanes: int             # a latent row as stored
+    inv_freq: Any          # [rope / 2] float32
+    sm_scale: float
+    scope_gate: str
 
 # Queries are scored and selected this many a row at a time: a block's
 # indexer scores [rows, block, heads, max_len] float32 are the program's
@@ -118,6 +154,24 @@ class MlaConfig:
     max_seq_len: int = 163840
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
+    # A second kind of layer. `layer_types`: "full" | "window", one a
+    # layer; None: every layer full, and nothing below is read. A window
+    # layer attends the last `sliding_window` slots (the query's own
+    # counted) with the ``swa_*`` widths and keeps no indexer.
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: int = 0
+    swa_n_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    # one sigmoid scalar a head from the layer's normed input, on the
+    # heads' outputs ahead of the output projection (every layer's kind)
+    attn_gate: bool = False
+    # the normed query and key-value latents times sqrt(dim / their rank)
+    lora_rescale: bool = False
 
     router = "sigmoid_grouped"         # `moe.moe_ffn_dropless` reads it
 
@@ -140,6 +194,21 @@ class MlaConfig:
                 or self.qk_rope_head_dim > self.index_head_dim:
             raise ValueError("MlaConfig: the rotary width must be even and "
                              "fit the indexer's head")
+        if self.layer_types is not None:
+            kinds = self.layer_types
+            if len(kinds) != self.n_layers \
+                    or any(k not in _KIND_NAMES for k in kinds):
+                raise ValueError("MlaConfig: layer_types names 'full' or "
+                                 "'window' once a layer")
+            if "window" in kinds[:self.n_dense_layers]:
+                raise ValueError("MlaConfig: the leading dense layers are "
+                                 "full layers")
+            if "window" in kinds and (
+                    self.sliding_window < 1 or self.swa_n_heads < 1
+                    or self.swa_qk_rope_head_dim % 2):
+                raise ValueError("MlaConfig: window layers need "
+                                 "sliding_window, the swa_* widths and an "
+                                 "even rotary width")
 
     # -- what `moe_ffn_dropless` and the engine read -----------------------
 
@@ -154,8 +223,8 @@ class MlaConfig:
 
     @property
     def latent_lanes(self) -> int:
-        """Lanes of a latent row as stored: kv_lora_rank + rotary, up to
-        the lane tile (576 -> 640)."""
+        """Lanes of a full layer's latent row as stored: kv_lora_rank +
+        rotary, up to the lane tile (576 -> 640)."""
         w = self.kv_lora_rank + self.qk_rope_head_dim
         return -(-w // _LANE) * _LANE
 
@@ -168,23 +237,68 @@ class MlaConfig:
             s *= m * m
         return s
 
+    def layer_kinds(self) -> Tuple[LayerKind, ...]:
+        """One `LayerKind` a layer, in stack order."""
+        if self.layer_types is None:
+            return (MLA,) * self.n_layers
+        return tuple(_KIND_NAMES[k] for k in self.layer_types)
+
+    @property
+    def n_window_layers(self) -> int:
+        return self.layer_kinds().count(SWA)
+
+    @property
+    def n_select_layers(self) -> int:
+        """Layers with an indexer and a selection: the full ones."""
+        return self.n_layers - self.n_window_layers
+
+    def geometry(self, kind: LayerKind) -> _Geometry:
+        """The widths, rotary and scale of ``kind``'s attention."""
+        if kind is MLA:
+            return _Geometry(
+                self.n_heads, self.qk_nope_head_dim, self.qk_rope_head_dim,
+                self.v_head_dim, self.q_lora_rank, self.kv_lora_rank,
+                self.latent_lanes, yarn_inv_freq(self), self.sm_scale,
+                sn.ATTN_GATE)
+        n, r, rc = (self.swa_qk_nope_head_dim, self.swa_qk_rope_head_dim,
+                    self.swa_kv_lora_rank)
+        return _Geometry(
+            self.swa_n_heads, n, r, self.swa_v_head_dim,
+            self.swa_q_lora_rank, rc, -(-(rc + r) // _LANE) * _LANE,
+            _inv_freq(r, self.swa_rope_theta, None), (n + r) ** -0.5,
+            sn.SWA_GATE)
+
     def layer_plan(self) -> Tuple[Segment, ...]:
         """The stack as segments, in `hybrid.Segment`'s terms: the ONE
-        description the scans, the pools and the counters are built from."""
+        description the scans, the pools and the counters are built from.
+        The expert layers are whole periods of the shortest pattern their
+        kinds repeat (F S S S), then what is left of a period."""
+        kinds, nd = self.layer_kinds(), self.n_dense_layers
         segs = []
-        if self.n_dense_layers:
-            segs.append(Segment("dense", (MLA,), self.n_dense_layers, 0))
-        segs.append(Segment("moe", (MLA,), self.n_moe_layers,
-                            self.n_dense_layers))
+        if nd:
+            segs.append(Segment("dense", (MLA,), nd, 0))
+        moe = kinds[nd:]
+        for p in range(1, len(moe) + 1):
+            q = len(moe) // p
+            if moe[:q * p] == moe[:p] * q \
+                    and moe[q * p:] == moe[:len(moe) % p]:
+                break
+        segs.append(Segment("moe", moe[:p], q, nd))
+        if len(moe) % p:
+            segs.append(Segment("moe_tail", moe[q * p:], 1, nd + q * p))
         return tuple(segs)
 
     def cache_planes(self) -> Tuple[CachePlane, ...]:
         """What a token stores: a latent row and an indexer key in every
-        layer, behind one table; no value plane."""
-        return (CachePlane("latent", "full", self.n_layers,
-                           self.latent_lanes, jnp.dtype(self.dtype)),
-                CachePlane("index", "full", self.n_layers,
-                           self.index_head_dim, jnp.dtype(self.dtype)))
+        FULL layer, behind one table, no value plane; and a latent row of
+        their own width in every window layer, behind the window table."""
+        dt, nf = jnp.dtype(self.dtype), self.n_select_layers
+        planes = (CachePlane("latent", "full", nf, self.latent_lanes, dt),
+                  CachePlane("index", "full", nf, self.index_head_dim, dt))
+        if self.n_window_layers:
+            planes += (CachePlane("wlatent", "window", self.n_window_layers,
+                                  self.geometry(SWA).lanes, dt),)
+        return planes
 
     def state_planes(self):
         return ()
@@ -238,15 +352,16 @@ class MlaConfig:
         defaults.update(kw)
         return MlaConfig(**defaults)
 
-    def _attn_params(self) -> int:
-        d, H = self.dim, self.n_heads
-        n, r, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
-                   self.v_head_dim)
-        rq, rc = self.q_lora_rank, self.kv_lora_rank
-        IH, ID = self.index_n_heads, self.index_head_dim
-        return (d * rq + rq + rq * H * (n + r) + d * (rc + r) + rc
-                + rc * H * (n + v) + H * v * d
-                + rq * IH * ID + d * ID + 2 * ID + d * IH + 2 * d)
+    def _attn_params(self, kind: LayerKind = MLA) -> int:
+        d, g = self.dim, self.geometry(kind)
+        n = (d * g.q_rank + g.q_rank + g.q_rank * g.heads * (g.nope + g.rope)
+             + d * (g.kv_rank + g.rope) + g.kv_rank
+             + g.kv_rank * g.heads * (g.nope + g.v) + g.heads * g.v * d
+             + 2 * d + d * g.heads * self.attn_gate)
+        if kind is MLA:
+            IH, ID = self.index_n_heads, self.index_head_dim
+            n += g.q_rank * IH * ID + d * ID + 2 * ID + d * IH
+        return n
 
     def num_params(self) -> int:
         """Parameters HELD here (held experts, this vocabulary)."""
@@ -255,7 +370,7 @@ class MlaConfig:
         moe = self.n_held * 3 * d * self.expert_dim + shared \
             + d * self.n_experts + self.n_experts
         return (2 * self.vocab_size * d + d
-                + self.n_layers * self._attn_params()
+                + sum(self._attn_params(k) for k in self.layer_kinds())
                 + self.n_dense_layers * 3 * d * self.ffn_dim
                 + self.n_moe_layers * moe)
 
@@ -272,12 +387,11 @@ def mla_init(key: jax.Array, cfg: MlaConfig) -> Params:
     logits). The expert
     stacks hold the `held_experts` alone. Jit it with `cfg` static to
     build a real-size model on the device in one program."""
-    d, H = cfg.dim, cfg.n_heads
-    n, r, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    rq, rc = cfg.q_lora_rank, cfg.kv_lora_rank
+    d = cfg.dim
     IH, ID = cfg.index_n_heads, cfg.index_head_dim
     pdt = cfg.param_dtype
-    keys = iter(jax.random.split(key, 64))
+    # (a stack of two kinds draws for a stack a kind, and a tail's)
+    keys = iter(jax.random.split(key, 64 if cfg.layer_types is None else 128))
 
     def mat(lead, n_in, n_out, std=None):
         std = n_in ** -0.5 if std is None else std
@@ -291,28 +405,36 @@ def mla_init(key: jax.Array, cfg: MlaConfig) -> Params:
     def ones(lead, width):
         return jnp.ones((*lead, width), pdt)
 
-    def attn(lead):
-        return {
+    def attn(lead, kind=MLA):
+        g = cfg.geometry(kind)
+        H, n, r, v, rq, rc = g[:6]
+        out = {
             "attn_norm": ones(lead, d), "mlp_norm": ones(lead, d),
             "wq_a": mat(lead, d, rq), "q_norm": ones(lead, rq),
             "wq_b": mat(lead, rq, H * (n + r)),
             "wkv_a": mat(lead, d, rc + r), "kv_norm": ones(lead, rc),
             "wk_b": mat(lead, rc, H * n), "wv_b": mat(lead, rc, H * v),
             "wo": mat(lead, H * v, d),
-            "wi_q": mat(lead, rq, IH * ID), "wi_k": mat(lead, d, ID),
-            "ik_norm_w": ones(lead, ID), "ik_norm_b": vec(lead, ID, 0.02),
-            "wi_w": mat(lead, d, IH),
         }
+        if kind is MLA:
+            out.update({
+                "wi_q": mat(lead, rq, IH * ID), "wi_k": mat(lead, d, ID),
+                "ik_norm_w": ones(lead, ID),
+                "ik_norm_b": vec(lead, ID, 0.02),
+                "wi_w": mat(lead, d, IH)})
+        if cfg.attn_gate:
+            out["w_attn_gate"] = mat(lead, d, H)
+        return out
 
     def dense(lead):
         f = cfg.ffn_dim
         return {**attn(lead), "w_gate": mat(lead, d, f),
                 "w_up": mat(lead, d, f), "w_down": mat(lead, f, d)}
 
-    def moe(lead):
+    def moe(lead, kind=MLA):
         f, eh = cfg.expert_dim, cfg.n_held
         fs = f * cfg.n_shared_experts
-        out = {**attn(lead), "w_router": mat(lead, d, cfg.n_experts),
+        out = {**attn(lead, kind), "w_router": mat(lead, d, cfg.n_experts),
                "router_bias": vec(lead, cfg.n_experts, _ROUTER_BIAS_STD)
                .astype(jnp.float32),
                "we_gate": mat((*lead, eh), d, f),
@@ -323,14 +445,25 @@ def mla_init(key: jax.Array, cfg: MlaConfig) -> Params:
                        ws_down=mat(lead, fs, d))
         return out
 
+    def segment(seg):
+        """An expert segment's layers: one stack where they are of one
+        kind, else a stack a kind, in stack order inside it."""
+        if cfg.layer_types is None:
+            return moe((seg.periods,))
+        return {name: moe((seg.periods * seg.kinds.count(kind),), kind)
+                for name, kind in _KIND_NAMES.items() if kind in seg.kinds}
+
+    plan = {seg.name: seg for seg in cfg.layer_plan()}
     params = {
         "tok_embed": mat((), cfg.vocab_size, d, std=0.02),
-        "moe": moe((cfg.n_moe_layers,)),
+        "moe": segment(plan["moe"]),
         "final_norm": ones((), d),
         "lm_head": mat((), d, cfg.vocab_size),
     }
     if cfg.n_dense_layers:
         params["dense"] = dense((cfg.n_dense_layers,))
+    if "moe_tail" in plan:
+        params["moe_tail"] = segment(plan["moe_tail"])
     return params
 
 
@@ -339,15 +472,19 @@ def mla_init(key: jax.Array, cfg: MlaConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 def yarn_inv_freq(cfg: MlaConfig) -> np.ndarray:
-    """[qk_rope_head_dim / 2] float32 inverse frequencies: plain rotary's,
-    blended with their `factor`-times slower copies by yarn's ramp between
-    the correction dimensions of `beta_fast` and `beta_slow` rotations at
-    the original context length (as `deepseek_v3` computes them)."""
-    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    """The full layers' `_inv_freq`."""
+    return _inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_scaling)
+
+
+def _inv_freq(dim: int, base: float, rope_scaling) -> np.ndarray:
+    """[dim / 2] float32 inverse frequencies: plain rotary's, blended
+    with their `factor`-times slower copies by yarn's ramp between the
+    correction dimensions of `beta_fast` and `beta_slow` rotations at the
+    original context length (as `deepseek_v3` computes them)."""
     pos = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if not cfg.rope_scaling:
+    if not rope_scaling:
         return (1.0 / pos).astype(np.float32)
-    factor, orig, beta_fast, beta_slow = cfg.rope_scaling[:4]
+    factor, orig, beta_fast, beta_slow = rope_scaling[:4]
 
     def correction_dim(rotations):
         return dim * math.log(orig / (rotations * 2 * math.pi)) \
@@ -504,45 +641,92 @@ def _by_query_blocks(fn, Sq: int, *per_query):
 # The stack against the engine's pools
 # ---------------------------------------------------------------------------
 
-def _attention(h, p, li, pool_c, pool_i, bt, slots, q_slots, cfg: MlaConfig,
-               want_selection: bool = False):
-    """One layer's ``h + Attn(N(h))`` for rows [B, S] at ``slots``: writes
-    the chunk's latent rows and indexer keys into layer ``li`` of the two
-    planes, then selects and attends through the table. Returns (h, the
-    planes and, asked, which slots each query chose [B, S, span] bool)."""
+def _latent_proj(a, p, slots, g: _Geometry, cfg: MlaConfig):
+    """A layer's low-rank projections in ``g``'s widths, for rows [B, S]
+    at ``slots``: (the query latent ``c_q``, the absorbed queries laid out
+    like a latent row [B, S, H, lanes], the latent rows to store
+    [B, S, lanes], cos, sin). Call it under the kind's projection scope."""
+    dt = cfg.dtype
+    B, S, _ = a.shape
+    H, n, r, rc = g.heads, g.nope, g.rope, g.kv_rank
+    ang = jnp.maximum(slots, 0).astype(jnp.float32)[..., None] \
+        * g.inv_freq                                      # [B, S, r / 2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    c_q = _rmsnorm(jnp.einsum("bsd,de->bse", a, p["wq_a"].astype(dt)),
+                   p["q_norm"], cfg.norm_eps)
+    if cfg.lora_rescale:
+        c_q = c_q * lora_rescale(cfg.dim, g.q_rank)
+    q = jnp.einsum("bse,ef->bsf", c_q, p["wq_b"].astype(dt)) \
+        .reshape(B, S, H, n + r)
+    q_rope = _rope_pairs(q[..., n:], cos[:, :, None], sin[:, :, None],
+                         True)
+    kv = jnp.einsum("bsd,de->bse", a, p["wkv_a"].astype(dt))
+    c = _rmsnorm(kv[..., :rc], p["kv_norm"], cfg.norm_eps)
+    if cfg.lora_rescale:
+        c = c * lora_rescale(cfg.dim, rc)
+    k_rope = _rope_pairs(kv[..., rc:], cos, sin, True)
+    # absorbed: the key map goes to the query's side
+    q_abs = jnp.einsum("bshn,chn->bshc", q[..., :n],
+                       p["wk_b"].astype(dt).reshape(rc, H, n))
+    fill = g.lanes - rc - r
+    q_full = jnp.concatenate(
+        [q_abs, q_rope] + ([jnp.zeros((B, S, H, fill), dt)]
+                           if fill else []), axis=-1)
+    latent = jnp.concatenate(
+        [c, k_rope] + ([jnp.zeros((B, S, fill), dt)] if fill else []),
+        axis=-1)
+    return c_q, q_full, latent, cos, sin
+
+
+def lora_rescale(dim: int, rank: int) -> float:
+    """What a normed low-rank latent is multiplied by where the config
+    says `lora_rescale`: ``sqrt(dim / rank)`` (`apply_mla_qkv_lora_rescale`
+    as LongCat-Flash's `mla_scale_q_lora` / `mla_scale_kv_lora`)."""
+    return math.sqrt(dim / rank)
+
+
+def head_gate(a, w_gate, dt, scope: str = sn.ATTN_GATE):
+    """The head-wise output gate: ``sigmoid(a W_g)`` [B, S, H], one
+    scalar a head from the layer's normed input (`attention_gate_type`
+    headwise), which the heads' outputs are multiplied by ahead of the
+    output projection."""
+    with jax.named_scope(scope):
+        return jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", a, w_gate.astype(dt),
+            preferred_element_type=jnp.float32)).astype(dt)
+
+
+def _attn_out(h, a, o_lat, p, g: _Geometry, cfg: MlaConfig):
+    """``h + W_o [g_h W_vb^h o_lat_h]``: the value map, the gate where the
+    config has one, the output projection and the residual."""
     dt = cfg.dtype
     B, S, _ = h.shape
-    H = cfg.n_heads
-    n, r, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    rc = cfg.kv_lora_rank
+    with jax.named_scope(sn.ATTN_OUT):
+        o = jnp.einsum("bshc,chv->bshv", o_lat,
+                       p["wv_b"].astype(dt).reshape(g.kv_rank, g.heads, g.v))
+        if cfg.attn_gate:
+            o = o * head_gate(a, p["w_attn_gate"], dt, g.scope_gate)[..., None]
+        return h + jnp.einsum("bse,ed->bsd", o.reshape(B, S, g.heads * g.v),
+                              p["wo"].astype(dt))
+
+
+def _attention(h, p, li, pool_c, pool_i, bt, slots, q_slots, cfg: MlaConfig,
+               want_selection: bool = False):
+    """A FULL layer's ``h + Attn(N(h))`` for rows [B, S] at ``slots``:
+    writes the chunk's latent rows and indexer keys into layer ``li`` of
+    the two planes (its place among the full layers), then selects and
+    attends through the table. Returns (h, the planes and, asked, which
+    slots each query chose [B, S, span] bool)."""
+    dt = cfg.dtype
+    B, S, _ = h.shape
+    g = cfg.geometry(MLA)
+    r = g.rope
     IH, ID = cfg.index_n_heads, cfg.index_head_dim
     T = pool_c.shape[2]
     f32 = jnp.float32
     a = _rmsnorm(h, p["attn_norm"], cfg.norm_eps)
     with jax.named_scope(sn.MLA_PROJ):
-        ang = jnp.maximum(slots, 0).astype(f32)[..., None] \
-            * yarn_inv_freq(cfg)                          # [B, S, r / 2]
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        c_q = _rmsnorm(jnp.einsum("bsd,de->bse", a, p["wq_a"].astype(dt)),
-                       p["q_norm"], cfg.norm_eps)
-        q = jnp.einsum("bse,ef->bsf", c_q, p["wq_b"].astype(dt)) \
-            .reshape(B, S, H, n + r)
-        q_rope = _rope_pairs(q[..., n:], cos[:, :, None], sin[:, :, None],
-                             True)
-        kv = jnp.einsum("bsd,de->bse", a, p["wkv_a"].astype(dt))
-        c = _rmsnorm(kv[..., :rc], p["kv_norm"], cfg.norm_eps)
-        k_rope = _rope_pairs(kv[..., rc:], cos, sin, True)
-        # absorbed: the key map goes to the query's side
-        q_abs = jnp.einsum("bshn,chn->bshc", q[..., :n],
-                           p["wk_b"].astype(dt).reshape(rc, H, n))
-        lanes = cfg.latent_lanes
-        fill = lanes - rc - r
-        q_full = jnp.concatenate(
-            [q_abs, q_rope] + ([jnp.zeros((B, S, H, fill), dt)]
-                               if fill else []), axis=-1)
-        latent = jnp.concatenate(
-            [c, k_rope] + ([jnp.zeros((B, S, fill), dt)] if fill else []),
-            axis=-1)
+        c_q, q_full, latent, cos, sin = _latent_proj(a, p, slots, g, cfg)
         # the indexer's inputs
         qi = jnp.einsum("bse,ef->bsf", c_q, p["wi_q"].astype(dt)) \
             .reshape(B, S, IH, ID)
@@ -578,26 +762,113 @@ def _attention(h, p, li, pool_c, pool_i, bt, slots, q_slots, cfg: MlaConfig,
         o_lat = attend_token(q_full, bias, q_slots, pool_c, bt, li, cfg)
     else:
         o_lat = attend_chunk(q_full, bias, q_slots, pool_c, bt, li, cfg)
-    with jax.named_scope(sn.ATTN_OUT):
-        o = jnp.einsum("bshc,chv->bshv", o_lat,
-                       p["wv_b"].astype(dt).reshape(rc, H, v))
-        h = h + jnp.einsum("bse,ed->bsd", o.reshape(B, S, H * v),
-                           p["wo"].astype(dt))
+    h = _attn_out(h, a, o_lat, p, g, cfg)
     return h, pool_c, pool_i, (bias == 0 if want_selection else None)
+
+
+def _window_pages(bt_w, slots, q_slots, T: int, window: int):
+    """What a window layer reads for rows [B, S] at ``slots``: the FEW
+    pages of the window table that cover the windows of the chunk's
+    queries, as a table of their own. Returns (``pages`` [B, P] block ids,
+    ``bias`` [B, S, P * T] f32 that is 0 where ``0 <= t - s < window`` and
+    -1e30 elsewhere, the queries' slots counted from the first page
+    [B, S], -1 for filler). A row shorter than the window and a window
+    that starts mid-block are masked inside the first page; entries past
+    the table read the null block and are masked."""
+    B, S = slots.shape
+    MB = bt_w.shape[1]
+    n_pages = min(MB, (S + window - 3 + T) // T + 1)
+    first = jnp.maximum(slots[:, 0] - (window - 1), 0) // T       # [B]
+    idx = first[:, None] + jnp.arange(n_pages)[None, :]           # [B, P]
+    pages = jnp.where(
+        idx < MB, jnp.take_along_axis(bt_w, jnp.minimum(idx, MB - 1), 1), 0)
+    s = (idx[:, :, None] * T + jnp.arange(T)).reshape(B, 1, n_pages * T)
+    t = q_slots[:, :, None]
+    bias = jnp.where((s <= t) & (t - s < window), 0.0, -1e30) \
+        .astype(jnp.float32)
+    local = jnp.where(q_slots >= 0, q_slots - first[:, None] * T, -1)
+    return pages, bias, local
+
+
+def _attention_window(h, p, wi, pool_w, bt_w, slots, q_slots,
+                      cfg: MlaConfig):
+    """A WINDOW layer's ``h + Attn(N(h))``: its own widths, rotary base
+    and scale, no indexer; writes the chunk's latent rows into layer
+    ``wi`` of the window plane (its place among the window layers) through
+    the window table and attends the slots ``0 <= t - s < sliding_window``
+    in the pages that cover them, through the kernels the full layers use
+    with the window as their mask. Returns (h, the plane)."""
+    from ray_tpu.ops import sparse_latent_attention as sla
+
+    B, S, _ = h.shape
+    g = cfg.geometry(SWA)
+    T = pool_w.shape[2]
+    a = _rmsnorm(h, p["attn_norm"], cfg.norm_eps)
+    with jax.named_scope(sn.SWA_PROJ):
+        _, q_full, latent, _, _ = _latent_proj(a, p, slots, g, cfg)
+    with jax.named_scope(sn.SWA_WRITE):
+        blk = bt_w[jnp.arange(B)[:, None], slots // T]
+        pool_w = pool_w.at[wi, blk, slots % T].set(
+            latent.astype(pool_w.dtype))
+    with jax.named_scope(sn.SWA_ATTENTION):
+        pages, bias, local = _window_pages(bt_w, slots, q_slots, T,
+                                           cfg.sliding_window)
+        on_chip = jax.default_backend() == "tpu" and T % _LANE == 0
+        if S == 1 and on_chip:
+            o_lat = sla.sparse_latent_decode(
+                q_full[:, 0], pool_w, pages, bias[:, 0], local[:, 0], wi,
+                rc=g.kv_rank, sm_scale=g.sm_scale, interpret=False)[:, None]
+        else:
+            # a page at a time: as ONE gather XLA splits a 1,152-lane row
+            # at 640 lanes and copies the WHOLE pool twice to do it (7.6 %
+            # of the cell's device time, PERF.md PR 52)
+            lat = jax.lax.map(
+                lambda page: jax.lax.dynamic_slice(
+                    pool_w, (wi, page, 0, 0), (1, 1, T, g.lanes))[0, 0],
+                pages.reshape(-1)).reshape(B, -1, g.lanes)
+            q = jnp.swapaxes(q_full, 1, 2)              # [B, H, S, lanes]
+            # (the kernel's key tile has to be whole lanes: 19 pages, a
+            # window eight times this one, would give it 608)
+            if on_chip and S % 8 == 0 \
+                    and sla._tile(lat.shape[1], 1024) % _LANE == 0:
+                o = sla.sparse_latent_attention(
+                    q, lat, bias, local, rc=g.kv_rank, sm_scale=g.sm_scale)
+            else:
+                o = sla.sparse_latent_attention_reference(
+                    q, lat, bias, rc=g.kv_rank, sm_scale=g.sm_scale)
+            o_lat = jnp.swapaxes(o, 1, 2)
+    return _attn_out(h, a, o_lat, p, g, cfg), pool_w
+
+
+def _kind_runs(kinds):
+    """A period's layers as runs of one kind: [(kind, its name, the run's
+    first place among the period's layers of that kind, length, how many
+    layers of that kind the period has)]."""
+    runs, seen = [], {}
+    for kind in kinds:
+        name = "window" if kind is SWA else "full"
+        if runs and runs[-1][0] is kind:
+            runs[-1][3] += 1
+        else:
+            runs.append([kind, name, seen.get(name, 0), 1])
+        seen[name] = seen.get(name, 0) + 1
+    return [(*r, seen[r[1]]) for r in runs]
 
 
 def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
                  cfg: MlaConfig, *, state=None, bt_w=None, live=None,
                  rows=None, n_valid=None, last_idx=None, final: bool = True,
                  moe_live=None, want_selection: bool = False):
-    """This family's stack against its two planes (latent ``pool_c``,
-    index ``pool_i``) through ``bt``, as `block_pool.ServedConfig.stack`
-    describes it. No recurrent state, no window plane, every layer for
-    every chunk: ``state``, ``bt_w``, ``live``, ``rows`` and ``final``
-    are ignored. The expert-layer counts are [n_moe_layers, 4]; the fifth
-    result, the state it does not have, is None or, with
+    """This family's stack against its planes (latent ``pool_c`` and index
+    ``pool_i`` of the full layers through ``bt``; the window layers'
+    ``state["wlatent"]`` through ``bt_w`` where the config has any), as
+    `block_pool.ServedConfig.stack` describes it. No recurrent state,
+    every layer for every chunk: ``live``, ``rows`` and ``final`` are
+    ignored. The expert-layer counts are [n_moe_layers, 4]; the fifth
+    result is the state as handed (None without window layers) or, with
     ``want_selection`` (the benchmark's `select_overlap`), which slots
-    each query chose in every layer [n_layers, B, S, span] bool."""
+    each query chose in every FULL layer [n_select_layers, B, S, span]
+    bool."""
     B, S = toks.shape
     dt = cfg.dtype
     slots = starts[:, None] + jnp.arange(S)[None, :]
@@ -610,51 +881,113 @@ def layers_paged(params: Params, toks, pool_c, pool_i, bt, starts,
     with jax.named_scope(sn.EMBED):
         h = params["tok_embed"].astype(dt)[toks]
 
-    def dense_body(carry, xs):
-        h, pc, pi = carry
-        p, li = xs
+    def attention(carry, p, li, kind):
+        h, pc, pi, pw = carry
+        if kind is SWA:
+            h, pw = _attention_window(h, p, li, pw, bt_w, slots, q_slots,
+                                      cfg)
+            return (h, pc, pi, pw), None
         h, pc, pi, sel = _attention(h, p, li, pc, pi, bt, slots, q_slots,
                                     cfg, want_selection)
+        return (h, pc, pi, pw), sel
+
+    def dense_body(carry, xs):
+        p, li = xs
+        (h, *pools), sel = attention(carry, p, li, MLA)
         x = _rmsnorm(h, p["mlp_norm"], cfg.norm_eps)
         with jax.named_scope(sn.MLP):
             gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(dt))
             up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(dt))
             h = h + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
                                p["w_down"].astype(dt))
-        return (h, pc, pi), (None, sel if want_selection else None)
+        return (h, *pools), (None, sel if want_selection else None)
 
-    def moe_body(carry, xs):
-        h, pc, pi = carry
+    def moe_body(carry, xs, kind, experts, first):
+        # ``li``: the layer's place among its kind (its plane's layer);
+        # ``first``: that of the stack's first layer
         p, li = xs
-        h, pc, pi, sel = _attention(h, p, li, pc, pi, bt, slots, q_slots,
-                                    cfg, want_selection)
+        (h, *pools), sel = attention(carry, p, li, kind)
         x = _rmsnorm(h, p["mlp_norm"], cfg.norm_eps)
-        # the expert stacks of ALL expert layers go in whole, with this
-        # layer's index: nothing of a layer's size is sliced out of them
+        # the expert stacks of ALL the stack's layers go in whole, with
+        # this layer's index: nothing of a layer's size is sliced out
         out, st = moe_ffn_dropless(x, {**p, **experts}, cfg, live=moe_live,
-                                   expert_stack_layer=li - cfg.n_dense_layers,
+                                   expert_stack_layer=li - first,
                                    read=real)
-        return (h + out, pc, pi), (st, sel if want_selection else None)
+        return (h + out, *pools), (st, sel if want_selection else None)
 
-    experts = {n: params["moe"][n].reshape(-1, *params["moe"][n].shape[2:])
-               for n in EXPERT_STACKS}
-    carry = (h, pool_c, pool_i)
-    stats, chosen = None, []
-    for seg in cfg.layer_plan():
-        body = dense_body if seg.name == "dense" else moe_body
-        layers = {n: v for n, v in params[seg.name].items()
-                  if n not in EXPERT_STACKS}
-        carry, (st, sel) = jax.lax.scan(
-            body, carry,
-            (layers, seg.first_layer + jnp.arange(seg.periods)))
+    def split(stack):
+        """(a stack's layers without their expert stacks, those flat)."""
+        return ({n: v for n, v in stack.items() if n not in EXPERT_STACKS},
+                {n: stack[n].reshape(-1, *stack[n].shape[2:])
+                 for n in EXPERT_STACKS})
+
+    def period_body(carry, xs, seg, stacks, first):
+        # one period of unlike kinds: its runs of one kind, each a scan
+        per, k = xs
+        ys = []
+        for kind, name, at, n, count in _kind_runs(seg.kinds):
+            carry, y = jax.lax.scan(
+                functools.partial(moe_body, kind=kind, experts=stacks[name],
+                                  first=first[name]),
+                carry,
+                (jax.tree_util.tree_map(lambda x: x[at:at + n], per[name]),
+                 first[name] + k * count + at + jnp.arange(n)))
+            ys.append(y)
+        return carry, ys
+
+    def in_order(runs):
+        # [periods, run, ...] a run -> [the segment's layers, ...]
+        runs = [y for y in runs if y is not None]
+        return jnp.concatenate(runs, axis=1).reshape(
+            -1, *runs[0].shape[2:]) if runs else None
+
+    one_kind = cfg.layer_types is None     # one stack an expert segment
+    plan = cfg.layer_plan()
+    splits = {seg.name: split(params[seg.name]) if one_kind else
+              {name: split(stack)
+               for name, stack in params[seg.name].items()}
+              for seg in plan if seg.name != "dense"}
+    pool_w = None if state is None else state["wlatent"]
+    carry = (h, pool_c, pool_i, pool_w)
+    stats, chosen = [], []
+    for seg in plan:
+        if seg.name == "dense":
+            carry, (st, sel) = jax.lax.scan(
+                dense_body, carry,
+                (params["dense"], seg.first_layer + jnp.arange(seg.periods)))
+        elif one_kind:
+            layers, experts = splits[seg.name]
+            carry, (st, sel) = jax.lax.scan(
+                functools.partial(moe_body, kind=MLA, experts=experts,
+                                  first=cfg.n_dense_layers),
+                carry, (layers, seg.first_layer + jnp.arange(seg.periods)))
+        else:
+            before = cfg.layer_kinds()[:seg.first_layer]
+            first = {"full": before.count(MLA), "window": before.count(SWA)}
+            pairs = splits[seg.name]
+            per = {name: jax.tree_util.tree_map(
+                lambda x: x.reshape(seg.periods, -1, *x.shape[1:]), layers)
+                for name, (layers, _) in pairs.items()}
+            carry, ys = jax.lax.scan(
+                functools.partial(
+                    period_body, seg=seg, first=first,
+                    stacks={name: e for name, (_, e) in pairs.items()}),
+                carry, (per, jnp.arange(seg.periods)))
+            st = in_order([st for st, _ in ys])
+            sel = in_order([sel for _, sel in ys])
+        stats.append(st)
         chosen.append(sel)
-        if seg.name == "moe":
-            stats = st
-    h, pool_c, pool_i = carry
+    h, pool_c, pool_i, pool_w = carry
     if last_idx is not None:
         h = h[jnp.arange(B), last_idx][:, None]
-    return h, pool_c, pool_i, stats, \
-        jnp.concatenate(chosen) if want_selection else None
+    if want_selection:
+        state = jnp.concatenate(chosen)
+    elif state is not None:
+        state = {**state, "wlatent": pool_w}
+    stats = [st for st in stats if st is not None]
+    return h, pool_c, pool_i, (
+        None if not stats else stats[0] if len(stats) == 1
+        else jnp.concatenate(stats)), state
 
 
 def lm_head(params: Params, h, cfg: MlaConfig):
@@ -674,16 +1007,17 @@ _SOLO_BLOCK = 32
 
 def init_cache(cfg: MlaConfig, batch_size: int, max_len: int):
     """What `generate.init_cache` is for the other families: the engine's
-    two planes with a trivial table (row b owns blocks ``1 + b * MB ..
-    (b + 1) * MB`` for good)."""
+    planes with a trivial table (row b owns blocks ``1 + b * MB ..
+    (b + 1) * MB`` for good, in the window plane too: nothing is released
+    behind a solo row's window)."""
     T = _SOLO_BLOCK
     mb = -(-max_len // T)
     nb = 1 + batch_size * mb
-    latent, index = cfg.cache_planes()
-    return {"c": jnp.zeros((latent.layers, nb, T, latent.lanes), latent.dtype),
-            "i": jnp.zeros((index.layers, nb, T, index.lanes), index.dtype),
-            "bt": 1 + jnp.arange(batch_size * mb,
-                                 dtype=jnp.int32).reshape(batch_size, mb)}
+    cache = {name: jnp.zeros((pl.layers, nb, T, pl.lanes), pl.dtype)
+             for name, pl in zip("ciw", cfg.cache_planes())}
+    cache["bt"] = 1 + jnp.arange(batch_size * mb,
+                                 dtype=jnp.int32).reshape(batch_size, mb)
+    return cache
 
 
 def forward_cached(params: Params, tokens, cache, start, cfg: MlaConfig,
@@ -698,8 +1032,12 @@ def forward_cached(params: Params, tokens, cache, start, cfg: MlaConfig,
             "and the indexer scores every slot below a query; batch "
             "prompts of one length, or use the engine")
     B, S = tokens.shape
-    h, c, i, _, _ = layers_paged(
+    h, c, i, _, state = layers_paged(
         params, tokens, cache["c"], cache["i"], cache["bt"],
         jnp.full((B,), start, jnp.int32), cfg,
-        last_idx=jnp.full((B,), S - 1, jnp.int32))
-    return lm_head(params, h, cfg), {"c": c, "i": i, "bt": cache["bt"]}
+        state={"wlatent": cache["w"]} if "w" in cache else None,
+        bt_w=cache["bt"], last_idx=jnp.full((B,), S - 1, jnp.int32))
+    out = {"c": c, "i": i, "bt": cache["bt"]}
+    if state is not None:
+        out["w"] = state["wlatent"]
+    return lm_head(params, h, cfg), out

@@ -53,6 +53,14 @@ GDN_STEP = "gdn_step"                # ... its one-token update (a decode
 #                                      token) with the state it moves
 ATTN_GATE = "attn_gate"              # gated attention: sigmoid(gate) on the
 #                                      heads' output
+SWA_PROJ = "swa_proj"                # a latent WINDOW layer (an `MlaConfig`
+#                                      with `layer_types`): its low-rank
+#                                      projections, norms, rotary, absorbed map
+SWA_WRITE = "swa_write"              # ... its latent rows into the window plane
+SWA_ATTENTION = "swa_attention"      # ... the pages that cover its queries'
+#                                      windows, the mask, and attention over them
+SWA_GATE = "swa_gate"                # ... its head-wise output gate (a full
+#                                      layer's is `attn_gate`)
 LM_HEAD = "lm_head"                  # final vocab projection
 SAMPLE = "sample"                    # on-device sampling and row freezing
 LOSS = "loss"                        # log-softmax and token nll
@@ -63,10 +71,11 @@ SCOPES = (EMBED, NORM, ATTN_QKV, KV_WRITE, KV_GATHER, PAGED_ATTENTION,
           LOSS, OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
           SSM_PROJ, SSM_SCAN, GMU, DIFF_COMBINE, MOE_SHARED, MLA_PROJ,
           INDEXER_SCORE, INDEXER_TOPK, LATENT_GATHER, SPARSE_ATTENTION,
-          GDN_PROJ, GDN_CONV, GDN_CHUNK, GDN_STEP, ATTN_GATE)
+          GDN_PROJ, GDN_CONV, GDN_CHUNK, GDN_STEP, ATTN_GATE, SWA_PROJ,
+          SWA_WRITE, SWA_ATTENTION, SWA_GATE)
 
 # Scopes whose ops move cached K/V without computing on it.
-KV_MOVE = (KV_WRITE, KV_GATHER)
+KV_MOVE = (KV_WRITE, KV_GATHER, SWA_WRITE)
 
 # `pallas_call(name=...)`: the name is in the kernel's custom call, so a
 # trace tells the kernels apart.

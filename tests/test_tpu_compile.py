@@ -755,6 +755,94 @@ def test_mla_cell_programs_fit_the_chip(v5e, program):
     assert (HIT_EXPERTS_KERNEL in text) == (program == "decode")
 
 
+# -- the two-geometry latent cell's programs -------------------------------------
+
+def _dots3_config():
+    """dots3-note-prev as `dots3-mixed-ctx` cuts it: layers F F S S S,
+    experts [0, 32) of 256 held, 1/8 of the vocabulary."""
+    from ray_tpu.models import MlaConfig
+
+    return MlaConfig(
+        vocab_size=19008, dim=5120, n_layers=5, n_dense_layers=1,
+        n_heads=128, q_lora_rank=1024, kv_lora_rank=512, ffn_dim=13824,
+        expert_dim=1536, n_group=1, topk_group=1, routed_scaling_factor=1.0,
+        held_experts=(0, 32), norm_eps=1e-5, rope_theta=8e7,
+        rope_scaling=None, max_seq_len=33792,
+        layer_types=("full", "full", "window", "window", "window"),
+        sliding_window=513, swa_n_heads=64, swa_q_lora_rank=1024,
+        swa_kv_lora_rank=1024, swa_qk_nope_head_dim=192,
+        swa_qk_rope_head_dim=64, swa_v_head_dim=128, swa_rope_theta=5e4,
+        attn_gate=True, lora_rescale=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _dots3_program(v5e, program):
+    """The `dots3-mixed-ctx` cell's engine as `DecodeEngine` builds it (64
+    slots, 132 table entries of 256 tokens, a 2.25 GiB pool of a latent
+    and an index plane for the 2 full layers, a window pool of 409 blocks
+    of a 1,152-lane latent plane for the 3 window layers):
+    `_decode_multi_paged` at the cell's horizon of 8 or
+    `_prefill_rows_paged` for 4 x 512 tokens, compiled for the described
+    chip with the kernels selected."""
+    from ray_tpu.models import engine, mla_init
+
+    cfg = _dots3_config()
+    B, T, MB = 64, 256, 132
+    latent, index, wlatent = cfg.cache_planes()
+    nb = 1 + (9 << 28) // (latent.block_bytes(T) + index.block_bytes(T))
+    nb_w = 1 + B * 6 + 8 * 3
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree.map(
+        lambda a: arg(a.shape, a.dtype),
+        jax.eval_shape(lambda: mla_init(jax.random.PRNGKey(0), cfg)))
+    pc = arg((latent.layers, nb, T, latent.lanes), jnp.bfloat16)
+    pi = arg((index.layers, nb, T, index.lanes), jnp.bfloat16)
+    hyb = {"wlatent": arg((wlatent.layers, nb_w, T, wlatent.lanes),
+                          jnp.bfloat16)}
+    logits = arg((B, cfg.vocab_size), jnp.float32)
+    ctr = arg((5,))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        if program == "decode":
+            lane, flag = arg((B,)), arg((B,), jnp.bool_)
+            lowered = engine._decode_multi_paged.lower(
+                params, pc, pi, arg((B, MB)), logits, lane, flag, lane,
+                lane, arg((B, 2), jnp.uint32), flag, 1.0, cfg, 8, True,
+                None, None, None, moe_ctr=ctr, hyb=hyb, bt_w=arg((B, MB)))
+        else:
+            lowered = engine._prefill_rows_paged.lower(
+                params, arg((4, 512)), pc, pi, logits, arg((4, MB)),
+                arg((4,)), arg((4,)), arg((4,)), cfg, moe_ctr=ctr, hyb=hyb,
+                bt_w=arg((4, MB)))
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_dots3_cell_programs_fit_the_chip(v5e, program):
+    """One chip's share of dots3-note-prev in bf16 (4.09 B parameters,
+    7.61 GiB) beside a 2.25 GiB pool of the full layers' two planes and a
+    0.67 GiB window pool of the window layers' one: 10.54 GiB of arguments
+    (`engine_notes` of benchmark/configs/dots3-note-prev-serve.json), and
+    no program adds 3 GiB of workspace. The window layers call the full
+    layers' two kernels at their own geometry (64 heads, 1,152 lanes,
+    latent 1,024): Mosaic takes both; all three planes are updated in
+    place."""
+    compiled = _dots3_program(v5e, program)
+    m = compiled.memory_analysis()
+    assert 10.3 * 2**30 < m.argument_size_in_bytes < 10.8 * 2**30
+    assert m.temp_size_in_bytes < 3 * 2**30
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2**30
+    assert m.alias_size_in_bytes > 2.9 * 2**30
+    from ray_tpu.ops.scope_names import (SPARSE_LATENT_DECODE_KERNEL,
+                                         SPARSE_LATENT_KERNEL)
+    text = compiled.as_text()
+    want = SPARSE_LATENT_DECODE_KERNEL if program == "decode" \
+        else SPARSE_LATENT_KERNEL
+    assert text.count(want) >= 2       # a full and a window call at least
+
+
 # -- the delta-rule cell's programs ----------------------------------------------
 
 @functools.lru_cache(maxsize=None)
