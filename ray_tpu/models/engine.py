@@ -1672,6 +1672,11 @@ class DecodeEngine:
         self.indexer_tokens_selected_total = 0  # prefill (an MlaConfig)
         self.indexer_decode_tokens_scored_total = 0    # ... decode alone
         self.indexer_decode_tokens_selected_total = 0
+        self.indexer_pages_walked_total = 0    # page-layers the selection
+        self.indexer_pages_table_total = 0     # walks, of its tables'
+        self.indexer_queries_total = 0         # query-layers, and those
+        self.indexer_queries_unselected_total = 0  # with <= index_topk
+        #                                            slots to see
         self.kv_walk_tokens_window_total = 0   # token-layers decode asks
         self.kv_walk_tokens_full_total = 0     # ... per READER of the pool
         self.window_blocks_freed_total = 0     # released behind the window
@@ -2536,7 +2541,7 @@ class DecodeEngine:
         self.paged_walk_rows_total += H * self.B
         self.paged_walk_rows_chained_total += H * (self.B - 1)
         if self._selects:       # its attention is not this kernel's
-            self._count_selection(slots + 1, decode=True)
+            self._count_selection(slots.reshape(-1, 1), decode=True)
             if self.kv_pool_w is not None:
                 self.swa_window_rows_total += slots.size
                 self.swa_window_slots_total += int(np.minimum(
@@ -2559,21 +2564,43 @@ class DecodeEngine:
                     * cfg.n_window_layers
             self.ssm_row_steps_total += H * len(rows)
 
-    def _count_selection(self, live: np.ndarray,
+    def _count_selection(self, q_slots: np.ndarray,
                          decode: bool = False) -> None:
         """Account the queries of one dispatch of a config that selects
-        (an `MlaConfig`): each sees ``live`` tokens, the indexer scores
-        them all and attention reads `index_topk` of them at most, in
-        every layer that selects (all but its window layers)."""
-        cfg = self.cfg
-        scored = int(live.sum()) * cfg.n_select_layers
-        selected = int(np.minimum(live, cfg.index_topk).sum()) \
-            * cfg.n_select_layers
+        (an `MlaConfig`), ``q_slots`` [rows, queries a call] their slots
+        (-1: bucket filler): a query at slot t sees t + 1 tokens, the
+        indexer scores them all and attention reads `index_topk` of them
+        at most, in every layer that selects (all but its window
+        layers). And what the selection WALKS of the rows' tables
+        (`ops.indexer_select`, whose arithmetic this asks): a block of
+        a row's queries the pages up to its last slot's, none while
+        that is below `index_topk` (such a query has no choice to make:
+        it keeps what it sees); the lax form, which takes the calls the
+        kernel has no tile for and every call off the chip, reads the
+        whole table, and is counted as the kernel where it stands in
+        for it."""
+        from ray_tpu.ops import indexer_select as isel
+
+        cfg, layers = self.cfg, self.cfg.n_select_layers
+        live = q_slots[q_slots >= 0] + 1
+        scored = int(live.sum()) * layers
+        selected = int(np.minimum(live, cfg.index_topk).sum()) * layers
         self.indexer_tokens_scored_total += scored
         self.indexer_tokens_selected_total += selected
         if decode:
             self.indexer_decode_tokens_scored_total += scored
             self.indexer_decode_tokens_selected_total += selected
+        rows, n = q_slots.shape
+        tq = isel.query_tile(n)
+        last = q_slots.reshape(rows, -1, tq or n).max(axis=2)
+        walked = isel.pages_walked(last, cfg.index_topk,
+                                   self.kv_block_tokens, self._mb) \
+            if tq else np.full_like(last, self._mb)
+        self.indexer_pages_walked_total += int(walked.sum()) * layers
+        self.indexer_pages_table_total += last.size * self._mb * layers
+        self.indexer_queries_total += live.size * layers
+        self.indexer_queries_unselected_total += int(
+            (live <= cfg.index_topk).sum()) * layers
 
     def _count_prefill_walk(self, starts: np.ndarray,
                             last_idx: np.ndarray, bucket: int) -> None:
@@ -2590,9 +2617,9 @@ class DecodeEngine:
         chunk takes (off the chip that lowering stands in for the
         kernel, and is counted)."""
         if self._selects:
-            real = np.arange(bucket)[None, :] <= last_idx[:, None]
-            live = starts[:, None] + np.arange(bucket)[None, :] + 1
-            self._count_selection(live[real])
+            at = np.arange(bucket)[None, :]
+            self._count_selection(np.where(at <= last_idx[:, None],
+                                           starts[:, None] + at, -1))
             return
         if self.kv_quant_spec is not None or self.kv_pool_w is not None \
                 or (self.mesh is not None and self.mesh.size > 1):
@@ -2918,6 +2945,10 @@ class DecodeEngine:
             self.indexer_decode_tokens_scored_total)
         out["indexer_decode_tokens_selected_total"] = float(
             self.indexer_decode_tokens_selected_total)
+        for name in ("indexer_pages_walked_total",
+                     "indexer_pages_table_total", "indexer_queries_total",
+                     "indexer_queries_unselected_total"):
+            out[name] = float(getattr(self, name))
         # Recurrent-state and window planes (a `HybridConfig`; the
         # `ssm_*` and `kv_walk_tokens_full_total` also a `GdnConfig`;
         # identically 0.0 otherwise): host estimates at dispatch, like

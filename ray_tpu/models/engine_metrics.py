@@ -38,8 +38,14 @@ the paged kernel is asked to read once for each layer that READS the
 table's pool (a `HybridConfig`'s one full layer and its cross-attention
 readers, each attention layer of a `GdnConfig`). They are 0 for a family
 without recurrent state. A SELECTING family (an `MlaConfig`) counts its
-own: the ``indexer_*`` counters are token-layers over the layers that
-select (all but its window layers), and where it has window layers
+own: the ``indexer_*tokens*`` counters are token-layers over the layers
+that select (all but its window layers), ``indexer_pages_walked_total`` of
+``indexer_pages_table_total`` the page-layers the selection walks of its
+rows' tables (`ops.indexer_select`: a block of queries the pages up to its
+last slot's, none below `index_topk`) and
+``indexer_queries_unselected_total`` of ``indexer_queries_total`` the
+query-layers with at most `index_topk` slots to see, which keep them all;
+and where it has window layers
 ``swa_window_rows_total`` / ``swa_window_slots_total`` count, a decode
 dispatch, the row-tokens that asked and the slots ONE window layer reads
 for them (min(row length, `sliding_window`) each; times the config's

@@ -53,7 +53,9 @@ EXACT top `index_topk` of them; (3) read those slots' latent rows; (4)
 attend them. A context at or under `index_topk` attends all of it (the
 selection then picks every live slot). The selection is a MASK
 (`select_mask`: the k-th largest score by counting, no sort, and
-`lax.top_k`'s own members), a block of queries at a time, and the row's
+`lax.top_k`'s own members), a block of queries at a time; on the chip the
+same mask from ONE kernel that walks the pages a block of queries can see
+and none for a block with no choice to make, `select_rows`), and the row's
 keys are attended densely under it: a chunk's queries would each gather
 their own 2,048 rows (1.3 GB a 512-token chunk a layer), and a decode
 token's gather and sort were half its time. `attend_chunk` lays the row's
@@ -574,6 +576,20 @@ def select_mask(qi, wt, q_slots, pool_i, bt, li, cfg: MlaConfig):
         return jnp.where(take, 0.0, -1e30).astype(jnp.float32)
 
 
+def select_rows(qi, wt, q_slots, pool_i, bt, li, cfg: MlaConfig):
+    """`select_mask` on the chip, for all the queries [B, S] of a call at
+    once (`ops.indexer_select`): a block of queries walks the pages of
+    its row that hold a slot it can see, where they lie, and none while
+    its last slot is below `index_topk`; nothing of the table's width is
+    gathered and no score leaves VMEM. The scores and the counts are one
+    kernel, under the scope of the scores."""
+    from ray_tpu.ops.indexer_select import indexer_select
+
+    with jax.named_scope(sn.INDEXER_SCORE):
+        return indexer_select(qi, wt, q_slots, pool_i, bt, li,
+                                   topk=cfg.index_topk, interpret=False)
+
+
 def attend_token(q_full, bias, q_slots, pool_c, bt, li, cfg: MlaConfig):
     """Steps (3) and (4) for ONE query a row (a decode token) on the chip:
     the row's pages read where they lie under the selection's mask
@@ -751,14 +767,22 @@ def _attention(h, p, li, pool_c, pool_i, bt, slots, q_slots, cfg: MlaConfig,
         pool_c = pool_c.at[li, blk, off].set(latent.astype(pool_c.dtype))
         pool_i = pool_i.at[li, blk, off].set(ki.astype(pool_i.dtype))
 
-    # the selection as a mask, a block of queries at a time; then a decode
-    # token on the chip walks its row's pages under it, and everything
-    # else attends the row's keys densely under it
-    def mask(qi, wt, q_slots):
-        return select_mask(qi, wt, q_slots, pool_i, bt, li, cfg)
+    # the selection as a mask: on the chip a kernel that walks the row's
+    # live index pages (`select_rows`), elsewhere the lax form a block of
+    # queries at a time; then a decode token on the chip walks its row's
+    # pages under it, and everything else attends the row's keys densely
+    # under it
+    from ray_tpu.ops.indexer_select import query_tile
 
-    bias = _by_query_blocks(mask, S, qi, wt, q_slots)
-    if S == 1 and T % _LANE == 0 and jax.default_backend() == "tpu":
+    on_chip = T % _LANE == 0 and jax.default_backend() == "tpu"
+    if on_chip and ID % _LANE == 0 and query_tile(S) is not None:
+        bias = select_rows(qi, wt, q_slots, pool_i, bt, li, cfg)
+    else:
+        def mask(qi, wt, q_slots):
+            return select_mask(qi, wt, q_slots, pool_i, bt, li, cfg)
+
+        bias = _by_query_blocks(mask, S, qi, wt, q_slots)
+    if S == 1 and on_chip:
         o_lat = attend_token(q_full, bias, q_slots, pool_c, bt, li, cfg)
     else:
         o_lat = attend_chunk(q_full, bias, q_slots, pool_c, bt, li, cfg)

@@ -88,8 +88,11 @@ SPARSE_LATENT_DECODE_KERNEL = "sparse_latent_decode"
 HIT_EXPERTS_KERNEL = "moe_hit_experts"
 HELD_GROUPED_KERNEL = "moe_held_grouped"
 DELTA_STEP_KERNEL = "gdn_delta_step"     # under the scope `gdn_step`
+INDEXER_SELECT_KERNEL = "indexer_select"    # under `indexer_score` and
+#                                             `indexer_topk` (see `mla`)
 
 KERNELS = (PAGED_KERNEL, FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV,
            SPARSE_LATENT_KERNEL, SPARSE_LATENT_DECODE_KERNEL,
-           HIT_EXPERTS_KERNEL, HELD_GROUPED_KERNEL, DELTA_STEP_KERNEL)
+           HIT_EXPERTS_KERNEL, HELD_GROUPED_KERNEL, DELTA_STEP_KERNEL,
+           INDEXER_SELECT_KERNEL)
 

@@ -322,6 +322,175 @@ def test_selection_mask_is_the_top_ks_own_members():
     assert (np.asarray(bias)[np.asarray(bias) != 0] < -1e29).all()
 
 
+# -- the selection's kernel against the lax form -------------------------------
+
+# 4 heads of 32, pages of 32 slots, a table of 8 (256 slots), the top 64
+_SEL_T, _SEL_MB, _SEL_K, _SEL_H, _SEL_D, _SEL_L = 32, 8, 64, 4, 32, 2
+_SEL_SPAN = _SEL_T * _SEL_MB
+_SEL_CFG = MlaConfig.nano_mla(index_n_heads=_SEL_H, index_head_dim=_SEL_D,
+                              index_topk=_SEL_K)
+# a decode batch: each row's (last) slot
+_SEL_TOKEN = {"asks_nothing": -1, "slot_0": 0, "k_minus_1": _SEL_K - 1,
+              "k": _SEL_K, "k_plus_1": _SEL_K + 1,
+              "page_end": _SEL_K + _SEL_T - 1, "page_start": _SEL_K + _SEL_T,
+              "table_end": _SEL_SPAN - 1}
+# chunks of 32 queries (two blocks of 16): (slot of the first, real ones)
+_SEL_CHUNK = {"below_k": (0, 32), "straddles_k": (_SEL_K - 24, 32),
+              "straddles_k_in_a_block": (_SEL_K - 8, 32),
+              "filler_tail": (100, 21), "one_block_of_filler": (70, 16),
+              "all_filler": (0, 0), "table_end": (_SEL_SPAN - 32, 32)}
+
+
+def _sel_inputs(q_slots, seed, whole=False):
+    """(qi, wt, q_slots, pool, bt): seeded float32 queries, weights and
+    an index plane of two layers whose rows' pages are scattered; with
+    ``whole`` every value is a small integer, so every sum is exact in
+    any order and keys repeat (ties at the k-th value)."""
+    rng = np.random.default_rng(seed)
+    B, S = q_slots.shape
+    nb = 1 + B * _SEL_MB
+
+    def draw(*shape):
+        if whole:
+            return rng.integers(-2, 3, size=shape).astype(np.float32)
+        return rng.standard_normal(shape).astype(np.float32)
+
+    pool = draw(_SEL_L, nb, _SEL_T, _SEL_D)
+    bt = 1 + rng.permutation(B * _SEL_MB).reshape(B, _SEL_MB)
+    # (the weights as `_attention` scales them: scores of order 1)
+    scale = np.float32(1.0 if whole else (_SEL_H * _SEL_D) ** -0.5)
+    return (jnp.asarray(draw(B, S, _SEL_H, _SEL_D)),
+            jnp.asarray(draw(B, S, _SEL_H) * scale),
+            jnp.asarray(q_slots, jnp.int32),
+            jnp.asarray(pool), jnp.asarray(bt, jnp.int32))
+
+
+def _sel_both(q_slots, seed=0, whole=False):
+    """The lax form's scores and mask and the kernel's (interpret mode),
+    layer 1 of the plane."""
+    from ray_tpu.ops import indexer_select as isel
+
+    args = _sel_inputs(q_slots, seed, whole)
+    return {
+        "q_slots": np.asarray(q_slots),
+        "scores": np.asarray(mla.indexer_scores(*args, 1, _SEL_CFG)),
+        "mask": np.asarray(mla.select_mask(*args, 1, _SEL_CFG)) == 0,
+        # (topk 0: every block walks to its last slot, whatever it is)
+        "kernel_scores": np.asarray(isel.indexer_select(
+            *args, 1, topk=0, interpret=True, scores_only=True)),
+        "kernel_mask": np.asarray(isel.indexer_select(
+            *args, 1, topk=_SEL_K, interpret=True)) == 0}
+
+
+@pytest.fixture(scope="module")
+def selected_tokens():
+    return _sel_both(np.asarray(list(_SEL_TOKEN.values()))[:, None])
+
+
+@pytest.fixture(scope="module")
+def selected_chunks():
+    at = np.arange(32)[None, :]
+    first, real = (np.asarray(x)[:, None]
+                   for x in zip(*_SEL_CHUNK.values()))
+    return _sel_both(np.where(at < real, first + at, -1), seed=1)
+
+
+def _check_selection(both, row):
+    """Row ``row`` of both forms: the kernel's scores are the lax form's
+    on the lanes a query sees and -inf elsewhere, it chose exactly
+    ``min(t + 1, k)`` slots, and the SAME slots wherever the k-th value
+    stands clear of the next one."""
+    t = both["q_slots"][row]                              # [S]
+    want, got = both["scores"][row], both["kernel_scores"][row]
+    seen = np.arange(_SEL_SPAN)[None, :] <= t[:, None]
+    assert np.isneginf(got[~seen]).all() and np.isneginf(want[~seen]).all()
+    np.testing.assert_allclose(got[seen], want[seen], atol=1e-6, rtol=1e-6)
+    chosen = both["kernel_mask"][row]
+    assert not chosen[~seen].any()
+    assert (chosen.sum(-1) == np.minimum(t + 1, _SEL_K)).all()
+    ranked = -np.sort(-want, axis=-1)
+    with np.errstate(invalid="ignore"):     # (-inf less -inf)
+        clear = np.isneginf(ranked[:, _SEL_K]) \
+            | (ranked[:, _SEL_K - 1] - ranked[:, _SEL_K] > 1e-6)
+    assert clear.sum() >= len(t) - 1
+    np.testing.assert_array_equal(chosen[clear], both["mask"][row][clear])
+
+
+@pytest.mark.parametrize("case", list(_SEL_TOKEN))
+def test_selection_kernel_is_the_lax_form_for_a_decode_token(
+        selected_tokens, case):
+    """One decode call whose rows end at every edge of the walk: a row
+    that asks nothing, slots 0, k - 1 (the last with no choice to make:
+    no page walked), k and k + 1 (the first that drop a slot), the last
+    slot of a page, the first of the next, the table's last."""
+    _check_selection(selected_tokens, list(_SEL_TOKEN).index(case))
+
+
+@pytest.mark.parametrize("case", list(_SEL_CHUNK))
+def test_selection_kernel_is_the_lax_form_for_a_prefill_chunk(
+        selected_chunks, case):
+    """One prefill call of 32 queries a row (two blocks of 16): chunks
+    wholly below k, straddling it between blocks and inside one, with
+    bucket filler (slot -1) at the tail, as a whole block and as the whole
+    row, and at the table's end."""
+    _check_selection(selected_chunks, list(_SEL_CHUNK).index(case))
+
+
+@pytest.mark.parametrize("queries", [1, 16], ids=["token", "chunk"])
+def test_selection_kernel_breaks_ties_as_the_lax_form_does(queries):
+    """Small whole numbers everywhere: every sum is exact in any order, so
+    both forms hold the same scores bit for bit, many of them equal. Of
+    the slots AT the k-th value the lowest are kept, as many as there is
+    room for: the kernel's running count across pages."""
+    first = np.asarray([_SEL_K + 3, 150, _SEL_SPAN - queries])[:, None]
+    both = _sel_both(first + np.arange(queries)[None, :], seed=2,
+                     whole=True)
+    ranked = -np.sort(-both["scores"], axis=-1)
+    assert (ranked[..., _SEL_K - 1] == ranked[..., _SEL_K]).any()
+    np.testing.assert_array_equal(both["kernel_scores"], both["scores"])
+    np.testing.assert_array_equal(both["kernel_mask"], both["mask"])
+
+
+def test_selection_kernel_reads_nothing_past_a_rows_last_page():
+    """NaN in every block no row can see (the null block, the blocks
+    behind table entries past a row's last slot): the same mask."""
+    from ray_tpu.ops import indexer_select as isel
+
+    q_slots = np.asarray([[_SEL_K + 5], [3 * _SEL_T - 1], [-1], [7]])
+    qi, wt, qs, pool, bt = _sel_inputs(q_slots, seed=3)
+    live = np.zeros(pool.shape[1], bool)
+    for row, t in zip(np.asarray(bt), q_slots[:, 0]):
+        live[row[:t // _SEL_T + 1 if t >= 0 else 0]] = True
+    dirty = jnp.where(live[None, :, None, None], pool, jnp.nan)
+    clean, got = (np.asarray(isel.indexer_select(
+        qi, wt, qs, p, bt, 0, topk=_SEL_K, interpret=True))
+        for p in (pool, dirty))
+    np.testing.assert_array_equal(got, clean)
+    assert ((got == 0).sum(-1)[:, 0] == [_SEL_K, _SEL_K, 0, 8]).all()
+
+
+def test_selection_counters_are_a_hand_count_for_three_rows(params):
+    """A decode dispatch of three rows at slots 5, 16 and 40 (blocks of 8,
+    a table of 16, the top 16) walks 0, 3 and 6 pages a layer of its 3 x
+    16 entries, and the first query alone has nothing to choose; a chunk
+    of 16 queries at slots 8..23 walks 3 of 16 and half of it has."""
+    eng = engine(params)
+    layers = CFG.n_select_layers
+    eng._count_selection(np.asarray([[5], [16], [40]]), decode=True)
+    st = eng.stats()
+    assert st["indexer_pages_walked_total"] == (0 + 3 + 6) * layers
+    assert st["indexer_pages_table_total"] == 3 * 16 * layers
+    assert st["indexer_queries_total"] == 3 * layers
+    assert st["indexer_queries_unselected_total"] == 1 * layers
+    assert st["indexer_decode_tokens_scored_total"] == (6 + 17 + 41) * layers
+    eng._count_selection(8 + np.arange(16)[None, :])
+    now = eng.stats()
+    assert now["indexer_pages_walked_total"] == (9 + 3) * layers
+    assert now["indexer_pages_table_total"] == 4 * 16 * layers
+    assert now["indexer_queries_total"] == (3 + 16) * layers
+    assert now["indexer_queries_unselected_total"] == (1 + 8) * layers
+
+
 # -- batching, horizons, preemption -------------------------------------------
 
 def test_batch_companions_change_nothing(params):

@@ -733,8 +733,9 @@ def test_mla_cell_programs_fit_the_chip(v5e, program):
     8.63 GiB) beside a 3 GiB pool of two planes: 11.64 GiB of arguments,
     and no program adds 2 GiB of workspace (0.66 decode, 1.54 the 4 x 512
     prefill as compiled here), within the chip's 15.75. Each program holds
-    its form of the selection's kernel, one call a segment; both planes
-    are updated in place."""
+    its form of the attention's kernel, one call a segment, behind the
+    selection's, which walks a row's live index pages; both planes are
+    updated in place."""
     compiled = _mla_program(v5e, program)
     m = compiled.memory_analysis()
     assert 11 * 2**30 < m.argument_size_in_bytes < 12 * 2**30
@@ -753,6 +754,21 @@ def test_mla_cell_programs_fit_the_chip(v5e, program):
                                          HIT_EXPERTS_KERNEL)
     assert HELD_GROUPED_KERNEL not in text
     assert (HIT_EXPERTS_KERNEL in text) == (program == "decode")
+    _selection_walks_the_rows_pages(text, 24 if program == "decode" else 4,
+                                    72, 256)
+
+
+def _selection_walks_the_rows_pages(text, rows, max_blocks, block_tokens):
+    """What PR 53 bought: the program holds the selection's kernel, and
+    no gather of its rows' whole index tables ``[rows, span, 128]`` is
+    left in it (under either of the shapes XLA gave it)."""
+    from ray_tpu.ops.scope_names import INDEXER_SELECT_KERNEL
+
+    assert INDEXER_SELECT_KERNEL in text
+    span = max_blocks * block_tokens
+    assert not re.search(
+        rf"bf16\[{rows},(?:{span}|{max_blocks},{block_tokens}),128[\],]",
+        text)
 
 
 # -- the two-geometry latent cell's programs -------------------------------------
@@ -841,6 +857,8 @@ def test_dots3_cell_programs_fit_the_chip(v5e, program):
     want = SPARSE_LATENT_DECODE_KERNEL if program == "decode" \
         else SPARSE_LATENT_KERNEL
     assert text.count(want) >= 2       # a full and a window call at least
+    _selection_walks_the_rows_pages(text, 64 if program == "decode" else 4,
+                                    132, 256)
 
 
 # -- the delta-rule cell's programs ----------------------------------------------

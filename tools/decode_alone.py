@@ -7,9 +7,9 @@ cost the program around it, and a traced benchmark run's
         [--live N[,N...]] [--len L[,L...]] [--rehearse]
 
 builds the engine of a serving cell of driver `serve_engine`,
-`serve_model`, `serve_hybrid`, `serve_gdn` or `serve_mla` (default
-`mistral7b-rollout`) from THAT tree (run it from the tree's root), brings
-N rows of L tokens into decode (N: all the slots by default; the other
+`serve_model`, `serve_hybrid`, `serve_gdn`, `serve_mla` or `serve_mla_swa`
+(default `mistral7b-rollout`) from THAT tree (run it from the tree's root),
+brings N rows of L tokens into decode (N: all the slots by default; the other
 slots stay dead) for every N and L, then calls `_decode_multi_paged` (the
 cell's `decode_horizon`, 8 where it names none) 3 x 40 times on the SAME
 row state and times it on the device's queue (async dispatch, one wait at
@@ -58,7 +58,8 @@ if a.rehearse:
     opts.update(cell.config["rehearsal"]["engine"])
 opts.pop("warm_groups")
 driver = cell.config.get("driver")
-own = driver in ("serve_hybrid", "serve_gdn", "serve_mla")
+own = driver in ("serve_hybrid", "serve_gdn", "serve_mla",
+                 "serve_mla_swa")
 if own:     # a family that brings its own stack, and its own driver
     cfg, init, _ = importlib.import_module(
         "benchmark.harness.drivers." + driver).program_config(
@@ -91,6 +92,8 @@ def kernel_steps(eng, row_len):
     that reads the full cache walks the pages up to the query's slot, a
     window layer those from its window's first page."""
     cfg, T = eng.cfg, eng.kv_block_tokens
+    if not hasattr(cfg, "n_kv_heads"):      # an `MlaConfig`: the paged
+        return 0.0                          # kernel is not its attention
     pps = walk_shape(1, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, T,
                      eng._mb, eng._pool_k.dtype.itemsize)[0]
     slots = row_len[:, None] + np.arange(H)[None, :]
