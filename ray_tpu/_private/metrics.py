@@ -11,10 +11,12 @@ from __future__ import annotations
 
 import threading
 import time
+from bisect import bisect_left
 from typing import Any, Dict, List, Optional, Tuple
 
 _lock = threading.Lock()
 _registry: Dict[Tuple[str, Tuple[Tuple[str, str], ...]], "_Metric"] = {}
+_generation = 0      # how many registries this process has started
 _pusher: Optional[threading.Thread] = None
 _push_stop = threading.Event()
 
@@ -59,20 +61,25 @@ def register(name: str, kind: str, description: str,
         return metric
 
 
-def record(metric: _Metric, value: float, kind: str) -> None:
+def registry_generation() -> int:
+    """Changes whenever `reset_registry` drops the series: a `_Metric`
+    a caller kept from `register` is the registry's own only while this
+    reads what it read then."""
+    return _generation
+
+
+def record(metric: _Metric, value: float, kind: str, n: int = 1) -> None:
+    """One record; a histogram takes `n` equal observations as one."""
     with _lock:
         if kind == "counter":
             metric.value += value
         elif kind == "gauge":
             metric.value = value
         else:
-            metric.sum += value
-            metric.count += 1
-            idx = 0
-            while idx < len(metric.boundaries) and \
-                    value > metric.boundaries[idx]:
-                idx += 1
-            metric.bucket_counts[idx] += 1
+            metric.sum += value * n
+            metric.count += n
+            metric.bucket_counts[bisect_left(metric.boundaries,
+                                             value)] += n
 
 
 def snapshots() -> List[Dict[str, Any]]:
@@ -87,8 +94,10 @@ def reset_registry() -> None:
     snapshots()/prometheus_text() assertions. Metric objects held by
     callers (EngineMetrics instruments, fleet gauge caches) stay valid
     — register() lazily re-creates a series on the next record."""
+    global _generation
     with _lock:
         _registry.clear()
+        _generation += 1
 
 
 # -- Prometheus text exposition ---------------------------------------------

@@ -115,6 +115,10 @@ from ray_tpu.util.compile_cache import ledger as _compile_ledger
 
 Params = Dict[str, Any]
 
+# A step at least this long counts as STALLED (`steps_stalled_total`): a
+# serving step takes 50-190 ms and the stalls met on the machine 0.33-4.5 s.
+STALL_STEP_S = 0.5
+
 
 def _pow2(n: int) -> int:
     """Smallest power of two >= n (n >= 1)."""
@@ -1093,10 +1097,14 @@ class _InflightStep:
     dispatch consumes it directly, so queued steps never synchronize
     with the host. ``run_ahead`` marks steps dispatched before the
     host had replayed the previous block — only those can contain
-    overrun iterations for rows that had already finished."""
+    overrun iterations for rows that had already finished. ``seq`` is
+    the step's place among ALL programs the engine dispatched, ``ahead``
+    the blocks in flight in front of it then, ``t_dispatch`` the engine
+    clock at its dispatch's return (a first token's path, `stats()`'s
+    ``first_*`` aggregates)."""
 
     __slots__ = ("toks", "H", "rows", "run_ahead", "chain", "spec",
-                 "w_max", "w_row")
+                 "w_max", "w_row", "seq", "ahead", "t_dispatch")
 
     def __init__(self, toks, H: int, rows: List[int], run_ahead: bool,
                  chain: tuple, spec: bool = False, w_max: int = 0,
@@ -1109,6 +1117,8 @@ class _InflightStep:
         self.spec = spec            # speculative round: H == w_max + 1
         self.w_max = w_max          # dispatch draft width
         self.w_row = w_row          # per-row width snapshot [B] (np)
+        self.seq = self.ahead = 0   # the three are stamped when the
+        self.t_dispatch = 0.0       # dispatch returns
 
 
 class DecodeEngine:
@@ -1537,6 +1547,44 @@ class DecodeEngine:
         self.host_syncs = 0            # device->host transfers
         self.device_waits = 0          # blocking pulls (`_device_wait`)
         self.device_wait_s = 0.0       # engine-clock seconds inside them
+        # Step clocks (same discipline: plain floats on the engine's
+        # clock, kept under enable_metrics=False). A step's wall time by
+        # seam: each total is the seconds inside the `eng.*` lane of the
+        # same name, read at the lane's two ends, so a difference of two
+        # `stats()` snapshots lies beside a profiler trace of the same
+        # stretch; with `device_wait_s`, and `step_other_s_total` the
+        # remainder, they add up to `step_s_total`.
+        self.step_s_total = 0.0        # inside `step()`, entry to exit
+        self.step_flush_s_total = 0.0  # `pipeline_flush`, less the pulls
+        #                                and replays inside it
+        self.step_admit_s_total = 0.0  # `admit`: the gate and row binding
+        self.step_prefill_dispatch_s_total = 0.0   # `advance_prefills`
+        self.step_dispatch_s_total = 0.0   # `dispatch` / `spec_draft`
+        self.step_emit_s_total = 0.0   # `emit`: the replay of a block
+        # A step of STALL_STEP_S or more, and where it stood.
+        self.steps_stalled_total = 0
+        self.step_stalled_s_total = 0.0
+        self.step_stalled_device_wait_s_total = 0.0
+        # Seconds the device had nothing to run as far as the host knows
+        # (`_idle_since`: the clock at which a pull returned with the
+        # ring empty and no program dispatched after the pulled block),
+        # closed by the next dispatch of any program and put down to ONE
+        # cause (`_starved_after`).
+        self.device_starved_s_total = 0.0
+        self.device_starved_dispatches_total = 0
+        self._starved_s = {"retire": 0.0, "admit": 0.0, "chunk": 0.0,
+                           "other": 0.0}
+        self._idle_since: Optional[float] = None
+        # an open seam's first clock reading, and `device_wait_s` at the
+        # step's entry: attributes, not locals (`_step_done` says why)
+        self._t_step = self._t_admit = self._t_prefill = 0.0
+        self._t_dispatch = 0.0
+        self._waited_in = 0.0
+        self._dispatch_seq = 0         # programs dispatched, all kinds
+        self._retired_since_decode = False   # a row ended in a block
+        #                    drained since the last decode dispatch
+        self._admitted_since_dispatch = False    # the gate admitted
+        #                    since the last dispatch of any program
         self.host_transfer_bytes = 0   # bytes those transfers moved
         self.tokens_out = 0            # tokens emitted, all requests
         # Prefill/prefix-reuse accounting (same plain-int discipline):
@@ -2139,6 +2187,8 @@ class DecodeEngine:
         queue: the host cannot know an EOS before it has the block."""
         if horizon is not None and horizon < 1:
             raise ValueError("horizon must be >= 1")
+        self._t_step = self._clock()
+        self._waited_in = self.device_wait_s
         self.steps_total += 1
         if self.sanitizer is not None and not self.sanitizer.armed:
             self._san_steps += 1
@@ -2160,6 +2210,7 @@ class DecodeEngine:
         # a second newcomer then waits for one block, not for two).
         flushed = bool(emitted)
         with self.trace.lane("admit", "admit") as admit:
+            self._t_admit = self._clock()
             # rows mid-prompt prefill a chunk this step too, and so did
             # a row that left its prompt in the chunk sent ahead for
             # this step
@@ -2235,14 +2286,16 @@ class DecodeEngine:
                                    args={"queued": len(self.scheduler)})
             admit.note(admitted=len(admissions))
             if admissions:
+                self._admitted_since_dispatch = True
                 self._admit_rows_paged(admissions)
+            self.step_admit_s_total += self._clock() - self._t_admit
         self._advance_prefills()
 
         live = [b for b in range(self.B) if self.row_req[b] is not None]
         if not live:
             if self._ring:             # defensive: never strand blocks
                 self._flush_pipeline(emitted)
-            return emitted
+            return self._step_done(emitted)
         # Rows mid-chunked-prefill are NOT decodable: their last_logits
         # still hold an intermediate chunk's scatter. They ride along
         # frozen (active=False) and take their next chunk next step.
@@ -2266,13 +2319,13 @@ class DecodeEngine:
                             args={"req": rid,
                                   "prompt_tokens": int(self.row_len[b])})
             self.metrics.on_step(len(live), len(self.scheduler), 0)
-            return emitted
+            return self._step_done(emitted)
         if len(decodable) < len(live):
             self.chunked_prefill_stalls += 1
             self.metrics.on_prefill_stall()
         if not decodable:
             self.metrics.on_step(len(live), len(self.scheduler), 0)
-            return emitted
+            return self._step_done(emitted)
 
         if not self._ring:
             decodable = self._dispatch_primary(decodable, live, horizon)
@@ -2300,6 +2353,30 @@ class DecodeEngine:
                                 self.kv_pool.blocks_in_use,
                                 self.kv_pool.free_blocks,
                                 bytes_per_token=self.kv_bytes_per_token)
+        return self._step_done(emitted)
+
+    def _step_done(self, emitted: Dict[int, List[int]]
+                   ) -> Dict[int, List[int]]:
+        """Every return of `step()`: its wall time, a stall, and an engine
+        left empty. Called AT the returns and not around the body, and
+        `step()` keeps its stamps on the engine (`_t_step`, `_waited_in`,
+        `_t_admit`; `_advance_prefills` its `_t_prefill`, a decode dispatch
+        its `_t_dispatch`) and not in
+        locals: a frame between `step` and a jitted call, and every local
+        added to a frame that stands on the stack at one, lengthened the
+        LOWERING of the Mistral prefill programs on the chip (6.7-7.0 s ->
+        8.4-8.7 with five locals, 20.9 s of tracing and lowering for 14.3
+        with the frame; PERF.md sections 6-7, PR 54)."""
+        wall = self._clock() - self._t_step
+        self.step_s_total += wall
+        if wall >= STALL_STEP_S:
+            self.steps_stalled_total += 1
+            self.step_stalled_s_total += wall
+            self.step_stalled_device_wait_s_total += \
+                self.device_wait_s - self._waited_in
+        if self._idle_since is not None and not len(self.scheduler) \
+                and not any(r is not None for r in self.row_req):
+            self._idle_since = None    # an empty engine starves nobody
         return emitted
 
     # -- async pipeline ----------------------------------------------------
@@ -2398,7 +2475,9 @@ class DecodeEngine:
         with self.trace.lane("spec_draft", "dispatch", window=W,
                              proposed=int(w_row[rows].sum()),
                              rows=len(rows),
-                             run_ahead=chain is not None):
+                             run_ahead=chain is not None,
+                             after=self._starved_after()):
+            self._t_dispatch = self._clock()
             args = chain if chain is not None else (
                 *self._row_state(), jnp.asarray(self._d_lag),
                 jnp.asarray(self._d_tok))
@@ -2430,6 +2509,7 @@ class DecodeEngine:
             self._count_decode_dispatch(chain)
             self.spec_dispatches += 1
             self.metrics.on_dispatch(W + 1, host_syncs=0)
+            self._decode_dispatched()
 
     def _dispatch_decode(self, H: int, rows: List[int],
                          chain: Optional[tuple]) -> None:
@@ -2441,7 +2521,9 @@ class DecodeEngine:
         computing the block — and any queued successors."""
         with self.trace.lane("dispatch", "dispatch", horizon=H,
                              rows=len(rows),
-                             run_ahead=chain is not None):
+                             run_ahead=chain is not None,
+                             after=self._starved_after()):
+            self._t_dispatch = self._clock()
             args = chain if chain is not None else self._row_state()
             # The static greedy flag is the all-greedy fast path: without
             # per-request overrides it equals the engine-wide mode exactly
@@ -2491,6 +2573,54 @@ class DecodeEngine:
             self.state_kernel_decode_dispatches += \
                 bool(in_place and in_place(self.cfg))
             self.metrics.on_dispatch(H, host_syncs=0)
+            self._decode_dispatched()
+
+    def _starved_after(self) -> str:
+        """The ONE cause of the gap the next dispatch closes, "" with no
+        gap open (`_idle_since`): ``retire`` a row ended in a block
+        drained since the last decode dispatch, its slot has a newcomer
+        or not (ROADMAP S14 b's case: the ring stops short of a block in
+        which a budget ends); ``admit`` none did and the gate has just
+        admitted (an arrival into a free slot); ``chunk`` a row is
+        mid-prompt; ``other`` the rest (a ring held to one block, a pool
+        too dry to run ahead). What the `dispatch`, `spec_draft` and
+        `prefill_dispatch` lanes carry as ``after=``."""
+        if self._idle_since is None:
+            return ""
+        if self._retired_since_decode:
+            return "retire"
+        if self._admitted_since_dispatch:
+            return "admit"
+        return "chunk" if self._row_prefill else "other"
+
+    def _dispatched(self, after: str, now: Optional[float] = None) -> None:
+        """One program of any kind is handed to the device: the device
+        has something to run again, and the seconds it had nothing
+        (`after`, from `_starved_after` before the launch) go under
+        their cause."""
+        self._dispatch_seq += 1
+        self._admitted_since_dispatch = False
+        if after:
+            gap = (self._clock() if now is None else now) \
+                - self._idle_since
+            self._idle_since = None
+            self.device_starved_s_total += gap
+            self._starved_s[after] += gap
+            self.device_starved_dispatches_total += 1
+
+    def _decode_dispatched(self) -> None:
+        """The end of a `dispatch` / `spec_draft` lane opened at
+        `_t_dispatch`, its block the ring's newest: ONE clock read is the
+        seam's end, the block's dispatch time and the end of a starved
+        gap."""
+        entry = self._ring[-1]
+        now = entry.t_dispatch = self._clock()
+        self.step_dispatch_s_total += now - self._t_dispatch
+        after = self._starved_after()
+        self._retired_since_decode = False
+        self._dispatched(after, now)
+        entry.seq = self._dispatch_seq
+        entry.ahead = len(self._ring) - 1
 
     def _count_decode_dispatch(self, chain: Optional[tuple]) -> None:
         """One fused decode (or speculative) launch; a chained one ran
@@ -2740,7 +2870,7 @@ class DecodeEngine:
         with tr.lane("host_drain", "drain", horizon=entry.H,
                      depth=depth) as drain:
             t0 = tr.now() if tr.enabled else 0.0
-            block = self._device_wait(entry.toks)
+            block = self._device_wait(entry.toks, entry.seq)
             self.host_syncs += 1
             nbytes = int(getattr(block, "nbytes", block.size * 4))
             drain.note(bytes=nbytes)
@@ -2755,8 +2885,12 @@ class DecodeEngine:
                 self._moe_seen = seen
                 block = block[:entry.H]
             with tr.lane("emit", "drain"):
+                t_emit = self._clock()
+                self.metrics.on_block(entry.t_dispatch, entry.ahead,
+                                      entry.H)
                 sp_rounds, sp_prop, sp_acc = self._emit_block(
                     block, entry, emitted)
+                self.step_emit_s_total += self._clock() - t_emit
             self.metrics.on_pipeline_drain(depth, len(self._ring))
             if entry.spec and sp_rounds:
                 self.metrics.on_spec_round(sp_rounds, sp_prop, sp_acc)
@@ -2775,16 +2909,24 @@ class DecodeEngine:
                        args={"window": entry.w_max, "rounds": sp_rounds,
                              "proposed": sp_prop, "accepted": sp_acc})
 
-    def _device_wait(self, x) -> np.ndarray:
+    def _device_wait(self, x, seq: Optional[int] = None) -> np.ndarray:
         """`_device_get` from inside the serving loop, with the time the
         host stood blocked in it kept (`device_wait_s`, `device_waits`)
         and shown as its own span: what is left of a step's wall time
-        is the host's own work."""
+        is the host's own work. `seq` is the pulled program's place
+        among the dispatches (None: the last one). With the ring empty
+        and nothing dispatched after it, the device has nothing to run
+        from the pull's return on (`_idle_since`); a program sent after
+        it (a chunk sent ahead) counts as in flight until the next
+        pull."""
         with self.trace.lane("device_wait", "drain"):
             t = self._clock()
             out = _device_get(x)
-            self.device_wait_s += self._clock() - t
+            now = self._clock()
+            self.device_wait_s += now - t
         self.device_waits += 1
+        self._idle_since = now if not self._ring and (
+            seq is None or seq == self._dispatch_seq) else None
         return out
 
     def _flush_pipeline(self, emitted: Dict[int, List[int]]) -> None:
@@ -2797,8 +2939,12 @@ class DecodeEngine:
         self.metrics.on_pipeline_flush()
         with self.trace.lane("pipeline_flush", "drain",
                              steps=len(self._ring)):
+            t0 = self._clock()
+            inside = self.device_wait_s + self.step_emit_s_total
             while self._ring:
                 self._drain_one(emitted)
+            self.step_flush_s_total += self._clock() - t0 - (
+                self.device_wait_s + self.step_emit_s_total - inside)
 
     def stats(self) -> Dict[str, float]:
         """Flat numeric telemetry snapshot (EngineMetrics.stats) plus
@@ -2850,6 +2996,30 @@ class DecodeEngine:
         # time less this is the host's own work.
         out["device_waits"] = float(self.device_waits)
         out["device_wait_s"] = float(self.device_wait_s)
+        # Step clocks: a step's wall time by seam (the six below,
+        # `device_wait_s` and the remainder add up to `step_s_total`
+        # while every pull happens inside a step; a handoff export
+        # pulls outside one), steps of STALL_STEP_S or more and their
+        # seconds inside `_device_get`, and the seconds the device had
+        # nothing to run as far as the host knows, by cause.
+        seams = 0.0
+        for name in ("step_flush_s_total", "step_admit_s_total",
+                     "step_prefill_dispatch_s_total",
+                     "step_dispatch_s_total", "step_emit_s_total"):
+            out[name] = float(getattr(self, name))
+            seams += out[name]
+        out["step_s_total"] = float(self.step_s_total)
+        out["step_other_s_total"] = \
+            self.step_s_total - seams - self.device_wait_s
+        out["steps_stalled_total"] = float(self.steps_stalled_total)
+        out["step_stalled_s_total"] = float(self.step_stalled_s_total)
+        out["step_stalled_device_wait_s_total"] = float(
+            self.step_stalled_device_wait_s_total)
+        out["device_starved_s_total"] = float(self.device_starved_s_total)
+        out["device_starved_dispatches_total"] = float(
+            self.device_starved_dispatches_total)
+        for cause, seconds in self._starved_s.items():
+            out[f"device_starved_{cause}_s_total"] = seconds
         # Compile plane: programs JAX built since the PROCESS started
         # (every engine of a process reports the same three; a rollup
         # takes them once). A warmed engine holds them still: one that
@@ -3101,6 +3271,7 @@ class DecodeEngine:
                                        for r in self.row_req),
                       "inflight_steps": len(self._ring)})
         self._ring.clear()
+        self._idle_since = None
         self._row_prefill.clear()
         self._chunk_ahead.clear()
         for row in range(self.B):
@@ -3360,6 +3531,7 @@ class DecodeEngine:
                 self._pool_k, self._pool_v, jnp.asarray(src),
                 jnp.asarray(dst), shardings=self._shardings,
                 scale_k=self._scale_k, scale_v=self._scale_v)
+            self._dispatched(self._starved_after())
         self._seed_draft_rows(draft_seeds)
 
     def _seed_draft_rows(
@@ -3418,6 +3590,7 @@ class DecodeEngine:
                         scale_k=self._scale_dk, scale_v=self._scale_dv,
                         qspec=self.kv_quant_spec)
                 self.spec_prefill_dispatches += 1
+                self._dispatched(self._starved_after())
 
     def _bind_row(self, row: int, req: _Request, chain: List[int],
                   start: int) -> None:
@@ -3619,6 +3792,7 @@ class DecodeEngine:
             self._pool_k, self._pool_v, jnp.asarray(bids),
             shardings=self._shardings, scale_k=self._scale_k,
             scale_v=self._scale_v)
+        self._dispatched(self._starved_after())
         parts = [k, v, self._last_logits[row], sk, sv]
         for x in parts:
             if x is not None:
@@ -3718,6 +3892,7 @@ class DecodeEngine:
             scale_v=self._scale_v,
             host_sk=None if swap.sk is None else jnp.asarray(swap.sk),
             host_sv=None if swap.sv is None else jnp.asarray(swap.sv))
+        self._dispatched(self._starved_after())
         self._set_row_logits(row, swap.logits)
         self._bind_row(row, req, ids, swap.row_len)
         self.row_budget[row] = swap.budget
@@ -3998,6 +4173,7 @@ class DecodeEngine:
             return
         with self.trace.lane("advance_prefills", "dispatch",
                              rows=len(todo), ahead=ahead):
+            self._t_prefill = self._clock()
             # A group is one program: the chunks of one bucket and, where
             # prefill stops early (a `HybridConfig`), of one kind, a
             # prompt's last chunk or not (the others are always "last":
@@ -4028,7 +4204,8 @@ class DecodeEngine:
                 grp = groups[Cb, final]
                 n = len(grp)
                 with self.trace.lane("prefill_dispatch", "dispatch",
-                                     bucket=Cb, rows=n) as span:
+                                     bucket=Cb, rows=n,
+                                     after=self._starved_after()) as span:
                     n_pad = _pow2(n)
                     prompts = np.zeros((n_pad, Cb), np.int32)
                     rows = np.zeros((n_pad,), np.int32)
@@ -4096,6 +4273,7 @@ class DecodeEngine:
                     self.prefill_padded_tokens += padded
                     self.metrics.on_prefill_batch(real, padded)
                     span.note(real=real, padded=padded)
+                    self._dispatched(self._starved_after())
             done_rows = []
             for grp in groups.values():
                 for row, st, C in grp:
@@ -4117,6 +4295,8 @@ class DecodeEngine:
             for row in done_rows:
                 st = self._row_prefill.pop(row)
                 self.metrics.on_decodable(st.req.req_id)
+            self.step_prefill_dispatch_s_total += \
+                self._clock() - self._t_prefill
 
     def _emit_block(self, block: np.ndarray, entry: _InflightStep,
                     emitted: Dict[int, List[int]]
@@ -4199,6 +4379,7 @@ class DecodeEngine:
                     or (self.eos_id is not None
                         and toks[-1] == self.eos_id)):
                 req.done = True
+                self._retired_since_decode = True
                 self.finished.add(req.req_id)
                 self.metrics.on_finish(req.req_id)
                 if tr.enabled:

@@ -9,7 +9,11 @@ a vLLM-class engine is judged by:
                    prompt chunk is dispatched)
 - first block     (decodable → first token on the host; the first token
                    rides a fused decode block that the host drains up
-                   to a pipeline depth later)
+                   to a pipeline depth later), itself in two at the
+                   dispatch of THAT block (`on_block`): first dispatch
+                   (decodable → the dispatch's return) and first return
+                   (→ the token on the host), with the blocks in flight
+                   in front of it and its horizon
 - TTFT            (submit → first emitted token; exactly the sum of the
                    three above, per request, on the engine's clock)
 - TPOT            (gap between consecutive tokens of one request)
@@ -26,7 +30,23 @@ feeding python/ray/_private/metrics_agent.py). Outside a cluster the
 registry is still populated locally — tests and notebooks read
 `stats()` or `ray_tpu._private.metrics.snapshots()` directly.
 
-`DecodeEngine.stats()` adds the engine's own counters to these series.
+A drained block hands a request `n` tokens at once: they are ONE
+weighted observation (`_Agg.add(v, n)`, `Histogram.observe(v, n=n)`), the
+same count, sum, max, percentiles and bucket counts as `n` calls, and the
+instruments resolve their series once, not a call.
+
+`DecodeEngine.stats()` adds the engine's own counters to these series,
+among them the step clocks, kept with the metrics plane off: a step's wall
+time by seam (`step_s_total` = `step_flush_s_total` + `step_admit_s_total`
++ `step_prefill_dispatch_s_total` + `step_dispatch_s_total` +
+`step_emit_s_total` + `device_wait_s` + `step_other_s_total`, each the
+seconds inside the `eng.*` lane of its name), the seconds the device had
+nothing to run as far as the host knows by cause
+(`device_starved_s_total`, `device_starved_{retire,admit,chunk,other}
+_s_total`, `device_starved_dispatches_total`) and the steps of
+`engine.STALL_STEP_S` or more with their seconds inside `_device_get`
+(`steps_stalled_total`, `step_stalled_s_total`,
+`step_stalled_device_wait_s_total`); `docs/serving.md` has the glossary.
 Two of them are named for the family they first counted and count MORE:
 ``ssm_state_resets_total`` (rows admitted from zero recurrent state: a
 request's first chunk, a recompute) and ``ssm_row_steps_total`` (live rows
@@ -60,7 +80,10 @@ from __future__ import annotations
 
 import itertools
 import time
+from array import array
 from typing import Callable, Dict, List, Optional
+
+import numpy as np
 
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
@@ -82,50 +105,67 @@ class _Agg:
     observations for tail-percentile snapshots. Mean/max alone hide the
     tail — the autoscaler scales on TTFT p95 and the SLO bench reports
     p95/p99, so `fields` additionally emits `_p50`/`_p95`/`_p99` over
-    the last ``WINDOW`` observations (a sliding window, the serving
+    the last ``WINDOW`` entries (a sliding window, the serving
     convention: an SLO is judged on RECENT traffic, and the bound keeps
-    a long-running engine's snapshot cost flat). The full unbounded
-    distribution still lives in the Histogram instruments."""
+    a long-running engine's snapshot cost flat). An entry is one `add`:
+    a value and the number of observations it stands for (`n`, the
+    tokens of one drained block), and a percentile is taken over the
+    observations, so `add(v, n)` reads as `n` calls of `add(v)` in every
+    field while costing one. The full unbounded distribution still lives
+    in the Histogram instruments."""
 
     WINDOW = 2048
 
-    __slots__ = ("count", "sum", "max", "_ring", "_ring_i")
+    __slots__ = ("count", "sum", "max", "_ring", "_ring_n", "_ring_i")
 
     def __init__(self):
         self.count = 0
         self.sum = 0.0
         self.max = 0.0
-        self._ring: List[float] = []
+        self._ring = array("d")             # values, and beside them the
+        self._ring_n = array("q")           # observations each stands for
         self._ring_i = 0
 
-    def add(self, v: float) -> None:
-        self.count += 1
-        self.sum += v
+    def add(self, v: float, n: int = 1) -> None:
+        if n <= 0:
+            return
+        self.count += n
+        self.sum += v * n
         if v > self.max:
             self.max = v
         if len(self._ring) < self.WINDOW:
             self._ring.append(v)
+            self._ring_n.append(n)
         else:                       # overwrite oldest: O(1), no shift
             self._ring[self._ring_i] = v
+            self._ring_n[self._ring_i] = n
             self._ring_i = (self._ring_i + 1) % self.WINDOW
 
-    def percentile(self, q: float) -> float:
-        """q-th percentile (0..100) of the retained window — the
-        nearest-rank method on a sorted copy; 0.0 when empty."""
+    def percentiles(self, qs) -> List[float]:
+        """The q-th percentiles (0..100) of the retained window's
+        observations — nearest rank over ONE sort, an entry of `n`
+        observations taking `n` ranks; 0.0 when empty."""
         if not self._ring:
-            return 0.0
-        vals = sorted(self._ring)
-        rank = max(0, min(len(vals) - 1,
-                          int(round(q / 100.0 * (len(vals) - 1)))))
-        return vals[rank]
+            return [0.0] * len(qs)
+        vals = np.frombuffer(self._ring)            # no copy
+        ns = np.frombuffer(self._ring_n, np.int64)
+        order = np.argsort(vals, kind="stable")
+        upto = np.cumsum(ns[order])     # observations up to each entry
+        total = int(upto[-1])
+        ranks = [max(0, min(total - 1, int(round(q / 100.0 * (total - 1)))))
+                 for q in qs]
+        at = np.searchsorted(upto, ranks, side="right")
+        return vals[order[at]].tolist()
+
+    def percentile(self, q: float) -> float:
+        return self.percentiles((q,))[0]
 
     def fields(self, prefix: str, out: Dict[str, float]) -> None:
         out[f"{prefix}_count"] = self.count
         out[f"{prefix}_mean"] = self.sum / self.count if self.count else 0.0
         out[f"{prefix}_max"] = self.max
-        out[f"{prefix}_p50"] = self.percentile(50.0)
-        out[f"{prefix}_p95"] = self.percentile(95.0)
-        out[f"{prefix}_p99"] = self.percentile(99.0)
+        (out[f"{prefix}_p50"], out[f"{prefix}_p95"],
+         out[f"{prefix}_p99"]) = self.percentiles((50.0, 95.0, 99.0))
 
 
 class _ReqTimes:
@@ -169,6 +209,14 @@ class EngineMetrics:
         self.queue_wait_s = _Agg()
         self.prefill_s = _Agg()
         self.first_block_s = _Agg()
+        # first_block_s in two, at the dispatch of the block that
+        # carries the first token (`on_block`), and what stood in front
+        # of that block
+        self.first_dispatch_s = _Agg()
+        self.first_return_s = _Agg()
+        self.first_blocks_ahead = _Agg()
+        self.first_block_horizon = _Agg()
+        self._block = (None, 0, 0)     # the block being replayed
         self.ttft_s = _Agg()
         self.tpot_s = _Agg()
         self.decode_dispatches = 0
@@ -465,14 +513,29 @@ class EngineMetrics:
         if rt is not None and rt.first_token_t is None:
             rt.decodable_t = self._clock()
 
+    def on_block(self, dispatch_t: float, blocks_ahead: int,
+                 horizon: int) -> None:
+        """The drained block whose tokens the `on_tokens` calls that
+        follow hand over: the engine clock at its dispatch's return, the
+        blocks in flight in front of it then, its horizon."""
+        self._block = (dispatch_t, blocks_ahead, horizon)
+
     def _on_first_token(self, rt: _ReqTimes, now: float) -> None:
-        """TTFT and its two inner parts close together, so that
-        queue_wait + prefill + first_block == ttft for every request."""
+        """TTFT and its inner parts close together, so that
+        queue_wait + prefill + first_block == ttft, and first_dispatch
+        + first_return == first_block, for every request."""
         rt.first_token_t = now
         admit_t = rt.submit_t if rt.admit_t is None else rt.admit_t
         dec_t = admit_t if rt.decodable_t is None else rt.decodable_t
         self.prefill_s.add(dec_t - admit_t)
         self.first_block_s.add(now - dec_t)
+        disp_t, ahead, horizon = self._block
+        if disp_t is None or disp_t < dec_t:   # no block named: all wait
+            disp_t = dec_t
+        self.first_dispatch_s.add(disp_t - dec_t)
+        self.first_return_s.add(now - disp_t)
+        self.first_blocks_ahead.add(ahead)
+        self.first_block_horizon.add(horizon)
         ttft = now - rt.submit_t
         self.ttft_s.add(ttft)
         self._m_ttft.observe(ttft)
@@ -502,7 +565,9 @@ class EngineMetrics:
         tokens each take an `n`-th of the wall gap since the block
         before (same count, same sum as one real gap and `n - 1` zeros,
         but a median that is the device's cadence and not 0); the
-        tokens that land WITH the first one wait 0.0 behind it."""
+        tokens that land WITH the first one wait 0.0 behind it. The
+        block's observations are ONE weighted call of the aggregate and
+        of the histogram, not a call a token."""
         if n <= 0:
             return
         rt = self._req.get(req_id)
@@ -516,9 +581,9 @@ class EngineMetrics:
             k, tpot = n - 1, 0.0
         else:
             k, tpot = n, (now - rt.last_token_t) / n
-        for _ in range(k):
-            self.tpot_s.add(tpot)
-            self._m_tpot.observe(tpot)
+        if k:
+            self.tpot_s.add(tpot, k)
+            self._m_tpot.observe(tpot, n=k)
         rt.last_token_t = now
         rt.n_tokens += n
 
@@ -830,6 +895,10 @@ class EngineMetrics:
         self.queue_wait_s.fields("queue_wait_s", out)
         self.prefill_s.fields("prefill_s", out)
         self.first_block_s.fields("first_block_s", out)
+        self.first_dispatch_s.fields("first_dispatch_s", out)
+        self.first_return_s.fields("first_return_s", out)
+        self.first_blocks_ahead.fields("first_blocks_ahead", out)
+        self.first_block_horizon.fields("first_block_horizon", out)
         self.ttft_s.fields("ttft_s", out)
         self.tpot_s.fields("tpot_s", out)
         self.decode_horizon.fields("decode_horizon", out)
@@ -855,6 +924,8 @@ class NullEngineMetrics:
     def on_token(self, req_id, n=1): pass
 
     def on_tokens(self, req_id, n): pass
+
+    def on_block(self, dispatch_t, blocks_ahead, horizon): pass
 
     def on_finish(self, req_id): pass
 

@@ -64,6 +64,16 @@ Span catalogue (name / tid lane / meaning):
 - ``prefill_dispatch`` (engine ``dispatch`` lane): one batched prefill
   program launch, inside ``advance_prefills`` (one chunk for every row
   mid-prompt).
+- ``dispatch``, ``spec_draft`` and ``prefill_dispatch`` carry
+  ``after=``: "" where the device had work when the launch went out,
+  else why it had none until this launch (``retire`` / ``admit`` /
+  ``chunk`` / ``other``: `DecodeEngine._starved_after`), the cause
+  `stats()` counts the gap under (``device_starved_<cause>_s_total``).
+- The engine reads its own clock at the two ends of ``admit``,
+  ``advance_prefills``, ``dispatch`` / ``spec_draft``,
+  ``pipeline_flush``, ``device_wait`` and ``emit`` and keeps the sums in
+  `stats()` (``step_*_s_total``, ``device_wait_s``): the same intervals
+  as these lanes, on the engine's clock, with no session running.
 - ``admit`` (engine ``admit`` lane): the admission loop of one step
   and the row binding / prefix work of what it admitted.
 - ``pipeline_flush`` (engine ``drain`` lane): a forced drain of the

@@ -43,6 +43,7 @@ def reset_registry() -> None:
 
 class _Base:
     _kind = ""
+    _boundaries: Optional[List[float]] = None      # a Histogram's
 
     def __init__(self, name: str, description: str = "",
                  tag_keys: Optional[Tuple[str, ...]] = None):
@@ -52,6 +53,9 @@ class _Base:
         self._description = description
         self._tag_keys = tuple(tag_keys or ())
         self._default_tags: Dict[str, str] = {}
+        # the series of the default tags, resolved once for the
+        # registry it was resolved in (`reset_registry` starts another)
+        self._series: Optional[Tuple[int, Any]] = None
         _impl.ensure_pusher()
 
     def set_default_tags(self, tags: Dict[str, str]):
@@ -59,7 +63,24 @@ class _Base:
         if bad:
             raise ValueError(f"tags {sorted(bad)} not in tag_keys")
         self._default_tags = dict(tags)
+        self._series = None
         return self
+
+    def _resolve(self, tags: Optional[Dict[str, str]]):
+        """The registry's series for `tags` over the defaults. A call
+        without tags of its own (every hot-path one) takes the series
+        kept from the call before: no dict, no key, no registry lock."""
+        if tags:
+            return _impl.register(self._name, self._kind,
+                                  self._description, self._merged(tags),
+                                  self._boundaries)
+        kept = self._series
+        if kept is None or kept[0] != _impl.registry_generation():
+            kept = self._series = (
+                _impl.registry_generation(),
+                _impl.register(self._name, self._kind, self._description,
+                               dict(self._default_tags), self._boundaries))
+        return kept[1]
 
     def _merged(self, tags: Optional[Dict[str, str]]) -> Dict[str, str]:
         merged = dict(self._default_tags)
@@ -84,9 +105,7 @@ class Counter(_Base):
             tags: Optional[Dict[str, str]] = None) -> None:
         if value <= 0:
             raise ValueError("Counter.inc value must be positive")
-        m = _impl.register(self._name, "counter", self._description,
-                           self._merged(tags))
-        _impl.record(m, value, "counter")
+        _impl.record(self._resolve(tags), value, "counter")
 
 
 class Gauge(_Base):
@@ -94,9 +113,7 @@ class Gauge(_Base):
 
     def set(self, value: float,
             tags: Optional[Dict[str, str]] = None) -> None:
-        m = _impl.register(self._name, "gauge", self._description,
-                           self._merged(tags))
-        _impl.record(m, value, "gauge")
+        _impl.record(self._resolve(tags), value, "gauge")
 
 
 class Histogram(_Base):
@@ -110,7 +127,7 @@ class Histogram(_Base):
             boundaries or _impl.DEFAULT_HISTOGRAM_BOUNDARIES)
 
     def observe(self, value: float,
-                tags: Optional[Dict[str, str]] = None) -> None:
-        m = _impl.register(self._name, "histogram", self._description,
-                           self._merged(tags), self._boundaries)
-        _impl.record(m, value, "histogram")
+                tags: Optional[Dict[str, str]] = None,
+                n: int = 1) -> None:
+        """One observation, or `n` equal ones at the cost of one."""
+        _impl.record(self._resolve(tags), value, "histogram", n)

@@ -2,7 +2,9 @@
 (PR 24): engine-lane spans on the profiler's clock with no knob, inert and
 allocation-free with no session; scopes and kernel names in the compiled
 programs; `queue_wait + prefill + first_block == ttft` per request;
-`device_wait_s`; the repaired `tpot_s`.
+`device_wait_s`; the repaired `tpot_s`; the step clocks (PR 54): a step's
+wall time by seam, the seconds the device had nothing to run by cause, a
+first token's path, a stalled step, a block as one weighted observation.
 """
 
 import functools
@@ -245,6 +247,350 @@ def test_tpot_median_is_the_cadence_not_zero_at_horizon_8(nano_model,
     assert s["tpot_s_mean"] * 32 == pytest.approx(t_last - t_first)
     assert s["tpot_s_p50"] > 0
     assert s["tpot_s_p50"] == pytest.approx(0.08 / 8)
+
+
+# -- step clocks (PR 54) -----------------------------------------------------
+
+SEAMS = ("step_flush_s_total", "step_admit_s_total",
+         "step_prefill_dispatch_s_total", "step_dispatch_s_total",
+         "step_emit_s_total", "device_wait_s", "step_other_s_total")
+CAUSES = ("retire", "admit", "chunk", "other")
+ENGINE_OWN = SEAMS + (
+    "step_s_total", "steps_stalled_total", "step_stalled_s_total",
+    "step_stalled_device_wait_s_total", "device_starved_s_total",
+    "device_starved_dispatches_total") + tuple(
+        f"device_starved_{c}_s_total" for c in CAUSES)
+
+
+class TickClock:
+    """A clock on which every reading takes a millisecond: each seam of a
+    step has a length without a real sleep."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 0.001
+        return self.t
+
+    def advance(self, dt):
+        self.t += dt
+
+
+def _slow_device(monkeypatch, clock, seconds=0.02):
+    real = engine_mod._device_get
+
+    def slow_get(x):
+        clock.advance(seconds)
+        return real(x)
+
+    monkeypatch.setattr(engine_mod, "_device_get", slow_get)
+
+
+def _starved(s):
+    return {c: s[f"device_starved_{c}_s_total"] for c in CAUSES}
+
+
+@pytest.mark.parametrize("metrics", [True, False], ids=["on", "off"])
+def test_step_seams_sum_to_the_step(nano_model, monkeypatch, metrics):
+    """Admissions, chunked prompts, run-ahead and a flush: the six seams,
+    `device_wait_s` and the remainder are `step_s_total`, and the
+    engine's own keys are there with the metrics plane off."""
+    cfg, params = nano_model
+    clock = TickClock()
+    _slow_device(monkeypatch, clock)
+    eng = DecodeEngine(params, cfg, batch_slots=3, max_len=64,
+                       kv_block_tokens=4, prefill_chunk=4, decode_horizon=2,
+                       prefix_cache=False, clock=clock,
+                       enable_metrics=metrics)
+    eng.submit(list(range(1, 11)), 24)          # three chunks
+    outside = 0.0
+    n = 0
+    while eng.pending():
+        if n == 8:                              # a free slot, blocks in
+            eng.submit([3, 1, 4, 1, 5, 9, 2], 6)    # flight: a flush
+        t = clock.t
+        eng.step()
+        outside += clock.t - t
+        n += 1
+    s = eng.stats()
+    assert set(ENGINE_OWN) <= set(s)
+    assert s["pipeline_flushes"] >= 1 and s["prefill_dispatches_ahead"] >= 1
+    assert s["decode_dispatches_chained"] >= 1
+    for k in SEAMS:
+        assert s[k] > 0, k
+    assert sum(s[k] for k in SEAMS) == pytest.approx(s["step_s_total"],
+                                                     rel=1e-12)
+    # the step's own two readings are a tick each: one falls inside
+    assert outside - s["step_s_total"] == pytest.approx(0.001 * n)
+    assert s["device_wait_s"] == pytest.approx(
+        0.021 * s["device_waits"])
+    assert sum(_starved(s).values()) == pytest.approx(
+        s["device_starved_s_total"])
+    assert s["steps_stalled_total"] == 0
+    assert ("first_dispatch_s_count" in s) == metrics
+
+
+def test_starved_seconds_after_a_retirement_with_a_newcomer(
+        nano_model, fake_clock, monkeypatch):
+    """The ring stops short of the block in which a budget ends while a
+    request waits: from that block's pull to the newcomer's prefill the
+    device has nothing, and the seconds go under `retire`; an engine run
+    dry starves nobody however long it stands."""
+    cfg, params = nano_model
+    _slow_device(monkeypatch, fake_clock)
+    eng = DecodeEngine(params, cfg, batch_slots=1, max_len=32,
+                       decode_horizon=2, prefix_cache=False,
+                       clock=fake_clock)
+    eng.submit([5, 6, 7], 4)
+    eng.submit([5, 6, 8], 4)
+    while eng.pending():
+        eng.step()
+        fake_clock.advance(0.1)
+    s = eng.stats()
+    assert s["decode_dispatches_chained_queued"] >= 1
+    assert _starved(s) == {"retire": pytest.approx(0.1), "admit": 0.0,
+                           "chunk": 0.0, "other": 0.0}
+    assert s["device_starved_dispatches_total"] == 1
+    fake_clock.advance(30.0)                    # empty: nothing accrues
+    eng.submit([5, 6, 9], 2)
+    eng.run()
+    assert eng.stats()["device_starved_s_total"] == pytest.approx(0.1)
+
+
+def test_starved_seconds_after_an_arrival_into_a_free_slot(
+        nano_model, fake_clock, monkeypatch):
+    cfg, params = nano_model
+    _slow_device(monkeypatch, fake_clock)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                       decode_horizon=2, prefix_cache=False,
+                       clock=fake_clock, trace=True)
+    binding = eng._admit_rows_paged
+
+    def slow_binding(admissions):               # between pull and prefill
+        fake_clock.advance(0.05)
+        binding(admissions)
+
+    eng._admit_rows_paged = slow_binding
+    eng.submit([5, 6, 7], 40)
+    for _ in range(4):
+        eng.step()
+    assert len(eng._ring) == 1 and eng.stats()["device_starved_s_total"] == 0
+    eng.submit([5, 6, 8], 4)                    # the flush, then the gate
+    eng.step()
+    s = eng.stats()
+    assert s["pipeline_flushes"] == 1
+    assert _starved(s) == {"retire": 0.0, "admit": pytest.approx(0.05),
+                           "chunk": 0.0, "other": 0.0}
+    # the span that ended the gap says what it followed
+    after = [args["after"] for name, _, _, _, _, args in eng.trace.events()
+             if name in ("dispatch", "prefill_dispatch")]
+    assert after.count("admit") == 1 and set(after) == {"", "admit"}
+
+
+def test_a_chunk_sent_ahead_is_in_flight_and_one_held_back_starves(
+        nano_model, fake_clock, monkeypatch):
+    cfg, params = nano_model
+    _slow_device(monkeypatch, fake_clock)
+
+    def drive(hold_back):
+        eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                           kv_block_tokens=4, prefill_chunk=4,
+                           decode_horizon=2, prefix_cache=False,
+                           clock=fake_clock)
+        chunks = eng._advance_prefills
+
+        def slow_chunks(ahead=False):
+            if not ahead:                       # the host takes its time
+                fake_clock.advance(0.1)         # before the step's chunk
+            if not (ahead and hold_back):
+                chunks(ahead)
+
+        eng._advance_prefills = slow_chunks
+        eng.submit([5, 6, 7], 40)
+        eng.step()
+        eng.submit(list(range(1, 30)), 2)       # eight chunks beside a row
+        for _ in range(4):
+            eng.step()
+        assert eng._row_prefill                 # still mid-prompt
+        return eng.stats()
+
+    s = drive(hold_back=False)
+    assert s["prefill_dispatches_ahead"] >= 3
+    # the newcomer's first chunk closes the only gap: the flush's
+    assert _starved(s) == {"retire": 0.0, "admit": pytest.approx(0.1),
+                           "chunk": 0.0, "other": 0.0}
+    assert s["device_starved_dispatches_total"] == 1
+    s = drive(hold_back=True)
+    assert s["prefill_dispatches_ahead"] == 0
+    assert _starved(s) == {"retire": 0.0, "admit": pytest.approx(0.1),
+                           "chunk": pytest.approx(0.3), "other": 0.0}
+
+
+def test_a_ring_of_one_block_starves_under_other(nano_model, fake_clock,
+                                                 monkeypatch):
+    cfg, params = nano_model
+    _slow_device(monkeypatch, fake_clock)
+    eng = DecodeEngine(params, cfg, batch_slots=1, max_len=32,
+                       decode_horizon=2, pipeline_depth=1,
+                       prefix_cache=False, clock=fake_clock)
+    eng.submit([5, 6, 7], 8)
+    while eng.pending():
+        eng.step()
+        fake_clock.advance(0.1)
+    s = eng.stats()
+    assert s["decode_dispatches"] == 4
+    assert _starved(s) == {"retire": 0.0, "admit": 0.0, "chunk": 0.0,
+                           "other": pytest.approx(0.3)}
+
+
+@pytest.mark.parametrize("chunk", [None, 4], ids=["unchunked", "chunked"])
+def test_first_block_is_the_sum_of_its_two_parts(nano_model, fake_clock,
+                                                 chunk, monkeypatch):
+    """Per request: decodable -> dispatch of the block that carries the
+    first token -> the token on the host; rows are preempted on the way."""
+    cfg, params = nano_model
+    _slow_device(monkeypatch, fake_clock)
+    eng = DecodeEngine(params, cfg, batch_slots=4, max_len=32,
+                       kv_block_tokens=4, prefill_chunk=chunk,
+                       preempt="swap", kv_pool_bytes=_pool_bytes(cfg, 10),
+                       prefix_cache=False, clock=fake_clock)
+    m = eng.metrics
+    per_request = []
+    closing = m._on_first_token
+
+    def spy(rt, now):
+        closing(rt, now)
+        per_request.append((rt.admit_t - rt.submit_t,) + tuple(
+            a._ring[-1] for a in (
+                m.first_dispatch_s, m.first_return_s, m.first_block_s,
+                m.prefill_s, m.ttft_s, m.first_blocks_ahead,
+                m.first_block_horizon)))
+
+    m._on_first_token = spy
+    prompts = [[7, 8, 9, 10, 11, 12, 13], [3, 1, 4, 1, 5], [2, 7, 1, 8, 2],
+               [9, 9, 8, 8, 7, 7], [1, 2, 3], [4, 5, 6, 7, 8, 9]]
+    for p in prompts:
+        eng.submit(p, 12)
+        fake_clock.advance(0.013)
+    while eng.pending():
+        fake_clock.advance(0.1)
+        eng.step()
+    s = eng.stats()
+    assert s["preemptions"] >= 1
+    assert len(per_request) == len(prompts) == s["first_dispatch_s_count"] \
+        == s["first_return_s_count"] == s["first_blocks_ahead_count"] \
+        == s["first_block_horizon_count"]
+    for q, d, r, f, p, t, ahead, horizon in per_request:
+        assert d + r == pytest.approx(f, abs=1e-12)
+        assert q + p + d + r == pytest.approx(t, abs=1e-12)
+        assert d >= 0 and r >= 0.02             # the pull alone is 20 ms
+        assert 0 <= ahead < eng.pipeline_depth and horizon >= 1
+    for key in ("first_dispatch_s", "first_return_s"):
+        assert {f"{key}_{f}" for f in ("count", "mean", "max", "p50", "p95",
+                                       "p99")} <= set(s)
+    assert s["first_dispatch_s_mean"] + s["first_return_s_mean"] \
+        == pytest.approx(s["first_block_s_mean"])
+
+
+def test_a_stalled_step_says_where_it_stood(nano_model, fake_clock,
+                                            monkeypatch):
+    cfg, params = nano_model
+    real = engine_mod._device_get
+    waits = iter([0.02, 0.02, 0.6] + [0.02] * 100)
+
+    def get(x):
+        fake_clock.advance(next(waits))
+        return real(x)
+
+    monkeypatch.setattr(engine_mod, "_device_get", get)
+    eng = DecodeEngine(params, cfg, batch_slots=2, max_len=64,
+                       decode_horizon=2, clock=fake_clock,
+                       enable_metrics=False)
+    assert engine_mod.STALL_STEP_S == 0.5
+    eng.submit([5, 6, 7], 20)
+    for _ in range(5):
+        eng.step()
+    s = eng.stats()
+    assert s["steps_stalled_total"] == 1
+    assert s["step_stalled_s_total"] == pytest.approx(0.6)
+    assert s["step_stalled_device_wait_s_total"] == pytest.approx(0.6)
+    binding = eng._admit_rows_paged
+
+    def descheduled(admissions):                # the host, not the device
+        fake_clock.advance(0.5)
+        binding(admissions)
+
+    eng._admit_rows_paged = descheduled
+    eng.submit([5, 6, 8], 4)
+    eng.step()
+    s = eng.stats()
+    assert s["steps_stalled_total"] == 2
+    # that step: the flush's one pull, the gate, and no drain after it
+    assert s["step_stalled_s_total"] == pytest.approx(0.6 + 0.52)
+    assert s["step_stalled_device_wait_s_total"] == pytest.approx(0.62)
+    eng.run()
+    assert eng.stats()["steps_stalled_total"] == 2
+
+
+@pytest.mark.parametrize("n", [1, 2, 8])
+def test_a_block_is_one_weighted_observation(n, fake_clock):
+    """`add(v, n)` and `observe(v, n=n)` are `n` single calls in every
+    field: count, sum, max, percentiles, bucket counts."""
+    from ray_tpu.models.engine_metrics import (LATENCY_BOUNDARIES_S,
+                                               EngineMetrics, _Agg)
+    from ray_tpu.util import metrics as um
+
+    values = [0.0004, 0.03, 0.0125, 0.3, 0.0125, 40.0, 0.002]
+    one, many = _Agg(), _Agg()
+    h_one = um.Histogram("test_block_one", "", LATENCY_BOUNDARIES_S)
+    h_many = um.Histogram("test_block_many", "", LATENCY_BOUNDARIES_S)
+    for v in values:
+        one.add(v, n)
+        h_one.observe(v, n=n)
+        for _ in range(n):
+            many.add(v)
+            h_many.observe(v)
+    a, b = {}, {}
+    one.fields("x", a)
+    many.fields("x", b)
+    assert a == pytest.approx(b, rel=1e-12) and a["x_count"] == 7 * n
+    rows = {r["name"]: r for r in um.snapshots()
+            if r["name"].startswith("test_block_")}
+    for field in ("bucket_counts", "count"):
+        assert rows["test_block_one"][field] \
+            == rows["test_block_many"][field]
+    assert rows["test_block_one"]["sum"] == pytest.approx(
+        rows["test_block_many"]["sum"])
+    # and through the engine's hook: a block of n tokens after the first
+    m = EngineMetrics(engine_id=f"block-{n}", clock=fake_clock)
+    m.on_submit(0)
+    m.on_admit(0)
+    m.on_tokens(0, 1)
+    fake_clock.advance(0.08)
+    m.on_tokens(0, n)
+    s = m.stats()
+    assert s["tpot_s_count"] == n and s["tpot_s_max"] == 0.08 / n
+    assert s["tpot_s_mean"] * n == pytest.approx(0.08)
+    assert s["tpot_s_p50"] == 0.08 / n
+    (row,) = [r for r in um.snapshots() if r["name"] == "llm_engine_tpot_s"
+              and r["tags"] == {"engine": f"block-{n}"}]
+    assert row["count"] == n and sum(row["bucket_counts"]) == n
+    assert row["sum"] == pytest.approx(0.08)
+
+
+def test_a_kept_series_is_resolved_again_after_a_registry_reset():
+    from ray_tpu.util import metrics as um
+
+    c = um.Counter("test_kept_series", "", tag_keys=("engine",)) \
+        .set_default_tags({"engine": "a"})
+    c.inc()
+    c.inc(2)
+    c.inc(tags={"engine": "b"})
+    um.reset_registry()
+    c.inc(5)
+    rows = [r for r in um.snapshots() if r["name"] == "test_kept_series"]
+    assert [(r["tags"], r["value"]) for r in rows] == [({"engine": "a"}, 5)]
 
 
 # -- scopes and kernel names in the compiled programs ------------------------
