@@ -1725,6 +1725,10 @@ class DecodeEngine:
         self.indexer_queries_total = 0         # query-layers, and those
         self.indexer_queries_unselected_total = 0  # with <= index_topk
         #                                            slots to see
+        self.sparse_decode_pages_walked_total = 0  # page-layers a decode
+        self.sparse_decode_pages_table_total = 0   # token's attention
+        #                               walks in its full layers, of the
+        #                               table entries of the rows that ask
         self.kv_walk_tokens_window_total = 0   # token-layers decode asks
         self.kv_walk_tokens_full_total = 0     # ... per READER of the pool
         self.window_blocks_freed_total = 0     # released behind the window
@@ -2672,6 +2676,7 @@ class DecodeEngine:
         self.paged_walk_rows_chained_total += H * (self.B - 1)
         if self._selects:       # its attention is not this kernel's
             self._count_selection(slots.reshape(-1, 1), decode=True)
+            self._count_sparse_decode(slots.reshape(-1))
             if self.kv_pool_w is not None:
                 self.swa_window_rows_total += slots.size
                 self.swa_window_slots_total += int(np.minimum(
@@ -2731,6 +2736,22 @@ class DecodeEngine:
         self.indexer_queries_total += live.size * layers
         self.indexer_queries_unselected_total += int(
             (live <= cfg.index_topk).sum()) * layers
+
+    def _count_sparse_decode(self, q_slots: np.ndarray) -> None:
+        """Account the attention of one decode dispatch of a config that
+        selects, ``q_slots`` [row-tokens] the queries' slots (-1: a row
+        that asks nothing, which walks and counts nothing): in every
+        layer that selects, a token's attention walks the pages up to its
+        slot's (`ops.sparse_latent_attention`, whose trip counts come
+        from the same arithmetic) of the ``MB`` table entries a grid step
+        each used to visit."""
+        from ray_tpu.ops import sparse_latent_attention as sla
+
+        layers = self.cfg.n_select_layers
+        walked = sla.pages_walked(q_slots, self.kv_block_tokens, self._mb)
+        self.sparse_decode_pages_walked_total += int(walked.sum()) * layers
+        self.sparse_decode_pages_table_total += \
+            int((q_slots >= 0).sum()) * self._mb * layers
 
     def _count_prefill_walk(self, starts: np.ndarray,
                             last_idx: np.ndarray, bucket: int) -> None:
@@ -3117,7 +3138,9 @@ class DecodeEngine:
             self.indexer_decode_tokens_selected_total)
         for name in ("indexer_pages_walked_total",
                      "indexer_pages_table_total", "indexer_queries_total",
-                     "indexer_queries_unselected_total"):
+                     "indexer_queries_unselected_total",
+                     "sparse_decode_pages_walked_total",
+                     "sparse_decode_pages_table_total"):
             out[name] = float(getattr(self, name))
         # Recurrent-state and window planes (a `HybridConfig`; the
         # `ssm_*` and `kv_walk_tokens_full_total` also a `GdnConfig`;

@@ -65,6 +65,11 @@ rows' tables (`ops.indexer_select`: a block of queries the pages up to its
 last slot's, none below `index_topk`) and
 ``indexer_queries_unselected_total`` of ``indexer_queries_total`` the
 query-layers with at most `index_topk` slots to see, which keep them all;
+``sparse_decode_pages_walked_total`` of ``sparse_decode_pages_table_total``
+the page-layers a decode dispatch's tokens walk in the layers that select
+(`ops.sparse_latent_attention.pages_walked`: the pages up to a token's
+slot's) of the table entries of the rows that ask: the share of a
+grid-a-table-entry walk that would be live;
 and where it has window layers
 ``swa_window_rows_total`` / ``swa_window_slots_total`` count, a decode
 dispatch, the row-tokens that asked and the slots ONE window layer reads

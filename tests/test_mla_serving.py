@@ -16,6 +16,7 @@ held here; blocks of 8 tokens, chunks of 16.
 import dataclasses
 import os
 import sys
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -267,30 +268,123 @@ def test_chunk_kernel_is_the_masked_dense_attention(tiles):
     assert not np.asarray(got)[1, :, 20:].any()      # filler attends nothing
 
 
-def test_token_kernel_reads_the_pages_where_they_lie():
+# Rows of `sparse_latent_decode`'s cases: (the query's slot, -1 a row that
+# asks nothing; the table's entries). Pages of 32 slots, a pool of 16.
+_TOKEN_CASES = {
+    # two rows of unlike length: pages past a row's slot are not walked
+    "two_rows": dict(rows=[(100, [3, 1, 7, 0]), (40, [5, 2, 0, 0])]),
+    # a row that asks nothing between two that do: zero trips, zeros out,
+    # and the row before it starts the first copies of the row after
+    "asks_nothing_between": dict(
+        rows=[(70, [3, 1, 7, 0]), (-1, [0, 0, 0, 0]), (127, [5, 2, 9, 4])]),
+    "asks_nothing_first_and_last": dict(
+        rows=[(-1, [0, 0, 0, 0]), (33, [6, 8, 0, 0]), (-1, [0, 0, 0, 0])]),
+    # a row that fills its whole table (6 entries, 2 pages a step)
+    "fills_its_table": dict(
+        rows=[(191, [3, 1, 7, 10, 12, 14]), (5, [5, 0, 0, 0, 0, 0])],
+        pages_a_step=2),
+    # a table whose last step is a half step (6 entries by 4): the row
+    # that fills it folds 4 pages and then the 2 that are left
+    "table_ends_in_a_half_step": dict(
+        rows=[(191, [3, 1, 7, 10, 12, 14]), (140, [5, 2, 4, 6, 8, 0])],
+        pages_a_step=4),
+    # live counts that are no multiple of the pages a step: 5 and 1 of 3,
+    # and 4 of 3 (a last step of one page)
+    "odd_live_count": dict(
+        rows=[(130, [3, 1, 7, 10, 12, 0]), (31, [5, 0, 0, 0, 0, 0]),
+              (100, [2, 4, 6, 8, 0, 0])], pages_a_step=3),
+    # every step one page, the slots alternating all through the call
+    "a_page_a_step": dict(
+        rows=[(95, [3, 1, 7, 0]), (64, [5, 2, 9, 0]), (0, [4, 0, 0, 0])],
+        pages_a_step=1),
+    "one_row": dict(rows=[(77, [11, 13, 15, 0])], pages_a_step=2),
+    # the window layers' geometry: fewer heads, lanes wider than the
+    # latent + rope, a table of the 4 pages that cover a 65-slot window
+    # (its first page masked in part), slots counted from the first page
+    "window": dict(
+        rows=[(64 + 20, [3, 1, 7, 0]), (64 + 31, [5, 2, 9, 0]),
+              (96 + 7, [4, 6, 8, 10]), (-1, [0, 0, 0, 0]),
+              (40, [12, 14, 0, 0])],
+        window=65, heads=4, lanes=256, rc=160, pages_a_step=2),
+}
+
+
+@pytest.mark.parametrize("case", list(_TOKEN_CASES))
+def test_token_kernel_reads_the_pages_where_they_lie(case, monkeypatch):
     """`sparse_latent_decode` in interpret mode against the plain form on
-    the gathered view: two rows of unlike length through a block table,
-    each layer of the pool, pages past a row's slot skipped."""
+    the gathered view: rows through a block table, each layer of the
+    pool, a row walking the pages up to its slot's and no others (NaN
+    in every block no row walks changes nothing)."""
     from ray_tpu.ops import sparse_latent_attention as sla
 
-    L, NB, T_, MB, H, W, rc = 2, 9, 32, 4, 8, 128, 96
+    c = _TOKEN_CASES[case]
+    L, NB, T_ = 2, 16, 32
+    H, W, rc = c.get("heads", 8), c.get("lanes", 128), c.get("rc", 96)
+    slots = np.asarray([r[0] for r in c["rows"]])
+    bt = jnp.asarray([r[1] for r in c["rows"]], jnp.int32)
+    B, MB = bt.shape
+    if "pages_a_step" in c:
+        monkeypatch.setattr(sla, "_STEP_BYTES",
+                            c["pages_a_step"] * T_ * W * 4)
+        assert sla.pages_per_step(T_, W, 4, MB) == c["pages_a_step"]
     k = jax.random.split(jax.random.PRNGKey(2), 4)
     pool = jax.random.normal(k[0], (L, NB, T_, W), jnp.float32)
-    bt = jnp.array([[3, 1, 7, 0], [5, 2, 0, 0]], jnp.int32)
-    q = jax.random.normal(k[1], (2, H, W), jnp.float32)
-    slots = jnp.array([100, 40])
-    seen = jnp.arange(MB * T_)[None, :] <= slots[:, None]
-    pick = jax.random.uniform(k[2], (2, MB * T_)) < 0.4
+    q = jax.random.normal(k[1], (B, H, W), jnp.float32)
+    at = np.arange(MB * T_)[None, :]
+    seen = at <= slots[:, None]
+    if "window" in c:
+        pick = slots[:, None] - at < c["window"]
+    else:
+        pick = np.asarray(jax.random.uniform(k[2], (B, MB * T_)) < 0.4)
     bias = jnp.where(seen & pick, 0.0, -1e30).astype(jnp.float32)
+    n_live = sla.pages_walked(slots, T_, MB)
+    assert (n_live == np.where(slots < 0, 0, slots // T_ + 1)).all()
+    walked = np.zeros(NB, bool)
+    for row, n in zip(np.asarray(bt), n_live):
+        walked[row[:n]] = True
+    dirty = jnp.where(walked[None, :, None, None], pool, jnp.nan)
     for li in range(L):
-        lat = pool[li, bt].reshape(2, MB * T_, W)
+        lat = pool[li, bt].reshape(B, MB * T_, W)
         want = sla.sparse_latent_attention_reference(
             q[:, :, None], lat, bias[:, None], rc=rc, sm_scale=0.2)[:, :, 0]
-        got = sla.sparse_latent_decode(q, pool, bt, bias, slots,
+        got = sla.sparse_latent_decode(q, dirty, bt, bias, jnp.asarray(slots),
                                        jnp.int32(li), rc=rc, sm_scale=0.2,
                                        interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=5e-6, rtol=0)
+        assert not np.asarray(got)[slots < 0].any()
+
+
+def test_sparse_decode_counters_are_a_hand_count(params):
+    """A decode dispatch of rows at slots 5, 16 and 40 (blocks of 8, a
+    table of 16) walks 1, 3 and 6 pages a layer that selects, of 3 x 16
+    entries; a row that asks nothing (-1) adds to neither; and the
+    kernel's wrapper takes its trip counts from the same function."""
+    from ray_tpu.ops import sparse_latent_attention as sla
+
+    eng = engine(params)
+    layers = CFG.n_select_layers
+    T, MB = eng.kv_block_tokens, eng._mb
+    assert (T, MB) == (8, 16)
+    slots = np.asarray([5, 16, -1, 40])
+    eng._count_sparse_decode(slots)
+    st = eng.stats()
+    assert st["sparse_decode_pages_walked_total"] == (1 + 3 + 6) * layers \
+        == int((slots[slots >= 0] // T + 1).sum()) * layers
+    assert st["sparse_decode_pages_table_total"] == 3 * MB * layers
+    eng._count_sparse_decode(np.asarray([-1, -1]))
+    assert eng.stats()["sparse_decode_pages_walked_total"] \
+        == st["sparse_decode_pages_walked_total"]
+    assert eng.stats()["sparse_decode_pages_table_total"] \
+        == st["sparse_decode_pages_table_total"]
+    # the wrapper's trip counts: the scalar-prefetched operand of the call
+    with mock.patch.object(sla, "pages_walked",
+                           wraps=sla.pages_walked) as asked:
+        jax.make_jaxpr(lambda: sla.sparse_latent_decode(
+            jnp.zeros((4, 8, 128)), jnp.zeros((1, 9, T, 128)),
+            jnp.zeros((4, MB), jnp.int32), jnp.zeros((4, MB * T)),
+            jnp.asarray(slots), 0, rc=96, sm_scale=1.0, interpret=True))()
+    assert asked.call_count == 1 and asked.call_args.args[1:] == (T, MB)
 
 
 def test_kth_largest_is_exact_without_a_sort():

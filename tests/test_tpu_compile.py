@@ -861,6 +861,45 @@ def test_dots3_cell_programs_fit_the_chip(v5e, program):
                                     132, 256)
 
 
+# rows, heads, lanes, latent, table entries, pool blocks, pages a step
+_LATENT_DECODE = {
+    "dsv32_full_24x128x640": (24, 128, 640, 512, 72, 8192, 8),
+    "dots3_full_64x128x640": (64, 128, 640, 512, 132, 6144, 8),
+    "dots3_window_64x64x1152": (64, 64, 1152, 1024, 4, 409, 4),
+}
+
+
+@pytest.mark.parametrize("case", list(_LATENT_DECODE))
+def test_latent_decode_kernel_compiles_at_its_callers_shapes(v5e, case):
+    """A decode token's latent attention alone at its three callers'
+    shapes (`mla.attend_token` of both latent cells, a window layer of
+    dots3's), pages of 256 slots: rows of a call one pipeline over the
+    plane where it lies, `pages_per_step` pages a step of the double
+    buffer, inside the default scoped VMEM (no `vmem_limit_bytes`:
+    PERF.md PR 30) and with no workspace of the plane's or the table's
+    size outside the kernel."""
+    from ray_tpu.ops import sparse_latent_attention as sla
+
+    B, H, W, rc, MB, NB, pps = _LATENT_DECODE[case]
+    T = 256
+    assert sla.pages_per_step(T, W, 2, MB) == pps
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def fn(q, pool, bt, bias, slots, layer):
+        return sla.sparse_latent_decode(q, pool, bt, bias, slots, layer,
+                                        rc=rc, sm_scale=0.1, interpret=False)
+
+    compiled = jax.jit(fn).lower(
+        arg((B, H, W), jnp.bfloat16), arg((3, NB, T, W), jnp.bfloat16),
+        arg((B, MB)), arg((B, MB * T), jnp.float32), arg((B,)),
+        arg(())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "vmem_limit" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # -- the delta-rule cell's programs ----------------------------------------------
 
 @functools.lru_cache(maxsize=None)
